@@ -3,8 +3,9 @@
 Analog of ``accelerator/real_accelerator.py:51``.  Selection order:
 1. ``DS_ACCELERATOR`` env var ("tpu" | "gpu" | "cpu") — explicit override,
    mirroring the reference's env-based selection.
-2. Probe JAX platforms: tpu > gpu > cpu (the reference probes module
-   imports; here a platform probe plays that role).
+2. JAX's own default backend (the reference probes module imports;
+   here JAX's platform selection plays that role — a TPU that fails to
+   initialise raises there instead of selecting the CPU).
 """
 
 from __future__ import annotations
@@ -23,13 +24,8 @@ _KNOWN = ("tpu", "gpu", "cuda", "cpu")
 def _probe_platform() -> str:
     import jax
 
-    for platform in ("tpu", "gpu"):
-        try:
-            if jax.devices(platform):
-                return platform
-        except RuntimeError:
-            continue
-    return "cpu"
+    backend = jax.default_backend()
+    return backend if backend in ("tpu", "gpu") else "cpu"
 
 
 def _make(name: str) -> DeepSpeedAccelerator:

@@ -32,10 +32,7 @@ class TPU_Accelerator(DeepSpeedAccelerator):
 
     # -- devices --------------------------------------------------------
     def _devices(self):
-        try:
-            return jax.devices(self._name)
-        except RuntimeError:
-            return jax.devices()
+        return jax.devices(self._name)
 
     def device(self, device_index: Optional[int] = None):
         devs = self._devices()

@@ -38,11 +38,12 @@ from deepspeed_tpu.analysis.hlo import (aggregate_census,
 from deepspeed_tpu.analysis.report import (Finding, GraphAuditReport)
 
 # jaxpr primitives that round-trip through the host mid-step.  A step
-# containing one serializes device execution behind python; only
-# debug_callback (jax.debug.print) degrades to a warning — it is at
-# least async — everything else is a high finding.
-HOST_CALLBACK_PRIMS = ("callback", "debug_callback", "io_callback",
-                       "outside_call", "pure_callback")
+# containing one serializes device execution behind python; only the
+# debug prims (jax.debug.callback / jax.debug.print) degrade to a
+# warning — they are at least async — everything else is a high finding.
+_DEBUG_CALLBACK_PRIMS = ("debug_callback", "debug_print")
+HOST_CALLBACK_PRIMS = ("callback", "io_callback", "outside_call",
+                       "pure_callback") + _DEBUG_CALLBACK_PRIMS
 
 # post-lowering spellings of the same defect
 _CALLBACK_CUSTOM_CALLS = ("xla_python_cpu_callback",
@@ -114,7 +115,7 @@ def _callback_findings(jaxpr, label: str) -> List[Finding]:
     return [
         Finding(
             kind="host_callback",
-            severity="warning" if prim == "debug_callback" else "high",
+            severity="warning" if prim in _DEBUG_CALLBACK_PRIMS else "high",
             message=f"{count}× `{prim}` inside the compiled step — every "
                     "call is a device→host→device round trip on the hot "
                     "path",

@@ -32,9 +32,8 @@ _EXEMPT_FILES = frozenset({
     "deepspeed_tpu/analysis/seam.py",
 })
 
-# Module prefixes that only exist (or only behave) on one side of the
-# 0.4.x / current-jax split, plus everything under jax._src (private —
-# any release may move it).
+# Module prefixes whose spelling has moved between jax releases, plus
+# everything under jax._src (private — any release may move it).
 GATED_MODULE_PREFIXES = ("jax.experimental.shard_map", "jax._src")
 
 # Attribute chains gated by version: `jax.shard_map` (current-only),

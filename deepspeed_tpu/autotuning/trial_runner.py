@@ -42,11 +42,9 @@ def run_timed_trial(model_cfg, config, seq_len: int, steps: int) -> dict:
 
 def main(argv=None) -> int:
     argv = argv if argv is not None else sys.argv[1:]
-    # honor the parent's platform choice even when a platform plugin pinned
-    # the config (env vars alone don't override a sitecustomize plugin)
-    from deepspeed_tpu.utils.platform import honor_jax_platforms_env
+    from deepspeed_tpu.utils.platform import setup_compile_cache
 
-    honor_jax_platforms_env()
+    setup_compile_cache()
     with open(argv[0], "rb") as f:
         p = pickle.load(f)
     r = run_timed_trial(p["model_cfg"], p["config"], p["seq_len"], p["steps"])

@@ -136,9 +136,9 @@ def main(argv=None) -> int:
     p.add_argument("--trials", type=int, default=5)
     p.add_argument("--axis", type=str, default="data")
     args = p.parse_args(argv)
-    from deepspeed_tpu.utils.platform import honor_jax_platforms_env
+    from deepspeed_tpu.utils.platform import setup_compile_cache
 
-    honor_jax_platforms_env()
+    setup_compile_cache()
     for row in run_bench(args.sizes_mb, args.trials, args.axis):
         print(json.dumps(row))
     return 0

@@ -89,14 +89,6 @@ def report_lines() -> "list[str]":
 
 
 def main() -> int:
-    # honor JAX_PLATFORMS even when a platform plugin pinned the config
-    # (e.g. forced-CPU reporting on a machine whose TPU is held elsewhere)
-    try:
-        from deepspeed_tpu.utils.platform import honor_jax_platforms_env
-
-        honor_jax_platforms_env()
-    except Exception:
-        pass
     print("\n".join(report_lines()))
     return 0
 

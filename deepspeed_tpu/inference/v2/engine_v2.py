@@ -23,8 +23,10 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
-from deepspeed_tpu.inference.v2.model import (check_sampling_params,
+from deepspeed_tpu.inference.v2.model import (attention_impl_name,
+                                              check_sampling_params,
                                               ragged_decode_loop,
                                               ragged_forward,
                                               ragged_forward_sampled,
@@ -192,6 +194,13 @@ class InferenceEngineV2:
         # attach_chaos; None keeps step() at one attribute check per call
         self.chaos = None
 
+        # Everything this engine creates goes to ITS mesh slice, never to
+        # the process default device: N replicas share one host, and an
+        # unplaced array would land on devices[0] for all of them.
+        replicated = NamedSharding(self.topology.mesh, PartitionSpec())
+        self._put = partial(jax.device_put, device=replicated)
+        zeros = partial(jnp.zeros, device=replicated)
+
         pages = self.cfg.num_blocks * self.cfg.block_size
         # [L, nkv, P, d]: kv-head-major so the paged-attention kernel's page
         # blocks have (rows, head_dim) as their minor dims (lane-aligned).
@@ -200,15 +209,15 @@ class InferenceEngineV2:
             # quantized cache: int8 payload + one fp32 scale per (head,
             # row) — decode reads half the KV bytes (bandwidth-bound)
             sc_shape = kv_shape[:-1]
-            self.cache_k = {"q": jnp.zeros(kv_shape, jnp.int8),
-                            "s": jnp.zeros(sc_shape, jnp.float32)}
-            self.cache_v = {"q": jnp.zeros(kv_shape, jnp.int8),
-                            "s": jnp.zeros(sc_shape, jnp.float32)}
+            self.cache_k = {"q": zeros(kv_shape, jnp.int8),
+                            "s": zeros(sc_shape, jnp.float32)}
+            self.cache_v = {"q": zeros(kv_shape, jnp.int8),
+                            "s": zeros(sc_shape, jnp.float32)}
         else:
             kv_dt = (jnp.bfloat16 if self.cfg.kv_dtype in ("bf16", "bfloat16")
                      else dt)
-            self.cache_k = jnp.zeros(kv_shape, dtype=kv_dt)
-            self.cache_v = jnp.zeros(kv_shape, dtype=kv_dt)
+            self.cache_k = zeros(kv_shape, dtype=kv_dt)
+            self.cache_v = zeros(kv_shape, dtype=kv_dt)
 
         self._step = jax.jit(
             partial(ragged_forward, cfg=mc, block_size=self.cfg.block_size),
@@ -236,9 +245,11 @@ class InferenceEngineV2:
         # into the donated caches in place (rows padded to a pow2 bucket
         # of block rows; padding points at the reserved garbage block 0)
         self._kv_write = jax.jit(_kv_scatter, donate_argnums=(0, 1))
+        self.attention_impl = attention_impl_name(mc, self.cfg.block_size)
         log_dist(f"InferenceEngineV2: budget={self.cfg.max_ragged_batch_size} "
                  f"blocks={self.cfg.num_blocks}×{self.cfg.block_size} "
-                 f"max_seqs={self.cfg.max_tracked_sequences} tp={self.cfg.tp_size}")
+                 f"max_seqs={self.cfg.max_tracked_sequences} tp={self.cfg.tp_size} "
+                 f"attention={self.attention_impl}")
 
     # ------------------------------------------------------------------
     def _ragged_step(self, batch_uids: Sequence[int],
@@ -299,13 +310,13 @@ class InferenceEngineV2:
             nb_bucket *= 2
         nb_bucket = min(nb_bucket, self.state_manager.max_blocks_per_seq)
         args = (self.params, self.cache_k, self.cache_v,
-                jnp.asarray(rb.token_ids[:t_bucket]),
-                jnp.asarray(rb.token_slot[:t_bucket]),
-                jnp.asarray(rb.token_pos[:t_bucket]),
-                jnp.asarray(rb.token_dest[:t_bucket]),
-                jnp.asarray(rb.block_tables[:, :nb_bucket]),
-                jnp.asarray(rb.ctx_lens),
-                jnp.asarray(rb.logits_idx))
+                self._put(rb.token_ids[:t_bucket]),
+                self._put(rb.token_slot[:t_bucket]),
+                self._put(rb.token_pos[:t_bucket]),
+                self._put(rb.token_dest[:t_bucket]),
+                self._put(rb.block_tables[:, :nb_bucket]),
+                self._put(rb.ctx_lens),
+                self._put(rb.logits_idx))
         if sample is None:
             logits, self.cache_k, self.cache_v = self._step(*args)
             return rb, logits
@@ -503,7 +514,7 @@ class InferenceEngineV2:
         rows = np.concatenate(
             [np.arange(b * bs, (b + 1) * bs, dtype=np.int32)
              for b in seq.blocks[:n_full]])
-        rows = jnp.asarray(rows)
+        rows = self._put(rows)
         k = _kv_gather(self.cache_k, rows)
         v = _kv_gather(self.cache_v, rows)
         return {"tokens": list(seq.tokens[:n_full * bs]), "k": k, "v": v,
@@ -561,7 +572,7 @@ class InferenceEngineV2:
         try:
             k, v = _cut(payload["k"]), _cut(payload["v"])
             self.cache_k, self.cache_v = self._kv_write(
-                self.cache_k, self.cache_v, jnp.asarray(rows), k, v)
+                self.cache_k, self.cache_v, self._put(rows), k, v)
         except BaseException:
             # a failed scatter must not leak the freshly-allocated pages
             # (the donated caches are only rebound on success)
@@ -634,12 +645,12 @@ class InferenceEngineV2:
         nb_bucket = min(nb_bucket, self.state_manager.max_blocks_per_seq)
         nxt, self.cache_k, self.cache_v = self._verify(
             self.params, self.cache_k, self.cache_v,
-            jnp.asarray(rb.token_ids[:t_bucket]),
-            jnp.asarray(rb.token_slot[:t_bucket]),
-            jnp.asarray(rb.token_pos[:t_bucket]),
-            jnp.asarray(rb.token_dest[:t_bucket]),
-            jnp.asarray(rb.block_tables[:, :nb_bucket]),
-            jnp.asarray(rb.ctx_lens), jnp.asarray(rb.logits_idx))
+            self._put(rb.token_ids[:t_bucket]),
+            self._put(rb.token_slot[:t_bucket]),
+            self._put(rb.token_pos[:t_bucket]),
+            self._put(rb.token_dest[:t_bucket]),
+            self._put(rb.block_tables[:, :nb_bucket]),
+            self._put(rb.ctx_lens), self._put(rb.logits_idx))
         nxt = np.asarray(nxt)
         out: Dict[int, List[int]] = {}
         cursor = 0
@@ -839,8 +850,8 @@ class InferenceEngineV2:
 
         sampled, _, self.cache_k, self.cache_v = self._decode_loop(
             self.params, self.cache_k, self.cache_v,
-            jnp.asarray(tokens0), jnp.asarray(ctx0), jnp.asarray(active),
-            jnp.asarray(tables), key, jnp.float32(max(temperature, 1e-6)),
+            self._put(tokens0), self._put(ctx0), self._put(active),
+            self._put(tables), key, jnp.float32(max(temperature, 1e-6)),
             n_steps=chunk, greedy=(temperature <= 0),
             top_k=top_k, top_p=top_p)
         sampled = np.asarray(sampled)  # [chunk, s_rows]

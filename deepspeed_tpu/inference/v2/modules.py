@@ -49,8 +49,8 @@ def available(kind: str):
     return tuple(_REGISTRY.get(kind, {}))
 
 
-def resolve(kind: str, name: str = "auto", **ctx):
-    """Resolve ``kind`` to an implementation callable.
+def resolve_name(kind: str, name: str = "auto", **ctx) -> str:
+    """Resolve ``kind`` to a registered implementation NAME.
 
     ``name="auto"`` walks the heuristics; an explicit name must exist in
     the registry (ref module_registry raises on unknown ConfigBundle).
@@ -63,17 +63,24 @@ def resolve(kind: str, name: str = "auto", **ctx):
             raise KeyError(
                 f"unknown {kind} implementation '{name}' "
                 f"(available: {', '.join(impls)})")
-        return impls[name]["impl"]
+        return name
     fallback = None
-    for entry in impls.values():
+    for impl_name, entry in impls.items():
         pred = entry["default_for"]
         if pred is None:
-            fallback = entry["impl"] if fallback is None else fallback
+            fallback = impl_name if fallback is None else fallback
         elif pred(**ctx):
-            return entry["impl"]
+            return impl_name
     if fallback is None:
         raise KeyError(f"no default implementation for '{kind}'")
     return fallback
+
+
+def resolve(kind: str, name: str = "auto", **ctx):
+    """Resolve ``kind`` to an implementation callable (see
+    :func:`resolve_name`)."""
+    chosen = resolve_name(kind, name, **ctx)
+    return _REGISTRY[kind][chosen]["impl"]
 
 
 def module_overrides(config: Optional[Dict[str, Any]]) -> Dict[str, str]:

@@ -34,8 +34,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.parallel.topology import EXPERT_AXIS, get_topology
-from deepspeed_tpu.utils.jax_compat import (axis_bound_manually,
-                                            get_abstract_mesh, shard_map)
+from deepspeed_tpu.utils.jax_compat import get_abstract_mesh, shard_map
 
 # Above this many one-hot elements (T·E·C) "auto" dispatch switches from the
 # einsum formulation to the sort-based one (the one-hot would dominate HBM
@@ -400,38 +399,13 @@ def moe_forward_ep(x: jnp.ndarray, p: Dict[str, jnp.ndarray], cfg,
     # inside another shard_map (e.g. the pipeline's manual "pipe" axis) the
     # inner shard_map must be built on the *context* mesh, whose outer axes
     # are already marked Manual — passing the raw device mesh is rejected
-    if axis_bound_manually(EXPERT_AXIS):
-        # 0.4.x full-manual fallback pipelines: every mesh axis (expert
-        # included) is already manual here, so a nested shard_map cannot
-        # re-manualize it.  Emulate its boundary by hand — the enclosing
-        # region replicates x and the expert params (pipeline in_specs
-        # P()/P(pipe)), so slice this rank's token/expert shards, run the
-        # body (its collectives bind to the enclosing axis names), and
-        # stitch the token shards back with an all_gather.
-        from deepspeed_tpu.utils.jax_compat import axis_size as _axis_size
-
-        ep = _axis_size(EXPERT_AXIS)
-        eidx = lax.axis_index(EXPERT_AXIS)
-        tb = x.shape[0]
-        x_l = lax.dynamic_slice_in_dim(x, eidx * (tb // ep), tb // ep, 0)
-        p_l = {k: (v if k == "router" else jax.tree.map(
-            lambda a: lax.dynamic_slice_in_dim(
-                a, eidx * (a.shape[0] // ep), a.shape[0] // ep, 0), v))
-            for k, v in routed_p.items()}
-        out_l, l_aux = body(x_l, p_l)
-        out = lax.all_gather(out_l, EXPERT_AXIS, axis=0, tiled=True)
-        # l_aux stays the rank-local value — the mapped version's P()
-        # out_spec does the same under check_vma=False (each rank's gate
-        # statistics over its token shard; the engine's aux coefficient
-        # tolerates the shard-local estimate)
-    else:
-        ctx = get_abstract_mesh()
-        mesh = topo.mesh if ctx.empty else ctx
-        mapped = shard_map(
-            body, mesh=mesh, axis_names={EXPERT_AXIS},
-            in_specs=(P(EXPERT_AXIS), p_specs),
-            out_specs=(P(EXPERT_AXIS), P()))
-        out, l_aux = mapped(x, routed_p)
+    ctx = get_abstract_mesh()
+    mesh = topo.mesh if ctx.empty else ctx
+    mapped = shard_map(
+        body, mesh=mesh, axis_names={EXPERT_AXIS},
+        in_specs=(P(EXPERT_AXIS), p_specs),
+        out_specs=(P(EXPERT_AXIS), P()))
+    out, l_aux = mapped(x, routed_p)
     # dense-per-token branches (PR-MoE residual mix, qwen2-moe shared
     # expert) run outside the manual region under the auto partitioner
     if "residual" in p:

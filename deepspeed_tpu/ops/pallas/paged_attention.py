@@ -45,8 +45,12 @@ INTERPRET = False
 
 
 def supports(block_size: int, d: int) -> bool:
-    """Kernel applicability: page rows must be sublane-aligned."""
-    return block_size >= 8 and block_size % 8 == 0
+    """Kernel applicability: page rows must be sublane-aligned and the
+    head dim lane-aligned — the page DMA slices ``[bs, d]`` out of the
+    HBM pool, and Mosaic refuses a slice whose minor dim is not a
+    multiple of the 128-lane tiling (head_dim 64 models ride
+    ``paged_xla``)."""
+    return block_size >= 8 and block_size % 8 == 0 and d % 128 == 0
 
 
 def _kernel(pages_ref, pos_ref, clen_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -132,8 +136,9 @@ def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
     """Int8-KV variant of :func:`_kernel`: the page payloads are int8 with
     one fp32 scale per (head, row).  Only the d-wide payload rides the
     manual double-buffered DMA (half the bytes of the bf16 cache — the
-    decode bandwidth win); the [P]-long per-head scale rows are small and
-    arrive whole through an ordinary VMEM BlockSpec, sliced per page.
+    decode bandwidth win); the per-head scales are small and arrive whole
+    through an ordinary VMEM BlockSpec as ``[pages, bs]``, one sublane
+    row per page.
     Scales fold into existing vectors: the k scale multiplies score
     COLUMNS after the q·k matmul, the v scale multiplies the softmax
     probabilities before p·v — no [bs, d] dequantized buffer ever
@@ -172,14 +177,14 @@ def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
         pltpu.make_async_copy(v_hbm.at[h, pl.dslice(0, bs)],
                               v_buf.at[slot], sem_v.at[slot]).wait()
         page = pages_ref[t, j]
-        ks = ksc_ref[0, pl.dslice(page * bs, bs)]        # [bs] f32
-        vs = vsc_ref[0, pl.dslice(page * bs, bs)]
+        ks = ksc_ref[0, pl.dslice(page, 1), :]           # [1, bs] f32
+        vs = vsc_ref[0, pl.dslice(page, 1), :]
         k = k_buf[slot].astype(jnp.float32)              # int8 rows exact
         v = v_buf[slot].astype(jnp.float32)
         s = jax.lax.dot_general(
             q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)           # [group, bs]
-        s = s * (sm_scale * ks)[None, :]
+        s = s * (sm_scale * ks)
         c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * bs
         valid = (c <= pos) & (c < clen)
         if window is not None:
@@ -192,7 +197,7 @@ def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
         e = jnp.exp(s - m_new)                           # [group, bs]
         l_new = l_prev * alpha + jnp.sum(e, axis=1, keepdims=True)
         pv = jax.lax.dot_general(
-            e * vs[None, :], v, (((1,), (0,)), ((), ())),
+            e * vs, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # [group, d]
         return m_new, l_new, acc * alpha + pv
 
@@ -229,10 +234,16 @@ def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
     ]
     extra = ()
     if quant:
-        # whole per-head scale rows live in VMEM via the normal pipeline
-        in_specs += [pl.BlockSpec((1, p_rows), lambda t_, h, *refs: (h, 0)),
-                     pl.BlockSpec((1, p_rows), lambda t_, h, *refs: (h, 0))]
-        extra = (k_scales.astype(jnp.float32), v_scales.astype(jnp.float32))
+        # whole per-head scales live in VMEM via the normal pipeline,
+        # viewed [nkv, pages, bs] so the block's trailing dims are the
+        # array's own (Mosaic block constraint) and a page's scales are
+        # one dynamically indexed sublane row
+        n_pages = p_rows // bs
+        sc_spec = pl.BlockSpec((1, n_pages, bs),
+                               lambda t_, h, *refs: (h, 0, 0))
+        in_specs += [sc_spec, sc_spec]
+        extra = tuple(s.astype(jnp.float32).reshape(nkv, n_pages, bs)
+                      for s in (k_scales, v_scales))
     in_specs += [
         # the page pools stay in HBM; the kernel DMAs live pages into
         # its double buffer itself

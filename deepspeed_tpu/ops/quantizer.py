@@ -28,6 +28,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.utils.platform import on_tpu
+
 
 def _pallas_ok(x: jnp.ndarray, num_bits: int, group_size: int,
                symmetric: bool, backend: str) -> bool:
@@ -40,9 +42,7 @@ def _pallas_ok(x: jnp.ndarray, num_bits: int, group_size: int,
 
     if not pq.supports(x.shape, group_size, symmetric, num_bits):
         return False
-    if backend == "pallas" or pq.INTERPRET:
-        return True
-    return jax.default_backend() not in ("cpu",)
+    return backend == "pallas" or pq.INTERPRET or on_tpu()
 
 
 def _group(x: jnp.ndarray, group_size: int) -> Tuple[jnp.ndarray, int]:

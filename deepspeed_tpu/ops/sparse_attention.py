@@ -214,7 +214,6 @@ def sparse_attention(q, k, v, sparsity_config: SparsityConfig,
         bsm = importlib.import_module(
             "deepspeed_tpu.ops.pallas.block_sparse_mha")
         fm = importlib.import_module("deepspeed_tpu.ops.pallas.flash_mha")
-        on_tpu = jax.default_backend() == "tpu"
         lb = sparsity_config.block
         ok = (s % lb == 0 and bsm.supports(s, q.shape[-1], lb, q.shape[2],
                                            layout_heads=layout.shape[0]))
@@ -223,7 +222,12 @@ def sparse_attention(q, k, v, sparsity_config: SparsityConfig,
                 f"impl='pallas' but the block-sparse kernel does not apply "
                 f"(seq {s}, block {lb}, heads {q.shape[2]} vs layout "
                 f"{layout.shape[0]}) — fix the config or use impl='auto'")
-        if (on_tpu or fm.INTERPRET or impl == "pallas") and ok:
+        # "auto" takes the kernel only under the interpreter: the chip's
+        # compiler refuses its layout-mask BlockSpec at every shape (PR 23
+        # compile rehearsal; ROADMAP D3), so on TPU the dense path below
+        # is the one that runs.  impl="pallas" still asks for the kernel
+        # and gets the compiler's own error.
+        if (fm.INTERPRET or impl == "pallas") and ok:
             out = bsm.block_sparse_mha(
                 q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                 v.transpose(0, 2, 1, 3), layout, lb, causal=causal,

@@ -113,12 +113,9 @@ class MeshTopology:
         self.sizes: Dict[str, int] = {ax: int(sizes[ax]) for ax in MESH_AXES}
         shape = tuple(self.sizes[ax] for ax in MESH_AXES)
         if n > 1:
-            try:
-                from jax.experimental import mesh_utils
+            from jax.experimental import mesh_utils
 
-                dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-            except Exception:
-                dev_array = np.asarray(devices).reshape(shape)
+            dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
         else:
             dev_array = np.asarray(devices).reshape(shape)
         self.mesh = Mesh(dev_array, MESH_AXES)

@@ -23,6 +23,7 @@ import optax
 
 from deepspeed_tpu.runtime import constants as C
 from deepspeed_tpu.utils.logging import logger
+from deepspeed_tpu.utils.platform import on_tpu
 
 
 @dataclass
@@ -93,9 +94,7 @@ def _fused_leaf_ok(p) -> bool:
     # "checkpoints interchangeable with the optax chain" contract.
     if p.dtype != jnp.float32:
         return False
-    if fo.INTERPRET:
-        return True
-    return jax.default_backend() not in ("cpu",)
+    return fo.INTERPRET or on_tpu()
 
 
 def _fused_adam(params_cfg: Dict[str, Any], adam_w_mode: bool) -> Optimizer:

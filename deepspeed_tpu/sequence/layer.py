@@ -39,11 +39,10 @@ def _constraint(x, spec):
         return x
     manual = manual_axis_names()
     if manual:
-        # inside a shard_map body (e.g. the pipeline stage_fn on 0.4.x,
-        # where the compat shard_map is FULL-manual): a constraint naming
-        # a manually-bound axis is a hard partitioner error, and inside a
-        # manual region per-shard layouts are explicit so the hint buys
-        # nothing — skip it
+        # inside a shard_map body (e.g. the pipeline stage_fn): a
+        # constraint naming a manually-bound axis is a hard partitioner
+        # error, and inside a manual region per-shard layouts are
+        # explicit so the hint buys nothing — skip it
         named = {a for part in spec if part is not None
                  for a in (part if isinstance(part, (tuple, list))
                            else (part,))}
@@ -59,7 +58,7 @@ def ulysses_qkv_constraint(q, k, v):
 
     Composed with tensor parallelism the heads are already tp-sharded, so
     the target layout shards heads JOINTLY over (tensor, seq) — pinning
-    them to seq alone asks the partitioner for a tensor→seq relayout it
+    them to seq alone asks the partitioner for a tensor→seq re-layout it
     cannot express and it hard-aborts. Requires heads % (tp·sp) == 0."""
     topo = get_topology()
     if topo is None or topo.sp_size == 1:

@@ -33,25 +33,29 @@ _PEAK_FLOPS_BY_KIND = {
     "tpu v6e": 918e12,
 }
 
-# Non-TPU fallback (CPU test meshes, unknown PJRT devices): generous
-# enough that a host backend can never exceed it, so MFU stays a
-# meaningful (0, 1] fraction instead of clamping at 1.
-_FALLBACK_PEAK_FLOPS = 1e13
+# Host backends (CPU test meshes) have no spec sheet: a value generous
+# enough that a host can never exceed it keeps their MFU a (0, 1]
+# fraction.  It is never used for a TPU.
+_HOST_PEAK_FLOPS = 1e13
 
 
 def detect_peak_flops_per_sec() -> float:
-    """Per-device peak FLOP/s from the JAX device kind; fallback for
-    backends without a known spec (MFU then reads as a lower bound)."""
-    try:
-        import jax
+    """Per-device peak FLOP/s from the JAX device kind.  A TPU whose
+    kind is not in the table raises — an MFU against a guessed peak is
+    not a measurement."""
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return _FALLBACK_PEAK_FLOPS
+    from deepspeed_tpu.utils.platform import on_tpu
+
+    kind = jax.devices()[0].device_kind.lower()
     for key in sorted(_PEAK_FLOPS_BY_KIND, key=len, reverse=True):
         if key in kind:
             return _PEAK_FLOPS_BY_KIND[key]
-    return _FALLBACK_PEAK_FLOPS
+    if on_tpu():
+        raise ValueError(
+            f"no peak FLOP/s on record for TPU device kind {kind!r}; add "
+            f"it to telemetry/record.py:_PEAK_FLOPS_BY_KIND with its source")
+    return _HOST_PEAK_FLOPS
 
 
 def collect_hbm_stats(max_devices: int = 64) -> Dict[str, Dict[str, int]]:

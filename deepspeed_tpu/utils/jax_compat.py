@@ -1,13 +1,11 @@
-"""Version-portable spellings of the jax APIs this repo leans on.
+"""The one file that spells the jax APIs this repo leans on.
 
-The codebase targets the current jax API surface (``jax.shard_map`` with
-``axis_names``/``check_vma``, ``jax.sharding.get_abstract_mesh``,
-``pltpu.CompilerParams``, ``jax.memory.Space``), but CI images and TPU
-pods pin older 0.4.x releases where the same features exist under their
-pre-stabilization names (``jax.experimental.shard_map`` with
-``auto``/``check_rep``, ``pltpu.TPUCompilerParams``,
-``TransferToMemoryKind``).  Every call site imports the helpers here so
-the version split lives in exactly one file.
+Written for the installed jax (0.9): ``jax.shard_map`` with
+``axis_names``/``check_vma``, ``jax.lax.axis_size``, ``jax.memory.Space``,
+``jax.sharding.get_abstract_mesh``, ``pltpu.CompilerParams``.  Every call
+site imports these helpers and the seam lint (``analysis/seam.py``) keeps
+the spellings out of the rest of the tree, so a jax release that moves
+one of them is repaired here and nowhere else.
 """
 
 from __future__ import annotations
@@ -15,132 +13,72 @@ from __future__ import annotations
 import jax
 
 __all__ = ["shard_map", "get_abstract_mesh", "tpu_compiler_params",
-           "axis_size", "axis_bound_manually", "memory_spaces"]
+           "axis_size", "manual_axis_names", "all_axes_manual",
+           "with_unit_axes", "memory_spaces"]
 
 
 def memory_spaces():
     """``(HOST, DEVICE)`` placement targets for ``device_put`` inside
-    jit: the ``jax.memory.Space`` enum where it exists (jax >= 0.5);
-    on 0.4.x the string-keyed ``TransferToMemoryKind`` carries the same
-    placement semantics (``pinned_host`` / ``device``)."""
-    try:
-        return jax.memory.Space.Host, jax.memory.Space.Device
-    except AttributeError:
-        from jax._src.sharding_impls import TransferToMemoryKind
-
-        return (TransferToMemoryKind("pinned_host"),
-                TransferToMemoryKind("device"))
-
-
-def axis_bound_manually(axis_name: str) -> bool:
-    """Whether ``axis_name`` is already bound as a manual axis at trace
-    time on a 0.4.x jax (always False on current jax, where nested
-    shard_map resolves through the abstract-mesh context instead).  Used
-    by callers that would nest a shard_map over an axis the 0.4.x
-    full-manual fallback has already manualized — there the body can run
-    directly on the local shard."""
-    if hasattr(jax, "shard_map"):
-        return False
-    from jax._src import core as _core
-
-    try:
-        _core.axis_frame(axis_name)
-        return True
-    except NameError:
-        return False
+    jit."""
+    return jax.memory.Space.Host, jax.memory.Space.Device
 
 
 def axis_size(axis_name) -> int:
     """Static size of a bound mesh axis (or product over a sequence of
-    axes) inside shard_map — ``lax.axis_size`` on current jax,
-    ``core.axis_frame`` (which returns the size) on 0.4.x."""
+    axes) inside shard_map."""
     names = ((axis_name,) if isinstance(axis_name, str) else tuple(axis_name))
-    if hasattr(jax.lax, "axis_size"):
-        n = 1
-        for name in names:
-            n *= int(jax.lax.axis_size(name))
-        return n
-    from jax._src import core as _core
-
     n = 1
     for name in names:
-        n *= int(_core.axis_frame(name))
+        n *= int(jax.lax.axis_size(name))
     return n
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """``jax.shard_map`` with the new-API signature on every jax.
-
-    ``axis_names``: the axes the body is *manual* over (None = all mesh
-    axes).  On 0.4.x this maps to the complementary ``auto`` frozenset and
-    ``check_vma`` to ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # 0.4.x partial-manual (the `auto` frozenset) miscompiles the patterns
-    # this repo needs (axis_index lowers to an unpartitionable PartitionId;
-    # scan+ppermute trips a manual-subgroup check in the SPMD partitioner),
-    # so fall back to FULL manual: axes the caller left automatic are
-    # simply unmentioned in the specs (= replicated into each shard), which
-    # is semantically identical and only costs a reshard at the boundary.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
-class _EmptyMesh:
-    """Stand-in for an empty abstract mesh on jax versions without
-    mesh contexts: ``.empty`` is the only attribute call sites read."""
-
-    empty = True
+    """``jax.shard_map``.  ``axis_names``: the axes the body is *manual*
+    over (None = all mesh axes)."""
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+              check_vma=check_vma)
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)
 
 
 def get_abstract_mesh():
     """Current abstract mesh context (``.empty`` when not under one)."""
-    try:
-        return jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        return _EmptyMesh()
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (named ``TPUCompilerParams`` on 0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-
-def set_num_cpu_devices(n: int) -> None:
-    """``jax.config.update("jax_num_cpu_devices", n)`` where the option
-    exists (jax >= 0.5); on 0.4.x the option is absent and the caller's
-    ``--xla_force_host_platform_device_count`` XLA_FLAGS entry (read at
-    CPU-client creation) is the only mechanism — a silent no-op here."""
-    try:
-        jax.config.update("jax_num_cpu_devices", max(int(n), 1))
-    except AttributeError:
-        pass
+    return jax.sharding.get_abstract_mesh()
 
 
 def manual_axis_names():
     """Mesh axes currently bound MANUALLY (i.e. we are inside a shard_map
-    body over them).  On 0.4.x the compat ``shard_map`` above falls back
-    to full-manual, where a ``with_sharding_constraint`` naming any bound
-    axis is a hard error — layout-hint call sites consult this set and
-    skip the hint instead (inside a manual region per-shard layouts are
-    explicit, so the hint is meaningless there anyway).  Returns the
-    empty set when the introspection API is absent (newer jax: partial-
-    manual makes the constraint legal, so applying it stays correct)."""
-    try:
-        from jax._src import core as _core
+    body over them).  A ``with_sharding_constraint`` naming one of them
+    is an error, and inside a manual region per-shard layouts are
+    explicit, so layout-hint call sites consult this set and skip the
+    hint."""
+    return set(get_abstract_mesh().manual_axes)
 
-        return set(_core.get_axis_env().axis_sizes)
-    except Exception:
-        return set()
+
+def all_axes_manual() -> bool:
+    """Whether every axis of the current mesh context is manual (or there
+    is no mesh context).  Mosaic kernels cannot be partitioned
+    automatically, so this is the only place the chip's compiler takes
+    one in a program that spans several devices."""
+    ctx = get_abstract_mesh()
+    return ctx.empty or set(ctx.manual_axes) == set(ctx.axis_names)
+
+
+def with_unit_axes(mesh, axis_names) -> set:
+    """``axis_names`` plus every size-1 axis of ``mesh`` that is not
+    manual yet, for a ``shard_map`` that holds a Mosaic kernel: going
+    manual over a size-1 axis changes nothing, and it is what makes
+    :func:`all_axes_manual` true inside when no other axis is sharded."""
+    outer = set(getattr(mesh, "manual_axes", ()))
+    return set(axis_names) | {a for a in mesh.axis_names
+                              if mesh.shape[a] == 1 and a not in outer}
+
+
+def tpu_compiler_params(**kwargs):
+    """``pltpu.CompilerParams``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(**kwargs)

@@ -18,6 +18,7 @@ import numpy as np
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import get_model_config
+from deepspeed_tpu.utils.platform import setup_compile_cache
 
 
 def synthetic_batches(vocab, rows, seq, steps, seed=0):
@@ -38,6 +39,7 @@ def main(argv=None) -> int:
     ap.add_argument("--local_rank", type=int, default=-1)  # launcher parity
     args = ap.parse_args(argv)
 
+    setup_compile_cache()
     model = get_model_config(args.model)
     config = args.config or {
         "train_micro_batch_size_per_gpu": 4,

@@ -113,7 +113,7 @@ def test_attach_chaos_wires_fleet_against_one_origin():
 
 def test_chaos_error_is_not_a_typed_serving_outcome():
     # a ChaosError must ride the "unexpected crash" paths, not the typed
-    # request-outcome taxonomy
+    # request-outcome classes
     assert issubclass(ChaosError, RuntimeError)
     assert not issubclass(ChaosError, ServingError)
 

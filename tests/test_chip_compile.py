@@ -1,0 +1,162 @@
+"""The main paths' Pallas kernels, compiled at real widths by the TPU
+v5e's own compiler — for a chip that is described, not attached.
+
+Interpret-mode tests cannot see what Mosaic refuses (a slice off the
+128-lane tiling, a block shape the lowering does not take); these compiles
+can, at no chip time.  Nothing runs, so they say nothing about results or
+speed — ``chip_smoke.py`` does that on the chip.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and under
+pytest-xdist every worker imports every test file.
+"""
+
+import functools
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+flash_mha_mod = importlib.import_module("deepspeed_tpu.ops.pallas.flash_mha")
+from deepspeed_tpu.ops.pallas import (fused_optimizer, gather_matmul,  # noqa: E402
+                                      paged_attention, quantize)
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one — keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``chip(shape, dtype)`` → an abstract array on one described v5e."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def _compile(fn, *args):
+    """Compile for the described chip; the text must hold a Mosaic kernel.
+    conftest.py asks for "highest" matmul precision (CPU numerics), which
+    no program on the chip runs under — compile as the chip would."""
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+# [B, Hq, Hkv, S, D, window] — flash_mha takes [B, H, S, D]
+FLASH_SHAPES = {
+    "gpt2-350m": (8, 16, 16, 1024, 64, None),
+    "mistral-7b-2k": (2, 32, 8, 2048, 128, 4096),
+    "mistral-7b-8k": (1, 32, 8, 8192, 128, 4096),
+}
+
+
+@pytest.mark.parametrize("name", list(FLASH_SHAPES))
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_mha(chip, name, grad):
+    b, hq, hkv, s, d, window = FLASH_SHAPES[name]
+    assert flash_mha_mod.supports(s, d)
+
+    def fwd(q, k, v):
+        return flash_mha_mod.flash_mha(q, k, v, True, None, window)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(F32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    _compile(fn, chip((b, hq, s, d), BF16), chip((b, hkv, s, d), BF16),
+             chip((b, hkv, s, d), BF16))
+
+
+# the serve shape: 32 decoding tokens, mistral-7b heads, 128 pages of 16
+_T, _NH, _NKV, _BS, _NB, _ROWS = 32, 32, 8, 16, 128, 1024 * 16
+
+
+def _paged_args(chip, d, kv_dtype):
+    return (chip((_T, _NH, d), BF16), chip((_NKV, _ROWS, d), kv_dtype),
+            chip((_NKV, _ROWS, d), kv_dtype), chip((_T, _NB), I32),
+            chip((_T,), I32), chip((_T,), I32))
+
+
+def test_paged_decode_serve_shape(chip):
+    assert paged_attention.supports(_BS, 128)
+    fn = functools.partial(paged_attention.paged_decode_attention,
+                           block_size=_BS, sm_scale=128 ** -0.5, window=4096)
+    _compile(fn, *_paged_args(chip, 128, BF16))
+
+
+def test_paged_decode_int8_kv(chip):
+    def fn(q, k, v, pages, pos, clen, ks, vs):
+        return paged_attention.paged_decode_attention(
+            q, k, v, pages, pos, clen, block_size=_BS, sm_scale=128 ** -0.5,
+            window=4096, k_scales=ks, v_scales=vs)
+
+    _compile(fn, *_paged_args(chip, 128, I8), chip((_NKV, _ROWS), F32),
+             chip((_NKV, _ROWS), F32))
+
+
+def test_paged_decode_head_dim_64_is_refused(chip):
+    """Every GPT-2 / OPT / Bloom / GPT-Neo width: Mosaic refuses the
+    64-wide page slice, and ``supports()`` says so first, so the module
+    registry names ``paged_xla`` for them."""
+    assert not paged_attention.supports(_BS, 64)
+    fn = functools.partial(paged_attention.paged_decode_attention,
+                           block_size=_BS, sm_scale=64 ** -0.5)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(fn, *_paged_args(chip, 64, BF16))
+
+
+@pytest.mark.parametrize("shape", [(50304, 1024), (1024, 4096)],
+                         ids=["embedding", "mlp"])
+def test_fused_adamw_leaf(chip, shape):
+    assert fused_optimizer.supports(shape)
+    leaf = chip(shape, F32)
+    _compile(fused_optimizer.fused_adamw_leaf, leaf, leaf, leaf, leaf,
+             chip((), F32), chip((), I32))
+
+
+def test_quantize(chip):
+    assert quantize.supports((8192, 8192), 256, True, 8)
+    _compile(quantize.quantize, chip((8192, 8192), BF16))
+
+
+def test_ring_flash_carry_block(chip):
+    """One ring-attention hop at mistral-7b heads, 2048-token shard."""
+    b, hq, hkv, d = 1, 32, 8, 128
+    s = flash_mha_mod.ring_carry_pad(2048)
+    stat = chip((b, hq, s, 128), F32)
+    _compile(flash_mha_mod.flash_carry_block,
+             chip((b, hq, s, d), BF16), chip((b, hkv, s, d), BF16),
+             chip((b, hkv, s, d), BF16), stat, stat, chip((b, hq, s, d), F32),
+             chip((), I32), chip((), I32))
+
+
+def test_pallas_matmul_kernel(chip, monkeypatch):
+    """The kernel behind the fused gather-matmul; its gate reads the live
+    backend (the CPU, here), so the test opens it."""
+    monkeypatch.setattr(gather_matmul, "on_tpu", lambda: True)
+    _compile(gather_matmul.pallas_matmul, chip((8192, 1024), BF16),
+             chip((1024, 4096), BF16))

@@ -156,7 +156,9 @@ def test_ring_quantized_fused_matches_xla_exactly(seq_topo, rng):
         l_p, g_p = _ring_loss_grads(seq_topo, q, k, v, "int8")
     finally:
         _fm.INTERPRET = old
-    assert abs(l_x - l_p) < 1e-5
+    # the loss is an fp32 sum of ~5: hold it to 1e-5 RELATIVE (the order
+    # of that sum follows how the shard_map is lowered)
+    assert abs(l_x - l_p) < 1e-5 * max(1.0, abs(l_x))
     for a, b in zip(g_p, g_x):
         assert np.abs(a - b).max() < 1e-4, np.abs(a - b).max()
 

@@ -158,7 +158,7 @@ def test_qwen_v1_parity():
     """Qwen v1 is a remote-code model (no transformers class), but its
     math is Qwen2's (rmsnorm + biased-qkv + swiglu, no GQA) in a
     different state-dict layout: fused transformer.h.*.attn.c_attn,
-    mlp.w1 (up) / w2 (gate) / c_proj, intermediate_size doubled.  Relay a
+    mlp.w1 (up) / w2 (gate) / c_proj, intermediate_size doubled.  Re-lay a
     tiny Qwen2 checkpoint into the v1 layout and require logits parity
     against the torch forward — this pins the converter's fused splits
     and gate/up mapping against real numerics."""

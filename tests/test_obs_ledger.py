@@ -4,9 +4,10 @@ sentinel").
 
 Covers the tier-1 acceptance set:
 
-* backfill — every committed BENCH_r*/BENCH_MEASURED_r*.json parses
-  into rollups, the trajectory spans r01→r18, and the r04-carried rows
-  come out ``stale`` with a runnable requeue command attached;
+* backfill — every BENCH_r*/BENCH_MEASURED_r*.json of a synthetic
+  history (tests/conftest.py ``bench_history``) parses into rollups, the
+  trajectory spans r01→r18, and the r04-carried rows come out ``stale``
+  with a runnable requeue command attached;
 * planted regressions — an MFU cliff, a TTFT-p95 regression, a goodput
   gap, and an SLO-burn spike are each detected with the right verdict /
   anomaly kind, and the planted-regression gate exits 1;
@@ -44,22 +45,24 @@ def _train_records(n, wall_s=0.1, mfu=0.5, goodput=1.0):
 # ----------------------------------------------------------------------
 # backfill: the committed history parses, end to end
 # ----------------------------------------------------------------------
-def test_backfill_parses_all_committed_bench_files():
-    rollups = ledger.load_bench_history(REPO)
-    assert len(rollups) >= 70
+def test_backfill_parses_all_committed_bench_files(bench_history):
+    rollups = ledger.load_bench_history(bench_history)
+    # 3 primaries + 5 measured (r04) + 4 rounds x 5 carried rows
+    assert len(rollups) == 28
     rounds = {r["round"] for r in rollups if r["round"] is not None}
-    assert min(rounds) == 1 and max(rounds) >= 18
+    assert min(rounds) == 1 and max(rounds) == 18
     rows = {r["row"] for r in rollups}
     assert {"gpt2_350m", "llama8b_class_zero3", "longseq_flash",
             "peak_params", "v2_decode"} <= rows
+    assert [r["error"] for r in rollups if r["round"] == 3] != [None]
     for r in rollups:
         assert tuple(sorted(r)) == ledger.ROLLUP_KEYS
         assert tuple(sorted(r["train"])) == ledger.ROLLUP_TRAIN_KEYS
         assert tuple(sorted(r["serve"])) == ledger.ROLLUP_SERVE_KEYS
 
 
-def test_backfill_flags_carried_rows_stale_with_requeue_cmds():
-    rollups = ledger.load_bench_history(REPO)
+def test_backfill_flags_carried_rows_stale_with_requeue_cmds(bench_history):
+    rollups = ledger.load_bench_history(bench_history)
     stale = {r["row"] for r in rollups if r["stale"]}
     assert stale == {"gpt2_350m", "llama8b_class_zero3", "longseq_flash",
                      "peak_params", "v2_decode"}
@@ -68,16 +71,18 @@ def test_backfill_flags_carried_rows_stale_with_requeue_cmds():
         if r["round"] is not None and r["round"] <= ledger.LAST_MEASURED_ROUND:
             assert not r["stale"]
     requeue = ledger.attach_requeue_cmds(
-        rollups, ledger.collect_queued_cmds(REPO))
+        rollups, ledger.collect_queued_cmds(bench_history))
     assert set(requeue) == stale
     for row, cmd in requeue.items():
         assert f"--row {row}" in cmd or "--peak-entry" in cmd
 
 
-def test_queued_cmd_row_names_are_clean():
+def test_queued_cmd_row_names_are_clean(bench_history):
     # the for-loop wrapped queue entries must not leak shell punctuation
     # into row names ("peak_params;" would silently duplicate the key)
-    for name in ledger.collect_queued_cmds(REPO):
+    queued = ledger.collect_queued_cmds(bench_history)
+    assert "peak_params" in queued
+    for name in queued:
         assert name == name.strip(";&|")
     loop = ("for CB in 1 2; do DSTPU_CHUNK_BYTES=$CB "
             "python bench.py --row peak_params; done")
@@ -263,12 +268,13 @@ def test_manifest_roundtrip_rollup_and_run_id(tmp_path):
 
 
 def test_obs_report_gate_clean_on_smoke_run_vs_committed_baseline(
-        tmp_path, capsys):
+        tmp_path, capsys, monkeypatch, bench_history):
     """The tier-1 gate: a fresh in-session smoke run diffed against the
     committed tools/obs_baseline.json must be clean, and the trend must
-    span the full committed history r01→r18."""
+    span the full bench history r01→r18."""
     _write_run(tmp_path, "gpt2_350m")
     obs = _load_tool("obs_report")
+    monkeypatch.setattr(obs, "REPO", bench_history)
     rc = obs.main(["--scan", str(tmp_path), "--gate"])
     out = capsys.readouterr().out
     assert rc == 0

@@ -122,14 +122,17 @@ def test_mid_sequence_positions():
     assert err < 0.05, err
 
 
-def test_dispatch_uses_pallas_kernel(monkeypatch):
+@pytest.mark.parametrize("d,n_calls", [(128, 1), (64, 0)])
+def test_dispatch_uses_pallas_kernel(monkeypatch, d, n_calls):
     """inference v2's _paged_attention routes through the repo kernel when
-    block tables are available on TPU, and the kernel output matches the
-    XLA gather path it replaces."""
+    block tables are available on TPU and the kernel's ``supports()``
+    takes the shape (head_dim 128), and the kernel output matches the XLA
+    gather path it replaces; head_dim 64 — which the chip's compiler
+    refuses — resolves to the XLA path by name."""
     from deepspeed_tpu.inference.v2 import model as m2
     from deepspeed_tpu.models.transformer import TransformerConfig
 
-    monkeypatch.setattr(m2, "_on_tpu", lambda: True)
+    monkeypatch.setattr(m2, "on_tpu", lambda: True)
     calls = {"n": 0}
     real = pm.paged_decode_attention
 
@@ -140,11 +143,13 @@ def test_dispatch_uses_pallas_kernel(monkeypatch):
     # model.py binds the kernel at import — patch the consumer's name
     monkeypatch.setattr(m2, "paged_decode_attention", counting)
 
-    t, nh, nkv, d, bs, nb = 3, 8, 2, 64, 16, 2
+    t, nh, nkv, bs, nb = 3, 8, 2, 16, 2
     q, kp, vp, tbl, pos, clen = _make_case(
         jax.random.PRNGKey(3), t, nh, nkv, d, 8, nb, bs)
     cfg = TransformerConfig(num_heads=nh, num_kv_heads=nkv,
                             hidden_size=nh * d, use_rope=True, arch="llama")
+    assert m2.attention_impl_name(cfg, bs) == (
+        "paged_pallas" if n_calls else "paged_xla")
     # gather_idx for the XLA path: flat page-row index of each ctx position
     c_idx = jnp.arange(nb * bs)
     gather_idx = tbl[:, c_idx // bs] * bs + (c_idx % bs)[None, :]
@@ -152,7 +157,7 @@ def test_dispatch_uses_pallas_kernel(monkeypatch):
     out = m2._paged_attention(q, kp, vp, gather_idx, pos, clen, cfg,
                               block_tables=tbl, token_slot=token_slot,
                               block_size=bs)
-    assert calls["n"] == 1, "Pallas paged kernel was not dispatched"
+    assert calls["n"] == n_calls, "wrong paged attention dispatch"
     ref = m2._paged_attention_xla(q, kp, vp, gather_idx, pos, clen, cfg)
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                 - ref.astype(jnp.float32))))

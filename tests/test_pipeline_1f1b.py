@@ -75,7 +75,7 @@ def _loss_and_grads(schedule, n_micro=8, pp=2):
 
 @pytest.mark.parametrize("pp", [2, 4])
 def test_1f1b_matches_gpipe_grads(pp):
-    """pp=4 exercises true middle stages: multi-hop cotangent relay,
+    """pp=4 exercises true middle stages: multi-hop cotangent hand-off,
     left/right clip gating, and arr slot reuse over a >2 ring."""
     l1, g1 = _loss_and_grads("1f1b", pp=pp)
     l2, g2 = _loss_and_grads("gpipe", pp=pp)

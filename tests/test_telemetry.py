@@ -547,19 +547,29 @@ def test_telemetry_check_lint_passes():
     assert mod.run_all() == []
 
 
-def test_bench_backlog_queue_is_runnable():
+def test_bench_backlog_queue_is_runnable(monkeypatch, bench_history):
     """Every queued measurement command in BENCH_MEASURED_r07+.json must
     still parse against the current bench.py flags, row names, tool
     scripts, and model registry — a renamed row or retired flag rots the
-    queue silently otherwise (tools/bench_backlog.py)."""
+    queue silently otherwise (tools/bench_backlog.py).  The queue is the
+    synthetic history of tests/conftest.py, validated against the real
+    bench.py and tools/."""
     import importlib.util
+    import json
 
     path = os.path.join(os.path.dirname(__file__), "..", "tools",
                         "bench_backlog.py")
     spec = importlib.util.spec_from_file_location("bench_backlog", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    monkeypatch.setattr(mod, "REPO", bench_history)
     assert mod.run_all() == []
+    # and a rotted entry is caught
+    with open(os.path.join(bench_history, "BENCH_MEASURED_r18.json"),
+              "w") as f:
+        json.dump({"queued_measurements_r18": [
+            {"what": "retired row", "cmd": "python bench.py --row nope"}]}, f)
+    assert any("unknown bench row" in e for e in mod.run_all())
 
 
 # ----------------------------------------------------------------------

@@ -22,9 +22,14 @@ def test_auto_resolution_by_context():
                    on_tpu=False, has_tables=False)
     assert impl is v2_model._attn_impl_xla
     # TPU-shaped context with servable geometry → pallas
-    impl = resolve("attention", "auto", block_size=16, head_dim=64,
+    impl = resolve("attention", "auto", block_size=16, head_dim=128,
                    on_tpu=True, has_tables=True)
     assert impl is v2_model._attn_impl_pallas
+    # head_dim 64: the chip's compiler refuses the page slice, so
+    # supports() does too and the registry names the XLA path
+    impl = resolve("attention", "auto", block_size=16, head_dim=64,
+                   on_tpu=True, has_tables=True)
+    assert impl is v2_model._attn_impl_xla
 
 
 def test_explicit_name_and_errors():

@@ -25,12 +25,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main():
-    import jax
-
     from deepspeed_tpu.autotuning.autotuner import Autotuner
     from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.utils.platform import device_facts_from_child
 
-    assert jax.default_backend() != "cpu", "needs the TPU backend"
+    # this process starts the isolated trials, which need the chip, so
+    # it never initialises a JAX backend itself
+    facts = device_facts_from_child()
+    assert facts["platform"] == "tpu", "needs the TPU backend"
     model = get_model_config("gpt2-125m", max_seq_len=512)
     base = {
         "gradient_accumulation_steps": 1,
@@ -45,7 +47,7 @@ def main():
     best, results = tuner.tune(patience=100)
 
     rows = sorted((r for r in results), key=lambda r: -r.throughput)
-    report = {"device": str(jax.devices()[0]),
+    report = {"device": facts["device_kind"],
               "space": "grid micro_batch x zero_stage, gpt2-125m seq512",
               "results": [
                   {"cand": r.config,

@@ -29,6 +29,9 @@ import jax.numpy as jnp
 def main():
     from deepspeed_tpu.models import get_model_config, init_params
     from deepspeed_tpu.models import transformer as tf
+    from deepspeed_tpu.utils.platform import setup_compile_cache
+
+    setup_compile_cache()
 
     seq = 1024
     rng = np.random.default_rng(0)

@@ -64,6 +64,9 @@ def run_variant(ov: dict) -> float:
 
 
 def main():
+    from deepspeed_tpu.utils.platform import setup_compile_cache
+
+    setup_compile_cache()
     for arg in sys.argv[1:]:
         ov = json.loads(arg)
         try:
