@@ -112,6 +112,46 @@ class RaggedInferenceEngineConfig:
                     f"(available: {', '.join(available(kind)) or 'none'})")
 
 
+def _named(name: str, fn, **static):
+    """``partial(fn, **static)`` under a ``__name__``: what ``jax.jit``
+    names the compiled program by."""
+    p = partial(fn, **static)
+    p.__name__ = name
+    return p
+
+
+def step_counts(items: Sequence[tuple], window: Optional[int] = None
+                ) -> Dict[str, int]:
+    """What one ragged step is asked to do, from its ``(cached, n_new)``
+    work items (tokens already in the cache, tokens this step adds); the
+    arguments of the ``v2.schedule`` span.  A decode item adds one token
+    to a context that is already there; anything else is a prefill
+    chunk.  ``kv_rows``: each sequence's context after the step, counted
+    once, cut to ``window``: the keys (and as many values) the step's
+    attention has to read.  ``qk_pairs``: live (query, key) pairs of the
+    new tokens under the causal mask, the token at 0-based position p
+    seeing ``min(p + 1, window)`` keys."""
+    prefill = decode = kv_rows = pairs = 0
+    for cached, n in items:
+        if n == 1 and cached > 0:
+            decode += 1
+        else:
+            prefill += n
+        end = cached + n
+        if window and end > window:
+            kv_rows += window
+            # positions below the window see p + 1 keys, the rest window
+            full = max(cached, window)
+            pairs += (full * (full + 1) - cached * (cached + 1)) // 2 \
+                + (end - full) * window
+        else:
+            kv_rows += end
+            pairs += (end * (end + 1) - cached * (cached + 1)) // 2
+    return {"seqs": len(items), "tokens": prefill + decode,
+            "prefill_tokens": prefill, "decode_tokens": decode,
+            "kv_rows": kv_rows, "qk_pairs": pairs}
+
+
 def _kv_scatter(cache_k, cache_v, rows, k, v):
     """Write handed-off KV page rows into the paged caches (both cache
     layouts: plain array [L, nkv, P, d], or the int8 quantized dict
@@ -190,6 +230,10 @@ class InferenceEngineV2:
         # under the serve loop's trace id instead of one-off orphan ids
         self.tracer = None
         self.trace_id = ""
+        self._step_span = None      # the open v2.ragged_step, if any
+        # (program, buckets, static arguments) this engine has dispatched:
+        # the first dispatch of a key is the one that compiles
+        self._dispatched: set = set()
         # fault injection (resilience/chaos.py ChaosInjector): attached by
         # attach_chaos; None keeps step() at one attribute check per call
         self.chaos = None
@@ -219,32 +263,38 @@ class InferenceEngineV2:
             self.cache_k = zeros(kv_shape, dtype=kv_dt)
             self.cache_v = zeros(kv_shape, dtype=kv_dt)
 
+        # every jitted step carries a function name of its own, so the
+        # profiler's ``XLA Modules`` line reads ``jit_ragged_step(...)``
+        # and not ``jit__unknown(...)`` (a partial has no ``__name__``)
         self._step = jax.jit(
-            partial(ragged_forward, cfg=mc, block_size=self.cfg.block_size),
+            _named("ragged_step", ragged_forward, cfg=mc,
+                   block_size=self.cfg.block_size),
             donate_argnums=(1, 2))
         # sampled variant: mixed prefill/decode steps fetch [max_seqs] int32
         # tokens instead of full [max_seqs, V] logits (ref Weak: v2 prefill
         # loop host-bound — sampling now happens on device for BOTH phases)
         self._step_sampled = jax.jit(
-            partial(ragged_forward_sampled, cfg=mc,
-                    block_size=self.cfg.block_size),
+            _named("ragged_step_sampled", ragged_forward_sampled, cfg=mc,
+                   block_size=self.cfg.block_size),
             static_argnames=("greedy", "top_k"),
             donate_argnums=(1, 2))
         self._decode_loop = jax.jit(
-            partial(ragged_decode_loop, cfg=mc, block_size=self.cfg.block_size),
+            _named("ragged_decode_loop", ragged_decode_loop, cfg=mc,
+                   block_size=self.cfg.block_size),
             static_argnames=("n_steps", "greedy", "top_k"),
             donate_argnums=(1, 2))
         # speculative-decoding verify-k: same argument tuple as _step, but
         # the greedy argmax comes back for EVERY token row ([T] int32), so
         # one ragged dispatch scores a whole batch of draft proposals
         self._verify = jax.jit(
-            partial(ragged_forward_verify, cfg=mc,
-                    block_size=self.cfg.block_size),
+            _named("ragged_verify", ragged_forward_verify, cfg=mc,
+                   block_size=self.cfg.block_size),
             donate_argnums=(1, 2))
         # disaggregated-serving KV import: scatter handed-off page rows
         # into the donated caches in place (rows padded to a pow2 bucket
         # of block rows; padding points at the reserved garbage block 0)
-        self._kv_write = jax.jit(_kv_scatter, donate_argnums=(0, 1))
+        self._kv_write = jax.jit(_named("kv_write", _kv_scatter),
+                                 donate_argnums=(0, 1))
         self.attention_impl = attention_impl_name(mc, self.cfg.block_size)
         log_dist(f"InferenceEngineV2: budget={self.cfg.max_ragged_batch_size} "
                  f"blocks={self.cfg.num_blocks}×{self.cfg.block_size} "
@@ -273,13 +323,25 @@ class InferenceEngineV2:
             seen.add(uid)
         for uid, toks in zip(batch_uids, batch_tokens):
             self.admit(uid, toks)
+        # host spans of the step (children of v2.ragged_step under the
+        # serve loop); ``tr`` is None unless spans are being recorded
+        tr = self.tracer
+        if tr is not None and not tr.enabled:
+            tr = None
+        parent = self._step_span
+        sp = (tr.span("v2.schedule", self.trace_id, parent)
+              if tr is not None else None)
         schedule = self.scheduler.next_schedule()
         if not schedule:
+            if sp is not None:
+                sp.end(seqs=0, tokens=0)
             return None, None
         try:
             rb = build_ragged_batch(schedule, self.state_manager,
                                     self.scheduler.token_budget)
         except KVCacheExhausted:
+            if sp is not None:
+                sp.end(kv_exhausted=True)
             # Nothing ran: no num_cached advanced, no KV written.  But
             # next_schedule already promoted prompts whose FINAL chunk was
             # scheduled into the decode set — roll mid-prefill ones back to
@@ -309,24 +371,46 @@ class InferenceEngineV2:
         while nb_bucket < nb_real:
             nb_bucket *= 2
         nb_bucket = min(nb_bucket, self.state_manager.max_blocks_per_seq)
+        if sp is not None:
+            # build_ragged_batch advanced num_cached past the new tokens
+            sp.end(**step_counts(
+                [(seq.num_cached - n, n) for seq, n in schedule],
+                self.model_config.sliding_window))
+        host = (rb.token_ids[:t_bucket], rb.token_slot[:t_bucket],
+                rb.token_pos[:t_bucket], rb.token_dest[:t_bucket],
+                rb.block_tables[:, :nb_bucket], rb.ctx_lens, rb.logits_idx)
+        sp = (tr.span("v2.h2d", self.trace_id, parent)
+              if tr is not None else None)
         args = (self.params, self.cache_k, self.cache_v,
-                self._put(rb.token_ids[:t_bucket]),
-                self._put(rb.token_slot[:t_bucket]),
-                self._put(rb.token_pos[:t_bucket]),
-                self._put(rb.token_dest[:t_bucket]),
-                self._put(rb.block_tables[:, :nb_bucket]),
-                self._put(rb.ctx_lens),
-                self._put(rb.logits_idx))
+                *[self._put(a) for a in host])
+        if sp is not None:
+            sp.end(arrays=len(host), bytes=sum(a.nbytes for a in host))
         if sample is None:
-            logits, self.cache_k, self.cache_v = self._step(*args)
-            return rb, logits
-        toks, self.cache_k, self.cache_v = self._step_sampled(
-            *args, key=sample["key"],
-            temperature=jnp.float32(max(sample["temperature"], 1e-6)),
-            greedy=(sample["temperature"] <= 0),
-            top_k=sample.get("top_k", 0),
-            top_p=sample.get("top_p"))
-        return rb, toks
+            key = ("ragged_step", t_bucket, nb_bucket)
+        else:
+            greedy = sample["temperature"] <= 0
+            key = ("ragged_step_sampled", t_bucket, nb_bucket, greedy,
+                   sample.get("top_k", 0), sample.get("top_p") is None)
+        new_shape = key not in self._dispatched
+        if new_shape:
+            self._dispatched.add(key)
+        sp = (tr.span("v2.dispatch", self.trace_id, parent)
+              if tr is not None else None)
+        try:
+            if sample is None:
+                out, self.cache_k, self.cache_v = self._step(*args)
+            else:
+                out, self.cache_k, self.cache_v = self._step_sampled(
+                    *args, key=sample["key"],
+                    temperature=jnp.float32(max(sample["temperature"],
+                                                1e-6)),
+                    greedy=greedy, top_k=sample.get("top_k", 0),
+                    top_p=sample.get("top_p"))
+        finally:
+            if sp is not None:
+                sp.end(t_bucket=t_bucket, nb_bucket=nb_bucket,
+                       new_shape=new_shape)
+        return rb, out
 
     def audit_step_args(self, phase: str = "decode"):
         """``(jitted ragged step, example args)`` for the static graph
@@ -429,11 +513,13 @@ class InferenceEngineV2:
             if not self.trace_id:   # standalone use: one stable id
                 self.trace_id = tr.new_trace_id()
             sp = tr.span("v2.ragged_step", self.trace_id)
+        self._step_span = sp
         try:
             return self._step_impl(temperature, key, top_k, top_p,
                                    return_logits)
         finally:
             if sp is not None:
+                self._step_span = None
                 sp.end()
 
     def _step_impl(self, temperature: float, key: Optional[Any],
@@ -443,7 +529,7 @@ class InferenceEngineV2:
             rb, logits = self._ragged_step([], [])
             if rb is None:
                 return {}
-            logits_np = np.asarray(logits)
+            logits_np = self._fetch(logits)
             return {uid: logits_np[slot]
                     for slot, uid in rb.uids_by_slot.items()}
         top_k, top_p = check_sampling_params(top_k, top_p,
@@ -457,9 +543,18 @@ class InferenceEngineV2:
                             "top_k": top_k, "top_p": top_p})
         if rb is None:
             return {}
-        toks_np = np.asarray(toks)
+        toks_np = self._fetch(toks)
         return {uid: int(toks_np[slot])
                 for slot, uid in rb.uids_by_slot.items()}
+
+    def _fetch(self, out) -> np.ndarray:
+        """The step's result on the host: the wait for the device and the
+        copy back (span ``v2.fetch``)."""
+        sp = self._step_span
+        if sp is None:
+            return np.asarray(out)
+        with self.tracer.span("v2.fetch", self.trace_id, sp):
+            return np.asarray(out)
 
     def extend(self, uid: int, token: int) -> None:
         """Append a sampled token so the next step decodes it."""

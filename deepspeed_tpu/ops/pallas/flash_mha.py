@@ -615,6 +615,7 @@ def flash_carry_block(q, k, v, m, l, acc, q_off, k_off, *, q_stride=1,
                           quantized=quantized),
         grid=grid,
         interpret=INTERPRET,
+        name="flash_carry",
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             q_spec, kv_spec, kv_spec, *scale_specs,
@@ -900,6 +901,7 @@ def flash_ring_dq_block(q, k, v, do, lse, delta, dq, q_off, k_off, *,
                           quantized=quantized),
         grid=grid,
         interpret=INTERPRET,
+        name="flash_ring_dq",
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             q_spec, kv_spec, kv_spec, *scale_specs,
@@ -959,6 +961,7 @@ def flash_ring_dkv_block(q, k, v, do, lse, delta, dk, dv, q_off, k_off, *,
                           s_real=s_real, group=group, quantized=quantized),
         grid=grid,
         interpret=INTERPRET,
+        name="flash_ring_dkv",
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             grp_spec, kv_spec, kv_spec, *scale_specs, grp_spec,
@@ -1012,6 +1015,7 @@ def _fwd(q, k, v, causal, sm_scale, need_lse=True, window=None):
                           bq=bq, s_pad=s_pad, s_real=s_real, window=window),
         grid=grid,
         interpret=INTERPRET,
+        name="flash_fwd",
         in_specs=[q_blk, kv_spec, kv_spec],
         out_specs=[q_blk] + ([lse_blk] if need_lse else []),
         out_shape=[jax.ShapeDtypeStruct((b, hq, s_pad, d), q.dtype)]
@@ -1064,6 +1068,7 @@ def _fwd_blocked(q, k, v, causal, sm_scale, need_lse=True, window=None):
                           window=window),
         grid=grid,
         interpret=INTERPRET,
+        name="flash_fwd_blocked",
         in_specs=[
             q_blk,
             pl.BlockSpec((1, 1, bk, d), kv_idx),
@@ -1132,6 +1137,7 @@ def _bwd_blocked(q, k, v, o, lse, g, causal, sm_scale, window=None):
                           window=window),
         grid=(b, hq, s_pad // bq, s_pad // bk),
         interpret=INTERPRET,
+        name="flash_bwd_dq_blocked",
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, lane_spec, lane_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, s_pad, d), q.dtype),
@@ -1163,6 +1169,7 @@ def _bwd_blocked(q, k, v, o, lse, g, causal, sm_scale, window=None):
                           group=group, window=window),
         grid=(b, hkv, s_pad // bk, s_pad // bq),
         interpret=INTERPRET,
+        name="flash_bwd_dkv_blocked",
         in_specs=[grp_spec, kv_own_spec, kv_own_spec, grp_spec,
                   grp_lane_spec, grp_lane_spec],
         out_specs=[kv_own_spec, kv_own_spec],
@@ -1215,6 +1222,7 @@ def _bwd_impl(q, k, v, o, lse, g, causal, sm_scale, window=None):
                           bq=bq, s_pad=s_pad, s_real=s_real, window=window),
         grid=(b, hq, s_pad // bq),
         interpret=INTERPRET,
+        name="flash_bwd_dq",
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq: (ib, ih, iq, 0)),
             kv_spec,
@@ -1239,6 +1247,7 @@ def _bwd_impl(q, k, v, o, lse, g, causal, sm_scale, window=None):
                           window=window),
         grid=(b, hkv, s_pad // bk),
         interpret=INTERPRET,
+        name="flash_bwd_dkv",
         in_specs=[
             grp_spec,
             pl.BlockSpec((1, 1, bk, d), lambda ib, ihkv, ik: (ib, ihkv, ik, 0)),

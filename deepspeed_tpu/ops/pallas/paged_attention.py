@@ -270,6 +270,7 @@ def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, nkv, group, d), q.dtype),
         interpret=INTERPRET,
+        name="paged_decode_q8" if quant else "paged_decode",
     )(pages.astype(jnp.int32), token_pos.astype(jnp.int32),
       token_ctx_len.astype(jnp.int32), q.reshape(t, nkv, group, d),
       *extra, k_pages, v_pages)

@@ -876,11 +876,24 @@ class DeepSpeedEngine:
             self._comms_baseline = cl.totals()
         # -- software spans + hang watchdog (telemetry/tracing, flight) --
         # one unconditional code path: without telemetry the NULL tracer
-        # answers every span call with the shared no-op singleton
+        # answers every span call with the shared no-op singleton.
+        # ``telemetry.tracing.enabled`` without ``telemetry.enabled``
+        # builds a tracer and no hub (the rule InferenceServer follows):
+        # spans alone, with no host sync and no StepRecord, so a traced
+        # step is the step that runs untraced
         from deepspeed_tpu.telemetry.tracing import NULL_TRACER
 
-        self._tracer = (self.telemetry.tracer if self.telemetry is not None
-                        else NULL_TRACER)
+        self._trace_path = ""
+        if self.telemetry is not None:
+            self._tracer = self.telemetry.tracer
+        elif cfg.telemetry.tracing.enabled:
+            from deepspeed_tpu.telemetry.flight import make_span_recorder
+
+            self._tracer, _ = make_span_recorder(
+                True, False, max_events=cfg.telemetry.tracing.max_events)
+            self._trace_path = cfg.telemetry.tracing.trace_path
+        else:
+            self._tracer = NULL_TRACER
         self._train_trace_id = (self._tracer.new_trace_id()
                                 if self._tracer.enabled else "")
         if self._super_opt is not None and hasattr(self._super_opt,
@@ -1786,6 +1799,13 @@ class DeepSpeedEngine:
             from deepspeed_tpu.utils.comms_logging import get_comms_logger
 
             get_comms_logger().enabled = self._comms_prev_enabled
+        elif self._trace_path:
+            # standalone tracer: nobody else will write the trace file
+            try:
+                self._tracer.export_chrome_trace(self._trace_path)
+            except OSError as e:
+                logger.warning(f"trace export failed: {e}")
+            self._trace_path = ""
         if self._swap_pool is not None:
             self._swap_pool.shutdown(wait=True)
             self._swap_pool = None
@@ -2556,6 +2576,13 @@ class DeepSpeedEngine:
 
     def train_batch_size(self) -> int:
         return self.train_batch_size_value
+
+    @property
+    def tracer(self):
+        """The ``Tracer`` this engine's ``train.*`` spans go to (the
+        hub's, a standalone one, or the shared disabled one), as
+        ``InferenceServer.tracer`` exposes the serve loop's."""
+        return self._tracer
 
     def gradient_accumulation_steps(self) -> int:
         return self.gradient_accumulation_steps_value

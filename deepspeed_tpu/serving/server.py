@@ -441,17 +441,27 @@ class InferenceServer:
                         RequestCancelled("server shutdown"))
                     return
                 now = time.monotonic()
+                # serve.admit_pass, serve.step and serve.deliver (or
+                # serve.idle_wait) tile one iteration of this loop
+                tr = self.tracer
+                sp = tr.span("serve.admit_pass", self._loop_trace_id)
+                n_before = self.metrics.admitted if tr.enabled else 0
                 self._sweep_queue(now)
                 self._sweep_active(now)
                 self._try_admit(now)
                 self._update_gauges()
+                if tr.enabled:
+                    sp.set(admitted=self.metrics.admitted - n_before)
+                sp.end()
                 if self.engine.scheduler.has_work:
                     self._step_once()
                 elif self._stop_requested and len(self.admission) == 0 \
                         and not self._active:
                     return
                 else:
-                    self.admission.wait_for_work(self.cfg.idle_wait_s)
+                    # the device is idle because no request is there
+                    with tr.span("serve.idle_wait", self._loop_trace_id):
+                        self.admission.wait_for_work(self.cfg.idle_wait_s)
         except BaseException as e:  # never die silently: fail the streams
             # error FIRST: stop() fail-fasts on this flag, and the
             # cleanup below may itself wedge on the broken engine
@@ -802,21 +812,32 @@ class InferenceServer:
             step_span.end(crashed=True)
             raise
         step_span.end()
-        self.metrics.record_step()
-        if not warm:
-            # straggler signal for the fleet supervisor: EMA of steady-
-            # state step wall time (the compile-paying first step would
-            # poison the average for the whole early window)
-            dt = time.monotonic() - step_t0
-            self.step_ema_s = (dt if self.step_ema_s == 0.0
-                               else 0.8 * self.step_ema_s + 0.2 * dt)
-        if (self.cfg.metrics_interval_steps and self.metrics.steps
-                % self.cfg.metrics_interval_steps == 0):
-            if self.monitor is not None:
-                self.metrics.write_to(self.monitor, self.metrics.steps)
-            if self.telemetry is not None:
-                self.telemetry.record_serving_step(self.metrics.steps,
-                                                   self.metrics.snapshot())
+        with tr.span("serve.deliver", self._loop_trace_id) as deliver_span:
+            self.metrics.record_step()
+            if not warm:
+                # straggler signal for the fleet supervisor: EMA of
+                # steady-state step wall time (the compile-paying first
+                # step would poison the average for the early window)
+                dt = time.monotonic() - step_t0
+                self.step_ema_s = (dt if self.step_ema_s == 0.0
+                                   else 0.8 * self.step_ema_s + 0.2 * dt)
+            if (self.cfg.metrics_interval_steps and self.metrics.steps
+                    % self.cfg.metrics_interval_steps == 0):
+                if self.monitor is not None:
+                    self.metrics.write_to(self.monitor, self.metrics.steps)
+                if self.telemetry is not None:
+                    self.telemetry.record_serving_step(
+                        self.metrics.steps, self.metrics.snapshot())
+            n_tokens, n_finished = self._deliver(emitted, spec_ready)
+            if tr.enabled:
+                deliver_span.set(tokens=n_tokens, finished=n_finished)
+
+    def _deliver(self, emitted: Dict[int, List[int]],
+                 spec_ready: bool) -> tuple:
+        """Hand one step's tokens to their streams, finish what is done,
+        extend what is not; ``(tokens delivered, requests finished)``."""
+        tr = self.tracer
+        n_tokens = n_finished = 0
         now = time.monotonic()
         for uid, burst in emitted.items():
             req = self._active.get(uid)
@@ -851,6 +872,7 @@ class InferenceServer:
                     req.span_phase = tr.span("serve.decode", req.trace_id,
                                              req.span_request).set(uid=uid)
                 req.stream._put_token(tok)
+                n_tokens += 1
                 if req.span_request is not None:
                     tr.instant("serve.emit", req.trace_id, uid=uid,
                                token=tok)
@@ -863,6 +885,7 @@ class InferenceServer:
                     done = True
                     break
             if done:
+                n_finished += 1
                 del self._active[uid]
                 if req.handoff:
                     # prefill-tier leg: export the finished chain's full
@@ -875,6 +898,7 @@ class InferenceServer:
                 # speculative bursts were appended to the engine sequence
                 # by verify_step itself; a plain step's token must extend
                 self.engine.extend(uid, burst[-1])
+        return n_tokens, n_finished
 
     def _spec_eligible(self) -> bool:
         """A speculative round needs EVERY active request greedy, opted

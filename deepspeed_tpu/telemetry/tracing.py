@@ -51,8 +51,11 @@ SPAN_NAMES = (
     "router.leg",              # one replica attempt of a routed request
     "router.request",          # whole routed-request lifetime (root span)
     "serve.admission_block",   # submit blocked on a full queue ('block' policy)
+    "serve.admit_pass",        # one loop iteration's sweeps + admissions
     "serve.decode",            # first token -> terminal (per request)
+    "serve.deliver",           # after serve.step: tokens to streams, finishes
     "serve.handoff",           # KV-chain export/import (disagg tiers)
+    "serve.idle_wait",         # no request there: wait_for_work
     "serve.prefill",           # admission -> first token (per request)
     "serve.queue_wait",        # enqueue -> admission (per request)
     "serve.request",           # whole request lifetime (root span)
@@ -64,7 +67,11 @@ SPAN_NAMES = (
     "train.step",              # one whole train_batch (root span)
     "train.sync",              # hard host sync (loss value fetch)
     "train.telemetry",         # StepRecord assembly + export
-    "v2.ragged_step",          # InferenceEngineV2.step ragged dispatch
+    "v2.dispatch",             # the jitted ragged step call until it returns
+    "v2.fetch",                # wait for the device + copy the result back
+    "v2.h2d",                  # device_put of the step's index arrays
+    "v2.ragged_step",          # InferenceEngineV2.step (parent of the v2.*)
+    "v2.schedule",             # next_schedule + build_ragged_batch
 )
 
 # Instant events (Chrome "i" events).

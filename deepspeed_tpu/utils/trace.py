@@ -15,6 +15,7 @@ viewer groups per-step work.  The engine drives this from the
 from __future__ import annotations
 
 import functools
+import time
 from typing import Optional
 
 import jax
@@ -39,6 +40,30 @@ def instrument_w_trace(func=None, *, name: Optional[str] = None):
         return wrapped
 
     return deco(func) if func is not None else deco
+
+
+CLOCK_ANCHOR = "dstpu.clock_anchor"
+
+
+def write_clock_anchor(label: str = "") -> int:
+    """Write a clock anchor into a running profiler capture and return
+    the ``time.monotonic_ns()`` it carries.
+
+    The anchor is a :class:`jax.profiler.TraceAnnotation` named
+    :data:`CLOCK_ANCHOR` whose ``monotonic_ns`` argument is this process's
+    monotonic clock at the instant the annotation begins.  The capture
+    stamps the annotation on its own host clock, so one anchor gives the
+    offset between a ``Tracer`` trace (``telemetry/tracing.py``, spans on
+    ``time.monotonic()``) and the capture's host plane, and two (one when
+    the capture starts, one when it stops) give the drift between them.
+    The host plane is NOT on the device planes' clock: whoever reduces
+    the capture measures that offset from the runtime's own events (see
+    ``benchmark/lib/attribute.py``).  Free when no capture is running."""
+    t = time.monotonic_ns()
+    with jax.profiler.TraceAnnotation(CLOCK_ANCHOR, monotonic_ns=t,
+                                      label=label):
+        pass
+    return t
 
 
 def range_push(msg: str) -> None:
@@ -92,6 +117,7 @@ class TraceProfiler:
         try:
             jax.profiler.start_trace(self.output_dir)
             self.active = True
+            write_clock_anchor("start")
             logger.info(f"TraceProfiler: capturing steps "
                         f"[{step}, {step + self.num_steps}) → "
                         f"{self.output_dir}")
@@ -126,6 +152,7 @@ class TraceProfiler:
             import jax.numpy as _jnp
 
             jax.block_until_ready(_jnp.zeros(()))
+            write_clock_anchor("stop")
             jax.profiler.stop_trace()
         finally:
             self.active = False

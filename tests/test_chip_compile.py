@@ -160,3 +160,49 @@ def test_pallas_matmul_kernel(chip, monkeypatch):
     monkeypatch.setattr(gather_matmul, "on_tpu", lambda: True)
     _compile(gather_matmul.pallas_matmul, chip((8192, 1024), BF16),
              chip((1024, 4096), BF16))
+
+
+# -- kernel names: what the profiler's ``XLA Ops`` line shows ---------------
+def _kernel_names(text):
+    """Instruction names of the Mosaic kernels in a compiled program."""
+    return [line.split("=")[0].strip().lstrip("%").strip()
+            for line in text.splitlines()
+            if "tpu_custom_call" in line and " = " in line]
+
+
+def test_flash_kernel_names_survive_checkpoint(chip):
+    """Each flash kernel is an instruction under its own name, not the
+    wrapper's (``checkpoint.N``, ``closed_call.N``): under
+    ``jax.checkpoint`` the forward runs again inside the backward."""
+    b, hq, hkv, s, d, window = FLASH_SHAPES["gpt2-350m"]
+
+    @jax.checkpoint
+    def fwd(q, k, v):
+        return flash_mha_mod.flash_mha(q, k, v, True, None, window)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(F32))
+
+    args = (chip((b, hq, s, d), BF16), chip((b, hkv, s, d), BF16),
+            chip((b, hkv, s, d), BF16))
+    names = _kernel_names(_compile(jax.grad(loss, argnums=(0, 1, 2)), *args))
+    for want in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert any(n.split(".")[0] == want for n in names), (want, names)
+    assert all(n.startswith("flash_") for n in names), names
+    fwd_names = _kernel_names(_compile(fwd, *args))
+    assert [n.split(".")[0] for n in fwd_names] == ["flash_fwd"]
+
+
+@pytest.mark.parametrize("kv_dtype,want", [(BF16, "paged_decode"),
+                                           (I8, "paged_decode_q8")])
+def test_paged_kernel_name(chip, kv_dtype, want):
+    def fn(q, k, v, pages, pos, clen, *scales):
+        ks, vs = scales if scales else (None, None)
+        return paged_attention.paged_decode_attention(
+            q, k, v, pages, pos, clen, block_size=_BS, sm_scale=128 ** -0.5,
+            window=4096, k_scales=ks, v_scales=vs)
+
+    extra = ((chip((_NKV, _ROWS), F32),) * 2 if kv_dtype == I8 else ())
+    names = _kernel_names(_compile(fn, *_paged_args(chip, 128, kv_dtype),
+                                   *extra))
+    assert [n.split(".")[0] for n in names] == [want], names

@@ -60,11 +60,13 @@ EXPECTED_SPAN_NAMES = [
     "fleet.sample",
     "offload.d2h", "offload.h2d", "offload.host_step",
     "recovery.outage", "router.leg", "router.request",
-    "serve.admission_block", "serve.decode", "serve.handoff",
+    "serve.admission_block", "serve.admit_pass", "serve.decode",
+    "serve.deliver", "serve.handoff", "serve.idle_wait",
     "serve.prefill", "serve.queue_wait", "serve.request", "serve.step",
     "spec.draft", "spec.verify",
     "train.data_ingest", "train.dispatch", "train.step", "train.sync",
-    "train.telemetry", "v2.ragged_step",
+    "train.telemetry",
+    "v2.dispatch", "v2.fetch", "v2.h2d", "v2.ragged_step", "v2.schedule",
 ]
 EXPECTED_EVENT_NAMES = [
     "chaos.inject", "fleet.brownout", "fleet.heal",
