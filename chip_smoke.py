@@ -196,6 +196,29 @@ def kernels_phase(seed: int = 0) -> dict:
                                          scale)
         errs[name] = max_err(out, ref)
 
+    # a ragged step as the engine lays it out: block tables and a slot per
+    # row; six decoding rows, then a 90-row prefill chunk on 100 cached
+    # tokens (its rows share page walks), then padding (the last table row)
+    n_dec, cached, chunk, t = 6, 100, 90, 128
+    real = n_dec + chunk
+    seq_tables = jnp.asarray(rng.integers(1, n_pages, size=(n_dec + 2, nb)),
+                             jnp.int32)
+    ctx = np.append(rng.integers(1, nb * bs, size=n_dec), [cached + chunk, 0])
+    slot = np.full((t,), n_dec + 1, np.int32)
+    slot[:real] = np.append(np.arange(n_dec), np.full(chunk, n_dec))
+    pos = np.zeros((t,), np.int32)
+    pos[:real] = np.append(ctx[:n_dec] - 1, np.arange(cached, cached + chunk))
+    slot, pos = jnp.asarray(slot), jnp.asarray(pos)
+    clen = jnp.asarray(ctx, jnp.int32)[slot]
+    q = rnd((t, nh, d))
+    gather_idx = (seq_tables[slot][:, c_idx // bs] * bs
+                  + (c_idx % bs)[None, :])
+    errs["paged bf16 ragged step"] = max_err(
+        paged_decode_attention(q, pool["k"], pool["v"], seq_tables, pos, clen,
+                               bs, scale, token_slot=slot)[:real],
+        v2_model._paged_attention_xla(q, pool["k"], pool["v"], gather_idx,
+                                      pos, clen, flat_cfg)[:real])
+
     for name, err in errs.items():
         log(f"[kernels] {name}: max |pallas - xla| = {err:.4f}")
         require(np.isfinite(err) and err < 0.05,

@@ -37,6 +37,8 @@ from deepspeed_tpu.inference.v2.ragged import (DSStateManager,
 from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
 from deepspeed_tpu.models import transformer as tf_model
 from deepspeed_tpu.models.transformer import TransformerConfig
+from deepspeed_tpu.ops.pallas.paged_attention import (QUERY_BLOCK,
+                                                      shared_walk_rows)
 from deepspeed_tpu.resilience.oracle import PartitionOracle
 from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
 from deepspeed_tpu.utils.logging import log_dist
@@ -120,8 +122,8 @@ def _named(name: str, fn, **static):
     return p
 
 
-def step_counts(items: Sequence[tuple], window: Optional[int] = None
-                ) -> Dict[str, int]:
+def step_counts(items: Sequence[tuple], window: Optional[int] = None,
+                query_block: int = 0) -> Dict[str, int]:
     """What one ragged step is asked to do, from its ``(cached, n_new)``
     work items (tokens already in the cache, tokens this step adds); the
     arguments of the ``v2.schedule`` span.  A decode item adds one token
@@ -130,7 +132,10 @@ def step_counts(items: Sequence[tuple], window: Optional[int] = None
     once, cut to ``window``: the keys (and as many values) the step's
     attention has to read.  ``qk_pairs``: live (query, key) pairs of the
     new tokens under the causal mask, the token at 0-based position p
-    seeing ``min(p + 1, window)`` keys."""
+    seeing ``min(p + 1, window)`` keys.  ``blocked_rows``: the new tokens
+    that share a page walk in the query-blocked paged kernel, which
+    serves ``query_block`` rows of the step a program (0: the step does
+    not run that kernel)."""
     prefill = decode = kv_rows = pairs = 0
     for cached, n in items:
         if n == 1 and cached > 0:
@@ -147,9 +152,11 @@ def step_counts(items: Sequence[tuple], window: Optional[int] = None
         else:
             kv_rows += end
             pairs += (end * (end + 1) - cached * (cached + 1)) // 2
+    blocked = (shared_walk_rows([n for _, n in items], query_block)
+               if query_block else 0)
     return {"seqs": len(items), "tokens": prefill + decode,
             "prefill_tokens": prefill, "decode_tokens": decode,
-            "kv_rows": kv_rows, "qk_pairs": pairs}
+            "blocked_rows": blocked, "kv_rows": kv_rows, "qk_pairs": pairs}
 
 
 def _kv_scatter(cache_k, cache_v, rows, k, v):
@@ -296,6 +303,12 @@ class InferenceEngineV2:
         self._kv_write = jax.jit(_named("kv_write", _kv_scatter),
                                  donate_argnums=(0, 1))
         self.attention_impl = attention_impl_name(mc, self.cfg.block_size)
+        # rows of a step one program of the query-blocked paged kernel
+        # serves; 0 where the step runs another kernel (the XLA gather
+        # path, the int8-KV row kernel)
+        self._query_block = (
+            QUERY_BLOCK if self.attention_impl == "paged_pallas"
+            and self.cfg.kv_dtype != "int8" else 0)
         log_dist(f"InferenceEngineV2: budget={self.cfg.max_ragged_batch_size} "
                  f"blocks={self.cfg.num_blocks}×{self.cfg.block_size} "
                  f"max_seqs={self.cfg.max_tracked_sequences} tp={self.cfg.tp_size} "
@@ -375,7 +388,7 @@ class InferenceEngineV2:
             # build_ragged_batch advanced num_cached past the new tokens
             sp.end(**step_counts(
                 [(seq.num_cached - n, n) for seq, n in schedule],
-                self.model_config.sliding_window))
+                self.model_config.sliding_window, self._query_block))
         host = (rb.token_ids[:t_bucket], rb.token_slot[:t_bucket],
                 rb.token_pos[:t_bucket], rb.token_dest[:t_bucket],
                 rb.block_tables[:, :nb_bucket], rb.ctx_lens, rb.logits_idx)

@@ -140,8 +140,9 @@ def _pallas_attn_default(block_size=0, head_dim=0, on_tpu=False,
 def _attn_impl_pallas(q, k_pages, v_pages, gather_idx, token_pos,
                       token_ctx_len, cfg, block_tables, token_slot,
                       block_size):
-    """Pallas block-table kernel (ops/pallas/paged_attention.py: page walk
-    with online softmax — no [T, C, ...] gather materialisation).
+    """Pallas block-table kernel (ops/pallas/paged_attention.py: runs of
+    a sequence's rows share one page walk with online softmax — no
+    [T, C, ...] gather materialisation, no per-token page table).
     Ref kernel: inference/v2/kernels/ragged_ops/blocked_flash."""
     if block_tables is None:
         raise ValueError(
@@ -151,17 +152,17 @@ def _attn_impl_pallas(q, k_pages, v_pages, gather_idx, token_pos,
         raise ValueError(
             "attention='paged_pallas' has no ALiBi score-bias lane — use "
             "'auto' or 'paged_xla' for bloom-class models")
-    pages = block_tables[token_slot]  # [T, NB]
     scale = (cfg.attn_scale if cfg.attn_scale is not None
              else 1.0 / math.sqrt(cfg.dim_per_head))
+    kw = dict(window=cfg.sliding_window or None, token_slot=token_slot)
     if _is_quant_cache(k_pages):
         return paged_decode_attention(
-            q, k_pages["q"], v_pages["q"], pages, token_pos, token_ctx_len,
-            block_size, scale, window=cfg.sliding_window or None,
-            k_scales=k_pages["s"], v_scales=v_pages["s"])
+            q, k_pages["q"], v_pages["q"], block_tables, token_pos,
+            token_ctx_len, block_size, scale, k_scales=k_pages["s"],
+            v_scales=v_pages["s"], **kw)
     return paged_decode_attention(
-        q, k_pages, v_pages, pages, token_pos, token_ctx_len,
-        block_size, scale, window=cfg.sliding_window or None)
+        q, k_pages, v_pages, block_tables, token_pos, token_ctx_len,
+        block_size, scale, **kw)
 
 
 @register_module("attention", "paged_xla")

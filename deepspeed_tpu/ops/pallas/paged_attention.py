@@ -1,30 +1,51 @@
-"""Repo-owned Pallas paged (block-table) attention for inference v2 decode.
+"""Repo-owned Pallas paged (block-table) attention for the inference v2
+ragged step: prefill chunks, speculative verify runs and decode rows.
 
 TPU replacement for the reference's ragged blocked-flash CUDA kernels
 (``/root/reference/deepspeed/inference/v2/kernels/ragged_ops/`` — blocked
-flash over a KV block table). Design:
+flash over a KV block table). Design of ``paged_qblock``, the kernel
+behind :func:`paged_decode_attention` for a bf16/fp32 cache:
 
-* **Grid (T, nkv)**: ONE program per (query token, KV head) walks that
-  token's live pages in an in-kernel ``fori_loop`` with double-buffered
-  manual DMA (``pltpu.make_async_copy``) out of the HBM-resident page
-  pool — page ``tables[t, j+1]``'s copy is in flight while page
-  ``tables[t, j]`` is being processed, the same prefetch loop the
-  reference's CUDA kernel implements by hand.  (Putting the page walk on
-  the grid instead costs T·nkv·NB invocations whose fixed per-step
-  overhead dominated decode — measured r04: 7.3 → 2.3 ms/call at T=32,
-  NB=128.)
-* **Online softmax** state (m, l, acc) rides the loop carry; dead pages
-  (beyond the causal frontier, or before the sliding window) are never
-  visited at all.
-* **GQA-native**: the q block for KV head ``h`` is its ``group`` query
-  heads ``[group, d]``, matmul'd against the page block ``[bs, d]`` — KV
-  heads are never repeated, and every contraction is a plain rank-2 matmul
-  (Mosaic-friendly; no in-kernel reshapes).
-* No [T, C, nkv, d] gather is ever materialised in HBM (the XLA fallback's
-  cost, and the reason decode throughput was gather-bound in round 1).
+* **Query-blocked grid (T / QB,)**: one program serves ``QB`` consecutive
+  rows of the step's flat token array, every KV head.  It cuts the block
+  into RUNS of consecutive rows of one sequence (read from ``token_slot``,
+  at run time, per block) and walks each run's live pages ONCE — from the
+  page of the window's lower edge at the run's smallest position to the
+  causal frontier of its largest — with all ``QB x group`` query rows of
+  a KV head as the left operand ``[QB*group, d]``.  A 256-token prefill
+  chunk therefore visits its pages once per 32 rows, not once per row; a
+  decode row is a run of one and walks its own pages, no others.  (The
+  row-per-program grid (T, nkv) this replaces took 150 ms a call on a
+  251-row chunk at context 3,840, this one 0.66 ms: PERF.md, PR 27.)
+* **Masks are per row**: causal, context-length and sliding-window masks
+  come from each row's own ``token_pos`` / ``token_ctx_len``, and rows
+  outside the run being walked are masked out, so the kernel relies on no
+  row order — ``build_ragged_batch``'s contiguous runs only make the
+  shared walks long.
+* **A compute step takes up to 512 keys**: that many whole pages, each
+  one strided DMA of its rows of ALL KV heads (``pltpu.make_async_copy``
+  out of the HBM-resident pool), land in one ``[nkv, keys, d]`` buffer,
+  double-buffered against the previous step's compute, so a score tile
+  ``[QB*group, keys]`` fills the lanes and the MXU instead of a 16-lane
+  page tile, and a page costs one descriptor, not one per KV head.
+* **Online softmax** state (m, l, acc) is per row and KV head, float32,
+  in VMEM scratch; operands are the cache's dtype, scores float32.  Dead
+  pages (beyond the run's causal frontier, or before its window) are
+  never visited.
+* **GQA-native**: a KV head's ``group`` query heads ride the row axis of
+  its query tile — KV heads are never repeated, and every contraction is
+  a plain rank-2 matmul (Mosaic-friendly; no in-kernel reshapes: XLA
+  lays the queries out ``[nkv, T*group, d]`` before the call and back
+  after it).
+* No [T, C, nkv, d] gather is ever materialised in HBM (the XLA
+  fallback's cost), and no per-token page table either: the kernel reads
+  ``block_tables[token_slot[t]]`` from SMEM itself.
+
+The int8-KV variant ``paged_decode_q8`` keeps the row-per-program grid
+(T, nkv) and a page a step (no cell or deployment measures it yet).
 
 Cache layout contract: k_pages/v_pages are ``[nkv, P, d]`` where P = number
-of pages × block_size rows; ``pages[t, j]`` gives page ids (row-blocks of
+of pages × block_size rows; ``pages[s, j]`` gives page ids (row-blocks of
 ``block_size``). Positions ``c = j*block_size + r`` are masked against the
 token's causal position and its sequence's context length.
 """
@@ -43,6 +64,14 @@ NEG_INF = -1e30
 
 INTERPRET = False
 
+# Rows of the step's flat token array one program serves (``QB``); the
+# host reads it for the ``blocked_rows`` count of the ``v2.schedule`` span.
+QUERY_BLOCK = 32
+# Keys one compute step of a walk takes (whole pages; at least one), as
+# far as the K and V double buffers of all KV heads fit their VMEM share.
+_STEP_KEYS = 512
+_KV_BUFFER_BYTES = 4 * 1024 * 1024
+
 
 def supports(block_size: int, d: int) -> bool:
     """Kernel applicability: page rows must be sublane-aligned and the
@@ -53,89 +82,168 @@ def supports(block_size: int, d: int) -> bool:
     return block_size >= 8 and block_size % 8 == 0 and d % 128 == 0
 
 
-def _kernel(pages_ref, pos_ref, clen_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sem_k, sem_v, *, bs, group, sm_scale,
-            window=None):
-    """Grid (T, nkv): ONE program per (token, KV head) walks that token's
-    live pages in an in-kernel fori_loop with double-buffered manual DMA
-    from the HBM-resident page pool.  The previous design put the page
-    walk on the grid — T·nkv·NB invocations whose fixed per-step cost
-    (~0.6 µs on v5e) dominated decode (measured r04: 7.3 ms/call at
-    T=32, NB=128 vs 0.35 ms for this form, with identical math)."""
-    t = pl.program_id(0)
-    h = pl.program_id(1)
-    pos = pos_ref[t]
-    clen = clen_ref[t]
-    j_lo = jnp.int32(0)
-    if window is not None:
-        j_lo = jnp.maximum((pos - (window - 1)) // bs, 0)
-    j_hi = pos // bs + 1  # one past the causal frontier page
+def shared_walk_rows(run_lengths, query_block: int = QUERY_BLOCK) -> int:
+    """Rows of a step that share a page walk with a neighbour: the step's
+    rows are its sequences' runs laid end to end (``run_lengths``, in row
+    order), a program cuts them at every multiple of ``query_block``, and
+    a piece of two rows or more is walked once for all its rows."""
+    shared = cursor = 0
+    for n in run_lengths:
+        end = cursor + n
+        while cursor < end:
+            piece = min(end, (cursor // query_block + 1) * query_block) \
+                - cursor
+            if piece > 1:
+                shared += piece
+            cursor += piece
+    return shared
 
-    def page_copy(j, slot):
-        page = pages_ref[t, j]
-        ck = pltpu.make_async_copy(
-            k_hbm.at[h, pl.dslice(page * bs, bs)], k_buf.at[slot],
-            sem_k.at[slot])
-        cv = pltpu.make_async_copy(
-            v_hbm.at[h, pl.dslice(page * bs, bs)], v_buf.at[slot],
-            sem_v.at[slot])
-        ck.start()
-        cv.start()
 
-    page_copy(j_lo, 0)
-    q = q_ref[0, 0]                                      # [group, d]
+def _kernel_qblock(tables_ref, slot_ref, pos_ref, clen_ref, q_ref, rowpos_ref,
+                   rowclen_ref, k_hbm, v_hbm, o_ref, m_scr, l_scr, acc_scr,
+                   k_buf, v_buf, sem_k, sem_v, *, bs, group, qb,
+                   pages_per_step, sm_scale, window=None):
+    """Grid (T / qb,): one program, ``qb`` rows of the token array, every
+    KV head; ``q_ref`` / ``o_ref`` are ``[nkv, qb*group, d]``, row = token
+    * group + head of the group.  The rows are cut into runs of one
+    sequence; each run is one double-buffered walk of ``pages_per_step``
+    pages a step, a page's rows of all KV heads in one strided DMA."""
+    base = pl.program_id(0) * qb
+    nkv, rows = q_ref.shape[:2]
+    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    row = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    row_pos = rowpos_ref[...]                            # [rows, 1]
+    row_clen = rowclen_ref[...]
 
-    def body(j, carry):
-        m_prev, l_prev, acc = carry
-        slot = lax.rem(j - j_lo, 2)
-
-        @pl.when(j + 1 < j_hi)
-        def _():
-            page_copy(j + 1, 1 - slot)
-
-        # wait() only consumes (sem, dst-bytes) — the src slice need not
-        # match the one the copy was started with, so a fixed slice
-        # reconstructs an equivalent descriptor for the decrement
-        pltpu.make_async_copy(k_hbm.at[h, pl.dslice(0, bs)],
-                              k_buf.at[slot], sem_k.at[slot]).wait()
-        pltpu.make_async_copy(v_hbm.at[h, pl.dslice(0, bs)],
-                              v_buf.at[slot], sem_v.at[slot]).wait()
-        k = k_buf[slot]                                  # [bs, d]
-        v = v_buf[slot]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [group, bs]
-        s = s * sm_scale
-        c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * bs
-        valid = (c <= pos) & (c < clen)
+    def walk(r0, r1, seq, pmin, pmax, cmax):
+        """Rows [r0, r1) of the block belong to table row ``seq``: visit
+        its pages once, every row masked by its own position."""
+        j_lo = jnp.int32(0)
         if window is not None:
-            valid &= pos - c < window
-        s = jnp.where(valid, s, NEG_INF)
+            j_lo = jnp.maximum((pmin - (window - 1)) // bs, 0)
+        # one past the causal frontier page; a run with no context (the
+        # padded tail) walks nothing
+        j_hi = jnp.where(cmax > 0, pmax // bs + 1, j_lo)
+        n_steps = (j_hi - j_lo + pages_per_step - 1) // pages_per_step
 
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                           # [group, bs]
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [group, d]
-        return m_new, l_new, acc * alpha + pv
+        def page_copies(buf, p, page):
+            dst = pl.dslice(pl.multiple_of(p * bs, bs), bs)
+            src = pl.dslice(page * bs, bs)
+            return (pltpu.make_async_copy(k_hbm.at[:, src],
+                                          k_buf.at[buf, :, dst],
+                                          sem_k.at[buf]),
+                    pltpu.make_async_copy(v_hbm.at[:, src],
+                                          v_buf.at[buf, :, dst],
+                                          sem_v.at[buf]))
 
-    m0 = jnp.full((group, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((group, 1), jnp.float32)
-    a0 = jnp.zeros((group, q.shape[-1]), jnp.float32)
-    m, l, acc = lax.fori_loop(j_lo, j_hi, body, (m0, l0, a0))
-    safe_l = jnp.where(l > 0, l, 1.0)
-    o_ref[0, 0] = (acc / safe_l).astype(o_ref.dtype)
+        def start_step(n, buf):
+            def start_page(p, _):
+                # past the frontier: fetch the last live page again (its
+                # keys sit beyond every row's position, so they are
+                # masked) — the buffer never holds uninitialised rows
+                j = jnp.minimum(j_lo + n * pages_per_step + p, j_hi - 1)
+                for copy in page_copies(buf, p, tables_ref[seq, j]):
+                    copy.start()
+                return 0
+
+            lax.fori_loop(0, pages_per_step, start_page, 0)
+
+        def wait_step(buf):
+            def wait_page(p, _):
+                # wait() only consumes (sem, dst-bytes) — the src slice
+                # need not be the one the copy was started with
+                for copy in page_copies(buf, p, 0):
+                    copy.wait()
+                return 0
+
+            lax.fori_loop(0, pages_per_step, wait_page, 0)
+
+        @pl.when(n_steps > 0)
+        def _():
+            start_step(0, 0)
+
+        in_run = (row >= r0 * group) & (row < r1 * group)
+
+        def body(n, _):
+            buf = lax.rem(n, 2)
+
+            @pl.when(n + 1 < n_steps)
+            def _():
+                start_step(n + 1, 1 - buf)
+
+            wait_step(buf)
+            c = (lax.broadcasted_iota(jnp.int32, (rows, pages_per_step * bs),
+                                      1)
+                 + (j_lo + n * pages_per_step) * bs)
+            valid = in_run & (c <= row_pos) & (c < row_clen)
+            if window is not None:
+                valid &= row_pos - c < window
+
+            def head(h, _):
+                k = k_buf[buf, h]                        # [step_keys, d]
+                v = v_buf[buf, h]
+                s = jax.lax.dot_general(
+                    q_ref[h], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # [rows, step_keys]
+                s = jnp.where(valid, s * sm_scale, NEG_INF)
+                m_prev = m_scr[h]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # a row with no live key in this step (another run's row,
+                # or one whose frontier lies before it) must add nothing:
+                # without the select its exp(NEG_INF - NEG_INF) would be 1
+                p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+                acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # [rows, d]
+                m_scr[h] = m_new
+                return 0
+
+            lax.fori_loop(0, nkv, head, 0)
+            return 0
+
+        lax.fori_loop(0, n_steps, body, 0)
+
+    big = jnp.int32(2 ** 30)
+
+    def row_body(i, run):
+        # one pass over the block's rows: a run ends where the next row
+        # belongs to another sequence, and is walked there
+        r0, pmin, pmax, cmax = run
+        seq = slot_ref[base + i]
+        pmin = jnp.minimum(pmin, pos_ref[base + i])
+        pmax = jnp.maximum(pmax, pos_ref[base + i])
+        cmax = jnp.maximum(cmax, clen_ref[base + i])
+        last = (i == qb - 1) | (
+            slot_ref[base + jnp.minimum(i + 1, qb - 1)] != seq)
+
+        @pl.when(last)
+        def _():
+            walk(r0, i + 1, seq, pmin, pmax, cmax)
+
+        return (jnp.where(last, i + 1, r0), jnp.where(last, big, pmin),
+                jnp.where(last, -1, pmax), jnp.where(last, 0, cmax))
+
+    lax.fori_loop(0, qb, row_body,
+                  (jnp.int32(0), big, jnp.int32(-1), jnp.int32(0)))
+
+    l = l_scr[...]
+    o_ref[...] = (acc_scr[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
 def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
                   k_hbm, v_hbm, o_ref, k_buf, v_buf, sem_k, sem_v, *,
                   bs, group, sm_scale, window=None):
-    """Int8-KV variant of :func:`_kernel`: the page payloads are int8 with
-    one fp32 scale per (head, row).  Only the d-wide payload rides the
-    manual double-buffered DMA (half the bytes of the bf16 cache — the
+    """Int8-KV row kernel, grid (T, nkv): ONE program per (token, KV head)
+    walks that token's live pages, a page a step, in an in-kernel
+    fori_loop with double-buffered manual DMA.  The page payloads are int8
+    with one fp32 scale per (head, row).  Only the d-wide payload rides
+    the manual double-buffered DMA (half the bytes of the bf16 cache — the
     decode bandwidth win); the per-head scales are small and arrive whole
     through an ordinary VMEM BlockSpec as ``[pages, bs]``, one sublane
     row per page.
@@ -150,7 +258,7 @@ def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
     j_lo = jnp.int32(0)
     if window is not None:
         j_lo = jnp.maximum((pos - (window - 1)) // bs, 0)
-    j_hi = pos // bs + 1
+    j_hi = pos // bs + 1  # one past the causal frontier page
 
     def page_copy(j, slot):
         page = pages_ref[t, j]
@@ -172,6 +280,9 @@ def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
         def _():
             page_copy(j + 1, 1 - slot)
 
+        # wait() only consumes (sem, dst-bytes) — the src slice need not
+        # match the one the copy was started with, so a fixed slice
+        # reconstructs an equivalent descriptor for the decrement
         pltpu.make_async_copy(k_hbm.at[h, pl.dslice(0, bs)],
                               k_buf.at[slot], sem_k.at[slot]).wait()
         pltpu.make_async_copy(v_hbm.at[h, pl.dslice(0, bs)],
@@ -209,53 +320,32 @@ def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
     o_ref[0, 0] = (acc / safe_l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_size", "sm_scale",
-                                             "window"))
-def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
-                           token_ctx_len, block_size: int, sm_scale: float,
-                           window: int | None = None,
-                           k_scales=None, v_scales=None):
-    """q: [T, nh, d]; k_pages/v_pages: [nkv, P, d]; pages: [T, NB] page ids
-    per token; token_pos/token_ctx_len: [T]; ``window``: Mistral sliding
-    window (key visible iff qpos - kpos < window).  With
-    ``k_scales``/``v_scales`` [nkv, P] the page payloads are int8 rows
-    scaled per (head, row) — ref KV-block layout
-    inference/v2/ragged/kv_cache.py:40.  Returns [T, nh, d]."""
+def _decode_q8(q, k_pages, v_pages, pages, token_pos, token_ctx_len, k_scales,
+               v_scales, bs, sm_scale, window):
+    """The int8-KV row kernel's call: ``pages`` is [T, NB], a table per
+    token."""
     t, nh, d = q.shape
     nkv, p_rows = k_pages.shape[0], k_pages.shape[1]
     group = nh // nkv
-    bs = block_size
-    quant = k_scales is not None
-
-    in_specs = [
-        # q reshaped to [T, nkv, group, d] outside: one KV head's query
-        # group per block, full trailing dims (Mosaic block constraint)
-        pl.BlockSpec((1, 1, group, d), lambda t_, h, *refs: (t_, h, 0, 0)),
-    ]
-    extra = ()
-    if quant:
-        # whole per-head scales live in VMEM via the normal pipeline,
-        # viewed [nkv, pages, bs] so the block's trailing dims are the
-        # array's own (Mosaic block constraint) and a page's scales are
-        # one dynamically indexed sublane row
-        n_pages = p_rows // bs
-        sc_spec = pl.BlockSpec((1, n_pages, bs),
-                               lambda t_, h, *refs: (h, 0, 0))
-        in_specs += [sc_spec, sc_spec]
-        extra = tuple(s.astype(jnp.float32).reshape(nkv, n_pages, bs)
-                      for s in (k_scales, v_scales))
-    in_specs += [
-        # the page pools stay in HBM; the kernel DMAs live pages into
-        # its double buffer itself
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
+    # q reshaped to [T, nkv, group, d] outside: one KV head's query
+    # group per block, full trailing dims (Mosaic block constraint)
+    q_spec = pl.BlockSpec((1, 1, group, d), lambda t_, h, *refs: (t_, h, 0, 0))
+    # whole per-head scales live in VMEM via the normal pipeline,
+    # viewed [nkv, pages, bs] so the block's trailing dims are the
+    # array's own (Mosaic block constraint) and a page's scales are
+    # one dynamically indexed sublane row
+    n_pages = p_rows // bs
+    sc_spec = pl.BlockSpec((1, n_pages, bs), lambda t_, h, *refs: (h, 0, 0))
+    scales = tuple(s.astype(jnp.float32).reshape(nkv, n_pages, bs)
+                   for s in (k_scales, v_scales))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(t, nkv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, group, d),
-                               lambda t_, h, *refs: (t_, h, 0, 0)),
+        # the page pools stay in HBM; the kernel DMAs live pages into
+        # its double buffer itself
+        in_specs=[q_spec, sc_spec, sc_spec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((2, bs, d), k_pages.dtype),   # k double buffer
             pltpu.VMEM((2, bs, d), v_pages.dtype),   # v double buffer
@@ -263,15 +353,96 @@ def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    kern = _kernel_quant if quant else _kernel
     out = pl.pallas_call(
-        functools.partial(kern, bs=bs, group=group, sm_scale=sm_scale,
-                          window=window),
+        functools.partial(_kernel_quant, bs=bs, group=group,
+                          sm_scale=sm_scale, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, nkv, group, d), q.dtype),
         interpret=INTERPRET,
-        name="paged_decode_q8" if quant else "paged_decode",
+        name="paged_decode_q8",
     )(pages.astype(jnp.int32), token_pos.astype(jnp.int32),
       token_ctx_len.astype(jnp.int32), q.reshape(t, nkv, group, d),
-      *extra, k_pages, v_pages)
+      *scales, k_pages, v_pages)
     return out.reshape(t, nh, d)
+
+
+@functools.partial(jax.jit, static_argnames=("block_size", "sm_scale",
+                                             "window"))
+def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
+                           token_ctx_len, block_size: int, sm_scale: float,
+                           window: int | None = None,
+                           k_scales=None, v_scales=None, token_slot=None):
+    """q: [T, nh, d]; k_pages/v_pages: [nkv, P, d]; token_pos/token_ctx_len:
+    [T]; ``pages``: page ids, [S, NB] block tables with ``token_slot`` [T]
+    naming each token's table row, or [T, NB] a table per token without
+    it; ``window``: Mistral sliding window (key visible iff qpos - kpos <
+    window).  With ``k_scales``/``v_scales`` [nkv, P] the page payloads
+    are int8 rows scaled per (head, row) — ref KV-block layout
+    inference/v2/ragged/kv_cache.py:40.  Returns [T, nh, d]."""
+    t, nh, d = q.shape
+    nkv = k_pages.shape[0]
+    group = nh // nkv
+    bs = block_size
+    i32 = lambda a: a.astype(jnp.int32)
+    if k_scales is not None:
+        if token_slot is not None:
+            pages = pages[token_slot]
+        return _decode_q8(q, k_pages, v_pages, pages, token_pos,
+                          token_ctx_len, k_scales, v_scales, bs, sm_scale,
+                          window)
+
+    slot = (jnp.arange(t, dtype=jnp.int32) if token_slot is None
+            else i32(token_slot))
+    qb = min(QUERY_BLOCK, t)
+    pad = -t % qb
+    q4 = q.reshape(t, nkv, group, d)
+    pos, clen = i32(token_pos), i32(token_ctx_len)
+    if pad:
+        # rows of no sequence (slot -1) with no context: walked by nobody
+        q4 = jnp.pad(q4, ((0, pad), (0, 0), (0, 0), (0, 0)))
+        slot = jnp.pad(slot, (0, pad), constant_values=-1)
+        pos, clen = jnp.pad(pos, (0, pad)), jnp.pad(clen, (0, pad))
+    tp = t + pad
+    rows = qb * group
+    step_keys = min(_STEP_KEYS, _KV_BUFFER_BYTES
+                    // (4 * nkv * d * k_pages.dtype.itemsize))
+    pages_per_step = max(1, step_keys // bs)
+
+    # per KV head a dense tile of its query groups, row = token * group +
+    # head of the group: no kernel-side relayout, one XLA transpose each way
+    q_spec = pl.BlockSpec((nkv, rows, d), lambda b, *refs: (0, b, 0))
+    # each tile row's own position and context length, as columns
+    col_spec = pl.BlockSpec((rows, 1), lambda b, *refs: (b, 0))
+    col = lambda a: jnp.repeat(a, group)[:, None]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(tp // qb,),
+        # the page pools stay in HBM; the kernel DMAs live pages into
+        # its double buffer itself
+        in_specs=[q_spec, col_spec, col_spec,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((nkv, rows, 1), jnp.float32),  # running max
+            pltpu.VMEM((nkv, rows, 1), jnp.float32),  # running sum
+            pltpu.VMEM((nkv, rows, d), jnp.float32),  # accumulator
+            pltpu.VMEM((2, nkv, pages_per_step * bs, d), k_pages.dtype),
+            pltpu.VMEM((2, nkv, pages_per_step * bs, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel_qblock, bs=bs, group=group, qb=qb,
+                          pages_per_step=pages_per_step, sm_scale=sm_scale,
+                          window=window),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((nkv, tp * group, d), q.dtype),
+        interpret=INTERPRET,
+        name="paged_qblock",
+    )(i32(pages), slot, pos, clen,
+      q4.swapaxes(0, 1).reshape(nkv, tp * group, d), col(pos), col(clen),
+      k_pages, v_pages)
+    out = out.reshape(nkv, tp, group, d).swapaxes(0, 1)
+    return out[:t].reshape(t, nh, d)

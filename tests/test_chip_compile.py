@@ -108,6 +108,22 @@ def test_paged_decode_serve_shape(chip):
     _compile(fn, *_paged_args(chip, 128, BF16))
 
 
+@pytest.mark.parametrize("t", [256, 16])
+def test_paged_qblock_serve_step(chip, t):
+    """The ragged step as the serving cells run it: block tables of 64
+    sequences and a padding row, 256 pages of 16 a sequence, 8 KV heads
+    of 4 query heads, head 128; a full 256-token step and the smallest
+    token bucket (below the query block)."""
+    def fn(q, k, v, tables, pos, clen, slot):
+        return paged_attention.paged_decode_attention(
+            q, k, v, tables, pos, clen, block_size=_BS,
+            sm_scale=128 ** -0.5, window=4096, token_slot=slot)
+
+    pool = chip((_NKV, 2048 * _BS, 128), BF16)
+    _compile(fn, chip((t, _NH, 128), BF16), pool, pool, chip((65, 256), I32),
+             chip((t,), I32), chip((t,), I32), chip((t,), I32))
+
+
 def test_paged_decode_int8_kv(chip):
     def fn(q, k, v, pages, pos, clen, ks, vs):
         return paged_attention.paged_decode_attention(
@@ -193,7 +209,7 @@ def test_flash_kernel_names_survive_checkpoint(chip):
     assert [n.split(".")[0] for n in fwd_names] == ["flash_fwd"]
 
 
-@pytest.mark.parametrize("kv_dtype,want", [(BF16, "paged_decode"),
+@pytest.mark.parametrize("kv_dtype,want", [(BF16, "paged_qblock"),
                                            (I8, "paged_decode_q8")])
 def test_paged_kernel_name(chip, kv_dtype, want):
     def fn(q, k, v, pages, pos, clen, *scales):

@@ -28,7 +28,8 @@ def _decode_fn(*args, **kw):
     return pm.paged_decode_attention.__wrapped__(*args, **kw)
 
 
-def _ref_paged(q, k_pages, v_pages, pages, pos, clen, bs, scale):
+def _ref_paged(q, k_pages, v_pages, pages, pos, clen, bs, scale,
+               window=None):
     """Gather-based reference: materialises each token's [C, d] context."""
     t, nh, d = q.shape
     nkv = k_pages.shape[0]
@@ -41,6 +42,8 @@ def _ref_paged(q, k_pages, v_pages, pages, pos, clen, bs, scale):
     qg = q.reshape(t, nkv, g, d).astype(jnp.float32)
     s = jnp.einsum("tkgd,ktcd->tkgc", qg, k_ctx) * scale
     valid = (c_idx[None, :] <= pos[:, None]) & (c_idx[None, :] < clen[:, None])
+    if window is not None:
+        valid &= pos[:, None] - c_idx[None, :] < window
     s = jnp.where(valid[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("tkgc,ktcd->tkgd", p, v_ctx)
@@ -174,21 +177,7 @@ def test_paged_sliding_window_parity():
     out = _decode_fn(q, kp, vp, tbl, pos, clen, block_size=bs,
                      sm_scale=scale, window=window)
 
-    # reference with window mask
-    nbk = tbl.shape[1]
-    c_idx = jnp.arange(nbk * bs)
-    rows = tbl[:, c_idx // bs] * bs + (c_idx % bs)[None, :]
-    k_ctx = kp[:, rows].astype(jnp.float32)
-    v_ctx = vp[:, rows].astype(jnp.float32)
-    g = nh // nkv
-    qg = q.reshape(t, nkv, g, d).astype(jnp.float32)
-    s = jnp.einsum("tkgd,ktcd->tkgc", qg, k_ctx) * scale
-    valid = ((c_idx[None, :] <= pos[:, None])
-             & (c_idx[None, :] < clen[:, None])
-             & (pos[:, None] - c_idx[None, :] < window))
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    ref = jnp.einsum("tkgc,ktcd->tkgd", p, v_ctx).reshape(t, nh, d)
+    ref = _ref_paged(q, kp, vp, tbl, pos, clen, bs, scale, window=window)
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref)))
     assert err < 0.05, err
 
@@ -221,3 +210,170 @@ def test_paged_quantized_parity(t, nh, nkv, d, n_pages, nb, bs):
     ref_float = _ref_paged(q, kp, vp, tbl, pos, clen, bs, scale)
     qerr = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref_float)))
     assert qerr < 0.15, qerr  # int8 per-row quantization noise bound
+
+
+# -- the ragged step: runs of one sequence's rows share a page walk ---------
+def _ragged_step(items, t, nb, bs, nh=4, nkv=2, d=64, seed=0, poison=False):
+    """A step as ``build_ragged_batch`` lays it out: each ``(cached,
+    n_new)`` item is one sequence's run of rows in position order, then
+    padding (slot = the last table row, no context).  Page 0 is the
+    garbage page; every sequence owns the pages after it, in order."""
+    n_seq = len(items)
+    tables = np.zeros((n_seq + 1, nb), np.int32)
+    slot = np.full((t,), n_seq, np.int32)
+    pos = np.zeros((t,), np.int32)
+    ctx = np.zeros((n_seq + 1,), np.int32)
+    cursor, page = 0, 1
+    for s, (cached, n) in enumerate(items):
+        need = -(-(cached + n) // bs)
+        tables[s, :need] = np.arange(page, page + need)
+        page += need
+        slot[cursor:cursor + n] = s
+        pos[cursor:cursor + n] = np.arange(cached, cached + n)
+        ctx[s] = cached + n
+        cursor += n
+    assert cursor <= t and max(ctx) <= nb * bs
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (t, nh, d), jnp.bfloat16)
+    k_pages = jax.random.normal(ks[1], (nkv, page * bs, d), jnp.bfloat16)
+    v_pages = jax.random.normal(ks[2], (nkv, page * bs, d), jnp.bfloat16)
+    if poison:
+        # huge finite values in page 0 must never leak through the masks
+        k_pages = k_pages.at[:, :bs].set(1e3)
+        v_pages = v_pages.at[:, :bs].set(1e3)
+    return (q, k_pages, v_pages, jnp.asarray(tables), jnp.asarray(slot),
+            jnp.asarray(pos), jnp.asarray(ctx)[slot], cursor)
+
+
+RAGGED_CASES = {
+    # a decode-only step: every row its own sequence, then padding
+    "decode_only": dict(items=[(c, 1) for c in (3, 17, 40, 95, 16, 31, 64,
+                                                1, 77, 50, 12, 88, 9, 33,
+                                                47, 5, 70, 23, 15, 60)],
+                        t=32, nb=8),
+    # one 256-row chunk that starts mid-page on a context already there
+    "chunk_256_mid_page": dict(items=[(37, 256)], t=256, nb=20),
+    # decode rows, two chunks whose seam falls inside a query block, padding
+    "decode_then_two_chunks": dict(items=[(20, 1), (45, 1), (9, 1), (63, 1),
+                                          (30, 1), (16, 40), (0, 30)],
+                                   t=128, nb=8),
+    # T below the query block; T above it and not a multiple of it
+    "t_below_block": dict(items=[(11, 1), (0, 6), (25, 1)], t=9, nb=4),
+    "t_not_a_multiple": dict(items=[(18, 1), (5, 41)], t=48, nb=4),
+    # a window shorter than the context, its edge moving inside a block
+    "window_edge_in_block": dict(items=[(7, 1), (100, 64)], t=96, nb=12,
+                                 window=40),
+    # the garbage page holds huge values
+    "garbage_page_poisoned": dict(items=[(33, 1), (2, 1), (19, 50)], t=64,
+                                  nb=8, poison=True),
+    # serving widths: 4 query heads a KV head, head 128
+    "group4_head128": dict(items=[(21, 1), (40, 45)], t=64, nb=8, nh=8,
+                           nkv=2, d=128),
+}
+
+
+@pytest.mark.parametrize("case", list(RAGGED_CASES), ids=list(RAGGED_CASES))
+def test_ragged_step_parity(case):
+    """The query-blocked kernel against the XLA gather reference on whole
+    ragged steps, block tables and ``token_slot`` as the model passes
+    them."""
+    kw = dict(RAGGED_CASES[case])
+    window, bs = kw.pop("window", None), 16
+    q, kp, vp, tables, slot, pos, clen, n_real = _ragged_step(bs=bs, **kw)
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    out = _decode_fn(q, kp, vp, tables, pos, clen, block_size=bs,
+                     sm_scale=scale, window=window, token_slot=slot)
+    ref = _ref_paged(q, kp, vp, tables[slot], pos, clen, bs, scale,
+                     window=window)
+    assert out.shape == q.shape
+    outf = out.astype(jnp.float32)
+    assert bool(jnp.all(jnp.isfinite(outf)))
+    err = float(jnp.max(jnp.abs(outf[:n_real] - ref[:n_real])))
+    assert err < 0.05, err
+    # a padded row belongs to no sequence and attends to nothing
+    assert float(jnp.max(jnp.abs(outf[n_real:]), initial=0.0)) == 0.0
+
+
+def test_rows_out_of_order_keep_their_own_masks():
+    """``build_ragged_batch``'s row order makes the shared walks long but
+    is not required: a sequence's rows with gaps and out of position
+    order, interleaved with another's, give each row its own answer."""
+    bs, nb = 16, 6
+    q, kp, vp, tables, _, _, _, _ = _ragged_step(
+        [(60, 1), (80, 1)], t=12, nb=nb, bs=bs)
+    slot = jnp.asarray([0, 0, 1, 0, 0, 1, 1, 1, 0, 2, 2, 2], jnp.int32)
+    pos = jnp.asarray([40, 3, 70, 59, 17, 2, 33, 80, 60, 0, 0, 0], jnp.int32)
+    clen = jnp.asarray([61, 81, 0], jnp.int32)[slot]
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    out = _decode_fn(q, kp, vp, tables, pos, clen, block_size=bs,
+                     sm_scale=scale, window=24, token_slot=slot)
+    ref = _ref_paged(q, kp, vp, tables[slot], pos, clen, bs, scale, window=24)
+    err = float(jnp.max(jnp.abs(out.astype(jnp.float32)[:9] - ref[:9])))
+    assert err < 0.05, err
+
+
+@pytest.mark.parametrize("runs,qb,want", [
+    ([1] * 20, 32, 0),                  # decode only
+    ([256], 32, 256),                   # one chunk, eight blocks
+    ([1] * 5 + [251], 32, 251),         # the seam block still shares a walk
+    ([1] * 5 + [40, 30], 32, 70),
+    ([1] * 31 + [2], 32, 0),            # a run cut in two rows of one
+    ([3, 1], 32, 3),
+    ([33], 32, 32),                     # its last row is alone in its block
+])
+def test_shared_walk_rows(runs, qb, want):
+    assert pm.shared_walk_rows(runs, qb) == want
+
+
+# -- the engine: a long prompt prefilled in chunks beside decoding rows ------
+def _engine_logits(attention):
+    """Two short sequences decode while a 150-token prompt prefills in
+    three chunks of the 64-token budget; every step's logits, the
+    ``v2.schedule`` spans' ``blocked_rows`` and the programs dispatched."""
+    from deepspeed_tpu.inference.v2 import build_engine
+    from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.telemetry.tracing import Tracer
+
+    # head_dim 128: the narrowest head the paged kernel takes
+    model = get_model_config("mistral-tiny", hidden_size=256, num_heads=2,
+                             num_kv_heads=1, num_layers=2)
+    eng = build_engine(
+        model, {"dtype": "float32", "modules": {"attention": attention},
+                "state_manager": {"max_tracked_sequences": 4,
+                                  "max_ragged_batch_size": 64},
+                "memory_config": {"num_blocks": 48, "block_size": 8},
+                "max_context": 192}, seed=0)
+    eng.tracer = Tracer(enabled=True)
+    rng = np.random.default_rng(11)
+    tok = lambda n: rng.integers(1, model.vocab_size, size=n).tolist()
+    steps = [eng.put([1, 2], [tok(5), tok(9)])]
+    eng.admit(3, tok(150))
+    for i in range(4):
+        # uid 3 has its first token after the third of these steps
+        for uid in (1, 2, 3)[:3 if i == 3 else 2]:
+            eng.extend(uid, tok(1)[0])
+        steps.append(eng.step(return_logits=True))
+    blocked = [e["args"]["blocked_rows"] for e in eng.tracer.snapshot()
+               if e["ph"] == "X" and e["name"] == "v2.schedule"]
+    return steps, blocked, set(eng._dispatched)
+
+
+def test_engine_chunked_prefill_beside_decode_matches_xla():
+    got, blocked, dispatched = _engine_logits("paged_pallas")
+    want, blocked_xla, dispatched_xla = _engine_logits("paged_xla")
+    # the prompt's last chunk comes in the third step after it was
+    # admitted: uid 3 is sampled there, and decodes in the fourth
+    assert [sorted(s) for s in got] == [[1, 2], [1, 2], [1, 2], [1, 2, 3],
+                                        [1, 2, 3]]
+    for step_got, step_want in zip(got, want):
+        for uid, logits in step_got.items():
+            err = float(np.max(np.abs(logits - step_want[uid])))
+            assert err < 0.05, (uid, err)
+    # runs of [5, 9]; [1, 1, 62] twice; [1, 1, 26]; three decode rows
+    assert blocked == [14, 62, 62, 26, 0]
+    assert blocked_xla == [0] * 5       # the gather path shares no walk
+    # one program per (program, t_bucket, nb_bucket), as before
+    assert dispatched == dispatched_xla == {
+        ("ragged_step", 16, 2), ("ragged_step", 64, 8),
+        ("ragged_step", 64, 16), ("ragged_step", 32, 24),
+        ("ragged_step", 16, 24)}

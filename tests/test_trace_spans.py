@@ -56,20 +56,24 @@ def _serve(eng, model, config):
 
 
 # -- the count behind the v2.schedule span ---------------------------------
-@pytest.mark.parametrize("window,want", [
+@pytest.mark.parametrize("window,query_block,want", [
     # A: 5 cached + 3 new (positions 5, 6, 7 see 6, 7, 8 keys);
-    # B: 9 cached + 1 new (position 9 sees 10 keys)
-    (None, {"seqs": 2, "tokens": 4, "prefill_tokens": 3, "decode_tokens": 1,
-            "kv_rows": 8 + 10, "qk_pairs": (6 + 7 + 8) + 10}),
-    # window 7: A's positions see 6, 7, 7; B's sees 7; each context 7 rows
-    (7, {"seqs": 2, "tokens": 4, "prefill_tokens": 3, "decode_tokens": 1,
-         "kv_rows": 7 + 7, "qk_pairs": (6 + 7 + 7) + 7}),
-    # a window no context reaches changes nothing
-    (64, {"seqs": 2, "tokens": 4, "prefill_tokens": 3, "decode_tokens": 1,
-          "kv_rows": 18, "qk_pairs": 31}),
+    # B: 9 cached + 1 new (position 9 sees 10 keys); A's three rows share
+    # a walk where the step runs the query-blocked kernel
+    (None, 32, {"seqs": 2, "tokens": 4, "prefill_tokens": 3,
+                "decode_tokens": 1, "blocked_rows": 3, "kv_rows": 8 + 10,
+                "qk_pairs": (6 + 7 + 8) + 10}),
+    # window 7: A's positions see 6, 7, 7; B's sees 7; each context 7 rows;
+    # a block of two rows cuts A's run into two rows and one
+    (7, 2, {"seqs": 2, "tokens": 4, "prefill_tokens": 3, "decode_tokens": 1,
+            "blocked_rows": 2, "kv_rows": 7 + 7,
+            "qk_pairs": (6 + 7 + 7) + 7}),
+    # a window no context reaches changes nothing; no blocked kernel
+    (64, 0, {"seqs": 2, "tokens": 4, "prefill_tokens": 3, "decode_tokens": 1,
+             "blocked_rows": 0, "kv_rows": 18, "qk_pairs": 31}),
 ])
-def test_step_counts_hand_worked(window, want):
-    assert step_counts([(5, 3), (9, 1)], window) == want
+def test_step_counts_hand_worked(window, query_block, want):
+    assert step_counts([(5, 3), (9, 1)], window, query_block) == want
 
 
 def test_step_counts_against_a_loop():
@@ -120,6 +124,8 @@ def test_new_spans_present_with_arguments_and_parents():
         a = e["args"]
         assert a["tokens"] == a["prefill_tokens"] + a["decode_tokens"]
         assert a["qk_pairs"] >= a["tokens"] and a["kv_rows"] >= a["seqs"]
+        # llama-tiny's head of 32 rides the XLA gather path: no shared walk
+        assert a["blocked_rows"] == 0
     # the 20-token prompt is split by the 16-token budget: prefill in
     # several steps; 5 new tokens a request: decode tokens in many
     assert sum(e["args"]["prefill_tokens"] for e in sched) == 5 + 20 + 3
