@@ -226,6 +226,69 @@ def kernels_phase(seed: int = 0) -> dict:
     return errs
 
 
+def ssd_phase(seed: int = 0, *, heads: int = 32, head_dim: int = 128,
+              state: int = 256, groups: int = 2, slots: int = 8,
+              chunk_rows: int = 150, chunk: int = 128) -> dict:
+    """The state-space scan kernel at Falcon-H1-34B's widths against its
+    XLA formulation over one ragged step: decode rows (runs of one), a
+    prompt's first ``chunk_rows`` rows from position 0 in a slot that
+    holds old state (crossing a chunk of the scan), a chunk that goes on
+    from its slot, and padding.  One decode row is silent (x, B = 0): its
+    slot must come back as exactly the decayed old state, which a state
+    kept in anything narrower than float32 would not."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas.ssd_ragged import ssd_ragged
+
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+    runs = [(2, 40, 1), (0, 7, 1), (5, 3, 1), (1, 0, chunk_rows),
+            (3, 64, 20)]                         # (slot, first position, rows)
+    n_pad = 6
+    slot = np.concatenate([np.full(n, s) for s, _, n in runs]
+                          + [np.full(n_pad, slots)]).astype(np.int32)
+    pos = np.concatenate([np.arange(p, p + n) for _, p, n in runs]
+                         + [np.zeros(n_pad)]).astype(np.int32)
+    t = len(slot)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    x = jax.random.normal(next(keys), (t, heads, head_dim), bf16)
+    b = jax.random.normal(next(keys), (t, groups, state), bf16)
+    c = jax.random.normal(next(keys), (t, groups, state), bf16)
+    x, b = x.at[1].set(0), b.at[1].set(0)        # the silent row, slot 0
+    dt = jax.nn.softplus(jax.random.normal(next(keys), (t, heads), f32) - 2)
+    a = -jnp.exp(jax.random.uniform(next(keys), (heads,), f32, 0.0, 2.7))
+    old = jax.random.normal(next(keys), (slots + 1, heads, head_dim, state),
+                            f32)
+    args = (x, dt, a, b, c)
+    want_y, want_s = ssd_ragged(*args, old, slot, pos, impl="xla")
+    got_y, got_s = ssd_ragged(*args, old + 0, slot, pos, impl="pallas",
+                              chunk=chunk)
+    real = slot != slots
+    scale_y = float(jnp.max(jnp.abs(want_y[real])))
+    err_y = float(jnp.max(jnp.abs(got_y - want_y)[real])) / scale_y
+    live = sorted({s for s, _, _ in runs})
+    err_s = float(jnp.max(jnp.abs(got_s - want_s)[jnp.asarray(live)])
+                  / jnp.max(jnp.abs(want_s[jnp.asarray(live)])))
+    idle = [s for s in range(slots) if s not in live]
+    untouched = bool(jnp.array_equal(got_s[jnp.asarray(idle)],
+                                     old[jnp.asarray(idle)]))
+    decayed = jnp.exp(dt[1] * a)[:, None, None] * old[0]
+    err_silent = float(jnp.max(jnp.abs(got_s[0] - decayed))
+                       / jnp.max(jnp.abs(decayed)))
+    log(f"[ssd] {t} rows ({len(runs)} runs, {n_pad} padding), {heads} heads "
+        f"of {head_dim}, state {state}: max |pallas - xla| y {err_y:.5f} of "
+        f"max |y|, state {err_s:.5f} of max |state|; silent row's slot off "
+        f"its decayed old state by {err_silent:.2e}; idle slots untouched "
+        f"{untouched}")
+    require(np.isfinite(err_y) and err_y < 0.02 and err_s < 0.02,
+            f"ssd_ragged disagrees with its XLA formulation (y {err_y}, "
+            f"state {err_s})")
+    require(untouched, "ssd_ragged wrote a slot no run of the step names")
+    require(err_silent < 1e-5, "ssd_ragged: a slot with no input is not its "
+            f"decayed old state in float32 ({err_silent})")
+    return {"y": err_y, "state": err_s, "silent": err_silent}
+
+
 # ----------------------------------------------------------------------
 # train
 # ----------------------------------------------------------------------
@@ -493,6 +556,7 @@ def run_one_chip(seed: int) -> None:
     from deepspeed_tpu.models import get_model_config
 
     kernels_phase(seed)
+    ssd_phase(seed)
     train = train_phase(
         get_model_config(TRAIN_MODEL, max_seq_len=TRAIN_SEQ),
         micro_batch=TRAIN_MICRO_BATCH, gas=TRAIN_GAS, seq=TRAIN_SEQ,

@@ -27,10 +27,12 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from deepspeed_tpu.inference.v2.model import (attention_impl_name,
                                               check_sampling_params,
+                                              new_ssm_state,
                                               ragged_decode_loop,
                                               ragged_forward,
                                               ragged_forward_sampled,
-                                              ragged_forward_verify)
+                                              ragged_forward_verify,
+                                              ssm_impl_name)
 from deepspeed_tpu.inference.v2.ragged import (DSStateManager,
                                                KVCacheExhausted,
                                                build_ragged_batch)
@@ -159,6 +161,28 @@ def step_counts(items: Sequence[tuple], window: Optional[int] = None,
             "blocked_rows": blocked, "kv_rows": kv_rows, "qk_pairs": pairs}
 
 
+def ssm_step_counts(items: Sequence[tuple], slot_bytes: int,
+                    live: int) -> Dict[str, int]:
+    """What one ragged step asks of a model's SSM mixer, from the same
+    ``(cached, n_new)`` items: further arguments of ``v2.schedule``.  Each
+    item is one run of rows through the scan.  ``state_bytes``: float32
+    recurrent state the runs move, every layer's — a run writes its
+    slot once and reads it once, unless it starts at position 0 and so
+    from zeros (``slot_bytes``: one slot's, all layers; the convolution's
+    tails, under a hundredth of it, are not counted).
+    ``state_slots_live``: sequences that hold a slot."""
+    fresh = sum(1 for cached, _ in items if cached == 0)
+    return {"ssm_runs": len(items), "ssm_rows": sum(n for _, n in items),
+            "state_slots_live": live,
+            "state_bytes": slot_bytes * (2 * len(items) - fresh)}
+
+
+class RecurrentStateUnsupported(NotImplementedError):
+    """A path that would need a snapshot of a sequence's recurrent state
+    (prefix reuse, speculative verify and rewind, KV hand-off) was asked
+    of a model that has such state: there are no snapshots."""
+
+
 def _kv_scatter(cache_k, cache_v, rows, k, v):
     """Write handed-off KV page rows into the paged caches (both cache
     layouts: plain array [L, nkv, P, d], or the int8 quantized dict
@@ -235,6 +259,7 @@ class InferenceEngineV2:
         # software-span tracer (telemetry/tracing.py) — the serving layer
         # injects both so ragged dispatches appear in the request trace
         # under the serve loop's trace id instead of one-off orphan ids
+        self._state_alloc = None    # what v2.state_alloc will say, once
         self.tracer = None
         self.trace_id = ""
         self._step_span = None      # the open v2.ragged_step, if any
@@ -270,26 +295,51 @@ class InferenceEngineV2:
             self.cache_k = zeros(kv_shape, dtype=kv_dt)
             self.cache_v = zeros(kv_shape, dtype=kv_dt)
 
+        # a model with an SSM mixer keeps a second kind of per-sequence
+        # state beside the pages: one slot a tracked sequence (and the
+        # padding rows' garbage slot), indexed by SequenceDescriptor.slot.
+        # Never cleared: a run that starts at position 0 starts from zeros
+        # inside the step
+        self.state = None
+        self.ssm_impl = None
+        self._slot_bytes = 0        # float32 recurrent state of one slot
+        donate: Dict[str, Any] = {"donate_argnums": (1, 2)}
+        if mc.ssm is not None:
+            t0 = time.monotonic()
+            self.state = jax.block_until_ready(new_ssm_state(
+                mc, self.cfg.max_tracked_sequences, zeros))
+            self.ssm_impl = ssm_impl_name(mc)
+            self._slot_bytes = (int(self.state["ssm"].nbytes)
+                                // self.state["ssm"].shape[1])
+            self._state_alloc = {
+                "ts": t0 * 1e6, "dur": (time.monotonic() - t0) * 1e6,
+                "ssm_bytes": int(self.state["ssm"].nbytes),
+                "conv_bytes": int(self.state["conv"].nbytes),
+                "slots": self.cfg.max_tracked_sequences + 1,
+                "ssm_impl": self.ssm_impl}
+            # by name wherever the engine calls; by place too for the
+            # ragged step, whose audit arguments are positional
+            donate["donate_argnames"] = ("state",)
+
         # every jitted step carries a function name of its own, so the
         # profiler's ``XLA Modules`` line reads ``jit_ragged_step(...)``
         # and not ``jit__unknown(...)`` (a partial has no ``__name__``)
         self._step = jax.jit(
             _named("ragged_step", ragged_forward, cfg=mc,
                    block_size=self.cfg.block_size),
-            donate_argnums=(1, 2))
+            **dict(donate, donate_argnums=(1, 2) if self.state is None
+                   else (1, 2, 10)))
         # sampled variant: mixed prefill/decode steps fetch [max_seqs] int32
         # tokens instead of full [max_seqs, V] logits (ref Weak: v2 prefill
         # loop host-bound — sampling now happens on device for BOTH phases)
         self._step_sampled = jax.jit(
             _named("ragged_step_sampled", ragged_forward_sampled, cfg=mc,
                    block_size=self.cfg.block_size),
-            static_argnames=("greedy", "top_k"),
-            donate_argnums=(1, 2))
+            static_argnames=("greedy", "top_k"), **donate)
         self._decode_loop = jax.jit(
             _named("ragged_decode_loop", ragged_decode_loop, cfg=mc,
                    block_size=self.cfg.block_size),
-            static_argnames=("n_steps", "greedy", "top_k"),
-            donate_argnums=(1, 2))
+            static_argnames=("n_steps", "greedy", "top_k"), **donate)
         # speculative-decoding verify-k: same argument tuple as _step, but
         # the greedy argmax comes back for EVERY token row ([T] int32), so
         # one ragged dispatch scores a whole batch of draft proposals
@@ -312,7 +362,53 @@ class InferenceEngineV2:
         log_dist(f"InferenceEngineV2: budget={self.cfg.max_ragged_batch_size} "
                  f"blocks={self.cfg.num_blocks}×{self.cfg.block_size} "
                  f"max_seqs={self.cfg.max_tracked_sequences} tp={self.cfg.tp_size} "
-                 f"attention={self.attention_impl}")
+                 f"attention={self.attention_impl}"
+                 + (f" ssm={self.ssm_impl} state="
+                    f"{self.state_bytes / 2**20:.0f}MiB"
+                    if self.state is not None else ""))
+
+    # -- recurrent state (a model with an SSM mixer) -------------------
+    @property
+    def tracer(self):
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tr) -> None:
+        """The serving layer hands its tracer over after construction:
+        the first enabled one is told of the state's allocation (span
+        ``v2.state_alloc``, at the time it happened)."""
+        self._tracer = tr
+        if tr is not None and tr.enabled and self._state_alloc is not None:
+            alloc, self._state_alloc = self._state_alloc, None
+            tr.complete("v2.state_alloc", alloc.pop("ts"), alloc.pop("dur"),
+                        **alloc)
+
+    @property
+    def state_bytes(self) -> int:
+        """Device bytes of the recurrent state slots (0 without a mixer):
+        what the engine holds beside the weights and the KV pool."""
+        if self.state is None:
+            return 0
+        return sum(int(a.nbytes) for a in jax.tree.leaves(self.state))
+
+    def _refuse_recurrent(self, what: str) -> None:
+        if self.state is not None:
+            raise RecurrentStateUnsupported(
+                f"{what} needs state snapshots: this model's Mamba-2 SSM "
+                "mixer keeps recurrent state per sequence, which is only "
+                "ever the state after the last row run — there is no copy "
+                "of it at an earlier position to adopt, rewind to or ship")
+
+    def _carried(self, out):
+        """Rebind what a step carries (the KV pools, and the recurrent
+        state after them where there is one); the rest of ``out``."""
+        if self.state is not None:
+            *out, self.state = out
+        *out, self.cache_k, self.cache_v = out
+        return out[0] if len(out) == 1 else out
+
+    def _state_kw(self) -> Dict[str, Any]:
+        return {} if self.state is None else {"state": self.state}
 
     # ------------------------------------------------------------------
     def _ragged_step(self, batch_uids: Sequence[int],
@@ -386,9 +482,13 @@ class InferenceEngineV2:
         nb_bucket = min(nb_bucket, self.state_manager.max_blocks_per_seq)
         if sp is not None:
             # build_ragged_batch advanced num_cached past the new tokens
-            sp.end(**step_counts(
-                [(seq.num_cached - n, n) for seq, n in schedule],
-                self.model_config.sliding_window, self._query_block))
+            items = [(seq.num_cached - n, n) for seq, n in schedule]
+            counts = step_counts(items, self.model_config.sliding_window,
+                                 self._query_block)
+            if self.state is not None:
+                counts.update(ssm_step_counts(
+                    items, self._slot_bytes, self.state_manager.n_active))
+            sp.end(**counts)
         host = (rb.token_ids[:t_bucket], rb.token_slot[:t_bucket],
                 rb.token_pos[:t_bucket], rb.token_dest[:t_bucket],
                 rb.block_tables[:, :nb_bucket], rb.ctx_lens, rb.logits_idx)
@@ -411,14 +511,14 @@ class InferenceEngineV2:
               if tr is not None else None)
         try:
             if sample is None:
-                out, self.cache_k, self.cache_v = self._step(*args)
+                out = self._carried(self._step(*args, **self._state_kw()))
             else:
-                out, self.cache_k, self.cache_v = self._step_sampled(
+                out = self._carried(self._step_sampled(
                     *args, key=sample["key"],
                     temperature=jnp.float32(max(sample["temperature"],
                                                 1e-6)),
                     greedy=greedy, top_k=sample.get("top_k", 0),
-                    top_p=sample.get("top_p"))
+                    top_p=sample.get("top_p"), **self._state_kw()))
         finally:
             if sp is not None:
                 sp.end(t_bucket=t_bucket, nb_bucket=nb_bucket,
@@ -436,6 +536,8 @@ class InferenceEngineV2:
         if phase not in ("decode", "prefill", "verify"):
             raise ValueError(f"audit_step_args: unknown phase {phase!r} "
                              "(decode|prefill|verify)")
+        if phase == "verify":
+            self._refuse_recurrent("the speculative verify step")
         sm = self.state_manager
         t = (min(16, self.scheduler.token_budget) if phase == "decode"
              else self.scheduler.token_budget)
@@ -445,6 +547,8 @@ class InferenceEngineV2:
                            jnp.int32)
         args = (self.params, self.cache_k, self.cache_v,
                 ids, ids, ids, ids, tables, rows, rows)
+        if self.state is not None:
+            args += (self.state,)
         return (self._verify if phase == "verify" else self._step), args
 
     def audit_arg_categories(self):
@@ -454,7 +558,8 @@ class InferenceEngineV2:
         classed ``other``), and the ragged index arrays."""
         return ("params", "other", "other",
                 "activations", "activations", "activations", "activations",
-                "other", "other", "other")
+                "other", "other", "other") + (
+                    ("other",) if self.state is not None else ())
 
     def put(self, batch_uids: Sequence[int],
             batch_tokens: Sequence[Sequence[int]]) -> Dict[int, np.ndarray]:
@@ -490,6 +595,9 @@ class InferenceEngineV2:
             raise ValueError(f"uid {uid} already active")
         if not len(tokens):
             raise ValueError(f"uid {uid}: empty prompt")
+        if num_cached or cached_blocks:
+            self._refuse_recurrent("adopting cached prefix pages "
+                                   f"(num_cached={num_cached})")
         self.state_manager.open(uid, [int(x) for x in tokens],
                                 cached_blocks=cached_blocks,
                                 num_cached=num_cached)
@@ -596,9 +704,16 @@ class InferenceEngineV2:
         importable: two engines with the same geometry (and the shared
         same-seed weight contract) hold interchangeable KV pages."""
         mc = self.model_config
-        return (mc.num_layers, mc.kv_heads, self.cfg.block_size,
+        geom = (mc.num_layers, mc.kv_heads, self.cfg.block_size,
                 mc.dim_per_head, str(self.cfg.kv_dtype),
                 str(self.model_config.dtype))
+        if self.state is not None:
+            # pages alone do not make a sequence here: the fingerprint
+            # says so, with the bytes of one sequence's recurrent slot
+            geom += (("recurrent_state_bytes_per_seq",
+                      self.state_bytes // (self.cfg.max_tracked_sequences
+                                           + 1)),)
+        return geom
 
     def export_kv_chain(self, uid: int) -> Optional[Dict[str, Any]]:
         """Read the FULL KV pages of a live sequence's written prefix —
@@ -613,6 +728,8 @@ class InferenceEngineV2:
         """
         import time as _time
 
+        self._refuse_recurrent("exporting a sequence's KV pages for "
+                               "hand-off")
         t0 = _time.perf_counter()
         seq = self.state_manager.get(uid)
         bs = self.cfg.block_size
@@ -643,6 +760,7 @@ class InferenceEngineV2:
         to re-running prefill) and ``KVCacheExhausted`` when the pool
         cannot host the tail.  Engine-owning thread only.
         """
+        self._refuse_recurrent("importing handed-off KV pages")
         if tuple(payload["geom"]) != self.kv_geometry():
             raise ValueError(
                 f"handoff payload geometry {payload['geom']} does not "
@@ -710,6 +828,7 @@ class InferenceEngineV2:
         from absolute positions, and attention masks by ``ctx_lens``).
         Raises ``KVCacheExhausted`` with every sequence rolled back.
         """
+        self._refuse_recurrent("verify_step (speculative decoding)")
         mgr = self.state_manager
         # validate the WHOLE batch before touching any state: a bad
         # entry must not leave earlier sequences carrying unverified
@@ -788,6 +907,7 @@ class InferenceEngineV2:
         shrink — garbage KV beyond it is overwritten when those
         positions are legitimately re-run.  Allocated pages stay with
         the sequence (capacity, not content)."""
+        self._refuse_recurrent("rewind")
         seq = self.state_manager.get(uid)
         if num_cached > seq.num_cached:
             raise ValueError(
@@ -956,12 +1076,12 @@ class InferenceEngineV2:
             seq = mgr.get(u)
             tables[seq.slot, :len(seq.blocks)] = seq.blocks
 
-        sampled, _, self.cache_k, self.cache_v = self._decode_loop(
+        sampled, _ = self._carried(self._decode_loop(
             self.params, self.cache_k, self.cache_v,
             self._put(tokens0), self._put(ctx0), self._put(active),
             self._put(tables), key, jnp.float32(max(temperature, 1e-6)),
             n_steps=chunk, greedy=(temperature <= 0),
-            top_k=top_k, top_p=top_p)
+            top_k=top_k, top_p=top_p, **self._state_kw()))
         sampled = np.asarray(sampled)  # [chunk, s_rows]
         for u in uids:
             seq = mgr.get(u)

@@ -33,6 +33,7 @@ from deepspeed_tpu.inference.v2.modules import (register_module, resolve,
 from deepspeed_tpu.models.transformer import (TransformerConfig, _mlp_block,
                                               _norm)
 from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
+from deepspeed_tpu.ops.pallas.ssd_ragged import run_layout, ssd_ragged
 from deepspeed_tpu.utils.platform import on_tpu
 
 
@@ -197,24 +198,149 @@ def _paged_attention(q, k_pages, v_pages, gather_idx, token_pos, token_ctx_len,
                 cfg, block_tables, token_slot, block_size)
 
 
+@register_module("ssm", "ssd_pallas",
+                 default_for=lambda on_tpu=False, **_: on_tpu)
+def _ssm_impl_pallas(*args, layer, chunk):
+    """The chunked scan kernel (ops/pallas/ssd_ragged.py): a run's state
+    read once from its slot and written back in place."""
+    return ssd_ragged(*args, layer=layer, impl="pallas", chunk=chunk)
+
+
+@register_module("ssm", "ssd_xla")
+def _ssm_impl_xla(*args, layer, chunk):
+    """The plain-XLA scan: a starting state per row — the CPU's, and a
+    test's; too large at published widths."""
+    return ssd_ragged(*args, layer=layer, impl="xla")
+
+
+def ssm_impl_name(cfg: TransformerConfig) -> str:
+    """The scan a model with a mixer runs: the kernel on a TPU, the XLA
+    formulation elsewhere; ``cfg.v2_modules`` pins a name."""
+    name = dict(cfg.v2_modules or ()).get("ssm", "auto")
+    return resolve_name("ssm", name, on_tpu=on_tpu())
+
+
+def new_ssm_state(cfg: TransformerConfig, max_seqs: int, zeros=jnp.zeros):
+    """The per-sequence slots of a model with a mixer, zeroed: ``ssm``
+    [L, max_seqs + 1, heads, head_dim, state] float32 and ``conv``
+    [L, max_seqs + 1, kernel - 1, channels] in the compute dtype (the
+    last inputs of the depthwise convolution, oldest first; channels
+    minor, so a row is whole lanes).  Slot ``max_seqs`` is the padding
+    rows' garbage slot."""
+    m = cfg.ssm
+    return {
+        "ssm": zeros((cfg.num_layers, max_seqs + 1, m.num_heads, m.head_dim,
+                      m.state_size), jnp.float32),
+        "conv": zeros((cfg.num_layers, max_seqs + 1, m.conv_kernel - 1,
+                       m.conv_dim), cfg.dtype),
+    }
+
+
+def _ragged_mixer(h, p, state, ssm_meta, cfg: TransformerConfig):
+    """The Mamba-2 mixer over the flat rows ``h`` [T, H] of a step, which
+    are runs of consecutive rows of one sequence each: a row's
+    predecessors in the convolution and in the scan are its own run's,
+    and before the run's start its slot's (zeros where the run starts at
+    position 0).  ``state``: ``ssm``, EVERY layer's recurrent slots (the
+    scan over layers carries the array whole, so that the kernel updates
+    it in place), ``layer``, this one's index in it, and ``conv``, this
+    layer's tails; returns (out [T, H], state')."""
+    m = cfg.ssm
+    slot, token_pos, run_start, from_zero, is_last = ssm_meta
+    dt_ = h.dtype
+    f32 = jnp.float32
+    t = h.shape[0]
+    k = m.conv_kernel
+    pad = state["conv"].shape[0] - 1
+
+    mup = jnp.concatenate([jnp.full((n,), v, dt_) for n, v in
+                           zip(m.part_sizes(), m.ssm_multipliers)])
+    proj = ((h * m.ssm_in_multiplier) @ p["in_proj"].astype(dt_)) * mup
+    z, xbc, dt = jnp.split(proj, [m.d_ssm, m.d_ssm + m.conv_dim], axis=-1)
+
+    # depthwise causal convolution over each run: the input d rows back is
+    # the row d above where the run reaches that far, else the slot's tail
+    tail = jnp.where(from_zero[:, None, None], 0,
+                     state["conv"][slot]).astype(dt_)       # [T, k-1, C]
+    in_run = jnp.arange(t, dtype=jnp.int32) - run_start
+    back = []                                   # d = k-1 .. 1 rows back
+    for d in range(k - 1, 0, -1):
+        v = jnp.roll(xbc, d, axis=0)
+        for j in range(d):                      # the run is j rows old
+            v = jnp.where((in_run == j)[:, None], tail[:, k - 1 - d + j], v)
+        back.append(v)
+    w = p["conv_w"].astype(f32)                              # [C, k]
+    conv = xbc.astype(f32) * w[:, k - 1]
+    for j, v in enumerate(back):
+        conv = conv + v.astype(f32) * w[:, j]
+    if "conv_b" in p:
+        conv = conv + p["conv_b"].astype(f32)
+    new_tail = jnp.stack(back[1:] + [xbc], axis=1)           # [T, k-1, C]
+    conv_state = state["conv"].at[jnp.where(is_last, slot, pad)].set(
+        new_tail.astype(state["conv"].dtype))
+    xbc = jax.nn.silu(conv).astype(dt_)
+
+    gn = m.n_groups * m.state_size
+    x = xbc[:, :m.d_ssm].reshape(t, m.num_heads, m.head_dim)
+    b = xbc[:, m.d_ssm:m.d_ssm + gn].reshape(t, m.n_groups, m.state_size)
+    c = xbc[:, m.d_ssm + gn:].reshape(t, m.n_groups, m.state_size)
+    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+    a = -jnp.exp(p["A_log"].astype(f32))
+    y, ssm_state = resolve("ssm", ssm_impl_name(cfg))(
+        x, dt, a, b, c, state["ssm"], slot, token_pos, layer=state["layer"],
+        chunk=m.chunk_size)
+    y = y + p["D"].astype(f32)[None, :, None] * x.astype(f32)
+
+    # gated RMSNorm, the gate first, the mean square over each group
+    y = y.reshape(t, m.d_ssm) * jax.nn.silu(z.astype(f32))
+    yg = y.reshape(t, m.n_groups, -1)
+    yg = yg * lax.rsqrt(jnp.mean(jnp.square(yg), axis=-1, keepdims=True)
+                        + cfg.layernorm_eps)
+    y = (yg.reshape(t, m.d_ssm) * p["norm"].astype(f32)).astype(dt_)
+    return (y @ p["out_proj"].astype(dt_),
+            {"ssm": ssm_state, "conv": conv_state, "layer": state["layer"]})
+
+
+def _ssm_meta(cfg: TransformerConfig, state, token_slot, token_pos):
+    """What every layer's mixer needs of the step's row layout, once."""
+    if cfg.ssm is None:
+        return None
+    if state is None:
+        raise ValueError(
+            "this model has a Mamba-2 SSM mixer: the ragged step needs its "
+            "per-sequence state slots (state=new_ssm_state(...)); a caller "
+            "that keeps none (inference.kv_generate) cannot run it")
+    if cfg.is_moe or cfg.alt_window or cfg.parallel_block:
+        raise NotImplementedError(
+            "an SSM mixer beside attention is served in the plain "
+            "sequential block only (no MoE, alt_window or parallel_block)")
+    return (token_slot, token_pos) + run_layout(token_slot, token_pos)
+
+
 def _ragged_layer(x, lp, k_pages, v_pages, meta, cfg: TransformerConfig,
-                  layer_is_moe=False):
-    """One block over flat tokens [T, H]; scatters KV, attends via pages."""
+                  layer_is_moe=False, state=None, ssm_meta=None):
+    """One block over flat tokens [T, H]; scatters KV, attends via pages.
+    Returns (x, k_pages, v_pages, state): ``state`` is the layer's
+    recurrent slots where the block has an SSM mixer, else None."""
     (token_pos, token_dest, gather_idx, token_ctx_len, token_slot,
      block_tables, block_size) = meta
+    mixer = cfg.ssm
     t = x.shape[0]
     nh, nkv, d = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
     dt = x.dtype
 
     h = _norm(x, lp["ln1"], cfg)
+    h_attn = h * mixer.attention_in_multiplier if mixer else h
 
     def proj(w, b_):
-        y = h @ w.astype(dt)
+        y = h_attn @ w.astype(dt)
         return y + b_.astype(dt) if b_ is not None else y
 
     q = proj(lp["attn"]["wq"], lp["attn"].get("bq")).reshape(t, nh, d)
     k = proj(lp["attn"]["wk"], lp["attn"].get("bk")).reshape(t, nkv, d)
     v = proj(lp["attn"]["wv"], lp["attn"].get("bv")).reshape(t, nkv, d)
+    if mixer:
+        k = k * mixer.key_multiplier
     if cfg.use_rope:
         q = _rope_tok(q, token_pos, cfg)
         k = _rope_tok(k, token_pos, cfg)
@@ -232,19 +358,25 @@ def _ragged_layer(x, lp, k_pages, v_pages, meta, cfg: TransformerConfig,
     attn = attn.reshape(t, nh * d) @ lp["attn"]["wo"].astype(dt)
     if lp["attn"].get("bo") is not None:
         attn = attn + lp["attn"]["bo"].astype(dt)
+    if mixer:
+        # the mixer reads the same normed input, beside attention
+        mix, state = _ragged_mixer(h, lp["ssm"], state, ssm_meta, cfg)
+        attn = (attn * mixer.attention_out_multiplier
+                + mix * mixer.ssm_out_multiplier)
 
     if cfg.parallel_block:
         # Falcon/Phi: attention and MLP read the shared input norm;
         # Falcon-40B/GPT-NeoX (parallel_norms): the MLP gets its own
         # ln2 on the same residual input (HF use_parallel_residual)
         h_mlp = _norm(x, lp["ln2"], cfg) if cfg.parallel_norms else h
-        return x + attn + _mlp_block(h_mlp, lp["mlp"], cfg), k_pages, v_pages
+        return (x + attn + _mlp_block(h_mlp, lp["mlp"], cfg), k_pages,
+                v_pages, state)
 
     x = x + attn
 
     h2 = _norm(x, lp["ln2"], cfg)
     if "moe" not in lp:
-        return x + _mlp_block(h2, lp["mlp"], cfg), k_pages, v_pages
+        return x + _mlp_block(h2, lp["mlp"], cfg), k_pages, v_pages, state
 
     from deepspeed_tpu.moe.sharded_moe import moe_forward, moe_forward_ep
     from deepspeed_tpu.parallel.topology import get_topology
@@ -281,20 +413,27 @@ def _ragged_layer(x, lp, k_pages, v_pages, meta, cfg: TransformerConfig,
         y = moe_branch(h2) if layer_is_moe else dense_branch(h2)
     else:
         y = lax.cond(layer_is_moe, moe_branch, dense_branch, h2)
-    return x + y, k_pages, v_pages
+    return x + y, k_pages, v_pages, state
 
 
 def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
                    token_dest, block_tables, ctx_lens, logits_idx,
-                   cfg: TransformerConfig,
-                   block_size: int) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                   state=None, *, cfg: TransformerConfig, block_size: int,
+                   state_slot=None):
     """One ragged step.
 
     cache_k/cache_v: [L, P, nkv, d]; block_tables: [S+1, NB]; returns
-    (logits [S+1, V], cache_k', cache_v').
+    (logits [S+1, V], cache_k', cache_v').  A model with an SSM mixer
+    (``cfg.ssm``) also takes ``state``, its recurrent slots
+    (:func:`new_ssm_state`), and returns them fourth; ``state_slot`` [T]
+    names each row's slot where that is not ``token_slot``.
     """
     dt = cfg.dtype
+    ssm_meta = _ssm_meta(cfg, state, token_slot if state_slot is None
+                         else state_slot, token_pos)
     x = params["embed"]["tokens"].astype(dt)[token_ids]  # [T, H]
+    if cfg.ssm:
+        x = x * cfg.ssm.embedding_multiplier
     if cfg.has_learned_positions and "positions" in params["embed"]:
         # gpt2/opt/gpt-neo learned positions (OPT's +2 offset is already
         # stripped at conversion, so token_pos indexes directly)
@@ -330,7 +469,7 @@ def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
             for j in range(2):
                 sub = jax.tree.map(lambda p, j=j: p[j], lp)
                 lcfg = cfg if j % 2 else cfg.replace(sliding_window=None)
-                h, ck_j, cv_j = _ragged_layer(
+                h, ck_j, cv_j, _ = _ragged_layer(
                     h, sub, jax.tree.map(lambda c, j=j: c[j], ck_l),
                     jax.tree.map(lambda c, j=j: c[j], cv_l), meta, lcfg)
                 ck_out.append(ck_j)
@@ -349,7 +488,13 @@ def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
         cache_k, cache_v = unpair(cache_k), unpair(cache_v)
     else:
         def body(h, scanned):
-            lp, ck_l, cv_l, idx = scanned
+            lp, ck_l, cv_l, idx, conv_l = scanned
+            st_l = None
+            if cfg.ssm:
+                # the recurrent slots ride the carry whole (sliced as xs
+                # and stacked as ys they would be copied every step)
+                h, ssm = h
+                st_l = {"ssm": ssm, "conv": conv_l, "layer": idx}
             if not cfg.is_moe:
                 is_moe_layer = False
             elif moe_every == 1:
@@ -358,13 +503,24 @@ def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
                 is_moe_layer = True
             else:
                 is_moe_layer = (idx % moe_every) == (moe_every - 1)
-            h, ck_l, cv_l = _ragged_layer(h, lp, ck_l, cv_l, meta, cfg,
-                                          layer_is_moe=is_moe_layer)
-            return h, (ck_l, cv_l)
+            h, ck_l, cv_l, st_l = _ragged_layer(
+                h, lp, ck_l, cv_l, meta, cfg, layer_is_moe=is_moe_layer,
+                state=st_l, ssm_meta=ssm_meta)
+            if cfg.ssm:
+                return (h, st_l["ssm"]), (ck_l, cv_l, st_l["conv"])
+            return h, (ck_l, cv_l, None)
 
         layer_idx = jnp.arange(cfg.num_layers)
-        x, (cache_k, cache_v) = lax.scan(
-            body, x, (params["layers"], cache_k, cache_v, layer_idx))
+        if cfg.ssm:
+            (x, ssm), (cache_k, cache_v, conv) = lax.scan(
+                body, (x, state["ssm"]),
+                (params["layers"], cache_k, cache_v, layer_idx,
+                 state["conv"]))
+            state = {"ssm": ssm, "conv": conv}
+        else:
+            x, (cache_k, cache_v, _) = lax.scan(
+                body, x, (params["layers"], cache_k, cache_v, layer_idx,
+                          None))
 
     x = _norm(x, params["final_norm"], cfg)
     last = x[logits_idx]  # [S+1, H] — ref: logits_gather
@@ -372,6 +528,9 @@ def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
         logits = last @ params["embed"]["tokens"].astype(dt).T
     else:
         logits = last @ params["lm_head"].astype(dt)
+    if cfg.ssm:
+        logits = logits * cfg.ssm.lm_head_multiplier
+        return logits.astype(jnp.float32), cache_k, cache_v, state
     return logits.astype(jnp.float32), cache_k, cache_v
 
 
@@ -399,6 +558,11 @@ def ragged_forward_verify(params, cache_k, cache_v, token_ids, token_slot,
     ``ragged_forward``.
     """
     del logits_idx
+    if cfg.ssm is not None:
+        raise NotImplementedError(
+            "speculative verify needs state snapshots: a rejected draft "
+            "row has already advanced the Mamba-2 SSM mixer's recurrent "
+            "state, and no copy of the state before it is kept")
     dt = cfg.dtype
     x = params["embed"]["tokens"].astype(dt)[token_ids]
     if cfg.has_learned_positions and "positions" in params["embed"]:
@@ -421,7 +585,7 @@ def ragged_forward_verify(params, cache_k, cache_v, token_ids, token_slot,
 
     def body(h, scanned):
         lp, ck_l, cv_l, _idx = scanned
-        h, ck_l, cv_l = _ragged_layer(h, lp, ck_l, cv_l, meta, cfg)
+        h, ck_l, cv_l, _ = _ragged_layer(h, lp, ck_l, cv_l, meta, cfg)
         return h, (ck_l, cv_l)
 
     layer_idx = jnp.arange(cfg.num_layers)
@@ -483,29 +647,27 @@ def ragged_forward_sampled(params, cache_k, cache_v, token_ids, token_slot,
                            token_pos, token_dest, block_tables, ctx_lens,
                            logits_idx, key, temperature,
                            cfg: TransformerConfig, block_size: int,
-                           greedy: bool, top_k: int = 0, top_p=None
-                           ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                           greedy: bool, top_k: int = 0, top_p=None,
+                           state=None):
     """Ragged step + ON-DEVICE sampling: the host receives [S+1] int32
     tokens instead of [S+1, V] logits.  Same sampling semantics as the
     fused decode loop (greedy argmax / temperature categorical with
     optional top-k/top-p), so a generation that alternates prefill and
-    decode phases stays consistent.
+    decode phases stays consistent.  ``state``: as ``ragged_forward``.
     """
-    logits, cache_k, cache_v = ragged_forward(
+    logits, *carried = ragged_forward(
         params, cache_k, cache_v, token_ids, token_slot, token_pos,
-        token_dest, block_tables, ctx_lens, logits_idx, cfg=cfg,
+        token_dest, block_tables, ctx_lens, logits_idx, state, cfg=cfg,
         block_size=block_size)
     nxt = sample_tokens(logits, key, temperature, greedy, top_k, top_p)
-    return nxt, cache_k, cache_v
+    return (nxt, *carried)
 
 
 def ragged_decode_loop(params, cache_k, cache_v, tokens0, ctx_lens0,
                        active, block_tables, key, temperature,
                        cfg: TransformerConfig, block_size: int,
                        n_steps: int, greedy: bool, top_k: int = 0,
-                       top_p=None
-                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
-                                  jnp.ndarray]:
+                       top_p=None, state=None):
     """Fused multi-step decode: ``lax.scan`` over ``n_steps`` single-token
     steps with on-device sampling — ONE dispatch for the whole decode
     phase, so per-step host/driver latency is paid once instead of per
@@ -515,28 +677,36 @@ def ragged_decode_loop(params, cache_k, cache_v, tokens0, ctx_lens0,
     already in cache; active [S] bool; block_tables [S, NB] preallocated
     for the full horizon.  Returns (sampled [n_steps, S], ctx_lens',
     cache_k', cache_v').  Slot s's row in ``sampled`` is garbage where
-    ``active[s]`` is False.
+    ``active[s]`` is False.  ``state``: a mixer's recurrent slots, as
+    ``ragged_forward`` takes them (returned fifth); an inactive row is
+    the garbage slot's.
     """
     s_rows = block_tables.shape[0]
     slots = jnp.arange(s_rows, dtype=jnp.int32)
     act_i = active.astype(jnp.int32)
+    state_slot = None
+    if state is not None:
+        state_slot = jnp.where(active, slots, state["ssm"].shape[1] - 1)
 
     def step(carry, step_key):
-        tokens, ctx_lens, ck, cv = carry
+        tokens, ctx_lens, ck, cv, st = carry
         pos = ctx_lens  # 0-based position of the incoming token
         dest = block_tables[slots, pos // block_size] * block_size \
             + pos % block_size
         dest = jnp.where(active, dest, 0)  # inactive → garbage page 0
         ctx_after = ctx_lens + act_i
-        logits, ck, cv = ragged_forward(
+        logits, ck, cv, *st = ragged_forward(
             params, ck, cv, tokens, slots, pos, dest, block_tables,
-            ctx_after, slots, cfg=cfg, block_size=block_size)
+            ctx_after, slots, st, cfg=cfg, block_size=block_size,
+            state_slot=state_slot)
         nxt = sample_tokens(logits, step_key, temperature, greedy, top_k,
                             top_p)
         nxt = jnp.where(active, nxt, 0)
-        return (nxt, ctx_after, ck, cv), nxt
+        return (nxt, ctx_after, ck, cv, st[0] if st else None), nxt
 
     keys = jax.random.split(key, n_steps)
-    (tokens, ctx_lens, cache_k, cache_v), sampled = lax.scan(
-        step, (tokens0, ctx_lens0, cache_k, cache_v), keys)
+    (tokens, ctx_lens, cache_k, cache_v, state), sampled = lax.scan(
+        step, (tokens0, ctx_lens0, cache_k, cache_v, state), keys)
+    if state is not None:
+        return sampled, ctx_lens, cache_k, cache_v, state
     return sampled, ctx_lens, cache_k, cache_v

@@ -130,6 +130,17 @@ class DSStateManager:
 
     ``max_seqs`` bounds concurrent sequences (device block-table rows);
     ``max_blocks_per_seq`` bounds context length per sequence.
+
+    A sequence holds two kinds of state: its KV pages (``blocks``, from
+    the allocator) and its ``slot``, which besides a block-table row is
+    the index of its recurrent state where the model has an SSM mixer
+    (``engine.state``; row ``max_seqs`` is the padding rows' garbage
+    slot, as block 0 is the garbage page).  A slot needs no clearing when
+    it is handed on: a sequence's first row is at position 0, and a run
+    that starts there starts from zero state inside the step.  So
+    ``flush`` frees both with no device work, and a preempted sequence
+    recomputes from zeros.  ``open(num_cached > 0)`` would start a
+    sequence past position 0: the engine refuses it for such a model.
     """
 
     def __init__(self, max_seqs: int, num_blocks: int, block_size: int,

@@ -8,7 +8,7 @@ converter*: ``config_from_hf`` maps an HF config to a
 the stacked functional param tree, after which every subsystem (engine,
 AutoTP, ZeRO, inference v1/v2) consumes the model like any other.
 
-Supported families: gpt2, llama, mistral, qwen, qwen2, mixtral, qwen2_moe,
+Supported families: gpt2, llama, mistral, falcon_h1, qwen, qwen2, mixtral, qwen2_moe,
 opt, falcon, phi, phi3 — the same set as the reference's v2 model
 implementations (MoE included) — plus the v1-injection families
 bloom (ALiBi), gptj (interleaved rotary), gpt_neox, and the encoder
@@ -103,6 +103,49 @@ def config_from_hf(hf_config) -> TransformerConfig:
             sliding_window=getattr(hf_config, "sliding_window", None)
             if mt == "mistral" else None,
             layernorm_eps=hf_config.rms_norm_eps, **moe_kw)
+    if mt == "falcon_h1":
+        # a Mamba-2 mixer beside attention in every block + muP
+        # multipliers (transformers models/falcon_h1)
+        from deepspeed_tpu.models.transformer import SSMConfig
+
+        c = hf_config
+        if getattr(c, "mamba_proj_bias", False) or getattr(
+                c, "projectors_bias", False) or getattr(
+                    c, "attention_bias", False) or getattr(c, "mlp_bias",
+                                                           False):
+            raise NotImplementedError("falcon_h1 with projection biases")
+        if not getattr(c, "mamba_rms_norm", True) or getattr(
+                c, "mamba_norm_before_gate", False):
+            raise NotImplementedError(
+                "falcon_h1: only the gated RMSNorm with the gate first "
+                "(mamba_rms_norm, not mamba_norm_before_gate) is served")
+        d_ssm = c.mamba_d_ssm or int(c.mamba_expand * c.hidden_size)
+        return TransformerConfig(
+            vocab_size=c.vocab_size, hidden_size=c.hidden_size,
+            intermediate_size=c.intermediate_size,
+            num_layers=c.num_hidden_layers,
+            num_heads=c.num_attention_heads,
+            num_kv_heads=c.num_key_value_heads,
+            head_dim=getattr(c, "head_dim", None),
+            max_seq_len=c.max_position_embeddings, arch="falcon_h1",
+            norm="rmsnorm", activation="swiglu", use_rope=True,
+            use_bias=False, rope_theta=float(c.rope_theta),
+            tie_embeddings=bool(c.tie_word_embeddings),
+            layernorm_eps=c.rms_norm_eps,
+            ssm=SSMConfig(
+                num_heads=c.mamba_n_heads, head_dim=d_ssm // c.mamba_n_heads,
+                state_size=c.mamba_d_state, n_groups=c.mamba_n_groups,
+                conv_kernel=c.mamba_d_conv, chunk_size=c.mamba_chunk_size,
+                conv_bias=bool(c.mamba_conv_bias),
+                embedding_multiplier=float(c.embedding_multiplier),
+                lm_head_multiplier=float(c.lm_head_multiplier),
+                attention_in_multiplier=float(c.attention_in_multiplier),
+                attention_out_multiplier=float(c.attention_out_multiplier),
+                key_multiplier=float(c.key_multiplier),
+                ssm_in_multiplier=float(c.ssm_in_multiplier),
+                ssm_out_multiplier=float(c.ssm_out_multiplier),
+                ssm_multipliers=tuple(float(v) for v in c.ssm_multipliers),
+                mlp_multipliers=tuple(float(v) for v in c.mlp_multipliers)))
     if mt == "phi3":
         # llama-family numerics with fused qkv_proj / gate_up_proj weights
         # (ref inference/v2/model_implementations/phi3)
@@ -441,6 +484,42 @@ def _convert_llama(sd, cfg):
     if not cfg.tie_embeddings:
         lm = sd.get("lm_head.weight", sd["model.embed_tokens.weight"])
         out["lm_head"] = lm.T
+    return out
+
+
+def _convert_falcon_h1(sd, cfg):
+    """HF ``falcon_h1``: ``mamba.*`` beside ``self_attn.*`` in every
+    layer, ``feed_forward.*``, ``input_layernorm`` / ``pre_ff_layernorm``,
+    ``model.final_layernorm``."""
+    layers = []
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        ssm = {"in_proj": sd[p + "mamba.in_proj.weight"].T,
+               "conv_w": sd[p + "mamba.conv1d.weight"][:, 0, :],
+               "dt_bias": sd[p + "mamba.dt_bias"],
+               "A_log": sd[p + "mamba.A_log"],
+               "D": sd[p + "mamba.D"],
+               "norm": sd[p + "mamba.norm.weight"],
+               "out_proj": sd[p + "mamba.out_proj.weight"].T}
+        if cfg.ssm.conv_bias:
+            ssm["conv_b"] = sd[p + "mamba.conv1d.bias"]
+        layers.append({
+            "attn": {"wq": sd[p + "self_attn.q_proj.weight"].T,
+                     "wk": sd[p + "self_attn.k_proj.weight"].T,
+                     "wv": sd[p + "self_attn.v_proj.weight"].T,
+                     "wo": sd[p + "self_attn.o_proj.weight"].T},
+            "ssm": ssm,
+            "mlp": {"wg": sd[p + "feed_forward.gate_proj.weight"].T,
+                    "wi": sd[p + "feed_forward.up_proj.weight"].T,
+                    "wo": sd[p + "feed_forward.down_proj.weight"].T},
+            "ln1": {"scale": sd[p + "input_layernorm.weight"]},
+            "ln2": {"scale": sd[p + "pre_ff_layernorm.weight"]},
+        })
+    out = {"embed": {"tokens": sd["model.embed_tokens.weight"]},
+           "layers": _stack(layers),
+           "final_norm": {"scale": sd["model.final_layernorm.weight"]}}
+    if not cfg.tie_embeddings:
+        out["lm_head"] = sd["lm_head.weight"].T
     return out
 
 
@@ -890,6 +969,7 @@ def load_hf_model(name_or_model, dtype=None):
 for _arch, _fn in (("gpt2", _convert_gpt2), ("llama", _convert_llama),
                    ("mistral", _convert_llama), ("qwen2", _convert_llama),
                    ("opt", _convert_opt), ("falcon", _convert_falcon),
+                   ("falcon_h1", _convert_falcon_h1),
                    ("phi", _convert_phi), ("phi3", _convert_phi3),
                    ("qwen", _convert_qwen), ("bert", _convert_bert),
                    ("distilbert", _convert_distilbert),

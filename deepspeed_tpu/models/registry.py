@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.transformer import TransformerConfig
+from deepspeed_tpu.models.transformer import SSMConfig, TransformerConfig
 
 _REGISTRY = {}
 
@@ -133,6 +133,42 @@ register("falcon-7b", TransformerConfig(
     num_layers=32, num_heads=71, num_kv_heads=1, max_seq_len=2048,
     arch="falcon", norm="layernorm", activation="gelu", use_rope=True,
     tie_embeddings=True, parallel_block=True, use_bias=False))
+
+# -- Falcon-H1 (HF falcon_h1): Mamba-2 heads beside attention heads in
+# every block, muP multipliers; tiiuae/Falcon-H1-34B-Instruct config.json
+_falcon_h1 = dict(arch="falcon_h1", norm="rmsnorm", activation="swiglu",
+                  use_rope=True, tie_embeddings=False, use_bias=False)
+
+register("falcon-h1-34b", TransformerConfig(
+    vocab_size=261120, hidden_size=5120, intermediate_size=21504,
+    num_layers=72, num_heads=20, num_kv_heads=4, head_dim=128,
+    max_seq_len=262144, rope_theta=1e11, layernorm_eps=1e-5,
+    ssm=SSMConfig(
+        num_heads=32, head_dim=128, state_size=256, n_groups=2,
+        conv_kernel=4, chunk_size=128, conv_bias=True,
+        embedding_multiplier=5.656854249492381,
+        lm_head_multiplier=0.0078125,
+        attention_in_multiplier=1.0, attention_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804,
+        ssm_in_multiplier=0.25, ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284)),
+    **_falcon_h1))
+
+register("falcon-h1-tiny", TransformerConfig(
+    vocab_size=512, hidden_size=128, intermediate_size=256, num_layers=2,
+    num_heads=4, num_kv_heads=2, head_dim=32, max_seq_len=256,
+    rope_theta=1e6,
+    ssm=SSMConfig(
+        num_heads=4, head_dim=32, state_size=16, n_groups=2, conv_kernel=4,
+        chunk_size=16, conv_bias=True, embedding_multiplier=2.5,
+        lm_head_multiplier=0.5, attention_in_multiplier=0.9,
+        attention_out_multiplier=0.7, key_multiplier=0.6,
+        ssm_in_multiplier=0.8, ssm_out_multiplier=1.3,
+        ssm_multipliers=(0.7, 1.2, 0.9, 1.1, 0.8),
+        mlp_multipliers=(1.4, 0.75)),
+    **_falcon_h1))
 
 # -- Phi (ref v2 phi: parallel block + partial rotary + biases) --------
 register("phi-2", TransformerConfig(

@@ -33,6 +33,52 @@ Params = Dict[str, Any]
 
 
 @dataclass(frozen=True)
+class SSMConfig:
+    """A Mamba-2 mixer beside the attention heads of every block, and the
+    muP multipliers of a block that has one (HF ``falcon_h1``).  The mixer
+    reads the block's normed input, as attention does; the two outputs are
+    scaled and added.  ``TransformerConfig.ssm`` is ``None`` for a model
+    without a mixer."""
+    num_heads: int
+    head_dim: int
+    state_size: int
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    conv_bias: bool = True
+    # multipliers (all 1.0 = a plain Mamba-2 hybrid)
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    # over the five parts of in_proj's output: gate z, x, B, C, dt
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    # inside the gate's activation, and on the down projection
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
+
+    @property
+    def d_ssm(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels through the depthwise convolution: x, B and C."""
+        return self.d_ssm + 2 * self.n_groups * self.state_size
+
+    @property
+    def proj_dim(self) -> int:
+        """in_proj's output: z, then x, B, C, then dt."""
+        return self.d_ssm + self.conv_dim + self.num_heads
+
+    def part_sizes(self) -> Tuple[int, int, int, int, int]:
+        gn = self.n_groups * self.state_size
+        return (self.d_ssm, self.d_ssm, gn, gn, self.num_heads)
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     """Architecture hyperparameters covering GPT-2 and Llama families."""
     vocab_size: int = 50257
@@ -217,6 +263,31 @@ class TransformerConfig:
     # inference-v2 module overrides as (kind, name) pairs — resolved via
     # inference/v2/modules.py (ref inference/v2/modules/heuristics.py)
     v2_modules: Optional[Tuple[Tuple[str, str], ...]] = None
+    # state-space mixer beside attention in every block (Falcon-H1);
+    # None: the block has attention alone.  Served by inference/v2 only
+    ssm: Optional[SSMConfig] = None
+
+    # the mixer's sizes by flat names (0 without one), for callers that
+    # hold a configuration to a file by ``getattr``
+    @property
+    def ssm_heads(self) -> int:
+        return self.ssm.num_heads if self.ssm else 0
+
+    @property
+    def ssm_head_dim(self) -> int:
+        return self.ssm.head_dim if self.ssm else 0
+
+    @property
+    def ssm_state(self) -> int:
+        return self.ssm.state_size if self.ssm else 0
+
+    @property
+    def ssm_groups(self) -> int:
+        return self.ssm.n_groups if self.ssm else 0
+
+    @property
+    def ssm_conv(self) -> int:
+        return self.ssm.conv_kernel if self.ssm else 0
 
     @property
     def kv_heads(self) -> int:
@@ -355,7 +426,53 @@ def init_layer_params(cfg: TransformerConfig, key) -> Params:
 
     block["ln1"] = norm_params()
     block["ln2"] = norm_params()
+    if cfg.ssm is not None:
+        block["ssm"] = init_ssm_params(cfg, jax.random.fold_in(key, 0x55D))
     return block
+
+
+def init_ssm_params(cfg: TransformerConfig, key) -> Params:
+    """One block's Mamba-2 mixer, by the Mamba-2 convention: ``A_log`` the
+    log of uniform 1..16, ``dt_bias`` the inverse softplus of a step
+    log-uniform in 1e-3..1e-1, ``D`` ones, the depthwise convolution
+    uniform in +-1/sqrt(kernel) (torch's Conv1d default), the gated
+    norm's scale ones."""
+    m, h, pd = cfg.ssm, cfg.hidden_size, cfg.param_dtype
+    k = jax.random.split(key, 6)
+    scale = 1.0 / math.sqrt(h)
+    bound = 1.0 / math.sqrt(m.conv_kernel)
+    dt = jnp.exp(jax.random.uniform(k[2], (m.num_heads,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    p = {
+        "in_proj": _dense_init(k[0], (h, m.proj_dim), scale, pd),
+        "conv_w": jax.random.uniform(k[1], (m.conv_dim, m.conv_kernel),
+                                     jnp.float32, -bound, bound).astype(pd),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pd),
+        "A_log": jnp.log(jax.random.uniform(k[3], (m.num_heads,),
+                                            jnp.float32, 1.0, 16.0)
+                         ).astype(pd),
+        "D": jnp.ones((m.num_heads,), pd),
+        "norm": jnp.ones((m.d_ssm,), pd),
+        "out_proj": _dense_init(
+            k[4], (m.d_ssm, h),
+            1.0 / math.sqrt(m.d_ssm) / math.sqrt(2 * cfg.num_layers), pd),
+    }
+    if m.conv_bias:
+        p["conv_b"] = jax.random.uniform(k[5], (m.conv_dim,), jnp.float32,
+                                         -bound, bound).astype(pd)
+    return p
+
+
+def refuse_ssm(cfg: TransformerConfig, what: str) -> None:
+    """The dense forward has no state-space scan (and no backward for
+    one): a configuration with a mixer is served by inference/v2 only."""
+    if cfg.ssm is not None:
+        raise NotImplementedError(
+            f"{what}: this configuration has a Mamba-2 SSM mixer beside "
+            f"attention ({cfg.ssm.num_heads} heads of {cfg.ssm.head_dim}, "
+            f"state {cfg.ssm.state_size}); models/transformer.py has no "
+            "state-space scan and would run the block without it. Serve "
+            "it through inference.v2.InferenceEngineV2")
 
 
 def init_params(cfg: TransformerConfig, key) -> Params:
@@ -673,6 +790,12 @@ def _mlp_block(x, p, cfg: TransformerConfig):
             y = y + p["bo"].astype(dt)
         return y.astype(dt0)
     if cfg.activation == "swiglu":
+        if cfg.ssm is not None:
+            # falcon_h1 muP: inside the gate's activation, and on the way out
+            m_gate, m_down = cfg.ssm.mlp_multipliers
+            gate = jax.nn.silu((x @ p["wg"].astype(dt)) * m_gate)
+            up = x @ p["wi"].astype(dt)
+            return (((gate * up) @ p["wo"].astype(dt)) * m_down).astype(dt0)
         gate = jax.nn.silu(x @ p["wg"].astype(dt))
         up = x @ p["wi"].astype(dt)
         return ((gate * up) @ p["wo"].astype(dt)).astype(dt0)
@@ -754,6 +877,7 @@ def transformer_layer(x, layer_params, positions, cfg: TransformerConfig,
     for residual dropout (None → off).  ``attention_mask``: [B, S] key
     padding mask (encoder serving).
     """
+    refuse_ssm(cfg, "transformer_layer()")
     dk = (lambda i: jax.random.fold_in(dropout_key, i)) \
         if dropout_key is not None else (lambda i: None)
     if cfg.parallel_block:
@@ -938,6 +1062,7 @@ def forward(params: Params, input_ids, cfg: TransformerConfig,
     ``cfg.dropout`` (None → dropout off, the eval/serve contract).
     ``token_type_ids``/``attention_mask``: encoder (BERT-class) segment
     ids and [B, S] key-padding mask."""
+    refuse_ssm(cfg, "forward()")
     b, s = input_ids.shape
     dt = cfg.dtype
     if positions is None:

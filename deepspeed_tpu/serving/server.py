@@ -131,6 +131,20 @@ class InferenceServer:
         self.engine = engine
         self.cfg = ServerConfig(config)
         self.monitor = monitor
+        # a model with recurrent state (an SSM mixer) has one state per
+        # sequence, the one after its last row: whatever would start a
+        # sequence midway, or take it back, needs snapshots of it
+        self._recurrent = getattr(engine, "state", None) is not None
+        if self._recurrent and self.cfg.prefix_cache.enabled:
+            self._refuse_recurrent(
+                "prefix_cache.enabled: a request that adopts cached "
+                "pages starts past position 0, and the mixer's state "
+                "at that position was not kept")
+        if self._recurrent and spec_decoder is not None:
+            self._refuse_recurrent(
+                "speculative decoding (spec_decoder): a rejected draft "
+                "token has already advanced the mixer's state, and the "
+                "state before it was not kept")
         # speculative decoding (serving/disagg.py SpeculativeDecoder): a
         # draft model living in this serve loop.  Anything with
         # round()/flush() works; None disables per-request `speculative`
@@ -222,6 +236,15 @@ class InferenceServer:
         # accounting lives in the ENGINE — engine.seq_blocks — so
         # admission and allocator can never disagree)
         self._total_blocks = engine.cfg.num_blocks - 1
+
+    @staticmethod
+    def _refuse_recurrent(why: str) -> None:
+        from deepspeed_tpu.inference.v2.engine_v2 import \
+            RecurrentStateUnsupported
+
+        raise RecurrentStateUnsupported(
+            "this engine's model has a Mamba-2 SSM mixer with recurrent "
+            f"state per sequence and no state snapshots — {why}")
 
     # -- lifecycle -------------------------------------------------------
     def start(self) -> "InferenceServer":
@@ -352,6 +375,11 @@ class InferenceServer:
         are per-tracer counters, so a foreign span would alias.
         """
         params = params or SamplingParams()
+        if self._recurrent and (handoff or kv_payload is not None):
+            self._refuse_recurrent(
+                "KV hand-off (handoff / kv_payload): the pages of a "
+                "sequence are not its whole state, and the mixer's state "
+                "is neither exported nor imported")
         if not len(prompt):
             raise ValueError("empty prompt")
         if params.max_new_tokens < 1:

@@ -72,6 +72,7 @@ SPAN_NAMES = (
     "v2.h2d",                  # device_put of the step's index arrays
     "v2.ragged_step",          # InferenceEngineV2.step (parent of the v2.*)
     "v2.schedule",             # next_schedule + build_ragged_batch
+    "v2.state_alloc",          # recurrent state slots made (engine init)
 )
 
 # Instant events (Chrome "i" events).
@@ -232,6 +233,19 @@ class Tracer:
             return NULL_SPAN
         return Span(self, name, trace_id or self.new_trace_id(),
                     parent.span_id if parent is not None else 0)
+
+    def complete(self, name: str, ts_us: float, dur_us: float,
+                 trace_id: str = "", **args) -> None:
+        """One finished span whose times the caller measured itself (on
+        ``time.monotonic``, in microseconds): for work done before this
+        tracer was there to open a span over it."""
+        if not self.enabled:
+            return
+        a = {"trace_id": trace_id or self.new_trace_id(),
+             "span_id": self._next_id(), **args}
+        self._emit({"name": name, "cat": name.split(".", 1)[0], "ph": "X",
+                    "ts": ts_us, "dur": dur_us, "pid": self._pid,
+                    "tid": threading.get_ident(), "args": a})
 
     def instant(self, name: str, trace_id: str = "", **args) -> None:
         """One timestamped marker event (Chrome ``ph: "i"``)."""

@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 flash_mha_mod = importlib.import_module("deepspeed_tpu.ops.pallas.flash_mha")
 from deepspeed_tpu.ops.pallas import (fused_optimizer, gather_matmul,  # noqa: E402
-                                      paged_attention, quantize)
+                                      paged_attention, quantize, ssd_ragged)
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -122,6 +122,45 @@ def test_paged_qblock_serve_step(chip, t):
     pool = chip((_NKV, 2048 * _BS, 128), BF16)
     _compile(fn, chip((t, _NH, 128), BF16), pool, pool, chip((65, 256), I32),
              chip((t,), I32), chip((t,), I32), chip((t,), I32))
+
+
+@pytest.mark.parametrize("t", [64, 256], ids=["decode_only", "with_chunk"])
+def test_ssd_ragged_published_shapes(chip, t):
+    """The state-space scan at Falcon-H1-34B's widths (32 heads of 128,
+    state 256, 2 groups, chunks of 128) over six layers' slots of 64
+    sequences and the pad's: a decode-only step (a bucket of 64 rows,
+    padded to one chunk, every row a run of one) and a full 256-row step
+    that carries a prompt's state from its first chunk to its second.
+    The state must come back in place."""
+    def fn(x, dt, a, b, c, state, slot, pos):
+        return ssd_ragged.ssd_ragged_pallas(x, dt, a, b, c, state, slot, pos,
+                                            layer=jnp.int32(3), chunk=128)
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(fn, donate_argnums=5).lower(
+            chip((t, 32, 128), BF16), chip((t, 32), F32), chip((32,), F32),
+            chip((t, 2, 256), BF16), chip((t, 2, 256), BF16),
+            chip((6, 65, 32, 128, 256), F32), chip((t,), I32),
+            chip((t,), I32)).compile().as_text()
+    assert "tpu_custom_call" in text
+    call = next(ln for ln in text.splitlines()
+                if "custom-call" in ln and "ssd_ragged" in ln)
+    assert "output_to_operand_aliasing" in call
+    assert "f32[6,65,32,128,256]" in call
+
+
+def test_paged_qblock_group_of_five(chip):
+    """Falcon-H1-34B's attention heads: 20 query heads on 4 key/value
+    heads of 128, a group that is no power of two (160 query rows a KV
+    head and block), pages of 16, 64 pages a sequence."""
+    def fn(q, k, v, tables, pos, clen, slot):
+        return paged_attention.paged_decode_attention(
+            q, k, v, tables, pos, clen, block_size=_BS,
+            sm_scale=128 ** -0.5, token_slot=slot)
+
+    pool = chip((4, 4352 * _BS, 128), BF16)
+    _compile(fn, chip((256, 20, 128), BF16), pool, pool, chip((65, 64), I32),
+             chip((256,), I32), chip((256,), I32), chip((256,), I32))
 
 
 def test_paged_decode_int8_kv(chip):

@@ -13,7 +13,8 @@ import chip_smoke
 from deepspeed_tpu.models import get_model_config
 
 _KERNEL_MODULES = ("deepspeed_tpu.ops.pallas.flash_mha",
-                   "deepspeed_tpu.ops.pallas.paged_attention")
+                   "deepspeed_tpu.ops.pallas.paged_attention",
+                   "deepspeed_tpu.ops.pallas.ssd_ragged")
 _DISPATCH_MODULES = ("deepspeed_tpu.ops.flash_attention",
                      "deepspeed_tpu.inference.v2.model")
 
@@ -45,6 +46,12 @@ def test_device_phase_refuses_cpu():
 def test_main_prints_no_result_without_tpu(capsys):
     assert chip_smoke.main([]) == 1
     assert '"ok"' not in capsys.readouterr().out
+
+
+def test_ssd_phase_tiny(tpu_branches):
+    out = chip_smoke.ssd_phase(heads=4, head_dim=32, state=16, groups=2,
+                               slots=6, chunk_rows=21, chunk=16)
+    assert out["silent"] < 1e-6 and out["y"] < 0.02
 
 
 def test_train_phase_tiny(tpu_branches):

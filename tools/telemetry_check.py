@@ -67,6 +67,7 @@ EXPECTED_SPAN_NAMES = [
     "train.data_ingest", "train.dispatch", "train.step", "train.sync",
     "train.telemetry",
     "v2.dispatch", "v2.fetch", "v2.h2d", "v2.ragged_step", "v2.schedule",
+    "v2.state_alloc",
 ]
 EXPECTED_EVENT_NAMES = [
     "chaos.inject", "fleet.brownout", "fleet.heal",
