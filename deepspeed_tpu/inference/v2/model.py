@@ -6,21 +6,28 @@ linear+blocked-KV rotary, logits_gather, embed): one forward processes an
 arbitrary prefill/decode mix as a flat token list with per-token metadata.
 
 Design (vs the reference's CUDA kernels):
-* KV cache pages are rows of a flat per-layer array ``[L, P, kv_heads, d]``
-  (P = num_blocks·block_size). Token KV is *scattered* to its page slot and
-  context KV is *gathered* through the block table — both are XLA
-  scatter/gather ops on static shapes, which XLA fuses around the attention
-  einsums; a Pallas kernel can later replace the gather+einsum pair without
-  changing this interface.
+* KV cache pages are rows of ONE pool for every layer, ``[L, kv_heads, P, d]``
+  (P = num_blocks·block_size; kv-head-major for the kernels' page blocks).
+  On the kernel path (``paged_pallas``) a step *scatters* its rows' KV into
+  the pool in place, at ``[layer, head, token_dest]``, and the Pallas
+  block-table kernels (``ops/pallas/paged_attention.py``) read the layer's
+  pages out of the whole pool by the layer index.  The XLA path (head 64,
+  ALiBi, the CPU) takes ONE layer's pages out of the pool, scatters into
+  them, *gathers* each token's context rows through the block table, and
+  puts the layer back.
 * Every shape is fixed by (token_budget, max_seqs, max_ctx): one compiled
   executable serves all batch mixes (the reference re-launches variable-size
   kernels instead).
-* The layer loop is ``lax.scan`` threading the cache as scan xs/ys, matching
-  the training forward's stacked-parameter layout.
+* The layer loop is ``lax.scan`` over the stacked parameters (the training
+  forward's layout) with the pools in its CARRY, whole: a donated pool is
+  updated in place and comes back as the buffer it came in (as the scan's
+  xs/ys the whole pool was rewritten every step to change a few rows of
+  it).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Tuple
 
@@ -67,17 +74,25 @@ def _is_quant_cache(pages) -> bool:
     return isinstance(pages, dict)
 
 
-def _kv_append(pages, x, token_dest):
-    """Scatter this step's KV rows [T, nkv, d] into the page pool —
-    quantizing on append when the cache is int8."""
-    xh = x.swapaxes(0, 1)                                # [nkv, T, d]
-    if _is_quant_cache(pages):
-        xf = xh.astype(jnp.float32)
+def _kv_append(pool, x, token_dest, layer=None):
+    """Scatter this step's KV rows [T, nkv, d] into ``layer``'s pages of
+    the pool [L, nkv, P, d], in place (``layer`` None: into one layer's
+    pages [nkv, P, d]) — quantizing on append when the cache is int8."""
+    if _is_quant_cache(pool):
+        xf = x.astype(jnp.float32)
         scale = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1), 1e-8) / 127.0
         q8 = jnp.clip(jnp.round(xf / scale[..., None]), -127, 127)
-        return {"q": pages["q"].at[:, token_dest].set(q8.astype(jnp.int8)),
-                "s": pages["s"].at[:, token_dest].set(scale)}
-    return pages.at[:, token_dest].set(xh.astype(pages.dtype))
+        return {"q": _kv_append(pool["q"], q8, token_dest, layer),
+                "s": _kv_append(pool["s"], scale, token_dest, layer)}
+    x = x.astype(pool.dtype)
+    if layer is None:
+        return pool.at[:, token_dest].set(x.swapaxes(0, 1))
+    # every (row, head) is an update of its own, a window of the minor
+    # dimension alone: the scatter then takes the pool in the layout the
+    # kernels read it in (with the heads in the window the TPU's compiler
+    # relays the whole pool out for the scatter and back for the kernel)
+    heads = jnp.arange(pool.shape[1], dtype=jnp.int32)
+    return pool.at[layer, heads[None, :], token_dest[:, None]].set(x)
 
 
 def _paged_attention_xla(q, k_pages, v_pages, gather_idx, token_pos,
@@ -140,7 +155,7 @@ def _pallas_attn_default(block_size=0, head_dim=0, on_tpu=False,
                  default_for=_pallas_attn_default)
 def _attn_impl_pallas(q, k_pages, v_pages, gather_idx, token_pos,
                       token_ctx_len, cfg, block_tables, token_slot,
-                      block_size):
+                      block_size, layer=None):
     """Pallas block-table kernel (ops/pallas/paged_attention.py: runs of
     a sequence's rows share one page walk with online softmax — no
     [T, C, ...] gather materialisation, no per-token page table).
@@ -155,7 +170,8 @@ def _attn_impl_pallas(q, k_pages, v_pages, gather_idx, token_pos,
             "'auto' or 'paged_xla' for bloom-class models")
     scale = (cfg.attn_scale if cfg.attn_scale is not None
              else 1.0 / math.sqrt(cfg.dim_per_head))
-    kw = dict(window=cfg.sliding_window or None, token_slot=token_slot)
+    kw = dict(window=cfg.sliding_window or None, token_slot=token_slot,
+              layer=layer)
     if _is_quant_cache(k_pages):
         return paged_decode_attention(
             q, k_pages["q"], v_pages["q"], block_tables, token_pos,
@@ -189,13 +205,16 @@ def attention_impl_name(cfg: TransformerConfig, block_size: int,
 
 def _paged_attention(q, k_pages, v_pages, gather_idx, token_pos, token_ctx_len,
                      cfg: TransformerConfig, block_tables=None, token_slot=None,
-                     block_size: int = 0):
-    """Attention of T query tokens against their sequences' KV pages,
-    through the implementation :func:`attention_impl_name` resolves."""
+                     block_size: int = 0, layer=None):
+    """Attention of T query tokens against their sequences' KV pages
+    [nkv, P, d], through the implementation :func:`attention_impl_name`
+    resolves; with ``layer``, against that layer's pages of every
+    layer's pool [L, nkv, P, d] (``paged_pallas`` alone reads it so)."""
     impl = resolve("attention", attention_impl_name(
         cfg, block_size, has_tables=block_tables is not None))
+    kw = {} if layer is None else {"layer": layer}
     return impl(q, k_pages, v_pages, gather_idx, token_pos, token_ctx_len,
-                cfg, block_tables, token_slot, block_size)
+                cfg, block_tables, token_slot, block_size, **kw)
 
 
 @register_module("ssm", "ssd_pallas",
@@ -317,10 +336,12 @@ def _ssm_meta(cfg: TransformerConfig, state, token_slot, token_pos):
     return (token_slot, token_pos) + run_layout(token_slot, token_pos)
 
 
-def _ragged_layer(x, lp, k_pages, v_pages, meta, cfg: TransformerConfig,
-                  layer_is_moe=False, state=None, ssm_meta=None):
-    """One block over flat tokens [T, H]; scatters KV, attends via pages.
-    Returns (x, k_pages, v_pages, state): ``state`` is the layer's
+def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
+                  cfg: TransformerConfig, layer_is_moe=False, state=None,
+                  ssm_meta=None):
+    """Block ``layer`` over flat tokens [T, H]; scatters its KV into the
+    pools [L, nkv, P, d] in place and attends via its pages of them.
+    Returns (x, cache_k, cache_v, state): ``state`` is the layer's
     recurrent slots where the block has an SSM mixer, else None."""
     (token_pos, token_dest, gather_idx, token_ctx_len, token_slot,
      block_tables, block_size) = meta
@@ -346,15 +367,33 @@ def _ragged_layer(x, lp, k_pages, v_pages, meta, cfg: TransformerConfig,
         k = _rope_tok(k, token_pos, cfg)
 
     # Write this step's KV to its pages (padding tokens target page 0 =
-    # garbage, so no mask needed; ref: linear_blocked_kv_copy). Cache layout
-    # is [nkv, P, d] (kv-head-major for the Pallas kernel's page blocks),
-    # quantized on append when the cache is int8 (_kv_append).
-    k_pages = _kv_append(k_pages, k, token_dest)
-    v_pages = _kv_append(v_pages, v, token_dest)
-
-    attn = _paged_attention(q, k_pages, v_pages, gather_idx, token_pos,
-                            token_ctx_len, cfg, block_tables=block_tables,
-                            token_slot=token_slot, block_size=block_size)
+    # garbage, so no mask needed; ref: linear_blocked_kv_copy). A layer's
+    # pages are [nkv, P, d] (kv-head-major for the Pallas kernel's page
+    # blocks), quantized on append when the cache is int8 (_kv_append).
+    attend = functools.partial(
+        _paged_attention, q, gather_idx=gather_idx, token_pos=token_pos,
+        token_ctx_len=token_ctx_len, cfg=cfg, block_tables=block_tables,
+        token_slot=token_slot, block_size=block_size)
+    if attention_impl_name(cfg, block_size,
+                           block_tables is not None) == "paged_pallas":
+        # the kernels read the layer's pages out of the whole pool
+        cache_k = _kv_append(cache_k, k, token_dest, layer)
+        cache_v = _kv_append(cache_v, v, token_dest, layer)
+        attn = attend(cache_k, cache_v, layer=layer)
+    else:
+        # the XLA gather path (head 64, ALiBi, the CPU) works on the
+        # layer's pages as a value of their own and puts them back: one
+        # layer read and written where the kernels touch the step's rows
+        # (scatter and gather into the whole pool at once make the TPU's
+        # compiler relay the pool out between them, for every layer)
+        k_pages = _kv_append(jax.tree.map(lambda c: c[layer], cache_k), k,
+                             token_dest)
+        v_pages = _kv_append(jax.tree.map(lambda c: c[layer], cache_v), v,
+                             token_dest)
+        attn = attend(k_pages, v_pages)
+        cache_k, cache_v = jax.tree.map(
+            lambda c, pages: c.at[layer].set(pages),
+            (cache_k, cache_v), (k_pages, v_pages))
     attn = attn.reshape(t, nh * d) @ lp["attn"]["wo"].astype(dt)
     if lp["attn"].get("bo") is not None:
         attn = attn + lp["attn"]["bo"].astype(dt)
@@ -369,14 +408,14 @@ def _ragged_layer(x, lp, k_pages, v_pages, meta, cfg: TransformerConfig,
         # Falcon-40B/GPT-NeoX (parallel_norms): the MLP gets its own
         # ln2 on the same residual input (HF use_parallel_residual)
         h_mlp = _norm(x, lp["ln2"], cfg) if cfg.parallel_norms else h
-        return (x + attn + _mlp_block(h_mlp, lp["mlp"], cfg), k_pages,
-                v_pages, state)
+        return (x + attn + _mlp_block(h_mlp, lp["mlp"], cfg), cache_k,
+                cache_v, state)
 
     x = x + attn
 
     h2 = _norm(x, lp["ln2"], cfg)
     if "moe" not in lp:
-        return x + _mlp_block(h2, lp["mlp"], cfg), k_pages, v_pages, state
+        return x + _mlp_block(h2, lp["mlp"], cfg), cache_k, cache_v, state
 
     from deepspeed_tpu.moe.sharded_moe import moe_forward, moe_forward_ep
     from deepspeed_tpu.parallel.topology import get_topology
@@ -413,24 +452,12 @@ def _ragged_layer(x, lp, k_pages, v_pages, meta, cfg: TransformerConfig,
         y = moe_branch(h2) if layer_is_moe else dense_branch(h2)
     else:
         y = lax.cond(layer_is_moe, moe_branch, dense_branch, h2)
-    return x + y, k_pages, v_pages, state
+    return x + y, cache_k, cache_v, state
 
 
-def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
-                   token_dest, block_tables, ctx_lens, logits_idx,
-                   state=None, *, cfg: TransformerConfig, block_size: int,
-                   state_slot=None):
-    """One ragged step.
-
-    cache_k/cache_v: [L, P, nkv, d]; block_tables: [S+1, NB]; returns
-    (logits [S+1, V], cache_k', cache_v').  A model with an SSM mixer
-    (``cfg.ssm``) also takes ``state``, its recurrent slots
-    (:func:`new_ssm_state`), and returns them fourth; ``state_slot`` [T]
-    names each row's slot where that is not ``token_slot``.
-    """
+def _embed_rows(params, token_ids, token_pos, cfg: TransformerConfig):
+    """The step's flat rows [T, H] as the first block takes them."""
     dt = cfg.dtype
-    ssm_meta = _ssm_meta(cfg, state, token_slot if state_slot is None
-                         else state_slot, token_pos)
     x = params["embed"]["tokens"].astype(dt)[token_ids]  # [T, H]
     if cfg.ssm:
         x = x * cfg.ssm.embedding_multiplier
@@ -440,61 +467,59 @@ def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
         x = x + params["embed"]["positions"].astype(dt)[token_pos]
     if cfg.embed_norm:
         x = _norm(x, params["embed"]["norm"], cfg)  # Bloom embedding LN
+    return x
 
-    # Context gather indices, shared by all layers (ref: atom_builder).
+
+def _step_meta(token_slot, token_pos, token_dest, block_tables, ctx_lens,
+               block_size: int):
+    """What every block needs of the step's layout, once: the context
+    gather indices among it (ref: atom_builder)."""
     nb = block_tables.shape[1]
     c = jnp.arange(nb * block_size, dtype=jnp.int32)
     ctx_idx = block_tables[:, c // block_size] * block_size + c % block_size  # [S+1, C]
     gather_idx = ctx_idx[token_slot]          # [T, C]
     token_ctx_len = ctx_lens[token_slot]      # [T]
-    meta = (token_pos, token_dest, gather_idx, token_ctx_len, token_slot,
+    return (token_pos, token_dest, gather_idx, token_ctx_len, token_slot,
             block_tables, block_size)
 
-    moe_every = max(1, cfg.moe_layer_freq)
 
+def _ragged_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
+                  token_dest, block_tables, ctx_lens, state,
+                  cfg: TransformerConfig, block_size: int, state_slot=None):
+    """Embedding, every block and the final norm over a step's flat rows:
+    (x [T, H], cache_k', cache_v', state').  The one layer loop of every
+    ragged program: the pools (and a mixer's recurrent slots) ride its
+    carry whole and each block updates them in place."""
+    ssm_meta = _ssm_meta(cfg, state, token_slot if state_slot is None
+                         else state_slot, token_pos)
+    x = _embed_rows(params, token_ids, token_pos, cfg)
+    meta = _step_meta(token_slot, token_pos, token_dest, block_tables,
+                      ctx_lens, block_size)
+
+    moe_every = max(1, cfg.moe_layer_freq)
+    layers = params["layers"]
+    # GPT-Neo alternating global/local: scan layer PAIRS so each member's
+    # window is static (see models/transformer scan_segment)
+    period = 2 if cfg.alt_window else 1
     if cfg.alt_window:
-        # GPT-Neo alternating global/local: scan layer PAIRS so each
-        # member's window is static (see models/transformer scan_segment)
         if cfg.is_moe:
             raise NotImplementedError("alt_window + MoE not supported")
         if cfg.num_layers % 2:
             raise NotImplementedError(
                 "alt_window needs an even layer count (the ragged path "
                 f"scans layer pairs; got {cfg.num_layers})")
-        pairs = cfg.num_layers // 2
+        layers = jax.tree.map(
+            lambda a: a.reshape((cfg.num_layers // 2, 2) + a.shape[1:]),
+            layers)
 
-        def body2(h, scanned):
-            lp, ck_l, cv_l, idx = scanned
-            ck_out, cv_out = [], []
-            for j in range(2):
+    def body(carry, scanned):
+        h, ck, cv, ssm = carry
+        lp, first, conv = scanned
+        for j in range(period):
+            idx, sub, lcfg = first + j, lp, cfg
+            if cfg.alt_window:
                 sub = jax.tree.map(lambda p, j=j: p[j], lp)
                 lcfg = cfg if j % 2 else cfg.replace(sliding_window=None)
-                h, ck_j, cv_j, _ = _ragged_layer(
-                    h, sub, jax.tree.map(lambda c, j=j: c[j], ck_l),
-                    jax.tree.map(lambda c, j=j: c[j], cv_l), meta, lcfg)
-                ck_out.append(ck_j)
-                cv_out.append(cv_j)
-            stack = lambda xs: jax.tree.map(
-                lambda *ys: jnp.stack(ys, axis=0), *xs)
-            return h, (stack(ck_out), stack(cv_out))
-
-        pair = lambda tree: jax.tree.map(
-            lambda a: a.reshape((pairs, 2) + a.shape[1:]), tree)
-        unpair = lambda tree: jax.tree.map(
-            lambda a: a.reshape((cfg.num_layers,) + a.shape[2:]), tree)
-        x, (cache_k, cache_v) = lax.scan(
-            body2, x, (pair(params["layers"]), pair(cache_k),
-                       pair(cache_v), jnp.arange(pairs)))
-        cache_k, cache_v = unpair(cache_k), unpair(cache_v)
-    else:
-        def body(h, scanned):
-            lp, ck_l, cv_l, idx, conv_l = scanned
-            st_l = None
-            if cfg.ssm:
-                # the recurrent slots ride the carry whole (sliced as xs
-                # and stacked as ys they would be copied every step)
-                h, ssm = h
-                st_l = {"ssm": ssm, "conv": conv_l, "layer": idx}
             if not cfg.is_moe:
                 is_moe_layer = False
             elif moe_every == 1:
@@ -503,31 +528,52 @@ def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
                 is_moe_layer = True
             else:
                 is_moe_layer = (idx % moe_every) == (moe_every - 1)
-            h, ck_l, cv_l, st_l = _ragged_layer(
-                h, lp, ck_l, cv_l, meta, cfg, layer_is_moe=is_moe_layer,
-                state=st_l, ssm_meta=ssm_meta)
+            st = ({"ssm": ssm, "conv": conv, "layer": idx} if cfg.ssm
+                  else None)
+            h, ck, cv, st = _ragged_layer(
+                h, sub, ck, cv, idx, meta, lcfg, layer_is_moe=is_moe_layer,
+                state=st, ssm_meta=ssm_meta)
             if cfg.ssm:
-                return (h, st_l["ssm"]), (ck_l, cv_l, st_l["conv"])
-            return h, (ck_l, cv_l, None)
+                ssm, conv = st["ssm"], st["conv"]
+        return (h, ck, cv, ssm), conv
 
-        layer_idx = jnp.arange(cfg.num_layers)
-        if cfg.ssm:
-            (x, ssm), (cache_k, cache_v, conv) = lax.scan(
-                body, (x, state["ssm"]),
-                (params["layers"], cache_k, cache_v, layer_idx,
-                 state["conv"]))
-            state = {"ssm": ssm, "conv": conv}
-        else:
-            x, (cache_k, cache_v, _) = lax.scan(
-                body, x, (params["layers"], cache_k, cache_v, layer_idx,
-                          None))
+    # the convolution's tails (a few MiB) are sliced and stacked by the
+    # scan; the pools and the recurrent slots are not
+    (x, cache_k, cache_v, ssm), conv = lax.scan(
+        body, (x, cache_k, cache_v, state["ssm"] if cfg.ssm else None),
+        (layers, jnp.arange(0, cfg.num_layers, period),
+         state["conv"] if cfg.ssm else None))
+    if cfg.ssm:
+        state = {"ssm": ssm, "conv": conv}
+    return _norm(x, params["final_norm"], cfg), cache_k, cache_v, state
 
-    x = _norm(x, params["final_norm"], cfg)
-    last = x[logits_idx]  # [S+1, H] — ref: logits_gather
+
+def _lm_head(x, params, cfg: TransformerConfig):
+    dt = cfg.dtype
     if cfg.tie_embeddings:
-        logits = last @ params["embed"]["tokens"].astype(dt).T
-    else:
-        logits = last @ params["lm_head"].astype(dt)
+        return x @ params["embed"]["tokens"].astype(dt).T
+    return x @ params["lm_head"].astype(dt)
+
+
+def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
+                   token_dest, block_tables, ctx_lens, logits_idx,
+                   state=None, *, cfg: TransformerConfig, block_size: int,
+                   state_slot=None):
+    """One ragged step.
+
+    cache_k/cache_v: [L, nkv, P, d], carried through the layer loop and
+    updated in place (donate them: they come back as the buffers they
+    came in); block_tables: [S+1, NB]; returns
+    (logits [S+1, V], cache_k', cache_v').  A model with an SSM mixer
+    (``cfg.ssm``) also takes ``state``, its recurrent slots
+    (:func:`new_ssm_state`), and returns them fourth; ``state_slot`` [T]
+    names each row's slot where that is not ``token_slot``.
+    """
+    x, cache_k, cache_v, state = _ragged_trunk(
+        params, cache_k, cache_v, token_ids, token_slot, token_pos,
+        token_dest, block_tables, ctx_lens, state, cfg, block_size,
+        state_slot=state_slot)
+    logits = _lm_head(x[logits_idx], params, cfg)  # ref: logits_gather
     if cfg.ssm:
         logits = logits * cfg.ssm.lm_head_multiplier
         return logits.astype(jnp.float32), cache_k, cache_v, state
@@ -563,40 +609,14 @@ def ragged_forward_verify(params, cache_k, cache_v, token_ids, token_slot,
             "speculative verify needs state snapshots: a rejected draft "
             "row has already advanced the Mamba-2 SSM mixer's recurrent "
             "state, and no copy of the state before it is kept")
-    dt = cfg.dtype
-    x = params["embed"]["tokens"].astype(dt)[token_ids]
-    if cfg.has_learned_positions and "positions" in params["embed"]:
-        x = x + params["embed"]["positions"].astype(dt)[token_pos]
-    if cfg.embed_norm:
-        x = _norm(x, params["embed"]["norm"], cfg)
-
-    nb = block_tables.shape[1]
-    c = jnp.arange(nb * block_size, dtype=jnp.int32)
-    ctx_idx = block_tables[:, c // block_size] * block_size + c % block_size
-    gather_idx = ctx_idx[token_slot]
-    token_ctx_len = ctx_lens[token_slot]
-    meta = (token_pos, token_dest, gather_idx, token_ctx_len, token_slot,
-            block_tables, block_size)
-
     if cfg.alt_window or cfg.is_moe:
         raise NotImplementedError(
             "speculative verify step supports the plain scanned-layer "
             "ragged path only (no alt_window, no MoE)")
-
-    def body(h, scanned):
-        lp, ck_l, cv_l, _idx = scanned
-        h, ck_l, cv_l, _ = _ragged_layer(h, lp, ck_l, cv_l, meta, cfg)
-        return h, (ck_l, cv_l)
-
-    layer_idx = jnp.arange(cfg.num_layers)
-    x, (cache_k, cache_v) = lax.scan(
-        body, x, (params["layers"], cache_k, cache_v, layer_idx))
-
-    x = _norm(x, params["final_norm"], cfg)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"]["tokens"].astype(dt).T
-    else:
-        logits = x @ params["lm_head"].astype(dt)
+    x, cache_k, cache_v, _ = _ragged_trunk(
+        params, cache_k, cache_v, token_ids, token_slot, token_pos,
+        token_dest, block_tables, ctx_lens, None, cfg, block_size)
+    logits = _lm_head(x, params, cfg)
     nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
     return nxt, cache_k, cache_v
 

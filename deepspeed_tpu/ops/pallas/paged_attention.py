@@ -44,8 +44,13 @@ behind :func:`paged_decode_attention` for a bf16/fp32 cache:
 The int8-KV variant ``paged_decode_q8`` keeps the row-per-program grid
 (T, nkv) and a page a step (no cell or deployment measures it yet).
 
-Cache layout contract: k_pages/v_pages are ``[nkv, P, d]`` where P = number
-of pages × block_size rows; ``pages[s, j]`` gives page ids (row-blocks of
+Cache layout contract: k_pages/v_pages are one layer's pages ``[nkv, P, d]``
+where P = number of pages × block_size rows, or EVERY layer's pool
+``[L, nkv, P, d]`` with ``layer`` naming the one to read: the index is one
+more scalar-prefetched operand and a page's DMA is ``pool[layer, :, rows]``,
+so a layer loop carries the pool whole and no layer is ever sliced out of it
+into a value of its own (as ``ssd_ragged`` takes its state).  The kernels
+only read the pool.  ``pages[s, j]`` gives page ids (row-blocks of
 ``block_size``). Positions ``c = j*block_size + r`` are masked against the
 token's causal position and its sequence's context length.
 """
@@ -99,16 +104,19 @@ def shared_walk_rows(run_lengths, query_block: int = QUERY_BLOCK) -> int:
     return shared
 
 
-def _kernel_qblock(tables_ref, slot_ref, pos_ref, clen_ref, q_ref, rowpos_ref,
-                   rowclen_ref, k_hbm, v_hbm, o_ref, m_scr, l_scr, acc_scr,
-                   k_buf, v_buf, sem_k, sem_v, *, bs, group, qb,
+def _kernel_qblock(tables_ref, slot_ref, pos_ref, clen_ref, layer_ref, q_ref,
+                   rowpos_ref, rowclen_ref, k_hbm, v_hbm, o_ref, m_scr, l_scr,
+                   acc_scr, k_buf, v_buf, sem_k, sem_v, *, bs, group, qb,
                    pages_per_step, sm_scale, window=None):
     """Grid (T / qb,): one program, ``qb`` rows of the token array, every
     KV head; ``q_ref`` / ``o_ref`` are ``[nkv, qb*group, d]``, row = token
-    * group + head of the group.  The rows are cut into runs of one
-    sequence; each run is one double-buffered walk of ``pages_per_step``
-    pages a step, a page's rows of all KV heads in one strided DMA."""
+    * group + head of the group; ``k_hbm`` / ``v_hbm`` are every layer's
+    pool ``[L, nkv, P, d]`` and ``layer_ref[0]`` the layer to read.  The
+    rows are cut into runs of one sequence; each run is one
+    double-buffered walk of ``pages_per_step`` pages a step, a page's rows
+    of all KV heads in one strided DMA."""
     base = pl.program_id(0) * qb
+    layer = layer_ref[0]
     nkv, rows = q_ref.shape[:2]
     m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
     l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
@@ -131,10 +139,10 @@ def _kernel_qblock(tables_ref, slot_ref, pos_ref, clen_ref, q_ref, rowpos_ref,
         def page_copies(buf, p, page):
             dst = pl.dslice(pl.multiple_of(p * bs, bs), bs)
             src = pl.dslice(page * bs, bs)
-            return (pltpu.make_async_copy(k_hbm.at[:, src],
+            return (pltpu.make_async_copy(k_hbm.at[layer, :, src],
                                           k_buf.at[buf, :, dst],
                                           sem_k.at[buf]),
-                    pltpu.make_async_copy(v_hbm.at[:, src],
+                    pltpu.make_async_copy(v_hbm.at[layer, :, src],
                                           v_buf.at[buf, :, dst],
                                           sem_v.at[buf]))
 
@@ -236,12 +244,13 @@ def _kernel_qblock(tables_ref, slot_ref, pos_ref, clen_ref, q_ref, rowpos_ref,
     o_ref[...] = (acc_scr[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
-def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
-                  k_hbm, v_hbm, o_ref, k_buf, v_buf, sem_k, sem_v, *,
+def _kernel_quant(pages_ref, pos_ref, clen_ref, layer_ref, q_ref, ksc_ref,
+                  vsc_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem_k, sem_v, *,
                   bs, group, sm_scale, window=None):
     """Int8-KV row kernel, grid (T, nkv): ONE program per (token, KV head)
     walks that token's live pages, a page a step, in an in-kernel
-    fori_loop with double-buffered manual DMA.  The page payloads are int8
+    fori_loop with double-buffered manual DMA.  The page payloads, read out
+    of every layer's pool ``[L, nkv, P, d]`` at ``layer_ref[0]``, are int8
     with one fp32 scale per (head, row).  Only the d-wide payload rides
     the manual double-buffered DMA (half the bytes of the bf16 cache — the
     decode bandwidth win); the per-head scales are small and arrive whole
@@ -253,6 +262,7 @@ def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
     materialises."""
     t = pl.program_id(0)
     h = pl.program_id(1)
+    layer = layer_ref[0]
     pos = pos_ref[t]
     clen = clen_ref[t]
     j_lo = jnp.int32(0)
@@ -263,10 +273,10 @@ def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
     def page_copy(j, slot):
         page = pages_ref[t, j]
         pltpu.make_async_copy(
-            k_hbm.at[h, pl.dslice(page * bs, bs)], k_buf.at[slot],
+            k_hbm.at[layer, h, pl.dslice(page * bs, bs)], k_buf.at[slot],
             sem_k.at[slot]).start()
         pltpu.make_async_copy(
-            v_hbm.at[h, pl.dslice(page * bs, bs)], v_buf.at[slot],
+            v_hbm.at[layer, h, pl.dslice(page * bs, bs)], v_buf.at[slot],
             sem_v.at[slot]).start()
 
     page_copy(j_lo, 0)
@@ -283,9 +293,9 @@ def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
         # wait() only consumes (sem, dst-bytes) — the src slice need not
         # match the one the copy was started with, so a fixed slice
         # reconstructs an equivalent descriptor for the decrement
-        pltpu.make_async_copy(k_hbm.at[h, pl.dslice(0, bs)],
+        pltpu.make_async_copy(k_hbm.at[layer, h, pl.dslice(0, bs)],
                               k_buf.at[slot], sem_k.at[slot]).wait()
-        pltpu.make_async_copy(v_hbm.at[h, pl.dslice(0, bs)],
+        pltpu.make_async_copy(v_hbm.at[layer, h, pl.dslice(0, bs)],
                               v_buf.at[slot], sem_v.at[slot]).wait()
         page = pages_ref[t, j]
         ks = ksc_ref[0, pl.dslice(page, 1), :]           # [1, bs] f32
@@ -321,11 +331,11 @@ def _kernel_quant(pages_ref, pos_ref, clen_ref, q_ref, ksc_ref, vsc_ref,
 
 
 def _decode_q8(q, k_pages, v_pages, pages, token_pos, token_ctx_len, k_scales,
-               v_scales, bs, sm_scale, window):
+               v_scales, layer, bs, sm_scale, window):
     """The int8-KV row kernel's call: ``pages`` is [T, NB], a table per
-    token."""
+    token; the pools are every layer's, the scales ``[L, nkv, P]``."""
     t, nh, d = q.shape
-    nkv, p_rows = k_pages.shape[0], k_pages.shape[1]
+    nkv, p_rows = k_pages.shape[1], k_pages.shape[2]
     group = nh // nkv
     # q reshaped to [T, nkv, group, d] outside: one KV head's query
     # group per block, full trailing dims (Mosaic block constraint)
@@ -333,13 +343,17 @@ def _decode_q8(q, k_pages, v_pages, pages, token_pos, token_ctx_len, k_scales,
     # whole per-head scales live in VMEM via the normal pipeline,
     # viewed [nkv, pages, bs] so the block's trailing dims are the
     # array's own (Mosaic block constraint) and a page's scales are
-    # one dynamically indexed sublane row
+    # one dynamically indexed sublane row.  The view is a relayout on the
+    # chip, so it is taken of the one layer's scales (1/d of its payload)
+    # and never of the pool's
     n_pages = p_rows // bs
     sc_spec = pl.BlockSpec((1, n_pages, bs), lambda t_, h, *refs: (h, 0, 0))
-    scales = tuple(s.astype(jnp.float32).reshape(nkv, n_pages, bs)
-                   for s in (k_scales, v_scales))
+    scales = tuple(
+        lax.dynamic_index_in_dim(s, layer[0], 0, keepdims=False)
+        .astype(jnp.float32).reshape(nkv, n_pages, bs)
+        for s in (k_scales, v_scales))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(t, nkv),
         # the page pools stay in HBM; the kernel DMAs live pages into
         # its double buffer itself
@@ -361,7 +375,7 @@ def _decode_q8(q, k_pages, v_pages, pages, token_pos, token_ctx_len, k_scales,
         interpret=INTERPRET,
         name="paged_decode_q8",
     )(pages.astype(jnp.int32), token_pos.astype(jnp.int32),
-      token_ctx_len.astype(jnp.int32), q.reshape(t, nkv, group, d),
+      token_ctx_len.astype(jnp.int32), layer, q.reshape(t, nkv, group, d),
       *scales, k_pages, v_pages)
     return out.reshape(t, nh, d)
 
@@ -371,16 +385,26 @@ def _decode_q8(q, k_pages, v_pages, pages, token_pos, token_ctx_len, k_scales,
 def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
                            token_ctx_len, block_size: int, sm_scale: float,
                            window: int | None = None,
-                           k_scales=None, v_scales=None, token_slot=None):
-    """q: [T, nh, d]; k_pages/v_pages: [nkv, P, d]; token_pos/token_ctx_len:
-    [T]; ``pages``: page ids, [S, NB] block tables with ``token_slot`` [T]
-    naming each token's table row, or [T, NB] a table per token without
-    it; ``window``: Mistral sliding window (key visible iff qpos - kpos <
-    window).  With ``k_scales``/``v_scales`` [nkv, P] the page payloads
+                           k_scales=None, v_scales=None, token_slot=None,
+                           layer=None):
+    """q: [T, nh, d]; k_pages/v_pages: [nkv, P, d], or every layer's pool
+    [L, nkv, P, d] with ``layer`` (a traced scalar will do) naming the one
+    to read; token_pos/token_ctx_len: [T]; ``pages``: page ids, [S, NB]
+    block tables with ``token_slot`` [T] naming each token's table row, or
+    [T, NB] a table per token without it; ``window``: Mistral sliding
+    window (key visible iff qpos - kpos < window).  With
+    ``k_scales``/``v_scales`` [nkv, P] (or [L, nkv, P]) the page payloads
     are int8 rows scaled per (head, row) — ref KV-block layout
     inference/v2/ragged/kv_cache.py:40.  Returns [T, nh, d]."""
+    if layer is None:
+        # one layer's pages are a pool of one layer (a free reshape)
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if k_scales is not None:
+            k_scales, v_scales = k_scales[None], v_scales[None]
+        layer = 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     t, nh, d = q.shape
-    nkv = k_pages.shape[0]
+    nkv = k_pages.shape[1]
     group = nh // nkv
     bs = block_size
     i32 = lambda a: a.astype(jnp.int32)
@@ -388,8 +412,8 @@ def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
         if token_slot is not None:
             pages = pages[token_slot]
         return _decode_q8(q, k_pages, v_pages, pages, token_pos,
-                          token_ctx_len, k_scales, v_scales, bs, sm_scale,
-                          window)
+                          token_ctx_len, k_scales, v_scales, layer, bs,
+                          sm_scale, window)
 
     slot = (jnp.arange(t, dtype=jnp.int32) if token_slot is None
             else i32(token_slot))
@@ -415,7 +439,7 @@ def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
     col_spec = pl.BlockSpec((rows, 1), lambda b, *refs: (b, 0))
     col = lambda a: jnp.repeat(a, group)[:, None]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(tp // qb,),
         # the page pools stay in HBM; the kernel DMAs live pages into
         # its double buffer itself
@@ -441,7 +465,7 @@ def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
         out_shape=jax.ShapeDtypeStruct((nkv, tp * group, d), q.dtype),
         interpret=INTERPRET,
         name="paged_qblock",
-    )(i32(pages), slot, pos, clen,
+    )(i32(pages), slot, pos, clen, layer,
       q4.swapaxes(0, 1).reshape(nkv, tp * group, d), col(pos), col(clen),
       k_pages, v_pages)
     out = out.reshape(nkv, tp, group, d).swapaxes(0, 1)

@@ -13,6 +13,7 @@ pytest-xdist every worker imports every test file.
 
 import functools
 import importlib
+import math
 import os
 
 import jax
@@ -147,6 +148,117 @@ def test_ssd_ragged_published_shapes(chip, t):
                 if "custom-call" in ln and "ssd_ragged" in ln)
     assert "output_to_operand_aliasing" in call
     assert "f32[6,65,32,128,256]" in call
+
+
+# -- the WHOLE ragged step: the pools ride the layer loop's carry ------------
+def _abstract(chip, tree):
+    return jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)
+
+
+def _compile_step(chip, cfg, pool, nb, t, state=None):
+    """``ragged_forward_sampled`` for one described v5e as the engine jits
+    it (pools and a mixer's state donated): 64 sequences and the padding
+    row, ``nb`` pages of 16 a sequence, ``t`` rows.  Abstract weights."""
+    from deepspeed_tpu.inference.v2 import model as v2_model
+    from deepspeed_tpu.models import transformer as tf_model
+
+    params = _abstract(chip, jax.eval_shape(
+        lambda k: tf_model.init_params(cfg, k), jax.random.PRNGKey(0)))
+    rows = chip((t,), I32)
+    fn = functools.partial(v2_model.ragged_forward_sampled, cfg=cfg,
+                           block_size=_BS, greedy=True)
+    args = (params, pool, pool, rows, rows, rows, rows, chip((65, nb), I32),
+            chip((65,), I32), chip((65,), I32), chip((2,), jnp.uint32),
+            chip((), F32))
+    kw = {} if state is None else {"state": state}
+    with jax.default_matmul_precision("default"):
+        return jax.jit(fn, donate_argnums=(1, 2),
+                       donate_argnames=("state",) if kw else None).lower(
+            *args, **kw).compile()
+
+
+def _aliased_outputs(text):
+    """{output index: parameter number} of the compiled module."""
+    import re
+
+    head = text.split("input_output_alias={", 1)[1].split(
+        ", entry_computation_layout", 1)[0]
+    return {int(o): int(p) for o, p in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", head)}
+
+
+def _assert_pools_in_place(compiled, pool_dims, n_aliased, temp_limit):
+    """The step returns every donated buffer as the buffer it came in,
+    keeps no temporary the size of a layer's pages, copies and slices no
+    pool-shaped or layer-of-pool-shaped array, and hands the kernel the
+    whole pool."""
+    import re
+
+    text = compiled.as_text()
+    assert len(_aliased_outputs(text)) == n_aliased, _aliased_outputs(text)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < temp_limit, mem
+    assert mem.alias_size_in_bytes >= 2 * 2 * math.prod(pool_dims)
+    whole = "bf16[" + ",".join(map(str, pool_dims)) + "]"
+    one_layer = "bf16[" + ",".join(map(str, pool_dims[1:])) + "]"
+    moved = []
+    for line in text.splitlines():
+        # ``%name = shape{layout} op(operands...)``; a fusion is named for
+        # what it holds (``copy_dynamic-update-slice_fusion.4``)
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+?)[{ ]\S* ?([\w-]+)\(", line)
+        if m and m.group(2) in (whole, one_layer) and re.search(
+                r"copy|dynamic-slice|dynamic-update-slice",
+                m.group(1) if m.group(3) == "fusion" else m.group(3)):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+    call = next(ln for ln in text.splitlines()
+                if "custom-call(" in ln and "paged_qblock" in ln)
+    layouts = call.split("operand_layout_constraints={", 1)[1]
+    assert layouts.count(whole) == 2, call[:400]
+
+
+@pytest.mark.parametrize("t", [256, 16])
+def test_ragged_step_carries_the_pools_mistral_7b_l16(chip, t):
+    """The serving step of ``mistral-7b-l16`` (16 layers at the published
+    widths, two pools ``bf16[16,8,32768,128]`` of 1 GiB, 65 x 256 block
+    tables; a full 256-row step and the smallest bucket).  As the scan's
+    xs/ys the pools cost 2.5 GiB of temporaries and three passes over
+    both pools a step (PERF.md, PR 30)."""
+    from deepspeed_tpu.models import get_model_config
+
+    cfg = get_model_config(
+        "mistral-7b", num_layers=16, param_dtype=BF16, dtype=BF16,
+        v2_modules=(("attention", "paged_pallas"),))
+    dims = (16, 8, 2048 * _BS, 128)
+    compiled = _compile_step(chip, cfg, chip(dims, BF16), 256, t)
+    # activations only: far below one layer's pages (64 MiB)
+    _assert_pools_in_place(compiled, dims, n_aliased=2,
+                           temp_limit=16 * 2 ** 20)
+
+
+def test_ragged_step_carries_the_pools_beside_a_mixer(chip):
+    """The same with a Mamba-2 mixer in every block, at Falcon-H1-34B's
+    widths and six layers (``falcon-h1-34b-l6``): the pools
+    ``bf16[6,4,69632,128]`` ride the carry beside the recurrent slots,
+    and all four donated arrays come back in place."""
+    from deepspeed_tpu.inference.v2 import model as v2_model
+    from deepspeed_tpu.models import get_model_config
+
+    cfg = get_model_config(
+        "falcon-h1-34b", num_layers=6, param_dtype=BF16, dtype=BF16,
+        v2_modules=(("attention", "paged_pallas"), ("ssm", "ssd_pallas")))
+    dims = (6, 4, 4352 * _BS, 128)
+    state = _abstract(chip, jax.eval_shape(
+        lambda: v2_model.new_ssm_state(cfg, 64)))
+    compiled = _compile_step(chip, cfg, chip(dims, BF16), 64, 256,
+                             state=state)
+    # a layer's pages are 68 MiB here; the mixer's activations are most
+    # of what is left
+    _assert_pools_in_place(compiled, dims, n_aliased=4,
+                           temp_limit=64 * 2 ** 20)
+    call = next(ln for ln in compiled.as_text().splitlines()
+                if "tpu_custom_call" in ln and "%ssd_ragged" in ln)
+    assert "output_to_operand_aliasing" in call
 
 
 def test_paged_qblock_group_of_five(chip):

@@ -309,3 +309,278 @@ def test_int8_kv_cache_generation():
     agree = np.mean([np.mean(np.asarray(a[:4]) == np.asarray(b[:4]))
                      for a, b in zip(outs["bf16"], outs["int8"])])
     assert agree >= 0.5, (agree, outs)
+
+
+# -- the pools ride the layer loop's carry: same bits as sliced and restacked -
+_CARRY_CASES = {
+    # preset, overrides, int8 cache, attention pinned
+    "llama": ("llama-tiny", {}, False, None),
+    "llama_bf16": ("llama-tiny", {"dtype": "bfloat16"}, False, None),
+    "llama_int8_cache": ("llama-tiny", {}, True, None),
+    "mistral_window": ("mistral-tiny", {}, False, None),
+    "gptneo_alt_window": ("gptneo-tiny", {}, False, None),
+    "mixtral_moe": ("mixtral-tiny", {}, False, None),
+    "bloom_alibi": ("bloom-tiny", {}, False, None),
+    "falcon_h1_mixer": ("falcon-h1-tiny", {}, False, None),
+    # the kernels (interpreted), which read the pool by a layer index
+    "head128_kernel": ("mistral-tiny", {"hidden_size": 256, "num_heads": 2,
+                                        "num_kv_heads": 1}, False,
+                       "paged_pallas"),
+    "head128_kernel_int8_cache": ("llama-tiny", {
+        "hidden_size": 256, "num_heads": 2, "num_kv_heads": 1}, True,
+        "paged_pallas"),
+}
+
+
+def _carry_case(name):
+    """A model, its weights, two pools filled with noise and one step: a
+    20-row chunk of sequence 0 at positions 10..29 (it straddles two
+    pages of 16), a decode row of sequence 1 at position 33, three
+    padding rows that write to the garbage page 0."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2 import model as m2
+    from deepspeed_tpu.models import transformer as tf_model
+
+    preset, over, int8, attention = _CARRY_CASES[name]
+    over = dict(over)
+    if "dtype" in over:
+        over["dtype"] = getattr(jnp, over["dtype"])
+    if attention:
+        over["v2_modules"] = (("attention", attention),)
+    cfg = get_model_config(preset, **over)
+    params = jax.jit(lambda k: tf_model.init_params(cfg, k))(
+        jax.random.PRNGKey(0))
+    bs, n_pages = 16, 12
+    shape = (cfg.num_layers, cfg.kv_heads, n_pages * bs, cfg.dim_per_head)
+
+    def pool(seed):
+        rng = np.random.default_rng(seed)
+        if int8:
+            return {"q": jnp.asarray(rng.integers(-127, 128, size=shape),
+                                     jnp.int8),
+                    "s": jnp.asarray(rng.uniform(0.01, 0.02, size=shape[:3]),
+                                     jnp.float32)}
+        return jnp.asarray(rng.normal(size=shape), cfg.dtype)
+
+    tables = np.array([[1, 2, 3], [4, 5, 6], [7, 8, 9], [0, 0, 0]], np.int32)
+    slot = np.array([0] * 20 + [1] + [3] * 3, np.int32)
+    pos = np.array(list(range(10, 30)) + [33] + [0] * 3, np.int32)
+    dest = np.where(slot < 3, tables[slot, pos // bs] * bs + pos % bs,
+                    0).astype(np.int32)
+    ids = np.random.default_rng(1).integers(
+        0, cfg.vocab_size, size=slot.shape).astype(np.int32)
+    step = [jnp.asarray(a) for a in (
+        ids, slot, pos, dest, tables, np.array([30, 34, 0, 0], np.int32),
+        np.array([19, 20, 0, 0], np.int32))]
+    state = None
+    if cfg.ssm is not None:
+        state = jax.tree.map(
+            lambda a: jnp.asarray(np.random.default_rng(5).normal(
+                size=a.shape), a.dtype), m2.new_ssm_state(cfg, 3))
+    return cfg, params, pool(2), pool(3), step, state, bs
+
+
+def _sliced_and_restacked(params, cache_k, cache_v, token_ids, token_slot,
+                          token_pos, token_dest, block_tables, ctx_lens,
+                          state, *, cfg, block_size, state_slot=None):
+    """The trunk as it was before the pools rode the carry: every layer's
+    pages are sliced out of the pool into a value of their own, updated
+    there and stacked into a NEW pool.  Returns (x, cache_k', cache_v',
+    state') as ``_ragged_trunk`` does."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2 import model as m2
+    from deepspeed_tpu.models.transformer import _norm
+
+    ssm_meta = m2._ssm_meta(cfg, state, token_slot if state_slot is None
+                            else state_slot, token_pos)
+    x = m2._embed_rows(params, token_ids, token_pos, cfg)
+    meta = m2._step_meta(token_slot, token_pos, token_dest, block_tables,
+                         ctx_lens, block_size)
+    every = max(1, cfg.moe_layer_freq)
+    # layer pairs where the window alternates, as the old loop scanned them
+    period = 2 if cfg.alt_window else 1
+    group = lambda tree: jax.tree.map(
+        lambda a: a.reshape((cfg.num_layers // period, period)
+                            + a.shape[1:]), tree)
+    ungroup = lambda tree: jax.tree.map(
+        lambda a: a.reshape((cfg.num_layers,) + a.shape[2:]), tree)
+
+    def body(carry, scanned):
+        h, ssm = carry
+        lp, k_group, v_group, first, conv_group = scanned
+        k_out, v_out, conv_out = [], [], []
+        for j in range(period):
+            member = lambda tree, j=j: jax.tree.map(lambda a: a[j], tree)
+            lcfg = cfg
+            if cfg.alt_window and j % 2 == 0:
+                lcfg = cfg.replace(sliding_window=None)
+            is_moe = cfg.is_moe and (
+                True if every == 1 else (first + j) % every == every - 1)
+            st = ({"ssm": ssm, "conv": conv_group[j], "layer": first + j}
+                  if cfg.ssm else None)
+            # the layer's pages, alone: a pool of one layer, its layer 0
+            h, k1, v1, st = m2._ragged_layer(
+                h, member(lp), jax.tree.map(lambda a: a[None],
+                                            member(k_group)),
+                jax.tree.map(lambda a: a[None], member(v_group)), 0, meta,
+                lcfg, layer_is_moe=is_moe, state=st, ssm_meta=ssm_meta)
+            k_out.append(k1)
+            v_out.append(v1)
+            if cfg.ssm:
+                ssm = st["ssm"]
+                conv_out.append(st["conv"])
+        stack = lambda pools: jax.tree.map(
+            lambda *a: jnp.concatenate(a, axis=0), *pools)
+        return (h, ssm), (stack(k_out), stack(v_out),
+                          jnp.stack(conv_out) if cfg.ssm else None)
+
+    (x, ssm), (cache_k, cache_v, conv) = jax.lax.scan(
+        body, (x, state["ssm"] if cfg.ssm else None),
+        (group(params["layers"]), group(cache_k), group(cache_v),
+         jnp.arange(0, cfg.num_layers, period),
+         group(state["conv"]) if cfg.ssm else None))
+    if cfg.ssm:
+        state = {"ssm": ssm, "conv": ungroup(conv)}
+    return (_norm(x, params["final_norm"], cfg), ungroup(cache_k),
+            ungroup(cache_v), state)
+
+
+def _assert_same_bits(got, want):
+    import jax
+
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g.astype("float32")),
+                                      np.asarray(w.astype("float32")))
+
+
+@pytest.fixture
+def interpreted_kernels():
+    import importlib
+
+    pm = importlib.import_module("deepspeed_tpu.ops.pallas.paged_attention")
+    old, pm.INTERPRET = pm.INTERPRET, True
+    yield
+    pm.INTERPRET = old
+
+
+@pytest.mark.parametrize("name", list(_CARRY_CASES))
+def test_carried_pools_same_bits_as_sliced_and_restacked(
+        name, interpreted_kernels):
+    """``ragged_forward``'s logits, pools and recurrent state against the
+    formulation it replaced, bit for bit: a chunk whose rows straddle two
+    pages, a decode row, padding rows into the garbage page."""
+    import functools
+
+    import jax
+
+    from deepspeed_tpu.inference.v2 import model as m2
+
+    cfg, params, ck, cv, step, state, bs = _carry_case(name)
+    ids, slot, pos, dest, tables, ctx, logits_idx = step
+    got = jax.jit(functools.partial(m2.ragged_forward, cfg=cfg,
+                                    block_size=bs))(
+        params, ck, cv, *step, state)
+
+    @jax.jit
+    def want_fn(params, ck, cv, state):
+        x, ck, cv, state = _sliced_and_restacked(
+            params, ck, cv, ids, slot, pos, dest, tables, ctx, state,
+            cfg=cfg, block_size=bs)
+        logits = m2._lm_head(x[logits_idx], params, cfg)
+        if cfg.ssm:
+            logits = logits * cfg.ssm.lm_head_multiplier
+        out = (logits.astype("float32"), ck, cv)
+        return out + (state,) if cfg.ssm else out
+
+    want = want_fn(params, ck, cv, state)
+    _assert_same_bits(got, want)
+    # the step wrote: the pools are not what came in, outside page 0 too
+    k_in, k_out = jax.tree.leaves(ck)[0], jax.tree.leaves(got[1])[0]
+    assert not np.array_equal(np.asarray(k_in[:, :, bs:].astype("float32")),
+                              np.asarray(k_out[:, :, bs:].astype("float32")))
+
+
+@pytest.mark.parametrize("name", ["llama", "mistral_window",
+                                  "head128_kernel"])
+def test_verify_step_same_bits_as_sliced_and_restacked(name,
+                                                       interpreted_kernels):
+    """``ragged_forward_verify`` runs the same trunk: every row's argmax
+    and the pools, bit for bit."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2 import model as m2
+
+    cfg, params, ck, cv, step, _, bs = _carry_case(name)
+    got = jax.jit(functools.partial(m2.ragged_forward_verify, cfg=cfg,
+                                    block_size=bs))(params, ck, cv, *step)
+
+    @jax.jit
+    def want_fn(params, ck, cv):
+        x, ck, cv, _ = _sliced_and_restacked(
+            params, ck, cv, *step[:-1], None, cfg=cfg, block_size=bs)
+        logits = m2._lm_head(x, params, cfg).astype(jnp.float32)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), ck, cv
+
+    _assert_same_bits(got, want_fn(params, ck, cv))
+
+
+@pytest.mark.parametrize("name", ["llama", "gptneo_alt_window",
+                                  "falcon_h1_mixer", "head128_kernel"])
+def test_decode_loop_same_bits_as_sliced_and_restacked(name,
+                                                       interpreted_kernels):
+    """``ragged_decode_loop`` hands its carried pools to the layer loop's
+    carry: four fused steps (one slot inactive, its row the garbage
+    page's) against four single steps of the old formulation."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2 import model as m2
+
+    cfg, params, ck, cv, step, state, bs = _carry_case(name)
+    tables = step[4][:3]
+    tokens0 = step[0][:3]
+    ctx0 = jnp.asarray([30, 15, 0], jnp.int32)
+    active = jnp.asarray([True, True, False])
+    key, n_steps = jax.random.PRNGKey(0), 4
+    got = jax.jit(functools.partial(
+        m2.ragged_decode_loop, cfg=cfg, block_size=bs, n_steps=n_steps,
+        greedy=True))(params, ck, cv, tokens0, ctx0, active, tables, key,
+                      jnp.float32(1.0), state=state)
+
+    slots = jnp.arange(3, dtype=jnp.int32)
+    # an inactive row's recurrent state is the garbage slot's
+    state_slot = None if state is None else jnp.where(
+        active, slots, state["ssm"].shape[1] - 1)
+
+    @jax.jit
+    def one_step(params, ck, cv, state, tokens, ctx):
+        dest = jnp.where(active, tables[slots, ctx // bs] * bs + ctx % bs, 0)
+        ctx_after = ctx + active.astype(jnp.int32)
+        x, ck, cv, state = _sliced_and_restacked(
+            params, ck, cv, tokens, slots, ctx, dest, tables, ctx_after,
+            state, cfg=cfg, block_size=bs, state_slot=state_slot)
+        logits = m2._lm_head(x, params, cfg).astype(jnp.float32)
+        if cfg.ssm:
+            logits = logits * cfg.ssm.lm_head_multiplier
+        nxt = jnp.where(active, jnp.argmax(logits, -1).astype(jnp.int32), 0)
+        return nxt, ctx_after, ck, cv, state
+
+    tokens, ctx, st = tokens0, ctx0, state
+    sampled = []
+    for _ in range(n_steps):
+        tokens, ctx, ck, cv, st = one_step(params, ck, cv, st, tokens, ctx)
+        sampled.append(tokens)
+    want = (jnp.stack(sampled), ctx, ck, cv) + ((st,) if cfg.ssm else ())
+    _assert_same_bits(got, want)

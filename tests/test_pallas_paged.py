@@ -212,6 +212,59 @@ def test_paged_quantized_parity(t, nh, nkv, d, n_pages, nb, bs):
     assert qerr < 0.15, qerr  # int8 per-row quantization noise bound
 
 
+# -- every layer's pool and a layer index: the layer loop carries the pool ---
+_N_LAYERS = 5
+
+
+@pytest.mark.parametrize("layer", [0, 2, _N_LAYERS - 1],
+                         ids=["first", "middle", "last"])
+@pytest.mark.parametrize("kind", ["bf16", "window", "int8", "ragged_step"])
+def test_layer_of_a_pool_matches_the_layers_own_pages(kind, layer):
+    """``paged_decode_attention(pool4d, layer=l)`` reads ``pool4d[l]``
+    out of the whole pool ``[L, nkv, P, d]`` and nothing else: the same
+    bits as the call on that layer's pages alone (a traced ``l``, as the
+    layer scan hands it over), with the other layers holding noise."""
+    t, nh, nkv, d, n_pages, nb, bs = 5, 8, 2, 64, 16, 3, 16
+    q, _, _, tbl, pos, clen = _make_case(
+        jax.random.PRNGKey(7), t, nh, nkv, d, n_pages, nb, bs)
+    kw = dict(block_size=bs, sm_scale=1.0 / np.sqrt(d))
+    if kind == "ragged_step":
+        q, _, _, tbl, slot, pos, clen, _ = _ragged_step(
+            [(20, 1), (45, 1), (16, 40), (0, 30)], t=96, nb=8, bs=bs,
+            nh=nh, nkv=nkv, d=d)
+        kw["token_slot"] = slot
+        n_pages = 12
+    if kind == "window":
+        kw["window"] = 24
+    kk, kv = jax.random.split(jax.random.PRNGKey(8))
+    shape = (_N_LAYERS, nkv, n_pages * bs, d)
+    pools = [jax.random.normal(kk, shape, jnp.bfloat16),
+             jax.random.normal(kv, shape, jnp.bfloat16)]
+    scales = []
+    if kind == "int8":
+        for i, p in enumerate(pools):
+            pf = p.astype(jnp.float32)
+            sc = jnp.maximum(jnp.max(jnp.abs(pf), axis=-1), 1e-8) / 127.0
+            pools[i] = jnp.clip(jnp.round(pf / sc[..., None]), -127,
+                                127).astype(jnp.int8)
+            scales.append(sc)
+
+    def attend(pools, scales, **layer):
+        sc = dict(zip(("k_scales", "v_scales"), scales))
+        return _decode_fn(q, *pools, tbl, pos, clen, **sc, **layer, **kw)
+
+    def own_pages(l):
+        return attend([p[l] for p in pools], [sc[l] for sc in scales])
+
+    got = jax.jit(lambda l: attend(pools, scales, layer=l))(jnp.int32(layer))
+    assert got.shape == q.shape
+    same = lambda a, b: np.array_equal(np.asarray(a.astype(jnp.float32)),
+                                       np.asarray(b.astype(jnp.float32)))
+    assert same(got, own_pages(layer))
+    # and it is that layer: its neighbour's pages give another answer
+    assert not same(got, own_pages((layer + 1) % _N_LAYERS))
+
+
 # -- the ragged step: runs of one sequence's rows share a page walk ---------
 def _ragged_step(items, t, nb, bs, nh=4, nkv=2, d=64, seed=0, poison=False):
     """A step as ``build_ragged_batch`` lays it out: each ``(cached,
