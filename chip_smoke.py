@@ -29,7 +29,7 @@ from importlib import metadata
 import numpy as np
 
 # One-chip sizes.  gpt2-350m trains at its full published width and depth
-# (bench.py row_gpt2_350m's configuration with gas cut 8 → 2).  mistral-7b
+# (ZeRO-1 AdamW in bf16, gas 2).  mistral-7b
 # serves at its published widths with bf16 weights; all 32 layers are
 # 13.6 GiB of them, and the compiler's own count for a 15.75 GiB v5e
 # (weights + the KV pool + up to 2.5 pool-sized copies the fused decode

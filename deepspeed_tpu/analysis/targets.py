@@ -1,28 +1,28 @@
-"""Bench-row audit targets: every step configuration ``bench.py`` times
+"""Audit targets: every step configuration the engines are run with
 gets a statically auditable twin here, scaled to the virtual 8-device
 CPU mesh so the tier-1 suite and ``tools/graft_lint.py --rows/--memory``
 can lower + audit each one WITHOUT running a step.
 
-The mapping (see bench.py's row table):
+The targets, and the step each one audits:
 
 =====================  ==============================================
-target                 bench row(s) whose step it audits
+target                 step it audits
 =====================  ==============================================
-``train_zero1``        gpt2_350m (primary ZeRO-1 train step)
-``train_zero3``        llama8b_class_zero3 / peak_params base rungs
-``train_commquant``    gpt2_350m_commquant (int8 quantized DP reduce)
-``train_autosched``    gpt2_350m_autosched (pinned zero3_prefetch)
-``train_fused_rs``     gpt2_350m_autosched fused A/B (decomposed +
+``train_zero1``        ZeRO-1 data-parallel train step
+``train_zero3``        ZeRO-3 train step (gathers + reduce-scatters)
+``train_commquant``    ZeRO-1 with the int8 quantized DP reduce
+``train_autosched``    ZeRO-3 under a pinned zero3_prefetch schedule
+``train_fused_rs``     the schedule's fused A/B (decomposed +
                        fused reduce-scatter epilogue)
-``train_fused_gather`` gpt2_350m_autosched fused A/B (stage-3 fused
+``train_fused_gather`` the schedule's fused A/B (stage-3 fused
                        gather-matmul MLP)
-``ring_attention``     longseq_ring (ring fwd+bwd on the 2×4 mesh)
-``ring_attention_quant``  longseq_ring quantized-wire A/B (int8
+``ring_attention``     ring attention fwd+bwd on the 2×4 mesh
+``ring_attention_quant``  its quantized-wire A/B (int8
                        ring_rotation)
-``v2_decode``          v2_decode / serve_load* (16-token decode step)
-``v2_prefill``         v2_decode / serve_load* (full-budget prefill)
-``v2_verify``          serve_disagg (speculative target verify-k step)
-``v2_spec_draft``      serve_disagg (draft-model propose/decode step)
+``v2_decode``          InferenceEngineV2 16-token decode step
+``v2_prefill``         InferenceEngineV2 full-budget prefill step
+``v2_verify``          speculative target verify-k step
+``v2_spec_draft``      draft-model propose/decode step
 =====================  ==============================================
 
 Each target PREPARES once — build its engine, read the step fn +
@@ -298,7 +298,7 @@ def _prep_v2(phase: str, model_name: str = "gpt2-tiny",
         "max_context": 128})
     # the point of the target is the CONFIGURED tiny geometry — a config
     # nesting drift that silently fell back to defaults would audit a
-    # 512-block step instead of the bench row's twin
+    # 512-block step instead of the configured twin
     assert eng.cfg.num_blocks == 16 and eng.state_manager.max_seqs == 4, \
         (eng.cfg.num_blocks, eng.state_manager.max_seqs)
     fn, args = eng.audit_step_args(phase)

@@ -92,8 +92,8 @@ def estimate_memory_breakdown(model_info: ModelInfo, zero_stage: int,
            // max(1, sp_size * tp_size))
     # fp32 [B, S, V] logits + their cotangent: dominates small models with
     # big vocabs (r04 on-chip validation: the estimator passed gpt2-125m
-    # mb=64 at 11.6GB est but the 6.6GB logits buffer OOM'd the trial —
-    # AUTOTUNE_TPU.json).  Sequence-tiled loss (loss_tiles) avoids the
+    # mb=64 at 11.6GB est but the 6.6GB logits buffer OOM'd the
+    # trial).  Sequence-tiled loss (loss_tiles) avoids the
     # buffer, but the tuner prices the default untiled path.
     logits = (micro_batch * seq_len * max(1, model_info.vocab_size) * 4 * 2
               // max(1, sp_size * tp_size))

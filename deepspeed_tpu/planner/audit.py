@@ -24,6 +24,41 @@ from deepspeed_tpu.planner.space import FleetSpec, ModelSpec
 PLAN_AUDIT_ROWS = ("gpt2_350m", "gpt2_350m_commquant",
                    "gpt2_350m_autosched", "longseq_ring")
 
+# the known-good pinned config of each PLAN_AUDIT_ROWS query at the
+# canonical 8-chip fleet (the regression gate asserts each ranks top-3),
+# plus the 6.7B offload rung the planner must propose sight-unseen
+PINNED_CONFIGS = {
+    "gpt2_350m": {
+        "mesh": {"data": 8},
+        "zero_optimization": {"stage": 1},
+    },
+    "gpt2_350m_commquant": {
+        "mesh": {"data": 8},
+        "zero_optimization": {"stage": 1},
+        "comm_quantization": {"enabled": True, "grad_reduce": "int8"},
+    },
+    "gpt2_350m_autosched": {
+        "mesh": {"data": 8},
+        "zero_optimization": {"stage": 3},
+        "step_schedule": {"mode": "pinned", "gather_prefetch_depth": 2,
+                          "param_persistence_threshold": 100_000},
+    },
+    "longseq_ring": {
+        "mesh": {"seq": 8},
+        "zero_optimization": {"stage": 2},
+    },
+    # streamed host params + chunked NVMe optimizer
+    "gpt2_6_7b_chunked": {
+        "mesh": {"data": 1},
+        "zero_optimization": {
+            "stage": 3,
+            "offload_param": {"device": "cpu"},
+            "offload_optimizer": {"device": "nvme",
+                                  "working_set_bytes": 1 << 30,
+                                  "chunk_bytes": 64 << 20}},
+    },
+}
+
 
 def plan_for_row(name: str, chips: int = 8, *,
                  top: Optional[int] = 10) -> Plan:

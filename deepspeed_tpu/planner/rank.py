@@ -107,7 +107,7 @@ class Plan:
 def config_fragment(model: ModelSpec, cand: Candidate,
                     gas: int = 1) -> Dict[str, Any]:
     """The pinned, load-ready DeepSpeedConfig fragment for a candidate —
-    the same block shapes the bench rows pin (bench.PINNED_ROW_CONFIGS),
+    the same block shapes as planner/audit.py's PINNED_CONFIGS,
     so a plan's top entry drops straight into ``deepspeed.initialize``."""
     if cand.disagg:
         n = (cand.disagg["prefill_replicas"]

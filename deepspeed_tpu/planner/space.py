@@ -17,8 +17,8 @@ from typing import Any, Dict, List, Optional, Tuple
 from deepspeed_tpu.autotuning.autotuner import (ModelInfo, enumerate_meshes,
                                                 predict_fit)
 
-# offload tiers mirror the peak_params ladder rungs (bench.py
-# _PEAK_LADDER): device-resident → host optimizer → full host → chunked
+# offload tiers, from everything on the device to everything on NVMe:
+# device-resident → host optimizer → full host → chunked
 # host pipeline (PR 16) → NVMe chunk files → full NVMe
 OFFLOAD_TIERS: Tuple[Tuple[str, Optional[Dict[str, Any]]], ...] = (
     ("none", None),

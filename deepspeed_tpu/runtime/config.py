@@ -373,10 +373,10 @@ class TelemetryConfig:
     the first recorded step (exact fused-program FLOPs); set False for
     the free analytic estimate."""
     enabled: bool = False
-    run_id: str = ""               # run-ledger stitching key ("" = none):
+    run_id: str = ""               # the owning run's identity ("" = none):
     # stamped into every StepRecord, the Tracer's trace metadata, and
-    # (via FleetSampler) every TierSnapshot row — telemetry/ledger.py
-    # joins a run's artifacts back together on it
+    # (via FleetSampler) every TierSnapshot row, so a run's artifacts
+    # can be joined back together on it
     jsonl_path: str = ""           # append-only StepRecord log ("" = off)
     prometheus_path: str = ""      # textfile-collector exposition ("" = off)
     interval_steps: int = 1        # record every Nth step

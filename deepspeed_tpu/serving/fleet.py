@@ -70,7 +70,7 @@ TIER_SNAPSHOT_KEYS = (
     "queue_wait_p95_ms",
     "queue_wait_p99_ms",
     "replicas_alive",
-    "run_id",                      # owning run (schema 2; "" = unstitched)
+    "run_id",                      # owning run (schema 2; "" = none)
     "running",                     # admitted + decoding requests (sum)
     "schema",                      # TIER_SNAPSHOT_SCHEMA
     "slo_violation",               # 1 = this tick breached a target

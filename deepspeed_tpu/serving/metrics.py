@@ -38,7 +38,7 @@ _OUTCOMES = ("completed", "failed", "cancelled", "expired", "shed")
 def spec_accept_rate(proposed: int, accepted: int) -> float:
     """THE accept-rate definition: accepted/proposed draft tokens, 0.0
     when no rounds ran.  One function so ``snapshot()``, the replica-set
-    rollup, the fleet sampler, and bench.py cannot drift on the
+    rollup and the fleet sampler cannot drift on the
     denominator (bonus tokens are excluded by construction — see
     :meth:`ServingMetrics.record_spec_round`)."""
     return accepted / max(1, proposed)

@@ -8,23 +8,10 @@ registry; PR-2's invariant is that serving/ never imports jax) stay
 jax-free.
 """
 
-from deepspeed_tpu.telemetry import derive
 from deepspeed_tpu.telemetry.export import (EXPORT_TAGS, JsonlExporter,
                                             Telemetry, events_from_record,
                                             read_jsonl, render_prometheus,
                                             write_prometheus_textfile)
-from deepspeed_tpu.telemetry.ledger import (ANOMALY_KINDS, ANOMALY_KEYS,
-                                            DRIFT_KEYS, LEDGER_SCHEMA,
-                                            MANIFEST_ARTIFACT_KEYS,
-                                            MANIFEST_KEYS, ROLLUP_KEYS,
-                                            ROLLUP_RECOVERY_KEYS,
-                                            ROLLUP_SERVE_KEYS,
-                                            ROLLUP_TRAIN_KEYS, VERDICTS,
-                                            diff_rollups, gate_findings,
-                                            load_bench_history, new_run_id,
-                                            plan_drift, rollup_from_manifest,
-                                            scan_manifest, scan_run,
-                                            write_manifest)
 from deepspeed_tpu.telemetry.flight import (FLIGHT_REASONS, FlightRecorder,
                                             Watchdog, dump_bundle,
                                             make_span_recorder)
@@ -54,22 +41,14 @@ def __getattr__(name):
 
 
 __all__ = [
-    "ANOMALY_KEYS", "ANOMALY_KINDS", "AutoCapture", "Counter",
-    "DRIFT_KEYS", "EVENT_NAMES", "EXPORT_TAGS",
+    "AutoCapture", "Counter", "EVENT_NAMES", "EXPORT_TAGS",
     "FLIGHT_REASONS", "FlightRecorder", "Gauge", "Histogram",
-    "JsonlExporter", "LEDGER_SCHEMA", "MANIFEST_ARTIFACT_KEYS",
-    "MANIFEST_KEYS", "MetricsRegistry", "NULL_SPAN", "NULL_TRACER",
-    "ROLLUP_KEYS", "ROLLUP_RECOVERY_KEYS", "ROLLUP_SERVE_KEYS",
-    "ROLLUP_TRAIN_KEYS",
+    "JsonlExporter", "MetricsRegistry", "NULL_SPAN", "NULL_TRACER",
     "SCHEMA_VERSION", "SLOLedger", "SLOSpec", "SLO_BLOCK_KEYS",
     "SLO_LEDGER_KEYS", "SLO_SCENARIO_KEYS", "SLO_TARGET_KEYS",
-    "SPAN_NAMES", "Span", "StepRecord", "Telemetry",
-    "Tracer", "VERDICTS", "Watchdog", "build_capture_report",
-    "collect_hbm_stats",
-    "derive", "detect_peak_flops_per_sec", "diff_rollups", "dump_bundle",
-    "events_from_record", "gate_findings", "load_bench_history",
-    "make_span_recorder", "new_run_id", "plan_drift", "read_jsonl",
-    "record_keys", "render_prometheus", "rollup_from_manifest",
-    "scan_manifest", "scan_run", "write_manifest",
+    "SPAN_NAMES", "Span", "StepRecord", "Telemetry", "Tracer", "Watchdog",
+    "build_capture_report", "collect_hbm_stats",
+    "detect_peak_flops_per_sec", "dump_bundle", "events_from_record",
+    "make_span_recorder", "read_jsonl", "record_keys", "render_prometheus",
     "write_prometheus_textfile",
 ]

@@ -19,7 +19,7 @@ from __future__ import annotations
 import json
 import os
 from collections import deque
-from typing import Deque, Dict, Optional, Tuple
+from typing import Deque, Dict, Optional, Sequence, Tuple
 
 from deepspeed_tpu.utils.logging import logger
 from deepspeed_tpu.utils.trace import TraceProfiler
@@ -54,6 +54,22 @@ def spans_overlap_estimate(window_totals: Dict[str, Dict]) -> Dict:
            if step_ms > 0 else 0.0)
     return {**phase, "step_ms": step_ms, "exposed_ms": sync_ms,
             "overlap_estimate": round(est, 4)}
+
+
+def trailing_regressed(times: Sequence[float], factor: float,
+                       min_samples: int = 8) -> bool:
+    """The capture-trigger heuristic: windowed ``p95 > factor × median``.
+
+    ``times`` is the trailing window of step wall-times (the capture
+    controller feeds its deque).  Fewer than ``min_samples`` samples or
+    a non-positive factor never trigger.
+    """
+    if factor <= 0 or len(times) < min_samples:
+        return False
+    xs = sorted(times)
+    median = xs[len(xs) // 2]
+    p95 = xs[min(len(xs) - 1, int(0.95 * (len(xs) - 1)))]
+    return median > 0 and p95 > factor * median
 
 
 def hbm_cross_check(static_memory: Optional[Dict],
@@ -192,10 +208,6 @@ class AutoCapture:
 
     # -- trigger logic ---------------------------------------------------
     def _regressed(self) -> bool:
-        # shared with the ledger's anomaly scan (telemetry/derive.py):
-        # windowed p95 > factor × median over the trailing deque
-        from deepspeed_tpu.telemetry.derive import trailing_regressed
-
         return trailing_regressed(list(self._times),
                                   self.regression_factor,
                                   self.MIN_SAMPLES)

@@ -153,9 +153,9 @@ class Telemetry:
         self.cfg = cfg
         self.monitor = monitor
         self.registry = registry or MetricsRegistry()
-        # one run_id per bench row / training run, stamped into every
+        # one run_id per training or serving run, stamped into every
         # StepRecord, the Tracer's trace metadata, and (via FleetSampler)
-        # every TierSnapshot row — the manifest stitching key
+        # every TierSnapshot row — the key they are joined on
         self.run_id = str(getattr(cfg, "run_id", "") or "")
         self.peak_flops_per_sec = (
             float(cfg.peak_flops_per_sec) if cfg.peak_flops_per_sec

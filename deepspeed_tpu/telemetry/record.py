@@ -20,7 +20,7 @@ from typing import Any, Dict, Optional
 SCHEMA_VERSION = 3
 
 # bf16 peak FLOP/s by TPU device kind (matmul peak; the MFU denominator).
-# Sources: public TPU spec sheets; v5e figure matches bench.py's 197e12.
+# Sources: public TPU spec sheets (v5e: 197e12, as benchmark/lib has it).
 _PEAK_FLOPS_BY_KIND = {
     "tpu v2": 45e12,
     "tpu v3": 123e12,
@@ -89,8 +89,8 @@ class StepRecord:
     step: int
     kind: str = "train"                    # train | serving
     schema: int = SCHEMA_VERSION
-    # the run this record belongs to (one bench row = one run_id, shared
-    # with Tracer metadata and FleetSampler rows; "" = unstitched)
+    # the run this record belongs to (telemetry.run_id, shared with
+    # Tracer metadata and FleetSampler rows; "" = none)
     run_id: str = ""
     # timing / throughput
     wall_time_s: float = 0.0

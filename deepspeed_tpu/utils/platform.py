@@ -11,8 +11,8 @@ The cache key includes the directory, so the directory never moves:
 itself — nothing is set in code), else ``<checkout>/.jax_cache``.
 
 ``device_facts_from_child()`` is for the parents that start children
-which need the chip (bench.py's peak_params row, the autotuner's
-isolated trials).  A process that has initialised a JAX backend holds
+which need the chip (the autotuner's isolated trials with
+``isolate_trials=True``).  A process that has initialised a JAX backend holds
 the chip until it exits, so such a parent never asks JAX itself.
 """
 

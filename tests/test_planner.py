@@ -2,9 +2,9 @@
 
 Three families:
 
-1. Regression gate — for every bench row with a pinned known-good
-   config (bench.PINNED_ROW_CONFIGS), the planner's row-mirroring query
-   must rank that config in its TOP-3; the 6.7B chunked-offload ladder
+1. Regression gate — for every audit row with a pinned known-good
+   config (planner.audit.PINNED_CONFIGS), the planner's row-mirroring
+   query must rank that config in its TOP-3; the 6.7B chunked-offload ladder
    rung and a MoE expert-parallel placement must be proposed
    sight-unseen.
 2. Cost-model properties — step time monotone in wire bytes at fixed
@@ -17,8 +17,6 @@ Three families:
 """
 
 import json
-import os
-import sys
 
 import pytest
 
@@ -28,17 +26,8 @@ from deepspeed_tpu.planner import (ANCHOR_TOLERANCE, PLAN_EVIDENCE_KEYS,
                                    apply_anchors, compile_plan,
                                    plan_rank_of, seed_candidates,
                                    step_time)
-from deepspeed_tpu.planner.audit import PLAN_AUDIT_ROWS, plan_for_row
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _pinned():
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    import bench
-
-    return bench.PINNED_ROW_CONFIGS
+from deepspeed_tpu.planner.audit import (PINNED_CONFIGS, PLAN_AUDIT_ROWS,
+                                         plan_for_row)
 
 
 # ---------------------------------------------------------------------
@@ -53,7 +42,7 @@ def row_plans():
 @pytest.mark.parametrize("name", PLAN_AUDIT_ROWS)
 def test_known_good_ranks_top3(row_plans, name):
     plan = row_plans[name]
-    rank = plan_rank_of(plan, _pinned()[name])
+    rank = plan_rank_of(plan, PINNED_CONFIGS[name])
     assert rank is not None and rank <= 3, \
         (name, rank, [r.candidate for r in plan.ranked[:5]])
 
@@ -80,7 +69,7 @@ def test_67b_chunked_proposed_sight_unseen(plan_67b):
     """The peak_params acceptance rung: on a 1-chip 16GiB fleet with a
     64GiB host and NVMe, the planner must propose the chunked-offload
     config the r16 ladder pinned — without ever having run it."""
-    rank = plan_rank_of(plan_67b, _pinned()["gpt2_6_7b_chunked"])
+    rank = plan_rank_of(plan_67b, PINNED_CONFIGS["gpt2_6_7b_chunked"])
     assert rank is not None and rank <= 3, \
         (rank, [r.candidate for r in plan_67b.ranked])
 
@@ -317,41 +306,34 @@ def test_cli_no_fit_exits_nonzero(tmp_path):
 
 
 # ---------------------------------------------------------------------
-# 3c. bench plumbing: resolved_config blobs + plan_validate row
+# 3c. a plain config fragment is what the planner reads
 # ---------------------------------------------------------------------
 
-def test_bench_resolved_config_blob_shape():
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    import bench
+def test_config_fragment_reads_as_chunked_nvme(plan_67b):
+    """A resolved config block (paths and batch keys included) is
+    fragment-shaped: the planner reads its tier from it directly."""
+    from deepspeed_tpu.planner.rank import _frag_key
 
-    blob = bench._resolved_config({
+    frag = {
         "train_micro_batch_size_per_gpu": 4,
         "gradient_accumulation_steps": 2,
-        "mesh": {"data": 8},
+        "mesh": {"data": 1},
         "zero_optimization": {
             "stage": 3,
+            "offload_param": {"device": "cpu"},
             "offload_optimizer": {"device": "nvme", "nvme_path": "/x",
                                   "working_set_bytes": 1 << 30,
                                   "chunk_bytes": 64 << 20}},
-        "comm_quantization": {"enabled": True, "grad_reduce": "int8"},
-    })
-    assert blob["mesh"] == {"data": 8}
-    assert blob["zero_optimization"]["stage"] == 3
-    # offload block keeps the planner-relevant keys, drops paths
-    oo = blob["zero_optimization"]["offload_optimizer"]
-    assert oo == {"device": "nvme", "working_set_bytes": 1 << 30,
-                  "chunk_bytes": 64 << 20}
-    assert json.loads(json.dumps(blob)) == blob
-    # the blob is fragment-shaped: plan_rank_of consumes it directly
-    from deepspeed_tpu.planner.rank import _frag_key
-    assert _frag_key(blob, 8)[3] == "nvme_chunked"
+    }
+    assert _frag_key(frag, 1) == ((("data", 1),), 3, None, "nvme_chunked")
+    rank = plan_rank_of(plan_67b, frag)
+    assert rank is not None
+    assert rank == plan_rank_of(plan_67b,
+                                PINNED_CONFIGS["gpt2_6_7b_chunked"])
+    # without a working set the same block is no chunked tier
+    del frag["zero_optimization"]["offload_optimizer"]["working_set_bytes"]
+    assert _frag_key(frag, 1)[3] != "nvme_chunked"
 
 
-def test_bench_registers_plan_validate_row():
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    import bench
-
-    assert "plan_validate" in bench._ROWS
-    assert set(bench.PINNED_ROW_CONFIGS) >= set(PLAN_AUDIT_ROWS)
+def test_pinned_configs_cover_audit_rows():
+    assert set(PINNED_CONFIGS) >= set(PLAN_AUDIT_ROWS)

@@ -536,40 +536,58 @@ def test_serving_metrics_counters_gauges():
 # ----------------------------------------------------------------------
 # the telemetry_check lint runs as a normal tier-1 test
 # ----------------------------------------------------------------------
-def test_telemetry_check_lint_passes():
-    import importlib.util
+@pytest.fixture
+def telemetry_check():
+    from tools import telemetry_check
 
-    path = os.path.join(os.path.dirname(__file__), "..", "tools",
-                        "telemetry_check.py")
-    spec = importlib.util.spec_from_file_location("telemetry_check", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod.run_all() == []
+    return telemetry_check
 
 
-def test_bench_backlog_queue_is_runnable(monkeypatch, bench_history):
-    """Every queued measurement command in BENCH_MEASURED_r07+.json must
-    still parse against the current bench.py flags, row names, tool
-    scripts, and model registry — a renamed row or retired flag rots the
-    queue silently otherwise (tools/bench_backlog.py).  The queue is the
-    synthetic history of tests/conftest.py, validated against the real
-    bench.py and tools/."""
-    import importlib.util
-    import json
+def test_telemetry_check_lint_passes(telemetry_check):
+    assert telemetry_check.run_all() == []
 
-    path = os.path.join(os.path.dirname(__file__), "..", "tools",
-                        "bench_backlog.py")
-    spec = importlib.util.spec_from_file_location("bench_backlog", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    monkeypatch.setattr(mod, "REPO", bench_history)
-    assert mod.run_all() == []
-    # and a rotted entry is caught
-    with open(os.path.join(bench_history, "BENCH_MEASURED_r18.json"),
-              "w") as f:
-        json.dump({"queued_measurements_r18": [
-            {"what": "retired row", "cmd": "python bench.py --row nope"}]}, f)
-    assert any("unknown bench row" in e for e in mod.run_all())
+
+# (check, the frozen list that gains a name no module has)
+_FROZEN_LIST_OF_CHECK = [
+    ("check_schema", "EXPECTED_RECORD_KEYS"),
+    ("check_span_names", "EXPECTED_SPAN_NAMES"),
+    ("check_quant_comm", "EXPECTED_QUANT_COMM_OPS"),
+    ("check_router_serving", "EXPECTED_REPLICA_TIERS"),
+    ("check_autotuning", "EXPECTED_SCHEDULE_DECISIONS"),
+    ("check_graph_audit", "EXPECTED_FINDING_KINDS"),
+    ("check_memory_audit", "EXPECTED_MEMORY_CLASSES"),
+    ("check_offload", "OFFLOAD_CONFIG_KEYS"),
+    ("check_recovery", "EXPECTED_RECOVERY_STATES"),
+    ("check_planner", "EXPECTED_LINK_CLASSES"),
+    ("check_fleet", "EXPECTED_TIER_SNAPSHOT_KEYS"),
+    ("check_chaos_fleet", "EXPECTED_FAULT_KINDS"),
+]
+
+
+@pytest.mark.parametrize("check,frozen", _FROZEN_LIST_OF_CHECK,
+                         ids=[c for c, _ in _FROZEN_LIST_OF_CHECK])
+def test_telemetry_check_trips(telemetry_check, monkeypatch, check, frozen):
+    """The lint can fail: a frozen list that drifts from its module is
+    reported by the check that owns it, by name."""
+    assert getattr(telemetry_check, check)() == []
+    planted = "zz_planted_name"
+    monkeypatch.setattr(telemetry_check, frozen,
+                        list(getattr(telemetry_check, frozen)) + [planted])
+    errors = getattr(telemetry_check, check)()
+    assert errors and any(planted in e for e in errors), errors
+
+
+def test_telemetry_check_trips_on_an_undocumented_tag(telemetry_check,
+                                                      tmp_path):
+    """... and so is a row that goes missing from the document."""
+    with open(telemetry_check.DOCS, encoding="utf-8") as f:
+        lines = f.read().splitlines(keepends=True)
+    kept = [ln for ln in lines if "telemetry/tokens_per_sec" not in ln]
+    assert len(kept) < len(lines)
+    copy = tmp_path / "OBSERVABILITY.md"
+    copy.write_text("".join(kept), encoding="utf-8")
+    errors = telemetry_check.check_tags_documented(str(copy))
+    assert any("telemetry/tokens_per_sec" in e for e in errors), errors
 
 
 # ----------------------------------------------------------------------

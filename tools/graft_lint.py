@@ -55,9 +55,9 @@ DEFAULT_MEMORY_BASELINE = os.path.join(REPO, "tools",
 
 
 def _setup_mesh_backend() -> None:
-    """Pin the virtual 8-device CPU mesh BEFORE any backend touch (same
-    discipline as ``bench.py --smoke``: audits check graph *structure*,
-    which the CPU mesh lowers identically, and need no chip)."""
+    """Pin the virtual 8-device CPU mesh BEFORE any backend touch
+    (audits check graph *structure*, which the CPU mesh lowers
+    identically, and need no chip)."""
     flags = os.environ.get("XLA_FLAGS", "")
     for flag in ("--xla_force_host_platform_device_count=8",
                  "--xla_backend_optimization_level=0"):

@@ -33,17 +33,16 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 # the shared frozen-vocabulary engine (deepspeed_tpu/analysis/vocab.py):
-# every "frozen list == module list, names documented, bench keys
-# emitted" contract below is ONE VocabSpec registration, shared with
-# tools/graft_lint.py
+# every "frozen list == module list, names documented" contract below
+# is ONE VocabSpec registration, shared with tools/graft_lint.py
 from deepspeed_tpu.analysis.vocab import VocabSpec  # noqa: E402
 from deepspeed_tpu.analysis.vocab import check_all as _vocab_check  # noqa: E402
 
 DOCS = os.path.join(REPO, "docs", "OBSERVABILITY.md")
 
 # frozen with schema version 3 (v2 added offload_overlap_fraction for
-# the chunked host-optimizer pipeline; v3 added run_id, the run-ledger
-# stitching key) — telemetry_check is the tripwire
+# the chunked host-optimizer pipeline; v3 added run_id) —
+# telemetry_check is the tripwire
 EXPECTED_SCHEMA_VERSION = 3
 EXPECTED_RECORD_KEYS = [
     "achieved_flops_per_sec", "comm", "flops_per_step", "flops_source",
@@ -83,31 +82,14 @@ EXPECTED_FLIGHT_REASONS = ["watchdog", "serve_crash", "engine_crash",
 # QUANT_COMM_OPS): every wire movement of the quantized ZeRO collectives
 # is recorded in CommsLogger — and therefore surfaces in the StepRecord
 # `comm` field — under one of these names.  Each must be documented in
-# docs/QUANTIZED_COMM.md; the bench comm-quant row keys below must appear
-# both in bench.py (so the lint trips when the row drifts) and the docs.
+# docs/QUANTIZED_COMM.md.
 QUANT_DOCS = os.path.join(REPO, "docs", "QUANTIZED_COMM.md")
 EXPECTED_QUANT_COMM_OPS = ["quant_all_gather", "quant_reduce_scatter"]
-QUANT_BENCH_KEYS = ["grad_reduce_bytes_fp32", "grad_reduce_bytes_quant",
-                    "bytes_reduction", "loss_delta"]
-
-# frozen ring bench-row vocabulary (same contract as QUANT_BENCH_KEYS):
-# the longseq_ring row keys (bench.py) and the fused-backward hop keys
-# (tools/bench_flash_longseq.py --bwd) must each be emitted by their
-# bench source AND documented in the docs/RING_ATTENTION.md key table —
-# the lint trips when either side drifts.
-RING_DOCS = os.path.join(REPO, "docs", "RING_ATTENTION.md")
-RING_BENCH_KEYS = ["mfu", "placement", "ring_backward", "vs_baseline",
-                   "ring_wire_bytes_fp32", "ring_wire_bytes_quant",
-                   "ring_wire_reduction", "ring_loss_delta"]
-RING_BWD_BENCH_KEYS = ["bwd_ms_per_hop_fused", "bwd_ms_per_hop_xla",
-                       "transient_bytes_fused", "transient_bytes_xla",
-                       "transient_reduction"]
 
 # frozen overlap-scheduler vocabulary (autotuning/overlap_scheduler.py;
 # docs/AUTOTUNING.md): decision names and evidence keys must match the
 # module AND be documented; the step_schedule config keys must be
-# documented; the autosched bench row keys must be emitted by bench.py
-# and documented; and the capture-report keys the scheduler consumes
+# documented; and the capture-report keys the scheduler consumes
 # (telemetry/capture.py) must be documented too.
 AUTOTUNING_DOCS = os.path.join(REPO, "docs", "AUTOTUNING.md")
 EXPECTED_SCHEDULE_DECISIONS = ["decomposed_update", "fused_gather_matmul",
@@ -121,37 +103,15 @@ EXPECTED_STEP_SCHEDULE_KEYS = [
     "param_persistence_threshold", "prefetch_bucket_size", "probe_steps",
     "ring_interleave", "weight_update",
 ]
-AUTOSCHED_BENCH_KEYS = ["mfu_static", "mfu_tuned", "exposed_comm_ms",
-                        "schedule_decision", "fused_gather_loss_delta",
-                        "fused_gather_wire_bytes"]
 CAPTURE_REPORT_SCHED_KEYS = ["dominant_collective", "exposed_ms",
                              "overlap_estimate", "spans", "step"]
 
-# frozen multi-replica serving vocabulary (same contract): the
-# serve_load_multi bench row keys must be emitted by bench.py and
-# documented in docs/SERVING.md; every router-tier Prometheus metric
-# (RouterMetrics over a fresh registry; per-replica counters normalized
-# to their documented `router_routed_r*_total` wildcard) must appear in
-# docs/SERVING.md too.
+# frozen serving vocabulary (docs/SERVING.md): every router-tier
+# Prometheus metric (RouterMetrics over a fresh registry; per-replica
+# counters normalized to their documented `router_routed_r*_total`
+# wildcard) must appear in the doc, and the replica tier names
+# (serving/disagg.py) must match their module and be documented.
 SERVING_DOCS = os.path.join(REPO, "docs", "SERVING.md")
-SERVE_MULTI_BENCH_KEYS = ["agg_tokens_per_sec", "ttft_p95_ms",
-                          "prefix_hit_rate", "prefill_tokens_saved"]
-
-# frozen disaggregated-serving vocabulary (serving/disagg.py;
-# docs/SERVING.md "Disaggregated tiers & speculative decoding"): the
-# serve_disagg bench row keys, the scenario load generator's traffic-mix
-# names (bench.py SCENARIO_MIXES), and the replica tier names must each
-# match their module, be documented, and (for bench keys) be literally
-# emitted by bench.py.
-DISAGG_BENCH_KEYS = ["agg_tokens_per_sec_disagg",
-                     "agg_tokens_per_sec_homog", "ttft_p95_ms_disagg",
-                     "ttft_p95_ms_homog", "tpot_p95_ms_disagg",
-                     "tpot_p95_ms_homog", "handoff_ms_p95",
-                     "handoff_bytes_per_req", "spec_accept_rate",
-                     "scenario_mix", "slo", "fleet_jsonl"]
-EXPECTED_SCENARIO_MIXES = ["burst", "session_heavy",
-                           "shared_system_prompt",
-                           "long_prompt_short_decode"]
 EXPECTED_REPLICA_TIERS = ["prefill", "decode", "unified"]
 
 # frozen static-graph-audit vocabulary (deepspeed_tpu/analysis/report.py;
@@ -177,9 +137,8 @@ EXPECTED_AUDIT_DONATION_KEYS = ["aliased", "declared", "missed",
 
 # frozen memory-plan-audit vocabulary (analysis/report.py MemoryAuditReport;
 # docs/STATIC_ANALYSIS.md): report/totals/buffer/budget/calibration key
-# sets and the buffer-classification classes, plus the peak_params
-# ladder-prediction bench keys — same tripwire contract as the graph
-# audit schema.
+# sets and the buffer-classification classes — same tripwire contract
+# as the graph audit schema.
 EXPECTED_MEMORY_REPORT_KEYS = ["backend", "budget", "buffers",
                                "calibration", "class_bytes", "findings",
                                "label", "num_partitions", "schema",
@@ -193,36 +152,29 @@ EXPECTED_MEMORY_CLASSES = ["activations", "grads", "opt_state", "other",
 EXPECTED_BUDGET_KEYS = ["bucketed_peak_bytes", "budget_bytes",
                         "peak_bytes"]
 EXPECTED_CALIBRATION_KEYS = ["analytic_bytes", "measured_bytes", "ratio"]
-MEMORY_BENCH_KEYS = ["predicted_peak_bytes", "predicted_fit"]
 
 # frozen host-tiered offload vocabulary (runtime/offload.py
 # ChunkedHostOptimizer + nvme/chunk_store.py; docs/OFFLOAD.md): the
-# peak_params ladder's measured per-rung host keys must be emitted by
-# bench.py and documented, and the chunked config knobs must be real
-# OffloadOptimizerConfig fields documented in the offload doc — same
-# tripwire contract as every other vocabulary.
+# chunked config knobs must be real OffloadOptimizerConfig fields
+# documented in the offload doc — same tripwire contract as every other
+# vocabulary.
 OFFLOAD_DOCS = os.path.join(REPO, "docs", "OFFLOAD.md")
-OFFLOAD_BENCH_KEYS = ["host_peak_bytes", "offload_overlap_fraction"]
 OFFLOAD_CONFIG_KEYS = ["buffer_count", "chunk_bytes", "nvme_path",
                        "working_set_bytes"]
 
 # frozen recovery vocabulary (resilience/supervisor.py RECOVERY_STATES;
-# docs/ELASTICITY.md): the supervisor's state machine and the chaos
-# bench row keys follow the same contract as every other vocabulary —
-# frozen list matches the module, every name documented, bench keys
-# literally emitted by bench.py.
+# docs/ELASTICITY.md): the supervisor's state machine follows the same
+# contract as every other vocabulary — frozen list matches the module,
+# every name documented.
 ELASTICITY_DOCS = os.path.join(REPO, "docs", "ELASTICITY.md")
 EXPECTED_RECOVERY_STATES = ["running", "detected", "dumped", "stopped",
                             "replanned", "restarted", "resumed", "failed"]
-CHAOS_BENCH_KEYS = ["recovery_s", "loss_gap", "goodput_after",
-                    "serve_ttft_p99_ms", "failovers", "regrown"]
 
 # frozen plan-compiler vocabulary (deepspeed_tpu/planner; docs/PLANNER.md):
 # the per-candidate evidence keys the planner pins, the link classes its
-# cost model prices, the offload tier ladder it enumerates, and the
-# plan_validate bench-row keys all follow the standard contract — frozen
-# list matches the module, every name documented, bench keys literally
-# emitted by bench.py.
+# cost model prices and the offload tier ladder it enumerates all
+# follow the standard contract — frozen list matches the module, every
+# name documented.
 PLANNER_DOCS = os.path.join(REPO, "docs", "PLANNER.md")
 EXPECTED_PLAN_EVIDENCE_KEYS = [
     "census", "census_mode", "dominant_class", "dominant_cost_term",
@@ -232,19 +184,16 @@ EXPECTED_PLAN_EVIDENCE_KEYS = [
 EXPECTED_LINK_CLASSES = ["ici", "dcn", "pcie", "nvme"]
 EXPECTED_OFFLOAD_TIER_NAMES = ["none", "opt_cpu", "cpu", "cpu_chunked",
                                "nvme_chunked", "nvme"]
-PLAN_BENCH_KEYS = ["plan_validate_known_good_top3", "known_good_ranks",
-                   "proposed_6_7b", "pruned_6_7b", "evidence_keys_ok"]
 
 # frozen fleet-observability vocabulary (serving/fleet.py TierSnapshot,
 # telemetry/slo.py SLO ledger, serving/disagg.py request timelines;
 # docs/OBSERVABILITY.md "Fleet snapshots & SLO ledger"): snapshot keys,
 # SLO block/scenario/ledger/target keys, and stitched-timeline keys each
 # follow the standard contract — frozen list matches the module, every
-# key documented, and the serve_disagg `slo`/`fleet_jsonl` row keys are
-# literally emitted by bench.py (they also ride in DISAGG_BENCH_KEYS).
+# key documented.
 # Per-tier Prometheus gauges are documented via their `fleet_*_<key>`
 # wildcard rows (tiers substitute into the `*`).
-EXPECTED_TIER_SNAPSHOT_SCHEMA = 2      # v2 added run_id (run ledger)
+EXPECTED_TIER_SNAPSHOT_SCHEMA = 2      # v2 added run_id
 EXPECTED_TIER_SNAPSHOT_KEYS = [
     "evictable_headroom_blocks", "handoff_bytes_per_sec",
     "handoffs_per_sec", "kv_utilization", "prefix_hit_rate",
@@ -267,41 +216,11 @@ EXPECTED_TIMELINE_KEYS = ["decode_ms", "failovers", "handoff_bytes",
                           "handoff_ms", "prefill_ms", "total_ms",
                           "trace_id", "uid"]
 
-# frozen run-ledger vocabulary (telemetry/ledger.py; docs/OBSERVABILITY.md
-# "Run ledger & regression sentinel"): manifest / rollup / finding /
-# anomaly / drift key sets, the sentinel verdicts, and the anomaly kinds
-# each follow the standard contract — frozen list matches the module,
-# every name documented, and bench.py literally stamps the run_id +
-# manifest keys into every row.
-EXPECTED_LEDGER_SCHEMA = 1
-EXPECTED_MANIFEST_KEYS = ["artifacts", "created_utc", "ledger_schema",
-                          "row", "run_id", "schema_versions", "smoke"]
-EXPECTED_MANIFEST_ARTIFACT_KEYS = ["fleet_jsonl", "flight_dir",
-                                   "resolved_config", "slo",
-                                   "telemetry_jsonl", "trace_json"]
-EXPECTED_ROLLUP_KEYS = ["error", "metric", "recovery", "round", "row",
-                        "run_id", "serve", "smoke", "source", "stale",
-                        "train", "unit", "value", "vs_baseline"]
-EXPECTED_ROLLUP_TRAIN_KEYS = ["comm_bytes_by_collective", "goodput",
-                              "hbm_peak_bytes", "mfu",
-                              "offload_overlap_fraction",
-                              "step_time_p50_ms", "step_time_p95_ms",
-                              "tokens_per_sec"]
-EXPECTED_ROLLUP_SERVE_KEYS = ["error_budget_burn", "handoff_bytes_per_req",
-                              "prefix_hit_rate", "queue_wait_p95_ms",
-                              "slo_attainment", "spec_accept_rate",
-                              "tokens_per_sec", "tpot_p50_ms",
-                              "tpot_p95_ms", "ttft_p50_ms", "ttft_p95_ms"]
-EXPECTED_ROLLUP_RECOVERY_KEYS = ["goodput_after", "loss_gap", "outage_s"]
-EXPECTED_VERDICTS = ["flat", "improved", "missing", "new", "regressed",
-                     "stale"]
-
 # frozen chaos / self-healing vocabulary (resilience/chaos.py fault
 # kinds + injection points, serving/supervisor.py health states,
 # serving/admission.py brownout ladder; docs/SERVING.md "Fault injection
-# & self-healing"): each frozen list matches its module, every name is
-# documented, and the chaos_serve bench row literally emits the frozen
-# keys — the standard vocabulary contract.
+# & self-healing"): each frozen list matches its module and every name
+# is documented — the standard vocabulary contract.
 EXPECTED_FAULT_KINDS = ["admission_storm", "cancel_storm", "handoff_fail",
                         "replica_crash", "replica_hang", "slow_replica"]
 EXPECTED_INJECTION_POINTS = ["engine.step", "router.dispatch",
@@ -310,19 +229,6 @@ EXPECTED_HEALTH_STATES = ["healthy", "suspect", "stuck", "straggler",
                           "dead", "quarantined", "respawned", "retired"]
 EXPECTED_BROWNOUT_LEVELS = ["normal", "shed_speculation", "cap_decode",
                             "shed_low_priority", "reject_new"]
-CHAOS_SERVE_BENCH_KEYS = ["faults_injected", "completed_chaos",
-                          "shed_chaos", "failed_chaos", "heals",
-                          "time_to_heal_s", "collapses", "restores",
-                          "bit_identical", "brownout_peak",
-                          "slo_violations_curve"]
-EXPECTED_ANOMALY_KINDS = ["goodput_gap", "heal_latency", "mfu_cliff",
-                          "slo_burn_spike", "step_time_spike"]
-EXPECTED_ANOMALY_KEYS = ["flight_bundle", "kind", "run_id", "step",
-                         "threshold", "tier", "trace_span", "value"]
-EXPECTED_OBS_FINDING_KEYS = ["baseline", "current", "delta", "fingerprint",
-                             "metric", "requeue_cmd", "row", "verdict"]
-EXPECTED_DRIFT_KEYS = ["actual", "metric", "predicted", "ratio", "row"]
-LEDGER_BENCH_KEYS = ["run_id", "manifest"]
 
 
 def _exported_monitor_tags() -> List[str]:
@@ -441,13 +347,9 @@ def _cross_link(docs_path: str, needle: str, what: str) -> List[str]:
     return []
 
 
-_BENCH = os.path.join(REPO, "bench.py")
-
-
 def check_quant_comm() -> List[str]:
     """Quantized-collective telemetry: frozen comm-op vocabulary matches
-    the module, every op and bench key is documented, and the bench row
-    actually emits the documented keys."""
+    the module and every op is documented."""
     def _ops():
         from deepspeed_tpu.comm.quantized import QUANT_COMM_OPS
 
@@ -457,49 +359,20 @@ def check_quant_comm() -> List[str]:
         VocabSpec(name="quantized.QUANT_COMM_OPS",
                   expected=EXPECTED_QUANT_COMM_OPS, actual=_ops,
                   docs_path=QUANT_DOCS),
-        VocabSpec(name="QUANT_BENCH_KEYS", expected=QUANT_BENCH_KEYS,
-                  docs_path=QUANT_DOCS,
-                  source_keys=[(_BENCH, QUANT_BENCH_KEYS)]),
     ]) + _cross_link(DOCS, "QUANTIZED_COMM.md", "comm")
-
-
-def check_ring_bench() -> List[str]:
-    """Ring bench-row vocabulary: every frozen longseq_ring / --bwd key
-    is emitted by its bench source and documented in the
-    docs/RING_ATTENTION.md bench-key table."""
-    return _vocab_check([
-        VocabSpec(name="RING_BENCH_KEYS", expected=RING_BENCH_KEYS,
-                  docs_path=RING_DOCS,
-                  source_keys=[(_BENCH, RING_BENCH_KEYS)]),
-        VocabSpec(name="RING_BWD_BENCH_KEYS",
-                  expected=RING_BWD_BENCH_KEYS, docs_path=RING_DOCS,
-                  source_keys=[(os.path.join(REPO, "tools",
-                                             "bench_flash_longseq.py"),
-                                RING_BWD_BENCH_KEYS)]),
-    ])
 
 
 def check_router_serving() -> List[str]:
     """Router-tier vocabulary: every RouterMetrics Prometheus name is
     documented in docs/SERVING.md (per-replica counters via their
-    ``_r*_`` wildcard), and the frozen serve_load_multi bench keys are
-    both emitted by bench.py and documented."""
+    ``_r*_`` wildcard), and the replica tier names match their module
+    and are documented."""
     import re
 
     from deepspeed_tpu.serving.metrics import RouterMetrics
 
     names = [m.name for m in
              RouterMetrics(n_replicas=2).registry.collect()]
-
-    def _mixes():
-        import importlib.util as _ilu
-
-        spec = _ilu.spec_from_file_location("_dstpu_bench", _BENCH)
-        # bench.py guards backend setup behind --smoke; importing it for
-        # the frozen tuple is safe (no row runs at import)
-        mod = _ilu.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.SCENARIO_MIXES
 
     def _tiers():
         from deepspeed_tpu.serving.disagg import REPLICA_TIERS
@@ -511,16 +384,6 @@ def check_router_serving() -> List[str]:
         VocabSpec(name="router metrics", doc_names=names,
                   docs_path=SERVING_DOCS,
                   doc_normalize=lambda n: re.sub(r"_r\d+_", "_r*_", n)),
-        VocabSpec(name="SERVE_MULTI_BENCH_KEYS",
-                  expected=SERVE_MULTI_BENCH_KEYS, docs_path=SERVING_DOCS,
-                  source_keys=[(_BENCH, SERVE_MULTI_BENCH_KEYS)]),
-        VocabSpec(name="DISAGG_BENCH_KEYS",
-                  expected=DISAGG_BENCH_KEYS, docs_path=SERVING_DOCS,
-                  source_keys=[(_BENCH, DISAGG_BENCH_KEYS)]),
-        VocabSpec(name="bench.SCENARIO_MIXES",
-                  expected=EXPECTED_SCENARIO_MIXES, actual=_mixes,
-                  docs_path=SERVING_DOCS,
-                  source_keys=[(_BENCH, EXPECTED_SCENARIO_MIXES)]),
         VocabSpec(name="disagg.REPLICA_TIERS",
                   expected=EXPECTED_REPLICA_TIERS, actual=_tiers,
                   docs_path=SERVING_DOCS),
@@ -529,9 +392,8 @@ def check_router_serving() -> List[str]:
 
 def check_autotuning() -> List[str]:
     """Overlap-scheduler vocabulary: frozen decision/evidence/config key
-    lists match the modules, every name is documented in
-    docs/AUTOTUNING.md, and the autosched bench row emits the frozen
-    keys."""
+    lists match the modules and every name is documented in
+    docs/AUTOTUNING.md."""
     from dataclasses import fields as dc_fields
 
     def _decisions():
@@ -560,9 +422,6 @@ def check_autotuning() -> List[str]:
         VocabSpec(name="StepScheduleConfig keys",
                   expected=EXPECTED_STEP_SCHEDULE_KEYS, actual=_ss_keys,
                   docs_path=AUTOTUNING_DOCS),
-        VocabSpec(name="AUTOSCHED_BENCH_KEYS",
-                  expected=AUTOSCHED_BENCH_KEYS, docs_path=AUTOTUNING_DOCS,
-                  source_keys=[(_BENCH, AUTOSCHED_BENCH_KEYS)]),
         VocabSpec(name="capture report scheduler keys",
                   expected=CAPTURE_REPORT_SCHED_KEYS,
                   docs_path=AUTOTUNING_DOCS),
@@ -604,9 +463,8 @@ def check_graph_audit() -> List[str]:
 def check_memory_audit() -> List[str]:
     """Memory-plan-audit vocabulary: the MemoryAuditReport's frozen key
     sets and classes match deepspeed_tpu/analysis/report.py, every name
-    is documented in docs/STATIC_ANALYSIS.md, the peak_params ladder
-    emits the frozen prediction keys, and docs/AUTOTUNING.md cross-links
-    the model_drift calibration record."""
+    is documented in docs/STATIC_ANALYSIS.md, and docs/AUTOTUNING.md
+    cross-links the model_drift calibration record."""
     from deepspeed_tpu.analysis import (BUDGET_KEYS, BUFFER_KEYS,
                                         CALIBRATION_KEYS, MEMORY_CLASSES,
                                         MEMORY_REPORT_KEYS,
@@ -633,17 +491,13 @@ def check_memory_audit() -> List[str]:
         VocabSpec(name="analysis.CALIBRATION_KEYS",
                   expected=EXPECTED_CALIBRATION_KEYS,
                   actual=lambda: CALIBRATION_KEYS, docs_path=STATIC_DOCS),
-        VocabSpec(name="MEMORY_BENCH_KEYS", expected=MEMORY_BENCH_KEYS,
-                  docs_path=STATIC_DOCS,
-                  source_keys=[(_BENCH, MEMORY_BENCH_KEYS)]),
     ]) + _cross_link(AUTOTUNING_DOCS, "model_drift", "calibration")
 
 
 def check_recovery() -> List[str]:
     """Recovery vocabulary: the supervisor's frozen state machine matches
-    the module and docs/ELASTICITY.md, the chaos bench row emits the
-    frozen keys, and the observability doc cross-links the elasticity
-    doc from its recovery rows."""
+    the module and docs/ELASTICITY.md, and the observability doc
+    cross-links the elasticity doc from its recovery rows."""
     def _states():
         from deepspeed_tpu.resilience.supervisor import RECOVERY_STATES
 
@@ -653,19 +507,14 @@ def check_recovery() -> List[str]:
         VocabSpec(name="supervisor.RECOVERY_STATES",
                   expected=EXPECTED_RECOVERY_STATES, actual=_states,
                   docs_path=ELASTICITY_DOCS),
-        VocabSpec(name="CHAOS_BENCH_KEYS", expected=CHAOS_BENCH_KEYS,
-                  docs_path=ELASTICITY_DOCS,
-                  source_keys=[(_BENCH, CHAOS_BENCH_KEYS)]),
     ]) + _cross_link(DOCS, "ELASTICITY.md", "recovery")
 
 
 def check_offload() -> List[str]:
-    """Host-tiered offload vocabulary: the ladder's measured host keys
-    (`host_peak_bytes` next to the predictor's number, plus the overlap
-    fraction) are emitted by bench.py and documented in docs/OFFLOAD.md,
-    the chunked config knobs are real OffloadOptimizerConfig fields and
-    documented, and the observability doc cross-links the offload doc
-    from its offload span rows."""
+    """Host-tiered offload vocabulary: the chunked config knobs are real
+    OffloadOptimizerConfig fields and documented in docs/OFFLOAD.md, and
+    the observability doc cross-links the offload doc from its offload
+    span rows."""
     from dataclasses import fields as dc_fields
 
     def _cfg_keys():
@@ -675,9 +524,6 @@ def check_offload() -> List[str]:
         return sorted(k for k in OFFLOAD_CONFIG_KEYS if k in have)
 
     return _vocab_check([
-        VocabSpec(name="OFFLOAD_BENCH_KEYS", expected=OFFLOAD_BENCH_KEYS,
-                  docs_path=OFFLOAD_DOCS,
-                  source_keys=[(_BENCH, OFFLOAD_BENCH_KEYS)]),
         VocabSpec(name="OffloadOptimizerConfig chunked keys",
                   expected=OFFLOAD_CONFIG_KEYS, actual=_cfg_keys,
                   docs_path=OFFLOAD_DOCS),
@@ -687,9 +533,8 @@ def check_offload() -> List[str]:
 def check_planner() -> List[str]:
     """Plan-compiler vocabulary: evidence keys / link classes / offload
     tier names match deepspeed_tpu/planner, every name is documented in
-    docs/PLANNER.md, the plan_validate bench keys are emitted by
-    bench.py, and the planner and autotuning docs cross-link each
-    other (the Autotuner's planner mode consumes seed_candidates)."""
+    docs/PLANNER.md, and the planner and autotuning docs cross-link
+    each other (the Autotuner's planner mode consumes seed_candidates)."""
     from deepspeed_tpu.planner import (LINK_CLASSES, OFFLOAD_TIERS,
                                        PLAN_EVIDENCE_KEYS)
 
@@ -705,9 +550,6 @@ def check_planner() -> List[str]:
                   expected=EXPECTED_OFFLOAD_TIER_NAMES,
                   actual=lambda: [n for n, _ in OFFLOAD_TIERS],
                   docs_path=PLANNER_DOCS),
-        VocabSpec(name="PLAN_BENCH_KEYS", expected=PLAN_BENCH_KEYS,
-                  docs_path=PLANNER_DOCS,
-                  source_keys=[(_BENCH, PLAN_BENCH_KEYS)]),
     ]) + _cross_link(AUTOTUNING_DOCS, "PLANNER.md", "planner mode") \
        + _cross_link(PLANNER_DOCS, "AUTOTUNING.md", "autotuner handoff")
 
@@ -773,69 +615,12 @@ def check_fleet() -> List[str]:
                      "fleet snapshots / autoscaler inputs")
 
 
-def check_obs_ledger() -> List[str]:
-    """Run-ledger vocabulary: manifest/rollup/finding/anomaly/drift key
-    sets, the sentinel verdicts, and the anomaly kinds match
-    telemetry/ledger.py; every name is documented in the
-    docs/OBSERVABILITY.md "Run ledger & regression sentinel" section;
-    bench.py stamps run_id + manifest into every row; and the ledger
-    schema version is pinned."""
-    def _led(name):
-        def thunk():
-            from deepspeed_tpu.telemetry import ledger
-
-            if ledger.LEDGER_SCHEMA != EXPECTED_LEDGER_SCHEMA:
-                raise ValueError(
-                    f"LEDGER_SCHEMA is {ledger.LEDGER_SCHEMA}, lint pins "
-                    f"{EXPECTED_LEDGER_SCHEMA}")
-            return getattr(ledger, name)
-        return thunk
-
-    return _vocab_check([
-        VocabSpec(name="ledger.MANIFEST_KEYS",
-                  expected=EXPECTED_MANIFEST_KEYS,
-                  actual=_led("MANIFEST_KEYS"), docs_path=DOCS),
-        VocabSpec(name="ledger.MANIFEST_ARTIFACT_KEYS",
-                  expected=EXPECTED_MANIFEST_ARTIFACT_KEYS,
-                  actual=_led("MANIFEST_ARTIFACT_KEYS"), docs_path=DOCS),
-        VocabSpec(name="ledger.ROLLUP_KEYS",
-                  expected=EXPECTED_ROLLUP_KEYS,
-                  actual=_led("ROLLUP_KEYS"), docs_path=DOCS),
-        VocabSpec(name="ledger.ROLLUP_TRAIN_KEYS",
-                  expected=EXPECTED_ROLLUP_TRAIN_KEYS,
-                  actual=_led("ROLLUP_TRAIN_KEYS"), docs_path=DOCS),
-        VocabSpec(name="ledger.ROLLUP_SERVE_KEYS",
-                  expected=EXPECTED_ROLLUP_SERVE_KEYS,
-                  actual=_led("ROLLUP_SERVE_KEYS"), docs_path=DOCS),
-        VocabSpec(name="ledger.ROLLUP_RECOVERY_KEYS",
-                  expected=EXPECTED_ROLLUP_RECOVERY_KEYS,
-                  actual=_led("ROLLUP_RECOVERY_KEYS"), docs_path=DOCS),
-        VocabSpec(name="ledger.VERDICTS", expected=EXPECTED_VERDICTS,
-                  actual=_led("VERDICTS"), docs_path=DOCS),
-        VocabSpec(name="ledger.ANOMALY_KINDS",
-                  expected=EXPECTED_ANOMALY_KINDS,
-                  actual=_led("ANOMALY_KINDS"), docs_path=DOCS),
-        VocabSpec(name="ledger.ANOMALY_KEYS",
-                  expected=EXPECTED_ANOMALY_KEYS,
-                  actual=_led("ANOMALY_KEYS"), docs_path=DOCS),
-        VocabSpec(name="ledger.FINDING_KEYS",
-                  expected=EXPECTED_OBS_FINDING_KEYS,
-                  actual=_led("FINDING_KEYS"), docs_path=DOCS),
-        VocabSpec(name="ledger.DRIFT_KEYS", expected=EXPECTED_DRIFT_KEYS,
-                  actual=_led("DRIFT_KEYS"), docs_path=DOCS),
-        VocabSpec(name="LEDGER_BENCH_KEYS", expected=LEDGER_BENCH_KEYS,
-                  docs_path=DOCS,
-                  source_keys=[(_BENCH, LEDGER_BENCH_KEYS)]),
-    ]) + _cross_link(PLANNER_DOCS, "obs_report", "calibration")
-
-
 def check_chaos_fleet() -> List[str]:
     """Chaos / self-healing vocabulary: fault kinds, injection points,
     health states and brownout levels match their modules and are
-    documented in docs/SERVING.md; the chaos_serve bench row emits the
-    frozen keys; and docs/ELASTICITY.md cross-links the serving doc
-    from its chaos section (the training and serving chaos halves share
-    resilience/chaos.py)."""
+    documented in docs/SERVING.md; and docs/ELASTICITY.md cross-links
+    the serving doc from its chaos section (the training and serving
+    chaos halves share resilience/chaos.py)."""
     def _kinds():
         from deepspeed_tpu.resilience.chaos import FAULT_KINDS
 
@@ -869,9 +654,6 @@ def check_chaos_fleet() -> List[str]:
         VocabSpec(name="admission.BROWNOUT_LEVELS",
                   expected=EXPECTED_BROWNOUT_LEVELS, actual=_levels,
                   docs_path=SERVING_DOCS),
-        VocabSpec(name="CHAOS_SERVE_BENCH_KEYS",
-                  expected=CHAOS_SERVE_BENCH_KEYS, docs_path=SERVING_DOCS,
-                  source_keys=[(_BENCH, CHAOS_SERVE_BENCH_KEYS)]),
     ]) + _cross_link(ELASTICITY_DOCS, "SERVING.md", "chaos")
 
 
@@ -941,11 +723,10 @@ def check_trace_export() -> List[str]:
 
 def run_all() -> List[str]:
     return (check_tags_documented() + check_schema() + check_span_names()
-            + check_quant_comm() + check_ring_bench()
-            + check_router_serving() + check_autotuning()
-            + check_graph_audit() + check_memory_audit()
-            + check_offload() + check_recovery() + check_planner()
-            + check_fleet() + check_obs_ledger() + check_chaos_fleet()
+            + check_quant_comm() + check_router_serving()
+            + check_autotuning() + check_graph_audit()
+            + check_memory_audit() + check_offload() + check_recovery()
+            + check_planner() + check_fleet() + check_chaos_fleet()
             + check_trace_export())
 
 
