@@ -9,7 +9,9 @@ top-k / top-p, sampled on device).
 
 TPU specifics: the ragged step is ONE jitted function with donated KV-cache
 buffers (no copies between steps) and fixed shapes — every prefill/decode
-mix replays the same executable; tensor-parallel serving reuses the training
+mix replays the same executable, and a step reaches the chip as one
+host→device transfer (its packed index buffer) and one program
+(``_ship``); tensor-parallel serving reuses the training
 ShardingRules so weights shard over the "tensor" mesh axis and XLA inserts
 the same collectives AutoTP injection produces in the reference.
 """
@@ -29,12 +31,12 @@ from deepspeed_tpu.inference.v2.model import (attention_impl_name,
                                               check_sampling_params,
                                               new_ssm_state,
                                               ragged_decode_loop,
-                                              ragged_forward,
-                                              ragged_forward_sampled,
-                                              ragged_forward_verify,
-                                              ssm_impl_name)
+                                              ragged_step,
+                                              ragged_step_sampled,
+                                              ragged_verify, ssm_impl_name)
 from deepspeed_tpu.inference.v2.ragged import (DSStateManager,
-                                               KVCacheExhausted,
+                                               KVCacheExhausted, PackedIndex,
+                                               RaggedBatch,
                                                build_ragged_batch)
 from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
 from deepspeed_tpu.models import transformer as tf_model
@@ -122,6 +124,19 @@ def _named(name: str, fn, **static):
     p = partial(fn, **static)
     p.__name__ = name
     return p
+
+
+def _next_key(key, temperature: float) -> tuple:
+    """``(key', subkey, programs)`` for one sampled dispatch.  A greedy
+    sampler never reads its key, so a greedy step splits none and hands
+    the key over as it is; any other draws from a fresh subkey (a fixed
+    key would correlate every step's draws; deterministic per seed).
+    ``programs``: what the split dispatched ahead of the step, the split
+    and the pair's unpacking."""
+    if temperature <= 0:
+        return key, key, 0
+    key, sub = jax.random.split(key)
+    return key, sub, 2
 
 
 def step_counts(items: Sequence[tuple], window: Optional[int] = None,
@@ -255,7 +270,6 @@ class InferenceEngineV2:
             max_blocks_per_seq=max_blocks_per_seq)
         self.scheduler = SplitFuseScheduler(self.state_manager,
                                             token_budget=self.cfg.max_ragged_batch_size)
-        self._step_key = jax.random.PRNGKey(seed ^ 0x57E9)  # step() default
         # software-span tracer (telemetry/tracing.py) — the serving layer
         # injects both so ragged dispatches appear in the request trace
         # under the serve loop's trace id instead of one-off orphan ids
@@ -276,6 +290,8 @@ class InferenceEngineV2:
         replicated = NamedSharding(self.topology.mesh, PartitionSpec())
         self._put = partial(jax.device_put, device=replicated)
         zeros = partial(jnp.zeros, device=replicated)
+        # step()'s default key, on the engine's devices once (_next_key)
+        self._step_key = self._put(jax.random.PRNGKey(seed ^ 0x57E9))
 
         pages = self.cfg.num_blocks * self.cfg.block_size
         # [L, nkv, P, d]: kv-head-major so the paged-attention kernel's page
@@ -325,15 +341,15 @@ class InferenceEngineV2:
         # profiler's ``XLA Modules`` line reads ``jit_ragged_step(...)``
         # and not ``jit__unknown(...)`` (a partial has no ``__name__``)
         self._step = jax.jit(
-            _named("ragged_step", ragged_forward, cfg=mc,
+            _named("ragged_step", ragged_step, cfg=mc,
                    block_size=self.cfg.block_size),
             **dict(donate, donate_argnums=(1, 2) if self.state is None
-                   else (1, 2, 10)))
+                   else (1, 2, 4)))
         # sampled variant: mixed prefill/decode steps fetch [max_seqs] int32
         # tokens instead of full [max_seqs, V] logits (ref Weak: v2 prefill
         # loop host-bound — sampling now happens on device for BOTH phases)
         self._step_sampled = jax.jit(
-            _named("ragged_step_sampled", ragged_forward_sampled, cfg=mc,
+            _named("ragged_step_sampled", ragged_step_sampled, cfg=mc,
                    block_size=self.cfg.block_size),
             static_argnames=("greedy", "top_k"), **donate)
         self._decode_loop = jax.jit(
@@ -344,7 +360,7 @@ class InferenceEngineV2:
         # the greedy argmax comes back for EVERY token row ([T] int32), so
         # one ragged dispatch scores a whole batch of draft proposals
         self._verify = jax.jit(
-            _named("ragged_verify", ragged_forward_verify, cfg=mc,
+            _named("ragged_verify", ragged_verify, cfg=mc,
                    block_size=self.cfg.block_size),
             donate_argnums=(1, 2))
         # disaggregated-serving KV import: scatter handed-off page rows
@@ -413,11 +429,14 @@ class InferenceEngineV2:
     # ------------------------------------------------------------------
     def _ragged_step(self, batch_uids: Sequence[int],
                      batch_tokens: Sequence[Sequence[int]],
-                     sample: Optional[Dict[str, Any]] = None):
+                     sample: Optional[Dict[str, Any]] = None,
+                     programs: int = 1):
         """Admit prompts and run ONE ragged step; returns (rb, result) where
         result is the full logits array (sample=None) or on-device-sampled
         tokens [max_seqs] (sample={'key','temperature'} with optional
-        'top_k'/'top_p' — see check_sampling_params for their contract)."""
+        'top_k'/'top_p' — see check_sampling_params for their contract).
+        ``programs``: this step's program and what the caller dispatched
+        for it beforehand (a key split), for the ``v2.dispatch`` span."""
         # Validate the whole batch before touching any state, so a bad entry
         # cannot leave earlier prompts half-admitted.
         if len(batch_uids) != len(batch_tokens):
@@ -464,22 +483,6 @@ class InferenceEngineV2:
                 if seq.uncached > 1:
                     self.scheduler.demote(seq.uid)
             raise
-        # Bucket the step's shapes (power-of-two token count and context
-        # width) so decode-heavy steps don't pay the full prefill budget:
-        # a 16-seq decode step runs [16, ctx] work, not [budget, max_ctx].
-        # A handful of bucket shapes → a handful of cached compilations
-        # (the shape discipline the reference gets from its CUDA kernels'
-        # ragged launch geometry).
-        t_bucket = 16
-        while t_bucket < rb.n_tokens:
-            t_bucket *= 2
-        t_bucket = min(t_bucket, self.scheduler.token_budget)
-        bs = self.cfg.block_size
-        nb_real = max(1, -(-int(rb.ctx_lens.max()) // bs))
-        nb_bucket = 1
-        while nb_bucket < nb_real:
-            nb_bucket *= 2
-        nb_bucket = min(nb_bucket, self.state_manager.max_blocks_per_seq)
         if sp is not None:
             # build_ragged_batch advanced num_cached past the new tokens
             items = [(seq.num_cached - n, n) for seq, n in schedule]
@@ -489,41 +492,59 @@ class InferenceEngineV2:
                 counts.update(ssm_step_counts(
                     items, self._slot_bytes, self.state_manager.n_active))
             sp.end(**counts)
-        host = (rb.token_ids[:t_bucket], rb.token_slot[:t_bucket],
-                rb.token_pos[:t_bucket], rb.token_dest[:t_bucket],
-                rb.block_tables[:, :nb_bucket], rb.ctx_lens, rb.logits_idx)
-        sp = (tr.span("v2.h2d", self.trace_id, parent)
-              if tr is not None else None)
-        args = (self.params, self.cache_k, self.cache_v,
-                *[self._put(a) for a in host])
-        if sp is not None:
-            sp.end(arrays=len(host), bytes=sum(a.nbytes for a in host))
         if sample is None:
-            key = ("ragged_step", t_bucket, nb_bucket)
+            program, variant, kw = self._step, (), {}
         else:
             greedy = sample["temperature"] <= 0
-            key = ("ragged_step_sampled", t_bucket, nb_bucket, greedy,
-                   sample.get("top_k", 0), sample.get("top_p") is None)
+            top_k, top_p = sample.get("top_k", 0), sample.get("top_p")
+            program, variant = self._step_sampled, (greedy, top_k,
+                                                    top_p is None)
+            # host scalars: the jitted call ships them itself, and not at
+            # all where the program does not read them (a greedy step)
+            kw = {"key": sample["key"], "greedy": greedy, "top_k": top_k,
+                  "top_p": top_p, "temperature": np.float32(
+                      max(sample["temperature"], 1e-6))}
+        index, sp, shape = self._ship(rb, program, variant, programs)
+        try:
+            out = self._carried(program(
+                self.params, self.cache_k, self.cache_v, index, **kw,
+                **self._state_kw()))
+        finally:
+            if sp is not None:
+                sp.end(**shape)
+        out.copy_to_host_async()    # the fetch waits for a copy under way
+        return rb, out
+
+    def _ship(self, rb: RaggedBatch, program, variant: tuple = (),
+              programs: int = 1):
+        """The launch path every ragged step shares (plain, sampled,
+        verify), up to the jitted call: ONE host→device transfer, the
+        step's packed index buffer (span ``v2.h2d``).  Returns the device
+        index, the open ``v2.dispatch`` span (None unless spans are
+        recorded) and that span's arguments (``programs``: what the caller
+        dispatched for this step, a key split's two programs included).
+        The caller makes the ONE program call itself, in its own frame,
+        ends the span and asks for the result's copy back at once: a
+        frame more between the serve loop and the jitted call costs every
+        program's first call 0.1 s (PERF.md §6, PR 32)."""
+        tr = self.tracer
+        if tr is not None and not tr.enabled:
+            tr = None
+        parent = self._step_span
+        host = rb.index
+        sp = (tr.span("v2.h2d", self.trace_id, parent)
+              if tr is not None else None)
+        index = self._put(host)         # a pytree of one leaf
+        if sp is not None:
+            sp.end(arrays=1, bytes=host.buf.nbytes)
+        key = (program.__name__, host.rows, host.blocks) + variant
         new_shape = key not in self._dispatched
         if new_shape:
             self._dispatched.add(key)
         sp = (tr.span("v2.dispatch", self.trace_id, parent)
               if tr is not None else None)
-        try:
-            if sample is None:
-                out = self._carried(self._step(*args, **self._state_kw()))
-            else:
-                out = self._carried(self._step_sampled(
-                    *args, key=sample["key"],
-                    temperature=jnp.float32(max(sample["temperature"],
-                                                1e-6)),
-                    greedy=greedy, top_k=sample.get("top_k", 0),
-                    top_p=sample.get("top_p"), **self._state_kw()))
-        finally:
-            if sp is not None:
-                sp.end(t_bucket=t_bucket, nb_bucket=nb_bucket,
-                       new_shape=new_shape)
-        return rb, out
+        return index, sp, {"t_bucket": host.rows, "nb_bucket": host.blocks,
+                           "new_shape": new_shape, "programs": programs}
 
     def audit_step_args(self, phase: str = "decode"):
         """``(jitted ragged step, example args)`` for the static graph
@@ -541,12 +562,10 @@ class InferenceEngineV2:
         sm = self.state_manager
         t = (min(16, self.scheduler.token_budget) if phase == "decode"
              else self.scheduler.token_budget)
-        ids = jnp.zeros((t,), jnp.int32)
-        rows = jnp.zeros((sm.max_seqs + 1,), jnp.int32)
-        tables = jnp.zeros((sm.max_seqs + 1, sm.max_blocks_per_seq),
-                           jnp.int32)
-        args = (self.params, self.cache_k, self.cache_v,
-                ids, ids, ids, ids, tables, rows, rows)
+        sizes = (t, sm.max_seqs + 1, sm.max_blocks_per_seq)
+        index = PackedIndex(jnp.zeros((PackedIndex.size(*sizes),), jnp.int32),
+                            *sizes)
+        args = (self.params, self.cache_k, self.cache_v, index)
         if self.state is not None:
             args += (self.state,)
         return (self._verify if phase == "verify" else self._step), args
@@ -555,11 +574,9 @@ class InferenceEngineV2:
         """Memory-class manifest for the ``audit_step_args`` tuple (one
         ``analysis.MEMORY_CLASSES`` entry per top-level argument): the
         weights, the two paged KV pools (state, not step-local —
-        classed ``other``), and the ragged index arrays."""
-        return ("params", "other", "other",
-                "activations", "activations", "activations", "activations",
-                "other", "other", "other") + (
-                    ("other",) if self.state is not None else ())
+        classed ``other``), and the packed ragged index buffer."""
+        return ("params", "other", "other", "other") + (
+            ("other",) if self.state is not None else ())
 
     def put(self, batch_uids: Sequence[int],
             batch_tokens: Sequence[Sequence[int]]) -> Dict[int, np.ndarray]:
@@ -655,13 +672,14 @@ class InferenceEngineV2:
                     for slot, uid in rb.uids_by_slot.items()}
         top_k, top_p = check_sampling_params(top_k, top_p,
                                              self.model_config.vocab_size)
+        split = 0
         if key is None:
-            # fresh subkey per call — a fixed key would correlate every
-            # non-greedy step's draws (deterministic per engine seed)
-            self._step_key, key = jax.random.split(self._step_key)
+            self._step_key, key, split = _next_key(self._step_key,
+                                                   temperature)
         rb, toks = self._ragged_step(
             [], [], sample={"key": key, "temperature": temperature,
-                            "top_k": top_k, "top_p": top_p})
+                            "top_k": top_k, "top_p": top_p},
+            programs=1 + split)
         if rb is None:
             return {}
         toks_np = self._fetch(toks)
@@ -669,8 +687,8 @@ class InferenceEngineV2:
                 for slot, uid in rb.uids_by_slot.items()}
 
     def _fetch(self, out) -> np.ndarray:
-        """The step's result on the host: the wait for the device and the
-        copy back (span ``v2.fetch``)."""
+        """The step's result on the host: the wait for the device and for
+        the copy back asked for at the dispatch (span ``v2.fetch``)."""
         sp = self._step_span
         if sp is None:
             return np.asarray(out)
@@ -860,24 +878,13 @@ class InferenceEngineV2:
             for uid, (n_tok, _nc) in saved.items():
                 del mgr.get(uid).tokens[n_tok:]
             raise
-        t_bucket = 16
-        while t_bucket < rb.n_tokens:
-            t_bucket *= 2
-        t_bucket = min(t_bucket, self.scheduler.token_budget)
-        bs = self.cfg.block_size
-        nb_real = max(1, -(-int(rb.ctx_lens.max()) // bs))
-        nb_bucket = 1
-        while nb_bucket < nb_real:
-            nb_bucket *= 2
-        nb_bucket = min(nb_bucket, self.state_manager.max_blocks_per_seq)
-        nxt, self.cache_k, self.cache_v = self._verify(
-            self.params, self.cache_k, self.cache_v,
-            self._put(rb.token_ids[:t_bucket]),
-            self._put(rb.token_slot[:t_bucket]),
-            self._put(rb.token_pos[:t_bucket]),
-            self._put(rb.token_dest[:t_bucket]),
-            self._put(rb.block_tables[:, :nb_bucket]),
-            self._put(rb.ctx_lens), self._put(rb.logits_idx))
+        index, sp, shape = self._ship(rb, self._verify)
+        try:
+            nxt = self._carried(self._verify(
+                self.params, self.cache_k, self.cache_v, index))
+        finally:
+            if sp is not None:
+                sp.end(**shape)
         nxt = np.asarray(nxt)
         out: Dict[int, List[int]] = {}
         cursor = 0
@@ -963,7 +970,7 @@ class InferenceEngineV2:
             if (not pending and active_uids
                     and all(self.state_manager.get(u).uncached == 1
                             for u in active_uids)):
-                decode_key, sub = jax.random.split(decode_key)
+                decode_key, sub, _ = _next_key(decode_key, temperature)
                 self._fused_decode(active_uids, remaining, outputs,
                                    temperature, sub, eos_token_id,
                                    top_k=top_k, top_p=top_p)
@@ -999,11 +1006,12 @@ class InferenceEngineV2:
             # mixed prefill/decode step with ON-DEVICE sampling: only
             # [max_seqs] int32 tokens cross to the host, not [seqs, V]
             # logits (the decode-phase discipline applied to prefill too)
-            step_key, sub = jax.random.split(step_key)
+            step_key, sub, split = _next_key(step_key, temperature)
             rb, toks = self._ragged_step(
                 admit_uids, admit_toks,
                 sample={"key": sub, "temperature": temperature,
-                        "top_k": top_k, "top_p": top_p})
+                        "top_k": top_k, "top_p": top_p},
+                programs=1 + split)
             toks_np = np.asarray(toks) if rb is not None else None
             results = ({} if rb is None
                        else {uid: int(toks_np[slot])
@@ -1079,7 +1087,7 @@ class InferenceEngineV2:
         sampled, _ = self._carried(self._decode_loop(
             self.params, self.cache_k, self.cache_v,
             self._put(tokens0), self._put(ctx0), self._put(active),
-            self._put(tables), key, jnp.float32(max(temperature, 1e-6)),
+            self._put(tables), key, np.float32(max(temperature, 1e-6)),
             n_steps=chunk, greedy=(temperature <= 0),
             top_k=top_k, top_p=top_p, **self._state_kw()))
         sampled = np.asarray(sampled)  # [chunk, s_rows]

@@ -33,6 +33,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from deepspeed_tpu.inference.v2.modules import (register_module, resolve,
@@ -580,6 +581,36 @@ def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
     return logits.astype(jnp.float32), cache_k, cache_v
 
 
+# The engine's step programs: this module's forwards with the seven index
+# arrays as ONE argument, a ``ragged.PackedIndex`` that reached the device
+# as one transfer and is cut apart here by static slices.
+def ragged_step(params, cache_k, cache_v, index, state=None, *,
+                cfg: TransformerConfig, block_size: int):
+    """:func:`ragged_forward` on a packed index buffer."""
+    return ragged_forward(params, cache_k, cache_v, *index.arrays(), state,
+                          cfg=cfg, block_size=block_size)
+
+
+def ragged_step_sampled(params, cache_k, cache_v, index, key, temperature,
+                        cfg: TransformerConfig, block_size: int,
+                        greedy: bool, top_k: int = 0, top_p=None,
+                        state=None):
+    """:func:`ragged_forward_sampled` on a packed index buffer (its body,
+    not a call of it: a frame fewer under every traced operation)."""
+    logits, *carried = ragged_forward(
+        params, cache_k, cache_v, *index.arrays(), state, cfg=cfg,
+        block_size=block_size)
+    nxt = sample_tokens(logits, key, temperature, greedy, top_k, top_p)
+    return (nxt, *carried)
+
+
+def ragged_verify(params, cache_k, cache_v, index, *,
+                  cfg: TransformerConfig, block_size: int):
+    """:func:`ragged_forward_verify` on a packed index buffer."""
+    return ragged_forward_verify(params, cache_k, cache_v, *index.arrays(),
+                                 cfg=cfg, block_size=block_size)
+
+
 def ragged_forward_verify(params, cache_k, cache_v, token_ids, token_slot,
                           token_pos, token_dest, block_tables, ctx_lens,
                           logits_idx, cfg: TransformerConfig,
@@ -627,12 +658,13 @@ def check_sampling_params(top_k: int, top_p, vocab_size: int):
     crash deep inside lax.top_k (top_k > vocab).  Returns the
     ``(top_k_static, top_p_traced)`` pair the jitted samplers take —
     top_k clamped to vocab, top_p None when disabled (>= 1.0) else a
-    traced fp32 scalar (so per-request values never recompile)."""
+    traced fp32 scalar (so per-request values never recompile): a HOST
+    scalar, which the jitted call ships with its other arguments."""
     if top_p is not None and not (0.0 < float(top_p) <= 1.0):
         raise ValueError(f"top_p must be in (0, 1], got {top_p}")
     if top_k < 0:
         raise ValueError(f"top_k must be >= 0, got {top_k}")
-    tp = None if top_p is None or float(top_p) >= 1.0 else jnp.float32(top_p)
+    tp = None if top_p is None or float(top_p) >= 1.0 else np.float32(top_p)
     return min(int(top_k), vocab_size), tp
 
 

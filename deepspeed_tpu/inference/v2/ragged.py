@@ -10,9 +10,10 @@ Differences forced by XLA (fixed shapes, no host pointers on device):
 * The device never sees Python sequence objects — each engine step receives a
   ``RaggedBatch`` of FIXED-shape int32 arrays (token ids, per-token sequence
   slot / position / KV-cache destination, block tables, sequence lengths),
-  padded up to (token_budget, max_seqs, max_blocks_per_seq). One executable
-  serves every prefill/decode mix — the padding discipline replaces the
-  reference's variable-size CUDA launches.
+  padded up to a (token bucket, max_seqs, block bucket). One executable a
+  bucket serves every prefill/decode mix — the padding discipline replaces
+  the reference's variable-size CUDA launches.  The arrays are views into
+  ONE host buffer (``PackedIndex``), so a step costs one transfer.
 * KV "pages" are rows of one flat device array per layer; the block table is
   data, not pointers, and paged attention is a gather over it.
 * Block 0 is reserved as a garbage page: padded tokens scatter their KV
@@ -23,8 +24,9 @@ Differences forced by XLA (fixed shapes, no host pointers on device):
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 
@@ -125,6 +127,50 @@ class SequenceDescriptor:
         return len(self.tokens) - self.num_cached
 
 
+@jax.tree_util.register_pytree_node_class
+class PackedIndex:
+    """One step's seven int32 index arrays in ONE flat buffer, so that
+    they reach the device as one transfer: ``token_ids``, ``token_slot``,
+    ``token_pos``, ``token_dest`` (``rows`` each), ``block_tables``
+    (``slots`` x ``blocks``), ``ctx_lens``, ``logits_idx`` (``slots``
+    each), in that order.
+
+    ``buf`` is a numpy array on the host, where ``build_ragged_batch``
+    writes through :meth:`arrays`' views, and a device array (or a
+    tracer) inside a step program, which cuts it apart by the same static
+    slices.  A pytree of one leaf: the three sizes are static, so a
+    jitted step is compiled once per (``rows``, ``blocks``) bucket, as it
+    was per shape of the separate arrays."""
+
+    def __init__(self, buf, rows: int, slots: int, blocks: int):
+        self.buf, self.rows, self.slots, self.blocks = buf, rows, slots, blocks
+
+    @staticmethod
+    def size(rows: int, slots: int, blocks: int) -> int:
+        return 4 * rows + slots * (blocks + 2)
+
+    def arrays(self) -> Tuple:
+        """The seven arrays, in the step programs' argument order:
+        ``token_ids`` [T] (0-padded), ``token_slot`` [T] (``max_seqs`` =
+        padding slot), ``token_pos`` [T] (absolute position),
+        ``token_dest`` [T] (flat KV-cache row, 0 = garbage),
+        ``block_tables`` [max_seqs+1, NB], ``ctx_lens`` [max_seqs+1]
+        (tokens in cache AFTER the step), ``logits_idx`` [max_seqs+1]
+        (row in T of a sequence's final token)."""
+        t, s, nb = self.rows, self.slots, self.blocks
+        b, tables_end = self.buf, 4 * t + s * nb
+        return (b[:t], b[t:2 * t], b[2 * t:3 * t], b[3 * t:4 * t],
+                b[4 * t:tables_end].reshape(s, nb),
+                b[tables_end:tables_end + s], b[tables_end + s:])
+
+    def tree_flatten(self):
+        return (self.buf,), (self.rows, self.slots, self.blocks)
+
+    @classmethod
+    def tree_unflatten(cls, sizes, leaves):
+        return cls(leaves[0], *sizes)
+
+
 class DSStateManager:
     """Tracks live sequences, their slots and KV pages (ref ragged_manager.py).
 
@@ -141,6 +187,10 @@ class DSStateManager:
     ``flush`` frees both with no device work, and a preempted sequence
     recomputes from zeros.  ``open(num_cached > 0)`` would start a
     sequence past position 0: the engine refuses it for such a model.
+
+    It also keeps the host side of a step's index arrays: one
+    ``PackedIndex`` buffer a (token bucket, block bucket), made at the
+    bucket's first step and rewritten by every later one.
     """
 
     def __init__(self, max_seqs: int, num_blocks: int, block_size: int,
@@ -151,6 +201,7 @@ class DSStateManager:
         self.allocator = BlockedAllocator(num_blocks)
         self._seqs: Dict[int, SequenceDescriptor] = {}
         self._free_slots = list(range(max_seqs - 1, -1, -1))
+        self._index: Dict[Tuple[int, int], PackedIndex] = {}
 
     def __contains__(self, uid: int) -> bool:
         return uid in self._seqs
@@ -224,80 +275,111 @@ class DSStateManager:
             self.allocator.free(seq.blocks)
         self._free_slots.append(seq.slot)
 
+    def step_index(self, rows: int, blocks: int) -> PackedIndex:
+        """The host index buffer of a (``rows``, ``blocks``) bucket,
+        cleared to a step's padding: token ids, positions and
+        destinations 0 (the garbage page), slot ``max_seqs``, empty
+        tables.  The SAME buffer every time: the caller has read the
+        result of the step it last built here before it builds the next
+        (every engine path fetches what it dispatched), so nothing still
+        reads what is overwritten."""
+        index = self._index.get((rows, blocks))
+        if index is None:
+            slots = self.max_seqs + 1
+            index = self._index[rows, blocks] = PackedIndex(
+                np.empty((PackedIndex.size(rows, slots, blocks),), np.int32),
+                rows, slots, blocks)
+        index.buf[:] = 0
+        index.buf[rows:2 * rows] = self.max_seqs
+        return index
+
 
 @dataclass
 class RaggedBatch:
     """Fixed-shape device inputs for one engine step
     (ref RaggedBatchWrapper, ragged_wrapper.py:31).
 
-    All arrays are host numpy; the engine ships them to device unchanged
-    every step, so shapes never vary and XLA compiles the step once.
+    ``index`` holds the seven host arrays (``index.arrays()``), cut to the
+    step's buckets; the engine ships its one buffer to the device
+    unchanged, so shapes vary only with the bucket and XLA compiles the
+    step once a bucket.
     """
-    token_ids: np.ndarray       # [T] int32, 0-padded
-    token_slot: np.ndarray      # [T] int32; max_seqs = padding slot
-    token_pos: np.ndarray       # [T] int32 absolute position in sequence
-    token_dest: np.ndarray      # [T] int32 flat KV-cache index (0 = garbage)
-    block_tables: np.ndarray    # [max_seqs+1, max_blocks_per_seq] int32
-    ctx_lens: np.ndarray        # [max_seqs+1] int32 tokens in cache AFTER step
-    logits_idx: np.ndarray      # [max_seqs+1] int32 row in T of final token
-    sample_mask: np.ndarray     # [max_seqs+1] bool — sample this slot?
+    index: PackedIndex
     n_tokens: int               # real (unpadded) token count
     uids_by_slot: Dict[int, int]  # slot → uid for sampled slots
 
 
+def _bucket(n: int, floor: int, cap: int) -> int:
+    """``n`` rounded up to ``floor`` times a power of two, at most ``cap``:
+    a handful of shapes, so a handful of compiled programs."""
+    b = floor
+    while b < n:
+        b *= 2
+    return min(b, cap)
+
+
 def build_ragged_batch(schedule: "List[tuple]", mgr: DSStateManager,
                        token_budget: int) -> RaggedBatch:
-    """Assemble device arrays from (seq, n_new_tokens) work items.
+    """Assemble device arrays from (seq, n_new_tokens) work items, in place
+    in the manager's buffer for the step's buckets.
 
     ``schedule`` holds (SequenceDescriptor, n_tokens) pairs; the last
     scheduled token of a sequence is sampled only if it is the sequence's
     final known token (i.e. the prompt chunk completes the prompt).
+
+    Shapes are bucketed (power-of-two token count and context width) so
+    decode-heavy steps don't pay the full prefill budget: a 16-seq decode
+    step runs [16, ctx] work, not [budget, max_ctx] (the shape discipline
+    the reference gets from its CUDA kernels' ragged launch geometry).
     """
     bs = mgr.block_size
-    t = token_budget
-    pad_slot = mgr.max_seqs
-    token_ids = np.zeros((t,), np.int32)
-    token_slot = np.full((t,), pad_slot, np.int32)
-    token_pos = np.zeros((t,), np.int32)
-    token_dest = np.zeros((t,), np.int32)
-    block_tables = np.zeros((mgr.max_seqs + 1, mgr.max_blocks_per_seq), np.int32)
-    ctx_lens = np.zeros((mgr.max_seqs + 1,), np.int32)
-    logits_idx = np.zeros((mgr.max_seqs + 1,), np.int32)
-    sample_mask = np.zeros((mgr.max_seqs + 1,), bool)
-    uids_by_slot: Dict[int, int] = {}
-
     total = sum(n_new for _, n_new in schedule)
-    if total > t:
-        raise RuntimeError(f"schedule ({total} tokens) exceeds budget {t}")
+    if total > token_budget:
+        raise RuntimeError(f"schedule ({total} tokens) exceeds budget "
+                           f"{token_budget}")
 
     # Reserve all pages up front so an allocator failure leaves every
     # sequence untouched (no num_cached advance without a KV write).
     for seq, n_new in schedule:
         mgr.ensure_capacity(seq, seq.num_cached + n_new)
 
+    ctx_max = max((seq.num_cached + n_new for seq, n_new in schedule),
+                  default=0)
+    nb = _bucket(-(-ctx_max // bs), 1, mgr.max_blocks_per_seq)
+    index = mgr.step_index(_bucket(total, 16, token_budget), nb)
+    (token_ids, token_slot, token_pos, token_dest, block_tables, ctx_lens,
+     logits_idx) = index.arrays()
+    uids_by_slot: Dict[int, int] = {}
+
+    slots, first_pos, counts = [], [], []
     cursor = 0
     for seq, n_new in schedule:
         start = seq.num_cached
         end = start + n_new
         sl = seq.slot
-        rows = np.arange(start, end, dtype=np.int32)
-        pos_block = rows // bs
-        dest = np.asarray(seq.blocks, np.int32)[pos_block] * bs + rows % bs
         token_ids[cursor:cursor + n_new] = seq.tokens[start:end]
-        token_slot[cursor:cursor + n_new] = sl
-        token_pos[cursor:cursor + n_new] = rows
-        token_dest[cursor:cursor + n_new] = dest
-        block_tables[sl, :len(seq.blocks)] = seq.blocks
+        # a sequence may hold pages past this step's context (a fused
+        # decode's horizon, a rewound draft): the bucket cuts them off
+        held = seq.blocks[:nb]
+        block_tables[sl, :len(held)] = held
         ctx_lens[sl] = end
         logits_idx[sl] = cursor + n_new - 1
-        sample_mask[sl] = (end == len(seq.tokens))
-        if sample_mask[sl]:
+        if end == len(seq.tokens):
             uids_by_slot[sl] = seq.uid
+        slots.append(sl)
+        first_pos.append(start - cursor)
+        counts.append(n_new)
         cursor += n_new
         seq.num_cached = end
 
-    return RaggedBatch(token_ids=token_ids, token_slot=token_slot,
-                       token_pos=token_pos, token_dest=token_dest,
-                       block_tables=block_tables, ctx_lens=ctx_lens,
-                       logits_idx=logits_idx, sample_mask=sample_mask,
-                       n_tokens=cursor, uids_by_slot=uids_by_slot)
+    if cursor:
+        # every sequence's rows at once: slot, position, KV destination
+        rows_slot = np.repeat(slots, counts)
+        rows_pos = np.arange(cursor) + np.repeat(first_pos, counts)
+        token_slot[:cursor] = rows_slot
+        token_pos[:cursor] = rows_pos
+        token_dest[:cursor] = (block_tables[rows_slot, rows_pos // bs] * bs
+                               + rows_pos % bs)
+
+    return RaggedBatch(index=index, n_tokens=cursor,
+                       uids_by_slot=uids_by_slot)

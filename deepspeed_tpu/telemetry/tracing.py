@@ -69,7 +69,7 @@ SPAN_NAMES = (
     "train.telemetry",         # StepRecord assembly + export
     "v2.dispatch",             # the jitted ragged step call until it returns
     "v2.fetch",                # wait for the device + copy the result back
-    "v2.h2d",                  # device_put of the step's index arrays
+    "v2.h2d",                  # device_put of the step's packed index buffer
     "v2.ragged_step",          # InferenceEngineV2.step (parent of the v2.*)
     "v2.schedule",             # next_schedule + build_ragged_batch
     "v2.state_alloc",          # recurrent state slots made (engine init)

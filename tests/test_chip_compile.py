@@ -156,20 +156,20 @@ def _abstract(chip, tree):
 
 
 def _compile_step(chip, cfg, pool, nb, t, state=None):
-    """``ragged_forward_sampled`` for one described v5e as the engine jits
-    it (pools and a mixer's state donated): 64 sequences and the padding
-    row, ``nb`` pages of 16 a sequence, ``t`` rows.  Abstract weights."""
+    """``ragged_step_sampled`` for one described v5e as the engine jits
+    it (pools and a mixer's state donated, the index arrays one packed
+    buffer): 64 sequences and the padding row, ``nb`` pages of 16 a
+    sequence, ``t`` rows.  Abstract weights."""
     from deepspeed_tpu.inference.v2 import model as v2_model
+    from deepspeed_tpu.inference.v2.ragged import PackedIndex
     from deepspeed_tpu.models import transformer as tf_model
 
     params = _abstract(chip, jax.eval_shape(
         lambda k: tf_model.init_params(cfg, k), jax.random.PRNGKey(0)))
-    rows = chip((t,), I32)
-    fn = functools.partial(v2_model.ragged_forward_sampled, cfg=cfg,
+    index = PackedIndex(chip((PackedIndex.size(t, 65, nb),), I32), t, 65, nb)
+    fn = functools.partial(v2_model.ragged_step_sampled, cfg=cfg,
                            block_size=_BS, greedy=True)
-    args = (params, pool, pool, rows, rows, rows, rows, chip((65, nb), I32),
-            chip((65,), I32), chip((65,), I32), chip((2,), jnp.uint32),
-            chip((), F32))
+    args = (params, pool, pool, index, chip((2,), jnp.uint32), chip((), F32))
     kw = {} if state is None else {"state": state}
     with jax.default_matmul_precision("default"):
         return jax.jit(fn, donate_argnums=(1, 2),

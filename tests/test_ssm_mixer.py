@@ -423,8 +423,8 @@ def test_models_without_a_mixer_keep_their_programs():
     assert eng.state is None and eng.ssm_impl is None
     assert eng.state_bytes == 0 and len(eng.kv_geometry()) == 6
     fn, args = eng.audit_step_args("decode")
-    assert len(args) == 10 and fn is eng._step
-    assert len(eng.audit_arg_categories()) == 10
+    assert len(args) == 4 and fn is eng._step
+    assert len(eng.audit_arg_categories()) == 4
     text = fn.lower(*args).as_text()
     assert "ssd_ragged" not in text and text.count("stablehlo.while") == 1
     eng.tracer = Tracer(enabled=True)

@@ -130,8 +130,10 @@ def test_new_spans_present_with_arguments_and_parents():
     # several steps; 5 new tokens a request: decode tokens in many
     assert sum(e["args"]["prefill_tokens"] for e in sched) == 5 + 20 + 3
     assert sum(e["args"]["decode_tokens"] for e in sched) == 3 * 4
+    # one packed index buffer a step; every request greedy: one program
     for e in by_name["v2.h2d"]:
-        assert e["args"]["arrays"] == 7 and e["args"]["bytes"] > 0
+        assert e["args"]["arrays"] == 1 and e["args"]["bytes"] > 0
+    assert all(e["args"]["programs"] == 1 for e in by_name["v2.dispatch"])
     shapes = [(e["args"]["t_bucket"], e["args"]["nb_bucket"],
                e["args"]["new_shape"]) for e in by_name["v2.dispatch"]]
     first = {}
