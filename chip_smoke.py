@@ -289,6 +289,73 @@ def ssd_phase(seed: int = 0, *, heads: int = 32, head_dim: int = 128,
     return {"y": err_y, "state": err_s, "silent": err_silent}
 
 
+def index_phase(seed: int = 0, *, heads: int = 64, dim: int = 128,
+                block_size: int = 128, blocks: int = 8,
+                chunk_rows: int = 150) -> dict:
+    """The learned indexer's score kernel at dots3-note's widths against
+    its XLA formulation (a gather of every row's keys) over one ragged
+    step: decode rows of different sequences, a prompt's chunk that
+    crosses a program's rows and several pages, a second chunk, and
+    padding.  Also held: a key a row may not see reads ``-inf`` in both,
+    so the sets the selection would choose are the same."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2.latent import (index_scores_xla,
+                                                   select_keys)
+    from deepspeed_tpu.ops.pallas.latent_index import index_scores
+
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 4))
+    bs, nb = block_size, blocks
+    ctx = bs * nb
+    runs = [(2, ctx - 1, 1), (0, 3 * bs + 5, 1), (1, bs // 2 + 3, chunk_rows),
+            (3, 0, 40)]                          # (slot, first position, rows)
+    n_pad = 6
+    slots = 4
+    slot = np.concatenate([np.full(n, s) for s, _, n in runs]
+                          + [np.full(n_pad, slots)]).astype(np.int32)
+    pos = np.concatenate([np.arange(p, p + n) for _, p, n in runs]
+                         + [np.zeros(n_pad)]).astype(np.int32)
+    t = len(slot)
+    clen = np.zeros(slots + 1, np.int32)
+    for s, p, n in runs:
+        clen[s] = p + n
+    tables = np.zeros((slots + 1, nb), np.int32)
+    tables[:slots] = 1 + np.random.default_rng(seed).permutation(
+        slots * nb).reshape(slots, nb)
+    bf16 = jnp.bfloat16
+    pool = jax.random.normal(next(keys), (2, (slots * nb + 1) * bs, dim), bf16)
+    q = jax.random.normal(next(keys), (t, heads, dim), bf16)
+    w = jax.random.normal(next(keys), (t, heads), jnp.float32) / heads
+    args = (q, w, pool, jnp.int32(1), jnp.asarray(tables), jnp.asarray(slot),
+            jnp.asarray(pos), jnp.asarray(clen)[slot])
+    want = jax.jit(index_scores_xla, static_argnums=8)(*args, bs)
+    got = index_scores(*args, block_size=bs)
+    real = slot != slots
+    same_mask = bool(jnp.array_equal(jnp.isinf(got)[real],
+                                     jnp.isinf(want)[real]))
+    seen = jnp.isfinite(want) & real[:, None]
+    err = float(jnp.max(jnp.where(seen, jnp.abs(got - want), 0.0))
+                / jnp.max(jnp.where(seen, jnp.abs(want), 0.0)))
+    k = min(2048, ctx // 2)
+    a, ok_a = select_keys(got, k)
+    b, ok_b = select_keys(want, k)
+    overlap = np.mean([
+        len(set(np.asarray(a[i])[np.asarray(ok_a[i])])
+            & set(np.asarray(b[i])[np.asarray(ok_b[i])]))
+        / max(1, int(ok_b[i].sum())) for i in np.flatnonzero(real)[::7]])
+    log(f"[index] {t} rows ({len(runs)} runs, {n_pad} padding), {heads} "
+        f"heads of {dim}, {nb} pages of {bs}: max |pallas - xla| {err:.5f} "
+        f"of max |score|; the same keys masked {same_mask}; the {k} best of "
+        f"a row shared {overlap:.4f}")
+    require(same_mask, "latent_index_scores masks other keys than its XLA "
+            "formulation")
+    require(np.isfinite(err) and err < 0.01 and overlap > 0.98,
+            f"latent_index_scores disagrees with its XLA formulation "
+            f"(scores {err}, chosen sets share {overlap})")
+    return {"scores": err, "overlap": float(overlap)}
+
+
 # ----------------------------------------------------------------------
 # train
 # ----------------------------------------------------------------------
@@ -557,6 +624,7 @@ def run_one_chip(seed: int) -> None:
 
     kernels_phase(seed)
     ssd_phase(seed)
+    index_phase(seed)
     train = train_phase(
         get_model_config(TRAIN_MODEL, max_seq_len=TRAIN_SEQ),
         micro_batch=TRAIN_MICRO_BATCH, gas=TRAIN_GAS, seq=TRAIN_SEQ,

@@ -59,6 +59,11 @@ class RaggedInferenceEngineConfig:
         state = d.get("state_manager", {})
         self.max_tracked_sequences = int(state.get("max_tracked_sequences", 64))
         self.max_ragged_batch_size = int(state.get("max_ragged_batch_size", 256))
+        # the narrowest block table a step program is compiled for: a
+        # deployment whose contexts all run past it compiles no program
+        # for the narrower tables (one a power of two otherwise); a
+        # shorter context then runs in this bucket's program
+        self.min_context_blocks = int(state.get("min_context_blocks", 1))
         self.memory_config = d.get("memory_config", {})
         self.num_blocks = int(self.memory_config.get("num_blocks", 512))
         self.block_size = int(self.memory_config.get("block_size", 16))
@@ -193,9 +198,23 @@ def ssm_step_counts(items: Sequence[tuple], slot_bytes: int,
 
 
 class RecurrentStateUnsupported(NotImplementedError):
-    """A path that would need a snapshot of a sequence's recurrent state
+    """A path that would need a snapshot of a sequence's slot state
     (prefix reuse, speculative verify and rewind, KV hand-off) was asked
-    of a model that has such state: there are no snapshots."""
+    of a model that has such state, a mixer's recurrent state or a window
+    latent layer's ring of rows: there are no snapshots."""
+
+
+# what the refusals call the state a slot holds, and why no earlier
+# position of it can be adopted, rewound to or shipped
+_MIXER_STATE = (
+    "this model's Mamba-2 SSM mixer keeps recurrent state per sequence, "
+    "which is only ever the state after the last row run — there is no "
+    "copy of it at an earlier position to adopt, rewind to or ship")
+_WINDOW_ROWS = (
+    "this model's sliding-window latent layers keep only the last window "
+    "of a sequence's rows, in a ring per sequence that later rows "
+    "overwrite — pages alone do not make a sequence, and there is no copy "
+    "of the ring at an earlier position to adopt, rewind to or ship")
 
 
 def _kv_scatter(cache_k, cache_v, rows, k, v):
@@ -267,7 +286,8 @@ class InferenceEngineV2:
             max_seqs=self.cfg.max_tracked_sequences,
             num_blocks=self.cfg.num_blocks,
             block_size=self.cfg.block_size,
-            max_blocks_per_seq=max_blocks_per_seq)
+            max_blocks_per_seq=max_blocks_per_seq,
+            min_blocks_bucket=self.cfg.min_context_blocks)
         self.scheduler = SplitFuseScheduler(self.state_manager,
                                             token_budget=self.cfg.max_ragged_batch_size)
         # software-span tracer (telemetry/tracing.py) — the serving layer
@@ -297,7 +317,20 @@ class InferenceEngineV2:
         # [L, nkv, P, d]: kv-head-major so the paged-attention kernel's page
         # blocks have (rows, head_dim) as their minor dims (lane-aligned).
         kv_shape = (mc.num_layers, mc.kv_heads, pages, mc.dim_per_head)
-        if self.cfg.kv_dtype == "int8":
+        latent = None
+        if mc.mla is not None:
+            # latent rows and index keys in the pages, the window layers'
+            # rings in the slots (made below, where a mixer's state is)
+            from deepspeed_tpu.inference.v2 import latent
+
+            if self.cfg.kv_dtype == "int8":
+                raise ValueError("memory_config.kv_dtype='int8': a latent "
+                                 "model's rows are kept in the compute dtype")
+            t0 = time.monotonic()
+            self.cache_k, self.cache_v, rings = latent.new_cache(
+                mc, pages, self.cfg.max_tracked_sequences,
+                self.cfg.max_ragged_batch_size, zeros, dt)
+        elif self.cfg.kv_dtype == "int8":
             # quantized cache: int8 payload + one fp32 scale per (head,
             # row) — decode reads half the KV bytes (bandwidth-bound)
             sc_shape = kv_shape[:-1]
@@ -317,14 +350,25 @@ class InferenceEngineV2:
         # Never cleared: a run that starts at position 0 starts from zeros
         # inside the step
         self.state = None
+        self.state_kind = None      # what the refusals call it
         self.ssm_impl = None
         self._slot_bytes = 0        # float32 recurrent state of one slot
         donate: Dict[str, Any] = {"donate_argnums": (1, 2)}
+        if latent is not None:
+            self.state = jax.block_until_ready(rings)
+            self.state_kind = _WINDOW_ROWS
+            self._state_alloc = {
+                "ts": t0 * 1e6, "dur": (time.monotonic() - t0) * 1e6,
+                "window_bytes": int(rings["win"].nbytes),
+                "ring_rows": int(rings["win"].shape[2]),
+                "slots": self.cfg.max_tracked_sequences + 1}
+            donate["donate_argnames"] = ("state",)
         if mc.ssm is not None:
             t0 = time.monotonic()
             self.state = jax.block_until_ready(new_ssm_state(
                 mc, self.cfg.max_tracked_sequences, zeros))
             self.ssm_impl = ssm_impl_name(mc)
+            self.state_kind = _MIXER_STATE
             self._slot_bytes = (int(self.state["ssm"].nbytes)
                                 // self.state["ssm"].shape[1])
             self._state_alloc = {
@@ -368,7 +412,9 @@ class InferenceEngineV2:
         # of block rows; padding points at the reserved garbage block 0)
         self._kv_write = jax.jit(_named("kv_write", _kv_scatter),
                                  donate_argnums=(0, 1))
-        self.attention_impl = attention_impl_name(mc, self.cfg.block_size)
+        self.attention_impl = (
+            attention_impl_name(mc, self.cfg.block_size) if latent is None
+            else "latent_" + latent.indexer_impl_name(mc))
         # rows of a step one program of the query-blocked paged kernel
         # serves; 0 where the step runs another kernel (the XLA gather
         # path, the int8-KV row kernel)
@@ -379,8 +425,8 @@ class InferenceEngineV2:
                  f"blocks={self.cfg.num_blocks}×{self.cfg.block_size} "
                  f"max_seqs={self.cfg.max_tracked_sequences} tp={self.cfg.tp_size} "
                  f"attention={self.attention_impl}"
-                 + (f" ssm={self.ssm_impl} state="
-                    f"{self.state_bytes / 2**20:.0f}MiB"
+                 + (f" ssm={self.ssm_impl}" if self.ssm_impl else "")
+                 + (f" state={self.state_bytes / 2**20:.0f}MiB"
                     if self.state is not None else ""))
 
     # -- recurrent state (a model with an SSM mixer) -------------------
@@ -410,10 +456,7 @@ class InferenceEngineV2:
     def _refuse_recurrent(self, what: str) -> None:
         if self.state is not None:
             raise RecurrentStateUnsupported(
-                f"{what} needs state snapshots: this model's Mamba-2 SSM "
-                "mixer keeps recurrent state per sequence, which is only "
-                "ever the state after the last row run — there is no copy "
-                "of it at an earlier position to adopt, rewind to or ship")
+                f"{what} needs state snapshots: {self.state_kind}")
 
     def _carried(self, out):
         """Rebind what a step carries (the KV pools, and the recurrent
@@ -488,9 +531,14 @@ class InferenceEngineV2:
             items = [(seq.num_cached - n, n) for seq, n in schedule]
             counts = step_counts(items, self.model_config.sliding_window,
                                  self._query_block)
-            if self.state is not None:
+            if self.model_config.ssm is not None:
                 counts.update(ssm_step_counts(
                     items, self._slot_bytes, self.state_manager.n_active))
+            elif self.model_config.mla is not None:
+                from deepspeed_tpu.inference.v2.latent import \
+                    latent_step_counts
+
+                counts.update(latent_step_counts(items, self.model_config))
             sp.end(**counts)
         if sample is None:
             program, variant, kw = self._step, (), {}

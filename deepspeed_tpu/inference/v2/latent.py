@@ -1,0 +1,511 @@
+"""Ragged step of a latent-attention model (``TransformerConfig.mla``; HF
+``dots3_note``): the trunk ``inference/v2/model.py:_ragged_trunk`` hands
+such a model to.  Imported where a latent model is built or traced, never
+on the package's import path.
+
+What a sequence keeps, and where:
+
+* **Full layers** write ONE row a token, ``[c_kv | k_r]`` (the normed key
+  latent and the rotary key every head shares), into pages of
+  ``cache_k`` ``[full layers, P, row]``, and the indexer's key into the
+  same page rows of ``cache_v`` ``[full layers, P, index dim]``: the
+  engine's block tables, allocator and ``token_dest`` serve both.  A
+  query reads the ``index_topk`` rows its indexer scores highest (all of
+  them while the context is shorter): scores over the sequence's pages
+  (:func:`deepspeed_tpu.ops.pallas.latent_index.index_scores` on a TPU,
+  a per-row gather elsewhere), :func:`select_keys`, a gather of the chosen
+  rows, a block of queries at a time, never ``[T, context, row]``.
+* **Window layers** keep the last ``sliding_window`` positions only: a
+  ring of ``ring`` rows a sequence, ``state["win"]`` ``[window layers,
+  max_seqs + 1, ring, row]``, in the per-sequence slots a state-space
+  mixer's state takes (``engine.state``); position ``p`` lives at
+  ``p % ring``.  With ``ring >= sliding_window + step budget`` a step
+  writes its rows first and reads after: no row a query of the step may
+  see has been overwritten by a later row of the same step.  Nothing is
+  cleared when a slot is handed on: a position below 0 is masked.  What
+  would need a copy of the rows at an earlier position (prefix adoption,
+  verify and rewind, KV hand-off) is refused by the engine, by name.
+
+Attention is the ABSORBED form for prefill chunks and decode rows alike:
+``q~_h = q_nope,h W_kb,h`` (``kv_lora_rank`` wide), score ``q~_h . c_kv +
+q_rope,h . k_r``, value ``(sum p c_kv) W_vb,h``: every head reads the one
+shared row, and no per-head key or value is ever formed.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from deepspeed_tpu.inference.v2.modules import (register_module, resolve,
+                                                resolve_name)
+from deepspeed_tpu.models.transformer import (LatentWidths,
+                                              TransformerConfig, _mlp_block,
+                                              _norm)
+from deepspeed_tpu.moe.sharded_moe import moe_forward_held
+from deepspeed_tpu.utils.platform import on_tpu
+
+NEG_INF = -1e30
+# elements of gathered rows one block of queries may hold at once
+_GATHER_ELEMENTS = 48 * 1024 * 1024
+INDEX_NORM_EPS = 1e-6
+
+
+def stored_width(row_dim: int) -> int:
+    """Lanes a cache row takes: whole 128-lane tiles.  Under the (8, 128)
+    tiling a 576-wide row occupies 640 lanes of HBM whatever is declared;
+    declared 576, the TPU's compiler turns the pool rows-minor for the
+    gather and copies it whole, there and back, in every layer (read in
+    the compiled text for a described v5e).  The tail is zeros, in the
+    rows and in the queries."""
+    return -(-row_dim // 128) * 128
+
+
+def ring_rows(cfg: TransformerConfig, token_budget: int) -> int:
+    """Rows of a sequence's ring in every window layer: the window and
+    one step's rows, in whole lane tiles."""
+    return -(-(cfg.mla.sliding_window + token_budget) // 128) * 128
+
+
+def new_cache(cfg: TransformerConfig, pool_rows: int, max_seqs: int,
+              token_budget: int, zeros=jnp.zeros, dtype=None):
+    """``(cache_k, cache_v, state)`` of a latent model, zeroed: the full
+    layers' latent rows and index keys in pages, the window layers'
+    rings in slots (slot ``max_seqs`` is the padding rows')."""
+    m, dtype = cfg.mla, dtype or cfg.dtype
+    kinds = m.kinds(cfg.num_layers)
+    n_full = sum(1 for full, _ in kinds if full)
+    n_win = len(kinds) - n_full
+    return (zeros((n_full, pool_rows, stored_width(m.full.row_dim)), dtype),
+            zeros((n_full, pool_rows, m.index_head_dim), dtype),
+            {"win": zeros((max(n_win, 1), max_seqs + 1,
+                           ring_rows(cfg, token_budget),
+                           stored_width(m.window.row_dim)), dtype)})
+
+
+def latent_step_counts(items, cfg: TransformerConfig) -> dict:
+    """What one ragged step asks of a latent model, from its ``(cached,
+    n_new)`` items: further arguments of ``v2.schedule``, each for ONE
+    layer of its kind.  ``latent_rows``: rows appended (a full layer's
+    page rows, a window layer's ring rows); ``index_pairs``: (row, key)
+    pairs the indexer scores, every causally visible key of every row;
+    ``selected_keys``: rows the full layers' attention then reads, at most
+    ``index_topk`` a query; ``window_keys``: rows a window layer reads;
+    ``expert_rows``: (row, held expert) products an expert layer expects
+    under even routing, rows x experts per token x held / routed."""
+    m = cfg.mla
+
+    def seen(cached, end, limit):
+        """Keys rows ``cached .. end`` read when each reads at most
+        ``limit``: the row at position p sees ``min(p + 1, limit)``."""
+        full = min(max(cached, limit), end)
+        return ((full * (full + 1) - cached * (cached + 1)) // 2
+                + (end - full) * limit)
+
+    rows = sum(n for _, n in items)
+    pairs = sum(seen(c, c + n, c + n) for c, n in items)
+    chosen = sum(seen(c, c + n, m.index_topk) for c, n in items)
+    window = sum(seen(c, c + n, m.sliding_window) for c, n in items)
+    return {"latent_rows": rows, "index_pairs": pairs,
+            "selected_keys": chosen, "window_keys": window,
+            "expert_rows": rows * m.num_experts_per_tok * m.experts_held[1]
+            / m.n_routed_experts}
+
+
+# -- pieces ------------------------------------------------------------
+def _rms(x, scale, cfg: TransformerConfig):
+    """RMSNorm at the model's eps, a bare gain for the params."""
+    return _norm(x, {"scale": scale}, cfg)
+
+
+def _rope(x, pos, theta):
+    """Half-split rotary over ALL dims of x [T, ..., n] at positions
+    ``pos`` [T]."""
+    n = x.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, n, 2, dtype=jnp.float32) / n)
+    ang = pos.astype(jnp.float32)[:, None] * inv              # [T, n/2]
+    ang = ang.reshape((ang.shape[0],) + (1,) * (x.ndim - 2) + (n // 2,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :n // 2], xf[..., n // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
+
+
+def _map_blocks(fn, arrays, n: int):
+    """``fn`` over blocks of ``n`` rows of ``arrays`` (a tuple of [T, ...]),
+    results laid end to end: one call where the rows are one block or do
+    not divide, else ``lax.map``."""
+    t = arrays[0].shape[0]
+    if t <= n or t % n:
+        return fn(arrays)
+    out = lax.map(fn, tuple(a.reshape((t // n, n) + a.shape[1:])
+                            for a in arrays))
+    return out.reshape((t,) + out.shape[2:])
+
+
+def _at(tree, i):
+    """Layer ``i`` of weights stacked on axis 0."""
+    return jax.tree.map(lambda a: a[i], tree)
+
+
+def _project(h, p, w: LatentWidths, pos, cfg: TransformerConfig):
+    """What both kinds of layer make of the normed input h [T, H]:
+    ``(c_q, q [T, heads, stored] absorbed, row [T, stored])``, query and
+    row ``[latent | rope | zeros]`` at the row's stored width."""
+    m, dt = cfg.mla, h.dtype
+    t = h.shape[0]
+    q_mul = math.sqrt(cfg.hidden_size / w.q_lora_rank) if m.lora_rescale else 1
+    kv_mul = (math.sqrt(cfg.hidden_size / w.kv_lora_rank)
+              if m.lora_rescale else 1)
+    c_q = _rms(h @ p["wq_a"].astype(dt), p["q_norm"], cfg) * q_mul
+    q = (c_q @ p["wq_b"].astype(dt)).reshape(t, w.num_heads, w.qk_head_dim)
+    q_rope = _rope(q[..., w.qk_nope_head_dim:], pos, w.rope_theta)
+    q_abs = jnp.einsum("thn,hnr->thr", q[..., :w.qk_nope_head_dim],
+                       p["wk_b"].astype(dt))
+    kv = h @ p["wkv_a"].astype(dt)
+    c_kv = _rms(kv[:, :w.kv_lora_rank], p["kv_norm"], cfg) * kv_mul
+    k_r = _rope(kv[:, w.kv_lora_rank:], pos, w.rope_theta)
+    pad = stored_width(w.row_dim) - w.row_dim
+    return (c_q,
+            jnp.concatenate([q_abs, q_rope, jnp.zeros(
+                (t, w.num_heads, pad), dt)], -1),
+            jnp.concatenate([c_kv, k_r, jnp.zeros((t, pad), dt)], -1))
+
+
+def _attend(q, gather, idx, ok, w: LatentWidths):
+    """Absorbed attention of q [T, heads, row] over the rows ``gather(idx
+    [n, K]) -> [n, K, row]`` gives each query, ``ok`` [T, K] masking; a
+    block of queries at a time.  Returns the attended latents
+    [T, heads, rank]."""
+    t, k = ok.shape
+    scale = 1.0 / math.sqrt(w.qk_head_dim)
+    rank = w.kv_lora_rank
+
+    def block(args):
+        qb, ib, mb = args
+        rows = gather(ib)                                   # [n, K, row]
+        s = jnp.einsum("nhd,nkd->nhk", qb, rows,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mb[:, None, :], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(qb.dtype)
+        # over the whole stored row, the latent cut out of the small
+        # result: cut out of the gathered rows it is a copy of them all
+        return jnp.einsum("nhk,nkd->nhd", p, rows)[..., :rank]
+
+    n = 16
+    while n < t and 2 * n * k * q.shape[-1] <= _GATHER_ELEMENTS:
+        n *= 2
+    return _map_blocks(block, (q, idx, ok), n)
+
+
+def _finish(x, h, ctx, p, w: LatentWidths):
+    """Up-project the attended latents, gate each head, project out."""
+    dt = x.dtype
+    out = jnp.einsum("thr,hrv->thv", ctx, p["wv_b"].astype(dt))
+    gate = jax.nn.sigmoid((h @ p["wg"].astype(dt)).astype(jnp.float32))
+    out = out * gate.astype(dt)[..., None]
+    return x + out.reshape(out.shape[0], -1) @ p["wo"].astype(dt)
+
+
+# -- the indexer -------------------------------------------------------
+def index_scores_xla(q_i, w_i, pool, layer, tables, token_slot, token_pos,
+                     token_ctx_len, block_size: int):
+    """``I[t, c] = sum_j w_i[t, j] relu(q_i[t, j] . k[c])`` over the
+    context positions ``c`` of row t's own sequence, ``-inf`` where ``c``
+    is not causally visible: a gather of every row's keys
+    ``[T, C, d]`` (the CPU's, and a test's: too large at a long
+    context).  pool: every full layer's index keys [L, P, d]."""
+    c = jnp.arange(tables.shape[1] * block_size, dtype=jnp.int32)
+    rows = tables[token_slot][:, c // block_size] * block_size \
+        + c % block_size                                       # [T, C]
+    k = pool[layer, rows]                                      # [T, C, d]
+    s = jnp.einsum("tjd,tcd->tjc", q_i, k,
+                   preferred_element_type=jnp.float32)
+    scores = jnp.einsum("tj,tjc->tc", w_i, jax.nn.relu(s))
+    seen = (c[None] <= token_pos[:, None]) & (c[None] < token_ctx_len[:, None])
+    return jnp.where(seen, scores, -jnp.inf)
+
+
+@register_module("indexer", "indexer_pallas",
+                 default_for=lambda on_tpu=False, **_: on_tpu)
+def _indexer_pallas(*args, block_size):
+    from deepspeed_tpu.ops.pallas.latent_index import index_scores
+
+    return index_scores(*args, block_size=block_size)
+
+
+@register_module("indexer", "indexer_xla")
+def _indexer_xla(*args, block_size):
+    return index_scores_xla(*args, block_size=block_size)
+
+
+def indexer_impl_name(cfg: TransformerConfig) -> str:
+    """The indexer's scores: the paged kernel on a TPU, the gather
+    elsewhere; ``cfg.v2_modules`` pins a name."""
+    name = dict(cfg.v2_modules or ()).get("indexer", "auto")
+    return resolve_name("indexer", name, on_tpu=on_tpu())
+
+
+def _index_inputs(h, c_q, p, token_pos, cfg: TransformerConfig):
+    """The indexer's queries [T, heads, d], keys [T, d] and head weights
+    [T, heads] (float32) of rows with normed input h and query latent
+    c_q: rotary on the first ``index_rope_dim`` dims of both."""
+    m, dt, t = cfg.mla, h.dtype, h.shape[0]
+    rot, theta = m.index_rope_dim, m.full.rope_theta
+    q_i = (c_q @ p["idx_wq"].astype(dt)).reshape(t, m.index_heads,
+                                                 m.index_head_dim)
+    q_i = jnp.concatenate([_rope(q_i[..., :rot], token_pos, theta),
+                           q_i[..., rot:]], -1)
+    k_i = (h @ p["idx_wk"].astype(dt)).astype(jnp.float32)
+    mean = k_i.mean(-1, keepdims=True)
+    var = jnp.square(k_i - mean).mean(-1, keepdims=True)
+    k_i = ((k_i - mean) * lax.rsqrt(var + INDEX_NORM_EPS)
+           * p["idx_k_norm"]["scale"].astype(jnp.float32)
+           + p["idx_k_norm"]["bias"].astype(jnp.float32)).astype(dt)
+    k_i = jnp.concatenate([_rope(k_i[:, :rot], token_pos, theta),
+                           k_i[:, rot:]], -1)
+    w_i = (h @ p["idx_ww"].astype(dt)).astype(jnp.float32) \
+        * (m.index_heads ** -0.5 * m.index_head_dim ** -0.5)
+    return q_i, k_i, w_i
+
+
+SELECT_BLOCK = 128      # context positions one place of the compaction spans
+_SELECT_ROWS = 64       # rows of a step compacted at once
+
+
+def select_keys(scores, topk: int):
+    """The ``topk`` best-scored context positions of every row, exactly,
+    ``(positions [T, k], ok [T, k])`` with ``k = min(topk, C)``: ``ok``
+    is False where a row sees fewer keys than ``k``; among equal scores
+    the lower position wins (as a stable descending sort has it).  The
+    positions come in rising order, not by score: attention does not
+    care.  scores: [T, C] float32, ``-inf`` where a key is not visible.
+
+    No sort.  ``lax.top_k`` is a full sort of ``[T, C]`` on the TPU: 70 ms
+    for a 1024-row chunk at a 32k context, a third of the chip's time in
+    the first traced run (PERF.md, PR 34).  Instead: the k-th largest
+    score of every row by bisection on the scores' bits, 32 counting
+    passes; then the chosen places are compacted into a list by counting:
+    places per ``SELECT_BLOCK`` positions, the block of the i-th chosen
+    place from the blocks' running counts, and its place inside the block
+    from the block's own running count, which a one-hot product on the
+    matrix unit fetches (small whole numbers, exact in bf16)."""
+    t, c = scores.shape
+    k = min(topk, c)
+    if c <= topk:
+        # every visible key is chosen
+        return (jnp.broadcast_to(jnp.arange(c, dtype=jnp.int32), (t, c)),
+                scores > -jnp.inf)
+    i32, u32 = jnp.int32, jnp.uint32
+    # -0.0 and 0.0 are one score
+    bits = lax.bitcast_convert_type(jnp.where(scores == 0, 0.0, scores), i32)
+    # order-preserving: a larger float is a larger unsigned number
+    key = lax.bitcast_convert_type(
+        jnp.where(bits < 0, ~bits, bits | i32(-2 ** 31)), u32)
+
+    def narrow(j, thr):
+        cand = thr | (u32(1) << (u32(31) - j.astype(u32)))
+        enough = jnp.sum((key >= cand[:, None]).astype(i32), axis=1) >= k
+        return jnp.where(enough, cand, thr)
+
+    # the largest value that at least k scores of the row reach
+    thr = lax.fori_loop(0, 32, narrow, jnp.zeros((t,), u32))[:, None]
+    above = key > thr
+    tied = key == thr
+    need = k - jnp.sum(above.astype(i32), axis=1, keepdims=True)
+    chosen = (scores > -jnp.inf) & (
+        above | (tied & (jnp.cumsum(tied.astype(i32), axis=1) <= need)))
+
+    b = min(SELECT_BLOCK, c)
+    nblk = c // b
+    tri = (jnp.arange(b)[:, None] <= jnp.arange(b)[None, :]
+           ).astype(jnp.bfloat16)
+    # running count inside every block (at most b: exact in bf16)
+    local = jnp.dot(chosen.reshape(t * nblk, b).astype(jnp.bfloat16), tri,
+                    preferred_element_type=jnp.float32
+                    ).reshape(t, nblk, b)
+    count = local[:, :, -1].astype(i32)                       # [T, nblk]
+    upto = jnp.cumsum(count, axis=1)
+    place = jnp.arange(k, dtype=i32)
+
+    def compact(args):
+        local_, count_, upto_ = args
+        before = upto_[:, None, :] <= place[None, :, None]    # [n, k, nblk]
+        block = jnp.sum(before.astype(i32), axis=-1)          # [n, k]
+        rank = place[None] - jnp.sum(jnp.where(before, count_[:, None, :],
+                                               0), axis=-1)
+        hot = (block[..., None] == jnp.arange(nblk, dtype=i32)
+               ).astype(jnp.bfloat16)
+        mine = jnp.einsum("nkj,njb->nkb", hot, local_.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+        inside = jnp.sum((mine <= rank[..., None].astype(jnp.float32)
+                          ).astype(i32), axis=-1)
+        return jnp.minimum(block, nblk - 1) * b + jnp.minimum(inside, b - 1)
+
+    positions = _map_blocks(compact, (local, count, upto), _SELECT_ROWS)
+    return positions, place[None] < upto[:, -1:]
+
+
+def _page_rows(tables, positions, block_size: int):
+    """Pool rows of context ``positions`` [T, K] under each row's own
+    page table ``tables`` [T, NB]: a compare-and-add over the table's
+    entries, which fuses into one pass (as an element gather it took 16
+    ms for a 1024-row chunk, more than the scores: PERF.md, PR 34)."""
+    j = jnp.arange(tables.shape[1], dtype=jnp.int32)
+    hit = (positions // block_size)[..., None] == j
+    pages = jnp.sum(jnp.where(hit, tables[:, None, :], 0), axis=-1)
+    return pages * block_size + positions % block_size
+
+
+# -- layers ------------------------------------------------------------
+def _full_layer(x, ln1, p, cache_k, cache_v, layer, meta,
+                cfg: TransformerConfig):
+    (token_pos, token_dest, token_slot, _, block_tables, ctx_lens,
+     block_size) = meta
+    m, w = cfg.mla, cfg.mla.full
+    h = _rms(x, ln1, cfg)
+    c_q, q, row = _project(h, p, w, token_pos, cfg)
+    cache_k = cache_k.at[layer, token_dest].set(row.astype(cache_k.dtype))
+
+    q_i, k_i, w_i = _index_inputs(h, c_q, p, token_pos, cfg)
+    cache_v = cache_v.at[layer, token_dest].set(k_i.astype(cache_v.dtype))
+
+    scores = resolve("indexer", indexer_impl_name(cfg))(
+        q_i, w_i, cache_v, layer, block_tables, token_slot, token_pos,
+        ctx_lens[token_slot], block_size=block_size)
+    sel, ok = select_keys(scores, m.index_topk)
+    rows = _page_rows(block_tables[token_slot], sel, block_size)
+    ctx = _attend(q, lambda i: cache_k[layer, i], rows, ok, w)
+    return _finish(x, h, ctx, p, w), cache_k, cache_v
+
+
+WINDOW_BLOCK = 128      # rows of a step that may share one read of the ring
+
+
+def _window_layer(x, ln1, p, ring, layer, meta, cfg: TransformerConfig):
+    """A window latent layer over the step's rows.  Consecutive rows of
+    one sequence see nearly the same keys: a block of ``WINDOW_BLOCK``
+    such rows reads the ``window - 1 + block`` ring rows they span ONCE
+    and masks the band (``lax.cond`` on what the block's rows are);
+    any other block (decode rows of different sequences, a run's ends,
+    padding) gathers each row's own ``window`` rows."""
+    token_pos, _, _, ring_slot = meta[:4]
+    w, window = cfg.mla.window, cfg.mla.sliding_window
+    size, t = ring.shape[2], x.shape[0]
+    h = _rms(x, ln1, cfg)
+    _, q, row = _project(h, p, w, token_pos, cfg)
+    ring = ring.at[layer, ring_slot, token_pos % size].set(
+        row.astype(ring.dtype))
+    scale = 1.0 / math.sqrt(w.qk_head_dim)
+    rank = w.kv_lora_rank
+    n = min(WINDOW_BLOCK, t)
+    # a row's own keys, oldest last; padded to whole sublane tiles
+    back = jnp.arange(-(-window // 8) * 8, dtype=jnp.int32)
+    span = jnp.arange(window - 1 + n, dtype=jnp.int32) - (window - 1)
+
+    def attend(qb, rows, seen):
+        sc = jnp.einsum("nhd,nkd->nhk" if rows.ndim == 3 else "nhd,kd->nhk",
+                        qb, rows, preferred_element_type=jnp.float32) * scale
+        pr = jax.nn.softmax(jnp.where(seen[:, None, :], sc, NEG_INF),
+                            axis=-1).astype(qb.dtype)
+        return jnp.einsum("nhk,nkd->nhd" if rows.ndim == 3 else "nhk,kd->nhd",
+                          pr, rows)[..., :rank]
+
+    def one_run(args):
+        qb, slot, pos = args
+        at = pos[0] + span                                   # [window-1+n]
+        rows = ring[layer, slot[0], at % size]
+        d = pos[:, None] - at[None, :]
+        return attend(qb, rows, (d >= 0) & (d < window) & (at[None] >= 0))
+
+    def each_row(args):
+        qb, slot, pos = args
+        at = pos[:, None] - back[None, :]                    # [n, window~]
+        rows = ring[layer, slot[:, None], at % size]
+        return attend(qb, rows, (at >= 0) & (back[None] < window))
+
+    def block(args):
+        _, slot, pos = args
+        together = jnp.all(slot == slot[0]) & jnp.all(
+            pos == pos[0] + jnp.arange(n, dtype=jnp.int32))
+        return lax.cond(together, one_run, each_row, args)
+
+    ctx = _map_blocks(block, (q, ring_slot, token_pos), n)
+    return _finish(x, h, ctx, p, w), ring
+
+
+def _feed_forward(x, ln2, stack, i, has_experts: bool,
+                  cfg: TransformerConfig):
+    """The ``i``-th feed-forward of its kind, ``stack`` holding the kind's
+    layers: dense, or this program's routed experts and the shared one."""
+    m = cfg.mla
+    h = _rms(x, ln2, cfg)
+    if not has_experts:
+        return x + _mlp_block(h, _at(stack, i), cfg)
+    return x + moe_forward_held(
+        h, stack, i, top_k=m.num_experts_per_tok,
+        first=m.experts_held[0]) + _mlp_block(h, _at(stack["shared"], i), cfg)
+
+
+def latent_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
+                 token_dest, block_tables, ctx_lens, state,
+                 cfg: TransformerConfig, block_size: int, state_slot=None):
+    """Embedding, every block and the final norm over a step's flat rows,
+    as ``model._ragged_trunk`` for a model without latent attention:
+    ``(x [T, H], cache_k', cache_v', state')``.  Consecutive layers of one
+    kind are one ``lax.scan`` (a period's three window layers); the pools
+    and the rings ride its carry whole and are updated in place."""
+    if state is None:
+        raise ValueError(
+            "this model's window latent layers keep their rows in "
+            "per-sequence rings (state=latent.new_cache(...)[2]); a caller "
+            "that keeps none (inference.kv_generate) cannot run it")
+    layers = params["layers"]
+    x = params["embed"]["tokens"].astype(cfg.dtype)[token_ids]
+    meta = (token_pos, token_dest, token_slot,
+            token_slot if state_slot is None else state_slot, block_tables,
+            ctx_lens, block_size)
+
+    def block(carry, i, start, kind):
+        x, ck, cv, ring = carry
+        is_full, has_experts = kind
+        at = {name: n + i for name, n in start.items()}
+        ln1 = layers["ln1"]["scale"][at["layer"]]
+        if is_full:
+            x, ck, cv = _full_layer(x, ln1, _at(layers["full"], at["full"]),
+                                    ck, cv, at["full"], meta, cfg)
+        else:
+            x, ring = _window_layer(x, ln1,
+                                    _at(layers["window"], at["window"]),
+                                    ring, at["window"], meta, cfg)
+        ffn = "moe" if has_experts else "mlp"
+        x = _feed_forward(x, layers["ln2"]["scale"][at["layer"]],
+                          layers[ffn], at[ffn], has_experts, cfg)
+        return (x, ck, cv, ring), None
+
+    kinds = cfg.mla.kinds(cfg.num_layers)
+    carry = (x, cache_k, cache_v, state["win"])
+    count = {"layer": 0, "full": 0, "window": 0, "mlp": 0, "moe": 0}
+    i = 0
+    while i < len(kinds):
+        n = 1
+        while i + n < len(kinds) and kinds[i + n] == kinds[i]:
+            n += 1
+        start, kind = dict(count), kinds[i]
+        if n == 1:
+            carry, _ = block(carry, 0, start, kind)
+        else:
+            carry, _ = lax.scan(
+                lambda c, j, s=start, k=kind: block(c, j, s, k), carry,
+                jnp.arange(n, dtype=jnp.int32))
+        count["layer"] += n
+        count["full" if kind[0] else "window"] += n
+        count["moe" if kind[1] else "mlp"] += n
+        i += n
+    x, cache_k, cache_v, ring = carry
+    return (_rms(x, params["final_norm"]["scale"], cfg),
+            cache_k, cache_v, {"win": ring})

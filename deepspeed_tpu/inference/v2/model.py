@@ -491,6 +491,13 @@ def _ragged_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
     (x [T, H], cache_k', cache_v', state').  The one layer loop of every
     ragged program: the pools (and a mixer's recurrent slots) ride its
     carry whole and each block updates them in place."""
+    if cfg.mla is not None:
+        # latent attention: pools of latent rows, rings in the slots
+        from deepspeed_tpu.inference.v2.latent import latent_trunk
+
+        return latent_trunk(params, cache_k, cache_v, token_ids, token_slot,
+                            token_pos, token_dest, block_tables, ctx_lens,
+                            state, cfg, block_size, state_slot)
     ssm_meta = _ssm_meta(cfg, state, token_slot if state_slot is None
                          else state_slot, token_pos)
     x = _embed_rows(params, token_ids, token_pos, cfg)
@@ -578,6 +585,8 @@ def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
     if cfg.ssm:
         logits = logits * cfg.ssm.lm_head_multiplier
         return logits.astype(jnp.float32), cache_k, cache_v, state
+    if cfg.mla:
+        return logits.astype(jnp.float32), cache_k, cache_v, state
     return logits.astype(jnp.float32), cache_k, cache_v
 
 
@@ -640,6 +649,12 @@ def ragged_forward_verify(params, cache_k, cache_v, token_ids, token_slot,
             "speculative verify needs state snapshots: a rejected draft "
             "row has already advanced the Mamba-2 SSM mixer's recurrent "
             "state, and no copy of the state before it is kept")
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            "speculative verify needs a copy of the window latent layers' "
+            "rows before the draft: a rejected draft row has already "
+            "overwritten its ring position, and only the last "
+            f"{cfg.mla.sliding_window} positions and one step are kept")
     if cfg.alt_window or cfg.is_moe:
         raise NotImplementedError(
             "speculative verify step supports the plain scanned-layer "
@@ -738,7 +753,9 @@ def ragged_decode_loop(params, cache_k, cache_v, tokens0, ctx_lens0,
     act_i = active.astype(jnp.int32)
     state_slot = None
     if state is not None:
-        state_slot = jnp.where(active, slots, state["ssm"].shape[1] - 1)
+        # the padding rows' slot, the last of every kind of slot state
+        state_slot = jnp.where(active, slots,
+                               jax.tree.leaves(state)[0].shape[1] - 1)
 
     def step(carry, step_key):
         tokens, ctx_lens, ck, cv, st = carry

@@ -175,7 +175,9 @@ class DSStateManager:
     """Tracks live sequences, their slots and KV pages (ref ragged_manager.py).
 
     ``max_seqs`` bounds concurrent sequences (device block-table rows);
-    ``max_blocks_per_seq`` bounds context length per sequence.
+    ``max_blocks_per_seq`` bounds context length per sequence;
+    ``min_blocks_bucket`` is the narrowest block table a step is cut to
+    (the context buckets are it times a power of two).
 
     A sequence holds two kinds of state: its KV pages (``blocks``, from
     the allocator) and its ``slot``, which besides a block-table row is
@@ -194,10 +196,15 @@ class DSStateManager:
     """
 
     def __init__(self, max_seqs: int, num_blocks: int, block_size: int,
-                 max_blocks_per_seq: int):
+                 max_blocks_per_seq: int, min_blocks_bucket: int = 1):
+        if not 1 <= min_blocks_bucket <= max_blocks_per_seq:
+            raise ValueError(
+                f"min_context_blocks={min_blocks_bucket}: expected 1 .. "
+                f"{max_blocks_per_seq} (max_context / block_size)")
         self.max_seqs = max_seqs
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
+        self.min_blocks_bucket = min_blocks_bucket
         self.allocator = BlockedAllocator(num_blocks)
         self._seqs: Dict[int, SequenceDescriptor] = {}
         self._free_slots = list(range(max_seqs - 1, -1, -1))
@@ -345,7 +352,8 @@ def build_ragged_batch(schedule: "List[tuple]", mgr: DSStateManager,
 
     ctx_max = max((seq.num_cached + n_new for seq, n_new in schedule),
                   default=0)
-    nb = _bucket(-(-ctx_max // bs), 1, mgr.max_blocks_per_seq)
+    nb = _bucket(-(-ctx_max // bs), mgr.min_blocks_bucket,
+                 mgr.max_blocks_per_seq)
     index = mgr.step_index(_bucket(total, 16, token_budget), nb)
     (token_ids, token_slot, token_pos, token_dest, block_tables, ctx_lens,
      logits_idx) = index.arrays()

@@ -4,7 +4,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.transformer import SSMConfig, TransformerConfig
+from deepspeed_tpu.models.transformer import (LatentConfig, LatentWidths,
+                                              SSMConfig, TransformerConfig)
 
 _REGISTRY = {}
 
@@ -169,6 +170,62 @@ register("falcon-h1-tiny", TransformerConfig(
         ssm_multipliers=(0.7, 1.2, 0.9, 1.1, 0.8),
         mlp_multipliers=(1.4, 0.75)),
     **_falcon_h1))
+
+# -- dots3-note (HF dots3_note: latent attention of two kinds by layer, a
+# learned top-k indexer in the full layers, sigmoid-routed experts) ----
+_dots3 = dict(arch="dots3_note", norm="rmsnorm", activation="swiglu",
+              use_rope=True, tie_embeddings=False, use_bias=False)
+# full at 0, 1, 5, 9, ...: a leading full layer, then periods of
+# (full, window, window, window)
+_dots3_types = ("full_attention",) + ("full_attention", "sliding_attention",
+                                      "sliding_attention",
+                                      "sliding_attention") * 11 \
+    + ("full_attention",)
+
+
+def _dots3_latent(experts_held):
+    return LatentConfig(
+        full=LatentWidths(num_heads=128, q_lora_rank=1024, kv_lora_rank=512,
+                          qk_nope_head_dim=128, qk_rope_head_dim=64,
+                          v_head_dim=128, rope_theta=8e7),
+        window=LatentWidths(num_heads=64, q_lora_rank=1024,
+                            kv_lora_rank=1024, qk_nope_head_dim=192,
+                            qk_rope_head_dim=64, v_head_dim=128,
+                            rope_theta=5e4),
+        layer_types=_dots3_types, sliding_window=513, index_heads=64,
+        index_head_dim=128, index_topk=2048, index_rope_dim=64,
+        n_routed_experts=256, experts_held=experts_held,
+        num_experts_per_tok=8, moe_intermediate_size=1536)
+
+
+register("dots3-note-prev", TransformerConfig(
+    vocab_size=152064, hidden_size=5120, intermediate_size=13824,
+    num_layers=46, num_heads=128, max_seq_len=524288, rope_theta=8e7,
+    layernorm_eps=1e-5, mla=_dots3_latent((0, 256)), **_dots3))
+
+# one chip's share of a layer divided over eight: experts 0-31 of the 256
+# (routing over all of them) and an eighth of the vocabulary; attention
+# and the shared expert whole
+register("dots3-note-prev-ep8", TransformerConfig(
+    vocab_size=19008, hidden_size=5120, intermediate_size=13824,
+    num_layers=46, num_heads=128, max_seq_len=524288, rope_theta=8e7,
+    layernorm_eps=1e-5, mla=_dots3_latent((0, 32)), **_dots3))
+
+register("dots3-note-tiny", TransformerConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=5,
+    num_heads=4, max_seq_len=256, rope_theta=1e4, layernorm_eps=1e-5,
+    mla=LatentConfig(
+        full=LatentWidths(num_heads=4, q_lora_rank=32, kv_lora_rank=24,
+                          qk_nope_head_dim=16, qk_rope_head_dim=8,
+                          v_head_dim=16, rope_theta=1e4),
+        window=LatentWidths(num_heads=2, q_lora_rank=32, kv_lora_rank=40,
+                            qk_nope_head_dim=24, qk_rope_head_dim=8,
+                            v_head_dim=16, rope_theta=5e2),
+        layer_types=_dots3_types, sliding_window=5, index_heads=4,
+        index_head_dim=16, index_topk=8, index_rope_dim=8,
+        n_routed_experts=16, experts_held=(4, 4), num_experts_per_tok=4,
+        moe_intermediate_size=32),
+    **_dots3))
 
 # -- Phi (ref v2 phi: parallel block + partial rotary + biases) --------
 register("phi-2", TransformerConfig(

@@ -79,6 +79,69 @@ class SSMConfig:
 
 
 @dataclass(frozen=True)
+class LatentWidths:
+    """One kind of latent-attention (MLA) layer's sizes: queries and keys
+    go through low-rank latents, a head's key is ``nope`` dims made from
+    the key latent plus ``rope`` rotary dims shared by every head, and
+    the cache holds ONE row a token, ``[c_kv | k_r]``."""
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+
+    @property
+    def row_dim(self) -> int:
+        """Width of a cache row: the key latent and the shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class LatentConfig:
+    """Latent attention in every block, of two kinds chosen per layer by
+    ``layer_types`` (HF ``dots3_note``): ``"full_attention"`` layers read
+    the ``index_topk`` keys a learned indexer scores highest,
+    ``"sliding_attention"`` layers the last ``sliding_window`` positions
+    at widths of their own; a headwise sigmoid gate on every head's
+    output; ``first_k_dense`` leading blocks with a dense feed-forward,
+    the others with sigmoid-routed experts of which this program holds
+    ``experts_held`` (first, count): it routes over all
+    ``n_routed_experts`` and computes its own experts' part (weights
+    normalised over the chosen, times a ``routed_scaling_factor`` of 1,
+    as published).
+    ``TransformerConfig.mla`` is ``None`` for a model without latent
+    attention.  Served by inference/v2 only."""
+    full: LatentWidths
+    window: LatentWidths
+    layer_types: Tuple[str, ...]
+    sliding_window: int
+    index_heads: int
+    index_head_dim: int
+    index_topk: int
+    index_rope_dim: int
+    n_routed_experts: int
+    experts_held: Tuple[int, int]
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    n_shared_experts: int = 1
+    first_k_dense: int = 1
+    lora_rescale: bool = True
+    rope_interleaved: bool = False
+
+    def kinds(self, num_layers: int) -> Tuple[Tuple[bool, bool], ...]:
+        """(is a full layer, has experts) for each of the first
+        ``num_layers`` layers."""
+        return tuple((t == "full_attention", i >= self.first_k_dense)
+                     for i, t in enumerate(self.layer_types[:num_layers]))
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     """Architecture hyperparameters covering GPT-2 and Llama families."""
     vocab_size: int = 50257
@@ -266,6 +329,32 @@ class TransformerConfig:
     # state-space mixer beside attention in every block (Falcon-H1);
     # None: the block has attention alone.  Served by inference/v2 only
     ssm: Optional[SSMConfig] = None
+    # latent (MLA) attention of two kinds by layer, a learned indexer and
+    # routed experts held in part (dots3-note); None: plain attention.
+    # Served by inference/v2 only
+    mla: Optional[LatentConfig] = None
+
+    # a latent model's sizes by flat names (0 without one), as the
+    # mixer's below
+    @property
+    def latent_row(self) -> int:
+        return self.mla.full.row_dim if self.mla else 0
+
+    @property
+    def window_row(self) -> int:
+        return self.mla.window.row_dim if self.mla else 0
+
+    @property
+    def index_topk(self) -> int:
+        return self.mla.index_topk if self.mla else 0
+
+    @property
+    def n_routed_experts(self) -> int:
+        return self.mla.n_routed_experts if self.mla else 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.mla.experts_held[1] if self.mla else 0
 
     # the mixer's sizes by flat names (0 without one), for callers that
     # hold a configuration to a file by ``getattr``
@@ -465,7 +554,15 @@ def init_ssm_params(cfg: TransformerConfig, key) -> Params:
 
 def refuse_ssm(cfg: TransformerConfig, what: str) -> None:
     """The dense forward has no state-space scan (and no backward for
-    one): a configuration with a mixer is served by inference/v2 only."""
+    one), and no latent attention: a configuration with either is served
+    by inference/v2 only."""
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            f"{what}: this configuration has latent (MLA) attention with a "
+            f"learned top-{cfg.mla.index_topk} indexer and window-"
+            f"{cfg.mla.sliding_window} latent layers; models/transformer.py "
+            "has neither and would run plain attention under its name. "
+            "Serve it through inference.v2.InferenceEngineV2")
     if cfg.ssm is not None:
         raise NotImplementedError(
             f"{what}: this configuration has a Mamba-2 SSM mixer beside "
@@ -475,11 +572,140 @@ def refuse_ssm(cfg: TransformerConfig, what: str) -> None:
             "it through inference.v2.InferenceEngineV2")
 
 
+# a latent model's seeded routing (init_latent_params has the reason)
+EMBED_COMMON = 0.07
+RESIDUAL_RMS = 0.5
+ROUTER_LOGIT_SD = 4.0
+ROUTER_LOGIT_MEAN = 3.5
+
+
+def init_latent_params(cfg: TransformerConfig, key) -> Params:
+    """A latent-attention model's params.  Layers of one kind are stacked
+    on axis 0 under the kind's name: ``layers/full`` and
+    ``layers/window`` (attention), ``layers/mlp`` (the leading dense
+    feed-forwards) and ``layers/moe`` (router over ALL experts, selection
+    bias, the held experts' weights ``[n, held, ...]``, the shared
+    expert); ``layers/ln1`` and ``ln2`` over every layer.  The up
+    projection of the key latent is kept as its two halves, ``wk_b``
+    ``[heads, nope, rank]`` and ``wv_b`` ``[heads, rank, v]``: the
+    absorbed form multiplies queries by the first and the attended latent
+    by the second.  The selection bias is zeros (a trained value is not
+    public); seeded normal initialisation otherwise.
+
+    **The routing's initialisation.**  With zero-mean router logits the
+    eight chosen scores all lie near one end of the sigmoid and each gets
+    an eighth of the weight: a near-tie between the eighth and the ninth
+    score, which any rounding upstream flips in one row of seven, swaps an
+    eighth of the routed output, and a comparison with a float32
+    reference then reads the flips and not the arithmetic (PERF.md §6, PR
+    34).  A trained ``noaux_tc`` router's scores lie in the sigmoid's
+    LOWER tail (a few experts near one, the rest near zero), so the
+    marginal expert of the eight carries little weight and a flip there
+    moves little.  Seeded weights get there as a trained model does, by a
+    direction every hidden state shares: every embedding element has the
+    offset ``EMBED_COMMON`` (beside a token-specific part of norm one) and
+    every router element the matching negative part, so that the logits
+    have standard deviation ``ROUTER_LOGIT_SD`` about a mean
+    ``ROUTER_LOGIT_MEAN`` standard deviations below zero where the
+    stream's token-specific part has grown to an rms of ``RESIDUAL_RMS``,
+    which the output projections' ``1 / sqrt(2 layers)`` makes of it by
+    the last blocks whatever the depth; earlier blocks sit lower still.
+    Down there the eight weights are a softmax of the eight logits."""
+    m, h, pd, nl = cfg.mla, cfg.hidden_size, cfg.param_dtype, cfg.num_layers
+    kinds = m.kinds(nl)
+    if len(kinds) != nl:
+        raise ValueError(f"layer_types names {len(kinds)} layers, the "
+                         f"model has {nl}")
+    out_scale = 1.0 / math.sqrt(2 * nl)
+
+    def dense(k, shape, fan_in, out=False):
+        return _dense_init(k, shape, (out_scale if out else 1.0)
+                           / math.sqrt(fan_in), pd)
+
+    def attn(k, w: LatentWidths, indexer: bool):
+        k = jax.random.split(k, 10)
+        nh = w.num_heads
+        # what reads a rescaled latent (rms sqrt(hidden / rank)) divides
+        # its fan-in by that, so queries, keys and values start at unit
+        # variance and attention's scores at about one: sharper scores
+        # would be a property of the seed, not of the architecture
+        q_in = w.q_lora_rank * (h / w.q_lora_rank if m.lora_rescale else 1)
+        kv_in = w.kv_lora_rank * (h / w.kv_lora_rank if m.lora_rescale else 1)
+        p = {
+            "wq_a": dense(k[0], (h, w.q_lora_rank), h),
+            "q_norm": jnp.ones((w.q_lora_rank,), pd),
+            "wq_b": dense(k[1], (w.q_lora_rank, nh * w.qk_head_dim), q_in),
+            "wkv_a": dense(k[2], (h, w.row_dim), h),
+            "kv_norm": jnp.ones((w.kv_lora_rank,), pd),
+            "wk_b": dense(k[3], (nh, w.qk_nope_head_dim, w.kv_lora_rank),
+                          kv_in),
+            "wv_b": dense(k[4], (nh, w.kv_lora_rank, w.v_head_dim), kv_in),
+            "wo": dense(k[5], (nh * w.v_head_dim, h), nh * w.v_head_dim,
+                        out=True),
+            "wg": dense(k[6], (h, nh), h),
+        }
+        if indexer:
+            p["idx_wq"] = dense(k[7], (w.q_lora_rank,
+                                       m.index_heads * m.index_head_dim),
+                                q_in)
+            p["idx_wk"] = dense(k[8], (h, m.index_head_dim), h)
+            p["idx_k_norm"] = {"scale": jnp.ones((m.index_head_dim,), pd),
+                               "bias": jnp.zeros((m.index_head_dim,), pd)}
+            p["idx_ww"] = dense(k[9], (h, m.index_heads), h)
+        return p
+
+    def swiglu(k, lead, width):
+        k = jax.random.split(k, 3)
+        return {"wg": dense(k[0], lead + (h, width), h),
+                "wi": dense(k[1], lead + (h, width), h),
+                "wo": dense(k[2], lead + (width, h), width, out=True)}
+
+    # a normed hidden state x has x . ones = h * c / sqrt(c^2 + r^2), r
+    # the rms of the stream's token-specific part: each router element's
+    # common part is the wanted mean logit over that, at the last blocks' r
+    router_common = (ROUTER_LOGIT_MEAN * ROUTER_LOGIT_SD
+                     * math.hypot(EMBED_COMMON, RESIDUAL_RMS)
+                     / (h * EMBED_COMMON))
+
+    def moe(k):
+        k = jax.random.split(k, 3)
+        f = m.moe_intermediate_size
+        return dict(swiglu(k[0], (m.experts_held[1],), f),
+                    router=(jax.random.normal(k[1], (h, m.n_routed_experts))
+                            * (ROUTER_LOGIT_SD / math.sqrt(h))
+                            - router_common).astype(pd),
+                    bias=jnp.zeros((m.n_routed_experts,), pd),
+                    shared=swiglu(k[2], (), f * m.n_shared_experts))
+
+    keys = jax.random.split(key, nl + 2)
+    groups: Dict[str, list] = {"full": [], "window": [], "mlp": [], "moe": []}
+    for i, (is_full, has_experts) in enumerate(kinds):
+        ka, kf = jax.random.split(keys[i])
+        groups["full" if is_full else "window"].append(
+            attn(ka, m.full if is_full else m.window, is_full))
+        groups["moe" if has_experts else "mlp"].append(
+            moe(kf) if has_experts else swiglu(kf, (), cfg.intermediate_size))
+    layers = {name: jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *ps)
+              for name, ps in groups.items() if ps}
+    layers["ln1"] = {"scale": jnp.ones((nl, h), pd)}
+    layers["ln2"] = {"scale": jnp.ones((nl, h), pd)}
+    return {
+        "embed": {"tokens": (
+            jax.random.normal(keys[nl], (cfg.vocab_size, h)) / math.sqrt(h)
+            + EMBED_COMMON).astype(pd)},
+        "layers": layers,
+        "final_norm": {"scale": jnp.ones((h,), pd)},
+        "lm_head": dense(keys[nl + 1], (h, cfg.vocab_size), h),
+    }
+
+
 def init_params(cfg: TransformerConfig, key) -> Params:
     """Full model params with per-layer params stacked on axis 0."""
     # nl+5 keys: rows are counter-derived, so rows nl..nl+2 keep the same
     # values the old nl+3 split produced (init stays bit-stable for
     # existing archs); the encoder-only params use the two new rows.
+    if cfg.mla is not None:
+        return init_latent_params(cfg, key)
     nl = cfg.num_layers
     keys = jax.random.split(key, nl + 5)
     scale = 1.0 / math.sqrt(cfg.hidden_size)

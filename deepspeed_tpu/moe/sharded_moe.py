@@ -415,3 +415,93 @@ def moe_forward_ep(x: jnp.ndarray, p: Dict[str, jnp.ndarray], cfg,
         out = out + _shared_expert_out(x.reshape(b * s, h), p,
                                        dt).reshape(x.shape)
     return out, l_aux
+
+
+# rows of a tile of (row, held expert) pairs in moe_forward_held
+HELD_TILE = 128
+
+
+def route_sigmoid(x, router, bias, top_k: int):
+    """Sigmoid routing without capacity (DeepSeek-V3 ``noaux_tc`` without
+    groups): scores ``s = sigmoid(x W_r)`` in float32; the ``top_k``
+    experts with the largest ``s + bias`` are CHOSEN, and weighted by
+    ``s`` alone, normalised over the chosen.  x: [T, H].  Returns
+    ``(chosen [T, k] int32, weights [T, k] float32)``: every token keeps
+    all its ``top_k`` experts, whatever the batch."""
+    s = jax.nn.sigmoid(jnp.dot(x, router.astype(x.dtype),
+                               preferred_element_type=jnp.float32))
+    _, chosen = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    return chosen.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+
+def moe_forward_held(x, p, layer, *, top_k: int, first: int):
+    """The part of a sigmoid-routed expert layer that THIS program's
+    experts give.  Every leaf of ``p`` holds the expert layers stacked on
+    axis 0 and ``layer`` (a traced scalar will do) says which: ``p["wg"]``
+    / ``["wi"]`` ``[L, held, H, F]`` and ``p["wo"]`` ``[L, held, F, H]``
+    are experts ``first .. first + held`` of the ``p["router"]``'s
+    ``[L, H, E]``; routing is over all ``E`` (:func:`route_sigmoid`), and
+    ``y[t] = sum over t's chosen experts that are held of w *
+    SwiGLU_e(x[t])``: what the other ranks of an expert-parallel layer
+    would add is theirs to compute, and a shared expert is the caller's.
+    x: [T, H] -> [T, H].  The tile loop reads ``[layer, expert]`` out of
+    the stack itself: a layer's experts sliced out first are a
+    loop-invariant value the compiler copies whole, 0.5 GB a matrix at
+    published widths.
+
+    No capacity and no dropped token.  The (row, held expert) pairs are
+    sorted by expert and laid out in tiles of ``HELD_TILE`` rows, an
+    expert's pairs starting on a tile's edge; a loop over the tiles IN USE
+    (a traced count: at most ``T * top_k / tile + held``, which is where
+    every row chose held experts only) multiplies a tile's rows by its
+    expert's three matrices and adds the weighted result to the rows it
+    came from.  An expert no row chose is never read: a decode step reads
+    the experts its few rows chose, a prefill chunk each expert once a
+    tile."""
+    t, h = x.shape
+    held = p["wg"].shape[1]
+    dt, f32 = x.dtype, jnp.float32
+    tm = min(HELD_TILE, max(8, t))
+    chosen, w = route_sigmoid(x, p["router"][layer], p["bias"][layer], top_k)
+    local = (chosen - first).reshape(-1)                       # [T * k]
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    n = t * top_k
+    order = jnp.argsort(local)
+    e_s = local[order]
+    r_s = (order // top_k).astype(jnp.int32)
+    w_s = w.reshape(-1)[order]
+
+    counts = jnp.sum(jax.nn.one_hot(local, held + 1, dtype=jnp.int32), axis=0)
+    tiles_e = (counts[:held] + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles_e)
+    first_pair = jnp.cumsum(counts) - counts                   # [held + 1]
+    n_tiles = n // tm + held
+    rank = jnp.arange(n, dtype=jnp.int32) - first_pair[e_s]
+    dest = jnp.where(
+        e_s < held,
+        (tile_end - tiles_e)[jnp.minimum(e_s, held - 1)] * tm + rank,
+        n_tiles * tm)                                          # the dump
+    # row T is a row of zeros that takes every unused place of a tile
+    src = jnp.full((n_tiles * tm + 1,), t, jnp.int32).at[dest].set(r_s)
+    gate = jnp.zeros((n_tiles * tm + 1,), f32).at[dest].set(w_s)
+    tile_expert = jnp.minimum(
+        jnp.sum(jnp.arange(n_tiles, dtype=jnp.int32)[:, None]
+                >= tile_end[None, :], axis=1), held - 1)
+    x_pad = jnp.concatenate([x, jnp.zeros((1, h), dt)])
+
+    def one_tile(i, y):
+        e = tile_expert[i]
+        rows = lax.dynamic_slice(src, (i * tm,), (tm,))
+        g = lax.dynamic_slice(gate, (i * tm,), (tm,))
+        xt = x_pad[rows]
+        act = jax.nn.silu(xt @ p["wg"][layer, e].astype(dt)) \
+            * (xt @ p["wi"][layer, e].astype(dt))
+        out = jnp.dot(act * g[:, None].astype(dt),
+                      p["wo"][layer, e].astype(dt),
+                      preferred_element_type=f32)
+        return y.at[rows].add(out)
+
+    y = lax.fori_loop(0, tile_end[-1], one_tile,
+                      jnp.zeros((t + 1, h), f32))
+    return y[:t].astype(dt)

@@ -237,14 +237,13 @@ class InferenceServer:
         # admission and allocator can never disagree)
         self._total_blocks = engine.cfg.num_blocks - 1
 
-    @staticmethod
-    def _refuse_recurrent(why: str) -> None:
+    def _refuse_recurrent(self, why: str) -> None:
         from deepspeed_tpu.inference.v2.engine_v2 import \
             RecurrentStateUnsupported
 
         raise RecurrentStateUnsupported(
-            "this engine's model has a Mamba-2 SSM mixer with recurrent "
-            f"state per sequence and no state snapshots — {why}")
+            f"{self.engine.state_kind}; there are no state snapshots — "
+            f"{why}")
 
     # -- lifecycle -------------------------------------------------------
     def start(self) -> "InferenceServer":

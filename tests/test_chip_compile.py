@@ -261,6 +261,77 @@ def test_ragged_step_carries_the_pools_beside_a_mixer(chip):
     assert "output_to_operand_aliasing" in call
 
 
+def test_latent_index_scores_at_dots3_widths(chip):
+    """The indexer's score kernel: 64 heads of 128 over pages of 128
+    rows, a full 1024-row step at a 32k context bucket (an output block
+    of 32 x 32768 float32 a program)."""
+    from deepspeed_tpu.ops.pallas import latent_index
+
+    fn = functools.partial(latent_index.index_scores, block_size=128)
+    text = _compile(fn, chip((1024, 64, 128), BF16), chip((1024, 64), F32),
+                    chip((2, 2048 * 128, 128), BF16), chip((), I32),
+                    chip((33, 256), I32), chip((1024,), I32),
+                    chip((1024,), I32), chip((1024,), I32))
+    assert "latent_index_scores" in text
+
+
+def test_latent_step_keeps_its_pools_and_rings_in_place(chip):
+    """The serving step of ``dots3-note-ep8-l5`` (five layers at the
+    published widths, a full 1024-row step at an 8k context bucket): the
+    pages of latent rows ``bf16[2,P,640]`` and of index keys
+    ``bf16[2,P,128]`` and the window layers' rings ``bf16[3,33,1664,1152]``
+    are donated, come back in place, and are never copied whole or by
+    layer (declared 576 and 1088 wide, the rows' own widths, the TPU's
+    compiler turns the pools rows-minor for the gather and copies them
+    there and back in every layer: PERF.md, PR 34); no layer's stack of
+    expert matrices is sliced out as a value of its own either."""
+    import re
+
+    from deepspeed_tpu.inference.v2 import latent
+    from deepspeed_tpu.inference.v2 import model as v2_model
+    from deepspeed_tpu.inference.v2.ragged import PackedIndex
+    from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.models import transformer as tf_model
+
+    cfg = get_model_config(
+        "dots3-note-prev-ep8", num_layers=5, param_dtype=BF16, dtype=BF16,
+        v2_modules=(("indexer", "indexer_pallas"),))
+    rows, t, nb, bs = 2048 * 128, 1024, 64, 128
+    params = _abstract(chip, jax.eval_shape(
+        lambda k: tf_model.init_params(cfg, k), jax.random.PRNGKey(0)))
+    ck, cv, state = _abstract(chip, jax.eval_shape(
+        lambda: latent.new_cache(cfg, rows, 32, t)))
+    assert ck.shape == (2, rows, 640) and cv.shape == (2, rows, 128)
+    assert state["win"].shape == (3, 33, 1664, 1152)
+    index = PackedIndex(chip((PackedIndex.size(t, 33, nb),), I32), t, 33, nb)
+    fn = functools.partial(v2_model.ragged_step_sampled, cfg=cfg,
+                           block_size=bs, greedy=True)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn, donate_argnums=(1, 2),
+                           donate_argnames=("state",)).lower(
+            params, ck, cv, index, chip((2,), jnp.uint32), chip((), F32),
+            state=state).compile()
+    text = compiled.as_text()
+    assert len(_aliased_outputs(text)) == 3
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * 2 ** 30, mem
+    assert "latent_index_scores" in text
+    held = ["bf16[%s]" % ",".join(map(str, a.shape))
+            for a in (ck, cv, state["win"])]
+    experts = ["bf16[32,5120,1536]", "bf16[32,1536,5120]"]
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+?)[{ ]\S* ?([\w-]+)\(", line)
+        if not m:
+            continue
+        op = m.group(1) if m.group(3) == "fusion" else m.group(3)
+        if m.group(2) in held and re.search(r"copy|transpose", op):
+            moved.append(line.strip()[:160])
+        if m.group(2) in experts and re.search(r"copy|slice", op):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+
+
 def test_paged_qblock_group_of_five(chip):
     """Falcon-H1-34B's attention heads: 20 query heads on 4 key/value
     heads of 128, a group that is no power of two (160 query rows a KV

@@ -14,7 +14,8 @@ from deepspeed_tpu.models import get_model_config
 
 _KERNEL_MODULES = ("deepspeed_tpu.ops.pallas.flash_mha",
                    "deepspeed_tpu.ops.pallas.paged_attention",
-                   "deepspeed_tpu.ops.pallas.ssd_ragged")
+                   "deepspeed_tpu.ops.pallas.ssd_ragged",
+                   "deepspeed_tpu.ops.pallas.latent_index")
 _DISPATCH_MODULES = ("deepspeed_tpu.ops.flash_attention",
                      "deepspeed_tpu.inference.v2.model")
 
@@ -52,6 +53,12 @@ def test_ssd_phase_tiny(tpu_branches):
     out = chip_smoke.ssd_phase(heads=4, head_dim=32, state=16, groups=2,
                                slots=6, chunk_rows=21, chunk=16)
     assert out["silent"] < 1e-6 and out["y"] < 0.02
+
+
+def test_index_phase_tiny(tpu_branches):
+    out = chip_smoke.index_phase(heads=4, dim=16, block_size=8, blocks=8,
+                                 chunk_rows=21)
+    assert out["scores"] < 1e-5 and out["overlap"] == 1.0
 
 
 def test_train_phase_tiny(tpu_branches):

@@ -219,6 +219,23 @@ def test_build_ragged_batch_checks_budget_first():
     assert seq.num_cached == 0  # state untouched
 
 
+@pytest.mark.parametrize("floor,tokens,want", [
+    (1, 5, 2), (1, 20, 8), (4, 5, 4), (4, 20, 8), (4, 60, 16), (16, 5, 16)])
+def test_context_bucket_is_the_floor_times_a_power_of_two(floor, tokens, want):
+    """``state_manager.min_context_blocks``: the narrowest block table a
+    step is cut to; 1 (the default) leaves the buckets as they were."""
+    from deepspeed_tpu.inference.v2.ragged import build_ragged_batch
+
+    mgr = DSStateManager(max_seqs=2, num_blocks=32, block_size=4,
+                         max_blocks_per_seq=16, min_blocks_bucket=floor)
+    seq = mgr.open(0, list(range(tokens)))
+    rb = build_ragged_batch([(seq, tokens)], mgr, token_budget=64)
+    assert rb.index.blocks == want
+    with pytest.raises(ValueError, match="min_context_blocks"):
+        DSStateManager(max_seqs=2, num_blocks=32, block_size=4,
+                       max_blocks_per_seq=16, min_blocks_bucket=32)
+
+
 def test_soak_staggered_eos_and_sampling_allocator_clean():
     """Soak: three generate() waves with eos cut-offs, varying lengths and
     nucleus sampling — the allocator must return to fully-free after every

@@ -299,6 +299,13 @@ def check_schema() -> List[str]:
     return errors
 
 
+# arguments the ``v2.schedule`` span carries for a latent-attention model
+# (inference/v2/latent.py latent_step_counts): the benchmark's readers
+# read them by name
+EXPECTED_LATENT_SCHEDULE_ARGS = ["expert_rows", "index_pairs", "latent_rows",
+                                 "selected_keys", "window_keys"]
+
+
 def check_span_names() -> List[str]:
     """Tracing vocabulary: frozen lists match the modules, every name is
     in the docs span table."""
@@ -332,6 +339,18 @@ def check_span_names() -> List[str]:
     for reason in FLIGHT_REASONS:
         if f"`{reason}`" not in docs:
             errors.append(f"flight reason {reason!r} not documented")
+    from deepspeed_tpu.inference.v2.latent import latent_step_counts
+    from deepspeed_tpu.models import get_model_config
+
+    counted = sorted(latent_step_counts(
+        [(0, 1)], get_model_config("dots3-note-tiny")))
+    if counted != EXPECTED_LATENT_SCHEDULE_ARGS:
+        errors.append("latent.latent_step_counts drifted from the frozen "
+                      f"v2.schedule arguments: {counted}")
+    for name in EXPECTED_LATENT_SCHEDULE_ARGS:
+        if f"`{name}`" not in docs:
+            errors.append(f"v2.schedule argument {name!r} not documented "
+                          f"in {os.path.basename(DOCS)}")
     return errors
 
 
