@@ -355,17 +355,28 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
     h_attn = h * mixer.attention_in_multiplier if mixer else h
 
     def proj(w, b_):
-        y = h_attn @ w.astype(dt)
-        return y + b_.astype(dt) if b_ is not None else y
+        # a value of its own, [T, heads*d] rows-major: with the heads'
+        # reshape folded into the product the TPU's compiler wants the
+        # weight [out][in], and slices the layer's matrix out of the stack
+        # and transposes the copy before it multiplies; behind the barrier
+        # the product streams the stack from HBM as wo's and the MLP's do,
+        # and the relayout falls on the step's rows.  float32 through bias
+        # and rope and rounded once, as the folded product was; weight and
+        # bias enter as dt holds them.
+        y = lax.optimization_barrier(jnp.matmul(
+            h_attn, w.astype(dt), preferred_element_type=jnp.float32))
+        return y + b_.astype(dt).astype(y.dtype) if b_ is not None else y
 
     q = proj(lp["attn"]["wq"], lp["attn"].get("bq")).reshape(t, nh, d)
     k = proj(lp["attn"]["wk"], lp["attn"].get("bk")).reshape(t, nkv, d)
     v = proj(lp["attn"]["wv"], lp["attn"].get("bv")).reshape(t, nkv, d)
     if mixer:
-        k = k * mixer.key_multiplier
+        # the multiplier as the model's dtype holds it (it scaled a dt k)
+        k = k * jnp.asarray(mixer.key_multiplier, dt).astype(k.dtype)
     if cfg.use_rope:
         q = _rope_tok(q, token_pos, cfg)
         k = _rope_tok(k, token_pos, cfg)
+    q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
 
     # Write this step's KV to its pages (padding tokens target page 0 =
     # garbage, so no mask needed; ref: linear_blocked_kv_copy). A layer's

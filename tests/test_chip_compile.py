@@ -217,41 +217,56 @@ def _assert_pools_in_place(compiled, pool_dims, n_aliased, temp_limit):
     assert layouts.count(whole) == 2, call[:400]
 
 
-@pytest.mark.parametrize("t", [256, 16])
-def test_ragged_step_carries_the_pools_mistral_7b_l16(chip, t):
-    """The serving step of ``mistral-7b-l16`` (16 layers at the published
-    widths, two pools ``bf16[16,8,32768,128]`` of 1 GiB, 65 x 256 block
-    tables; a full 256-row step and the smallest bucket).  As the scan's
-    xs/ys the pools cost 2.5 GiB of temporaries and three passes over
-    both pools a step (PERF.md, PR 30)."""
+# the serving cells' models: preset, modules, pool [L, nkv, P, d], pages a
+# sequence.  ``mistral-7b-l16``: 16 layers at the published widths, two
+# pools of 1 GiB, 65 x 256 block tables.  ``falcon-h1-34b-l6``: a Mamba-2
+# mixer in every block at Falcon-H1-34B's widths, six layers, the pools
+# beside the recurrent slots.
+_SERVED = {
+    "mistral-7b-l16": ("mistral-7b", (("attention", "paged_pallas"),),
+                       (16, 8, 2048 * _BS, 128), 256),
+    "falcon-h1-34b-l6": ("falcon-h1-34b", (("attention", "paged_pallas"),
+                                           ("ssm", "ssd_pallas")),
+                         (6, 4, 4352 * _BS, 128), 64),
+}
+
+
+@pytest.fixture(scope="module")
+def serving_step(chip):
+    """``serving_step(model, rows)`` → (cfg, pool dims, compiled): the
+    serving step of a model of ``_SERVED``, compiled once for every test
+    that reads it."""
+    from deepspeed_tpu.inference.v2 import model as v2_model
     from deepspeed_tpu.models import get_model_config
 
-    cfg = get_model_config(
-        "mistral-7b", num_layers=16, param_dtype=BF16, dtype=BF16,
-        v2_modules=(("attention", "paged_pallas"),))
-    dims = (16, 8, 2048 * _BS, 128)
-    compiled = _compile_step(chip, cfg, chip(dims, BF16), 256, t)
+    @functools.lru_cache(maxsize=None)
+    def step(model, t):
+        preset, modules, dims, nb = _SERVED[model]
+        cfg = get_model_config(preset, num_layers=dims[0], param_dtype=BF16,
+                               dtype=BF16, v2_modules=modules)
+        state = None if cfg.ssm is None else _abstract(chip, jax.eval_shape(
+            lambda: v2_model.new_ssm_state(cfg, 64)))
+        return cfg, dims, _compile_step(chip, cfg, chip(dims, BF16), nb, t,
+                                        state=state)
+
+    return step
+
+
+@pytest.mark.parametrize("t", [256, 16])
+def test_ragged_step_carries_the_pools_mistral_7b_l16(serving_step, t):
+    """A full 256-row step and the smallest bucket.  As the scan's xs/ys
+    the pools cost 2.5 GiB of temporaries and three passes over both
+    pools a step (PERF.md, PR 30)."""
+    _, dims, compiled = serving_step("mistral-7b-l16", t)
     # activations only: far below one layer's pages (64 MiB)
     _assert_pools_in_place(compiled, dims, n_aliased=2,
                            temp_limit=16 * 2 ** 20)
 
 
-def test_ragged_step_carries_the_pools_beside_a_mixer(chip):
-    """The same with a Mamba-2 mixer in every block, at Falcon-H1-34B's
-    widths and six layers (``falcon-h1-34b-l6``): the pools
-    ``bf16[6,4,69632,128]`` ride the carry beside the recurrent slots,
-    and all four donated arrays come back in place."""
-    from deepspeed_tpu.inference.v2 import model as v2_model
-    from deepspeed_tpu.models import get_model_config
-
-    cfg = get_model_config(
-        "falcon-h1-34b", num_layers=6, param_dtype=BF16, dtype=BF16,
-        v2_modules=(("attention", "paged_pallas"), ("ssm", "ssd_pallas")))
-    dims = (6, 4, 4352 * _BS, 128)
-    state = _abstract(chip, jax.eval_shape(
-        lambda: v2_model.new_ssm_state(cfg, 64)))
-    compiled = _compile_step(chip, cfg, chip(dims, BF16), 64, 256,
-                             state=state)
+def test_ragged_step_carries_the_pools_beside_a_mixer(serving_step):
+    """The pools ride the carry beside the recurrent slots, and all four
+    donated arrays come back in place."""
+    _, dims, compiled = serving_step("falcon-h1-34b-l6", 256)
     # a layer's pages are 68 MiB here; the mixer's activations are most
     # of what is left
     _assert_pools_in_place(compiled, dims, n_aliased=4,
@@ -259,6 +274,57 @@ def test_ragged_step_carries_the_pools_beside_a_mixer(chip):
     call = next(ln for ln in compiled.as_text().splitlines()
                 if "tpu_custom_call" in ln and "%ssd_ragged" in ln)
     assert "output_to_operand_aliasing" in call
+
+
+def _computations(text):
+    """{name: (header, [instruction lines])} of a compiled module."""
+    import re
+
+    out, lines = {}, None
+    for ln in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", ln)
+        if m:
+            lines = []
+            out[m.group(1)] = (ln, lines)
+        elif lines is not None and ln.startswith("  "):
+            lines.append(ln)
+    return out
+
+
+@pytest.mark.parametrize("model,t", [("mistral-7b-l16", 256),
+                                     ("mistral-7b-l16", 16),
+                                     ("falcon-h1-34b-l6", 256)])
+def test_ragged_step_streams_the_qkv_weights(serving_step, model, t):
+    """The layer loop's q, k and v products read the stacked weights from
+    HBM inside the fusion that multiplies, as ``wo``'s and the MLP's do.
+    With the heads' reshape folded into the product the compiler wants
+    the weight ``[out][in]``: it slices the layer's matrix out of the
+    stack, transposes the copy and only then multiplies (PERF.md, PR 36:
+    124 us a layer at mistral-7b's widths against 78 us streamed)."""
+    import collections
+    import re
+
+    cfg, _, compiled = serving_step(model, t)
+    comps = _computations(compiled.as_text())
+    body = next(lines for _, lines in comps.values() if any(
+        "custom-call(" in ln and "paged_qblock" in ln for ln in lines))
+    n, h = cfg.num_layers, cfg.hidden_size
+    q_out, kv_out = (cfg.num_heads * cfg.dim_per_head,
+                     cfg.kv_heads * cfg.dim_per_head)
+    one_layer = {f"bf16[1,{h},{q_out}]", f"bf16[1,{h},{kv_out}]"}
+    moved = [ln.strip()[:160] for ln in body if (m := re.match(
+        r"\s*(?:ROOT )?%\S+ = (\S+?)[{ ]", ln)) and m.group(1) in one_layer]
+    assert not moved, moved
+    streamed = collections.Counter()
+    for ln in body:
+        m = re.search(r" fusion\(.*calls=%([^\s,]+)", ln)
+        if m and any(" convolution(" in x for x in comps[m.group(1)][1]):
+            streamed.update(re.findall(r": (bf16\[[\d,]+\])",
+                                       comps[m.group(1)][0]))
+    want = collections.Counter(
+        [f"bf16[{n},{h},{q_out}]", f"bf16[{n},{h},{kv_out}]",
+         f"bf16[{n},{h},{kv_out}]", f"bf16[{n},{q_out},{h}]"])
+    assert not want - streamed, (want, streamed)
 
 
 def test_latent_index_scores_at_dots3_widths(chip):

@@ -335,6 +335,7 @@ _CARRY_CASES = {
     "llama_bf16": ("llama-tiny", {"dtype": "bfloat16"}, False, None),
     "llama_int8_cache": ("llama-tiny", {}, True, None),
     "mistral_window": ("mistral-tiny", {}, False, None),
+    "qwen2_qkv_bias": ("qwen2-tiny", {}, False, None),
     "gptneo_alt_window": ("gptneo-tiny", {}, False, None),
     "mixtral_moe": ("mixtral-tiny", {}, False, None),
     "bloom_alibi": ("bloom-tiny", {}, False, None),
@@ -350,7 +351,8 @@ _CARRY_CASES = {
 
 
 def _carry_case(name):
-    """A model, its weights, two pools filled with noise and one step: a
+    """A model (a name of ``_CARRY_CASES`` or such a tuple), its weights,
+    two pools filled with noise and one step: a
     20-row chunk of sequence 0 at positions 10..29 (it straddles two
     pages of 16), a decode row of sequence 1 at position 33, three
     padding rows that write to the garbage page 0."""
@@ -360,7 +362,8 @@ def _carry_case(name):
     from deepspeed_tpu.inference.v2 import model as m2
     from deepspeed_tpu.models import transformer as tf_model
 
-    preset, over, int8, attention = _CARRY_CASES[name]
+    preset, over, int8, attention = (
+        _CARRY_CASES[name] if isinstance(name, str) else name)
     over = dict(over)
     if "dtype" in over:
         over["dtype"] = getattr(jnp, over["dtype"])
@@ -601,3 +604,221 @@ def test_decode_loop_same_bits_as_sliced_and_restacked(name,
         sampled.append(tokens)
     want = (jnp.stack(sampled), ctx, ck, cv) + ((st,) if cfg.ssm else ())
     _assert_same_bits(got, want)
+
+
+# -- q, k, v: products of their own behind a barrier (PERF.md, PR 36) --------
+def _seed_attn_biases(params, seed=7):
+    """The projections' biases initialise to zeros: give them values."""
+    import jax
+
+    attn = params["layers"]["attn"]
+    for i, name in enumerate(n for n in ("bq", "bk", "bv", "bo")
+                             if n in attn):
+        attn[name] = 0.5 * jax.random.normal(
+            jax.random.PRNGKey(seed + i), attn[name].shape, attn[name].dtype)
+    return params
+
+
+@pytest.fixture
+def tapped_qkv(monkeypatch):
+    """What ``_ragged_layer`` hands on while a program is traced: q to
+    ``_paged_attention``, k then v to ``_kv_append``.  ``tapped_qkv(fn)``
+    calls ``fn(name, array)`` on each, inside the traced program."""
+    import itertools
+
+    from deepspeed_tpu.inference.v2 import model as m2
+
+    attend, append = m2._paged_attention, m2._kv_append
+    appended = itertools.cycle("kv")
+
+    def install(fn):
+        def tap_attend(q, *a, **kw):
+            fn("q", q)
+            return attend(q, *a, **kw)
+
+        def tap_append(pool, x, *a, **kw):
+            fn(next(appended), x)
+            return append(pool, x, *a, **kw)
+
+        monkeypatch.setattr(m2, "_paged_attention", tap_attend)
+        monkeypatch.setattr(m2, "_kv_append", tap_append)
+
+    return install
+
+
+# preset, programs: a mixer's recurrent slots are refused by verify, and its
+# decode loop is held above (test_decode_loop_same_bits_...)
+_QKV_MODELS = [("qwen2-tiny", "step"), ("qwen2-tiny", "verify"),
+               ("qwen2-tiny", "decode_loop"), ("falcon-h1-tiny", "step")]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("preset,program", _QKV_MODELS)
+def test_qkv_behind_the_barrier_are_the_plain_products(preset, program, dtype,
+                                                       tapped_qkv):
+    """One block, biases seeded: the q, k, v that each ragged program's
+    block attends with and appends are ``rope(norm(x) @ w + b)``, computed
+    here outside any program (GQA + rope + bias; Falcon-H1's multipliers
+    beside its mixer).  In bfloat16 the product's float32 accumulator goes
+    through bias, key multiplier and rope and is rounded once, to the bit:
+    the TPU's compiler had folded the roundings between away, and a
+    barrier after a rounding would put them back; the key multiplier
+    itself is the model's dtype's, as it was when it scaled a bfloat16 k
+    (PERF.md, PR 36)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2 import model as m2
+    from deepspeed_tpu.models.transformer import _norm
+
+    cfg, params, ck, cv, step, state, bs = _carry_case(
+        (preset, {"num_layers": 1, "dtype": dtype}, False, None))
+    params = _seed_attn_biases(params)
+    seen = {}
+    tapped_qkv(lambda name, x: jax.debug.callback(
+        lambda a: seen.__setitem__(name, np.asarray(a)), x))
+    if program == "decode_loop":
+        ids, pos = step[0][:3], jnp.asarray([30, 15, 0], jnp.int32)
+        out = jax.jit(functools.partial(
+            m2.ragged_decode_loop, cfg=cfg, block_size=bs, n_steps=1,
+            greedy=True))(params, ck, cv, ids, pos,
+                          jnp.asarray([True, True, False]), step[4][:3],
+                          jax.random.PRNGKey(0), jnp.float32(1.0))
+    else:
+        ids, pos = step[0], step[2]
+        fn, extra = ((m2.ragged_forward, (state,)) if program == "step"
+                     else (m2.ragged_forward_verify, ()))
+        out = jax.jit(functools.partial(fn, cfg=cfg, block_size=bs))(
+            params, ck, cv, *step, *extra)
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+    h = _norm(m2._embed_rows(params, ids, pos, cfg), lp["ln1"], cfg)
+    if cfg.ssm:
+        h = h * cfg.ssm.attention_in_multiplier
+    t, d = ids.shape[0], cfg.dim_per_head
+    plain = {}
+    for n in "qkv":
+        y = jnp.matmul(h, lp["attn"]["w" + n].astype(cfg.dtype),
+                       preferred_element_type=jnp.float32)
+        if "b" + n in lp["attn"]:
+            assert float(jnp.abs(lp["attn"]["b" + n]).max()) > 0.1
+            y = y + lp["attn"]["b" + n].astype(cfg.dtype)
+        plain[n] = y.reshape(t, -1, d)
+    if cfg.ssm:
+        plain["k"] = plain["k"] * jnp.asarray(
+            cfg.ssm.key_multiplier, cfg.dtype).astype(jnp.float32)
+    for n in "qk":
+        plain[n] = m2._rope_tok(plain[n], pos, cfg)
+    assert sorted(seen) == ["k", "q", "v"]
+    for n in "qkv":
+        want = np.asarray(plain[n].astype(cfg.dtype).astype(jnp.float32))
+        got = seen[n].astype(np.float32)
+        if dtype == "bfloat16":
+            np.testing.assert_array_equal(got, want)
+        else:
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+_PREFILL_MODELS = {
+    "gqa_rope_bias": ("qwen2-tiny", {}),
+    "bias_no_rope": ("opt-tiny", {}),
+    "head64_paged_xla": ("gpt2-tiny", {"num_heads": 2}),
+}
+
+
+def _prefill_logits(eng, uid, prompt):
+    """The next-token logits of ``prompt``, prefilled in the engine's
+    chunks; the sequence is flushed."""
+    out = eng.put([uid], [prompt])
+    while uid not in out:
+        out = eng.put([], [])
+    eng.flush(uid)
+    return np.asarray(out[uid])
+
+
+def _tiny_engine(model, params=None, **over):
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+
+    return InferenceEngineV2(model, {
+        "dtype": "float32", "max_context": 64,
+        "state_manager": {"max_tracked_sequences": 2,
+                          "max_ragged_batch_size": 8},
+        "memory_config": {"num_blocks": 32, "block_size": 4}, **over},
+        model_params=params, seed=3)
+
+
+@pytest.mark.parametrize("name", list(_PREFILL_MODELS))
+def test_chunked_prefill_logits_match_forward(name):
+    """A 21-token prompt through three 8-row steps against the training
+    model's ``forward`` in float32, the projections' biases seeded."""
+    import jax
+
+    from deepspeed_tpu.models import transformer as tf_model
+
+    preset, over = _PREFILL_MODELS[name]
+    model = get_model_config(preset, **over)
+    if name == "head64_paged_xla":
+        assert model.dim_per_head == 64
+    eng = _tiny_engine(model)
+    assert eng.attention_impl == "paged_xla"
+    eng.params = _seed_attn_biases(eng.params)
+    prompt = [int(x) for x in np.random.default_rng(2).integers(
+        0, model.vocab_size, size=21)]
+    got = _prefill_logits(eng, 1, prompt)
+    want = np.asarray(jax.jit(lambda p, ids: tf_model.forward(
+        p, ids, eng.model_config))(eng.params, np.asarray([prompt])))[0, -1]
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_weight_swap_reaches_the_next_step():
+    """The step reads ``engine.params`` as they are when it is called:
+    there is no prepared form of the projections to go stale."""
+    import jax
+
+    from deepspeed_tpu.models import transformer as tf_model
+
+    model = get_model_config("qwen2-tiny")
+    eng = _tiny_engine(model)
+    prompt = list(range(3, 16))
+    before = _prefill_logits(eng, 1, prompt)
+    swapped = _seed_attn_biases(jax.jit(
+        lambda k: tf_model.init_params(eng.model_config, k))(
+        jax.random.PRNGKey(11)))
+    eng.params = jax.device_put(swapped,
+                                eng.rules.tree_shardings(swapped))
+    after = _prefill_logits(eng, 2, prompt)
+    want = np.asarray(jax.jit(lambda p, ids: tf_model.forward(
+        p, ids, eng.model_config))(swapped, np.asarray([prompt])))[0, -1]
+    np.testing.assert_allclose(after, want, rtol=2e-4, atol=2e-4)
+    assert np.abs(after - before).max() > 0.1
+
+
+def test_qkv_stay_column_parallel_under_tp2(tapped_qkv):
+    """``tp_size=2``: behind the barrier q, k and v are still split over
+    the tensor axis by KV-head group (q's heads 0-1 with KV head 0), and
+    the logits are the unsharded engine's."""
+    import jax
+
+    model = get_model_config("qwen2-tiny")
+    prompt = list(range(3, 16))
+    base = _tiny_engine(model)
+    base.params = _seed_attn_biases(base.params)
+    want = _prefill_logits(base, 1, prompt)
+
+    shards = {}
+    tapped_qkv(lambda name, x: jax.debug.inspect_array_sharding(
+        x, callback=lambda s: shards.__setitem__(
+            name, (x.shape, s.shard_shape(x.shape)))))
+    eng = _tiny_engine(model, params=jax.device_get(base.params),
+                       tensor_parallel={"tp_size": 2})
+    got = _prefill_logits(eng, 1, prompt)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    assert sorted(shards) == ["k", "q", "v"]
+    for name, heads in (("q", model.num_heads), ("k", model.kv_heads),
+                        ("v", model.kv_heads)):
+        (t, nh, d), shard = shards[name]
+        assert nh == heads and shard == (t, heads // 2, d), (name, shards)
