@@ -31,6 +31,7 @@ from deepspeed_tpu.inference.v2.model import (attention_impl_name,
                                               check_sampling_params,
                                               new_ssm_state,
                                               ragged_decode_loop,
+                                              ragged_draft_step,
                                               ragged_step,
                                               ragged_step_sampled,
                                               ragged_verify, ssm_impl_name)
@@ -103,6 +104,10 @@ class RaggedInferenceEngineConfig:
         mdc = max(1, int(d.get("max_decode_chunk", 32)))
         self.max_decode_chunk = 1 << (mdc.bit_length() - 1)
         self.dtype = d.get("dtype", "bfloat16")
+        # self-drafting through the model's multi-token-prediction module
+        # (docs/SERVING.md): a greedy step() verifies each decoding
+        # sequence's draft and makes the next, in the one ragged program
+        self.self_draft = bool(d.get("self_draft", False))
         ep = d.get("expert_parallel", {})
         self.ep_size = int(ep.get("ep_size", 1) if isinstance(ep, dict)
                            else ep)
@@ -195,6 +200,10 @@ def ssm_step_counts(items: Sequence[tuple], slot_bytes: int,
     return {"ssm_runs": len(items), "ssm_rows": sum(n for _, n in items),
             "state_slots_live": live,
             "state_bytes": slot_bytes * (2 * len(items) - fresh)}
+
+
+# _ragged_step's ``sample`` for a self-drafting greedy step
+_DRAFT = {"draft": True}
 
 
 class RecurrentStateUnsupported(NotImplementedError):
@@ -354,7 +363,7 @@ class InferenceEngineV2:
         self.ssm_impl = None
         self._slot_bytes = 0        # float32 recurrent state of one slot
         donate: Dict[str, Any] = {"donate_argnums": (1, 2)}
-        if latent is not None:
+        if latent is not None and rings is not None:
             self.state = jax.block_until_ready(rings)
             self.state_kind = _WINDOW_ROWS
             self._state_alloc = {
@@ -412,6 +421,22 @@ class InferenceEngineV2:
         # of block rows; padding points at the reserved garbage block 0)
         self._kv_write = jax.jit(_named("kv_write", _kv_scatter),
                                  donate_argnums=(0, 1))
+        # self-drafting: the greedy step's program and what it has
+        # verified and accepted so far (the serving metrics' counters)
+        self.self_draft = self.cfg.self_draft
+        self.drafts_verified = self.drafts_accepted = 0
+        if self.self_draft:
+            if not mc.mtp_layers or self.state is not None:
+                raise ValueError(
+                    "self_draft: the model needs a multi-token-prediction "
+                    "module (mla.mtp_layers) and no per-sequence state a "
+                    "refused draft row would have advanced; "
+                    + (self.state_kind or "this model has no module"))
+            self.state_manager.drafting = True
+            self._draft = jax.jit(
+                _named("ragged_draft_step", ragged_draft_step, cfg=mc,
+                       block_size=self.cfg.block_size),
+                donate_argnums=(1, 2))
         self.attention_impl = (
             attention_impl_name(mc, self.cfg.block_size) if latent is None
             else "latent_" + latent.indexer_impl_name(mc))
@@ -458,6 +483,22 @@ class InferenceEngineV2:
             raise RecurrentStateUnsupported(
                 f"{what} needs state snapshots: {self.state_kind}")
 
+    def _refuse_latent_handoff(self) -> None:
+        if self.model_config.mla is not None:
+            raise NotImplementedError(
+                "KV hand-off of a latent-attention model: its pages hold "
+                "one latent row and one index key a token and layer, "
+                "[layers, rows, width], and the hand-off's gather, "
+                "scatter and geometry are written for [layers, heads, "
+                "rows, head_dim] pages")
+
+    def _refuse_external_draft(self, what: str) -> None:
+        if self.self_draft:
+            raise ValueError(
+                f"{what}: this engine drafts for itself (self_draft), and "
+                "rows a caller verifies or takes back would leave its "
+                "module's cache rows behind the trunk's")
+
     def _carried(self, out):
         """Rebind what a step carries (the KV pools, and the recurrent
         state after them where there is one); the rest of ``out``."""
@@ -502,7 +543,7 @@ class InferenceEngineV2:
         parent = self._step_span
         sp = (tr.span("v2.schedule", self.trace_id, parent)
               if tr is not None else None)
-        schedule = self.scheduler.next_schedule()
+        schedule = self.scheduler.next_schedule(sample is _DRAFT)
         if not schedule:
             if sp is not None:
                 sp.end(seqs=0, tokens=0)
@@ -539,9 +580,15 @@ class InferenceEngineV2:
                     latent_step_counts
 
                 counts.update(latent_step_counts(items, self.model_config))
+                if sample is _DRAFT:
+                    counts.update(verify_runs=len(rb.verified),
+                                  draft_rows=len(rb.verified),
+                                  mtp_rows=rb.n_tokens)
             sp.end(**counts)
         if sample is None:
             program, variant, kw = self._step, (), {}
+        elif sample is _DRAFT:
+            program, variant, kw = self._draft, (), {}
         else:
             greedy = sample["temperature"] <= 0
             top_k, top_p = sample.get("top_k", 0), sample.get("top_p")
@@ -552,6 +599,10 @@ class InferenceEngineV2:
             kw = {"key": sample["key"], "greedy": greedy, "top_k": top_k,
                   "top_p": top_p, "temperature": np.float32(
                       max(sample["temperature"], 1e-6))}
+        if self.self_draft and sample is not _DRAFT:
+            # a step without the module leaves a hole in its cache rows
+            for seq, _ in schedule:
+                seq.draft, seq.draftable = None, False
         index, sp, shape = self._ship(rb, program, variant, programs)
         try:
             out = self._carried(program(
@@ -663,9 +714,12 @@ class InferenceEngineV2:
         if num_cached or cached_blocks:
             self._refuse_recurrent("adopting cached prefix pages "
                                    f"(num_cached={num_cached})")
-        self.state_manager.open(uid, [int(x) for x in tokens],
-                                cached_blocks=cached_blocks,
-                                num_cached=num_cached)
+        seq = self.state_manager.open(uid, [int(x) for x in tokens],
+                                      cached_blocks=cached_blocks,
+                                      num_cached=num_cached)
+        # adopted pages may lack the module's rows (their donor's steps
+        # may have run without it)
+        seq.draftable = not num_cached
         self.scheduler.add(uid, priority=priority, front=front)
 
     def step(self, temperature: float = 0.0, key: Optional[Any] = None,
@@ -682,7 +736,56 @@ class InferenceEngineV2:
         ``KVCacheExhausted`` (with scheduler state rolled back, nothing
         run) when the step needs more KV pages than remain — preempt a
         victim and retry.
+
+        **A self-drafting engine** (``self_draft`` in its configuration):
+        a greedy step is :meth:`step_bursts`, which delivers one token a
+        sequence or two.  The value by uid here is the LAST of them, the
+        one the caller's ``extend`` appends as after any step, so the
+        plain calling sequence (``step``, ``extend``, ``flush``) serves
+        and runs the programs a server will; a burst's first token is
+        then in the sequence's ``tokens`` and NOT in what this call
+        returns: a caller that delivers tokens asks ``step_bursts``.  A
+        step that runs WITHOUT the module (``put``, ``return_logits``, a
+        temperature) leaves its sequences' module rows with a hole: they
+        are served on, one row a step, and draft no more
+        (``SequenceDescriptor.draftable``).
         """
+        if self.self_draft and temperature <= 0 and not return_logits:
+            return {uid: burst[-1]
+                    for uid, burst in self.step_bursts().items()}
+        sp = self._begin_step()
+        try:
+            return self._step_impl(temperature, key, top_k, top_p,
+                                   return_logits)
+        finally:
+            self._end_step(sp)
+
+    def step_bursts(self) -> Dict[int, List[int]]:
+        """ONE greedy step of a self-drafting engine (``self_draft``: a
+        latent model with a multi-token-prediction module, every layer
+        full): ``ragged_draft_step``, one program, one transfer and one
+        fetch as any step.  A decoding sequence brings its pending token
+        and its draft, a prompt its chunk, under the one token budget.
+        Returns ``{uid: tokens delivered}``: one, or two where the draft
+        equalled the trunk's argmax; the stream is, token for token,
+        plain greedy decoding's.  As after any step the caller extends
+        the sequence with the LAST token (or flushes it); the engine has
+        appended a burst's first token itself, given a refused draft's
+        position back (host bookkeeping, ``SequenceDescriptor.settle``)
+        and kept the module's next draft."""
+        if not self.self_draft:
+            raise ValueError("step_bursts: the engine's configuration "
+                             "has no self_draft")
+        sp = self._begin_step()
+        try:
+            rb, out = self._ragged_step([], [], sample=_DRAFT)
+            return {} if rb is None else self._settle(rb, out)
+        finally:
+            self._end_step(sp)
+
+    def _begin_step(self):
+        """The ``engine.step`` injection point and the step's span
+        (None unless spans are recorded)."""
         if self.chaos is not None:
             # "engine.step" injection point: specs pinned here (see
             # resilience/chaos.py FaultSpec.point) delay or kill the
@@ -700,13 +803,12 @@ class InferenceEngineV2:
                 self.trace_id = tr.new_trace_id()
             sp = tr.span("v2.ragged_step", self.trace_id)
         self._step_span = sp
-        try:
-            return self._step_impl(temperature, key, top_k, top_p,
-                                   return_logits)
-        finally:
-            if sp is not None:
-                self._step_span = None
-                sp.end()
+        return sp
+
+    def _end_step(self, sp) -> None:
+        if sp is not None:
+            self._step_span = None
+            sp.end()
 
     def _step_impl(self, temperature: float, key: Optional[Any],
                    top_k: int, top_p: float,
@@ -733,6 +835,37 @@ class InferenceEngineV2:
         toks_np = self._fetch(toks)
         return {uid: int(toks_np[slot])
                 for slot, uid in rb.uids_by_slot.items()}
+
+    def _settle(self, rb: RaggedBatch, out) -> Dict[int, List[int]]:
+        """A self-drafting step's ONE fetch (span ``v2.fetch``, with the
+        drafts it verified and accepted) and the host's bookkeeping:
+        every sampled sequence takes in a burst's first token, gives a
+        refused draft's position back and keeps the module's next
+        draft.  ``{uid: tokens delivered}``."""
+        sp = self._step_span
+        fetch = (self.tracer.span("v2.fetch", self.trace_id, sp)
+                 if sp is not None else None)
+        first, second, accepted, draft = np.asarray(out).tolist()
+        mgr = self.state_manager
+        room = mgr.max_blocks_per_seq * mgr.block_size
+        verified = {seq.uid for seq in rb.verified}
+        n_accepted = 0
+        result: Dict[int, List[int]] = {}
+        for slot, uid in rb.uids_by_slot.items():
+            seq = mgr.get(uid)
+            ok = bool(accepted[slot])       # never set but on a verify run
+            n_accepted += ok
+            result[uid] = seq.settle(first[slot], second[slot], ok,
+                                     uid in verified)
+            # a draft sits one position past the pending token's, which
+            # is the burst's last, still the caller's to append
+            seq.draft = (draft[slot] if seq.draftable
+                         and len(seq.tokens) + 1 < room else None)
+        self.drafts_verified += len(verified)
+        self.drafts_accepted += n_accepted
+        if fetch is not None:
+            fetch.end(drafts=len(verified), accepted=n_accepted)
+        return result
 
     def _fetch(self, out) -> np.ndarray:
         """The step's result on the host: the wait for the device and for
@@ -796,6 +929,7 @@ class InferenceEngineV2:
 
         self._refuse_recurrent("exporting a sequence's KV pages for "
                                "hand-off")
+        self._refuse_latent_handoff()
         t0 = _time.perf_counter()
         seq = self.state_manager.get(uid)
         bs = self.cfg.block_size
@@ -827,6 +961,7 @@ class InferenceEngineV2:
         cannot host the tail.  Engine-owning thread only.
         """
         self._refuse_recurrent("importing handed-off KV pages")
+        self._refuse_latent_handoff()
         if tuple(payload["geom"]) != self.kv_geometry():
             raise ValueError(
                 f"handoff payload geometry {payload['geom']} does not "
@@ -895,6 +1030,7 @@ class InferenceEngineV2:
         Raises ``KVCacheExhausted`` with every sequence rolled back.
         """
         self._refuse_recurrent("verify_step (speculative decoding)")
+        self._refuse_external_draft("verify_step")
         mgr = self.state_manager
         # validate the WHOLE batch before touching any state: a bad
         # entry must not leave earlier sequences carrying unverified
@@ -963,6 +1099,7 @@ class InferenceEngineV2:
         positions are legitimately re-run.  Allocated pages stay with
         the sequence (capacity, not content)."""
         self._refuse_recurrent("rewind")
+        self._refuse_external_draft("rewind")
         seq = self.state_manager.get(uid)
         if num_cached > seq.num_cached:
             raise ValueError(

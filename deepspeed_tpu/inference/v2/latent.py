@@ -1,7 +1,9 @@
 """Ragged step of a latent-attention model (``TransformerConfig.mla``; HF
-``dots3_note``): the trunk ``inference/v2/model.py:_ragged_trunk`` hands
-such a model to.  Imported where a latent model is built or traced, never
-on the package's import path.
+``dots3_note``, ``glm_moe_dsa``): the trunk
+``inference/v2/model.py:_ragged_trunk`` hands such a model to, and the
+multi-token-prediction module a self-drafting step runs after it
+(:func:`mtp_rows`).  Imported where a latent model is built or traced,
+never on the package's import path.
 
 What a sequence keeps, and where:
 
@@ -24,7 +26,14 @@ What a sequence keeps, and where:
   see has been overwritten by a later row of the same step.  Nothing is
   cleared when a slot is handed on: a position below 0 is masked.  What
   would need a copy of the rows at an earlier position (prefix adoption,
-  verify and rewind, KV hand-off) is refused by the engine, by name.
+  verify and rewind, KV hand-off) is refused by the engine, by name.  A
+  model whose layers are all full keeps no ring (``state`` is ``None``)
+  and is refused none of it on that ground: a rejected draft row leaves
+  a latent row and an index key at a position the next step overwrites
+  before any row reads it.
+* **A multi-token-prediction module** (``mla.mtp_layers``) is one more
+  full layer: its rows and keys are the LAST layer of ``cache_k`` and
+  ``cache_v``, in the same pages under the same tables.
 
 Attention is the ABSORBED form for prefill chunks and decode rows alike:
 ``q~_h = q_nope,h W_kb,h`` (``kv_lora_rank`` wide), score ``q~_h . c_kv +
@@ -73,17 +82,18 @@ def ring_rows(cfg: TransformerConfig, token_budget: int) -> int:
 def new_cache(cfg: TransformerConfig, pool_rows: int, max_seqs: int,
               token_budget: int, zeros=jnp.zeros, dtype=None):
     """``(cache_k, cache_v, state)`` of a latent model, zeroed: the full
-    layers' latent rows and index keys in pages, the window layers'
-    rings in slots (slot ``max_seqs`` is the padding rows')."""
+    layers' (and a module's) latent rows and index keys in pages, the
+    window layers' rings in slots (slot ``max_seqs`` is the padding
+    rows'); ``state`` is ``None`` where no layer is a window layer."""
     m, dtype = cfg.mla, dtype or cfg.dtype
-    kinds = m.kinds(cfg.num_layers)
-    n_full = sum(1 for full, _ in kinds if full)
-    n_win = len(kinds) - n_full
-    return (zeros((n_full, pool_rows, stored_width(m.full.row_dim)), dtype),
-            zeros((n_full, pool_rows, m.index_head_dim), dtype),
-            {"win": zeros((max(n_win, 1), max_seqs + 1,
+    n_cache = m.cache_layers(cfg.num_layers)
+    n_win = sum(1 for full, _ in m.kinds(cfg.num_layers) if not full)
+    return (zeros((n_cache, pool_rows, stored_width(m.full.row_dim)), dtype),
+            zeros((n_cache, pool_rows, m.index_head_dim), dtype),
+            {"win": zeros((n_win, max_seqs + 1,
                            ring_rows(cfg, token_budget),
-                           stored_width(m.window.row_dim)), dtype)})
+                           stored_width(m.window.row_dim)), dtype)}
+            if n_win else None)
 
 
 def latent_step_counts(items, cfg: TransformerConfig) -> dict:
@@ -95,7 +105,9 @@ def latent_step_counts(items, cfg: TransformerConfig) -> dict:
     ``selected_keys``: rows the full layers' attention then reads, at most
     ``index_topk`` a query; ``window_keys``: rows a window layer reads;
     ``expert_rows``: (row, held expert) products an expert layer expects
-    under even routing, rows x experts per token x held / routed."""
+    under even routing, rows x experts per token x held / routed.  A
+    module's layer is one more full layer with experts: the same counts
+    hold for it."""
     m = cfg.mla
 
     def seen(cached, end, limit):
@@ -121,16 +133,23 @@ def _rms(x, scale, cfg: TransformerConfig):
     return _norm(x, {"scale": scale}, cfg)
 
 
-def _rope(x, pos, theta):
-    """Half-split rotary over ALL dims of x [T, ..., n] at positions
-    ``pos`` [T]."""
+def _rope(x, pos, theta, interleaved: bool = False):
+    """Rotary over ALL dims of x [T, ..., n] at positions ``pos`` [T]:
+    pair ``i`` is dims ``(i, i + n/2)``, or with ``interleaved`` the
+    neighbours ``(2i, 2i + 1)``.  The result is laid out ``[first
+    members | second members]`` either way (as HF's ``rope_interleave``
+    leaves it): queries and keys come out in one order, and a dot
+    product does not care which."""
     n = x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, n, 2, dtype=jnp.float32) / n)
     ang = pos.astype(jnp.float32)[:, None] * inv              # [T, n/2]
     ang = ang.reshape((ang.shape[0],) + (1,) * (x.ndim - 2) + (n // 2,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :n // 2], xf[..., n // 2:]
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    else:
+        x1, x2 = xf[..., :n // 2], xf[..., n // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            -1).astype(x.dtype)
 
@@ -163,12 +182,14 @@ def _project(h, p, w: LatentWidths, pos, cfg: TransformerConfig):
               if m.lora_rescale else 1)
     c_q = _rms(h @ p["wq_a"].astype(dt), p["q_norm"], cfg) * q_mul
     q = (c_q @ p["wq_b"].astype(dt)).reshape(t, w.num_heads, w.qk_head_dim)
-    q_rope = _rope(q[..., w.qk_nope_head_dim:], pos, w.rope_theta)
+    q_rope = _rope(q[..., w.qk_nope_head_dim:], pos, w.rope_theta,
+                   m.rope_interleaved)
     q_abs = jnp.einsum("thn,hnr->thr", q[..., :w.qk_nope_head_dim],
                        p["wk_b"].astype(dt))
     kv = h @ p["wkv_a"].astype(dt)
     c_kv = _rms(kv[:, :w.kv_lora_rank], p["kv_norm"], cfg) * kv_mul
-    k_r = _rope(kv[:, w.kv_lora_rank:], pos, w.rope_theta)
+    k_r = _rope(kv[:, w.kv_lora_rank:], pos, w.rope_theta,
+                m.rope_interleaved)
     pad = stored_width(w.row_dim) - w.row_dim
     return (c_q,
             jnp.concatenate([q_abs, q_rope, jnp.zeros(
@@ -203,11 +224,13 @@ def _attend(q, gather, idx, ok, w: LatentWidths):
 
 
 def _finish(x, h, ctx, p, w: LatentWidths):
-    """Up-project the attended latents, gate each head, project out."""
+    """Up-project the attended latents, gate each head where the model
+    has a gate (its params a ``wg``), project out."""
     dt = x.dtype
     out = jnp.einsum("thr,hrv->thv", ctx, p["wv_b"].astype(dt))
-    gate = jax.nn.sigmoid((h @ p["wg"].astype(dt)).astype(jnp.float32))
-    out = out * gate.astype(dt)[..., None]
+    if "wg" in p:
+        gate = jax.nn.sigmoid((h @ p["wg"].astype(dt)).astype(jnp.float32))
+        out = out * gate.astype(dt)[..., None]
     return x + out.reshape(out.shape[0], -1) @ p["wo"].astype(dt)
 
 
@@ -258,16 +281,16 @@ def _index_inputs(h, c_q, p, token_pos, cfg: TransformerConfig):
     rot, theta = m.index_rope_dim, m.full.rope_theta
     q_i = (c_q @ p["idx_wq"].astype(dt)).reshape(t, m.index_heads,
                                                  m.index_head_dim)
-    q_i = jnp.concatenate([_rope(q_i[..., :rot], token_pos, theta),
-                           q_i[..., rot:]], -1)
+    q_i = jnp.concatenate([_rope(q_i[..., :rot], token_pos, theta,
+                                 m.rope_interleaved), q_i[..., rot:]], -1)
     k_i = (h @ p["idx_wk"].astype(dt)).astype(jnp.float32)
     mean = k_i.mean(-1, keepdims=True)
     var = jnp.square(k_i - mean).mean(-1, keepdims=True)
     k_i = ((k_i - mean) * lax.rsqrt(var + INDEX_NORM_EPS)
            * p["idx_k_norm"]["scale"].astype(jnp.float32)
            + p["idx_k_norm"]["bias"].astype(jnp.float32)).astype(dt)
-    k_i = jnp.concatenate([_rope(k_i[:, :rot], token_pos, theta),
-                           k_i[:, rot:]], -1)
+    k_i = jnp.concatenate([_rope(k_i[:, :rot], token_pos, theta,
+                                 m.rope_interleaved), k_i[:, rot:]], -1)
     w_i = (h @ p["idx_ww"].astype(dt)).astype(jnp.float32) \
         * (m.index_heads ** -0.5 * m.index_head_dim ** -0.5)
     return q_i, k_i, w_i
@@ -447,8 +470,9 @@ def _feed_forward(x, ln2, stack, i, has_experts: bool,
     if not has_experts:
         return x + _mlp_block(h, _at(stack, i), cfg)
     return x + moe_forward_held(
-        h, stack, i, top_k=m.num_experts_per_tok,
-        first=m.experts_held[0]) + _mlp_block(h, _at(stack["shared"], i), cfg)
+        h, stack, i, top_k=m.num_experts_per_tok, first=m.experts_held[0],
+        scale=m.routed_scaling_factor
+    ) + _mlp_block(h, _at(stack["shared"], i), cfg)
 
 
 def latent_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
@@ -458,12 +482,21 @@ def latent_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
     as ``model._ragged_trunk`` for a model without latent attention:
     ``(x [T, H], cache_k', cache_v', state')``.  Consecutive layers of one
     kind are one ``lax.scan`` (a period's three window layers); the pools
-    and the rings ride its carry whole and are updated in place."""
-    if state is None:
+    and the rings ride its carry whole and are updated in place.
+    ``state`` is ``None``, coming and going, for a model without a
+    window layer."""
+    rings = cfg.mla.has_window(cfg.num_layers)
+    if rings and state is None:
         raise ValueError(
             "this model's window latent layers keep their rows in "
             "per-sequence rings (state=latent.new_cache(...)[2]); a caller "
             "that keeps none (inference.kv_generate) cannot run it")
+    if cache_k.ndim != 3:
+        raise ValueError(
+            "a latent model's pools hold one latent row and one index key "
+            "a token and layer, [layers, rows, width] "
+            "(latent.new_cache(...)); a caller that makes per-head pages "
+            "(inference.kv_generate) cannot run it")
     layers = params["layers"]
     x = params["embed"]["tokens"].astype(cfg.dtype)[token_ids]
     meta = (token_pos, token_dest, token_slot,
@@ -488,7 +521,7 @@ def latent_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
         return (x, ck, cv, ring), None
 
     kinds = cfg.mla.kinds(cfg.num_layers)
-    carry = (x, cache_k, cache_v, state["win"])
+    carry = (x, cache_k, cache_v, state["win"] if rings else None)
     count = {"layer": 0, "full": 0, "window": 0, "mlp": 0, "moe": 0}
     i = 0
     while i < len(kinds):
@@ -508,4 +541,29 @@ def latent_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
         i += n
     x, cache_k, cache_v, ring = carry
     return (_rms(x, params["final_norm"]["scale"], cfg),
-            cache_k, cache_v, {"win": ring})
+            cache_k, cache_v, {"win": ring} if rings else None)
+
+
+def mtp_rows(params, x, next_ids, cache_k, cache_v, token_slot, token_pos,
+             token_dest, block_tables, ctx_lens, cfg: TransformerConfig,
+             block_size: int):
+    """The multi-token-prediction module over a step's rows: ``x`` [T, H]
+    is the trunk's output (after its final norm), ``next_ids`` [T] the
+    token that FOLLOWS each row.  ``u = [rms_e(Emb(next)) ; rms_h(x)]
+    W_eh``, one full layer with experts whose latent rows and index keys
+    are the caches' last layer (same pages, same destinations as the
+    trunk's rows of the step), the module's norm: ``(hidden [T, H],
+    cache_k', cache_v')``; the trunk's head makes of ``hidden[i]`` the
+    logits of the token after ``next_ids[i]``."""
+    mp, dt = params["mtp"], cfg.dtype
+    emb = params["embed"]["tokens"].astype(dt)[next_ids]
+    u = jnp.concatenate([_rms(emb, mp["enorm"]["scale"], cfg),
+                         _rms(x, mp["hnorm"]["scale"], cfg)],
+                        -1) @ mp["eh_proj"].astype(dt)
+    meta = (token_pos, token_dest, token_slot, token_slot, block_tables,
+            ctx_lens, block_size)
+    u, cache_k, cache_v = _full_layer(
+        u, mp["attn_norm"]["scale"], mp["full"], cache_k, cache_v,
+        cache_k.shape[0] - 1, meta, cfg)
+    u = _feed_forward(u, mp["ffn_norm"]["scale"], mp["moe"], 0, True, cfg)
+    return _rms(u, mp["norm"]["scale"], cfg), cache_k, cache_v

@@ -596,7 +596,8 @@ def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
     if cfg.ssm:
         logits = logits * cfg.ssm.lm_head_multiplier
         return logits.astype(jnp.float32), cache_k, cache_v, state
-    if cfg.mla:
+    if state is not None:
+        # a latent model's window rings
         return logits.astype(jnp.float32), cache_k, cache_v, state
     return logits.astype(jnp.float32), cache_k, cache_v
 
@@ -653,6 +654,13 @@ def ragged_forward_verify(params, cache_k, cache_v, token_ids, token_slot,
     ``logits_idx`` is accepted (unused) so the verify step shares the
     exact argument tuple — and therefore the audit/bench plumbing — of
     ``ragged_forward``.
+
+    Refused, by name, for what a rejected row would leave behind and no
+    later write undoes: a mixer's recurrent state, a window latent
+    layer's ring.  A latent model whose layers are all full (held
+    experts included) is served: a rejected row leaves a latent row and
+    an index key at a position the next step rewrites before any row
+    reads it.
     """
     del logits_idx
     if cfg.ssm is not None:
@@ -660,22 +668,78 @@ def ragged_forward_verify(params, cache_k, cache_v, token_ids, token_slot,
             "speculative verify needs state snapshots: a rejected draft "
             "row has already advanced the Mamba-2 SSM mixer's recurrent "
             "state, and no copy of the state before it is kept")
-    if cfg.mla is not None:
+    if cfg.mla is not None and cfg.mla.has_window(cfg.num_layers):
         raise NotImplementedError(
             "speculative verify needs a copy of the window latent layers' "
             "rows before the draft: a rejected draft row has already "
             "overwritten its ring position, and only the last "
             f"{cfg.mla.sliding_window} positions and one step are kept")
-    if cfg.alt_window or cfg.is_moe:
+    if cfg.alt_window or (cfg.is_moe and cfg.mla is None):
         raise NotImplementedError(
-            "speculative verify step supports the plain scanned-layer "
-            "ragged path only (no alt_window, no MoE)")
+            "speculative verify step supports the scanned-layer ragged "
+            "path and latent models without window layers only (no "
+            "alt_window, no capacity-routed MoE)")
     x, cache_k, cache_v, _ = _ragged_trunk(
         params, cache_k, cache_v, token_ids, token_slot, token_pos,
         token_dest, block_tables, ctx_lens, None, cfg, block_size)
     logits = _lm_head(x, params, cfg)
     nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
     return nxt, cache_k, cache_v
+
+
+def ragged_draft_step(params, cache_k, cache_v, index, *,
+                      cfg: TransformerConfig, block_size: int):
+    """One SELF-DRAFTING greedy step of a latent model with a
+    multi-token-prediction module (``cfg.mla.mtp_layers``), on a packed
+    index buffer that also carries ``token_next`` [T] and ``verify``
+    [S+1] (``ragged.PackedIndex.draft_arrays``): trunk, the greedy argmax
+    at both rows of every verify run, accept, module, next draft, as ONE
+    program.
+
+    A decoding sequence whose draft is ``d`` brought the rows ``(pending,
+    d)``; ``a1``, ``a2`` are the trunk's argmax at the two.  ``d`` stands
+    iff ``d == a1``.  The module then runs over every row with the token
+    that follows it: the host's ``token_next`` where the host knows it (a
+    prompt's next token), else the argmax just taken at that row.  The
+    next draft is the module's argmax at the sequence's last row that
+    stands (the pending token's where ``d`` was refused: the refused
+    row's cache rows, the trunk's and the module's, lie at a position
+    the next step writes before any row reads it).
+
+    Returns ``(out [4, S+1] int32, cache_k', cache_v')``, ``out`` by
+    slot: the first token delivered, the second (read only where a
+    draft was accepted), whether one was, the next draft.  Any other
+    run of rows (a prefill chunk, a sequence without a draft) delivers
+    the argmax at its last row, as ``ragged_step_sampled`` does."""
+    from deepspeed_tpu.inference.v2.latent import latent_trunk, mtp_rows
+
+    (token_ids, token_slot, token_pos, token_dest, block_tables, ctx_lens,
+     last) = index.arrays()
+    token_next, verify = index.draft_arrays()
+    x, cache_k, cache_v, _ = latent_trunk(
+        params, cache_k, cache_v, token_ids, token_slot, token_pos,
+        token_dest, block_tables, ctx_lens, None, cfg, block_size)
+
+    def argmax_at(hidden, rows):
+        return jnp.argmax(_lm_head(hidden[rows], params, cfg).astype(
+            jnp.float32), axis=-1).astype(jnp.int32)
+
+    slots = last.shape[0]
+    first = jnp.maximum(last - 1, 0)
+    both = argmax_at(x, jnp.concatenate([first, last]))
+    a_first, a_last = both[:slots], both[slots:]
+    verify = verify > 0
+    accepted = verify & (token_ids[last] == a_first)
+    own = jnp.where(jnp.arange(token_ids.shape[0]) == last[token_slot],
+                    a_last[token_slot], a_first[token_slot])
+    hidden, cache_k, cache_v = mtp_rows(
+        params, x, jnp.where(token_next >= 0, token_next, own), cache_k,
+        cache_v, token_slot, token_pos, token_dest, block_tables, ctx_lens,
+        cfg, block_size)
+    draft = argmax_at(hidden, jnp.where(verify & ~accepted, first, last))
+    out = jnp.stack([jnp.where(verify, a_first, a_last), a_last,
+                     accepted.astype(jnp.int32), draft])
+    return out, cache_k, cache_v
 
 
 def check_sampling_params(top_k: int, top_p, vocab_size: int):

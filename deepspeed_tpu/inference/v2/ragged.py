@@ -121,10 +121,33 @@ class SequenceDescriptor:
     tokens: List[int] = field(default_factory=list)   # full known token ids
     num_cached: int = 0             # tokens whose KV is already in cache
     blocks: List[int] = field(default_factory=list)
+    # a self-drafting engine's (engine_v2: ``self_draft``): the module's
+    # guess at the token after the pending one, which the next step
+    # verifies; whether the module's cache rows cover every cached
+    # position (a step that ran without the module leaves a hole, and
+    # the sequence drafts no more)
+    draft: Optional[int] = None
+    draftable: bool = True
 
     @property
     def uncached(self) -> int:
         return len(self.tokens) - self.num_cached
+
+    def settle(self, first: int, second: int, accepted: bool,
+               verified: bool) -> List[int]:
+        """Take in a self-drafting step's tokens: ``first`` and, where
+        the draft it verified was ``accepted``, ``second``.  The last of
+        them is left to the caller's ``extend``, as after any step; the
+        one before it is appended here.  A refused draft's position is
+        given back (its cache rows are rewritten by the next step before
+        any row reads them): bookkeeping alone, no device copy.  Returns
+        the tokens delivered."""
+        if accepted:
+            self.tokens.append(first)
+            return [first, second]
+        if verified:
+            self.num_cached -= 1
+        return [first]
 
 
 @jax.tree_util.register_pytree_node_class
@@ -142,12 +165,23 @@ class PackedIndex:
     jitted step is compiled once per (``rows``, ``blocks``) bucket, as it
     was per shape of the separate arrays."""
 
-    def __init__(self, buf, rows: int, slots: int, blocks: int):
+    def __init__(self, buf, rows: int, slots: int, blocks: int,
+                 draft: bool = False):
         self.buf, self.rows, self.slots, self.blocks = buf, rows, slots, blocks
+        self.draft = draft
 
     @staticmethod
-    def size(rows: int, slots: int, blocks: int) -> int:
-        return 4 * rows + slots * (blocks + 2)
+    def size(rows: int, slots: int, blocks: int, draft: bool = False) -> int:
+        return 4 * rows + slots * (blocks + 2) + (rows + slots) * draft
+
+    def draft_arrays(self) -> Tuple:
+        """A self-drafting step's two further arrays, after the seven:
+        ``token_next`` [T], the token that follows each row where the
+        host knows it (-1: the step's own argmax at that row), and
+        ``verify`` [max_seqs+1], 1 where the sequence's last row is a
+        draft to be verified against the argmax of the row before."""
+        at = self.size(self.rows, self.slots, self.blocks)
+        return (self.buf[at:at + self.rows], self.buf[at + self.rows:])
 
     def arrays(self) -> Tuple:
         """The seven arrays, in the step programs' argument order:
@@ -161,10 +195,11 @@ class PackedIndex:
         b, tables_end = self.buf, 4 * t + s * nb
         return (b[:t], b[t:2 * t], b[2 * t:3 * t], b[3 * t:4 * t],
                 b[4 * t:tables_end].reshape(s, nb),
-                b[tables_end:tables_end + s], b[tables_end + s:])
+                b[tables_end:tables_end + s],
+                b[tables_end + s:tables_end + 2 * s])
 
     def tree_flatten(self):
-        return (self.buf,), (self.rows, self.slots, self.blocks)
+        return (self.buf,), (self.rows, self.slots, self.blocks, self.draft)
 
     @classmethod
     def tree_unflatten(cls, sizes, leaves):
@@ -209,6 +244,8 @@ class DSStateManager:
         self._seqs: Dict[int, SequenceDescriptor] = {}
         self._free_slots = list(range(max_seqs - 1, -1, -1))
         self._index: Dict[Tuple[int, int], PackedIndex] = {}
+        # a self-drafting engine's index buffers carry two more arrays
+        self.drafting = False
 
     def __contains__(self, uid: int) -> bool:
         return uid in self._seqs
@@ -294,8 +331,9 @@ class DSStateManager:
         if index is None:
             slots = self.max_seqs + 1
             index = self._index[rows, blocks] = PackedIndex(
-                np.empty((PackedIndex.size(rows, slots, blocks),), np.int32),
-                rows, slots, blocks)
+                np.empty((PackedIndex.size(rows, slots, blocks,
+                                           self.drafting),), np.int32),
+                rows, slots, blocks, self.drafting)
         index.buf[:] = 0
         index.buf[rows:2 * rows] = self.max_seqs
         return index
@@ -314,6 +352,9 @@ class RaggedBatch:
     index: PackedIndex
     n_tokens: int               # real (unpadded) token count
     uids_by_slot: Dict[int, int]  # slot → uid for sampled slots
+    # a self-drafting step's verify runs: the sequences whose last row
+    # is their draft
+    verified: Tuple[SequenceDescriptor, ...] = ()
 
 
 def _bucket(n: int, floor: int, cap: int) -> int:
@@ -332,7 +373,11 @@ def build_ragged_batch(schedule: "List[tuple]", mgr: DSStateManager,
 
     ``schedule`` holds (SequenceDescriptor, n_tokens) pairs; the last
     scheduled token of a sequence is sampled only if it is the sequence's
-    final known token (i.e. the prompt chunk completes the prompt).
+    final known token (i.e. the prompt chunk completes the prompt).  An
+    item one token longer than what the sequence has uncached is a
+    self-drafting engine's VERIFY RUN: its last row is ``seq.draft``, at
+    the position after the last known token (``num_cached`` runs past
+    it; ``SequenceDescriptor.settle`` gives it back if it is refused).
 
     Shapes are bucketed (power-of-two token count and context width) so
     decode-heavy steps don't pay the full prefill budget: a 16-seq decode
@@ -358,6 +403,10 @@ def build_ragged_batch(schedule: "List[tuple]", mgr: DSStateManager,
     (token_ids, token_slot, token_pos, token_dest, block_tables, ctx_lens,
      logits_idx) = index.arrays()
     uids_by_slot: Dict[int, int] = {}
+    verified = []
+    if index.draft:
+        token_next, verify = index.draft_arrays()
+        token_next[:] = -1
 
     slots, first_pos, counts = [], [], []
     cursor = 0
@@ -365,14 +414,22 @@ def build_ragged_batch(schedule: "List[tuple]", mgr: DSStateManager,
         start = seq.num_cached
         end = start + n_new
         sl = seq.slot
-        token_ids[cursor:cursor + n_new] = seq.tokens[start:end]
+        known = seq.tokens[start:end]
+        token_ids[cursor:cursor + len(known)] = known
+        if index.draft:
+            follows = seq.tokens[start + 1:end + 1]
+            token_next[cursor:cursor + len(follows)] = follows
+            if end > len(seq.tokens):
+                token_ids[cursor + n_new - 1] = seq.draft
+                verify[sl] = 1
+                verified.append(seq)
         # a sequence may hold pages past this step's context (a fused
         # decode's horizon, a rewound draft): the bucket cuts them off
         held = seq.blocks[:nb]
         block_tables[sl, :len(held)] = held
         ctx_lens[sl] = end
         logits_idx[sl] = cursor + n_new - 1
-        if end == len(seq.tokens):
+        if end >= len(seq.tokens):
             uids_by_slot[sl] = seq.uid
         slots.append(sl)
         first_pos.append(start - cursor)
@@ -390,4 +447,4 @@ def build_ragged_batch(schedule: "List[tuple]", mgr: DSStateManager,
                                + rows_pos % bs)
 
     return RaggedBatch(index=index, n_tokens=cursor,
-                       uids_by_slot=uids_by_slot)
+                       uids_by_slot=uids_by_slot, verified=tuple(verified))

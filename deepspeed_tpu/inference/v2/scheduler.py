@@ -70,13 +70,18 @@ class SplitFuseScheduler:
     def has_work(self) -> bool:
         return bool(self._decode or self._prefill)
 
-    def next_schedule(self) -> List[Tuple[SequenceDescriptor, int]]:
+    def next_schedule(self, drafts: bool = False
+                      ) -> List[Tuple[SequenceDescriptor, int]]:
         """(sequence, n_tokens) items for one step, ≤ token_budget total.
 
         Decode sequences first (1 token each — they bound latency), then
         prompt chunks; both sets walk in priority order. A prompt whose
         remaining tokens exceed the leftover budget is split; its
-        unsampled chunk stays queued.
+        unsampled chunk stays queued.  ``drafts`` (a self-drafting
+        engine's step): a decode sequence that holds a draft brings TWO
+        rows, its pending token and the draft (a verify run), under the
+        same budget; where one row is all that is left, the draft is dropped
+        (the step makes the next one).
         """
         budget = self.token_budget
         schedule: List[Tuple[SequenceDescriptor, int]] = []
@@ -86,8 +91,10 @@ class SplitFuseScheduler:
             seq = self.mgr.get(uid)
             if seq.uncached <= 0:
                 continue
-            schedule.append((seq, 1))
-            budget -= 1
+            n = 2 if (drafts and seq.draft is not None and seq.uncached == 1
+                      and budget >= 2) else 1
+            schedule.append((seq, n))
+            budget -= n
 
         finished_prefill = []
         for uid in list(self._prefill):

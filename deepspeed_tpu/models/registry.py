@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.transformer import (LatentConfig, LatentWidths,
@@ -19,6 +21,15 @@ def get_model_config(name: str, **overrides) -> TransformerConfig:
     if name not in _REGISTRY:
         raise KeyError(f"unknown model '{name}'; known: {sorted(_REGISTRY)}")
     cfg = _REGISTRY[name]
+    if cfg.mla is not None:
+        # what a latent model keeps in its LatentConfig alone (its leading
+        # dense layers, say: a cut of the depth takes fewer of them with
+        # it) is overridden there
+        own = ({f.name for f in dataclasses.fields(cfg.mla)}
+               - {f.name for f in dataclasses.fields(cfg)})
+        latent = {k: overrides.pop(k) for k in own & set(overrides)}
+        if latent:
+            cfg = cfg.replace(mla=dataclasses.replace(cfg.mla, **latent))
     return cfg.replace(**overrides) if overrides else cfg
 
 
@@ -226,6 +237,56 @@ register("dots3-note-tiny", TransformerConfig(
         n_routed_experts=16, experts_held=(4, 4), num_experts_per_tok=4,
         moe_intermediate_size=32),
     **_dots3))
+
+# -- GLM-5 (HF glm_moe_dsa: latent attention with a learned top-k
+# indexer in EVERY layer, no gate, no window layer, interleaved rotary
+# pairs, sigmoid-routed experts scaled by 2.5 after three dense layers,
+# one multi-token-prediction module) ------------------------------------
+_glm5 = dict(arch="glm_moe_dsa", norm="rmsnorm", activation="swiglu",
+             use_rope=True, tie_embeddings=False, use_bias=False)
+
+
+def _glm5_latent(experts_held):
+    return LatentConfig(
+        full=LatentWidths(num_heads=64, q_lora_rank=2048, kv_lora_rank=512,
+                          qk_nope_head_dim=192, qk_rope_head_dim=64,
+                          v_head_dim=256, rope_theta=1e6),
+        window=None, layer_types=("full_attention",) * 78,
+        sliding_window=0, index_heads=32, index_head_dim=128,
+        index_topk=2048, index_rope_dim=64, n_routed_experts=256,
+        experts_held=experts_held, num_experts_per_tok=8,
+        moe_intermediate_size=2048, first_k_dense=3, lora_rescale=False,
+        rope_interleaved=True, gate=False, routed_scaling_factor=2.5,
+        mtp_layers=1)
+
+
+register("glm-5", TransformerConfig(
+    vocab_size=154880, hidden_size=6144, intermediate_size=12288,
+    num_layers=78, num_heads=64, max_seq_len=202752, rope_theta=1e6,
+    layernorm_eps=1e-5, mla=_glm5_latent((0, 256)), **_glm5))
+
+# one chip's share of a layer divided over sixteen: experts 0-15 of the
+# 256 (routing over all of them) and a sixteenth of the vocabulary;
+# attention, the dense feed-forwards and the shared expert whole
+register("glm-5-ep16", TransformerConfig(
+    vocab_size=19360, hidden_size=6144, intermediate_size=12288,
+    num_layers=78, num_heads=64, max_seq_len=202752, rope_theta=1e6,
+    layernorm_eps=1e-5, mla=_glm5_latent((0, 16)), **_glm5))
+
+register("glm-5-tiny", TransformerConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=4,
+    num_heads=4, max_seq_len=256, rope_theta=1e4, layernorm_eps=1e-5,
+    mla=LatentConfig(
+        full=LatentWidths(num_heads=4, q_lora_rank=32, kv_lora_rank=24,
+                          qk_nope_head_dim=16, qk_rope_head_dim=8,
+                          v_head_dim=16, rope_theta=1e4),
+        window=None, layer_types=("full_attention",) * 4, sliding_window=0,
+        index_heads=4, index_head_dim=16, index_topk=8, index_rope_dim=8,
+        n_routed_experts=16, experts_held=(4, 4), num_experts_per_tok=4,
+        moe_intermediate_size=32, first_k_dense=2, lora_rescale=False,
+        rope_interleaved=True, gate=False, routed_scaling_factor=2.5,
+        mtp_layers=1),
+    **_glm5))
 
 # -- Phi (ref v2 phi: parallel block + partial rotary + biases) --------
 register("phi-2", TransformerConfig(

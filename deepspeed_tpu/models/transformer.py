@@ -105,20 +105,29 @@ class LatentWidths:
 @dataclass(frozen=True)
 class LatentConfig:
     """Latent attention in every block, of two kinds chosen per layer by
-    ``layer_types`` (HF ``dots3_note``): ``"full_attention"`` layers read
-    the ``index_topk`` keys a learned indexer scores highest,
-    ``"sliding_attention"`` layers the last ``sliding_window`` positions
-    at widths of their own; a headwise sigmoid gate on every head's
-    output; ``first_k_dense`` leading blocks with a dense feed-forward,
-    the others with sigmoid-routed experts of which this program holds
-    ``experts_held`` (first, count): it routes over all
-    ``n_routed_experts`` and computes its own experts' part (weights
-    normalised over the chosen, times a ``routed_scaling_factor`` of 1,
-    as published).
+    ``layer_types`` (HF ``dots3_note``, ``glm_moe_dsa``):
+    ``"full_attention"`` layers read the ``index_topk`` keys a learned
+    indexer scores highest, ``"sliding_attention"`` layers the last
+    ``sliding_window`` positions at widths of their own (``window`` is
+    ``None`` and ``sliding_window`` 0 for a model whose layers are all
+    full: it keeps no rings); ``gate``: a headwise sigmoid gate on every
+    head's output (dots3-note has one, GLM-5 none); ``first_k_dense``
+    leading blocks with a dense feed-forward, the others with
+    sigmoid-routed experts of which this program holds ``experts_held``
+    (first, count): it routes over all ``n_routed_experts`` and computes
+    its own experts' part (weights normalised over the chosen, times
+    ``routed_scaling_factor``).  ``rope_interleaved``: rotary pairs are
+    neighbours ``(2i, 2i + 1)``, in attention and in the indexer, and not
+    the halves ``(i, i + n/2)``.  ``mtp_layers`` (0 or 1): a
+    multi-token-prediction module after the trunk (DeepSeek-V3's; HF
+    ``num_nextn_predict_layers``): one more full layer with experts, on
+    ``[RMSNorm(Emb(next token)) ; RMSNorm(trunk output)] W_eh``, through
+    its own norm into the trunk's head; a serving engine drafts with it
+    (``inference/v2``: ``self_draft``).
     ``TransformerConfig.mla`` is ``None`` for a model without latent
     attention.  Served by inference/v2 only."""
     full: LatentWidths
-    window: LatentWidths
+    window: Optional[LatentWidths]
     layer_types: Tuple[str, ...]
     sliding_window: int
     index_heads: int
@@ -133,12 +142,25 @@ class LatentConfig:
     first_k_dense: int = 1
     lora_rescale: bool = True
     rope_interleaved: bool = False
+    gate: bool = True
+    routed_scaling_factor: float = 1.0
+    mtp_layers: int = 0
 
     def kinds(self, num_layers: int) -> Tuple[Tuple[bool, bool], ...]:
         """(is a full layer, has experts) for each of the first
-        ``num_layers`` layers."""
+        ``num_layers`` layers (the trunk's: a module is none of them)."""
         return tuple((t == "full_attention", i >= self.first_k_dense)
                      for i, t in enumerate(self.layer_types[:num_layers]))
+
+    def has_window(self, num_layers: int) -> bool:
+        """Whether any of the first ``num_layers`` layers keeps a ring."""
+        return not all(full for full, _ in self.kinds(num_layers))
+
+    def cache_layers(self, num_layers: int) -> int:
+        """Layers that keep latent rows and index keys in the pages: the
+        trunk's full layers and the module's one."""
+        return sum(1 for full, _ in self.kinds(num_layers) if full) \
+            + self.mtp_layers
 
 
 @dataclass(frozen=True)
@@ -342,7 +364,8 @@ class TransformerConfig:
 
     @property
     def window_row(self) -> int:
-        return self.mla.window.row_dim if self.mla else 0
+        return (self.mla.window.row_dim
+                if self.mla and self.mla.window else 0)
 
     @property
     def index_topk(self) -> int:
@@ -355,6 +378,18 @@ class TransformerConfig:
     @property
     def experts_held(self) -> int:
         return self.mla.experts_held[1] if self.mla else 0
+
+    @property
+    def mtp_layers(self) -> int:
+        return self.mla.mtp_layers if self.mla else 0
+
+    @property
+    def first_k_dense(self) -> int:
+        return self.mla.first_k_dense if self.mla else 0
+
+    @property
+    def routed_scaling_factor(self) -> float:
+        return self.mla.routed_scaling_factor if self.mla else 0.0
 
     # the mixer's sizes by flat names (0 without one), for callers that
     # hold a configuration to a file by ``getattr``
@@ -559,8 +594,10 @@ def refuse_ssm(cfg: TransformerConfig, what: str) -> None:
     if cfg.mla is not None:
         raise NotImplementedError(
             f"{what}: this configuration has latent (MLA) attention with a "
-            f"learned top-{cfg.mla.index_topk} indexer and window-"
-            f"{cfg.mla.sliding_window} latent layers; models/transformer.py "
+            f"learned top-{cfg.mla.index_topk} indexer"
+            + (f" and window-{cfg.mla.sliding_window} latent layers"
+               if cfg.mla.window else "")
+            + "; models/transformer.py "
             "has neither and would run plain attention under its name. "
             "Serve it through inference.v2.InferenceEngineV2")
     if cfg.ssm is not None:
@@ -577,6 +614,11 @@ EMBED_COMMON = 0.07
 RESIDUAL_RMS = 0.5
 ROUTER_LOGIT_SD = 4.0
 ROUTER_LOGIT_MEAN = 3.5
+# a model with a multi-token-prediction module: its seeded successor
+# structure (init_latent_params has the reason)
+SUCCESSOR_EMBED_NORM = 12.0
+SUCCESSOR_HEAD_GAIN = 0.15
+SUCCESSOR_SHARE = 0.8
 
 
 def init_latent_params(cfg: TransformerConfig, key) -> Params:
@@ -610,7 +652,46 @@ def init_latent_params(cfg: TransformerConfig, key) -> Params:
     stream's token-specific part has grown to an rms of ``RESIDUAL_RMS``,
     which the output projections' ``1 / sqrt(2 layers)`` makes of it by
     the last blocks whatever the depth; earlier blocks sit lower still.
-    Down there the eight weights are a softmax of the eight logits."""
+    Down there the eight weights are a softmax of the eight logits.
+
+    **A module's initialisation** (``mtp_layers``: ``params["mtp"]`` holds
+    ``enorm``, ``hnorm``, ``eh_proj`` ``[2 hidden, hidden]`` (the
+    embedding's half first), one full layer with experts (``attn_norm``,
+    ``full``, ``ffn_norm``, ``moe`` stacked ``[1, ...]``) and ``norm``; the
+    embedding and the head are the trunk's).  A trained module's draft
+    is the trunk's own next token for 85-90 % of its drafts (DeepSeek-V3's
+    report); on plain seeded weights two argmaxes over the vocabulary
+    agree once in ``vocab_size`` tries, and a server that always refuses
+    its drafts measures what no user sees.  So a model with a module is
+    given what a trained one has, a next token that follows from the
+    last and a module that mostly knows it: a token's own part of its
+    embedding row has norm ``SUCCESSOR_EMBED_NORM`` (not one) and lies in
+    the first half of the hidden dims for a seeded ``SUCCESSOR_SHARE`` of
+    the tokens and in the second half for the others; the head's column
+    of token ``pi(v)`` carries ``SUCCESSOR_HEAD_GAIN`` times that part of
+    the row of ``v`` beside its seeded normal part (``pi`` a seeded
+    permutation of the vocabulary); and ``eh_proj``'s embedding half
+    carries the identity ON THE FIRST HALF of the dims beside its
+    normal part.  The trunk's greedy successor of
+    ``v`` is then ``pi(v)`` by a wide margin over what the layers wrote
+    into the stream (a logit near 27 against a largest other near 9; at
+    half the norm and twice the gain, 13 against 9, one token in two
+    hundred lost to the token the layers favour, every stream then
+    restarted from that ONE token, and the share followed the few
+    hundred tokens after it: 0.74-0.84 over three seeds), so a
+    greedy stream walks a cycle of ``pi``, thousands of tokens long, and
+    never the short loops greedy decoding of seeded weights falls into;
+    the module is given the embedding row of the token it is to follow
+    and says ``pi`` of it where it can see that row's own part, the
+    ``SUCCESSOR_SHARE``, and something else where it cannot.  So the
+    share of drafts accepted is about ``SUCCESSOR_SHARE`` whatever the
+    seed.  What did NOT hold still (read on the chip, PERF.md, PR 39): one
+    gain for every token at the margin of what the layers write (0.06 of
+    the drafts accepted at a gain of 0.10, 0.88 at 0.13), and a gain for
+    a share of the tokens only (a stream then falls into one short loop,
+    all accepted or none: 0.96, 1.00 and 0.08 on three seeds).  Nothing
+    is forced: a draft is the module's argmax, accepted where it equals
+    the trunk's."""
     m, h, pd, nl = cfg.mla, cfg.hidden_size, cfg.param_dtype, cfg.num_layers
     kinds = m.kinds(nl)
     if len(kinds) != nl:
@@ -642,8 +723,9 @@ def init_latent_params(cfg: TransformerConfig, key) -> Params:
             "wv_b": dense(k[4], (nh, w.kv_lora_rank, w.v_head_dim), kv_in),
             "wo": dense(k[5], (nh * w.v_head_dim, h), nh * w.v_head_dim,
                         out=True),
-            "wg": dense(k[6], (h, nh), h),
         }
+        if m.gate:
+            p["wg"] = dense(k[6], (h, nh), h)
         if indexer:
             p["idx_wq"] = dense(k[7], (w.q_lora_rank,
                                        m.index_heads * m.index_head_dim),
@@ -677,7 +759,7 @@ def init_latent_params(cfg: TransformerConfig, key) -> Params:
                     bias=jnp.zeros((m.n_routed_experts,), pd),
                     shared=swiglu(k[2], (), f * m.n_shared_experts))
 
-    keys = jax.random.split(key, nl + 2)
+    keys = jax.random.split(key, nl + 2 + 2 * m.mtp_layers)
     groups: Dict[str, list] = {"full": [], "window": [], "mlp": [], "moe": []}
     for i, (is_full, has_experts) in enumerate(kinds):
         ka, kf = jax.random.split(keys[i])
@@ -689,14 +771,39 @@ def init_latent_params(cfg: TransformerConfig, key) -> Params:
               for name, ps in groups.items() if ps}
     layers["ln1"] = {"scale": jnp.ones((nl, h), pd)}
     layers["ln2"] = {"scale": jnp.ones((nl, h), pd)}
-    return {
-        "embed": {"tokens": (
-            jax.random.normal(keys[nl], (cfg.vocab_size, h)) / math.sqrt(h)
-            + EMBED_COMMON).astype(pd)},
-        "layers": layers,
-        "final_norm": {"scale": jnp.ones((h,), pd)},
-        "lm_head": dense(keys[nl + 1], (h, cfg.vocab_size), h),
-    }
+    own = jax.random.normal(keys[nl], (cfg.vocab_size, h)) / math.sqrt(h)
+    head = dense(keys[nl + 1], (h, cfg.vocab_size), h)
+    params = {"layers": layers,
+              "final_norm": {"scale": jnp.ones((h,), pd)}}
+    if m.mtp_layers:
+        if m.mtp_layers != 1:
+            raise NotImplementedError(
+                f"{m.mtp_layers} multi-token-prediction modules: one is "
+                "built (it drafts one token)")
+        ka, kf, ke, kp, ks = jax.random.split(keys[nl + 2], 5)
+        first_half = jnp.arange(h) < h // 2
+        seen = jax.random.uniform(ks, (cfg.vocab_size, 1)) < SUCCESSOR_SHARE
+        # the same norm in half the dims
+        own = jnp.where(seen == first_half, own, 0.0) \
+            * (SUCCESSOR_EMBED_NORM * math.sqrt(2.0))
+        successor = jax.random.permutation(kp, cfg.vocab_size)
+        head = (head.astype(jnp.float32).T.at[successor].add(
+            SUCCESSOR_HEAD_GAIN * own)).T.astype(pd)
+        eh = jax.random.normal(ke, (2 * h, h)) / math.sqrt(2 * h)
+        params["mtp"] = {
+            "enorm": {"scale": jnp.ones((h,), pd)},
+            "hnorm": {"scale": jnp.ones((h,), pd)},
+            "eh_proj": eh.at[jnp.arange(h // 2), jnp.arange(h // 2)].add(
+                1.0).astype(pd),
+            "attn_norm": {"scale": jnp.ones((h,), pd)},
+            "full": attn(ka, m.full, True),
+            "ffn_norm": {"scale": jnp.ones((h,), pd)},
+            "moe": jax.tree.map(lambda a: a[None], moe(kf)),
+            "norm": {"scale": jnp.ones((h,), pd)},
+        }
+    params["embed"] = {"tokens": (own + EMBED_COMMON).astype(pd)}
+    params["lm_head"] = head
+    return params
 
 
 def init_params(cfg: TransformerConfig, key) -> Params:

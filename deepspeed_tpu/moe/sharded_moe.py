@@ -435,15 +435,17 @@ def route_sigmoid(x, router, bias, top_k: int):
     return chosen.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
 
 
-def moe_forward_held(x, p, layer, *, top_k: int, first: int):
+def moe_forward_held(x, p, layer, *, top_k: int, first: int,
+                     scale: float = 1.0):
     """The part of a sigmoid-routed expert layer that THIS program's
     experts give.  Every leaf of ``p`` holds the expert layers stacked on
     axis 0 and ``layer`` (a traced scalar will do) says which: ``p["wg"]``
     / ``["wi"]`` ``[L, held, H, F]`` and ``p["wo"]`` ``[L, held, F, H]``
     are experts ``first .. first + held`` of the ``p["router"]``'s
     ``[L, H, E]``; routing is over all ``E`` (:func:`route_sigmoid`), and
-    ``y[t] = sum over t's chosen experts that are held of w *
-    SwiGLU_e(x[t])``: what the other ranks of an expert-parallel layer
+    ``y[t] = scale * sum over t's chosen experts that are held of w *
+    SwiGLU_e(x[t])`` (``scale``: HF ``routed_scaling_factor``): what the
+    other ranks of an expert-parallel layer
     would add is theirs to compute, and a shared expert is the caller's.
     x: [T, H] -> [T, H].  The tile loop reads ``[layer, expert]`` out of
     the stack itself: a layer's experts sliced out first are a
@@ -464,6 +466,8 @@ def moe_forward_held(x, p, layer, *, top_k: int, first: int):
     dt, f32 = x.dtype, jnp.float32
     tm = min(HELD_TILE, max(8, t))
     chosen, w = route_sigmoid(x, p["router"][layer], p["bias"][layer], top_k)
+    if scale != 1.0:
+        w = w * scale
     local = (chosen - first).reshape(-1)                       # [T * k]
     local = jnp.where((local >= 0) & (local < held), local, held)
     n = t * top_k

@@ -454,7 +454,13 @@ class SpeculativeServingConfig:
     decoding on the decode tier (serving/disagg.py SpeculativeDecoder):
     a draft model in the same serve loop proposes ``spec_k`` tokens per
     sequence, the target verifies them in one ragged step, and greedy
-    acceptance is bit-identical to decoding without a draft."""
+    acceptance is bit-identical to decoding without a draft.  An
+    EXTERNAL draft: a second engine, rounds only while every active
+    request is opted in and decoding.  A model that publishes a
+    multi-token-prediction module drafts for ITSELF instead, inside the
+    one ragged step and beside prompts' chunks, with
+    ``engine_config["self_draft"]`` and none of these keys
+    (docs/SERVING.md)."""
     enabled: bool = False
     draft_model: str = ""          # models.get_model_config name
     spec_k: int = 4                # proposals per sequence per round

@@ -74,7 +74,12 @@ _TIMELINE_RING = 1024
 
 
 class SpeculativeConfig:
-    """``serving.disagg.speculative`` block, serving-side parser."""
+    """``serving.disagg.speculative`` block, serving-side parser: an
+    EXTERNAL draft (a second engine, ``spec_k`` proposals a round, every
+    active request opted in and decoding).  A model that drafts for
+    itself through its own multi-token-prediction module needs none of
+    this: ``engine_config`` ``self_draft`` (docs/SERVING.md), one
+    program a step, verify runs beside prompts' chunks, no gate."""
 
     def __init__(self, d: Optional[dict] = None, **kw):
         d = {**(d or {}), **kw}
@@ -132,6 +137,14 @@ class SpeculativeDecoder:
     flushed and re-admitted (a cheap draft-model re-prefill), so draft
     KV exhaustion, preemption, and fail-over all degrade to plain
     greedy decoding rather than to an error.
+
+    Left as it was by PR 39: the target's ``verify_step`` still takes
+    the argmax at every row of its own program (``ragged_verify``), not
+    the self-drafting step's two rows a run (that step knows its runs
+    are two rows long and fuses accept, module and next draft; this one
+    serves ``spec_k`` rows a run and a draft engine's rewind).  The
+    target may now be a latent model whose layers are all full; a
+    self-drafting engine refuses to be one.
     """
 
     def __init__(self, target: Any, draft: Any, spec_k: int = 4):

@@ -149,6 +149,16 @@ class InferenceServer:
         # draft model living in this serve loop.  Anything with
         # round()/flush() works; None disables per-request `speculative`
         self._spec = spec_decoder
+        # an engine that drafts for itself (engine_config self_draft):
+        # every all-greedy step is a self-drafting one, whoever is still
+        # prefilling; no per-request opt-in, no gate (_spec_eligible is
+        # the external draft's)
+        self._self_draft = bool(getattr(engine, "self_draft", False))
+        if self._self_draft and spec_decoder is not None:
+            raise ValueError(
+                "spec_decoder with a self-drafting engine (self_draft): "
+                "rows an external draft verifies or takes back would "
+                "leave the engine's own module's cache rows behind")
         # a telemetry.Telemetry hub: serving histograms register in ITS
         # registry (one Prometheus exposition for both hot loops) and the
         # loop emits kind="serving" StepRecords to the same JSONL
@@ -806,6 +816,18 @@ class InferenceServer:
                     # each value is the accepted token burst (>= 1), and
                     # the engine's sequences already carry them
                     emitted = self._spec.round(self._active)
+                elif all_greedy and self._self_draft:
+                    # the engine drafts for itself inside the ragged step
+                    # (its multi-token-prediction module): a burst of one
+                    # or two tokens a sequence, prompts' chunks in the
+                    # same step; the last token is extended below as a
+                    # plain step's
+                    before = (self.engine.drafts_verified,
+                              self.engine.drafts_accepted)
+                    emitted = self.engine.step_bursts()
+                    self.metrics.record_spec_round(
+                        self.engine.drafts_verified - before[0],
+                        self.engine.drafts_accepted - before[1])
                 elif all_greedy:
                     emitted = {u: [t] for u, t in
                                self.engine.step(temperature=0.0).items()}
@@ -924,16 +946,19 @@ class InferenceServer:
             elif not spec_ready:
                 # speculative bursts were appended to the engine sequence
                 # by verify_step itself; a plain step's token must extend
+                # (and a self-drafting step's last)
                 self.engine.extend(uid, burst[-1])
         return n_tokens, n_finished
 
     def _spec_eligible(self) -> bool:
-        """A speculative round needs EVERY active request greedy, opted
-        in, and in steady-state decode (exactly one pending sampled
-        token) — the decode tier's steady state.  Mixed batches (a
-        prefill mid-flight, a non-greedy or non-speculative peer) run
-        the plain step; speculation resumes when the batch is
-        homogeneous again."""
+        """A speculative round (an EXTERNAL draft model) needs EVERY
+        active request greedy, opted in, and in steady-state decode
+        (exactly one pending sampled token) — the decode tier's steady
+        state.  Mixed batches (a prefill mid-flight, a non-greedy or
+        non-speculative peer) run the plain step; speculation resumes
+        when the batch is homogeneous again.  A self-drafting engine
+        needs none of this: its verify runs share a step with prompts'
+        chunks, and it is asked nothing here."""
         if self._spec is None or not self._active:
             return False
         if self._brownout >= _BL_SHED_SPEC:

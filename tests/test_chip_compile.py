@@ -398,6 +398,65 @@ def test_latent_step_keeps_its_pools_and_rings_in_place(chip):
     assert not moved, moved
 
 
+@pytest.mark.parametrize("t,nb", [(1024, 64), (32, 32)],
+                         ids=["with_chunk", "verify_runs"])
+def test_self_drafting_step_is_one_program_with_its_pools_in_place(chip, t,
+                                                                   nb):
+    """The self-drafting step of ``glm-5-ep16-l5`` (five layers and the
+    module at the published widths): trunk, the argmax at both rows of
+    the verify runs, accept, module and next draft compile as ONE
+    program whose two pools (six cache layers: the trunk's five and the
+    module's) are donated, come back in place and are never copied
+    whole or by layer; the one result is ``s32[4, slots]``; the index
+    kernel runs in all six layers; no stack of expert matrices is sliced
+    out as a value of its own."""
+    import re
+
+    from deepspeed_tpu.inference.v2 import latent
+    from deepspeed_tpu.inference.v2 import model as v2_model
+    from deepspeed_tpu.inference.v2.ragged import PackedIndex
+    from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.models import transformer as tf_model
+
+    cfg = get_model_config(
+        "glm-5-ep16", num_layers=5, first_k_dense=1, param_dtype=BF16,
+        dtype=BF16, v2_modules=(("indexer", "indexer_pallas"),))
+    rows, bs = 1024 * 128, 128
+    params = _abstract(chip, jax.eval_shape(
+        lambda k: tf_model.init_params(cfg, k), jax.random.PRNGKey(0)))
+    ck, cv, state = jax.eval_shape(lambda: latent.new_cache(cfg, rows, 32, t))
+    assert state is None
+    ck, cv = _abstract(chip, (ck, cv))
+    assert ck.shape == (6, rows, 640) and cv.shape == (6, rows, 128)
+    index = PackedIndex(chip((PackedIndex.size(t, 33, nb, True),), I32),
+                        t, 33, nb, True)
+    fn = functools.partial(v2_model.ragged_draft_step, cfg=cfg,
+                           block_size=bs)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+            params, ck, cv, index).compile()
+    text = compiled.as_text()
+    assert len(_aliased_outputs(text)) == 2
+    assert "s32[4,33]" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * 2 ** 30, mem
+    assert text.count("latent_index_scores") >= 3   # trunk segments, module
+    held = ["bf16[%s]" % ",".join(map(str, a.shape)) for a in (ck, cv)]
+    held += ["bf16[%s]" % ",".join(map(str, a.shape[1:])) for a in (ck, cv)]
+    experts = ["bf16[16,6144,2048]", "bf16[16,2048,6144]"]
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+?)[{ ]\S* ?([\w-]+)\(", line)
+        if not m:
+            continue
+        op = m.group(1) if m.group(3) == "fusion" else m.group(3)
+        if m.group(2) in held and re.search(r"copy|transpose", op):
+            moved.append(line.strip()[:160])
+        if m.group(2) in experts and re.search(r"copy|slice", op):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+
+
 def test_paged_qblock_group_of_five(chip):
     """Falcon-H1-34B's attention heads: 20 query heads on 4 key/value
     heads of 128, a group that is no power of two (160 query rows a KV
