@@ -289,6 +289,54 @@ def ssd_phase(seed: int = 0, *, heads: int = 32, head_dim: int = 128,
     return {"y": err_y, "state": err_s, "silent": err_silent}
 
 
+def append_phase(seed: int = 0, *, layers: int = 4, kv_heads: int = 8,
+                 head_dim: int = 128, block_size: int = 16,
+                 pages: int = 256, chunk_rows: int = 150) -> dict:
+    """The page-granular KV append (both pools in one kernel) at
+    mistral-7b's page shape against the row scatter it replaced, over one
+    ragged step: a prompt's ``chunk_rows`` rows from the middle of a page,
+    decode rows in pages of their own, padding.  Every page but page 0,
+    the garbage page, must hold the same bits, and no other layer's page
+    may change."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2.model import _kv_append
+    from deepspeed_tpu.ops.pallas.kv_append import kv_append, step_pages
+
+    bs = block_size
+    rng = np.random.default_rng(seed)
+    table = rng.permutation(np.arange(1, pages))
+    pos = 5 + np.arange(chunk_rows)
+    n_decode, n_pad = 9, 6
+    dest = np.concatenate([
+        table[pos // bs] * bs + pos % bs,
+        table[-n_decode:] * bs + rng.integers(0, bs, size=n_decode),
+        np.zeros(n_pad)]).astype(np.int32)
+    t = len(dest)
+    shape = (layers, kv_heads, pages * bs, head_dim)
+    bf16 = jnp.bfloat16
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 4))
+    ck = jax.random.normal(next(keys), shape, bf16)
+    cv = jax.random.normal(next(keys), shape, bf16)
+    k = jax.random.normal(next(keys), (t, kv_heads, head_dim), bf16)
+    v = jax.random.normal(next(keys), (t, kv_heads, head_dim), bf16)
+    layer = jnp.int32(layers - 2)
+    want = jax.jit(lambda ck, cv: (_kv_append(ck, k, dest, layer),
+                                   _kv_append(cv, v, dest, layer)))(ck, cv)
+    got = jax.jit(lambda ck, cv: kv_append(
+        ck, cv, k, v, step_pages(ck, jnp.asarray(dest), bs), layer,
+        bs))(ck, cv)
+    same = all(bool(jnp.array_equal(g[:, :, bs:], w[:, :, bs:]))
+               for g, w in zip(got, want))
+    wrote = not bool(jnp.array_equal(got[0][layers - 2], ck[layers - 2]))
+    log(f"[append] {t} rows ({chunk_rows} of a chunk, {n_decode} decode, "
+        f"{n_pad} padding) into {kv_heads} x {pages} pages of {bs} x "
+        f"{head_dim}: every page but page 0 equal to the scatter's {same}")
+    require(same and wrote, "kv_append's pages are not the row scatter's")
+    return {"same": same, "rows": t}
+
+
 def index_phase(seed: int = 0, *, heads: int = 64, dim: int = 128,
                 block_size: int = 128, blocks: int = 8,
                 chunk_rows: int = 150) -> dict:
@@ -624,6 +672,7 @@ def run_one_chip(seed: int) -> None:
 
     kernels_phase(seed)
     ssd_phase(seed)
+    append_phase(seed)
     index_phase(seed)
     train = train_phase(
         get_model_config(TRAIN_MODEL, max_seq_len=TRAIN_SEQ),

@@ -42,6 +42,7 @@ from deepspeed_tpu.inference.v2.ragged import (DSStateManager,
 from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
 from deepspeed_tpu.models import transformer as tf_model
 from deepspeed_tpu.models.transformer import TransformerConfig
+from deepspeed_tpu.ops.pallas.kv_append import append_pages
 from deepspeed_tpu.ops.pallas.paged_attention import (QUERY_BLOCK,
                                                       shared_walk_rows)
 from deepspeed_tpu.resilience.oracle import PartitionOracle
@@ -572,6 +573,9 @@ class InferenceEngineV2:
             items = [(seq.num_cached - n, n) for seq, n in schedule]
             counts = step_counts(items, self.model_config.sliding_window,
                                  self._query_block)
+            # the pages the new rows land in, a sequence's counted apart:
+            # with ``tokens``, what the append moves (whole pages)
+            counts["append_pages"] = append_pages(items, self.cfg.block_size)
             if self.model_config.ssm is not None:
                 counts.update(ssm_step_counts(
                     items, self._slot_bytes, self.state_manager.n_active))

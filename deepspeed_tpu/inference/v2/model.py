@@ -8,10 +8,11 @@ arbitrary prefill/decode mix as a flat token list with per-token metadata.
 Design (vs the reference's CUDA kernels):
 * KV cache pages are rows of ONE pool for every layer, ``[L, kv_heads, P, d]``
   (P = num_blocks·block_size; kv-head-major for the kernels' page blocks).
-  On the kernel path (``paged_pallas``) a step *scatters* its rows' KV into
-  the pool in place, at ``[layer, head, token_dest]``, and the Pallas
-  block-table kernels (``ops/pallas/paged_attention.py``) read the layer's
-  pages out of the whole pool by the layer index.  The XLA path (head 64,
+  On the kernel path (``paged_pallas``) a step puts its rows' KV into the
+  pool in place a page at a time, both pools in one kernel
+  (``ops/pallas/kv_append.py``), and the Pallas block-table kernels
+  (``ops/pallas/paged_attention.py``) read the layer's pages out of the
+  whole pool by the layer index.  The XLA path (head 64,
   ALiBi, the CPU) takes ONE layer's pages out of the pool, scatters into
   them, *gathers* each token's context rows through the block table, and
   puts the layer back.
@@ -40,6 +41,7 @@ from deepspeed_tpu.inference.v2.modules import (register_module, resolve,
                                                 resolve_name)
 from deepspeed_tpu.models.transformer import (TransformerConfig, _mlp_block,
                                               _norm)
+from deepspeed_tpu.ops.pallas.kv_append import kv_append, step_pages
 from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
 from deepspeed_tpu.ops.pallas.ssd_ragged import run_layout, ssd_ragged
 from deepspeed_tpu.utils.platform import on_tpu
@@ -340,12 +342,12 @@ def _ssm_meta(cfg: TransformerConfig, state, token_slot, token_pos):
 def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
                   cfg: TransformerConfig, layer_is_moe=False, state=None,
                   ssm_meta=None):
-    """Block ``layer`` over flat tokens [T, H]; scatters its KV into the
+    """Block ``layer`` over flat tokens [T, H]; appends its KV to the
     pools [L, nkv, P, d] in place and attends via its pages of them.
     Returns (x, cache_k, cache_v, state): ``state`` is the layer's
     recurrent slots where the block has an SSM mixer, else None."""
     (token_pos, token_dest, gather_idx, token_ctx_len, token_slot,
-     block_tables, block_size) = meta
+     block_tables, block_size, dest_pages) = meta
     mixer = cfg.ssm
     t = x.shape[0]
     nh, nkv, d = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
@@ -388,9 +390,15 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
         token_slot=token_slot, block_size=block_size)
     if attention_impl_name(cfg, block_size,
                            block_tables is not None) == "paged_pallas":
-        # the kernels read the layer's pages out of the whole pool
-        cache_k = _kv_append(cache_k, k, token_dest, layer)
-        cache_v = _kv_append(cache_v, v, token_dest, layer)
+        # the kernels read the layer's pages out of the whole pool; the
+        # rows reach it by whole pages, both pools in one call (by the
+        # row scatter where _step_meta made no page list)
+        if dest_pages is None:
+            cache_k = _kv_append(cache_k, k, token_dest, layer)
+            cache_v = _kv_append(cache_v, v, token_dest, layer)
+        else:
+            cache_k, cache_v = kv_append(cache_k, cache_v, k, v, dest_pages,
+                                         layer, block_size)
         attn = attend(cache_k, cache_v, layer=layer)
     else:
         # the XLA gather path (head 64, ALiBi, the CPU) works on the
@@ -483,16 +491,23 @@ def _embed_rows(params, token_ids, token_pos, cfg: TransformerConfig):
 
 
 def _step_meta(token_slot, token_pos, token_dest, block_tables, ctx_lens,
-               block_size: int):
+               block_size: int, cache_k, cfg: TransformerConfig):
     """What every block needs of the step's layout, once: the context
-    gather indices among it (ref: atom_builder)."""
+    gather indices (ref: atom_builder) and, on the kernels' path, the
+    rows' destinations by page for the append (None where the pools keep
+    the row scatter: the int8 cache, which no deployment runs, and a
+    shape whose pages do not fit the append's VMEM)."""
+    dest_pages = None
+    if not _is_quant_cache(cache_k) and attention_impl_name(
+            cfg, block_size, block_tables is not None) == "paged_pallas":
+        dest_pages = step_pages(cache_k, token_dest, block_size)
     nb = block_tables.shape[1]
     c = jnp.arange(nb * block_size, dtype=jnp.int32)
     ctx_idx = block_tables[:, c // block_size] * block_size + c % block_size  # [S+1, C]
     gather_idx = ctx_idx[token_slot]          # [T, C]
     token_ctx_len = ctx_lens[token_slot]      # [T]
     return (token_pos, token_dest, gather_idx, token_ctx_len, token_slot,
-            block_tables, block_size)
+            block_tables, block_size, dest_pages)
 
 
 def _ragged_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
@@ -513,7 +528,7 @@ def _ragged_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
                          else state_slot, token_pos)
     x = _embed_rows(params, token_ids, token_pos, cfg)
     meta = _step_meta(token_slot, token_pos, token_dest, block_tables,
-                      ctx_lens, block_size)
+                      ctx_lens, block_size, cache_k, cfg)
 
     moe_every = max(1, cfg.moe_layer_freq)
     layers = params["layers"]

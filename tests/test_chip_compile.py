@@ -327,6 +327,70 @@ def test_ragged_step_streams_the_qkv_weights(serving_step, model, t):
     assert not want - streamed, (want, streamed)
 
 
+@pytest.mark.parametrize("model,t", [("mistral-7b-l16", 256),
+                                     ("mistral-7b-l16", 16),
+                                     ("falcon-h1-34b-l6", 256)])
+def test_ragged_step_appends_both_pools_in_one_kernel(serving_step, model, t):
+    """The layer loop puts the step's K and V rows down through ONE
+    ``kv_append`` call, both pools aliased in place, and scatters into no
+    pool (two row scatters of 2,048 updates each took 16 % of the chip's
+    time in ``longdoc_closed``: PERF.md, PR 41).  One call and not one a
+    pool: half the kernels to lower and to launch."""
+    import re
+
+    _, dims, compiled = serving_step(model, t)
+    text = compiled.as_text()
+    comps = _computations(text)
+    body = next(lines for _, lines in comps.values() if any(
+        "custom-call(" in ln and "paged_qblock" in ln for ln in lines))
+    calls = [ln for ln in body if "custom-call(" in ln and "kv_append" in ln]
+    assert len(calls) == 1, calls
+    whole = "bf16[" + ",".join(map(str, dims)) + "]"
+    aliased = re.findall(r"\{(\d+)\}: \((\d+), \{\}\)", calls[0].split(
+        "output_to_operand_aliasing={", 1)[1].split(", frontend_attr", 1)[0])
+    assert len(aliased) == 2, calls[0][:600]
+    result = calls[0].split(" custom-call(", 1)[0]
+    assert result.count(whole) == 2, result
+    # nowhere in the program: a scatter, or a fusion that holds one, with
+    # a pool among its operands or as its result
+    scatters = [ln.strip()[:200] for _, lines in comps.values()
+                for ln in lines if " scatter(" in ln and whole in ln]
+    assert not scatters, scatters
+    assert sum("kv_append" in ln and "custom-call(" in ln
+               for ln in text.splitlines()) == 1
+
+
+@pytest.mark.parametrize("t,nkv,d,bs,dtype", [
+    (2048, 8, 128, 16, BF16), (256, 4, 256, 16, BF16), (64, 8, 128, 16, F32),
+    (256, 32, 128, 16, BF16), (256, 8, 128, 64, BF16),
+    (256, 8, 128, 128, BF16), (256, 32, 128, 128, BF16),
+    (16, 32, 128, 128, BF16), (256, 8, 128, 8, BF16)],
+    ids=["eight_row_blocks", "head_256", "float32_pool", "kv_heads_32",
+         "page_64", "page_128", "kv_heads_32_page_128",
+         "kv_heads_32_page_128_decode", "page_8"])
+def test_kv_append_other_shapes(chip, t, nkv, d, bs, dtype):
+    """What ``paged_attention.supports`` admits and no serving cell runs:
+    a token budget of several row blocks, a head of two lane tiles, a
+    float32 pool, as many kv heads as heads (gpt2, a llama without GQA),
+    pages of 64 and 128 rows (what the engine advises for long contexts)
+    and of 8: ``fit`` sizes the rows and pages a program holds to its
+    VMEM from these, and the compiler takes each."""
+    from deepspeed_tpu.ops.pallas import kv_append as ka
+
+    def fn(ck, cv, k, v, dest, layer):
+        return ka.kv_append(ck, cv, k, v, ka.step_pages(ck, dest, bs), layer,
+                            bs)
+
+    pool = chip((4, nkv, 64 * bs, d), dtype)
+    rows = chip((t, nkv, d), BF16)
+    text = jax.jit(fn, donate_argnums=(0, 1)).lower(
+        pool, pool, rows, rows, chip((t,), I32), chip((), I32)
+    ).compile().as_text()
+    call = next(ln for ln in text.splitlines()
+                if "custom-call(" in ln and "kv_append" in ln)
+    assert "output_to_operand_aliasing={{0}: (8, {}), {1}: (9, {})}" in call
+
+
 def test_latent_index_scores_at_dots3_widths(chip):
     """The indexer's score kernel: 64 heads of 128 over pages of 128
     rows, a full 1024-row step at a 32k context bucket (an output block

@@ -55,6 +55,12 @@ def test_ssd_phase_tiny(tpu_branches):
     assert out["silent"] < 1e-6 and out["y"] < 0.02
 
 
+def test_append_phase_tiny(tpu_branches):
+    out = chip_smoke.append_phase(layers=3, kv_heads=2, head_dim=128,
+                                  block_size=8, pages=40, chunk_rows=21)
+    assert out["same"] and out["rows"] == 36
+
+
 def test_index_phase_tiny(tpu_branches):
     out = chip_smoke.index_phase(heads=4, dim=16, block_size=8, blocks=8,
                                  chunk_rows=21)

@@ -419,7 +419,7 @@ def _sliced_and_restacked(params, cache_k, cache_v, token_ids, token_slot,
                             else state_slot, token_pos)
     x = m2._embed_rows(params, token_ids, token_pos, cfg)
     meta = m2._step_meta(token_slot, token_pos, token_dest, block_tables,
-                         ctx_lens, block_size)
+                         ctx_lens, block_size, cache_k, cfg)
     every = max(1, cfg.moe_layer_freq)
     # layer pairs where the window alternates, as the old loop scanned them
     period = 2 if cfg.alt_window else 1
@@ -527,6 +527,37 @@ def test_carried_pools_same_bits_as_sliced_and_restacked(
                               np.asarray(k_out[:, :, bs:].astype("float32")))
 
 
+def test_pools_with_no_append_kernel_keep_the_row_scatter(
+        interpreted_kernels, monkeypatch):
+    """Pools of which not a page fits the append kernel's VMEM (here: a
+    budget of nothing) are appended to by the row scatter, chosen from the
+    shape while the program is traced: the same logits and, from page 1
+    on, the same pools."""
+    import functools
+
+    import jax
+
+    from deepspeed_tpu.inference.v2 import model as m2
+    from deepspeed_tpu.ops.pallas import kv_append as ka
+
+    cfg, params, ck, cv, step, state, bs = _carry_case("head128_kernel")
+    run = lambda: jax.jit(functools.partial(
+        m2.ragged_forward, cfg=cfg, block_size=bs))(params, ck, cv, *step,
+                                                    state)
+    calls = []
+    kernel = m2.kv_append
+    monkeypatch.setattr(m2, "kv_append", lambda *a, **kw: (
+        calls.append(1), kernel(*a, **kw))[1])
+    want = run()
+    assert calls
+    del calls[:]
+    monkeypatch.setattr(ka, "_VMEM_BUDGET", 0)
+    got = run()
+    assert not calls
+    _assert_same_bits((got[0], got[1][:, :, bs:], got[2][:, :, bs:]),
+                      (want[0], want[1][:, :, bs:], want[2][:, :, bs:]))
+
+
 @pytest.mark.parametrize("name", ["llama", "mistral_window",
                                   "head128_kernel"])
 def test_verify_step_same_bits_as_sliced_and_restacked(name,
@@ -622,13 +653,16 @@ def _seed_attn_biases(params, seed=7):
 @pytest.fixture
 def tapped_qkv(monkeypatch):
     """What ``_ragged_layer`` hands on while a program is traced: q to
-    ``_paged_attention``, k then v to ``_kv_append``.  ``tapped_qkv(fn)``
-    calls ``fn(name, array)`` on each, inside the traced program."""
+    ``_paged_attention``; k and v to ``kv_append``, both in one call, on
+    the kernels' path, and k then v to ``_kv_append`` on the XLA path.
+    ``tapped_qkv(fn)`` calls ``fn(name, array)`` on each, inside the
+    traced program."""
     import itertools
 
     from deepspeed_tpu.inference.v2 import model as m2
 
-    attend, append = m2._paged_attention, m2._kv_append
+    attend, append, append_pages = (m2._paged_attention, m2._kv_append,
+                                    m2.kv_append)
     appended = itertools.cycle("kv")
 
     def install(fn):
@@ -640,8 +674,14 @@ def tapped_qkv(monkeypatch):
             fn(next(appended), x)
             return append(pool, x, *a, **kw)
 
+        def tap_append_pages(cache_k, cache_v, k, v, *a, **kw):
+            fn("k", k)
+            fn("v", v)
+            return append_pages(cache_k, cache_v, k, v, *a, **kw)
+
         monkeypatch.setattr(m2, "_paged_attention", tap_attend)
         monkeypatch.setattr(m2, "_kv_append", tap_append)
+        monkeypatch.setattr(m2, "kv_append", tap_append_pages)
 
     return install
 
@@ -649,13 +689,18 @@ def tapped_qkv(monkeypatch):
 # preset, programs: a mixer's recurrent slots are refused by verify, and its
 # decode loop is held above (test_decode_loop_same_bits_...)
 _QKV_MODELS = [("qwen2-tiny", "step"), ("qwen2-tiny", "verify"),
-               ("qwen2-tiny", "decode_loop"), ("falcon-h1-tiny", "step")]
+               ("qwen2-tiny", "decode_loop"), ("falcon-h1-tiny", "step"),
+               ("mistral-tiny", "step")]
+# the kernels' path (interpreted): heads of 128, the page-granular append
+_QKV_KERNELS = {"mistral-tiny": ({"hidden_size": 256, "num_heads": 2,
+                                  "num_kv_heads": 1}, "paged_pallas")}
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("preset,program", _QKV_MODELS)
 def test_qkv_behind_the_barrier_are_the_plain_products(preset, program, dtype,
-                                                       tapped_qkv):
+                                                       tapped_qkv,
+                                                       interpreted_kernels):
     """One block, biases seeded: the q, k, v that each ragged program's
     block attends with and appends are ``rope(norm(x) @ w + b)``, computed
     here outside any program (GQA + rope + bias; Falcon-H1's multipliers
@@ -673,8 +718,9 @@ def test_qkv_behind_the_barrier_are_the_plain_products(preset, program, dtype,
     from deepspeed_tpu.inference.v2 import model as m2
     from deepspeed_tpu.models.transformer import _norm
 
+    over, attention = _QKV_KERNELS.get(preset, ({}, None))
     cfg, params, ck, cv, step, state, bs = _carry_case(
-        (preset, {"num_layers": 1, "dtype": dtype}, False, None))
+        (preset, {"num_layers": 1, "dtype": dtype, **over}, False, attention))
     params = _seed_attn_biases(params)
     seen = {}
     tapped_qkv(lambda name, x: jax.debug.callback(

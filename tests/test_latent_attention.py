@@ -517,7 +517,7 @@ def _parent_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
     ssm_meta = m._ssm_meta(cfg, state, token_slot, token_pos)
     x = m._embed_rows(params, token_ids, token_pos, cfg)
     meta = m._step_meta(token_slot, token_pos, token_dest, block_tables,
-                        ctx_lens, block_size)
+                        ctx_lens, block_size, cache_k, cfg)
 
     def body(carry, scanned):
         h, ck, cv, ssm = carry
