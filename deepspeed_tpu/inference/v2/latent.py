@@ -33,7 +33,9 @@ What a sequence keeps, and where:
   before any row reads it.
 * **A multi-token-prediction module** (``mla.mtp_layers``) is one more
   full layer: its rows and keys are the LAST layer of ``cache_k`` and
-  ``cache_v``, in the same pages under the same tables.
+  ``cache_v``, in the same pages under the same tables.  Its caller
+  wraps it in the ``mtp`` stage; the layer inside keeps a full layer's
+  stage names beneath it.
 
 Attention is the ABSORBED form for prefill chunks and decode rows alike:
 ``q~_h = q_nope,h W_kb,h`` (``kv_lora_rank`` wide), score ``q~_h . c_kv +
@@ -208,7 +210,8 @@ def _attend(q, gather, idx, ok, w: LatentWidths):
 
     def block(args):
         qb, ib, mb = args
-        rows = gather(ib)                                   # [n, K, row]
+        with jax.named_scope("latent.gather"):
+            rows = gather(ib)                               # [n, K, row]
         s = jnp.einsum("nhd,nkd->nhk", qb, rows,
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(mb[:, None, :], s, NEG_INF)
@@ -390,20 +393,31 @@ def _full_layer(x, ln1, p, cache_k, cache_v, layer, meta,
     (token_pos, token_dest, token_slot, _, block_tables, ctx_lens,
      block_size) = meta
     m, w = cfg.mla, cfg.mla.full
-    h = _rms(x, ln1, cfg)
-    c_q, q, row = _project(h, p, w, token_pos, cfg)
-    cache_k = cache_k.at[layer, token_dest].set(row.astype(cache_k.dtype))
+    with jax.named_scope("latent.down"):
+        h = _rms(x, ln1, cfg)
+        c_q, q, row = _project(h, p, w, token_pos, cfg)
+    with jax.named_scope("attn.append"):
+        cache_k = cache_k.at[layer, token_dest].set(
+            row.astype(cache_k.dtype))
 
-    q_i, k_i, w_i = _index_inputs(h, c_q, p, token_pos, cfg)
-    cache_v = cache_v.at[layer, token_dest].set(k_i.astype(cache_v.dtype))
+    with jax.named_scope("latent.index"):
+        q_i, k_i, w_i = _index_inputs(h, c_q, p, token_pos, cfg)
+    with jax.named_scope("attn.append"):
+        cache_v = cache_v.at[layer, token_dest].set(
+            k_i.astype(cache_v.dtype))
 
-    scores = resolve("indexer", indexer_impl_name(cfg))(
-        q_i, w_i, cache_v, layer, block_tables, token_slot, token_pos,
-        ctx_lens[token_slot], block_size=block_size)
-    sel, ok = select_keys(scores, m.index_topk)
-    rows = _page_rows(block_tables[token_slot], sel, block_size)
-    ctx = _attend(q, lambda i: cache_k[layer, i], rows, ok, w)
-    return _finish(x, h, ctx, p, w), cache_k, cache_v
+    with jax.named_scope("latent.index"):
+        scores = resolve("indexer", indexer_impl_name(cfg))(
+            q_i, w_i, cache_v, layer, block_tables, token_slot, token_pos,
+            ctx_lens[token_slot], block_size=block_size)
+    with jax.named_scope("latent.select"):
+        sel, ok = select_keys(scores, m.index_topk)
+    with jax.named_scope("latent.gather"):
+        rows = _page_rows(block_tables[token_slot], sel, block_size)
+    with jax.named_scope("latent.read"):
+        ctx = _attend(q, lambda i: cache_k[layer, i], rows, ok, w)
+    with jax.named_scope("attn.out"):
+        return _finish(x, h, ctx, p, w), cache_k, cache_v
 
 
 WINDOW_BLOCK = 128      # rows of a step that may share one read of the ring
@@ -419,10 +433,12 @@ def _window_layer(x, ln1, p, ring, layer, meta, cfg: TransformerConfig):
     token_pos, _, _, ring_slot = meta[:4]
     w, window = cfg.mla.window, cfg.mla.sliding_window
     size, t = ring.shape[2], x.shape[0]
-    h = _rms(x, ln1, cfg)
-    _, q, row = _project(h, p, w, token_pos, cfg)
-    ring = ring.at[layer, ring_slot, token_pos % size].set(
-        row.astype(ring.dtype))
+    with jax.named_scope("latent.down"):
+        h = _rms(x, ln1, cfg)
+        _, q, row = _project(h, p, w, token_pos, cfg)
+    with jax.named_scope("latent.window"):
+        ring = ring.at[layer, ring_slot, token_pos % size].set(
+            row.astype(ring.dtype))
     scale = 1.0 / math.sqrt(w.qk_head_dim)
     rank = w.kv_lora_rank
     n = min(WINDOW_BLOCK, t)
@@ -457,8 +473,10 @@ def _window_layer(x, ln1, p, ring, layer, meta, cfg: TransformerConfig):
             pos == pos[0] + jnp.arange(n, dtype=jnp.int32))
         return lax.cond(together, one_run, each_row, args)
 
-    ctx = _map_blocks(block, (q, ring_slot, token_pos), n)
-    return _finish(x, h, ctx, p, w), ring
+    with jax.named_scope("latent.window"):
+        ctx = _map_blocks(block, (q, ring_slot, token_pos), n)
+    with jax.named_scope("attn.out"):
+        return _finish(x, h, ctx, p, w), ring
 
 
 def _feed_forward(x, ln2, stack, i, has_experts: bool,
@@ -466,13 +484,18 @@ def _feed_forward(x, ln2, stack, i, has_experts: bool,
     """The ``i``-th feed-forward of its kind, ``stack`` holding the kind's
     layers: dense, or this program's routed experts and the shared one."""
     m = cfg.mla
-    h = _rms(x, ln2, cfg)
     if not has_experts:
-        return x + _mlp_block(h, _at(stack, i), cfg)
-    return x + moe_forward_held(
+        with jax.named_scope("mlp"):
+            return x + _mlp_block(_rms(x, ln2, cfg), _at(stack, i), cfg)
+    with jax.named_scope("moe.router"):
+        h = _rms(x, ln2, cfg)
+    routed = moe_forward_held(
         h, stack, i, top_k=m.num_experts_per_tok, first=m.experts_held[0],
-        scale=m.routed_scaling_factor
-    ) + _mlp_block(h, _at(stack["shared"], i), cfg)
+        scale=m.routed_scaling_factor)
+    with jax.named_scope("moe.combine"):
+        x = x + routed
+    with jax.named_scope("moe.shared"):
+        return x + _mlp_block(h, _at(stack["shared"], i), cfg)
 
 
 def latent_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
@@ -498,7 +521,8 @@ def latent_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
             "(latent.new_cache(...)); a caller that makes per-head pages "
             "(inference.kv_generate) cannot run it")
     layers = params["layers"]
-    x = params["embed"]["tokens"].astype(cfg.dtype)[token_ids]
+    with jax.named_scope("embed"):
+        x = params["embed"]["tokens"].astype(cfg.dtype)[token_ids]
     meta = (token_pos, token_dest, token_slot,
             token_slot if state_slot is None else state_slot, block_tables,
             ctx_lens, block_size)
@@ -523,25 +547,27 @@ def latent_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
     kinds = cfg.mla.kinds(cfg.num_layers)
     carry = (x, cache_k, cache_v, state["win"] if rings else None)
     count = {"layer": 0, "full": 0, "window": 0, "mlp": 0, "moe": 0}
-    i = 0
-    while i < len(kinds):
-        n = 1
-        while i + n < len(kinds) and kinds[i + n] == kinds[i]:
-            n += 1
-        start, kind = dict(count), kinds[i]
-        if n == 1:
-            carry, _ = block(carry, 0, start, kind)
-        else:
-            carry, _ = lax.scan(
-                lambda c, j, s=start, k=kind: block(c, j, s, k), carry,
-                jnp.arange(n, dtype=jnp.int32))
-        count["layer"] += n
-        count["full" if kind[0] else "window"] += n
-        count["moe" if kind[1] else "mlp"] += n
-        i += n
+    with jax.named_scope("layers"):
+        i = 0
+        while i < len(kinds):
+            n = 1
+            while i + n < len(kinds) and kinds[i + n] == kinds[i]:
+                n += 1
+            start, kind = dict(count), kinds[i]
+            if n == 1:
+                carry, _ = block(carry, 0, start, kind)
+            else:
+                carry, _ = lax.scan(
+                    lambda c, j, s=start, k=kind: block(c, j, s, k), carry,
+                    jnp.arange(n, dtype=jnp.int32))
+            count["layer"] += n
+            count["full" if kind[0] else "window"] += n
+            count["moe" if kind[1] else "mlp"] += n
+            i += n
     x, cache_k, cache_v, ring = carry
-    return (_rms(x, params["final_norm"]["scale"], cfg),
-            cache_k, cache_v, {"win": ring} if rings else None)
+    with jax.named_scope("head"):
+        x = _rms(x, params["final_norm"]["scale"], cfg)
+    return x, cache_k, cache_v, {"win": ring} if rings else None
 
 
 def mtp_rows(params, x, next_ids, cache_k, cache_v, token_slot, token_pos,
@@ -556,14 +582,16 @@ def mtp_rows(params, x, next_ids, cache_k, cache_v, token_slot, token_pos,
     cache_k', cache_v')``; the trunk's head makes of ``hidden[i]`` the
     logits of the token after ``next_ids[i]``."""
     mp, dt = params["mtp"], cfg.dtype
-    emb = params["embed"]["tokens"].astype(dt)[next_ids]
-    u = jnp.concatenate([_rms(emb, mp["enorm"]["scale"], cfg),
-                         _rms(x, mp["hnorm"]["scale"], cfg)],
-                        -1) @ mp["eh_proj"].astype(dt)
+    with jax.named_scope("embed"):
+        emb = params["embed"]["tokens"].astype(dt)[next_ids]
+        u = jnp.concatenate([_rms(emb, mp["enorm"]["scale"], cfg),
+                             _rms(x, mp["hnorm"]["scale"], cfg)],
+                            -1) @ mp["eh_proj"].astype(dt)
     meta = (token_pos, token_dest, token_slot, token_slot, block_tables,
             ctx_lens, block_size)
     u, cache_k, cache_v = _full_layer(
         u, mp["attn_norm"]["scale"], mp["full"], cache_k, cache_v,
         cache_k.shape[0] - 1, meta, cfg)
     u = _feed_forward(u, mp["ffn_norm"]["scale"], mp["moe"], 0, True, cfg)
-    return _rms(u, mp["norm"]["scale"], cfg), cache_k, cache_v
+    with jax.named_scope("head"):
+        return _rms(u, mp["norm"]["scale"], cfg), cache_k, cache_v

@@ -275,52 +275,60 @@ def _ragged_mixer(h, p, state, ssm_meta, cfg: TransformerConfig):
     k = m.conv_kernel
     pad = state["conv"].shape[0] - 1
 
-    mup = jnp.concatenate([jnp.full((n,), v, dt_) for n, v in
-                           zip(m.part_sizes(), m.ssm_multipliers)])
-    proj = ((h * m.ssm_in_multiplier) @ p["in_proj"].astype(dt_)) * mup
-    z, xbc, dt = jnp.split(proj, [m.d_ssm, m.d_ssm + m.conv_dim], axis=-1)
+    with jax.named_scope("ssm.in"):
+        mup = jnp.concatenate([jnp.full((n,), v, dt_) for n, v in
+                               zip(m.part_sizes(), m.ssm_multipliers)])
+        proj = ((h * m.ssm_in_multiplier) @ p["in_proj"].astype(dt_)) * mup
+        z, xbc, dt = jnp.split(proj, [m.d_ssm, m.d_ssm + m.conv_dim],
+                               axis=-1)
 
-    # depthwise causal convolution over each run: the input d rows back is
-    # the row d above where the run reaches that far, else the slot's tail
-    tail = jnp.where(from_zero[:, None, None], 0,
-                     state["conv"][slot]).astype(dt_)       # [T, k-1, C]
-    in_run = jnp.arange(t, dtype=jnp.int32) - run_start
-    back = []                                   # d = k-1 .. 1 rows back
-    for d in range(k - 1, 0, -1):
-        v = jnp.roll(xbc, d, axis=0)
-        for j in range(d):                      # the run is j rows old
-            v = jnp.where((in_run == j)[:, None], tail[:, k - 1 - d + j], v)
-        back.append(v)
-    w = p["conv_w"].astype(f32)                              # [C, k]
-    conv = xbc.astype(f32) * w[:, k - 1]
-    for j, v in enumerate(back):
-        conv = conv + v.astype(f32) * w[:, j]
-    if "conv_b" in p:
-        conv = conv + p["conv_b"].astype(f32)
-    new_tail = jnp.stack(back[1:] + [xbc], axis=1)           # [T, k-1, C]
-    conv_state = state["conv"].at[jnp.where(is_last, slot, pad)].set(
-        new_tail.astype(state["conv"].dtype))
-    xbc = jax.nn.silu(conv).astype(dt_)
+    with jax.named_scope("ssm.conv"):
+        # depthwise causal convolution over each run: the input d rows back
+        # is the row d above where the run reaches that far, else the
+        # slot's tail
+        tail = jnp.where(from_zero[:, None, None], 0,
+                         state["conv"][slot]).astype(dt_)   # [T, k-1, C]
+        in_run = jnp.arange(t, dtype=jnp.int32) - run_start
+        back = []                               # d = k-1 .. 1 rows back
+        for d in range(k - 1, 0, -1):
+            v = jnp.roll(xbc, d, axis=0)
+            for j in range(d):                  # the run is j rows old
+                v = jnp.where((in_run == j)[:, None],
+                              tail[:, k - 1 - d + j], v)
+            back.append(v)
+        w = p["conv_w"].astype(f32)                          # [C, k]
+        conv = xbc.astype(f32) * w[:, k - 1]
+        for j, v in enumerate(back):
+            conv = conv + v.astype(f32) * w[:, j]
+        if "conv_b" in p:
+            conv = conv + p["conv_b"].astype(f32)
+        new_tail = jnp.stack(back[1:] + [xbc], axis=1)       # [T, k-1, C]
+        conv_state = state["conv"].at[jnp.where(is_last, slot, pad)].set(
+            new_tail.astype(state["conv"].dtype))
+        xbc = jax.nn.silu(conv).astype(dt_)
 
-    gn = m.n_groups * m.state_size
-    x = xbc[:, :m.d_ssm].reshape(t, m.num_heads, m.head_dim)
-    b = xbc[:, m.d_ssm:m.d_ssm + gn].reshape(t, m.n_groups, m.state_size)
-    c = xbc[:, m.d_ssm + gn:].reshape(t, m.n_groups, m.state_size)
-    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
-    a = -jnp.exp(p["A_log"].astype(f32))
-    y, ssm_state = resolve("ssm", ssm_impl_name(cfg))(
-        x, dt, a, b, c, state["ssm"], slot, token_pos, layer=state["layer"],
-        chunk=m.chunk_size)
-    y = y + p["D"].astype(f32)[None, :, None] * x.astype(f32)
+    with jax.named_scope("ssm.scan"):
+        gn = m.n_groups * m.state_size
+        x = xbc[:, :m.d_ssm].reshape(t, m.num_heads, m.head_dim)
+        b = xbc[:, m.d_ssm:m.d_ssm + gn].reshape(t, m.n_groups, m.state_size)
+        c = xbc[:, m.d_ssm + gn:].reshape(t, m.n_groups, m.state_size)
+        dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+        a = -jnp.exp(p["A_log"].astype(f32))
+        y, ssm_state = resolve("ssm", ssm_impl_name(cfg))(
+            x, dt, a, b, c, state["ssm"], slot, token_pos,
+            layer=state["layer"], chunk=m.chunk_size)
+        y = y + p["D"].astype(f32)[None, :, None] * x.astype(f32)
 
-    # gated RMSNorm, the gate first, the mean square over each group
-    y = y.reshape(t, m.d_ssm) * jax.nn.silu(z.astype(f32))
-    yg = y.reshape(t, m.n_groups, -1)
-    yg = yg * lax.rsqrt(jnp.mean(jnp.square(yg), axis=-1, keepdims=True)
-                        + cfg.layernorm_eps)
-    y = (yg.reshape(t, m.d_ssm) * p["norm"].astype(f32)).astype(dt_)
-    return (y @ p["out_proj"].astype(dt_),
-            {"ssm": ssm_state, "conv": conv_state, "layer": state["layer"]})
+    with jax.named_scope("ssm.out"):
+        # gated RMSNorm, the gate first, the mean square over each group
+        y = y.reshape(t, m.d_ssm) * jax.nn.silu(z.astype(f32))
+        yg = y.reshape(t, m.n_groups, -1)
+        yg = yg * lax.rsqrt(jnp.mean(jnp.square(yg), axis=-1, keepdims=True)
+                            + cfg.layernorm_eps)
+        y = (yg.reshape(t, m.d_ssm) * p["norm"].astype(f32)).astype(dt_)
+        return (y @ p["out_proj"].astype(dt_),
+                {"ssm": ssm_state, "conv": conv_state,
+                 "layer": state["layer"]})
 
 
 def _ssm_meta(cfg: TransformerConfig, state, token_slot, token_pos):
@@ -336,7 +344,8 @@ def _ssm_meta(cfg: TransformerConfig, state, token_slot, token_pos):
         raise NotImplementedError(
             "an SSM mixer beside attention is served in the plain "
             "sequential block only (no MoE, alt_window or parallel_block)")
-    return (token_slot, token_pos) + run_layout(token_slot, token_pos)
+    with jax.named_scope("ssm.scan"):
+        return (token_slot, token_pos) + run_layout(token_slot, token_pos)
 
 
 def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
@@ -353,9 +362,6 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
     nh, nkv, d = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
     dt = x.dtype
 
-    h = _norm(x, lp["ln1"], cfg)
-    h_attn = h * mixer.attention_in_multiplier if mixer else h
-
     def proj(w, b_):
         # a value of its own, [T, heads*d] rows-major: with the heads'
         # reshape folded into the product the TPU's compiler wants the
@@ -369,16 +375,19 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
             h_attn, w.astype(dt), preferred_element_type=jnp.float32))
         return y + b_.astype(dt).astype(y.dtype) if b_ is not None else y
 
-    q = proj(lp["attn"]["wq"], lp["attn"].get("bq")).reshape(t, nh, d)
-    k = proj(lp["attn"]["wk"], lp["attn"].get("bk")).reshape(t, nkv, d)
-    v = proj(lp["attn"]["wv"], lp["attn"].get("bv")).reshape(t, nkv, d)
-    if mixer:
-        # the multiplier as the model's dtype holds it (it scaled a dt k)
-        k = k * jnp.asarray(mixer.key_multiplier, dt).astype(k.dtype)
-    if cfg.use_rope:
-        q = _rope_tok(q, token_pos, cfg)
-        k = _rope_tok(k, token_pos, cfg)
-    q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
+    with jax.named_scope("attn.qkv"):
+        h = _norm(x, lp["ln1"], cfg)
+        h_attn = h * mixer.attention_in_multiplier if mixer else h
+        q = proj(lp["attn"]["wq"], lp["attn"].get("bq")).reshape(t, nh, d)
+        k = proj(lp["attn"]["wk"], lp["attn"].get("bk")).reshape(t, nkv, d)
+        v = proj(lp["attn"]["wv"], lp["attn"].get("bv")).reshape(t, nkv, d)
+        if mixer:
+            # the multiplier as the model's dtype holds it (it scaled a dt k)
+            k = k * jnp.asarray(mixer.key_multiplier, dt).astype(k.dtype)
+        if cfg.use_rope:
+            q = _rope_tok(q, token_pos, cfg)
+            k = _rope_tok(k, token_pos, cfg)
+        q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
 
     # Write this step's KV to its pages (padding tokens target page 0 =
     # garbage, so no mask needed; ref: linear_blocked_kv_copy). A layer's
@@ -393,49 +402,62 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
         # the kernels read the layer's pages out of the whole pool; the
         # rows reach it by whole pages, both pools in one call (by the
         # row scatter where _step_meta made no page list)
-        if dest_pages is None:
-            cache_k = _kv_append(cache_k, k, token_dest, layer)
-            cache_v = _kv_append(cache_v, v, token_dest, layer)
-        else:
-            cache_k, cache_v = kv_append(cache_k, cache_v, k, v, dest_pages,
-                                         layer, block_size)
-        attn = attend(cache_k, cache_v, layer=layer)
+        with jax.named_scope("attn.append"):
+            if dest_pages is None:
+                cache_k = _kv_append(cache_k, k, token_dest, layer)
+                cache_v = _kv_append(cache_v, v, token_dest, layer)
+            else:
+                cache_k, cache_v = kv_append(cache_k, cache_v, k, v,
+                                             dest_pages, layer, block_size)
+        with jax.named_scope("attn.read"):
+            attn = attend(cache_k, cache_v, layer=layer)
     else:
         # the XLA gather path (head 64, ALiBi, the CPU) works on the
         # layer's pages as a value of their own and puts them back: one
         # layer read and written where the kernels touch the step's rows
         # (scatter and gather into the whole pool at once make the TPU's
         # compiler relay the pool out between them, for every layer)
-        k_pages = _kv_append(jax.tree.map(lambda c: c[layer], cache_k), k,
-                             token_dest)
-        v_pages = _kv_append(jax.tree.map(lambda c: c[layer], cache_v), v,
-                             token_dest)
-        attn = attend(k_pages, v_pages)
-        cache_k, cache_v = jax.tree.map(
-            lambda c, pages: c.at[layer].set(pages),
-            (cache_k, cache_v), (k_pages, v_pages))
-    attn = attn.reshape(t, nh * d) @ lp["attn"]["wo"].astype(dt)
-    if lp["attn"].get("bo") is not None:
-        attn = attn + lp["attn"]["bo"].astype(dt)
+        with jax.named_scope("attn.append"):
+            k_pages = _kv_append(jax.tree.map(lambda c: c[layer], cache_k),
+                                 k, token_dest)
+            v_pages = _kv_append(jax.tree.map(lambda c: c[layer], cache_v),
+                                 v, token_dest)
+        with jax.named_scope("attn.read"):
+            attn = attend(k_pages, v_pages)
+        with jax.named_scope("attn.append"):
+            cache_k, cache_v = jax.tree.map(
+                lambda c, pages: c.at[layer].set(pages),
+                (cache_k, cache_v), (k_pages, v_pages))
+    with jax.named_scope("attn.out"):
+        attn = attn.reshape(t, nh * d) @ lp["attn"]["wo"].astype(dt)
+        if lp["attn"].get("bo") is not None:
+            attn = attn + lp["attn"]["bo"].astype(dt)
     if mixer:
         # the mixer reads the same normed input, beside attention
         mix, state = _ragged_mixer(h, lp["ssm"], state, ssm_meta, cfg)
-        attn = (attn * mixer.attention_out_multiplier
-                + mix * mixer.ssm_out_multiplier)
+        with jax.named_scope("attn.out"):
+            attn = (attn * mixer.attention_out_multiplier
+                    + mix * mixer.ssm_out_multiplier)
 
     if cfg.parallel_block:
         # Falcon/Phi: attention and MLP read the shared input norm;
         # Falcon-40B/GPT-NeoX (parallel_norms): the MLP gets its own
         # ln2 on the same residual input (HF use_parallel_residual)
-        h_mlp = _norm(x, lp["ln2"], cfg) if cfg.parallel_norms else h
-        return (x + attn + _mlp_block(h_mlp, lp["mlp"], cfg), cache_k,
-                cache_v, state)
+        with jax.named_scope("mlp"):
+            h_mlp = _norm(x, lp["ln2"], cfg) if cfg.parallel_norms else h
+            return (x + attn + _mlp_block(h_mlp, lp["mlp"], cfg), cache_k,
+                    cache_v, state)
 
-    x = x + attn
+    with jax.named_scope("attn.out"):
+        x = x + attn
 
-    h2 = _norm(x, lp["ln2"], cfg)
-    if "moe" not in lp:
-        return x + _mlp_block(h2, lp["mlp"], cfg), cache_k, cache_v, state
+    experts = "moe" in lp
+    with jax.named_scope("moe.router" if experts else "mlp"):
+        h2 = _norm(x, lp["ln2"], cfg)
+    if not experts:
+        with jax.named_scope("mlp"):
+            return (x + _mlp_block(h2, lp["mlp"], cfg), cache_k, cache_v,
+                    state)
 
     from deepspeed_tpu.moe.sharded_moe import moe_forward, moe_forward_ep
     from deepspeed_tpu.parallel.topology import get_topology
@@ -466,13 +488,15 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
         return out[0]
 
     def dense_branch(hh):
-        return _mlp_block(hh, lp["mlp"], cfg)
+        with jax.named_scope("mlp"):
+            return _mlp_block(hh, lp["mlp"], cfg)
 
     if isinstance(layer_is_moe, bool):
         y = moe_branch(h2) if layer_is_moe else dense_branch(h2)
     else:
         y = lax.cond(layer_is_moe, moe_branch, dense_branch, h2)
-    return x + y, cache_k, cache_v, state
+    with jax.named_scope("mlp" if layer_is_moe is False else "moe.combine"):
+        return x + y, cache_k, cache_v, state
 
 
 def _embed_rows(params, token_ids, token_pos, cfg: TransformerConfig):
@@ -500,12 +524,15 @@ def _step_meta(token_slot, token_pos, token_dest, block_tables, ctx_lens,
     dest_pages = None
     if not _is_quant_cache(cache_k) and attention_impl_name(
             cfg, block_size, block_tables is not None) == "paged_pallas":
-        dest_pages = step_pages(cache_k, token_dest, block_size)
-    nb = block_tables.shape[1]
-    c = jnp.arange(nb * block_size, dtype=jnp.int32)
-    ctx_idx = block_tables[:, c // block_size] * block_size + c % block_size  # [S+1, C]
-    gather_idx = ctx_idx[token_slot]          # [T, C]
-    token_ctx_len = ctx_lens[token_slot]      # [T]
+        with jax.named_scope("attn.append"):
+            dest_pages = step_pages(cache_k, token_dest, block_size)
+    with jax.named_scope("attn.read"):
+        nb = block_tables.shape[1]
+        c = jnp.arange(nb * block_size, dtype=jnp.int32)
+        ctx_idx = (block_tables[:, c // block_size] * block_size
+                   + c % block_size)              # [S+1, C]
+        gather_idx = ctx_idx[token_slot]          # [T, C]
+        token_ctx_len = ctx_lens[token_slot]      # [T]
     return (token_pos, token_dest, gather_idx, token_ctx_len, token_slot,
             block_tables, block_size, dest_pages)
 
@@ -526,7 +553,8 @@ def _ragged_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
                             state, cfg, block_size, state_slot)
     ssm_meta = _ssm_meta(cfg, state, token_slot if state_slot is None
                          else state_slot, token_pos)
-    x = _embed_rows(params, token_ids, token_pos, cfg)
+    with jax.named_scope("embed"):
+        x = _embed_rows(params, token_ids, token_pos, cfg)
     meta = _step_meta(token_slot, token_pos, token_dest, block_tables,
                       ctx_lens, block_size, cache_k, cfg)
 
@@ -573,13 +601,15 @@ def _ragged_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
 
     # the convolution's tails (a few MiB) are sliced and stacked by the
     # scan; the pools and the recurrent slots are not
-    (x, cache_k, cache_v, ssm), conv = lax.scan(
-        body, (x, cache_k, cache_v, state["ssm"] if cfg.ssm else None),
-        (layers, jnp.arange(0, cfg.num_layers, period),
-         state["conv"] if cfg.ssm else None))
+    with jax.named_scope("layers"):
+        (x, cache_k, cache_v, ssm), conv = lax.scan(
+            body, (x, cache_k, cache_v, state["ssm"] if cfg.ssm else None),
+            (layers, jnp.arange(0, cfg.num_layers, period),
+             state["conv"] if cfg.ssm else None))
     if cfg.ssm:
         state = {"ssm": ssm, "conv": conv}
-    return _norm(x, params["final_norm"], cfg), cache_k, cache_v, state
+    with jax.named_scope("head"):
+        return _norm(x, params["final_norm"], cfg), cache_k, cache_v, state
 
 
 def _lm_head(x, params, cfg: TransformerConfig):
@@ -607,14 +637,15 @@ def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
         params, cache_k, cache_v, token_ids, token_slot, token_pos,
         token_dest, block_tables, ctx_lens, state, cfg, block_size,
         state_slot=state_slot)
-    logits = _lm_head(x[logits_idx], params, cfg)  # ref: logits_gather
-    if cfg.ssm:
-        logits = logits * cfg.ssm.lm_head_multiplier
-        return logits.astype(jnp.float32), cache_k, cache_v, state
+    with jax.named_scope("head"):
+        logits = _lm_head(x[logits_idx], params, cfg)  # ref: logits_gather
+        if cfg.ssm:
+            logits = logits * cfg.ssm.lm_head_multiplier
+        logits = logits.astype(jnp.float32)
     if state is not None:
-        # a latent model's window rings
-        return logits.astype(jnp.float32), cache_k, cache_v, state
-    return logits.astype(jnp.float32), cache_k, cache_v
+        # a mixer's recurrent slots, a latent model's window rings
+        return logits, cache_k, cache_v, state
+    return logits, cache_k, cache_v
 
 
 # The engine's step programs: this module's forwards with the seven index
@@ -636,7 +667,8 @@ def ragged_step_sampled(params, cache_k, cache_v, index, key, temperature,
     logits, *carried = ragged_forward(
         params, cache_k, cache_v, *index.arrays(), state, cfg=cfg,
         block_size=block_size)
-    nxt = sample_tokens(logits, key, temperature, greedy, top_k, top_p)
+    with jax.named_scope("head"):
+        nxt = sample_tokens(logits, key, temperature, greedy, top_k, top_p)
     return (nxt, *carried)
 
 
@@ -697,8 +729,10 @@ def ragged_forward_verify(params, cache_k, cache_v, token_ids, token_slot,
     x, cache_k, cache_v, _ = _ragged_trunk(
         params, cache_k, cache_v, token_ids, token_slot, token_pos,
         token_dest, block_tables, ctx_lens, None, cfg, block_size)
-    logits = _lm_head(x, params, cfg)
-    nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+    with jax.named_scope("head"):
+        logits = _lm_head(x, params, cfg)
+        nxt = jnp.argmax(logits.astype(jnp.float32),
+                         axis=-1).astype(jnp.int32)
     return nxt, cache_k, cache_v
 
 
@@ -736,24 +770,29 @@ def ragged_draft_step(params, cache_k, cache_v, index, *,
         token_dest, block_tables, ctx_lens, None, cfg, block_size)
 
     def argmax_at(hidden, rows):
-        return jnp.argmax(_lm_head(hidden[rows], params, cfg).astype(
-            jnp.float32), axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            return jnp.argmax(_lm_head(hidden[rows], params, cfg).astype(
+                jnp.float32), axis=-1).astype(jnp.int32)
 
     slots = last.shape[0]
-    first = jnp.maximum(last - 1, 0)
+    with jax.named_scope("verify"):
+        first = jnp.maximum(last - 1, 0)
     both = argmax_at(x, jnp.concatenate([first, last]))
-    a_first, a_last = both[:slots], both[slots:]
-    verify = verify > 0
-    accepted = verify & (token_ids[last] == a_first)
-    own = jnp.where(jnp.arange(token_ids.shape[0]) == last[token_slot],
-                    a_last[token_slot], a_first[token_slot])
-    hidden, cache_k, cache_v = mtp_rows(
-        params, x, jnp.where(token_next >= 0, token_next, own), cache_k,
-        cache_v, token_slot, token_pos, token_dest, block_tables, ctx_lens,
-        cfg, block_size)
-    draft = argmax_at(hidden, jnp.where(verify & ~accepted, first, last))
-    out = jnp.stack([jnp.where(verify, a_first, a_last), a_last,
-                     accepted.astype(jnp.int32), draft])
+    with jax.named_scope("verify"):
+        a_first, a_last = both[:slots], both[slots:]
+        verify = verify > 0
+        accepted = verify & (token_ids[last] == a_first)
+        own = jnp.where(jnp.arange(token_ids.shape[0]) == last[token_slot],
+                        a_last[token_slot], a_first[token_slot])
+        next_ids = jnp.where(token_next >= 0, token_next, own)
+    with jax.named_scope("mtp"):
+        hidden, cache_k, cache_v = mtp_rows(
+            params, x, next_ids, cache_k, cache_v, token_slot, token_pos,
+            token_dest, block_tables, ctx_lens, cfg, block_size)
+        draft = argmax_at(hidden, jnp.where(verify & ~accepted, first, last))
+    with jax.named_scope("verify"):
+        out = jnp.stack([jnp.where(verify, a_first, a_last), a_last,
+                         accepted.astype(jnp.int32), draft])
     return out, cache_k, cache_v
 
 
@@ -816,7 +855,8 @@ def ragged_forward_sampled(params, cache_k, cache_v, token_ids, token_slot,
         params, cache_k, cache_v, token_ids, token_slot, token_pos,
         token_dest, block_tables, ctx_lens, logits_idx, state, cfg=cfg,
         block_size=block_size)
-    nxt = sample_tokens(logits, key, temperature, greedy, top_k, top_p)
+    with jax.named_scope("head"):
+        nxt = sample_tokens(logits, key, temperature, greedy, top_k, top_p)
     return (nxt, *carried)
 
 
@@ -850,17 +890,19 @@ def ragged_decode_loop(params, cache_k, cache_v, tokens0, ctx_lens0,
     def step(carry, step_key):
         tokens, ctx_lens, ck, cv, st = carry
         pos = ctx_lens  # 0-based position of the incoming token
-        dest = block_tables[slots, pos // block_size] * block_size \
-            + pos % block_size
-        dest = jnp.where(active, dest, 0)  # inactive → garbage page 0
-        ctx_after = ctx_lens + act_i
+        with jax.named_scope("attn.append"):
+            dest = block_tables[slots, pos // block_size] * block_size \
+                + pos % block_size
+            dest = jnp.where(active, dest, 0)  # inactive → garbage page 0
+            ctx_after = ctx_lens + act_i
         logits, ck, cv, *st = ragged_forward(
             params, ck, cv, tokens, slots, pos, dest, block_tables,
             ctx_after, slots, st, cfg=cfg, block_size=block_size,
             state_slot=state_slot)
-        nxt = sample_tokens(logits, step_key, temperature, greedy, top_k,
-                            top_p)
-        nxt = jnp.where(active, nxt, 0)
+        with jax.named_scope("head"):
+            nxt = sample_tokens(logits, step_key, temperature, greedy, top_k,
+                                top_p)
+            nxt = jnp.where(active, nxt, 0)
         return (nxt, ctx_after, ck, cv, st[0] if st else None), nxt
 
     keys = jax.random.split(key, n_steps)

@@ -187,10 +187,11 @@ def _resolve_dispatch(cfg, t: int, e: int, c: int) -> str:
 
 def _dispatch_combine_einsum(tokens, logits, cfg, dt, select_logits=None):
     """Einsum formulation: returns (dispatched [E,C,H], combine_fn, aux)."""
-    l_aux, combine, dispatch = top_k_gating(
-        logits, cfg.top_k, cfg.capacity_factor,
-        norm_topk=getattr(cfg, "moe_norm_topk", False),
-        select_logits=select_logits)
+    with jax.named_scope("moe.router"):
+        l_aux, combine, dispatch = top_k_gating(
+            logits, cfg.top_k, cfg.capacity_factor,
+            norm_topk=getattr(cfg, "moe_norm_topk", False),
+            select_logits=select_logits)
     dispatched = jnp.einsum("tec,th->ech", dispatch.astype(dt), tokens)
 
     def combine_fn(expert_out):
@@ -204,10 +205,11 @@ def _dispatch_combine_sorted(tokens, logits, cfg, dt, select_logits=None):
     t, h = tokens.shape
     e = logits.shape[1]
     k = cfg.top_k
-    l_aux, slot, gate, c = top_k_gating_sorted(
-        logits, k, cfg.capacity_factor,
-        norm_topk=getattr(cfg, "moe_norm_topk", False),
-        select_logits=select_logits)
+    with jax.named_scope("moe.router"):
+        l_aux, slot, gate, c = top_k_gating_sorted(
+            logits, k, cfg.capacity_factor,
+            norm_topk=getattr(cfg, "moe_norm_topk", False),
+            select_logits=select_logits)
     token_of = jnp.tile(jnp.arange(t, dtype=jnp.int32), k)     # choice-major
     # slot → source token (E·C+1 wide so the trash slot can't clip-corrupt;
     # empty slots keep the out-of-range sentinel t, gathered as zeros below)
@@ -272,20 +274,26 @@ def moe_forward(x: jnp.ndarray, p: Dict[str, jnp.ndarray], cfg,
 
     rt = jnp.float32 if op_fp32(cfg, "router") else dt
     policy = _validate_noisy_policy(cfg)
-    gate_in = _jitter_tokens(tokens, noise_key) \
-        if noise_key is not None and policy == "Jitter" else tokens
-    logits = (gate_in.astype(rt) @ p["router"].astype(rt)).astype(jnp.float32)
-    select = _rsample_logits(logits, noise_key) \
-        if noise_key is not None and policy == "RSample" else None
+    with jax.named_scope("moe.router"):
+        gate_in = _jitter_tokens(tokens, noise_key) \
+            if noise_key is not None and policy == "Jitter" else tokens
+        logits = (gate_in.astype(rt)
+                  @ p["router"].astype(rt)).astype(jnp.float32)
+        select = _rsample_logits(logits, noise_key) \
+            if noise_key is not None and policy == "RSample" else None
     t, e = logits.shape
     c = _capacity(t, e, cfg.capacity_factor, cfg.top_k)
     mode = _resolve_dispatch(cfg, t, e, c)
-    dispatched, combine_fn, l_aux = _DISPATCHERS[mode](tokens, logits, cfg,
-                                                       dt, select)
-    expert_out = _expert_ffn(dispatched, p, dt)
-    out = combine_fn(expert_out)
-    out = _residual_mix(tokens, out, p, dt)
-    out = out + _shared_expert_out(tokens, p, dt)
+    with jax.named_scope("moe.dispatch"):
+        dispatched, combine_fn, l_aux = _DISPATCHERS[mode](
+            tokens, logits, cfg, dt, select)
+    with jax.named_scope("moe.experts"):
+        expert_out = _expert_ffn(dispatched, p, dt)
+    with jax.named_scope("moe.combine"):
+        out = combine_fn(expert_out)
+    with jax.named_scope("moe.shared"):
+        out = _residual_mix(tokens, out, p, dt)
+        out = out + _shared_expert_out(tokens, p, dt)
     return out.reshape(b, s, h), l_aux.astype(jnp.float32)
 
 
@@ -362,29 +370,37 @@ def moe_forward_ep(x: jnp.ndarray, p: Dict[str, jnp.ndarray], cfg,
         nk = jax.random.fold_in(noise_key, lax.axis_index(EXPERT_AXIS)) \
             if noise_key is not None else None
         policy = _validate_noisy_policy(cfg)
-        gate_in = _jitter_tokens(tokens, nk) \
-            if nk is not None and policy == "Jitter" else tokens
-        # fp32 router matmul: routing precision, and the replicated router's
-        # backward psum must not be bf16 (XLA CPU's AllReducePromotion
-        # aborts on the bf16 all-reduce that shard_map's transpose of a
-        # replicated input otherwise emits)
-        logits = gate_in.astype(jnp.float32) @ ps["router"].astype(jnp.float32)
-        select = _rsample_logits(logits, nk) \
-            if nk is not None and policy == "RSample" else None
+        with jax.named_scope("moe.router"):
+            gate_in = _jitter_tokens(tokens, nk) \
+                if nk is not None and policy == "Jitter" else tokens
+            # fp32 router matmul: routing precision, and the replicated
+            # router's backward psum must not be bf16 (XLA CPU's
+            # AllReducePromotion aborts on the bf16 all-reduce that
+            # shard_map's transpose of a replicated input otherwise emits)
+            logits = gate_in.astype(jnp.float32) \
+                @ ps["router"].astype(jnp.float32)
+            select = _rsample_logits(logits, nk) \
+                if nk is not None and policy == "RSample" else None
         t, e = logits.shape
         c = _capacity(t, e, cfg.capacity_factor, cfg.top_k)
         mode = _resolve_dispatch(cfg, t, e, c)
-        dispatched, combine_fn, l_aux = _DISPATCHERS[mode](tokens, logits,
-                                                           cfg, dt, select)
-        # [E, C_loc, H] → [E/ep, ep·C_loc, H]: shard i keeps experts
-        # [i·E/ep, (i+1)·E/ep) and receives their queues from every peer
-        dispatched = lax.all_to_all(dispatched, EXPERT_AXIS, split_axis=0,
-                                    concat_axis=1, tiled=True)
-        expert_out = _expert_ffn(dispatched, ps, dt)
-        expert_out = lax.all_to_all(expert_out, EXPERT_AXIS, split_axis=1,
-                                    concat_axis=0, tiled=True)
-        out = combine_fn(expert_out)
-        l_aux = lax.pmean(l_aux, EXPERT_AXIS)
+        with jax.named_scope("moe.dispatch"):
+            dispatched, combine_fn, l_aux = _DISPATCHERS[mode](
+                tokens, logits, cfg, dt, select)
+            # [E, C_loc, H] → [E/ep, ep·C_loc, H]: shard i keeps experts
+            # [i·E/ep, (i+1)·E/ep) and receives their queues from every peer
+            dispatched = lax.all_to_all(dispatched, EXPERT_AXIS,
+                                        split_axis=0, concat_axis=1,
+                                        tiled=True)
+        with jax.named_scope("moe.experts"):
+            expert_out = _expert_ffn(dispatched, ps, dt)
+        with jax.named_scope("moe.combine"):
+            expert_out = lax.all_to_all(expert_out, EXPERT_AXIS,
+                                        split_axis=1, concat_axis=0,
+                                        tiled=True)
+            out = combine_fn(expert_out)
+        with jax.named_scope("moe.router"):
+            l_aux = lax.pmean(l_aux, EXPERT_AXIS)
         return out.reshape(bl, s, h), l_aux.astype(jnp.float32)
 
     # tokens' batch dim is sharded over the expert axis (it is part of the
@@ -408,12 +424,13 @@ def moe_forward_ep(x: jnp.ndarray, p: Dict[str, jnp.ndarray], cfg,
     out, l_aux = mapped(x, routed_p)
     # dense-per-token branches (PR-MoE residual mix, qwen2-moe shared
     # expert) run outside the manual region under the auto partitioner
-    if "residual" in p:
-        out = _residual_mix(x.reshape(b * s, h), out.reshape(b * s, h), p,
-                            dt).reshape(x.shape)
-    if "shared" in p:
-        out = out + _shared_expert_out(x.reshape(b * s, h), p,
-                                       dt).reshape(x.shape)
+    with jax.named_scope("moe.shared"):
+        if "residual" in p:
+            out = _residual_mix(x.reshape(b * s, h), out.reshape(b * s, h),
+                                p, dt).reshape(x.shape)
+        if "shared" in p:
+            out = out + _shared_expert_out(x.reshape(b * s, h), p,
+                                           dt).reshape(x.shape)
     return out, l_aux
 
 
@@ -465,47 +482,56 @@ def moe_forward_held(x, p, layer, *, top_k: int, first: int,
     held = p["wg"].shape[1]
     dt, f32 = x.dtype, jnp.float32
     tm = min(HELD_TILE, max(8, t))
-    chosen, w = route_sigmoid(x, p["router"][layer], p["bias"][layer], top_k)
-    if scale != 1.0:
-        w = w * scale
-    local = (chosen - first).reshape(-1)                       # [T * k]
-    local = jnp.where((local >= 0) & (local < held), local, held)
-    n = t * top_k
-    order = jnp.argsort(local)
-    e_s = local[order]
-    r_s = (order // top_k).astype(jnp.int32)
-    w_s = w.reshape(-1)[order]
+    with jax.named_scope("moe.router"):
+        chosen, w = route_sigmoid(x, p["router"][layer], p["bias"][layer],
+                                  top_k)
+        if scale != 1.0:
+            w = w * scale
+    with jax.named_scope("moe.dispatch"):
+        local = (chosen - first).reshape(-1)                   # [T * k]
+        local = jnp.where((local >= 0) & (local < held), local, held)
+        n = t * top_k
+        order = jnp.argsort(local)
+        e_s = local[order]
+        r_s = (order // top_k).astype(jnp.int32)
+        w_s = w.reshape(-1)[order]
 
-    counts = jnp.sum(jax.nn.one_hot(local, held + 1, dtype=jnp.int32), axis=0)
-    tiles_e = (counts[:held] + tm - 1) // tm
-    tile_end = jnp.cumsum(tiles_e)
-    first_pair = jnp.cumsum(counts) - counts                   # [held + 1]
-    n_tiles = n // tm + held
-    rank = jnp.arange(n, dtype=jnp.int32) - first_pair[e_s]
-    dest = jnp.where(
-        e_s < held,
-        (tile_end - tiles_e)[jnp.minimum(e_s, held - 1)] * tm + rank,
-        n_tiles * tm)                                          # the dump
-    # row T is a row of zeros that takes every unused place of a tile
-    src = jnp.full((n_tiles * tm + 1,), t, jnp.int32).at[dest].set(r_s)
-    gate = jnp.zeros((n_tiles * tm + 1,), f32).at[dest].set(w_s)
-    tile_expert = jnp.minimum(
-        jnp.sum(jnp.arange(n_tiles, dtype=jnp.int32)[:, None]
-                >= tile_end[None, :], axis=1), held - 1)
-    x_pad = jnp.concatenate([x, jnp.zeros((1, h), dt)])
+        counts = jnp.sum(jax.nn.one_hot(local, held + 1, dtype=jnp.int32),
+                         axis=0)
+        tiles_e = (counts[:held] + tm - 1) // tm
+        tile_end = jnp.cumsum(tiles_e)
+        first_pair = jnp.cumsum(counts) - counts               # [held + 1]
+        n_tiles = n // tm + held
+        rank = jnp.arange(n, dtype=jnp.int32) - first_pair[e_s]
+        dest = jnp.where(
+            e_s < held,
+            (tile_end - tiles_e)[jnp.minimum(e_s, held - 1)] * tm + rank,
+            n_tiles * tm)                                      # the dump
+        # row T is a row of zeros that takes every unused place of a tile
+        src = jnp.full((n_tiles * tm + 1,), t, jnp.int32).at[dest].set(r_s)
+        gate = jnp.zeros((n_tiles * tm + 1,), f32).at[dest].set(w_s)
+        tile_expert = jnp.minimum(
+            jnp.sum(jnp.arange(n_tiles, dtype=jnp.int32)[:, None]
+                    >= tile_end[None, :], axis=1), held - 1)
+        x_pad = jnp.concatenate([x, jnp.zeros((1, h), dt)])
 
     def one_tile(i, y):
-        e = tile_expert[i]
-        rows = lax.dynamic_slice(src, (i * tm,), (tm,))
-        g = lax.dynamic_slice(gate, (i * tm,), (tm,))
-        xt = x_pad[rows]
-        act = jax.nn.silu(xt @ p["wg"][layer, e].astype(dt)) \
-            * (xt @ p["wi"][layer, e].astype(dt))
-        out = jnp.dot(act * g[:, None].astype(dt),
-                      p["wo"][layer, e].astype(dt),
-                      preferred_element_type=f32)
-        return y.at[rows].add(out)
+        with jax.named_scope("moe.dispatch"):
+            e = tile_expert[i]
+            rows = lax.dynamic_slice(src, (i * tm,), (tm,))
+            g = lax.dynamic_slice(gate, (i * tm,), (tm,))
+            xt = x_pad[rows]
+        with jax.named_scope("moe.experts"):
+            act = jax.nn.silu(xt @ p["wg"][layer, e].astype(dt)) \
+                * (xt @ p["wi"][layer, e].astype(dt))
+            out = jnp.dot(act * g[:, None].astype(dt),
+                          p["wo"][layer, e].astype(dt),
+                          preferred_element_type=f32)
+        with jax.named_scope("moe.combine"):
+            return y.at[rows].add(out)
 
-    y = lax.fori_loop(0, tile_end[-1], one_tile,
-                      jnp.zeros((t + 1, h), f32))
-    return y[:t].astype(dt)
+    with jax.named_scope("moe.experts"):
+        y = lax.fori_loop(0, tile_end[-1], one_tile,
+                          jnp.zeros((t + 1, h), f32))
+    with jax.named_scope("moe.combine"):
+        return y[:t].astype(dt)

@@ -26,8 +26,8 @@ from deepspeed_tpu.telemetry.slo import (SLO_BLOCK_KEYS, SLO_LEDGER_KEYS,
                                          SLO_TARGET_KEYS, SLOLedger,
                                          SLOSpec)
 from deepspeed_tpu.telemetry.tracing import (EVENT_NAMES, NULL_SPAN,
-                                             NULL_TRACER, SPAN_NAMES, Span,
-                                             Tracer)
+                                             NULL_TRACER, SPAN_NAMES,
+                                             STAGE_NAMES, Span, Tracer)
 
 _LAZY = ("AutoCapture", "build_capture_report")
 
@@ -46,7 +46,8 @@ __all__ = [
     "JsonlExporter", "MetricsRegistry", "NULL_SPAN", "NULL_TRACER",
     "SCHEMA_VERSION", "SLOLedger", "SLOSpec", "SLO_BLOCK_KEYS",
     "SLO_LEDGER_KEYS", "SLO_SCENARIO_KEYS", "SLO_TARGET_KEYS",
-    "SPAN_NAMES", "Span", "StepRecord", "Telemetry", "Tracer", "Watchdog",
+    "SPAN_NAMES", "STAGE_NAMES", "Span", "StepRecord", "Telemetry", "Tracer",
+    "Watchdog",
     "build_capture_report", "collect_hbm_stats",
     "detect_peak_flops_per_sec", "dump_bundle", "events_from_record",
     "make_span_recorder", "read_jsonl", "record_keys", "render_prometheus",

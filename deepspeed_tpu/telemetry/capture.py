@@ -117,12 +117,21 @@ def build_capture_report(logdir: str, device_substr: str = "TPU",
     the top-ops table falls back to host planes, and — when the caller
     hands per-window span totals — the ``spans`` block carries the
     software overlap estimate so the report still feeds the overlap
-    scheduler's decision inputs."""
+    scheduler's decision inputs.
+
+    ``stages``: device time by stage of the model
+    (``telemetry.tracing.STAGE_NAMES``, read from the operations' name
+    stacks by ``utils/xplane.py:time_by_stage``), and each ``top_ops``
+    row's ``stage``: what ``fusion.424`` is a part of.
+    ``dominant_collective``: the largest of ALL the capture's collectives,
+    in ``top_ops`` (self times, the ten largest) or not, an asynchronous
+    one by its time from start to done."""
     from deepspeed_tpu.utils import xplane
 
     report: Dict = {"logdir": logdir, "device_substr": device_substr,
                     "overlap_fraction": 0.0, "devices": {},
-                    "top_ops": [], "dominant_collective": None,
+                    "top_ops": [], "stages": {},
+                    "dominant_collective": None,
                     "spans": spans_overlap_estimate(span_totals or {}),
                     "note": ""}
     try:
@@ -138,20 +147,36 @@ def build_capture_report(logdir: str, device_substr: str = "TPU",
                 report["overlap_fraction"] = res["mean_overlap_fraction"]
                 report["devices"] = res["devices"]
             tops: Dict[str, Dict] = {}
+            collectives: Dict[str, Dict] = {}
+            stages: Dict[str, Dict[str, float]] = {"stages": {}, "outer": {}}
+
+            def add(acc: Dict[str, Dict], op: Dict) -> None:
+                agg = acc.setdefault(op["name"], dict(op, total_ms=0.0,
+                                                      count=0))
+                agg["total_ms"] = round(agg["total_ms"] + op["total_ms"], 4)
+                agg["count"] += op["count"]
+
             for path in files:
+                xspace = xplane.load_xspace(path)
                 for op in xplane.top_device_ops(
-                        xplane.load_xspace(path),
-                        device_substr=device_substr):
-                    agg = tops.setdefault(op["name"],
-                                          {"name": op["name"],
-                                           "total_ms": 0.0, "count": 0})
-                    agg["total_ms"] = round(
-                        agg["total_ms"] + op["total_ms"], 4)
-                    agg["count"] += op["count"]
+                        xspace, device_substr=device_substr):
+                    add(tops, op)
+                for op in xplane._collective_ops(xspace, device_substr):
+                    add(collectives, op)
+                by_stage = xplane.time_by_stage(xspace, device_substr)
+                for table, acc in stages.items():
+                    for name, ms in by_stage[table].items():
+                        acc[name] = round(acc.get(name, 0.0) + ms, 4)
+            total = sum(stages["stages"].values())
+            report["stages"] = {
+                "total_ms": round(total, 4), **stages,
+                "unscoped_share": round(
+                    stages["stages"].get("unscoped", 0.0) / total, 4)
+                if total else 0.0}
             report["top_ops"] = sorted(tops.values(),
                                        key=lambda o: -o["total_ms"])[:10]
             report["dominant_collective"] = xplane.dominant_collective(
-                report["top_ops"])
+                list(collectives.values()))
     except Exception as e:  # a broken trace must not kill training
         report["note"] = f"capture post-processing failed: {e!r}"
     hbm, hbm_note = hbm_cross_check(static_memory, step_record)

@@ -24,7 +24,9 @@ Design constraints (docs/OBSERVABILITY.md "Tracing & flight recorder"):
 Span names are a frozen vocabulary (:data:`SPAN_NAMES` /
 :data:`EVENT_NAMES`), linted against the docs table by
 ``tools/telemetry_check.py`` — the same frozen-schema contract as the
-StepRecord key set.
+StepRecord key set.  :data:`STAGE_NAMES` is the device side's: the
+``jax.named_scope`` names the jitted steps put on the device timeline,
+under the same lint.
 """
 
 from __future__ import annotations
@@ -95,6 +97,47 @@ EVENT_NAMES = (
     "slo.violation",           # a tier tick breached an SLO target
     "spec.accept",             # verify round outcome (proposed/accepted)
     "watchdog.fire",           # hang watchdog dumped a flight bundle
+)
+
+# Device stages (``jax.named_scope`` around a stage of a jitted step: the
+# name joins the JAX name stack of every operation traced inside it and
+# reaches a profiler capture as part of the operation's ``tf_op`` stat;
+# ``utils/xplane.py:time_by_stage`` adds device time up by it).  Metadata
+# only: a scope changes no compiled program and costs a step nothing.
+# A name is chosen when the body is traced: where a TRACED value says which
+# kind a layer is (dense and expert layers by turns in one scanned body),
+# the norm before the ``lax.cond`` and the residual add after it carry
+# ``moe.router`` / ``moe.combine`` in the dense layers too.  These are
+# the SERVING steps' stages (``inference/v2``, ``moe/``): the training step
+# (``models/``, ``runtime/``) has no scope yet, and a capture of a train run
+# reads ``unscoped`` but for an expert block's ``moe.*`` (``moe_forward`` is
+# the served step's too).
+STAGE_NAMES = (
+    "attn.append",             # the step's K/V (or latent / index-key) rows into pages
+    "attn.out",                # attention's output product, gate, residual add
+    "attn.qkv",                # input norm, q/k/v products, biases, rotary
+    "attn.read",               # attention over the context (paged kernel, XLA gather)
+    "embed",                   # token (+ position / type) rows, embedding norm
+    "head",                    # final norm, the head's product, argmax / sampling
+    "latent.down",             # latent attention: down/up projections, absorbed q
+    "latent.gather",           # page rows of the selected keys and their copy
+    "latent.index",            # the indexer's queries, keys, weights and scores
+    "latent.read",             # absorbed attention over the selected rows
+    "latent.select",           # counting passes + compaction of the top-k keys
+    "latent.window",           # a window layer's ring update and read
+    "layers",                  # the layer loop: weights sliced from their stacks, carried buffers
+    "mlp",                     # post-attention norm and the dense feed-forward
+    "moe.combine",             # expert outputs weighted back to their rows
+    "moe.dispatch",            # rows sorted / gathered into per-expert tiles
+    "moe.experts",             # the routed experts' products
+    "moe.router",              # post-attention norm, router logits, top-k, gates
+    "moe.shared",              # the shared (always-on) expert
+    "mtp",                     # the multi-token-prediction module, as a whole
+    "ssm.conv",                # mixer: depthwise convolution and its tails
+    "ssm.in",                  # mixer: input projection and split
+    "ssm.out",                 # mixer: gated norm and output projection
+    "ssm.scan",                # mixer: dt/A/B/C and the state-space scan
+    "verify",                  # self-drafting: accept/refuse bookkeeping
 )
 
 DEFAULT_MAX_EVENTS = 100_000

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import glob
 import os
+import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 _COLLECTIVE_MARKERS = ("all-reduce", "all-gather", "reduce-scatter",
@@ -28,6 +29,14 @@ _COLLECTIVE_MARKERS = ("all-reduce", "all-gather", "reduce-scatter",
 # layer loop (collectives included) and would count every in-loop
 # collective as hidden, inflating the metric toward 1.0
 _COMPUTE_MARKERS = ("fusion", "dot", "convolution", "custom-call")
+# loops and branches are events too and span their bodies' operations
+_CONTROL_OPS = ("while", "conditional", "call")
+_OPCODE = re.compile(r"[\]})] ([a-z][\w\-]*)\(")
+_NUMBER = re.compile(r"\.\d+$")
+# a transformation wrapped around a scope of a name stack:
+# ``jit(step)/transpose(jvp(mlp))/while/body/checkpoint/attn.qkv/dot_general:``
+_WRAPPED = re.compile(r"^(\w+)\((.*)\)$")
+_UNSCOPED = "unscoped"
 
 
 def find_xplane_files(logdir: str) -> List[str]:
@@ -104,33 +113,153 @@ def overlap_fraction(collective: Sequence[Tuple[int, int]],
     return covered / total
 
 
+def _is_control_op(name: str) -> bool:
+    """A loop, branch or call: its event spans the operations of its
+    body, which are events of their own.  ``name``: an instruction's
+    whole text (a TPU capture) or its bare name."""
+    _, eq, rhs = name.partition(" = ")
+    m = _OPCODE.search(rhs) if eq else None
+    stem = m.group(1) if m else _NUMBER.sub("", name.lstrip("%").strip())
+    return stem in _CONTROL_OPS
+
+
+def _stage_of(name_stack: str, first: bool = False) -> str:
+    """The stage (``telemetry.tracing.STAGE_NAMES``) an operation belongs
+    to, from its JAX name stack (the ``tf_op`` stat of its event
+    metadata): the LAST scope of the stack that is a stage name (the
+    innermost: an operation of the prediction module's attention reads
+    ``attn.read``), or with ``first`` the outermost (``mtp``);
+    ``unscoped`` where none is.  Transformations wrapped around a
+    scope (``transpose(jvp(mlp))``) are read through; a jitted function's
+    own name (``jit(loss)``) is not a scope."""
+    from deepspeed_tpu.telemetry.tracing import STAGE_NAMES
+
+    found = []
+    # the last segment is the primitive (or, alone, an argument's name:
+    # ``params['embed']:`` is no scope)
+    for seg in name_stack.split("/")[:-1]:
+        while (m := _WRAPPED.match(seg)) and m.group(1) not in ("jit",
+                                                                "pjit"):
+            seg = m.group(2)
+        if seg in STAGE_NAMES:
+            found.append(seg)
+    if not found:
+        return _UNSCOPED
+    return found[0] if first else found[-1]
+
+
+def _metadata_stat(plane, md, stat_name: str) -> str:
+    """One stat of an event's METADATA (where XLA's per-instruction
+    facts live: ``tf_op``, ``hlo_category``, ``program_id``), as text."""
+    for st in md.stats:
+        if plane.stat_metadata[st.metadata_id].name != stat_name:
+            continue
+        kind = st.WhichOneof("value")
+        value = getattr(st, kind)
+        if kind == "ref_value":
+            return plane.stat_metadata[value].name
+        return value.decode(errors="replace") if isinstance(value, bytes) \
+            else str(value)
+    return ""
+
+
+def _leaf_ops(xspace, device_substr: str, async_spans: bool = False):
+    """``(plane, metadata, duration_ps)`` of every operation of the
+    op-level lines that is not a loop or a branch, over the planes that
+    match (all planes where none does: a CPU capture)."""
+    matched = [p for p in xspace.planes if device_substr in p.name]
+    for plane in (matched or xspace.planes):
+        meta = plane.event_metadata
+        # "XLA Ops" is the instruction stream; "Async XLA Ops" spans each
+        # asynchronous copy or collective from its start to its done,
+        # over other operations, under the start's name
+        op_lines = [ln for ln in plane.lines if "op" in ln.name.lower()
+                    and (async_spans or "async" not in ln.name.lower())]
+        control: Dict[int, bool] = {}     # one answer an instruction
+        for line in (op_lines or plane.lines):
+            for ev in line.events:
+                md = meta[ev.metadata_id]
+                skip = control.get(ev.metadata_id)
+                if skip is None:
+                    skip = control[ev.metadata_id] = _is_control_op(md.name)
+                if not skip:
+                    yield plane, md, ev.duration_ps
+
+
+def _op_totals(xspace, device_substr: str,
+               async_spans: bool = False) -> List[Dict]:
+    """Every leaf op-line event added up by metadata name, largest first:
+    ``[{"name", "total_ms", "count", "stage"}, ...]``.  ``async_spans``
+    adds, to an asynchronous operation's start, its time from start to
+    done (the "Async XLA Ops" line: time on the wire, hidden or not, which
+    is no self time and not busy time)."""
+    totals: Dict[str, List] = {}
+    for plane, md, ps in _leaf_ops(xspace, device_substr, async_spans):
+        rec = totals.get(md.name)
+        if rec is None:
+            rec = totals[md.name] = [
+                0.0, 0, _stage_of(_metadata_stat(plane, md, "tf_op"))]
+        rec[0] += ps / 1e9  # ps → ms
+        rec[1] += 1
+    ranked = sorted(totals.items(), key=lambda kv: -kv[1][0])
+    return [{"name": n, "total_ms": round(t, 4), "count": c, "stage": st}
+            for n, (t, c, st) in ranked]
+
+
 def top_device_ops(xspace, device_substr: str = "TPU",
                    k: int = 10) -> List[Dict]:
     """Top-k device ops by total self time across matching planes.
 
-    Aggregates leaf op-line events by metadata name; returns
-    ``[{"name", "total_ms", "count"}, ...]`` sorted by total time.  When
-    no plane matches ``device_substr`` (e.g. a CPU capture, host events
-    only), falls back to every plane that has op-shaped lines so the
-    caller still sees *something* — flagged by the caller, not here."""
-    totals: Dict[str, List[float]] = {}
+    Aggregates leaf op-line events by metadata name (loops, branches and
+    calls, whose events span their bodies, are left out, and the "Async
+    XLA Ops" line, whose events span other operations: an asynchronous
+    collective is its start's and its done's self times); returns
+    ``[{"name", "total_ms", "count", "stage"}, ...]`` sorted by total
+    time, ``stage`` by :func:`_stage_of`.  When no plane matches
+    ``device_substr`` (e.g. a CPU capture, host events only), falls back
+    to every plane that has op-shaped lines so the caller still sees
+    *something* — flagged by the caller, not here."""
+    return _op_totals(xspace, device_substr)[:k]
 
-    def scan(plane) -> None:
-        meta = plane.event_metadata
-        op_lines = [ln for ln in plane.lines if "op" in ln.name.lower()]
-        for line in (op_lines or plane.lines):
-            for ev in line.events:
-                name = meta[ev.metadata_id].name
-                rec = totals.setdefault(name, [0.0, 0])
-                rec[0] += ev.duration_ps / 1e9  # ps → ms
-                rec[1] += 1
 
-    matched = [p for p in xspace.planes if device_substr in p.name]
-    for plane in (matched or xspace.planes):
-        scan(plane)
-    ranked = sorted(totals.items(), key=lambda kv: -kv[1][0])[:k]
-    return [{"name": n, "total_ms": round(t, 4), "count": c}
-            for n, (t, c) in ranked]
+def _collective_ops(xspace, device_substr: str = "TPU") -> List[Dict]:
+    """Every collective of the capture, however small beside the ten
+    largest operations, in ``top_device_ops``' shape: an asynchronous one
+    by its time from start to done plus its self times, under the start's
+    name.  What :func:`dominant_collective` ranks for a capture report."""
+    return [op for op in _op_totals(xspace, device_substr, async_spans=True)
+            if classify_op(op["name"]) == "collective"]
+
+
+def time_by_stage(xspace, device_substr: str = "TPU") -> Dict:
+    """Device time by stage of the model: every leaf operation's time
+    goes to the stage its name stack says (:func:`_stage_of`), loops and
+    branches left out.  ``{"total_ms", "stages": {stage: ms} by the
+    innermost stage, "outer": {stage: ms} by the outermost (``mtp`` as a
+    whole), "unscoped_share"}``, summed over the matching planes.  A
+    fusion is one operation and carries its root's name stack: what
+    another stage's operations cost inside it goes to the root's."""
+    inner: Dict[str, float] = {}
+    outer: Dict[str, float] = {}
+    stages: Dict[Tuple[int, int], Tuple[str, str]] = {}
+    for plane, md, ps in _leaf_ops(xspace, device_substr):
+        key = (id(plane), md.id)
+        if key not in stages:
+            stack = _metadata_stat(plane, md, "tf_op")
+            stages[key] = (_stage_of(stack), _stage_of(stack, first=True))
+        last, first = stages[key]
+        inner[last] = inner.get(last, 0.0) + ps / 1e9
+        outer[first] = outer.get(first, 0.0) + ps / 1e9
+    total = sum(inner.values())
+
+    def table(d):
+        return {k: round(v, 4) for k, v in
+                sorted(d.items(), key=lambda kv: -kv[1])}
+
+    return {"total_ms": round(total, 4), "stages": table(inner),
+            "outer": table(outer),
+            "unscoped_share": round(inner.get(_UNSCOPED, 0.0) / total, 4)
+            if total else 0.0}
 
 
 def classify_op(name: str) -> str:

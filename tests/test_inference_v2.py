@@ -705,7 +705,9 @@ def test_qkv_behind_the_barrier_are_the_plain_products(preset, program, dtype,
     block attends with and appends are ``rope(norm(x) @ w + b)``, computed
     here outside any program (GQA + rope + bias; Falcon-H1's multipliers
     beside its mixer).  In bfloat16 the product's float32 accumulator goes
-    through bias, key multiplier and rope and is rounded once, to the bit:
+    through bias, key multiplier and rope and is rounded once (held to one
+    step of bfloat16, and to the bit in all but a hundredth of the
+    elements: a second rounding in between moves far more of them):
     the TPU's compiler had folded the roundings between away, and a
     barrier after a rounding would put them back; the key multiplier
     itself is the model's dtype's, as it was when it scaled a bfloat16 k
@@ -764,7 +766,16 @@ def test_qkv_behind_the_barrier_are_the_plain_products(preset, program, dtype,
         want = np.asarray(plain[n].astype(cfg.dtype).astype(jnp.float32))
         got = seen[n].astype(np.float32)
         if dtype == "bfloat16":
-            np.testing.assert_array_equal(got, want)
+            # one step of bfloat16 (8 significant bits) at the element's
+            # magnitude: where the program's compiler and this test's
+            # contract the float32 sum in another order, an element on a
+            # rounding edge lands one step off, by machine (CHANGES.md,
+            # PR 39: one of 3,072 in falcon-h1-tiny's step)
+            step = 2.0 ** (np.floor(np.log2(np.maximum(
+                np.abs(want), np.float32(2.0 ** -126)))) - 7)
+            off = np.abs(got - want)
+            assert np.all(off <= step), (n, float((off / step).max()))
+            assert np.mean(off > 0) < 0.01, (n, float(np.mean(off > 0)))
         else:
             np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 

@@ -17,13 +17,18 @@ Checks:
    docs span table;
 5. an exported trace is well-formed Chrome trace-event JSON — a sample
    trace covering every span/event name is generated and validated
-   (``validate_chrome_trace`` is also importable for ad-hoc files).
+   (``validate_chrome_trace`` is also importable for ad-hoc files);
+6. the device-stage vocabulary (telemetry/tracing.py STAGE_NAMES) matches
+   the frozen list below, every name is in the docs stage table, every
+   name is used by a ``jax.named_scope`` in the package, and every
+   ``named_scope`` in the package uses names of the table and no other.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import re
 import sys
 import tempfile
 from typing import Any, List
@@ -75,6 +80,17 @@ EXPECTED_EVENT_NAMES = [
     "serve.enqueue", "serve.finish", "serve.first_token", "serve.preempt",
     "serve.prefix_hit", "slo.violation", "spec.accept", "watchdog.fire",
 ]
+# device stages: the ``jax.named_scope`` names of the jitted serving steps
+EXPECTED_STAGE_NAMES = [
+    "attn.append", "attn.out", "attn.qkv", "attn.read", "embed", "head",
+    "latent.down", "latent.gather", "latent.index", "latent.read",
+    "latent.select", "latent.window", "layers", "mlp",
+    "moe.combine", "moe.dispatch", "moe.experts", "moe.router", "moe.shared",
+    "mtp", "ssm.conv", "ssm.in", "ssm.out", "ssm.scan", "verify",
+]
+PACKAGE = os.path.join(REPO, "deepspeed_tpu")
+_NAMED_SCOPE = re.compile(r"named_scope\(((?:[^()]|\([^()]*\))*)\)")
+_LITERAL = re.compile(r"""["']([^"']+)["']""")
 EXPECTED_FLIGHT_REASONS = ["watchdog", "serve_crash", "engine_crash",
                            "manual", "recovery", "fleet"]
 
@@ -351,6 +367,63 @@ def check_span_names() -> List[str]:
         if f"`{name}`" not in docs:
             errors.append(f"v2.schedule argument {name!r} not documented "
                           f"in {os.path.basename(DOCS)}")
+    return errors
+
+
+def named_scopes(package: str = PACKAGE) -> List[tuple]:
+    """``(file, line, [names])`` of every ``named_scope(...)`` call in the
+    package's source: the string literals of its argument (a call may
+    choose between two names)."""
+    found = []
+    for root, _, files in os.walk(package):
+        for fn in files:
+            if not fn.endswith(".py"):
+                continue
+            path = os.path.join(root, fn)
+            with open(path, "r", encoding="utf-8") as f:
+                src = f.read()
+            for m in _NAMED_SCOPE.finditer(src):
+                found.append((os.path.relpath(path, REPO),
+                              src.count("\n", 0, m.start()) + 1,
+                              _LITERAL.findall(m.group(1))))
+    return found
+
+
+def check_stage_names(package: str = PACKAGE) -> List[str]:
+    """Device-stage vocabulary: the frozen list matches the module, every
+    name is documented and used, and no scope uses a name outside it."""
+    from deepspeed_tpu.telemetry.tracing import STAGE_NAMES
+
+    errors = []
+    if sorted(STAGE_NAMES) != sorted(EXPECTED_STAGE_NAMES):
+        errors.append(
+            "tracing.STAGE_NAMES drifted from the frozen list: "
+            f"extra={sorted(set(STAGE_NAMES) - set(EXPECTED_STAGE_NAMES))}, "
+            f"missing={sorted(set(EXPECTED_STAGE_NAMES) - set(STAGE_NAMES))}"
+            " — update EXPECTED_STAGE_NAMES + the docs stage table together")
+    try:
+        with open(DOCS, "r", encoding="utf-8") as f:
+            docs = f.read()
+    except OSError as e:
+        return errors + [f"cannot read {DOCS}: {e}"]
+    used = set()
+    for path, line, names in named_scopes(package):
+        if not names:
+            errors.append(f"{path}:{line}: named_scope without a literal "
+                          "name: a stage is a name of STAGE_NAMES, written "
+                          "where the scope is")
+        for name in names:
+            used.add(name)
+            if name not in STAGE_NAMES:
+                errors.append(f"{path}:{line}: named_scope({name!r}) is not "
+                              "in tracing.STAGE_NAMES")
+    for name in STAGE_NAMES:
+        if f"`{name}`" not in docs:
+            errors.append(f"stage {name!r} not documented in "
+                          f"{os.path.basename(DOCS)}")
+        if name not in used:
+            errors.append(f"stage {name!r} is used by no named_scope in "
+                          "the package")
     return errors
 
 
@@ -742,7 +815,7 @@ def check_trace_export() -> List[str]:
 
 def run_all() -> List[str]:
     return (check_tags_documented() + check_schema() + check_span_names()
-            + check_quant_comm() + check_router_serving()
+            + check_stage_names() + check_quant_comm() + check_router_serving()
             + check_autotuning() + check_graph_audit()
             + check_memory_audit() + check_offload() + check_recovery()
             + check_planner() + check_fleet() + check_chaos_fleet()
