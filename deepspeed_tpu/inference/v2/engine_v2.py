@@ -583,7 +583,9 @@ class InferenceEngineV2:
                 from deepspeed_tpu.inference.v2.latent import \
                     latent_step_counts
 
-                counts.update(latent_step_counts(items, self.model_config))
+                counts.update(latent_step_counts(
+                    items, self.model_config, rb.index.rows,
+                    rb.index.blocks * self.cfg.block_size))
                 if sample is _DRAFT:
                     counts.update(verify_runs=len(rb.verified),
                                   draft_rows=len(rb.verified),

@@ -15,8 +15,13 @@ What a sequence keeps, and where:
   query reads the ``index_topk`` rows its indexer scores highest (all of
   them while the context is shorter): scores over the sequence's pages
   (:func:`deepspeed_tpu.ops.pallas.latent_index.index_scores` on a TPU,
-  a per-row gather elsewhere), :func:`select_keys`, a gather of the chosen
-  rows, a block of queries at a time, never ``[T, context, row]``.
+  a per-row gather elsewhere), :func:`choose_keys`, then one of two reads
+  (:func:`read_impl_name`, a step program carries one): a walk of the
+  sequence's own pages under the choice as a mask, all heads on the one
+  row (:func:`deepspeed_tpu.ops.pallas.latent_read.latent_read`, on a TPU
+  where its rule says it is the cheaper), or :func:`select_keys` and a
+  gather of the chosen rows, a block of queries at a time, never
+  ``[T, context, row]``.
 * **Window layers** keep the last ``sliding_window`` positions only: a
   ring of ``ring`` rows a sequence, ``state["win"]`` ``[window layers,
   max_seqs + 1, ring, row]``, in the per-sequence slots a state-space
@@ -98,18 +103,23 @@ def new_cache(cfg: TransformerConfig, pool_rows: int, max_seqs: int,
             if n_win else None)
 
 
-def latent_step_counts(items, cfg: TransformerConfig) -> dict:
+def latent_step_counts(items, cfg: TransformerConfig, row_bucket: int = 0,
+                       context_bucket: int = 0) -> dict:
     """What one ragged step asks of a latent model, from its ``(cached,
-    n_new)`` items: further arguments of ``v2.schedule``, each for ONE
-    layer of its kind.  ``latent_rows``: rows appended (a full layer's
-    page rows, a window layer's ring rows); ``index_pairs``: (row, key)
-    pairs the indexer scores, every causally visible key of every row;
-    ``selected_keys``: rows the full layers' attention then reads, at most
-    ``index_topk`` a query; ``window_keys``: rows a window layer reads;
-    ``expert_rows``: (row, held expert) products an expert layer expects
-    under even routing, rows x experts per token x held / routed.  A
-    module's layer is one more full layer with experts: the same counts
-    hold for it."""
+    n_new)`` items and the step program's buckets (rows and context
+    positions; 0: no program is named): further arguments of
+    ``v2.schedule``, each for ONE layer of its kind.  ``latent_rows``:
+    rows appended (a full layer's page rows, a window layer's ring rows);
+    ``index_pairs``: (row, key) pairs the indexer scores, every causally
+    visible key of every row; ``selected_keys``: rows the full layers'
+    attention then reads, at most ``index_topk`` a query;
+    ``walked_pairs``: (row, key) pairs that read multiplies where the
+    step's program walks the pages under the selection as a mask
+    (``read_impl_name``): ``index_pairs``, and 0 where it gathers;
+    ``window_keys``: rows a window layer reads; ``expert_rows``: (row,
+    held expert) products an expert layer expects under even routing, rows
+    x experts per token x held / routed.  A module's layer is one more
+    full layer with experts: the same counts hold for it."""
     m = cfg.mla
 
     def seen(cached, end, limit):
@@ -123,8 +133,11 @@ def latent_step_counts(items, cfg: TransformerConfig) -> dict:
     pairs = sum(seen(c, c + n, c + n) for c, n in items)
     chosen = sum(seen(c, c + n, m.index_topk) for c, n in items)
     window = sum(seen(c, c + n, m.sliding_window) for c, n in items)
+    walked = row_bucket > 0 and read_impl_name(
+        cfg, row_bucket, context_bucket) == "latent_read_walk"
     return {"latent_rows": rows, "index_pairs": pairs,
-            "selected_keys": chosen, "window_keys": window,
+            "selected_keys": chosen, "walked_pairs": pairs if walked else 0,
+            "window_keys": window,
             "expert_rows": rows * m.num_experts_per_tok * m.experts_held[1]
             / m.n_routed_experts}
 
@@ -303,29 +316,22 @@ SELECT_BLOCK = 128      # context positions one place of the compaction spans
 _SELECT_ROWS = 64       # rows of a step compacted at once
 
 
-def select_keys(scores, topk: int):
-    """The ``topk`` best-scored context positions of every row, exactly,
-    ``(positions [T, k], ok [T, k])`` with ``k = min(topk, C)``: ``ok``
-    is False where a row sees fewer keys than ``k``; among equal scores
-    the lower position wins (as a stable descending sort has it).  The
-    positions come in rising order, not by score: attention does not
-    care.  scores: [T, C] float32, ``-inf`` where a key is not visible.
+def choose_keys(scores, topk: int):
+    """``chosen`` [T, C] bool: the ``topk`` best-scored context positions
+    of every row, exactly (every visible one where a row sees fewer);
+    among equal scores the lower position wins (as a stable descending
+    sort has it).  scores: [T, C] float32, ``-inf`` where a key is not
+    visible.
 
     No sort.  ``lax.top_k`` is a full sort of ``[T, C]`` on the TPU: 70 ms
     for a 1024-row chunk at a 32k context, a third of the chip's time in
     the first traced run (PERF.md, PR 34).  Instead: the k-th largest
     score of every row by bisection on the scores' bits, 32 counting
-    passes; then the chosen places are compacted into a list by counting:
-    places per ``SELECT_BLOCK`` positions, the block of the i-th chosen
-    place from the blocks' running counts, and its place inside the block
-    from the block's own running count, which a one-hot product on the
-    matrix unit fetches (small whole numbers, exact in bf16)."""
+    passes, and of the scores that tie with it the first few."""
     t, c = scores.shape
-    k = min(topk, c)
     if c <= topk:
         # every visible key is chosen
-        return (jnp.broadcast_to(jnp.arange(c, dtype=jnp.int32), (t, c)),
-                scores > -jnp.inf)
+        return scores > -jnp.inf
     i32, u32 = jnp.int32, jnp.uint32
     # -0.0 and 0.0 are one score
     bits = lax.bitcast_convert_type(jnp.where(scores == 0, 0.0, scores), i32)
@@ -335,17 +341,36 @@ def select_keys(scores, topk: int):
 
     def narrow(j, thr):
         cand = thr | (u32(1) << (u32(31) - j.astype(u32)))
-        enough = jnp.sum((key >= cand[:, None]).astype(i32), axis=1) >= k
+        enough = jnp.sum((key >= cand[:, None]).astype(i32), axis=1) >= topk
         return jnp.where(enough, cand, thr)
 
-    # the largest value that at least k scores of the row reach
+    # the largest value that at least topk scores of the row reach
     thr = lax.fori_loop(0, 32, narrow, jnp.zeros((t,), u32))[:, None]
     above = key > thr
     tied = key == thr
-    need = k - jnp.sum(above.astype(i32), axis=1, keepdims=True)
-    chosen = (scores > -jnp.inf) & (
+    need = topk - jnp.sum(above.astype(i32), axis=1, keepdims=True)
+    return (scores > -jnp.inf) & (
         above | (tied & (jnp.cumsum(tied.astype(i32), axis=1) <= need)))
 
+
+def select_keys(scores, topk: int):
+    """:func:`choose_keys` as a list, ``(positions [T, k], ok [T, k])``
+    with ``k = min(topk, C)``: ``ok`` is False where a row sees fewer keys
+    than ``k``.  The positions come in rising order, not by score:
+    attention does not care.
+
+    The chosen places are compacted into the list by counting: places per
+    ``SELECT_BLOCK`` positions, the block of the i-th chosen place from
+    the blocks' running counts, and its place inside the block from the
+    block's own running count, which a one-hot product on the matrix unit
+    fetches (small whole numbers, exact in bf16)."""
+    t, c = scores.shape
+    k = min(topk, c)
+    chosen = choose_keys(scores, topk)
+    if c <= topk:
+        return (jnp.broadcast_to(jnp.arange(c, dtype=jnp.int32), (t, c)),
+                chosen)
+    i32 = jnp.int32
     b = min(SELECT_BLOCK, c)
     nblk = c // b
     tri = (jnp.arange(b)[:, None] <= jnp.arange(b)[None, :]
@@ -387,12 +412,58 @@ def _page_rows(tables, positions, block_size: int):
     return pages * block_size + positions % block_size
 
 
+# -- the full layers' read of their selected rows -----------------------
+@register_module("latent_read", "latent_read_walk",
+                 default_for=lambda on_tpu=False, walks=False, **_:
+                 on_tpu and walks)
+def _read_walk(q, scores, cache_k, layer, meta, cfg: TransformerConfig):
+    """The selection as a mask over the sequence's own pages, walked once
+    a run of rows (``ops/pallas/latent_read.py``)."""
+    from deepspeed_tpu.ops.pallas.latent_read import latent_read
+
+    token_pos, _, token_slot, _, block_tables, ctx_lens, block_size = meta
+    w = cfg.mla.full
+    with jax.named_scope("latent.select"):
+        chosen = choose_keys(scores, cfg.mla.index_topk)
+    with jax.named_scope("latent.read"):
+        return latent_read(
+            q, chosen, cache_k, layer, block_tables, token_slot, token_pos,
+            ctx_lens[token_slot], block_size=block_size, rank=w.kv_lora_rank,
+            scale=1.0 / math.sqrt(w.qk_head_dim))
+
+
+@register_module("latent_read", "latent_read_gather")
+def _read_gather(q, scores, cache_k, layer, meta, cfg: TransformerConfig):
+    """The selection as a list of pool rows, gathered for every query."""
+    _, _, token_slot, _, block_tables, _, block_size = meta
+    with jax.named_scope("latent.select"):
+        sel, ok = select_keys(scores, cfg.mla.index_topk)
+    with jax.named_scope("latent.gather"):
+        rows = _page_rows(block_tables[token_slot], sel, block_size)
+    with jax.named_scope("latent.read"):
+        return _attend(q, lambda i: cache_k[layer, i], rows, ok, cfg.mla.full)
+
+
+def read_impl_name(cfg: TransformerConfig, rows: int, context: int) -> str:
+    """The full layers' read in a step program of ``rows`` rows at a
+    context bucket of ``context`` positions: the walk on a TPU where
+    ``latent_read.walks`` says it is the cheaper of the two, the gather
+    elsewhere; ``cfg.v2_modules`` pins a name.  One program, one read."""
+    from deepspeed_tpu.ops.pallas.latent_read import walks
+
+    name = dict(cfg.v2_modules or ()).get("latent_read", "auto")
+    return resolve_name(
+        "latent_read", name, on_tpu=on_tpu(),
+        walks=walks(rows, context, cfg.mla.index_topk,
+                    cfg.mla.full.num_heads))
+
+
 # -- layers ------------------------------------------------------------
 def _full_layer(x, ln1, p, cache_k, cache_v, layer, meta,
                 cfg: TransformerConfig):
     (token_pos, token_dest, token_slot, _, block_tables, ctx_lens,
      block_size) = meta
-    m, w = cfg.mla, cfg.mla.full
+    w = cfg.mla.full
     with jax.named_scope("latent.down"):
         h = _rms(x, ln1, cfg)
         c_q, q, row = _project(h, p, w, token_pos, cfg)
@@ -410,12 +481,8 @@ def _full_layer(x, ln1, p, cache_k, cache_v, layer, meta,
         scores = resolve("indexer", indexer_impl_name(cfg))(
             q_i, w_i, cache_v, layer, block_tables, token_slot, token_pos,
             ctx_lens[token_slot], block_size=block_size)
-    with jax.named_scope("latent.select"):
-        sel, ok = select_keys(scores, m.index_topk)
-    with jax.named_scope("latent.gather"):
-        rows = _page_rows(block_tables[token_slot], sel, block_size)
-    with jax.named_scope("latent.read"):
-        ctx = _attend(q, lambda i: cache_k[layer, i], rows, ok, w)
+    ctx = resolve("latent_read", read_impl_name(cfg, *scores.shape))(
+        q, scores, cache_k, layer, meta, cfg)
     with jax.named_scope("attn.out"):
         return _finish(x, h, ctx, p, w), cache_k, cache_v
 

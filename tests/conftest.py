@@ -41,6 +41,39 @@ def _reset_global_topology():
     topology._GLOBAL_TOPOLOGY = None
 
 
+_CACHE_AT_START = {
+    "env": os.environ.get("JAX_COMPILATION_CACHE_DIR"),
+    "jax_compilation_cache_dir": jax.config.jax_compilation_cache_dir,
+    "jax_persistent_cache_min_compile_time_secs":
+        jax.config.jax_persistent_cache_min_compile_time_secs,
+    "jax_persistent_cache_min_entry_size_bytes":
+        jax.config.jax_persistent_cache_min_entry_size_bytes,
+}
+
+
+@pytest.fixture(autouse=True)
+def _persistent_compile_cache_ends_with_its_test():
+    """A test that places the persistent compile cache (a benchmark tool's
+    ``main`` run in process) takes it away again: a multi-device XLA:CPU
+    program READ BACK from the cache hangs in its collectives (rendezvous
+    abort after 40 s), in this worker's later tests and in the children
+    they start, which inherit the directory through the environment."""
+    yield
+    if jax.config.jax_compilation_cache_dir == \
+            _CACHE_AT_START["jax_compilation_cache_dir"]:
+        return
+    from jax._src import compilation_cache
+
+    for key, value in _CACHE_AT_START.items():
+        if key != "env":
+            jax.config.update(key, value)
+    if _CACHE_AT_START["env"] is None:
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+    else:
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = _CACHE_AT_START["env"]
+    compilation_cache.reset_cache()
+
+
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)

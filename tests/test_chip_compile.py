@@ -405,6 +405,33 @@ def test_latent_index_scores_at_dots3_widths(chip):
     assert "latent_index_scores" in text
 
 
+_DOTS3_EXPERTS = ["bf16[32,5120,1536]", "bf16[32,1536,5120]"]
+_GLM5_EXPERTS = ["bf16[16,6144,2048]", "bf16[16,2048,6144]"]
+
+
+def _assert_latent_buffers_stay(text, buffers, experts, by_layer=False):
+    """No operation of the compiled ``text`` copies or transposes a value
+    the shape of one of ``buffers`` (with ``by_layer``: of one layer of
+    it), and none copies or slices a stack of ``experts`` matrices."""
+    import re
+
+    held = ["bf16[%s]" % ",".join(map(str, a.shape)) for a in buffers]
+    if by_layer:
+        held += ["bf16[%s]" % ",".join(map(str, a.shape[1:]))
+                 for a in buffers]
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+?)[{ ]\S* ?([\w-]+)\(", line)
+        if not m:
+            continue
+        op = m.group(1) if m.group(3) == "fusion" else m.group(3)
+        if m.group(2) in held and re.search(r"copy|transpose", op):
+            moved.append(line.strip()[:160])
+        if m.group(2) in experts and re.search(r"copy|slice", op):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+
+
 def test_latent_step_keeps_its_pools_and_rings_in_place(chip):
     """The serving step of ``dots3-note-ep8-l5`` (five layers at the
     published widths, a full 1024-row step at an 8k context bucket): the
@@ -415,8 +442,6 @@ def test_latent_step_keeps_its_pools_and_rings_in_place(chip):
     compiler turns the pools rows-minor for the gather and copies them
     there and back in every layer: PERF.md, PR 34); no layer's stack of
     expert matrices is sliced out as a value of its own either."""
-    import re
-
     from deepspeed_tpu.inference.v2 import latent
     from deepspeed_tpu.inference.v2 import model as v2_model
     from deepspeed_tpu.inference.v2.ragged import PackedIndex
@@ -446,20 +471,7 @@ def test_latent_step_keeps_its_pools_and_rings_in_place(chip):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 * 2 ** 30, mem
     assert "latent_index_scores" in text
-    held = ["bf16[%s]" % ",".join(map(str, a.shape))
-            for a in (ck, cv, state["win"])]
-    experts = ["bf16[32,5120,1536]", "bf16[32,1536,5120]"]
-    moved = []
-    for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+?)[{ ]\S* ?([\w-]+)\(", line)
-        if not m:
-            continue
-        op = m.group(1) if m.group(3) == "fusion" else m.group(3)
-        if m.group(2) in held and re.search(r"copy|transpose", op):
-            moved.append(line.strip()[:160])
-        if m.group(2) in experts and re.search(r"copy|slice", op):
-            moved.append(line.strip()[:160])
-    assert not moved, moved
+    _assert_latent_buffers_stay(text, (ck, cv, state["win"]), _DOTS3_EXPERTS)
 
 
 @pytest.mark.parametrize("t,nb", [(1024, 64), (32, 32)],
@@ -474,8 +486,6 @@ def test_self_drafting_step_is_one_program_with_its_pools_in_place(chip, t,
     whole or by layer; the one result is ``s32[4, slots]``; the index
     kernel runs in all six layers; no stack of expert matrices is sliced
     out as a value of its own."""
-    import re
-
     from deepspeed_tpu.inference.v2 import latent
     from deepspeed_tpu.inference.v2 import model as v2_model
     from deepspeed_tpu.inference.v2.ragged import PackedIndex
@@ -505,20 +515,84 @@ def test_self_drafting_step_is_one_program_with_its_pools_in_place(chip, t,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 * 2 ** 30, mem
     assert text.count("latent_index_scores") >= 3   # trunk segments, module
-    held = ["bf16[%s]" % ",".join(map(str, a.shape)) for a in (ck, cv)]
-    held += ["bf16[%s]" % ",".join(map(str, a.shape[1:])) for a in (ck, cv)]
-    experts = ["bf16[16,6144,2048]", "bf16[16,2048,6144]"]
-    moved = []
-    for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+?)[{ ]\S* ?([\w-]+)\(", line)
-        if not m:
-            continue
-        op = m.group(1) if m.group(3) == "fusion" else m.group(3)
-        if m.group(2) in held and re.search(r"copy|transpose", op):
-            moved.append(line.strip()[:160])
-        if m.group(2) in experts and re.search(r"copy|slice", op):
-            moved.append(line.strip()[:160])
-    assert not moved, moved
+    _assert_latent_buffers_stay(text, (ck, cv), _GLM5_EXPERTS, by_layer=True)
+
+
+# (preset, its overrides, pool rows, the self-drafting step?, context pages)
+_LATENT_READS = {
+    "glm5_8k": ("glm-5-ep16", {"num_layers": 5, "first_k_dense": 1},
+                1024 * 128, True, 64),
+    "dots3_16k": ("dots3-note-prev-ep8", {"num_layers": 5}, 2048 * 128,
+                  False, 128),
+    "dots3_32k": ("dots3-note-prev-ep8", {"num_layers": 5}, 2048 * 128,
+                  False, 256),
+}
+
+
+@pytest.mark.parametrize("case,walked", [
+    ("glm5_8k", True), ("dots3_16k", True), ("dots3_32k", False)])
+def test_latent_step_reads_by_the_one_path_its_shapes_choose(
+        chip, case, walked, monkeypatch):
+    """The 1024-row step program of both latent cells at their published
+    widths, traced as on a TPU (``latent.on_tpu``: the indexer's kernel
+    and ``latent_read.walks`` choose).  At a walked bucket (GLM-5's
+    widest, 8k; dots3's 16k) it holds the ``latent_read_walk`` kernel in
+    every full layer and no temporary of ``rows x index_topk x row``
+    elements, and the pools stay in place; at dots3's 32k bucket it holds
+    the gather's temporary and no call of the kernel: one program, one
+    read."""
+    import re
+
+    from deepspeed_tpu.inference.v2 import latent
+    from deepspeed_tpu.inference.v2 import model as v2_model
+    from deepspeed_tpu.inference.v2.ragged import PackedIndex
+    from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.models import transformer as tf_model
+    from deepspeed_tpu.ops.pallas import latent_read
+
+    preset, over, rows, drafting, nb = _LATENT_READS[case]
+    monkeypatch.setattr(latent, "on_tpu", lambda: True)
+    cfg = get_model_config(preset, param_dtype=BF16, dtype=BF16, **over)
+    t, bs, heads = 1024, 128, cfg.mla.full.num_heads
+    assert latent_read.walks(t, nb * bs, cfg.mla.index_topk, heads) is walked
+    assert latent.read_impl_name(cfg, t, nb * bs) == (
+        "latent_read_walk" if walked else "latent_read_gather")
+    params = _abstract(chip, jax.eval_shape(
+        lambda k: tf_model.init_params(cfg, k), jax.random.PRNGKey(0)))
+    ck, cv, state = jax.eval_shape(lambda: latent.new_cache(cfg, rows, 32, t))
+    ck, cv = _abstract(chip, (ck, cv))
+    index = PackedIndex(chip((PackedIndex.size(t, 33, nb, drafting),), I32),
+                        t, 33, nb, drafting)
+    with jax.default_matmul_precision("default"):
+        if drafting:
+            fn = functools.partial(v2_model.ragged_draft_step, cfg=cfg,
+                                   block_size=bs)
+            compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+                params, ck, cv, index).compile()
+            held, experts = (ck, cv), _GLM5_EXPERTS
+        else:
+            state = _abstract(chip, state)
+            fn = functools.partial(v2_model.ragged_step_sampled, cfg=cfg,
+                                   block_size=bs, greedy=True)
+            compiled = jax.jit(fn, donate_argnums=(1, 2),
+                               donate_argnames=("state",)).lower(
+                params, ck, cv, index, chip((2,), jnp.uint32), chip((), F32),
+                state=state).compile()
+            held, experts = (ck, cv, state["win"]), _DOTS3_EXPERTS
+    text = compiled.as_text()
+    assert "latent_index_scores" in text
+    # every query's gathered rows, a block of queries at a time
+    gathered = re.findall(r"bf16\[\d+,%d,640\]" % cfg.mla.index_topk, text)
+    if walked:
+        # the trunk's segments (and the module): one traced body each
+        assert text.count("latent_read_walk") >= (3 if drafting else 2)
+        assert not gathered, gathered[:3]
+        assert len(_aliased_outputs(text)) == len(held)
+        _assert_latent_buffers_stay(text, held, experts, by_layer=drafting)
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    else:
+        assert "latent_read_walk" not in text
+        assert gathered
 
 
 def test_paged_qblock_group_of_five(chip):

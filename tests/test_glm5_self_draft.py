@@ -244,12 +244,18 @@ def test_the_plain_calling_sequence_serves_a_drafting_engine(plain_streams):
         plain.step_bursts()
 
 
+@pytest.mark.parametrize("read", ["latent_read_gather", "latent_read_walk"])
 def test_a_refused_drafts_position_is_rewritten_before_it_is_read(
-        plain_streams):
+        plain_streams, read, monkeypatch):
     """After every step the cache rows, the trunk's and the module's, at
     and past each sequence's next position are overwritten with NaN: a
-    refused draft's rows lie there.  The streams do not change."""
-    eng = engine(self_draft=True)
+    refused draft's rows lie there.  The streams do not change, whether
+    the full layers gather their rows or walk the pages (the kernel,
+    interpreted)."""
+    from deepspeed_tpu.ops.pallas import latent_read
+
+    monkeypatch.setattr(latent_read, "INTERPRET", True)
+    eng = engine(self_draft=True, modules={"latent_read": read})
     bs = eng.cfg.block_size
     for u, p in PROMPTS.items():
         eng.admit(u, p)

@@ -108,14 +108,24 @@ def reference_logits(eng, tokens, last, selected=None):
 # contexts run well past index_topk (8) and the window (5); "wrapped": the
 # ring of 128 rows has been written round more than once; "blocks": a
 # step's rows are cut into blocks of 8, some of one run and some mixed
+# "walked": the full layers' read pinned to the kernel, interpreted (off the
+# TPU a step program gathers)
 @pytest.mark.parametrize("case,prompt,budget,block_size,window_block", [
     ("short chunks", 40, 16, 4, 128),
     ("wrapped ring", 170, 32, 8, 128),
-    ("blocks of one run and mixed", 75, 32, 4, 8)])
+    ("blocks of one run and mixed", 75, 32, 4, 8),
+    ("short chunks, walked", 40, 16, 4, 128),
+    ("rows past a query block, walked", 75, 64, 8, 128)])
 def test_prefill_in_chunks_then_decode_is_the_reference(
         case, prompt, budget, block_size, window_block, monkeypatch):
     monkeypatch.setattr(latent, "WINDOW_BLOCK", window_block)
-    eng = engine(budget=budget, block_size=block_size)
+    pinned = {}
+    if case.endswith("walked"):
+        from deepspeed_tpu.ops.pallas import latent_read
+
+        monkeypatch.setattr(latent_read, "INTERPRET", True)
+        pinned = {"modules": {"latent_read": "latent_read_walk"}}
+    eng = engine(budget=budget, block_size=block_size, **pinned)
     nonzero_bias(eng)
     if case == "wrapped ring":
         assert eng.state["win"].shape[2] == 128 < prompt
@@ -376,6 +386,16 @@ def test_step_counts_against_a_hand_count():
     assert got["latent_rows"] == 5
     assert got["index_pairs"] == 21 + (7 + 8 + 9 + 10)
     assert got["selected_keys"] == 8 + (7 + 8 + 8 + 8)
+    # no program named, and off the TPU a program gathers
+    assert got["walked_pairs"] == 0
+    assert latent.latent_step_counts(
+        [(20, 1), (6, 4)], model, 16, 32)["walked_pairs"] == 0
+    # a program that walks multiplies every visible key of every row
+    walking = model.replace(
+        v2_modules=(("latent_read", "latent_read_walk"),))
+    got = latent.latent_step_counts([(20, 1), (6, 4)], walking, 16, 32)
+    assert got["walked_pairs"] == got["index_pairs"] == 55
+    assert got["selected_keys"] == 8 + (7 + 8 + 8 + 8)
     assert got["window_keys"] == 5 + 4 * 5
     assert got["expert_rows"] == 5 * 4 * 4 / 16
     # a prompt from position 0: the first rows see fewer than the limits
@@ -385,11 +405,12 @@ def test_step_counts_against_a_hand_count():
     assert got["window_keys"] == 15 + 5 * 5
 
 
-def test_schedule_span_carries_the_counts_for_a_latent_model_only():
+def test_schedule_span_carries_the_counts_for_a_latent_model_only(
+        monkeypatch):
     from deepspeed_tpu.telemetry.tracing import Tracer
 
     names = {"latent_rows", "index_pairs", "selected_keys", "window_keys",
-             "expert_rows"}
+             "expert_rows", "walked_pairs"}
     eng = engine(budget=16)
     eng.tracer = Tracer(enabled=True)
     eng.admit(1, list(range(1, 31)))
@@ -400,6 +421,22 @@ def test_schedule_span_carries_the_counts_for_a_latent_model_only():
     assert len(sched) == 2 and all(names <= set(a) for a in sched)
     assert sched[0]["latent_rows"] == 16 and sched[0]["index_pairs"] == 136
     assert sched[1]["selected_keys"] == 14 * 8
+    # these programs gather (no TPU here): nothing is walked
+    assert [a["walked_pairs"] for a in sched] == [0, 0]
+    # an engine whose programs walk says so, by the rule it traces them by
+    from deepspeed_tpu.ops.pallas import latent_read
+
+    monkeypatch.setattr(latent_read, "INTERPRET", True)
+    walking = engine(budget=16, modules={"latent_read": "latent_read_walk"})
+    walking.tracer = Tracer(enabled=True)
+    walking.admit(1, list(range(1, 31)))
+    walking.step()
+    walking.step()
+    sched = [e["args"] for e in walking.tracer.snapshot()
+             if e["name"] == "v2.schedule"]
+    assert [a["walked_pairs"] for a in sched] == [136, sum(range(17, 31))]
+    assert [a["walked_pairs"] for a in sched] == [a["index_pairs"]
+                                                  for a in sched]
     alloc = [e["args"] for e in spans if e["name"] == "v2.state_alloc"]
     assert alloc and alloc[0]["ring_rows"] == 128
     assert alloc[0]["window_bytes"] == eng.state_bytes

@@ -319,7 +319,8 @@ def check_schema() -> List[str]:
 # (inference/v2/latent.py latent_step_counts): the benchmark's readers
 # read them by name
 EXPECTED_LATENT_SCHEDULE_ARGS = ["expert_rows", "index_pairs", "latent_rows",
-                                 "selected_keys", "window_keys"]
+                                 "selected_keys", "walked_pairs",
+                                 "window_keys"]
 
 
 def check_span_names() -> List[str]:
