@@ -30,6 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from deepspeed_tpu.inference.v2.model import (attention_impl_name,
                                               check_sampling_params,
                                               new_ssm_state,
+                                              new_window_pools,
                                               ragged_decode_loop,
                                               ragged_draft_step,
                                               ragged_step,
@@ -69,6 +70,10 @@ class RaggedInferenceEngineConfig:
         self.memory_config = d.get("memory_config", {})
         self.num_blocks = int(self.memory_config.get("num_blocks", 512))
         self.block_size = int(self.memory_config.get("block_size", 16))
+        # pages of the window layers' own pool, for a model that mixes
+        # window and full attention by layer (any other keeps one pool)
+        self.window_blocks = int(self.memory_config.get("window_blocks",
+                                                        self.num_blocks))
         # "int8": blockwise-quantized KV pages (one fp32 scale per
         # (head, row)) — halves decode's KV bandwidth, the bound resource
         # (ref KV-block layout inference/v2/ragged/kv_cache.py:40)
@@ -187,6 +192,28 @@ def step_counts(items: Sequence[tuple], window: Optional[int] = None,
             "blocked_rows": blocked, "kv_rows": kv_rows, "qk_pairs": pairs}
 
 
+def window_step_counts(items: Sequence[tuple], cfg: TransformerConfig,
+                       held: tuple, freed: int) -> Dict[str, Any]:
+    """What one ragged step asks of a model that mixes window and full
+    attention by layer, from the same ``(cached, n_new)`` items: further
+    arguments of ``v2.schedule``, each for ONE layer of its kind.
+    ``full_kv_rows`` / ``window_kv_rows``: keys the step's rows read,
+    each sequence's context after the step counted once, cut to the
+    window for a window layer; ``full_pages`` / ``window_pages``: pages
+    live sequences hold after the step (``held``); ``pages_freed``: window
+    pages this step returned; ``expert_rows``: (row, held expert) products
+    an expert layer expects under even routing, rows x experts per token x
+    held / routed."""
+    mx = cfg.mixed
+    ends = [cached + n for cached, n in items]
+    return {"full_kv_rows": sum(ends),
+            "window_kv_rows": sum(min(e, mx.sliding_window) for e in ends),
+            "full_pages": held[0], "window_pages": held[1],
+            "pages_freed": freed,
+            "expert_rows": sum(n for _, n in items) * mx.num_experts_per_tok
+            * mx.experts_held[1] / mx.n_routed_experts}
+
+
 def ssm_step_counts(items: Sequence[tuple], slot_bytes: int,
                     live: int) -> Dict[str, int]:
     """What one ragged step asks of a model's SSM mixer, from the same
@@ -220,6 +247,12 @@ _MIXER_STATE = (
     "this model's Mamba-2 SSM mixer keeps recurrent state per sequence, "
     "which is only ever the state after the last row run — there is no "
     "copy of it at an earlier position to adopt, rewind to or ship")
+_WINDOW_PAGES = (
+    "this model's sliding-window layers keep their rows in a page pool of "
+    "their own and return a page to it once every row of it lies more "
+    "than the window below the sequence's last row — the pages at an "
+    "earlier position are gone, and there is no copy of them to adopt, "
+    "rewind to or ship")
 _WINDOW_ROWS = (
     "this model's sliding-window latent layers keep only the last window "
     "of a sequence's rows, in a ring per sequence that later rows "
@@ -297,7 +330,8 @@ class InferenceEngineV2:
             num_blocks=self.cfg.num_blocks,
             block_size=self.cfg.block_size,
             max_blocks_per_seq=max_blocks_per_seq,
-            min_blocks_bucket=self.cfg.min_context_blocks)
+            min_blocks_bucket=self.cfg.min_context_blocks,
+            window=mc.layer_window, window_blocks=self.cfg.window_blocks)
         self.scheduler = SplitFuseScheduler(self.state_manager,
                                             token_budget=self.cfg.max_ragged_batch_size)
         # software-span tracer (telemetry/tracing.py) — the serving layer
@@ -327,8 +361,19 @@ class InferenceEngineV2:
         # [L, nkv, P, d]: kv-head-major so the paged-attention kernel's page
         # blocks have (rows, head_dim) as their minor dims (lane-aligned).
         kv_shape = (mc.num_layers, mc.kv_heads, pages, mc.dim_per_head)
-        latent = None
-        if mc.mla is not None:
+        latent = pools = None
+        if mc.mixed is not None:
+            # the full layers' rows in the pool, the window layers' in a
+            # second one (kept where a mixer's state is: donated, carried)
+            if self.cfg.kv_dtype == "int8":
+                raise ValueError("memory_config.kv_dtype='int8': a mixed-"
+                                 "attention model's rows are kept in the "
+                                 "compute dtype")
+            t0 = time.monotonic()
+            self.cache_k, self.cache_v, pools = new_window_pools(
+                mc, pages, self.cfg.window_blocks * self.cfg.block_size,
+                zeros, dt)
+        elif mc.mla is not None:
             # latent rows and index keys in the pages, the window layers'
             # rings in the slots (made below, where a mixer's state is)
             from deepspeed_tpu.inference.v2 import latent
@@ -364,6 +409,15 @@ class InferenceEngineV2:
         self.ssm_impl = None
         self._slot_bytes = 0        # float32 recurrent state of one slot
         donate: Dict[str, Any] = {"donate_argnums": (1, 2)}
+        if pools is not None:
+            self.state = jax.block_until_ready(pools)
+            self.state_kind = _WINDOW_PAGES
+            self._state_alloc = {
+                "ts": t0 * 1e6, "dur": (time.monotonic() - t0) * 1e6,
+                "full_pool_bytes": 2 * int(self.cache_k.nbytes),
+                "window_pool_bytes": self.state_bytes,
+                "window_layers": mc.window_layers}
+            donate["donate_argnames"] = ("state",)
         if latent is not None and rings is not None:
             self.state = jax.block_until_ready(rings)
             self.state_kind = _WINDOW_ROWS
@@ -576,7 +630,12 @@ class InferenceEngineV2:
             # the pages the new rows land in, a sequence's counted apart:
             # with ``tokens``, what the append moves (whole pages)
             counts["append_pages"] = append_pages(items, self.cfg.block_size)
-            if self.model_config.ssm is not None:
+            if self.model_config.mixed is not None:
+                mgr = self.state_manager
+                counts.update(window_step_counts(
+                    items, self.model_config, mgr.pages_held(),
+                    mgr.pages_freed))
+            elif self.model_config.ssm is not None:
                 counts.update(ssm_step_counts(
                     items, self._slot_bytes, self.state_manager.n_active))
             elif self.model_config.mla is not None:
@@ -667,7 +726,8 @@ class InferenceEngineV2:
         sm = self.state_manager
         t = (min(16, self.scheduler.token_budget) if phase == "decode"
              else self.scheduler.token_budget)
-        sizes = (t, sm.max_seqs + 1, sm.max_blocks_per_seq)
+        sizes = (t, sm.max_seqs + 1, sm.max_blocks_per_seq, False,
+                 bool(sm.window))
         index = PackedIndex(jnp.zeros((PackedIndex.size(*sizes),), jnp.int32),
                             *sizes)
         args = (self.params, self.cache_k, self.cache_v, index)
@@ -1122,6 +1182,30 @@ class InferenceEngineV2:
     def free_blocks(self) -> int:
         return self.state_manager.allocator.free_blocks
 
+    @property
+    def free_window_blocks(self) -> int:
+        """Free pages of the window layers' own pool (0 without one)."""
+        wa = self.state_manager.window_allocator
+        return wa.free_blocks if wa is not None else 0
+
+    def window_seq_blocks(self, n_tokens: int) -> int:
+        """Pages of the window layers' pool a sequence of ``n_tokens``
+        holds at most: its context's, until the window and a step's rows
+        span fewer (0 for a model without that pool)."""
+        mgr = self.state_manager
+        if not mgr.window:
+            return 0
+        bs = self.cfg.block_size
+        return min(self.seq_blocks(n_tokens),
+                   -(-(mgr.window + self.scheduler.token_budget) // bs) + 1)
+
+    def window_admissible(self, n_tokens: int) -> bool:
+        """Whether the window layers' pool has the pages a new sequence
+        of ``n_tokens`` can come to hold (always, without that pool):
+        admission counts both pools, this one by what a sequence holds at
+        its widest, since it gives pages back as it goes."""
+        return self.window_seq_blocks(n_tokens) <= self.free_window_blocks
+
     def seq_blocks(self, n_tokens: int) -> int:
         """KV pages a sequence of ``n_tokens`` tokens occupies — THE page
         accounting rule; admission layers must use it rather than re-derive
@@ -1159,6 +1243,7 @@ class InferenceEngineV2:
             # of a full-logits transfer per token).
             active_uids = [u for u in uids if u in self.state_manager]
             if (not pending and active_uids
+                    and not self.state_manager.window
                     and all(self.state_manager.get(u).uncached == 1
                             for u in active_uids)):
                 decode_key, sub, _ = _next_key(decode_key, temperature)
@@ -1169,12 +1254,15 @@ class InferenceEngineV2:
             admit_uids, admit_toks = [], []
             # Active sequences will still claim pages as they decode: reserve
             # their remaining future blocks so admission never overcommits.
-            reserved = 0
+            reserved = reserved_window = 0
             for u in uids:
                 if u in self.state_manager:
                     seq = self.state_manager.get(u)
                     final = self.seq_blocks(len(seq.tokens) + remaining[u])
                     reserved += max(0, final - len(seq.blocks))
+                    reserved_window += max(0, self.window_seq_blocks(
+                        len(seq.tokens) + remaining[u])
+                        - (len(seq.window_blocks) - seq.window_freed))
             # Admit while slots and KV pages allow (continuous batching).
             while pending and (self.state_manager.n_active + len(admit_uids)
                                < self.state_manager.max_seqs):
@@ -1185,10 +1273,15 @@ class InferenceEngineV2:
                         f"prompt uid {u} needs {need} KV blocks but the cache "
                         f"allows {self.max_seq_blocks} per sequence; "
                         "raise num_blocks/max_context or shorten the prompt")
-                if need + reserved > self.state_manager.allocator.free_blocks:
+                need_window = self.window_seq_blocks(len(toks)
+                                                     + max_new_tokens)
+                if need + reserved > self.state_manager.allocator.free_blocks \
+                        or (need_window + reserved_window
+                            > self.free_window_blocks):
                     break
                 pending.pop(0)
                 reserved += need
+                reserved_window += need_window
                 admit_uids.append(u)
                 admit_toks.append(toks)
             if pending and not admit_uids and self.state_manager.n_active == 0:

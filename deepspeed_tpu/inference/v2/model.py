@@ -358,6 +358,9 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
     (token_pos, token_dest, gather_idx, token_ctx_len, token_slot,
      block_tables, block_size, dest_pages) = meta
     mixer = cfg.ssm
+    # q/k norms, a gate on the attention output, a norm after attention
+    # and after the feed-forward, held experts (a mixed-attention model)
+    mx = cfg.mixed
     t = x.shape[0]
     nh, nkv, d = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
     dt = x.dtype
@@ -384,6 +387,12 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
         if mixer:
             # the multiplier as the model's dtype holds it (it scaled a dt k)
             k = k * jnp.asarray(mixer.key_multiplier, dt).astype(k.dtype)
+        if mx is not None and mx.gate:
+            gate = proj(lp["attn"]["wg"], None)
+        if mx is not None and mx.qk_norm:
+            # over the dims of a head, one gain for all heads, before rotary
+            q = _norm(q, {"scale": lp["attn"]["q_norm"]}, cfg)
+            k = _norm(k, {"scale": lp["attn"]["k_norm"]}, cfg)
         if cfg.use_rope:
             q = _rope_tok(q, token_pos, cfg)
             k = _rope_tok(k, token_pos, cfg)
@@ -429,7 +438,12 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
                 lambda c, pages: c.at[layer].set(pages),
                 (cache_k, cache_v), (k_pages, v_pages))
     with jax.named_scope("attn.out"):
-        attn = attn.reshape(t, nh * d) @ lp["attn"]["wo"].astype(dt)
+        attn = attn.reshape(t, nh * d)
+        if mx is not None and mx.gate:
+            attn = attn * jax.nn.sigmoid(gate).astype(dt)
+        attn = attn @ lp["attn"]["wo"].astype(dt)
+        if mx is not None and mx.sandwich_norm:
+            attn = _norm(attn, lp["post_attn"], cfg)
         if lp["attn"].get("bo") is not None:
             attn = attn + lp["attn"]["bo"].astype(dt)
     if mixer:
@@ -452,8 +466,12 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
         x = x + attn
 
     experts = "moe" in lp
-    with jax.named_scope("moe.router" if experts else "mlp"):
+    held = "held" in lp         # this program's share of sigmoid-routed ones
+    with jax.named_scope("moe.router" if experts or held else "mlp"):
         h2 = _norm(x, lp["ln2"], cfg)
+    if mx is not None:
+        return (_mixed_feed_forward(x, h2, lp, cfg), cache_k, cache_v,
+                state)
     if not experts:
         with jax.named_scope("mlp"):
             return (x + _mlp_block(h2, lp["mlp"], cfg), cache_k, cache_v,
@@ -499,6 +517,145 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
         return x + y, cache_k, cache_v, state
 
 
+def _at(tree, i):
+    """Layer ``i`` of weights stacked on axis 0."""
+    return jax.tree.map(lambda a: a[i], tree)
+
+
+def _mixed_feed_forward(x, h2, lp, cfg: TransformerConfig):
+    """The feed-forward of a mixed-attention model's block on the normed
+    rows ``h2``, with its norm after and the residual add: dense
+    (``lp["mlp"]``), or this program's routed experts and the shared one
+    (``lp["held"]``: the expert layers' stack and this layer's index in
+    it, as ``moe_forward_held`` takes them)."""
+    from deepspeed_tpu.moe.sharded_moe import moe_forward_held
+
+    mx = cfg.mixed
+    post = ((lambda y: _norm(y, lp["post_mlp"], cfg)) if mx.sandwich_norm
+            else (lambda y: y))
+    if "mlp" in lp:
+        with jax.named_scope("mlp"):
+            return x + post(_mlp_block(h2, lp["mlp"], cfg))
+    stack, i = lp["held"]
+    routed = moe_forward_held(
+        h2, stack, i, top_k=mx.num_experts_per_tok,
+        first=mx.experts_held[0], scale=mx.route_scale)
+    with jax.named_scope("moe.shared"):
+        y = routed + _mlp_block(h2, _at(stack["shared"], i), cfg)
+    with jax.named_scope("moe.combine"):
+        return x + post(y)
+
+
+def layer_segments(kinds) -> list:
+    """``kinds`` (one hashable a layer) cut into ``(start, period,
+    repeats)``: where a stretch of ``period`` layers repeats at least
+    twice it is one segment (the stretch that covers most layers, the
+    shortest among equals), any other layer a segment of its own."""
+    out, i, n = [], 0, len(kinds)
+    while i < n:
+        best = (1, 1)
+        for p in range(1, (n - i) // 2 + 1):
+            r = 1
+            while kinds[i + r * p:i + (r + 1) * p] == kinds[i:i + p]:
+                r += 1
+            if r > 1 and r * p > best[0] * best[1]:
+                best = (p, r)
+        out.append((i,) + best)
+        i += best[0] * best[1]
+    return out
+
+
+def new_window_pools(cfg: TransformerConfig, full_rows: int,
+                     window_rows: int, zeros=jnp.zeros, dtype=None):
+    """``(cache_k, cache_v, state)`` of a mixed-attention model, zeroed:
+    the full layers' pools ``[full layers, nkv, full_rows, d]`` and, in
+    ``state``, the window layers' ``k`` and ``v`` ``[window layers, nkv,
+    window_rows, d]``."""
+    dtype = dtype or cfg.dtype
+    n_win = cfg.window_layers
+    full = (cfg.num_layers - n_win, cfg.kv_heads, full_rows, cfg.dim_per_head)
+    win = (n_win, cfg.kv_heads, window_rows, cfg.dim_per_head)
+    return (zeros(full, dtype=dtype), zeros(full, dtype=dtype),
+            {"k": zeros(win, dtype=dtype), "v": zeros(win, dtype=dtype)})
+
+
+def _mixed_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
+                 token_dest, block_tables, ctx_lens, state,
+                 cfg: TransformerConfig, block_size: int, window):
+    """:func:`_ragged_trunk` of a mixed-attention model (``cfg.mixed``):
+    window layers and full layers by ``layer_types``, each kind's rows in
+    a pool of its own (the full layers' ``cache_k`` / ``cache_v`` under
+    ``block_tables`` / ``token_dest``, the window layers' ``state["k"]`` /
+    ``["v"]`` under ``window`` = ``(window_dest, window_tables)``), dense
+    feed-forwards first and held experts after.  The layer list is walked
+    in :func:`layer_segments`: one ``lax.scan`` over the repeats where a
+    stretch repeats, its body a stretch unrolled so that every layer's
+    window and rotary are static; all four pools ride the carry whole."""
+    mx = cfg.mixed
+    if state is None or window is None:
+        raise ValueError(
+            "this model's window layers keep their rows in a page pool of "
+            "their own with tables of their own (state=new_window_pools("
+            "...)[2], a PackedIndex with window arrays); a caller that "
+            "keeps one pool (inference.kv_generate) cannot run it")
+    if _is_quant_cache(cache_k):
+        raise ValueError("a mixed-attention model's pools are kept in the "
+                         "compute dtype (no int8 cache)")
+    window_dest, window_tables = window
+    layers = params["layers"]
+    with jax.named_scope("embed"):
+        x = params["embed"]["tokens"].astype(cfg.dtype)[token_ids]
+        if mx.embed_multiplier != 1.0:
+            x = x * mx.embed_multiplier
+    meta = {True: _step_meta(token_slot, token_pos, token_dest, block_tables,
+                             ctx_lens, block_size, cache_k, cfg),
+            False: _step_meta(token_slot, token_pos, window_dest,
+                              window_tables, ctx_lens, block_size,
+                              state["k"], cfg)}
+    kind_cfg = {True: cfg.replace(sliding_window=None, use_rope=mx.rope_full),
+                False: cfg.replace(sliding_window=mx.sliding_window,
+                                   use_rope=True)}
+    kinds = mx.kinds(cfg.num_layers)
+    fulls = [is_full for is_full, _ in kinds]
+
+    def stretch(carry, j, start, period):
+        """Layers ``start + j * period`` on, ``period`` of them."""
+        x, pools = carry
+        for k in range(start, start + period):
+            is_full, has_experts = kinds[k]
+            layer = k + j * period
+            # this layer's index in its kind's pool: the kind's layers
+            # before it in the list, and in the repeats before this one
+            in_pool = fulls[:k].count(is_full) \
+                + j * fulls[start:start + period].count(is_full)
+            lp = {name: _at(layers[name], layer)
+                  for name in ("attn", "ln1", "ln2", "post_attn", "post_mlp")
+                  if name in layers}
+            if has_experts:
+                lp["held"] = (layers["moe"], layer - mx.num_dense_layers)
+            else:
+                lp["mlp"] = _at(layers["mlp"], layer)
+            ck, cv = pools[is_full]
+            x, ck, cv, _ = _ragged_layer(
+                x, lp, ck, cv, in_pool, meta[is_full], kind_cfg[is_full])
+            pools = {**pools, is_full: (ck, cv)}
+        return (x, pools), None
+
+    carry = (x, {True: (cache_k, cache_v), False: (state["k"], state["v"])})
+    with jax.named_scope("layers"):
+        for start, period, repeats in layer_segments(kinds):
+            if repeats == 1:
+                carry, _ = stretch(carry, 0, start, period)
+            else:
+                carry, _ = lax.scan(
+                    lambda c, j, s=start, p=period: stretch(c, j, s, p),
+                    carry, jnp.arange(repeats, dtype=jnp.int32))
+    x, pools = carry
+    with jax.named_scope("head"):
+        return (_norm(x, params["final_norm"], cfg), *pools[True],
+                {"k": pools[False][0], "v": pools[False][1]})
+
+
 def _embed_rows(params, token_ids, token_pos, cfg: TransformerConfig):
     """The step's flat rows [T, H] as the first block takes them."""
     dt = cfg.dtype
@@ -539,7 +696,8 @@ def _step_meta(token_slot, token_pos, token_dest, block_tables, ctx_lens,
 
 def _ragged_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
                   token_dest, block_tables, ctx_lens, state,
-                  cfg: TransformerConfig, block_size: int, state_slot=None):
+                  cfg: TransformerConfig, block_size: int, state_slot=None,
+                  window=None):
     """Embedding, every block and the final norm over a step's flat rows:
     (x [T, H], cache_k', cache_v', state').  The one layer loop of every
     ragged program: the pools (and a mixer's recurrent slots) ride its
@@ -551,6 +709,11 @@ def _ragged_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
         return latent_trunk(params, cache_k, cache_v, token_ids, token_slot,
                             token_pos, token_dest, block_tables, ctx_lens,
                             state, cfg, block_size, state_slot)
+    if cfg.mixed is not None:
+        # window and full layers by layer, a pool each
+        return _mixed_trunk(params, cache_k, cache_v, token_ids, token_slot,
+                            token_pos, token_dest, block_tables, ctx_lens,
+                            state, cfg, block_size, window)
     ssm_meta = _ssm_meta(cfg, state, token_slot if state_slot is None
                          else state_slot, token_pos)
     with jax.named_scope("embed"):
@@ -622,7 +785,7 @@ def _lm_head(x, params, cfg: TransformerConfig):
 def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
                    token_dest, block_tables, ctx_lens, logits_idx,
                    state=None, *, cfg: TransformerConfig, block_size: int,
-                   state_slot=None):
+                   state_slot=None, window=None):
     """One ragged step.
 
     cache_k/cache_v: [L, nkv, P, d], carried through the layer loop and
@@ -631,12 +794,15 @@ def ragged_forward(params, cache_k, cache_v, token_ids, token_slot, token_pos,
     (logits [S+1, V], cache_k', cache_v').  A model with an SSM mixer
     (``cfg.ssm``) also takes ``state``, its recurrent slots
     (:func:`new_ssm_state`), and returns them fourth; ``state_slot`` [T]
-    names each row's slot where that is not ``token_slot``.
+    names each row's slot where that is not ``token_slot``.  A
+    mixed-attention model (``cfg.mixed``) takes its window layers' pools
+    as ``state`` (:func:`new_window_pools`) and their destinations and
+    tables as ``window`` (``PackedIndex.window_arrays``).
     """
     x, cache_k, cache_v, state = _ragged_trunk(
         params, cache_k, cache_v, token_ids, token_slot, token_pos,
         token_dest, block_tables, ctx_lens, state, cfg, block_size,
-        state_slot=state_slot)
+        state_slot=state_slot, window=window)
     with jax.named_scope("head"):
         logits = _lm_head(x[logits_idx], params, cfg)  # ref: logits_gather
         if cfg.ssm:
@@ -655,7 +821,8 @@ def ragged_step(params, cache_k, cache_v, index, state=None, *,
                 cfg: TransformerConfig, block_size: int):
     """:func:`ragged_forward` on a packed index buffer."""
     return ragged_forward(params, cache_k, cache_v, *index.arrays(), state,
-                          cfg=cfg, block_size=block_size)
+                          cfg=cfg, block_size=block_size,
+                          window=index.window_arrays())
 
 
 def ragged_step_sampled(params, cache_k, cache_v, index, key, temperature,
@@ -666,7 +833,7 @@ def ragged_step_sampled(params, cache_k, cache_v, index, key, temperature,
     not a call of it: a frame fewer under every traced operation)."""
     logits, *carried = ragged_forward(
         params, cache_k, cache_v, *index.arrays(), state, cfg=cfg,
-        block_size=block_size)
+        block_size=block_size, window=index.window_arrays())
     with jax.named_scope("head"):
         nxt = sample_tokens(logits, key, temperature, greedy, top_k, top_p)
     return (nxt, *carried)
@@ -721,6 +888,12 @@ def ragged_forward_verify(params, cache_k, cache_v, token_ids, token_slot,
             "rows before the draft: a rejected draft row has already "
             "overwritten its ring position, and only the last "
             f"{cfg.mla.sliding_window} positions and one step are kept")
+    if cfg.mixed is not None:
+        raise NotImplementedError(
+            "speculative verify needs the window layers' pages at the "
+            "positions a rejected draft row gives back: this model's window "
+            f"layers free their pages {cfg.mixed.sliding_window} positions "
+            "behind a sequence's last row, on the host, a step at a time")
     if cfg.alt_window or (cfg.is_moe and cfg.mla is None):
         raise NotImplementedError(
             "speculative verify step supports the scanned-layer ragged "
@@ -878,6 +1051,12 @@ def ragged_decode_loop(params, cache_k, cache_v, tokens0, ctx_lens0,
     ``ragged_forward`` takes them (returned fifth); an inactive row is
     the garbage slot's.
     """
+    if cfg.mixed is not None:
+        raise NotImplementedError(
+            "the fused decode loop keeps one block table for its whole "
+            "horizon: this model's window layers free their pages behind "
+            "the window on the host, a step at a time (InferenceEngineV2."
+            "step)")
     s_rows = block_tables.shape[0]
     slots = jnp.arange(s_rows, dtype=jnp.int32)
     act_i = active.astype(jnp.int32)

@@ -128,6 +128,13 @@ class SequenceDescriptor:
     # the sequence drafts no more)
     draft: Optional[int] = None
     draftable: bool = True
+    # a model with window layers in a pool of their own (``DSStateManager
+    # .window``): that pool's pages by the same index as ``blocks`` (the
+    # page of position p is entry p // block_size), 0 where the page went
+    # back to the free list behind the window; ``window_freed`` leading
+    # entries have
+    window_blocks: List[int] = field(default_factory=list)
+    window_freed: int = 0
 
     @property
     def uncached(self) -> int:
@@ -166,13 +173,28 @@ class PackedIndex:
     was per shape of the separate arrays."""
 
     def __init__(self, buf, rows: int, slots: int, blocks: int,
-                 draft: bool = False):
+                 draft: bool = False, window: bool = False):
         self.buf, self.rows, self.slots, self.blocks = buf, rows, slots, blocks
-        self.draft = draft
+        self.draft, self.window = draft, window
 
     @staticmethod
-    def size(rows: int, slots: int, blocks: int, draft: bool = False) -> int:
-        return 4 * rows + slots * (blocks + 2) + (rows + slots) * draft
+    def size(rows: int, slots: int, blocks: int, draft: bool = False,
+             window: bool = False) -> int:
+        return (4 * rows + slots * (blocks + 2) + (rows + slots) * draft
+                + (rows + slots * blocks) * window)
+
+    def window_arrays(self) -> Optional[Tuple]:
+        """The two further arrays of a model whose window layers keep
+        their rows in a pool of their own, after the seven (such a model
+        drafts nothing): ``window_dest`` [T], each row's flat row of THAT
+        pool, and ``window_tables`` [max_seqs+1, NB], its pages by the
+        same index as ``block_tables``, 0 (the garbage page) where a page
+        was freed behind the window.  None for any other model."""
+        if not self.window:
+            return None
+        at = self.size(self.rows, self.slots, self.blocks, self.draft)
+        return (self.buf[at:at + self.rows],
+                self.buf[at + self.rows:].reshape(self.slots, self.blocks))
 
     def draft_arrays(self) -> Tuple:
         """A self-drafting step's two further arrays, after the seven:
@@ -181,7 +203,8 @@ class PackedIndex:
         ``verify`` [max_seqs+1], 1 where the sequence's last row is a
         draft to be verified against the argmax of the row before."""
         at = self.size(self.rows, self.slots, self.blocks)
-        return (self.buf[at:at + self.rows], self.buf[at + self.rows:])
+        return (self.buf[at:at + self.rows],
+                self.buf[at + self.rows:at + self.rows + self.slots])
 
     def arrays(self) -> Tuple:
         """The seven arrays, in the step programs' argument order:
@@ -199,7 +222,8 @@ class PackedIndex:
                 b[tables_end + s:tables_end + 2 * s])
 
     def tree_flatten(self):
-        return (self.buf,), (self.rows, self.slots, self.blocks, self.draft)
+        return (self.buf,), (self.rows, self.slots, self.blocks, self.draft,
+                             self.window)
 
     @classmethod
     def tree_unflatten(cls, sizes, leaves):
@@ -228,10 +252,25 @@ class DSStateManager:
     It also keeps the host side of a step's index arrays: one
     ``PackedIndex`` buffer a (token bucket, block bucket), made at the
     bucket's first step and rewritten by every later one.
+
+    **Two kinds of layer** (``window`` > 0: a model that mixes window and
+    full attention by layer).  The full layers' rows live in the pool
+    above.  The window layers' rows live in a SECOND pool of
+    ``window_blocks`` pages with an allocator of its own
+    (``window_allocator``) and a table of its own a sequence
+    (``SequenceDescriptor.window_blocks``).  A row of a window layer sees
+    the ``window`` last positions only, so a page whose every row lies
+    more than ``window`` below the sequence's next position will never be
+    read again: ``free_behind_window`` returns it at the start of the
+    next step's build and points its entry at page 0.  A sequence so
+    holds at most ``ceil((window + token budget) / block_size) + 1`` pages
+    of that pool whatever its context.  What would need such a page again
+    (prefix adoption, rewind, hand-off) the engine refuses, by name.
     """
 
     def __init__(self, max_seqs: int, num_blocks: int, block_size: int,
-                 max_blocks_per_seq: int, min_blocks_bucket: int = 1):
+                 max_blocks_per_seq: int, min_blocks_bucket: int = 1,
+                 window: int = 0, window_blocks: int = 0):
         if not 1 <= min_blocks_bucket <= max_blocks_per_seq:
             raise ValueError(
                 f"min_context_blocks={min_blocks_bucket}: expected 1 .. "
@@ -246,6 +285,12 @@ class DSStateManager:
         self._index: Dict[Tuple[int, int], PackedIndex] = {}
         # a self-drafting engine's index buffers carry two more arrays
         self.drafting = False
+        self.window = int(window)
+        self.window_allocator = (BlockedAllocator(window_blocks)
+                                 if window else None)
+        # window pages the last step's build returned, pages of either
+        # pool live sequences hold: the v2.schedule span's counts
+        self.pages_freed = 0
 
     def __contains__(self, uid: int) -> bool:
         return uid in self._seqs
@@ -311,12 +356,38 @@ class DSStateManager:
                 f"max_blocks_per_seq {self.max_blocks_per_seq}")
         if need > len(seq.blocks):
             seq.blocks.extend(self.allocator.allocate(need - len(seq.blocks)))
+        if self.window and need > len(seq.window_blocks):
+            seq.window_blocks.extend(self.window_allocator.allocate(
+                need - len(seq.window_blocks)))
+
+    def free_behind_window(self, seq: SequenceDescriptor) -> int:
+        """Return the window pool's pages of ``seq`` that no later row can
+        see: every row of such a page lies more than ``window`` below the
+        sequence's next position (``num_cached``: the row there sees
+        positions ``num_cached - window + 1`` on).  Their entries point at
+        page 0 from now on.  Returns how many were returned."""
+        upto = min(max(0, (seq.num_cached - self.window) // self.block_size),
+                   len(seq.window_blocks))
+        gone = seq.window_blocks[seq.window_freed:upto]
+        if gone:
+            self.window_allocator.free(gone)
+            seq.window_blocks[seq.window_freed:upto] = [0] * len(gone)
+            seq.window_freed = upto
+        return len(gone)
+
+    def pages_held(self) -> Tuple[int, int]:
+        """Pages handed out, (full pool, window pool): what live
+        sequences hold (such a model shares no page with a prefix cache)."""
+        return tuple(a.num_blocks - 1 - a.free_blocks
+                     for a in (self.allocator, self.window_allocator))
 
     def flush(self, uid: int) -> None:
         """Release a finished sequence (ref ragged_manager flush path)."""
         seq = self._seqs.pop(uid)
         if seq.blocks:
             self.allocator.free(seq.blocks)
+        if seq.window_blocks[seq.window_freed:]:
+            self.window_allocator.free(seq.window_blocks[seq.window_freed:])
         self._free_slots.append(seq.slot)
 
     def step_index(self, rows: int, blocks: int) -> PackedIndex:
@@ -331,9 +402,9 @@ class DSStateManager:
         if index is None:
             slots = self.max_seqs + 1
             index = self._index[rows, blocks] = PackedIndex(
-                np.empty((PackedIndex.size(rows, slots, blocks,
-                                           self.drafting),), np.int32),
-                rows, slots, blocks, self.drafting)
+                np.empty((PackedIndex.size(rows, slots, blocks, self.drafting,
+                                           bool(self.window)),), np.int32),
+                rows, slots, blocks, self.drafting, bool(self.window))
         index.buf[:] = 0
         index.buf[rows:2 * rows] = self.max_seqs
         return index
@@ -391,7 +462,13 @@ def build_ragged_batch(schedule: "List[tuple]", mgr: DSStateManager,
                            f"{token_budget}")
 
     # Reserve all pages up front so an allocator failure leaves every
-    # sequence untouched (no num_cached advance without a KV write).
+    # sequence untouched (no num_cached advance without a KV write).  A
+    # window page nobody can see any more goes back first (gone whether
+    # or not the step then runs): the step after its last row left the
+    # window.
+    if mgr.window:
+        mgr.pages_freed = sum(mgr.free_behind_window(seq)
+                              for seq, _ in schedule)
     for seq, n_new in schedule:
         mgr.ensure_capacity(seq, seq.num_cached + n_new)
 
@@ -407,6 +484,8 @@ def build_ragged_batch(schedule: "List[tuple]", mgr: DSStateManager,
     if index.draft:
         token_next, verify = index.draft_arrays()
         token_next[:] = -1
+    if index.window:
+        window_dest, window_tables = index.window_arrays()
 
     slots, first_pos, counts = [], [], []
     cursor = 0
@@ -427,6 +506,9 @@ def build_ragged_batch(schedule: "List[tuple]", mgr: DSStateManager,
         # decode's horizon, a rewound draft): the bucket cuts them off
         held = seq.blocks[:nb]
         block_tables[sl, :len(held)] = held
+        if index.window:
+            held = seq.window_blocks[:nb]
+            window_tables[sl, :len(held)] = held
         ctx_lens[sl] = end
         logits_idx[sl] = cursor + n_new - 1
         if end >= len(seq.tokens):
@@ -445,6 +527,9 @@ def build_ragged_batch(schedule: "List[tuple]", mgr: DSStateManager,
         token_pos[:cursor] = rows_pos
         token_dest[:cursor] = (block_tables[rows_slot, rows_pos // bs] * bs
                                + rows_pos % bs)
+        if index.window:
+            window_dest[:cursor] = (
+                window_tables[rows_slot, rows_pos // bs] * bs + rows_pos % bs)
 
     return RaggedBatch(index=index, n_tokens=cursor,
                        uids_by_slot=uids_by_slot, verified=tuple(verified))

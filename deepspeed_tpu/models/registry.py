@@ -7,6 +7,7 @@ import dataclasses
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.transformer import (LatentConfig, LatentWidths,
+                                              MixedAttentionConfig,
                                               SSMConfig, TransformerConfig)
 
 _REGISTRY = {}
@@ -21,15 +22,18 @@ def get_model_config(name: str, **overrides) -> TransformerConfig:
     if name not in _REGISTRY:
         raise KeyError(f"unknown model '{name}'; known: {sorted(_REGISTRY)}")
     cfg = _REGISTRY[name]
-    if cfg.mla is not None:
-        # what a latent model keeps in its LatentConfig alone (its leading
-        # dense layers, say: a cut of the depth takes fewer of them with
-        # it) is overridden there
-        own = ({f.name for f in dataclasses.fields(cfg.mla)}
+    for nested in ("mla", "mixed"):
+        # what a latent or mixed-attention model keeps in its nested
+        # configuration alone (its leading dense layers, say: a cut of
+        # the depth takes fewer of them with it) is overridden there
+        inner = getattr(cfg, nested)
+        if inner is None:
+            continue
+        own = ({f.name for f in dataclasses.fields(inner)}
                - {f.name for f in dataclasses.fields(cfg)})
-        latent = {k: overrides.pop(k) for k in own & set(overrides)}
-        if latent:
-            cfg = cfg.replace(mla=dataclasses.replace(cfg.mla, **latent))
+        sub = {k: overrides.pop(k) for k in own & set(overrides)}
+        if sub:
+            cfg = cfg.replace(**{nested: dataclasses.replace(inner, **sub)})
     return cfg.replace(**overrides) if overrides else cfg
 
 
@@ -287,6 +291,53 @@ register("glm-5-tiny", TransformerConfig(
         rope_interleaved=True, gate=False, routed_scaling_factor=2.5,
         mtp_layers=1),
     **_glm5))
+
+# -- Trinity (HF afmoe: grouped-query attention of two kinds by layer,
+# three sliding-4096 layers with rotary then a full one without, q/k
+# norms, a gated attention output, sandwich norms, a muP embedding scale,
+# six dense layers, then 256 sigmoid-routed experts, top 4 scaled by
+# 2.448, and a shared one); arcee-ai/Trinity-Large-Preview config.json
+_trinity = dict(arch="afmoe", norm="rmsnorm", activation="swiglu",
+                use_rope=True, tie_embeddings=False, use_bias=False)
+_trinity_types = ("sliding_attention",) * 3 + ("full_attention",)
+
+
+def _trinity_mixed(experts_held):
+    return MixedAttentionConfig(
+        layer_types=_trinity_types * 15, sliding_window=4096,
+        n_routed_experts=256, experts_held=experts_held,
+        num_experts_per_tok=4, moe_intermediate_size=3072,
+        num_dense_layers=6, route_scale=2.448,
+        embed_multiplier=3072 ** 0.5)
+
+
+register("trinity-large-preview", TransformerConfig(
+    vocab_size=200192, hidden_size=3072, intermediate_size=12288,
+    num_layers=60, num_heads=48, num_kv_heads=8, head_dim=128,
+    max_seq_len=262144, rope_theta=1e4, layernorm_eps=1e-5,
+    mixed=_trinity_mixed((0, 256)), **_trinity))
+
+# one chip's share of a layer divided over eight: experts 0-31 of the 256
+# (routing over all of them) and an eighth of the vocabulary; attention,
+# the dense feed-forwards and the shared expert whole
+register("trinity-large-preview-ep8", TransformerConfig(
+    vocab_size=25024, hidden_size=3072, intermediate_size=12288,
+    num_layers=60, num_heads=48, num_kv_heads=8, head_dim=128,
+    max_seq_len=262144, rope_theta=1e4, layernorm_eps=1e-5,
+    mixed=_trinity_mixed((0, 32)), **_trinity))
+
+# two periods (of up to four), two layers of them dense; a window of
+# three pages of 8
+register("trinity-tiny", TransformerConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=8,
+    num_heads=4, num_kv_heads=2, head_dim=16, max_seq_len=256,
+    rope_theta=1e4, layernorm_eps=1e-5,
+    mixed=MixedAttentionConfig(
+        layer_types=_trinity_types * 4, sliding_window=24,
+        n_routed_experts=16, experts_held=(4, 4), num_experts_per_tok=4,
+        moe_intermediate_size=32, num_dense_layers=2, route_scale=2.448,
+        embed_multiplier=8.0),
+    **_trinity))
 
 # -- Phi (ref v2 phi: parallel block + partial rotary + biases) --------
 register("phi-2", TransformerConfig(

@@ -164,6 +164,52 @@ class LatentConfig:
 
 
 @dataclass(frozen=True)
+class MixedAttentionConfig:
+    """Plain (grouped-query) attention of two kinds chosen per layer by
+    ``layer_types`` (HF ``afmoe``): ``"sliding_attention"`` layers see the
+    token and the ``sliding_window - 1`` before it and carry rotary,
+    ``"full_attention"`` layers are causal and carry rotary only with
+    ``rope_full``.  ``qk_norm``: an RMSNorm over the dims of a head on
+    queries and on keys, one gain vector each for all heads, before
+    rotary; ``gate``: the attention output times ``sigmoid(a W_g)``,
+    element by element, ``a`` the normed input, before the out
+    projection; ``sandwich_norm``: an RMSNorm AFTER attention and AFTER
+    the feed-forward, each before its residual add;
+    ``embed_multiplier``: the embedding rows' muP scale.
+    ``num_dense_layers`` leading blocks have a dense feed-forward, the
+    others sigmoid-routed experts of which this program holds
+    ``experts_held`` (first, count): it routes over all
+    ``n_routed_experts`` and computes its own experts' part (weights
+    normalised over the chosen, times ``route_scale``) and the shared
+    expert whole.  ``TransformerConfig.mixed`` is ``None`` for any other
+    model.  Served by inference/v2 only: the window layers' rows live in
+    a page pool of their own that frees pages behind the window."""
+    layer_types: Tuple[str, ...]
+    sliding_window: int
+    n_routed_experts: int
+    experts_held: Tuple[int, int]
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    num_dense_layers: int = 1
+    n_shared_experts: int = 1
+    route_scale: float = 1.0
+    rope_full: bool = False
+    qk_norm: bool = True
+    gate: bool = True
+    sandwich_norm: bool = True
+    embed_multiplier: float = 1.0
+
+    def kinds(self, num_layers: int) -> Tuple[Tuple[bool, bool], ...]:
+        """(is a full layer, has experts) for each of the first
+        ``num_layers`` layers."""
+        return tuple((t == "full_attention", i >= self.num_dense_layers)
+                     for i, t in enumerate(self.layer_types[:num_layers]))
+
+    def window_layers(self, num_layers: int) -> int:
+        return sum(1 for full, _ in self.kinds(num_layers) if not full)
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     """Architecture hyperparameters covering GPT-2 and Llama families."""
     vocab_size: int = 50257
@@ -355,6 +401,16 @@ class TransformerConfig:
     # routed experts held in part (dots3-note); None: plain attention.
     # Served by inference/v2 only
     mla: Optional[LatentConfig] = None
+    # plain attention of two kinds by layer (window layers in a page pool
+    # that frees behind the window), q/k norms, an attention gate,
+    # sandwich norms and held sigmoid-routed experts (Trinity, HF afmoe);
+    # None: one kind of layer.  Served by inference/v2 only
+    mixed: Optional[MixedAttentionConfig] = None
+
+    @property
+    def _held(self):
+        """The nested configuration that says which experts are held."""
+        return self.mla or self.mixed
 
     # a latent model's sizes by flat names (0 without one), as the
     # mixer's below
@@ -373,11 +429,11 @@ class TransformerConfig:
 
     @property
     def n_routed_experts(self) -> int:
-        return self.mla.n_routed_experts if self.mla else 0
+        return self._held.n_routed_experts if self._held else 0
 
     @property
     def experts_held(self) -> int:
-        return self.mla.experts_held[1] if self.mla else 0
+        return self._held.experts_held[1] if self._held else 0
 
     @property
     def mtp_layers(self) -> int:
@@ -390,6 +446,35 @@ class TransformerConfig:
     @property
     def routed_scaling_factor(self) -> float:
         return self.mla.routed_scaling_factor if self.mla else 0.0
+
+    # a mixed-attention model's sizes by flat names (0 without one)
+    @property
+    def num_dense_layers(self) -> int:
+        return self.mixed.num_dense_layers if self.mixed else 0
+
+    @property
+    def route_scale(self) -> float:
+        return self.mixed.route_scale if self.mixed else 0.0
+
+    @property
+    def window_layers(self) -> int:
+        return self.mixed.window_layers(self.num_layers) if self.mixed else 0
+
+    @property
+    def layer_window(self) -> int:
+        return self.mixed.sliding_window if self.mixed else 0
+
+    @property
+    def experts_per_tok(self) -> int:
+        return self._held.num_experts_per_tok if self._held else 0
+
+    @property
+    def expert_width(self) -> int:
+        return self._held.moe_intermediate_size if self._held else 0
+
+    @property
+    def embed_multiplier(self) -> float:
+        return self.mixed.embed_multiplier if self.mixed else 1.0
 
     # the mixer's sizes by flat names (0 without one), for callers that
     # hold a configuration to a file by ``getattr``
@@ -600,6 +685,14 @@ def refuse_ssm(cfg: TransformerConfig, what: str) -> None:
             + "; models/transformer.py "
             "has neither and would run plain attention under its name. "
             "Serve it through inference.v2.InferenceEngineV2")
+    if cfg.mixed is not None:
+        raise NotImplementedError(
+            f"{what}: this configuration mixes window-"
+            f"{cfg.mixed.sliding_window} and full attention by layer, with "
+            "q/k norms, an attention gate, sandwich norms and held "
+            "sigmoid-routed experts; models/transformer.py has none of "
+            "them and would run a plain block under its name. Serve it "
+            "through inference.v2.InferenceEngineV2")
     if cfg.ssm is not None:
         raise NotImplementedError(
             f"{what}: this configuration has a Mamba-2 SSM mixer beside "
@@ -806,6 +899,169 @@ def init_latent_params(cfg: TransformerConfig, key) -> Params:
     return params
 
 
+# a mixed-attention model's seeded routing (init_mixed_params has the
+# reason); the logits' spread and mean are the latent models'
+MIXED_EMBED_COMMON = 0.5
+MIXED_COMMON_NORM = 16.0
+MIXED_ROUTER_LOGIT_SD = 8.0
+
+
+def init_mixed_params(cfg: TransformerConfig, key) -> Params:
+    """A mixed-attention model's params (``cfg.mixed``).  Attention and
+    the four norms of a block are stacked over EVERY layer
+    (``layers/attn``: ``wq``, ``wk``, ``wv``, ``wg`` (the gate), ``wo``,
+    and the heads' ``q_norm`` / ``k_norm`` gains ``[L, head_dim]``;
+    ``layers/ln1``, ``post_attn``, ``ln2``, ``post_mlp``), the leading
+    dense feed-forwards under ``layers/mlp`` and the expert layers under
+    ``layers/moe`` (router over ALL experts, the selection ``bias``, the
+    held experts' weights ``[n, held, ...]``, the ``shared`` expert), as a
+    latent model's are.  Seeded normal, ``1 / sqrt(fan_in)``; every gain
+    ones (a published "depth-scaled" gain is a trained value's start, and
+    every block's output passes a norm here whatever its scale).
+
+    **The routing's initialisation.**  Two things are wanted of seeded
+    weights that a trained model has.  (1) The chosen scores lie in the
+    sigmoid's LOWER tail, so that the last of a row's experts carries a
+    twentieth of the weight and not a quarter: a near-tie between the
+    last chosen and the first not chosen, which any rounding upstream
+    flips in one row of forty, then moves little, and a comparison with a
+    float32 reference reads the arithmetic and not the flips
+    (``init_latent_params``, PERF.md section 6 PR 34).  (2) The rows'
+    choices are spread evenly over the experts, from every row and at
+    every context length (PERF.md section 7, PR 39: a few favoured experts
+    made a cell spread 4.3-4.6 % over seeds).  A lower tail needs a
+    direction every hidden state shares: each element of the scaled
+    embedding has the offset ``MIXED_EMBED_COMMON`` (more at a narrow
+    width, so that the offset's norm is ``MIXED_COMMON_NORM`` at the
+    least: a router reads it beside the chance part of a row's own part
+    along the same direction, which at 64 dims is as large, and a logit
+    that lands below -87 is a score of zero in float32 and a weight of 0
+    / 0) beside a part of its own of variance one, and each router element the matching negative
+    part, sized for the layer's depth (a block adds two normed outputs of
+    variance one to the stream), so that the logits have standard
+    deviation ``ROUTER_LOGIT_SD`` about a mean ``ROUTER_LOGIT_MEAN``
+    standard deviations below zero.  The spread here is
+    ``MIXED_ROUTER_LOGIT_SD``, twice the latent models': at their 4 the
+    last of four experts still carried 8 % of the weight (0.19 of an
+    expert's output after ``route_scale``), and on the chip one weight
+    seed in a dozen read 3.9 % against the float32 reference where the
+    others read 0.9-1.4 %, one position 14 % off: a swapped expert, with
+    every weight through int8 reading 8 % (PERF.md section 6, PR 46); at
+    8 the four weights are 0.77, 0.15, 0.055 and 0.026 in the mean
+    (counted at the published widths) and a swap at the edge of the
+    choice moves a third of what it moved; the readings' tail did not go
+    (3.0 % once in nine), so not every outlier is such a swap: section 7.
+    What made the latent models' routing
+    uneven is that NOTHING ELSE may see that direction: values that share
+    a part make attention's output over a long context that part and
+    little else, the norm after attention scales it back to one, and every
+    later router then adds the same per-expert offset to every row.  So
+    every matrix that reads the stream other than a router (``wq``,
+    ``wk``, ``wv``, ``wg``, the feed-forwards' ``wg`` and ``wi``) has its
+    columns centred (each sums to zero over the hidden dims), as has the
+    routers' seeded part: the shared direction shifts all of a row's
+    logits alike and does nothing else.  What would still be uneven is
+    the router columns' norms (an expert whose column is 1 % longer is
+    chosen 5 % more often: 8 % between experts at these widths), so each
+    column's seeded part is scaled to the same norm: the experts are then
+    alike but for their directions, and over 32,768 seeded rows at the
+    published widths their loads spread as a Poisson count does (4.2 %
+    against 4.4 %).  The published model reaches the same end with its
+    selection ``bias``; the bias-update rule, tried here over a seeded
+    batch (sign steps, 8 or 16 rounds, 4,096 to 32,768 rows), chased the
+    batch's own noise and left the loads 1.5 to 4 times LESS even than it
+    found them, so the ``bias`` is zeros in seeded weights (a trained
+    value is not public; the tests seed a non-zero one: it moves the
+    choice only, never a weight).  Tried on the chip and taken out
+    (PERF.md section 6, PR 46): a head whose column of token ``pi(v)``
+    carries the embedding row of ``v``, so that greedy streams walk a long
+    cycle instead of the short loop seeded weights fall into; the time a
+    token then spread 4.7 % over six weight seeds where the loops' spread
+    0.9 % without the farthest run."""
+    m, h, pd, nl = cfg.mixed, cfg.hidden_size, cfg.param_dtype, cfg.num_layers
+    kinds = m.kinds(nl)
+    if len(kinds) != nl:
+        raise ValueError(f"layer_types names {len(kinds)} layers, the "
+                         f"model has {nl}")
+    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
+    c = max(MIXED_EMBED_COMMON, MIXED_COMMON_NORM / math.sqrt(h))
+
+    def dense(k, shape, fan_in, centred=False):
+        w = jax.random.normal(k, shape) / math.sqrt(fan_in)
+        if centred:     # over the hidden dims, the axis before the last
+            w = w - w.mean(axis=-2, keepdims=True)
+        return w.astype(pd)
+
+    def swiglu(k, lead, width):
+        k = jax.random.split(k, 3)
+        return {"wg": dense(k[0], lead + (h, width), h, True),
+                "wi": dense(k[1], lead + (h, width), h, True),
+                "wo": dense(k[2], lead + (width, h), width)}
+
+    def attn(k):
+        k = jax.random.split(k, 5)
+        p = {"wq": dense(k[0], (h, nh * hd), h, True),
+             "wk": dense(k[1], (h, nkv * hd), h, True),
+             "wv": dense(k[2], (h, nkv * hd), h, True),
+             "wo": dense(k[3], (nh * hd, h), nh * hd)}
+        if m.gate:
+            p["wg"] = dense(k[4], (h, nh * hd), h, True)
+        if m.qk_norm:
+            p["q_norm"] = jnp.ones((hd,), pd)
+            p["k_norm"] = jnp.ones((hd,), pd)
+        return p
+
+    def router(k, layer):
+        e = m.n_routed_experts
+        # the stream at this block's second norm: the embedding's own
+        # part, the offset, and 2 * layer + 1 normed outputs
+        own = 2.0 * layer + 2.0
+        rms = math.sqrt(own + c * c)
+        seeded = jax.random.normal(k, (h, e))
+        seeded = seeded - seeded.mean(axis=0, keepdims=True)
+        seeded = seeded * lax.rsqrt(jnp.mean(jnp.square(seeded), axis=0,
+                                             keepdims=True))
+        return (seeded * (MIXED_ROUTER_LOGIT_SD / math.sqrt(h) * rms
+                          / math.sqrt(own))
+                - ROUTER_LOGIT_MEAN * MIXED_ROUTER_LOGIT_SD * rms / (h * c)
+                ).astype(pd)
+
+    def stacked(fn, ks):
+        return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0),
+                            *[fn(k) for k in ks])
+
+    keys = jax.random.split(key, nl + 2)
+    sub = [jax.random.split(keys[i], 4) for i in range(nl)]
+    dense_at = [i for i, (_, has_experts) in enumerate(kinds)
+                if not has_experts]
+    moe_at = [i for i, (_, has_experts) in enumerate(kinds) if has_experts]
+    layers = {"attn": stacked(attn, [sub[i][0] for i in range(nl)])}
+    if dense_at:
+        layers["mlp"] = stacked(
+            lambda k: swiglu(k, (), cfg.intermediate_size),
+            [sub[i][1] for i in dense_at])
+    if moe_at:
+        f = m.moe_intermediate_size
+        # a layer's held experts at a time, written into their place in
+        # the stack (a loop, not a stack of whole layers: at the published
+        # widths the stack's copy beside its parts is 14 GB of a chip's 16)
+        layers["moe"] = dict(
+            lax.map(lambda k: swiglu(k, (m.experts_held[1],), f),
+                    jnp.stack([sub[i][1] for i in moe_at])),
+            router=jnp.stack([router(sub[i][2], i) for i in moe_at]),
+            bias=jnp.zeros((len(moe_at), m.n_routed_experts), pd),
+            shared=stacked(lambda k: swiglu(k, (), f * m.n_shared_experts),
+                           [sub[i][3] for i in moe_at]))
+    for name in ("ln1", "ln2") + (("post_attn", "post_mlp")
+                                  if m.sandwich_norm else ()):
+        layers[name] = {"scale": jnp.ones((nl, h), pd)}
+    own = jax.random.normal(keys[nl], (cfg.vocab_size, h))
+    return {"embed": {"tokens": ((own + c) / m.embed_multiplier).astype(pd)},
+            "layers": layers,
+            "final_norm": {"scale": jnp.ones((h,), pd)},
+            "lm_head": dense(keys[nl + 1], (h, cfg.vocab_size), h)}
+
+
 def init_params(cfg: TransformerConfig, key) -> Params:
     """Full model params with per-layer params stacked on axis 0."""
     # nl+5 keys: rows are counter-derived, so rows nl..nl+2 keep the same
@@ -813,6 +1069,8 @@ def init_params(cfg: TransformerConfig, key) -> Params:
     # existing archs); the encoder-only params use the two new rows.
     if cfg.mla is not None:
         return init_latent_params(cfg, key)
+    if cfg.mixed is not None:
+        return init_mixed_params(cfg, key)
     nl = cfg.num_layers
     keys = jax.random.split(key, nl + 5)
     scale = 1.0 / math.sqrt(cfg.hidden_size)

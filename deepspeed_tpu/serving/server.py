@@ -660,7 +660,13 @@ class InferenceServer:
                 shortfall = self.admission.admission_shortfall(eng, need)
                 if shortfall > 0:
                     pc.evict(shortfall)
-            if not self.admission.kv_admissible(eng, need):
+            # a model whose window layers keep a page pool of their own:
+            # admission counts that pool too, by what the request can come
+            # to hold of it
+            fits_window = eng.window_admissible(len(req.tokens)
+                                                + req.remaining)
+            if not (self.admission.kv_admissible(eng, need)
+                    and fits_window):
                 if self._active:
                     if pc:
                         pc.release(adopted)
@@ -668,7 +674,7 @@ class InferenceServer:
                 # Progress guarantee: with the engine idle nothing will
                 # ever free pages, so the watermark must yield — admit if
                 # the request fits at all, else it can never run.
-                if need > eng.free_blocks:
+                if need > eng.free_blocks or not fits_window:
                     if pc:
                         pc.release(adopted)
                     assert self.admission.pop() is req

@@ -323,6 +323,16 @@ EXPECTED_LATENT_SCHEDULE_ARGS = ["expert_rows", "index_pairs", "latent_rows",
                                  "window_keys"]
 
 
+# arguments the ``v2.schedule`` span carries for a model that mixes window
+# and full attention by layer (engine_v2.window_step_counts), and those of
+# its pools' ``v2.state_alloc``: the benchmark's readers read them by name
+EXPECTED_WINDOW_SCHEDULE_ARGS = ["expert_rows", "full_kv_rows", "full_pages",
+                                 "pages_freed", "window_kv_rows",
+                                 "window_pages"]
+EXPECTED_WINDOW_ALLOC_ARGS = ["full_pool_bytes", "window_layers",
+                              "window_pool_bytes"]
+
+
 def check_span_names() -> List[str]:
     """Tracing vocabulary: frozen lists match the modules, every name is
     in the docs span table."""
@@ -364,9 +374,24 @@ def check_span_names() -> List[str]:
     if counted != EXPECTED_LATENT_SCHEDULE_ARGS:
         errors.append("latent.latent_step_counts drifted from the frozen "
                       f"v2.schedule arguments: {counted}")
-    for name in EXPECTED_LATENT_SCHEDULE_ARGS:
+    from deepspeed_tpu.inference.v2.engine_v2 import window_step_counts
+
+    counted = sorted(window_step_counts(
+        [(0, 1)], get_model_config("trinity-tiny"), (1, 1), 0))
+    if counted != EXPECTED_WINDOW_SCHEDULE_ARGS:
+        errors.append("engine_v2.window_step_counts drifted from the frozen "
+                      f"v2.schedule arguments: {counted}")
+    with open(os.path.join(PACKAGE, "inference", "v2", "engine_v2.py"),
+              encoding="utf-8") as f:
+        engine = f.read()
+    for name in EXPECTED_WINDOW_ALLOC_ARGS:
+        if f'"{name}":' not in engine:
+            errors.append(f"v2.state_alloc argument {name!r} is documented "
+                          "and the engine does not set it")
+    for name in (EXPECTED_LATENT_SCHEDULE_ARGS + EXPECTED_WINDOW_SCHEDULE_ARGS
+                 + EXPECTED_WINDOW_ALLOC_ARGS):
         if f"`{name}`" not in docs:
-            errors.append(f"v2.schedule argument {name!r} not documented "
+            errors.append(f"span argument {name!r} not documented "
                           f"in {os.path.basename(DOCS)}")
     return errors
 
