@@ -120,6 +120,21 @@ def _lib_flash(q, k, v, causal, sm_scale, blk):
     return out.swapaxes(1, 2)
 
 
+def flash_executed_shares(seq: int, head_dim: int, group: int, causal: bool,
+                          window: int | None = None):
+    """``(forward, backward)``: the (query, key) pairs the repo kernels
+    execute for one head of such a call over ``seq²`` (0.5 is all a
+    causal mask keeps; ``flash_mha.plan`` has the decision), or None
+    where ``flash_attention(impl="auto")`` does not run them: off the
+    TPU, or past the kernels' budget."""
+    from deepspeed_tpu.ops.pallas.flash_mha import plan, supports
+
+    if not on_tpu() or not supports(seq, head_dim):
+        return None
+    p = plan(seq, head_dim, group, causal, window or None)
+    return p.executed_share_fwd, p.executed_share_bwd
+
+
 def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
                     impl: str = "auto", window: int | None = None):
     """Multi-head attention over [B, S, H, D] tensors.

@@ -6,10 +6,17 @@ TPU replacement for the reference's fused attention CUDA kernels
 for the TPU memory hierarchy rather than ported:
 
 * **Full KV resident in VMEM** per (batch, kv-head) program. At training
-  sequence lengths (S·D ≤ ~512K elements, e.g. 8K × 64) K and V fit on-chip,
-  so each q-block does a single-shot softmax over one [bq, S] score matrix —
-  two big MXU matmuls — instead of the chunked online-softmax loop a GPU
-  kernel needs.
+  sequence lengths (S ≤ 2048) K and V fit on-chip.  Without a mask each
+  q-block does a single-shot softmax over one [bq, S] score matrix — two
+  big MXU matmuls.  Under a causal mask or a sliding window one program
+  a head unrolls, statically, only the LIVE part of that matrix: a q
+  block's keys up to the diagonal (from the window's far edge) in one
+  unmasked product, the chunk the diagonal or the window's edge crosses
+  in a masked one, the one-shot softmax over both (dq and dkv likewise,
+  dkv by k blocks).  The causally dead half is never computed and the
+  live half is never masked; nothing is decided at run time, so skipping
+  costs no grid step and no online-softmax state (:func:`plan` says what
+  a shape executes).
 * **KV-blocked long-context path**: beyond the VMEM-resident budget a
   second set of kernels runs a 4D grid (B, H, nq, nk) with classic online
   softmax over 512-row KV blocks — (m, l, acc) accumulators in VMEM
@@ -41,9 +48,11 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
@@ -56,12 +65,16 @@ _MAX_KV_ELEMS = 1 << 20  # S * D
 # lane-replicated lse/delta residuals in HBM, not VMEM (256K at d=128)
 _MAX_BLOCKED_ELEMS = 1 << 25  # S * D
 # q/k block edges for the KV-blocked path (scores tile = bq×bk×4 B in
-# VMEM).  None → per-call heuristic (_choose_blocks); tools/
-# bench_flash_longseq.py sweeps explicit values on-chip.  Measured r04
-# (v5e, S=32k MHA, full fwd+bwd with dk/dv live): 1024×1024 runs 57.8
-# TF/s (d=64) / 113.4 TF/s (d=128) vs 37.4 / 72.2 for 512×512 — ~1.55×;
-# bigger tiles amortize the per-tile online-softmax state updates and
-# masking work.
+# VMEM).  None → per-call heuristic (_choose_blocks).  Measured r04 (v5e,
+# S=32k MHA, full fwd+bwd with dk/dv live): 1024×1024 runs 57.8 TF/s
+# (d=64) / 113.4 TF/s (d=128) vs 37.4 / 72.2 for 512×512 — ~1.55×.  What
+# a smaller tile pays is per TILE, not per element (PR 47, the same
+# kernels at S=1024 / 2048, tools/bench_flash_blocks.py): the online
+# softmax's (m, l, alpha) are [bq, 1] columns that fill one lane of a
+# vector register in 128 and cost as much as a [bq, 128] strip of the
+# scores each, and every tile is a grid step: 512² tiles ran the forward
+# 2.0x and 256² tiles 3.8x slower than the one-shot block at S=1024
+# although they skip the dead half.  So S ≤ 2048 does not come here.
 _BLK_Q = None
 _BLK_K = None
 
@@ -104,10 +117,13 @@ def _choose_bq(s_pad: int, scores_budget: int = 1 << 20) -> int:
 
 # Resident-path sequence ceiling.  Measured r04 (v5e, d=64, MHA): past
 # ~2k the KV-blocked kernels overtake the one-shot-softmax resident path
-# (fwd+bwd 1.5x faster at 4k, 1.8x at 8k) — the resident bwd's grouped
-# full-sequence q-side stops paying for itself once the score matrix
-# spans many 128-row strips.  Below 2k the two are equal and resident
-# keeps the smaller launch graph.
+# (fwd+bwd 1.5x faster at 4k, 1.8x at 8k): they skip the causally dead
+# tiles, which the one-shot kernels computed and masked, and at those
+# lengths the skipped half outweighs what a tile costs (see _BLK_Q).  Up
+# to 2k the resident kernels now leave the dead half out themselves, by
+# static unrolling (1.43x / 1.58x faster than the one-shot ones at 1k /
+# 2k, PR 47); unrolled code grows with S², which is what keeps the
+# ceiling where it is.
 _RESIDENT_MAX_SEQ = 2048
 
 
@@ -126,6 +142,126 @@ def supports(s: int, d: int) -> bool:
     """Kernel applicability (resident or KV-blocked path)."""
     s_pad = -(-s // 128) * 128
     return s_pad * d <= _MAX_BLOCKED_ELEMS
+
+
+# Block edges of the resident kernels under a mask: q blocks (and the key
+# chunks their diagonal is cut into) for forward and dq, k blocks (and
+# query chunks) for dkv.  Measured PR 47 (v5e, d=64, MHA, the two train
+# cells' shapes; tools/bench_flash_blocks.py, ms a call at S=1024 /
+# 2048): forward 0.326 / 0.514 at 256, 0.336 / 0.516 at 512, 0.367 /
+# 0.555 at 128; dq 0.366 / 0.616, 0.419 / 0.676, 0.364 / 0.630; dkv
+# 0.692 / 1.060, 0.607 / 0.940, 0.917 / 1.725 — dkv's products stream
+# the k block's rows past weights made of the q side, and a block of 256
+# rows does not pay for loading them.
+_LIVE_EDGE_Q = 256
+_LIVE_EDGE_KV = 512
+
+
+def _live_blocks(s_pad128: int):
+    """(q-block edge of forward and dq, k-block edge of dkv), capped at
+    the sequence's own power of two so a short sequence is one tile and
+    not padding.  The larger one is what the sequence is padded to."""
+    cap = 1 << (s_pad128 - 1).bit_length()
+    return min(_LIVE_EDGE_Q, cap), min(_LIVE_EDGE_KV, cap)
+
+
+class KernelPlan(NamedTuple):
+    """What one of the three kernels will run: ``path`` is "oneshot"
+    (resident, the whole [bq, s_pad] score block at once), "live"
+    (resident, the live part of it unrolled statically) or "blocked" (the
+    4D grid); ``executed_pairs`` counts the (query, key) pairs of one head
+    whose score it computes."""
+    path: str
+    bq: int
+    bk: int
+    s_pad: int
+    executed_pairs: int
+
+
+class FlashPlan(NamedTuple):
+    fwd: KernelPlan
+    dq: KernelPlan
+    dkv: KernelPlan
+    live_pairs: int        # pairs of one head the mask keeps
+    s: int
+
+    @property
+    def executed_share_fwd(self) -> float:
+        """Executed pairs of the forward over S²."""
+        return self.fwd.executed_pairs / (self.s * self.s)
+
+    @property
+    def executed_share_bwd(self) -> float:
+        """The same for the backward, dq's three and dkv's four matrix
+        products a pair weighed together."""
+        return ((3 * self.dq.executed_pairs + 4 * self.dkv.executed_pairs)
+                / (7 * self.s * self.s))
+
+
+def _kernel_plan(path, bq, bk, s, causal, window, by_k=False, s_pad=None):
+    if s_pad is None:
+        step = max(bq, bk)
+        s_pad = -(-s // step) * step
+    if path == "oneshot":
+        # forward and dq: [bq, s_pad] a q block; dkv: [s_pad, bk] a k block
+        return KernelPlan(path, bq, bk, s_pad, s_pad * s_pad)
+    if path == "live":
+        if by_k:
+            lo, hi = _live_q_chunks(np.arange(s_pad // bk), bq, bk, s,
+                                    causal, window)
+        else:
+            lo, hi = _live_k_chunks(np.arange(s_pad // bq), bq, bk, s,
+                                    causal, window)
+        tiles = int(np.sum(np.maximum(hi - lo, 0)))
+    else:
+        iq = np.arange(s_pad // bq)[:, None]
+        ik = np.arange(s_pad // bk)[None, :]
+        alive = _tile_alive(iq, ik, bq, bk, causal, window)
+        tiles = iq.size * ik.size if alive is None else int(np.sum(alive))
+    return KernelPlan(path, bq, bk, s_pad, tiles * bq * bk)
+
+
+def plan(s: int, d: int, group: int = 1, causal: bool = True,
+         window: int | None = None) -> FlashPlan:
+    """The path and block edges the three kernels take for a call of
+    these shapes, and the (query, key) pairs they execute against the
+    pairs the mask keeps — the ONE decision `_fwd` and `_bwd_impl` run
+    on, from shapes alone.
+
+    A call with nothing to skip (no causal mask, no window) keeps the
+    one-shot resident kernels; under a mask the resident lengths run
+    their live part only; past `_RESIDENT_MAX_SEQ` (or the K+V budget) the
+    4D KV-blocked grid runs; the grouped dkv kernel's q side decides
+    alone whether the backward can stay resident (_resident_bwd_fits)."""
+    if not supports(s, d):
+        raise ValueError(
+            f"flash_mha: S={s}, D={d} exceeds the KV-blocked "
+            f"ceiling (S_pad*D <= {_MAX_BLOCKED_ELEMS}); shard the "
+            "sequence (Ulysses/FPDT) before attention")
+    s_pad128 = -(-s // 128) * 128
+    rows = np.arange(s)
+    first = np.maximum(rows - (window - 1), 0) if window is not None else 0
+    last = rows if causal else s - 1
+    live_pairs = int(np.sum(np.broadcast_to(last - first + 1, (s,))))
+    kp = functools.partial(_kernel_plan, s=s, causal=causal, window=window)
+    blocked = kp("blocked", *_choose_blocks(group))
+    if not _supports_resident(s, d):
+        return FlashPlan(blocked, blocked, blocked, live_pairs, s)
+    if causal or window is not None:
+        eq, ekv = _live_blocks(s_pad128)
+        s_pad = -(-s // ekv) * ekv
+        fwd = kp("live", eq, eq, s_pad=s_pad)
+        if _resident_bwd_fits(s_pad, d, group, ekv,
+                              rows=min(_LIVE_SEG, s_pad)):
+            return FlashPlan(fwd, fwd,
+                             kp("live", ekv, ekv, by_k=True, s_pad=s_pad),
+                             live_pairs, s)
+    else:
+        bq = _choose_bq(s_pad128)
+        fwd = kp("oneshot", bq, bq)
+        if _resident_bwd_fits(s_pad128, d, group, bq):
+            return FlashPlan(fwd, fwd, fwd, live_pairs, s)
+    return FlashPlan(fwd, blocked, blocked, live_pairs, s)
 
 
 # ----------------------------------------------------------------------
@@ -259,7 +395,7 @@ def _tile_alive(iq, ik, bq, bk, causal, window):
         pred = ik * bk <= iq * bq + bq - 1
     if window is not None:
         wa = iq * bq - ik * bk - bk + 1 < window
-        pred = wa if pred is None else jnp.logical_and(pred, wa)
+        pred = wa if pred is None else pred & wa
     return pred
 
 
@@ -440,6 +576,176 @@ def _dkv_kernel_blocked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+# ----------------------------------------------------------------------
+# Resident kernels under a mask (causal and/or window, S <= 2048): one
+# program per (batch, head) — dkv: per kv head — with q, K and V whole in
+# VMEM, and the LIVE part of the score matrix unrolled statically: a q
+# block's keys up to the diagonal (from the window's far edge) in one
+# unmasked product, the chunks a mask crosses in a masked one, and the
+# one-shot softmax over both.  Nothing is decided at run time, so there
+# is no grid step, no online-softmax state and no mask on an interior
+# tile to pay for leaving the dead half out (what each costs at these
+# lengths: tools/bench_flash_blocks.py, PERF.md §6 PR 47).
+# ----------------------------------------------------------------------
+def _live_k_chunks(iq, bq, bk, s_real, causal, window):
+    """[lo, hi) of the bk-row key chunks a q block of bq rows at ``iq``
+    sees: up to the diagonal (causal) and to the last real key, from the
+    window's far edge.  Host integers (or numpy arrays of them)."""
+    hi = -(-s_real // bk)
+    if causal:
+        hi = np.minimum(hi, (iq * bq + bq - 1) // bk + 1)
+    lo = np.zeros_like(hi)
+    if window is not None:
+        lo = np.maximum(iq * bq - (window - 1), 0) // bk
+    return lo, hi
+
+
+def _live_q_chunks(ik, bq, bk, s_real, causal, window):
+    """[lo, hi) of the bq-row query chunks that see a k block of bk rows
+    at ``ik``: from the diagonal (causal) to the window's near edge and
+    the last real query."""
+    hi = -(-s_real // bq) + 0 * ik
+    if window is not None:
+        hi = np.minimum(hi, (ik * bk + bk - 1 + window - 1) // bq + 1)
+    lo = (ik * bk) // bq if causal else np.zeros_like(hi)
+    return lo, hi
+
+
+# rows (or keys) of one unmasked product: bounds the fp32 [rows, bk]
+# intermediates of the dkv kernel, whose q side is VMEM's largest tenant
+_LIVE_SEG = 1024
+
+
+def _segments(lo, hi, edge, interior):
+    """Static runs ``(start, stop, masked)`` in rows, covering chunks
+    [lo, hi) of ``edge`` rows each: consecutive chunks that need no mask
+    (``interior(i)``) merge into one product of at most _LIVE_SEG rows,
+    consecutive chunks a mask crosses into one masked product."""
+    runs = []
+    for i in range(int(lo), int(hi)):
+        masked = not interior(i)
+        if (runs and runs[-1][2] == masked and runs[-1][1] == i * edge
+                and (masked or (i + 1) * edge - runs[-1][0] <= _LIVE_SEG)):
+            runs[-1] = (runs[-1][0], (i + 1) * edge, masked)
+        else:
+            runs.append((i * edge, (i + 1) * edge, masked))
+    return runs
+
+
+def _k_segments(iq, bq, bk, s_real, causal, window):
+    lo, hi = _live_k_chunks(iq, bq, bk, s_real, causal, window)
+    return _segments(lo, hi, bk, lambda ik: _tile_interior(
+        iq, ik, bq, bk, s_real, causal, window))
+
+
+def _fwd_kernel_live(q_ref, k_ref, v_ref, o_ref, *rest,
+                     sm_scale, causal, bq, bk, s_pad, s_real, window=None):
+    lse_ref = rest[0] if rest else None
+    for iq in range(s_pad // bq):                           # static
+        rows = slice(iq * bq, (iq + 1) * bq)
+        q = q_ref[0, 0, rows, :]
+        parts = []
+        for k0, k1, masked in _k_segments(iq, bq, bk, s_real, causal,
+                                          window):
+            s = _scores(q, k_ref[0, 0, k0:k1, :], sm_scale)
+            if masked:
+                s = jnp.where(_block_mask(bq, k1 - k0, iq * bq, k0, s_real,
+                                          causal, window=window),
+                              s, NEG_INF)
+            parts.append((s, k0, k1))
+        if not parts:       # pad rows a window cuts off from every real key
+            o_ref[0, 0, rows, :] = jnp.zeros((bq, o_ref.shape[-1]),
+                                             o_ref.dtype)
+            if lse_ref is not None:
+                lse_ref[0, 0, rows, :] = jnp.zeros((bq, 128), jnp.float32)
+            continue
+        m = functools.reduce(jnp.maximum,
+                             [jnp.max(s, axis=1, keepdims=True)
+                              for s, _, _ in parts])            # [bq, 1]
+        l = o = 0.0
+        for s, k0, k1 in parts:
+            p = jnp.exp(s - m)                                  # fp32
+            l = l + jnp.sum(p, axis=1, keepdims=True)
+            v = v_ref[0, 0, k0:k1, :]
+            o = o + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        o_ref[0, 0, rows, :] = (o / l).astype(o_ref.dtype)
+        if lse_ref is not None:
+            # 128-lane-replicated, as _fwd_kernel writes it
+            lse_ref[0, 0, rows, :] = jnp.broadcast_to(m + jnp.log(l),
+                                                      (bq, 128))
+
+
+def _dq_kernel_live(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                    *, sm_scale, causal, bq, bk, s_pad, s_real, window=None):
+    for iq in range(s_pad // bq):                           # static
+        rows = slice(iq * bq, (iq + 1) * bq)
+        q = q_ref[0, 0, rows, :]
+        do = do_ref[0, 0, rows, :]
+        lse = lse_ref[0, 0, rows, :][:, 0:1]                    # [bq, 1]
+        delta = delta_ref[0, 0, rows, :][:, 0:1]
+        dq = jnp.zeros(q.shape, jnp.float32)
+        for k0, k1, masked in _k_segments(iq, bq, bk, s_real, causal,
+                                          window):
+            k = k_ref[0, 0, k0:k1, :]
+            s = _scores(q, k, sm_scale)
+            if masked:
+                s = jnp.where(_block_mask(bq, k1 - k0, iq * bq, k0, s_real,
+                                          causal, window=window),
+                              s, NEG_INF)
+            p = jnp.exp(s - lse)
+            dp = jax.lax.dot_general(do, v_ref[0, 0, k0:k1, :],
+                                     (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - delta) * sm_scale
+            dq += jax.lax.dot_general(ds.astype(k.dtype), k,
+                                      (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
+
+
+def _dkv_kernel_live(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dk_ref, dv_ref, *, sm_scale, causal, bq, bk, s_pad,
+                     s_real, group, window=None):
+    for ik in range(s_pad // bk):                           # static
+        cols = slice(ik * bk, (ik + 1) * bk)
+        k = k_ref[0, 0, cols, :]                                # [bk, d]
+        v = v_ref[0, 0, cols, :]
+        dk = jnp.zeros(k.shape, jnp.float32)
+        dv = jnp.zeros(v.shape, jnp.float32)
+        lo, hi = _live_q_chunks(ik, bq, bk, s_real, causal, window)
+        segs = _segments(lo, hi, bq, lambda iq: _tile_interior(
+            iq, ik, bq, bk, s_real, causal, window, check_rows=True))
+        for g in range(group):                              # static loop
+            for r0, r1, masked in segs:
+                q = q_ref[0, g, r0:r1, :]
+                do = do_ref[0, g, r0:r1, :]
+                lse = lse_ref[0, g, r0:r1, :][:, 0:1]
+                delta = delta_ref[0, g, r0:r1, :][:, 0:1]
+                s = _scores(q, k, sm_scale)                     # [rows, bk]
+                if masked:
+                    valid = _block_mask(r1 - r0, bk, r0, ik * bk, s_real,
+                                        causal, with_rows=True,
+                                        window=window)
+                    s = jnp.where(valid, s, NEG_INF)
+                p = jnp.exp(s - lse)
+                if masked:
+                    # pad query rows carry lse = 0; kill them with the mask
+                    p = jnp.where(valid, p, 0.0)
+                dv += jax.lax.dot_general(
+                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                ds = p * (dp - delta) * sm_scale
+                dk += jax.lax.dot_general(
+                    ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+        dk_ref[0, 0, cols, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0, cols, :] = dv.astype(dv_ref.dtype)
 
 
 # ----------------------------------------------------------------------
@@ -990,20 +1296,24 @@ def _pad_seq(x, s_pad):
 
 def _fwd(q, k, v, causal, sm_scale, need_lse=True, window=None):
     b, hq, s_real, d = q.shape
-    if not _supports_resident(s_real, d):
-        if not supports(s_real, d):
-            raise ValueError(
-                f"flash_mha: S={s_real}, D={d} exceeds the KV-blocked "
-                f"ceiling (S_pad*D <= {_MAX_BLOCKED_ELEMS}); shard the "
-                "sequence (Ulysses/FPDT) before attention")
-        return _fwd_blocked(q, k, v, causal, sm_scale, need_lse=need_lse,
-                            window=window)
     hkv = k.shape[1]
     group = hq // hkv
-    s_pad = -(-s_real // 128) * 128
-    bq = _choose_bq(s_pad)
-    s_pad = -(-s_real // bq) * bq  # pad to a whole number of q blocks
+    kern = plan(s_real, d, group, causal, window).fwd
+    if kern.path == "blocked":
+        return _fwd_blocked(q, k, v, causal, sm_scale, need_lse=need_lse,
+                            window=window)
+    bq, s_pad = kern.bq, kern.s_pad
     qp, kp, vp = _pad_seq(q, s_pad), _pad_seq(k, s_pad), _pad_seq(v, s_pad)
+    if kern.path == "live":
+        # one program a head: the live triangle is unrolled in the kernel
+        bq = s_pad
+        kernel = functools.partial(_fwd_kernel_live, sm_scale=sm_scale,
+                                   causal=causal, bq=kern.bq, bk=kern.bk,
+                                   s_pad=s_pad, s_real=s_real, window=window)
+    else:
+        kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
+                                   causal=causal, bq=bq, s_pad=s_pad,
+                                   s_real=s_real, window=window)
     grid = (b, hq, s_pad // bq)
 
     kv_spec = pl.BlockSpec((1, 1, s_pad, d),
@@ -1011,8 +1321,7 @@ def _fwd(q, k, v, causal, sm_scale, need_lse=True, window=None):
     q_blk = pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq: (ib, ih, iq, 0))
     lse_blk = pl.BlockSpec((1, 1, bq, 128), lambda ib, ih, iq: (ib, ih, iq, 0))
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                          bq=bq, s_pad=s_pad, s_real=s_real, window=window),
+        kernel,
         grid=grid,
         interpret=INTERPRET,
         name="flash_fwd",
@@ -1183,18 +1492,21 @@ def _bwd_blocked(q, k, v, o, lse, g, causal, sm_scale, window=None):
     return dq[:, :, :s_real], dk[:, :, :s_real], dv[:, :, :s_real]
 
 
-def _resident_bwd_fits(s_pad: int, d: int, group: int, bq: int) -> bool:
+def _resident_bwd_fits(s_pad: int, d: int, group: int, bq: int,
+                       rows: int | None = None) -> bool:
     """Whether the grouped resident dkv kernel fits scoped VMEM (16 MB).
 
     It holds the whole [group, s_pad] q-side per program — q and do in
     bf16 plus the 128-lane-replicated fp32 lse/delta — double-buffered by
-    the Pallas pipeline, with ~3 live [s_pad, bq] fp32 score
-    intermediates.  GQA multiplies the q-side by `group`, so e.g.
-    group=4, S=1024, D=128 (Llama-3 geometry) overruns the limit even
-    though S·D is within the resident budget; fall back to the
-    KV-blocked backward there."""
+    the Pallas pipeline, with ~3 live [rows, bq] fp32 score
+    intermediates: ``rows`` is s_pad for the one-shot kernel and at most
+    _LIVE_SEG for the live kernel's products, which is how S=2048, d=64
+    (opt-1.3b) stays resident under a mask.  GQA multiplies the q-side
+    by `group`, so e.g. group=4, S=1024, D=128 (Llama-3 geometry)
+    overruns the limit on the q side alone even though S·D is within the
+    resident budget; fall back to the KV-blocked backward there."""
     blocks = group * s_pad * (2 * d * 2 + 2 * 128 * 4)  # q+do, lse+delta
-    interm = 3 * s_pad * bq * 4
+    interm = 3 * (s_pad if rows is None else rows) * bq * 4
     return 2 * blocks + interm <= 12 * (1 << 20)
 
 
@@ -1202,24 +1514,39 @@ def _bwd_impl(q, k, v, o, lse, g, causal, sm_scale, window=None):
     b, hq, s_real, d = q.shape
     hkv = k.shape[1]
     group = hq // hkv
-    s_pad128 = -(-s_real // 128) * 128
-    if not _supports_resident(s_real, d) or not _resident_bwd_fits(
-            s_pad128, d, group, _choose_bq(s_pad128)):
+    p = plan(s_real, d, group, causal, window)
+    if p.dq.path == "blocked":
         return _bwd_blocked(q, k, v, o, lse, g, causal, sm_scale,
                             window=window)
-    s_pad = s_pad128
-    bq = _choose_bq(s_pad)
-    s_pad = -(-s_real // bq) * bq
+    bq, s_pad = p.dq.bq, p.dq.s_pad
+    bk = p.dkv.bk
 
     qp, kp, vp = _pad_seq(q, s_pad), _pad_seq(k, s_pad), _pad_seq(v, s_pad)
     gp = _pad_seq(g, s_pad)
     lsep, deltap = bwd_lane_residuals(o, g, lse, s_pad)
 
+    if p.dq.path == "live":
+        # one program a head (a kv head): the kernels unroll their blocks
+        dq_kernel = functools.partial(
+            _dq_kernel_live, sm_scale=sm_scale, causal=causal, bq=bq,
+            bk=p.dq.bk, s_pad=s_pad, s_real=s_real, window=window)
+        dkv_kernel = functools.partial(
+            _dkv_kernel_live, sm_scale=sm_scale, causal=causal,
+            bq=p.dkv.bq, bk=bk, s_pad=s_pad, s_real=s_real, group=group,
+            window=window)
+        bq = bk = s_pad
+    else:
+        dq_kernel = functools.partial(
+            _dq_kernel, sm_scale=sm_scale, causal=causal, bq=bq,
+            s_pad=s_pad, s_real=s_real, window=window)
+        dkv_kernel = functools.partial(
+            _dkv_kernel, sm_scale=sm_scale, causal=causal, bk=bk,
+            s_pad=s_pad, s_real=s_real, group=group, window=window)
+
     kv_spec = pl.BlockSpec((1, 1, s_pad, d),
                            lambda ib, ih, iq: (ib, ih // group, 0, 0))
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          bq=bq, s_pad=s_pad, s_real=s_real, window=window),
+        dq_kernel,
         grid=(b, hq, s_pad // bq),
         interpret=INTERPRET,
         name="flash_bwd_dq",
@@ -1236,15 +1563,12 @@ def _bwd_impl(q, k, v, o, lse, g, causal, sm_scale, window=None):
         out_shape=jax.ShapeDtypeStruct((b, hq, s_pad, d), q.dtype),
     )(qp, kp, vp, gp, lsep, deltap)
 
-    bk = bq
     grp_spec = pl.BlockSpec((1, group, s_pad, d),
                             lambda ib, ihkv, ik: (ib, ihkv, 0, 0))
     grp_lane_spec = pl.BlockSpec((1, group, s_pad, 128),
                                  lambda ib, ihkv, ik: (ib, ihkv, 0, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          bk=bk, s_pad=s_pad, s_real=s_real, group=group,
-                          window=window),
+        dkv_kernel,
         grid=(b, hkv, s_pad // bk),
         interpret=INTERPRET,
         name="flash_bwd_dkv",

@@ -2048,6 +2048,8 @@ class DeepSpeedEngine:
         self._step_span = sp = tr.span("train.step", self._train_trace_id)
         if tr.enabled:
             sp.set(step=self.global_steps + 1)
+            if not self._compiled_step_done:
+                sp.set(**self._flash_executed_shares(data))
         wd = self._watchdog
         if wd is not None and self._compiled_step_done:
             wd.resume()     # arm for this step (no-op deadline otherwise)
@@ -2074,6 +2076,28 @@ class DeepSpeedEngine:
                 wd.pause()  # inter-step time is not a stall
         self._compiled_step_done = True
         return loss
+
+    def _flash_executed_shares(self, data) -> Dict[str, float]:
+        """Arguments of a run's first ``train.step`` span where the
+        model's attention runs the repo flash kernels: the share of S²
+        they execute, forward and backward, for the batch's sequence
+        length (``ops/pallas/flash_mha.plan``; 0.5 is all a causal mask
+        keeps).  Empty where attention takes another path."""
+        mc = self.model_config
+        ids = data.get("input_ids") if isinstance(data, dict) else None
+        if (mc is None or ids is None or mc.use_alibi or mc.alt_window
+                or mc.attn_impl not in ("pallas_flash", "auto")
+                or (mc.seq_impl == "ring" and self.topology.sp_size > 1)):
+            return {}
+        from deepspeed_tpu.ops.flash_attention import flash_executed_shares
+
+        shares = flash_executed_shares(
+            np.shape(ids)[-1], mc.dim_per_head, mc.num_heads // mc.kv_heads,
+            mc.causal, mc.sliding_window)
+        if shares is None:
+            return {}
+        return {"flash_executed_share_fwd": round(shares[0], 4),
+                "flash_executed_share_bwd": round(shares[1], 4)}
 
     # ------------------------------------------------------------------
     # Telemetry (unified per-step StepRecord; telemetry/)

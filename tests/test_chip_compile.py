@@ -70,6 +70,7 @@ def _compile(fn, *args):
 # [B, Hq, Hkv, S, D, window] — flash_mha takes [B, H, S, D]
 FLASH_SHAPES = {
     "gpt2-350m": (8, 16, 16, 1024, 64, None),
+    "opt-1.3b": (2, 32, 32, 2048, 64, None),     # a chip's share of zero3
     "mistral-7b-2k": (2, 32, 8, 2048, 128, 4096),
     "mistral-7b-8k": (1, 32, 8, 8192, 128, 4096),
 }
@@ -692,6 +693,60 @@ def test_flash_kernel_names_survive_checkpoint(chip):
     assert all(n.startswith("flash_") for n in names), names
     fwd_names = _kernel_names(_compile(fwd, *args))
     assert [n.split(".")[0] for n in fwd_names] == ["flash_fwd"]
+
+
+def _pallas_calls(jaxpr, out=None):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones included."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn)
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("name", ["gpt2-350m", "opt-1.3b"])
+def test_flash_kernels_execute_what_plan_says(chip, name):
+    """The two train cells' shapes: three kernels under the ledger's
+    names, and the score products INSIDE them cover the pairs ``plan``
+    counts and no more — a later edit cannot quietly bring the whole
+    [bq, S] score block back (it would read S² here, as it did before PR
+    47).  A score product is a ``[rows, d] x [keys, d]`` contraction: one
+    a pair in the forward, two (scores and ``do v^T``) in dq and dkv."""
+    b, hq, hkv, s, d, window = FLASH_SHAPES[name]
+    p = flash_mha_mod.plan(s, d, hq // hkv, True, window)
+    assert {p.fwd.path, p.dq.path, p.dkv.path} == {"live"}
+
+    def loss(q, k, v):
+        return jnp.sum(flash_mha_mod.flash_mha(q, k, v, True, None,
+                                               window).astype(F32))
+
+    args = (chip((b, hq, s, d), BF16), chip((b, hkv, s, d), BF16),
+            chip((b, hkv, s, d), BF16))
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    names = _kernel_names(_compile(grad, *args))
+    assert len(names) == 3, names       # (a bare grad wraps them: jvp_...)
+    for want in ("flash_fwd", "flash_bwd_dq_", "flash_bwd_dkv"):
+        assert sum(want in n + "_" for n in names) == 1, (want, names)
+    executed = {}
+    for eqn in _pallas_calls(jax.make_jaxpr(grad)(*args).jaxpr):
+        pairs = 0
+        for inner in eqn.params["jaxpr"].eqns:
+            if (inner.primitive.name == "dot_general"
+                    and inner.params["dimension_numbers"]
+                    == (((1,), (1,)), ((), ()))):
+                rows, keys = inner.outvars[0].aval.shape
+                assert keys < s, (eqn.params["name"], rows, keys)
+                pairs += rows * keys
+        executed[eqn.params["name"]] = pairs
+    assert executed == {"flash_fwd": p.fwd.executed_pairs,
+                        "flash_bwd_dq": 2 * p.dq.executed_pairs,
+                        "flash_bwd_dkv": 2 * p.dkv.executed_pairs}
+    assert p.fwd.executed_pairs <= 0.65 * s * s
 
 
 @pytest.mark.parametrize("kv_dtype,want", [(BF16, "paged_qblock"),

@@ -96,6 +96,18 @@ def test_supports_budget():
     # (r04 crossover study) even though 8192x64 fits the VMEM budget
     assert not fm._supports_resident(8192, 64)
     assert not fm._supports_resident(16384, 128)
+    # ...and plan() is what the calls run on: resident lengths run their
+    # live part only under a mask and keep the one-shot kernels without one
+    assert fm.plan(1024, 64).fwd.path == "live"
+    assert fm.plan(2048, 128, group=4, window=4096).fwd.path == "live"
+    assert fm.plan(1024, 64, causal=False).fwd.path == "oneshot"
+    for s, d in ((8192, 64), (16384, 128)):
+        p = fm.plan(s, d)
+        assert {p.fwd.path, p.dq.path, p.dkv.path} == {"blocked"}
+        assert (p.fwd.bq, p.fwd.bk) == (1024, 1024)   # r04's choice stands
+    assert fm.plan(8192, 128, group=4).dkv[:3] == ("blocked", 512, 1024)
+    with pytest.raises(ValueError, match="KV-blocked ceiling"):
+        fm.plan(1 << 20, 128)
 
 
 def test_resident_bwd_vmem_budget():
@@ -103,9 +115,128 @@ def test_resident_bwd_vmem_budget():
     Llama-3 geometry (group=4, S=1024, D=128) measured 17.55M against the
     16M scoped-vmem limit on a real v5e (r04), so the backward must route
     to the KV-blocked path there while the r02-tuned MHA d=64 config
-    keeps the resident fast path."""
+    keeps the resident fast path — and under a mask the live kernel's
+    segment-sized intermediates let S=2048, d=64 (opt-1.3b) stay resident
+    too, which the one-shot kernel's [s_pad, bq] ones did not."""
     assert not fm._resident_bwd_fits(1024, 128, 4, fm._choose_bq(1024))
     assert fm._resident_bwd_fits(1024, 64, 1, fm._choose_bq(1024))
+    assert not fm._resident_bwd_fits(2048, 64, 1, fm._choose_bq(2048))
+    llama, gpt2, opt = (fm.plan(1024, 128, group=4), fm.plan(1024, 64),
+                        fm.plan(2048, 64))
+    assert (llama.fwd.path, llama.dq.path, llama.dkv.path) == (
+        "live", "blocked", "blocked")
+    assert (llama.dkv.bq, llama.dkv.bk) == (512, 1024)
+    for p in (gpt2, opt):
+        assert (p.fwd.path, p.dq.path, p.dkv.path) == ("live",) * 3
+    dense = fm.plan(2048, 64, causal=False)    # nothing to skip: as before
+    assert (dense.fwd.path, dense.dq.path) == ("oneshot", "blocked")
+
+
+@pytest.mark.parametrize("s,d", [(1024, 64), (2048, 64)])
+def test_plan_executed_share(s, d):
+    """The two train cells' shapes: under the causal mask forward and dq
+    execute at most 0.65 of S² (1.0 before PR 47) and dkv, whose products
+    need k blocks of 512 rows to pay for their weights (the block sweep:
+    flash_mha._LIVE_EDGE_KV), at most 0.75 (1.0 before for gpt2, 0.75 for
+    opt's blocked backward, now 0.625); never less than the pairs the
+    mask keeps, and a dense call executes all of it."""
+    p = fm.plan(s, d)
+    assert p.live_pairs == s * (s + 1) // 2
+    for kp, most in ((p.fwd, 0.65), (p.dq, 0.65), (p.dkv, 0.75)):
+        assert p.live_pairs <= kp.executed_pairs <= most * s * s, kp
+    assert 0.5 < p.executed_share_fwd <= 0.65
+    assert 0.5 < p.executed_share_bwd <= 0.70
+    dense = fm.plan(s, d, causal=False)
+    assert dense.live_pairs == s * s
+    assert dense.executed_share_fwd == dense.executed_share_bwd == 1.0
+    # a window narrower than S cuts the far side as well
+    w = fm.plan(s, d, window=256)
+    assert w.live_pairs == sum(min(r + 1, 256) for r in range(s))
+    assert w.live_pairs <= w.fwd.executed_pairs < p.fwd.executed_pairs
+    assert w.live_pairs <= w.dkv.executed_pairs <= p.dkv.executed_pairs
+
+
+LIVE_CASES = [
+    # b, hq, hkv, s, d, window, backward's path: the skip ENGAGES (four q
+    # blocks or more); the grouped dkv kernel's q side is what VMEM refuses
+    pytest.param(1, 2, 2, 1024, 64, None, "live", id="mha-1k"),
+    pytest.param(1, 1, 1, 2048, 64, None, "live", id="mha-2k-opt"),
+    pytest.param(1, 4, 2, 1024, 64, None, "live", id="gqa2"),
+    pytest.param(1, 2, 1, 1024, 64, None, "live", id="mqa"),
+    pytest.param(1, 4, 1, 1024, 64, None, "blocked", id="mqa4-bwd-blocked"),
+    pytest.param(1, 2, 2, 1000, 64, None, "live", id="odd-length"),
+    pytest.param(1, 2, 2, 1024, 64, 300, "live", id="window-300"),
+    pytest.param(1, 2, 2, 1100, 64, 200, "live", id="window-200-odd"),
+    pytest.param(1, 2, 1, 1100, 64, 200, "blocked", id="window-200-odd-gqa"),
+    pytest.param(1, 2, 2, 1024, 64, 64, "live", id="window-one-tile"),
+    pytest.param(1, 4, 1, 1024, 128, 600, "blocked", id="d128-bwd-blocked"),
+]
+
+
+@pytest.mark.parametrize("b,hq,hkv,s,d,window,bwd", LIVE_CASES)
+def test_live_part_parity(b, hq, hkv, s, d, window, bwd):
+    """Forward and gradients against the dense reference at shapes where
+    the resident kernels have dead chunks to leave out, interior chunks
+    to run unmasked and edge chunks to mask: causal, causal +
+    window, tail padding, GQA and MQA; ``window-one-tile``: every row's
+    whole live range lies in one or two tiles."""
+    p = fm.plan(s, d, hq // hkv, True, window)
+    assert p.fwd.path == "live" and p.fwd.s_pad // p.fwd.bq >= 4
+    assert p.fwd.executed_pairs < p.fwd.s_pad ** 2
+    assert p.dq.path == p.dkv.path == bwd
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (b, hq, s, d), jnp.float32)
+    k = jax.random.normal(ks[1], (b, hkv, s, d), jnp.float32)
+    v = jax.random.normal(ks[2], (b, hkv, s, d), jnp.float32)
+    w = jnp.linspace(0.0, 1.0, d)
+    scale = 1.0 / np.sqrt(d)
+
+    def ref(q, k, v):
+        if window is None:
+            return _ref_attn(q, k, v, True, scale)
+        return _ref_attn_window(q, k, v, True, scale, window)
+
+    def ours(q, k, v):
+        return fm.flash_mha(q, k, v, True, None, window)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * w).sum()
+
+    assert float(jnp.max(jnp.abs(ours(q, k, v) - ref(q, k, v)))) < 5e-5
+    g1 = jax.grad(loss(ours), argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(g1, g2):
+        rel = float(jnp.linalg.norm((a - b_).ravel())
+                    / (jnp.linalg.norm(b_.ravel()) + 1e-9))
+        assert rel < 1e-4, rel
+
+
+def test_live_part_parity_bf16():
+    """The train cells' precision (bf16 operands, fp32 scores) at the
+    file's bf16 tolerances, four q blocks."""
+    b, hq, hkv, s, d = 1, 2, 2, 1024, 64
+    ks = jax.random.split(jax.random.PRNGKey(12), 3)
+    q = jax.random.normal(ks[0], (b, hq, s, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, hkv, s, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, hkv, s, d), jnp.bfloat16)
+    w = jnp.linspace(0.0, 1.0, d)
+    scale = 1.0 / np.sqrt(d)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * w).sum()
+
+    out = fm.flash_mha(q, k, v, True)
+    ref = _ref_attn(q, k, v, True, scale)
+    assert float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref))) < 0.05
+    g1 = jax.grad(loss(lambda q, k, v: fm.flash_mha(q, k, v, True)),
+                  argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss(lambda q, k, v: _ref_attn(q, k, v, True, scale)),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(g1, g2):
+        a32, b32 = a.astype(jnp.float32), b_.astype(jnp.float32)
+        rel = float(jnp.linalg.norm((a32 - b32).ravel())
+                    / (jnp.linalg.norm(b32.ravel()) + 1e-9))
+        assert rel < 0.02, rel
 
 
 def test_gqa_d128_grad_parity_blocked_fallback():

@@ -235,6 +235,57 @@ def test_train_engine_tracing_alone_builds_no_hub(tmp_path):
     assert len(exported) == len(events)
 
 
+@pytest.mark.parametrize("seq,on_chip,want", [
+    (1024, True, "plan"),      # the repo kernels run: what plan() executes
+    (1024, False, None),       # off the TPU attention is the XLA path
+])
+def test_first_train_step_span_carries_flash_executed_shares(
+        monkeypatch, seq, on_chip, want):
+    """A run's first ``train.step`` span says what share of S² the flash
+    kernels execute for the batch's length, by the rule the kernels
+    themselves are chosen by; later steps and other attention paths say
+    nothing."""
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.ops import flash_attention as fa
+    from deepspeed_tpu.ops.pallas.flash_mha import plan
+
+    model = get_model_config("gpt2-tiny")
+    engine, _, _, _ = ds.initialize(model=model, config={
+        "train_micro_batch_size_per_gpu": 1,
+        "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+        "steps_per_print": 10_000,
+        "telemetry": {"tracing": {"enabled": True}}})
+    monkeypatch.setattr(fa, "on_tpu", lambda: on_chip)
+    got = engine._flash_executed_shares(
+        {"input_ids": np.zeros((8, seq), np.int32)})
+    if want is None:
+        assert got == {}
+    else:
+        p = plan(seq, model.dim_per_head, 1, True, None)
+        assert got == {
+            "flash_executed_share_fwd": round(p.executed_share_fwd, 4),
+            "flash_executed_share_bwd": round(p.executed_share_bwd, 4)}
+        assert 0.5 < got["flash_executed_share_fwd"] <= 0.65
+    assert engine._flash_executed_shares([1, 2]) == {}     # no token ids
+    # on the spans: the first step's only (the count is stubbed, so the
+    # step itself runs the CPU's XLA attention)
+    monkeypatch.setattr(fa, "on_tpu", lambda: False)
+    monkeypatch.setattr(fa, "flash_executed_shares",
+                        lambda *a, **k: (0.625, 0.6))
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, model.vocab_size, size=(8, 33), dtype=np.int32)
+    batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:].astype(np.int32)}
+    for _ in range(2):
+        engine.train_batch(batch)
+    first, second = [e["args"] for e in engine.tracer.snapshot()
+                     if e["name"] == "train.step"]
+    assert (first["flash_executed_share_fwd"],
+            first["flash_executed_share_bwd"]) == (0.625, 0.6)
+    assert "flash_executed_share_fwd" not in second
+    engine.destroy()
+
+
 def test_train_engine_without_tracing_shares_the_null_tracer():
     import deepspeed_tpu as ds
     from deepspeed_tpu.models import get_model_config

@@ -333,6 +333,12 @@ EXPECTED_WINDOW_ALLOC_ARGS = ["full_pool_bytes", "window_layers",
                               "window_pool_bytes"]
 
 
+# arguments a run's first ``train.step`` span carries where the model
+# runs the repo flash kernels (runtime/engine.py _flash_executed_shares)
+EXPECTED_TRAIN_STEP_ARGS = ["flash_executed_share_bwd",
+                            "flash_executed_share_fwd"]
+
+
 def check_span_names() -> List[str]:
     """Tracing vocabulary: frozen lists match the modules, every name is
     in the docs span table."""
@@ -388,8 +394,15 @@ def check_span_names() -> List[str]:
         if f'"{name}":' not in engine:
             errors.append(f"v2.state_alloc argument {name!r} is documented "
                           "and the engine does not set it")
+    with open(os.path.join(PACKAGE, "runtime", "engine.py"),
+              encoding="utf-8") as f:
+        engine = f.read()
+    for name in EXPECTED_TRAIN_STEP_ARGS:
+        if f'"{name}":' not in engine:
+            errors.append(f"train.step argument {name!r} is documented and "
+                          "the engine does not set it")
     for name in (EXPECTED_LATENT_SCHEDULE_ARGS + EXPECTED_WINDOW_SCHEDULE_ARGS
-                 + EXPECTED_WINDOW_ALLOC_ARGS):
+                 + EXPECTED_WINDOW_ALLOC_ARGS + EXPECTED_TRAIN_STEP_ARGS):
         if f"`{name}`" not in docs:
             errors.append(f"span argument {name!r} not documented "
                           f"in {os.path.basename(DOCS)}")
