@@ -44,6 +44,7 @@ from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
 from deepspeed_tpu.models import transformer as tf_model
 from deepspeed_tpu.models.transformer import TransformerConfig
 from deepspeed_tpu.ops.pallas.kv_append import append_pages
+from deepspeed_tpu.ops.pallas.kv_append import fit as kv_append_fit
 from deepspeed_tpu.ops.pallas.paged_attention import (QUERY_BLOCK,
                                                       shared_walk_rows)
 from deepspeed_tpu.resilience.oracle import PartitionOracle
@@ -230,6 +231,54 @@ def ssm_step_counts(items: Sequence[tuple], slot_bytes: int,
             "state_bytes": slot_bytes * (2 * len(items) - fresh)}
 
 
+def hybrid_step_counts(items: Sequence[tuple], cfg: TransformerConfig,
+                       mgr) -> Dict[str, Any]:
+    """What one ragged step asks of a one-mixer-a-layer model beside
+    :func:`ssm_step_counts` (its ``state_bytes`` are the ``M`` layers')
+    and :func:`step_counts` (``kv_rows``, ``qk_pairs`` and
+    ``append_pages`` are ONE ``*`` layer's): ``kv_pages_held``, pages the
+    live sequences hold after the step, one ``*`` layer's; ``expert_rows``,
+    (row, held expert) products ONE ``E`` layer expects under even
+    routing, rows x experts per token x held / routed."""
+    hy = cfg.hybrid
+    return {"kv_pages_held": mgr.allocator.num_blocks - 1
+            - mgr.allocator.free_blocks,
+            "expert_rows": sum(n for _, n in items) * hy.num_experts_per_tok
+            * hy.experts_held[1] / hy.n_routed_experts}
+
+
+def hybrid_alloc_counts(cfg: TransformerConfig, engine_cfg, state, cache_k,
+                        ssm_impl: str) -> Dict[str, int]:
+    """Further arguments of a one-mixer-a-layer model's
+    ``v2.state_alloc``: how many layers of each kind, what ONE sequence
+    holds of recurrent state and convolution tails over every ``M`` layer
+    (``slot_bytes``: admission is bound by these slots, not by pages),
+    both pools' bytes, one page of one ``*`` layer (K and V), and
+    ``kernel_calls_per_step``: the Pallas calls ONE step program makes,
+    from the layer kinds and the kernels resolved (the scan a ``M``
+    layer; the append and the read a ``*`` layer)."""
+    slots = engine_cfg.max_tracked_sequences + 1
+    per_attn = 0
+    if attention_impl_name(cfg, engine_cfg.block_size) == "paged_pallas":
+        appends = engine_cfg.kv_dtype != "int8" and kv_append_fit(
+            16, cfg.kv_heads, cfg.dim_per_head, engine_cfg.block_size,
+            cache_k.dtype) is not None
+        per_attn = 1 + int(appends)
+    pool = sum(int(a.nbytes) for a in jax.tree.leaves(cache_k))
+    return {"ssm_layers": cfg.ssm_layers, "attn_layers": cfg.attn_layers,
+            "expert_layers": cfg.expert_layers,
+            "slot_bytes": int(state["ssm"].nbytes) // slots
+            + int(state["conv"].nbytes) * cfg.ssm_layers
+            // state["conv"].shape[0],
+            "state_pool_bytes": sum(int(a.nbytes)
+                                    for a in jax.tree.leaves(state)),
+            "kv_pool_bytes": 2 * pool,
+            "page_bytes": 2 * pool // (cfg.attn_layers
+                                       * engine_cfg.num_blocks),
+            "kernel_calls_per_step": cfg.ssm_layers
+            * int(ssm_impl == "ssd_pallas") + cfg.attn_layers * per_attn}
+
+
 # _ragged_step's ``sample`` for a self-drafting greedy step
 _DRAFT = {"draft": True}
 
@@ -360,7 +409,9 @@ class InferenceEngineV2:
         pages = self.cfg.num_blocks * self.cfg.block_size
         # [L, nkv, P, d]: kv-head-major so the paged-attention kernel's page
         # blocks have (rows, head_dim) as their minor dims (lane-aligned).
-        kv_shape = (mc.num_layers, mc.kv_heads, pages, mc.dim_per_head)
+        # (every layer's, but for a one-mixer-a-layer model: its pools
+        # hold its attention layers)
+        kv_shape = (mc.attn_layers, mc.kv_heads, pages, mc.dim_per_head)
         latent = pools = None
         if mc.mixed is not None:
             # the full layers' rows in the pool, the window layers' in a
@@ -441,6 +492,9 @@ class InferenceEngineV2:
                 "conv_bytes": int(self.state["conv"].nbytes),
                 "slots": self.cfg.max_tracked_sequences + 1,
                 "ssm_impl": self.ssm_impl}
+            if mc.hybrid is not None:
+                self._state_alloc.update(hybrid_alloc_counts(
+                    mc, self.cfg, self.state, self.cache_k, self.ssm_impl))
             # by name wherever the engine calls; by place too for the
             # ragged step, whose audit arguments are positional
             donate["donate_argnames"] = ("state",)
@@ -638,6 +692,9 @@ class InferenceEngineV2:
             elif self.model_config.ssm is not None:
                 counts.update(ssm_step_counts(
                     items, self._slot_bytes, self.state_manager.n_active))
+                if self.model_config.hybrid is not None:
+                    counts.update(hybrid_step_counts(
+                        items, self.model_config, self.state_manager))
             elif self.model_config.mla is not None:
                 from deepspeed_tpu.inference.v2.latent import \
                     latent_step_counts

@@ -247,12 +247,26 @@ def new_ssm_state(cfg: TransformerConfig, max_seqs: int, zeros=jnp.zeros):
     [L, max_seqs + 1, heads, head_dim, state] float32 and ``conv``
     [L, max_seqs + 1, kernel - 1, channels] in the compute dtype (the
     last inputs of the depthwise convolution, oldest first; channels
-    minor, so a row is whole lanes).  Slot ``max_seqs`` is the padding
-    rows' garbage slot."""
+    minor, so a row is whole lanes), ``L`` the layers that HAVE a mixer
+    (``cfg.ssm_layers``).  Slot ``max_seqs`` is the padding rows'
+    garbage slot.  A one-mixer-a-layer model's tails ride its layer walk
+    whole, as ``conv`` [L * slots, (kernel - 1) * channels], a sequence's
+    tails of a layer ONE row (``layer * slots + slot``; the slots made up
+    to a multiple of 8): the chip lays an array out by its shape, lanes
+    and sublanes along dims that fill their tiles, and the step gathers
+    and scatters whole rows; as [L, max_seqs + 1, 3, channels] it came in
+    with the LAYERS on the sublanes and all of it was relaid, in and
+    out, every step."""
     m = cfg.ssm
+    ssm = zeros((cfg.ssm_layers, max_seqs + 1, m.num_heads, m.head_dim,
+                 m.state_size), jnp.float32)
+    if cfg.hybrid is not None:
+        return {"ssm": ssm,
+                "conv": zeros((cfg.ssm_layers * (-(-(max_seqs + 1) // 8) * 8),
+                               (m.conv_kernel - 1) * m.conv_dim),
+                              cfg.dtype)}
     return {
-        "ssm": zeros((cfg.num_layers, max_seqs + 1, m.num_heads, m.head_dim,
-                      m.state_size), jnp.float32),
+        "ssm": ssm,
         "conv": zeros((cfg.num_layers, max_seqs + 1, m.conv_kernel - 1,
                        m.conv_dim), cfg.dtype),
     }
@@ -266,14 +280,21 @@ def _ragged_mixer(h, p, state, ssm_meta, cfg: TransformerConfig):
     position 0).  ``state``: ``ssm``, EVERY layer's recurrent slots (the
     scan over layers carries the array whole, so that the kernel updates
     it in place), ``layer``, this one's index in it, and ``conv``, this
-    layer's tails; returns (out [T, H], state')."""
+    layer's tails [S + 1, k - 1, C], or every layer's [L * slots, (k - 1)
+    * C], carried whole as ``ssm`` is (:func:`new_ssm_state`); returns
+    (out [T, H], state')."""
     m = cfg.ssm
     slot, token_pos, run_start, from_zero, is_last = ssm_meta
     dt_ = h.dtype
     f32 = jnp.float32
     t = h.shape[0]
     k = m.conv_kernel
-    pad = state["conv"].shape[0] - 1
+    pad = state["ssm"].shape[1] - 1
+    # every layer's tails: a sequence's are row layer * slots + slot
+    whole = state["conv"].ndim == 2
+    if whole:
+        first = state["layer"] * (state["conv"].shape[0]
+                                  // state["ssm"].shape[0])
 
     with jax.named_scope("ssm.in"):
         mup = jnp.concatenate([jnp.full((n,), v, dt_) for n, v in
@@ -286,15 +307,20 @@ def _ragged_mixer(h, p, state, ssm_meta, cfg: TransformerConfig):
         # depthwise causal convolution over each run: the input d rows back
         # is the row d above where the run reaches that far, else the
         # slot's tail
-        tail = jnp.where(from_zero[:, None, None], 0,
-                         state["conv"][slot]).astype(dt_)   # [T, k-1, C]
+        if whole:
+            tail = jnp.where(from_zero[:, None], 0,
+                             state["conv"][first + slot]).astype(dt_)
+            tap = lambda i: tail[:, i * m.conv_dim:(i + 1) * m.conv_dim]
+        else:
+            tail = jnp.where(from_zero[:, None, None], 0,
+                             state["conv"][slot]).astype(dt_)  # [T, k-1, C]
+            tap = lambda i: tail[:, i]
         in_run = jnp.arange(t, dtype=jnp.int32) - run_start
         back = []                               # d = k-1 .. 1 rows back
         for d in range(k - 1, 0, -1):
             v = jnp.roll(xbc, d, axis=0)
             for j in range(d):                  # the run is j rows old
-                v = jnp.where((in_run == j)[:, None],
-                              tail[:, k - 1 - d + j], v)
+                v = jnp.where((in_run == j)[:, None], tap(k - 1 - d + j), v)
             back.append(v)
         w = p["conv_w"].astype(f32)                          # [C, k]
         conv = xbc.astype(f32) * w[:, k - 1]
@@ -302,8 +328,13 @@ def _ragged_mixer(h, p, state, ssm_meta, cfg: TransformerConfig):
             conv = conv + v.astype(f32) * w[:, j]
         if "conv_b" in p:
             conv = conv + p["conv_b"].astype(f32)
-        new_tail = jnp.stack(back[1:] + [xbc], axis=1)       # [T, k-1, C]
-        conv_state = state["conv"].at[jnp.where(is_last, slot, pad)].set(
+        if whole:
+            new_tail = jnp.concatenate(back[1:] + [xbc], axis=1)
+            dest = first + jnp.where(is_last, slot, pad)
+        else:
+            new_tail = jnp.stack(back[1:] + [xbc], axis=1)   # [T, k-1, C]
+            dest = jnp.where(is_last, slot, pad)
+        conv_state = state["conv"].at[dest].set(
             new_tail.astype(state["conv"].dtype))
         xbc = jax.nn.silu(conv).astype(dt_)
 
@@ -656,6 +687,153 @@ def _mixed_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
                 {"k": pools[False][0], "v": pools[False][1]})
 
 
+def _hybrid_attention(h, p, cache_k, cache_v, layer, meta,
+                      cfg: TransformerConfig):
+    """The mixer of a ``"*"`` layer of a one-mixer-a-layer model on the
+    normed rows ``h``: grouped-query attention without rotary or bias
+    over ``layer``'s pages of the pools, which hold the ``"*"`` layers
+    alone; ``(out [T, H], cache_k', cache_v')``.  The append and the read
+    as :func:`_ragged_layer` makes them."""
+    (token_pos, token_dest, gather_idx, token_ctx_len, token_slot,
+     block_tables, block_size, dest_pages) = meta
+    t, dt = h.shape[0], h.dtype
+    nh, nkv, d = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
+
+    def proj(w, heads):
+        # behind a barrier, for _ragged_layer's reason: the product
+        # streams the weight from its stack as it lies
+        return lax.optimization_barrier(jnp.matmul(
+            h, w.astype(dt), preferred_element_type=jnp.float32)
+        ).reshape(t, heads, d).astype(dt)
+
+    with jax.named_scope("attn.qkv"):
+        q, k, v = proj(p["wq"], nh), proj(p["wk"], nkv), proj(p["wv"], nkv)
+    attend = functools.partial(
+        _paged_attention, q, gather_idx=gather_idx, token_pos=token_pos,
+        token_ctx_len=token_ctx_len, cfg=cfg, block_tables=block_tables,
+        token_slot=token_slot, block_size=block_size)
+    if attention_impl_name(cfg, block_size,
+                           block_tables is not None) == "paged_pallas":
+        with jax.named_scope("attn.append"):
+            if dest_pages is None:
+                cache_k = _kv_append(cache_k, k, token_dest, layer)
+                cache_v = _kv_append(cache_v, v, token_dest, layer)
+            else:
+                cache_k, cache_v = kv_append(cache_k, cache_v, k, v,
+                                             dest_pages, layer, block_size)
+        with jax.named_scope("attn.read"):
+            attn = attend(cache_k, cache_v, layer=layer)
+    else:
+        with jax.named_scope("attn.append"):
+            k_pages = _kv_append(jax.tree.map(lambda c: c[layer], cache_k),
+                                 k, token_dest)
+            v_pages = _kv_append(jax.tree.map(lambda c: c[layer], cache_v),
+                                 v, token_dest)
+        with jax.named_scope("attn.read"):
+            attn = attend(k_pages, v_pages)
+        with jax.named_scope("attn.append"):
+            cache_k, cache_v = jax.tree.map(
+                lambda c, pages: c.at[layer].set(pages),
+                (cache_k, cache_v), (k_pages, v_pages))
+    with jax.named_scope("attn.out"):
+        return (attn.reshape(t, nh * d) @ p["wo"].astype(dt), cache_k,
+                cache_v)
+
+
+def _hybrid_experts(h, moe, layer, cfg: TransformerConfig):
+    """The mixer of an ``"E"`` layer on the normed rows ``h``: this
+    program's routed experts' part and the shared expert whole, each
+    ``relu(h W_i)^2 W_o``; ``moe``: the expert layers' stack, ``layer``
+    this one's index in it (as ``moe_forward_held`` takes them)."""
+    from deepspeed_tpu.moe.sharded_moe import moe_forward_held
+
+    hy, dt = cfg.hybrid, h.dtype
+    routed = moe_forward_held(
+        h, moe, layer, top_k=hy.num_experts_per_tok,
+        first=hy.experts_held[0], scale=hy.route_scale)
+    with jax.named_scope("moe.shared"):
+        shared = moe["shared"]
+        act = jnp.square(jax.nn.relu(h @ shared["wi"][layer].astype(dt)))
+        return routed + act @ shared["wo"][layer].astype(dt)
+
+
+def _hybrid_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
+                  token_dest, block_tables, ctx_lens, state,
+                  cfg: TransformerConfig, block_size: int, state_slot):
+    """:func:`_ragged_trunk` of a one-mixer-a-layer model
+    (``cfg.hybrid``): every block is ``x + f(RMSNorm(x))`` with ONE ``f``
+    by the layer's kind, ``"M"`` the Mamba-2 mixer
+    (:func:`_ragged_mixer`), ``"*"`` attention without rotary
+    (:func:`_hybrid_attention`), ``"E"`` held experts and the shared one
+    (:func:`_hybrid_experts`).  A walk over the list of kinds in
+    :func:`layer_segments`, as :func:`_mixed_trunk` walks its own: one
+    ``lax.scan`` over the repeats where a stretch repeats.  Each kind's
+    weights and carried buffers are stacked over THAT kind's layers (the
+    recurrent slots and the convolution's tails over the ``"M"`` layers,
+    the KV pools over the ``"*"`` layers), and a layer reads and updates
+    them by its index in its kind; all four buffers ride the carry
+    whole."""
+    layers = params["layers"]
+    kinds = cfg.hybrid.kinds(cfg.num_layers)
+    ssm_meta = _ssm_meta(cfg, state, token_slot if state_slot is None
+                         else state_slot, token_pos)
+    with jax.named_scope("embed"):
+        x = params["embed"]["tokens"].astype(cfg.dtype)[token_ids]
+    meta = _step_meta(token_slot, token_pos, token_dest, block_tables,
+                      ctx_lens, block_size, cache_k, cfg)
+
+    def stretch(carry, j, start, period):
+        """Layers ``start + j * period`` on, ``period`` of them."""
+        x, ck, cv, ssm, conv = carry
+        for k in range(start, start + period):
+            kind = kinds[k]
+            layer = k + j * period
+            # this layer's index among its kind's: those before it in the
+            # list, and in the repeats before this one
+            at = kinds[:k].count(kind) \
+                + j * kinds[start:start + period].count(kind)
+            gain = _at(layers["norm"], layer)
+            # the pre-norm and the residual add are counted to the mixer's
+            # first and last stage
+            if kind == "M":
+                with jax.named_scope("ssm.in"):
+                    h = _norm(x, gain, cfg)
+                f, st = _ragged_mixer(
+                    h, _at(layers["ssm"], at),
+                    {"ssm": ssm, "conv": conv, "layer": at}, ssm_meta, cfg)
+                ssm, conv = st["ssm"], st["conv"]
+                with jax.named_scope("ssm.out"):
+                    x = x + f
+            elif kind == "*":
+                with jax.named_scope("attn.qkv"):
+                    h = _norm(x, gain, cfg)
+                f, ck, cv = _hybrid_attention(h, _at(layers["attn"], at),
+                                              ck, cv, at, meta, cfg)
+                with jax.named_scope("attn.out"):
+                    x = x + f
+            else:
+                with jax.named_scope("moe.router"):
+                    h = _norm(x, gain, cfg)
+                f = _hybrid_experts(h, layers["moe"], at, cfg)
+                with jax.named_scope("moe.combine"):
+                    x = x + f
+        return (x, ck, cv, ssm, conv), None
+
+    carry = (x, cache_k, cache_v, state["ssm"], state["conv"])
+    with jax.named_scope("layers"):
+        for start, period, repeats in layer_segments(list(kinds)):
+            if repeats == 1:
+                carry, _ = stretch(carry, 0, start, period)
+            else:
+                carry, _ = lax.scan(
+                    lambda c, j, s=start, p=period: stretch(c, j, s, p),
+                    carry, jnp.arange(repeats, dtype=jnp.int32))
+    x, cache_k, cache_v, ssm, conv = carry
+    with jax.named_scope("head"):
+        return (_norm(x, params["final_norm"], cfg), cache_k, cache_v,
+                {"ssm": ssm, "conv": conv})
+
+
 def _embed_rows(params, token_ids, token_pos, cfg: TransformerConfig):
     """The step's flat rows [T, H] as the first block takes them."""
     dt = cfg.dtype
@@ -714,6 +892,11 @@ def _ragged_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
         return _mixed_trunk(params, cache_k, cache_v, token_ids, token_slot,
                             token_pos, token_dest, block_tables, ctx_lens,
                             state, cfg, block_size, window)
+    if cfg.hybrid is not None:
+        # one mixer a layer, each kind's buffers over its own layers
+        return _hybrid_trunk(params, cache_k, cache_v, token_ids, token_slot,
+                             token_pos, token_dest, block_tables, ctx_lens,
+                             state, cfg, block_size, state_slot)
     ssm_meta = _ssm_meta(cfg, state, token_slot if state_slot is None
                          else state_slot, token_pos)
     with jax.named_scope("embed"):
@@ -1063,8 +1246,9 @@ def ragged_decode_loop(params, cache_k, cache_v, tokens0, ctx_lens0,
     state_slot = None
     if state is not None:
         # the padding rows' slot, the last of every kind of slot state
-        state_slot = jnp.where(active, slots,
-                               jax.tree.leaves(state)[0].shape[1] - 1)
+        state_slot = jnp.where(
+            active, slots, (state["ssm"] if "ssm" in state
+                            else jax.tree.leaves(state)[0]).shape[1] - 1)
 
     def step(carry, step_key):
         tokens, ctx_lens, ck, cv, st = carry

@@ -6,7 +6,8 @@ import dataclasses
 
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.transformer import (LatentConfig, LatentWidths,
+from deepspeed_tpu.models.transformer import (HybridConfig, LatentConfig,
+                                              LatentWidths,
                                               MixedAttentionConfig,
                                               SSMConfig, TransformerConfig)
 
@@ -338,6 +339,53 @@ register("trinity-tiny", TransformerConfig(
         moe_intermediate_size=32, num_dense_layers=2, route_scale=2.448,
         embed_multiplier=8.0),
     **_trinity))
+
+# -- Nemotron-H (HF nemotron_h: ONE mixer a layer by a pattern, M a
+# Mamba-2 scan of 64 heads of 64 in 8 groups at state 128, * grouped-query
+# attention 32:2 at head 128 WITHOUT rotary, E 128 sigmoid-routed experts
+# of two matrices, relu^2 without a gate, top 6 scaled by 2.5, and a
+# shared one of twice the width; one RMSNorm a layer);
+# nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 config.json
+_nemotron = dict(arch="nemotron_h", norm="rmsnorm", activation="relu2",
+                 use_rope=False, tie_embeddings=False, use_bias=False)
+_nemotron_pattern = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+def _nemotron_3_nano(vocab_size, experts_held):
+    return TransformerConfig(
+        vocab_size=vocab_size, hidden_size=2688, intermediate_size=1856,
+        num_layers=52, num_heads=32, num_kv_heads=2, head_dim=128,
+        max_seq_len=262144, rope_theta=1e4, layernorm_eps=1e-5,
+        ssm=SSMConfig(num_heads=64, head_dim=64, state_size=128, n_groups=8,
+                      conv_kernel=4, chunk_size=128, conv_bias=True),
+        hybrid=HybridConfig(
+            pattern=_nemotron_pattern, n_routed_experts=128,
+            experts_held=experts_held, num_experts_per_tok=6,
+            moe_intermediate_size=1856, shared_intermediate_size=3712,
+            route_scale=2.5),
+        **_nemotron)
+
+
+register("nemotron-3-nano-30b-a3b", _nemotron_3_nano(131072, (0, 128)))
+
+# one chip's share of a layer divided over two: experts 0-63 of the 128
+# (routing over all of them) and half of the vocabulary; the mixers, the
+# attention and the shared expert whole
+register("nemotron-3-nano-30b-a3b-ep2", _nemotron_3_nano(65536, (0, 64)))
+
+# the first nine layers' pattern, experts 8-15 of 16
+register("nemotron-h-tiny", TransformerConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=32, num_layers=9,
+    num_heads=4, num_kv_heads=2, head_dim=16, max_seq_len=256,
+    rope_theta=1e4, layernorm_eps=1e-5,
+    ssm=SSMConfig(num_heads=8, head_dim=8, state_size=16, n_groups=2,
+                  conv_kernel=4, chunk_size=16, conv_bias=True),
+    hybrid=HybridConfig(
+        pattern=_nemotron_pattern[:9], n_routed_experts=16,
+        experts_held=(8, 8), num_experts_per_tok=3,
+        moe_intermediate_size=32, shared_intermediate_size=64,
+        route_scale=2.5),
+    **_nemotron))
 
 # -- Phi (ref v2 phi: parallel block + partial rotary + biases) --------
 register("phi-2", TransformerConfig(

@@ -210,6 +210,37 @@ class MixedAttentionConfig:
 
 
 @dataclass(frozen=True)
+class HybridConfig:
+    """ONE mixer a layer, chosen per layer by ``pattern`` (HF
+    ``nemotron_h``'s ``hybrid_override_pattern``): ``"M"`` a Mamba-2 mixer
+    (its sizes in ``TransformerConfig.ssm``, every multiplier 1), ``"*"``
+    grouped-query attention with NO rotary and no other position signal,
+    ``"E"`` sigmoid-routed experts of TWO matrices each, ``relu(u W_up)^2
+    W_down`` without a gate, beside a shared one of
+    ``shared_intermediate_size``.  A block is ``x + f(RMSNorm(x))`` with
+    that one ``f``; there is no feed-forward beside a mixer.  This program
+    holds ``experts_held`` (first, count) of the ``n_routed_experts``: it
+    routes over all of them and computes its own experts' part (weights
+    normalised over the chosen, times ``route_scale``) and the shared
+    expert whole.  The recurrent slots are sized by the ``"M"`` layers and
+    the KV pools by the ``"*"`` layers, not by ``num_layers``.
+    ``TransformerConfig.hybrid`` is ``None`` for any other model.  Served
+    by inference/v2 only."""
+    pattern: str
+    n_routed_experts: int
+    experts_held: Tuple[int, int]
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    shared_intermediate_size: int
+    route_scale: float = 1.0
+
+    def kinds(self, num_layers: int) -> Tuple[str, ...]:
+        """``"M"``, ``"*"`` or ``"E"`` for each of the first
+        ``num_layers`` layers."""
+        return tuple(self.pattern[:num_layers])
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     """Architecture hyperparameters covering GPT-2 and Llama families."""
     vocab_size: int = 50257
@@ -406,11 +437,16 @@ class TransformerConfig:
     # sandwich norms and held sigmoid-routed experts (Trinity, HF afmoe);
     # None: one kind of layer.  Served by inference/v2 only
     mixed: Optional[MixedAttentionConfig] = None
+    # one mixer a layer by a pattern: a Mamba-2 scan (``ssm`` has its
+    # sizes), attention without rotary, or held ungated relu^2 experts
+    # (Nemotron-H, HF nemotron_h); None: every block alike.  Served by
+    # inference/v2 only
+    hybrid: Optional[HybridConfig] = None
 
     @property
     def _held(self):
         """The nested configuration that says which experts are held."""
-        return self.mla or self.mixed
+        return self.mla or self.mixed or self.hybrid
 
     # a latent model's sizes by flat names (0 without one), as the
     # mixer's below
@@ -454,7 +490,8 @@ class TransformerConfig:
 
     @property
     def route_scale(self) -> float:
-        return self.mixed.route_scale if self.mixed else 0.0
+        held = self.mixed or self.hybrid
+        return held.route_scale if held else 0.0
 
     @property
     def window_layers(self) -> int:
@@ -475,6 +512,34 @@ class TransformerConfig:
     @property
     def embed_multiplier(self) -> float:
         return self.mixed.embed_multiplier if self.mixed else 1.0
+
+    # a one-mixer-a-layer model's sizes by flat names ("" / 0 without one)
+    @property
+    def layer_kinds(self) -> str:
+        return "".join(self.hybrid.kinds(self.num_layers)) \
+            if self.hybrid else ""
+
+    @property
+    def shared_width(self) -> int:
+        return self.hybrid.shared_intermediate_size if self.hybrid else 0
+
+    @property
+    def expert_layers(self) -> int:
+        return self.layer_kinds.count("E")
+
+    @property
+    def ssm_layers(self) -> int:
+        """Layers that keep a recurrent slot a sequence."""
+        if self.hybrid:
+            return self.layer_kinds.count("M")
+        return self.num_layers if self.ssm else 0
+
+    @property
+    def attn_layers(self) -> int:
+        """Layers of the plain KV pools ``[layers, kv_heads, rows, d]``."""
+        if self.hybrid:
+            return self.layer_kinds.count("*")
+        return self.num_layers - self.window_layers
 
     # the mixer's sizes by flat names (0 without one), for callers that
     # hold a configuration to a file by ``getattr``
@@ -693,6 +758,15 @@ def refuse_ssm(cfg: TransformerConfig, what: str) -> None:
             "sigmoid-routed experts; models/transformer.py has none of "
             "them and would run a plain block under its name. Serve it "
             "through inference.v2.InferenceEngineV2")
+    if cfg.hybrid is not None:
+        raise NotImplementedError(
+            f"{what}: this configuration has ONE mixer a layer "
+            f"({cfg.layer_kinds}: M a Mamba-2 scan, * attention without "
+            "rotary, E ungated relu^2 experts held in part); "
+            "models/transformer.py has no state-space scan, no layer "
+            "without a feed-forward and no held experts, and would run a "
+            "plain block under its name. Serve it through "
+            "inference.v2.InferenceEngineV2")
     if cfg.ssm is not None:
         raise NotImplementedError(
             f"{what}: this configuration has a Mamba-2 SSM mixer beside "
@@ -1062,6 +1136,141 @@ def init_mixed_params(cfg: TransformerConfig, key) -> Params:
             "lm_head": dense(keys[nl + 1], (h, cfg.vocab_size), h)}
 
 
+# a one-mixer-a-layer model's seeded routing: what a layer of each kind
+# adds to the variance of the stream's own part under init_hybrid_params,
+# counted over seeded rows at hidden 64 and 512: a mixer one, an attention
+# layer a context's mean of centred values (next to nothing), an expert
+# layer the shared expert's 0.3 and, of the routed experts' 1.2, the
+# share that is held
+HYBRID_OWN_GAIN = {"M": 1.0, "*": 0.05, "E": (0.3, 1.2)}
+HYBRID_DOWN_SCALE = 0.5
+
+
+def init_hybrid_params(cfg: TransformerConfig, key) -> Params:
+    """A one-mixer-a-layer model's params (``cfg.hybrid``).  Layers of one
+    kind are stacked on axis 0 under the kind's name, in layer order:
+    ``layers/ssm`` ``[M layers, ...]`` (:func:`init_ssm_params`' leaves),
+    ``layers/attn`` ``[* layers, ...]`` (``wq``, ``wk``, ``wv``, ``wo``)
+    and ``layers/moe`` ``[E layers, ...]`` (``router`` over ALL experts,
+    the selection ``bias``, the held experts' TWO matrices ``wu`` and
+    ``wo``, both ``[n, held, F, H]`` (the up matrix a hidden unit's
+    weights a row: ``moe_forward_held`` has the reason), the ``shared``
+    expert's ``wi`` ``[n, H, F]`` and ``wo``); ``layers/norm`` is every
+    layer's one pre-norm gain ``[L, H]``.
+    Seeded normal, ``1 / sqrt(fan_in)``, no bias but the convolution's;
+    ``A_log``, ``dt_bias``, ``D``, the convolution and the mixer's gain as
+    :func:`init_ssm_params` seeds them.
+
+    The routing is seeded as :func:`init_mixed_params` seeds it, for the
+    reasons written there: each embedding element has the offset
+    ``MIXED_EMBED_COMMON`` beside a part of its own of variance one, the
+    router's columns have a seeded part of zero sum and equal norm and the
+    matching negative part, so that the logits have standard deviation
+    ``MIXED_ROUTER_LOGIT_SD`` about a mean ``ROUTER_LOGIT_MEAN`` standard
+    deviations below zero, and every matrix that READS the stream other
+    than a router has its columns centred, so the shared direction moves
+    nothing but all of a row's logits alike.  Two things differ.  A block
+    has no norm after its mixer, so the variance of the stream's own part
+    at a router is counted from ``HYBRID_OWN_GAIN`` a layer before it, and
+    the experts' down matrices are seeded at ``HYBRID_DOWN_SCALE / sqrt(
+    fan_in)``, so that an expert layer adds to the stream about what a
+    mixer adds (at ``1 / sqrt(fan_in)`` six weighted ``relu^2`` experts
+    and the shared one added six times a mixer's variance, layer after
+    layer, and the mixers' part of the logits was a few percent).
+    And ``relu^2`` is never negative: an expert's hidden units have a mean
+    of a half, which through ``W_down`` would be ONE vector added to
+    every row, a per-expert offset on every later router's logits for
+    every row alike (the uneven loads PERF.md section 7 tells of); so
+    the matrices that WRITE the stream (``wo``, ``out_proj``) are centred
+    over their input dims and pass a uniform mean on as nothing.  The
+    selection ``bias`` is zeros."""
+    m, s, h, pd = cfg.hybrid, cfg.ssm, cfg.hidden_size, cfg.param_dtype
+    nl = cfg.num_layers
+    kinds = m.kinds(nl)
+    if len(kinds) != nl or set(kinds) - set("M*E") or s is None:
+        raise ValueError(f"pattern {m.pattern!r} names {len(kinds)} layers "
+                         f"of kinds M, * and E (the model has {nl}), and "
+                         "the M layers' sizes are cfg.ssm")
+    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
+    c = max(MIXED_EMBED_COMMON, MIXED_COMMON_NORM / math.sqrt(h))
+
+    def dense(k, shape, fan_in):
+        """Centred over the axis before the last: a matrix that reads
+        the stream sees nothing of its shared direction, one that writes
+        it passes no uniform mean on."""
+        w = jax.random.normal(k, shape) / math.sqrt(fan_in)
+        return (w - w.mean(axis=-2, keepdims=True)).astype(pd)
+
+    def relu2(k, lead, width):
+        k = jax.random.split(k)
+        up = dense(k[0], lead + (h, width), h)
+        down = dense(k[1], lead + (width, h), width / HYBRID_DOWN_SCALE ** 2)
+        return ({"wu": up.swapaxes(-1, -2), "wo": down} if lead
+                else {"wi": up, "wo": down})
+
+    def mixer(k):
+        k = jax.random.split(k, 3)
+        return dict(init_ssm_params(cfg, k[0]),
+                    in_proj=dense(k[1], (h, s.proj_dim), h),
+                    out_proj=dense(k[2], (s.d_ssm, h), s.d_ssm))
+
+    def attn(k):
+        k = jax.random.split(k, 4)
+        return {"wq": dense(k[0], (h, nh * hd), h),
+                "wk": dense(k[1], (h, nkv * hd), h),
+                "wv": dense(k[2], (h, nkv * hd), h),
+                "wo": dense(k[3], (nh * hd, h), nh * hd)}
+
+    shared_gain, routed_gain = HYBRID_OWN_GAIN["E"]
+    gain = dict(HYBRID_OWN_GAIN, E=shared_gain + routed_gain
+                * m.experts_held[1] / m.n_routed_experts)
+
+    def router(k, layer):
+        # the stream's own part at this layer's norm: the embedding's
+        # and what each layer before it added
+        own = 1.0 + sum(gain[kind] for kind in kinds[:layer])
+        rms = math.sqrt(own + c * c)
+        seeded = jax.random.normal(k, (h, m.n_routed_experts))
+        seeded = seeded - seeded.mean(axis=0, keepdims=True)
+        seeded = seeded * lax.rsqrt(jnp.mean(jnp.square(seeded), axis=0,
+                                             keepdims=True))
+        return (seeded * (MIXED_ROUTER_LOGIT_SD / math.sqrt(h) * rms
+                          / math.sqrt(own))
+                - ROUTER_LOGIT_MEAN * MIXED_ROUTER_LOGIT_SD * rms / (h * c)
+                ).astype(pd)
+
+    def stacked(fn, ks):
+        return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0),
+                            *[fn(k) for k in ks])
+
+    keys = jax.random.split(key, nl + 2)
+    sub = [jax.random.split(keys[i], 3) for i in range(nl)]
+    at = {kind: [i for i, k in enumerate(kinds) if k == kind]
+          for kind in "M*E"}
+    layers = {"norm": {"scale": jnp.ones((nl, h), pd)}}
+    if at["M"]:
+        layers["ssm"] = stacked(mixer, [sub[i][0] for i in at["M"]])
+    if at["*"]:
+        layers["attn"] = stacked(attn, [sub[i][0] for i in at["*"]])
+    if at["E"]:
+        # a layer's held experts at a time (init_mixed_params: a stack of
+        # whole layers would be copied beside its parts)
+        layers["moe"] = dict(
+            lax.map(lambda k: relu2(k, (m.experts_held[1],),
+                                    m.moe_intermediate_size),
+                    jnp.stack([sub[i][0] for i in at["E"]])),
+            router=jnp.stack([router(sub[i][1], i) for i in at["E"]]),
+            bias=jnp.zeros((len(at["E"]), m.n_routed_experts), pd),
+            shared=stacked(lambda k: relu2(k, (), m.shared_intermediate_size),
+                           [sub[i][2] for i in at["E"]]))
+    own = jax.random.normal(keys[nl], (cfg.vocab_size, h))
+    return {"embed": {"tokens": (own + c).astype(pd)},
+            "layers": layers,
+            "final_norm": {"scale": jnp.ones((h,), pd)},
+            "lm_head": (jax.random.normal(keys[nl + 1], (h, cfg.vocab_size))
+                        / math.sqrt(h)).astype(pd)}
+
+
 def init_params(cfg: TransformerConfig, key) -> Params:
     """Full model params with per-layer params stacked on axis 0."""
     # nl+5 keys: rows are counter-derived, so rows nl..nl+2 keep the same
@@ -1071,6 +1280,8 @@ def init_params(cfg: TransformerConfig, key) -> Params:
         return init_latent_params(cfg, key)
     if cfg.mixed is not None:
         return init_mixed_params(cfg, key)
+    if cfg.hybrid is not None:
+        return init_hybrid_params(cfg, key)
     nl = cfg.num_layers
     keys = jax.random.split(key, nl + 5)
     scale = 1.0 / math.sqrt(cfg.hidden_size)

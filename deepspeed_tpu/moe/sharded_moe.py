@@ -464,6 +464,13 @@ def moe_forward_held(x, p, layer, *, top_k: int, first: int,
     SwiGLU_e(x[t])`` (``scale``: HF ``routed_scaling_factor``): what the
     other ranks of an expert-parallel layer
     would add is theirs to compute, and a shared expert is the caller's.
+    Without a ``p["wg"]`` the experts have TWO matrices and no gate,
+    ``relu(x W_u^T)^2 W_o`` (HF ``nemotron_h``), the up matrix stored as
+    ``p["wu"]`` ``[L, held, F, H]``, a hidden unit's weights a row: the
+    chip lays an array out by its shape, lanes along a dim that fills
+    them, and at a width that is no multiple of 128 (1856) an ``[H, F]``
+    stack came in lanes along ``H`` and was copied whole, 2.5 GB a step,
+    into the order the product reads.
     x: [T, H] -> [T, H].  The tile loop reads ``[layer, expert]`` out of
     the stack itself: a layer's experts sliced out first are a
     loop-invariant value the compiler copies whole, 0.5 GB a matrix at
@@ -479,7 +486,8 @@ def moe_forward_held(x, p, layer, *, top_k: int, first: int,
     the experts its few rows chose, a prefill chunk each expert once a
     tile."""
     t, h = x.shape
-    held = p["wg"].shape[1]
+    held = p["wo"].shape[1]
+    gated = "wg" in p
     dt, f32 = x.dtype, jnp.float32
     tm = min(HELD_TILE, max(8, t))
     with jax.named_scope("moe.router"):
@@ -522,8 +530,13 @@ def moe_forward_held(x, p, layer, *, top_k: int, first: int,
             g = lax.dynamic_slice(gate, (i * tm,), (tm,))
             xt = x_pad[rows]
         with jax.named_scope("moe.experts"):
-            act = jax.nn.silu(xt @ p["wg"][layer, e].astype(dt)) \
-                * (xt @ p["wi"][layer, e].astype(dt))
+            if gated:
+                act = jax.nn.silu(xt @ p["wg"][layer, e].astype(dt)) \
+                    * (xt @ p["wi"][layer, e].astype(dt))
+            else:
+                act = jnp.square(jax.nn.relu(lax.dot_general(
+                    xt, p["wu"][layer, e].astype(dt),
+                    (((1,), (1,)), ((), ())))))
             out = jnp.dot(act * g[:, None].astype(dt),
                           p["wo"][layer, e].astype(dt),
                           preferred_element_type=f32)

@@ -475,6 +475,84 @@ def test_latent_step_keeps_its_pools_and_rings_in_place(chip):
     _assert_latent_buffers_stay(text, (ck, cv, state["win"]), _DOTS3_EXPERTS)
 
 
+@pytest.mark.parametrize("t", [512, 16], ids=["full_step", "decode_only"])
+def test_one_mixer_a_layer_step_keeps_every_buffer_in_place(chip, t):
+    """The serving step of ``nemotron-3-nano-ep2-l9`` (nine layers at the
+    published widths: four scans of 64 heads of 64 in 8 groups at state
+    128, one attention layer of 16 query heads to each of 2 KV heads, four
+    layers of 64 held two-matrix experts), a full 512-row step and the
+    smallest bucket, 256 sequences and the padding row: the recurrent
+    slots ``f32[4,257,64,64,128]``, the convolution's tails, one row a
+    sequence and layer, and the attention layer's pools are donated and
+    come back in place; neither they nor the stacked expert weights are
+    copied, transposed or sliced whole in any layer (the experts' up
+    matrices stored ``[F, H]``: as ``[H, 1856]`` the stack came in lanes
+    along ``H`` and was copied whole, 2.5 GB a step; the tails as ``[4,
+    257, 3, 6144]`` came in with the layers on the sublanes); the step makes
+    the Pallas calls the engine counts, four scans, one append, one
+    read."""
+    import re
+
+    from deepspeed_tpu.inference.v2 import model as v2_model
+    from deepspeed_tpu.inference.v2.ragged import PackedIndex
+    from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.models import transformer as tf_model
+
+    cfg = get_model_config(
+        "nemotron-3-nano-30b-a3b-ep2", num_layers=9, param_dtype=BF16,
+        dtype=BF16, v2_modules=(("attention", "paged_pallas"),
+                                ("ssm", "ssd_pallas")))
+    bs, nb, slots = 32, 128, 257
+    params = _abstract(chip, jax.eval_shape(
+        lambda k: tf_model.init_params(cfg, k), jax.random.PRNGKey(0)))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 3_166_244_352
+    pool = chip((1, 2, 32769 * bs, 128), BF16)
+    state = _abstract(chip, jax.eval_shape(
+        lambda: v2_model.new_ssm_state(cfg, slots - 1)))
+    assert state["ssm"].shape == (4, 257, 64, 64, 128)
+    assert state["conv"].shape == (4 * 264, 3 * 6144)
+    index = PackedIndex(chip((PackedIndex.size(t, slots, nb),), I32), t,
+                        slots, nb)
+    fn = functools.partial(v2_model.ragged_step_sampled, cfg=cfg,
+                           block_size=bs, greedy=True)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn, donate_argnums=(1, 2),
+                           donate_argnames=("state",)).lower(
+            params, pool, pool, index, chip((2,), jnp.uint32), chip((), F32),
+            state=state).compile()
+    text = compiled.as_text()
+    # both pools, the slots and the tails come back as they came in
+    assert len(_aliased_outputs(text)) == 4, _aliased_outputs(text)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20, mem
+    held = {"f32[4,257,64,64,128]", "bf16[1056,18432]",
+            "bf16[1,2,1048608,128]", "bf16[2,1048608,128]"}
+    experts = {"bf16[4,64,1856,2688]", "bf16[64,1856,2688]",
+               "bf16[4,2688,3712]", "bf16[4,3712,2688]"}
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \(?(\S+?)[{ ]\S* ?([\w-]+)\(", line)
+        if not m:
+            continue
+        # (a ``copy-start`` / ``copy-done`` pair is the compiler moving a
+        # buffer to its faster memory and back as it lies, not a relayout)
+        op = m.group(1) if m.group(3) == "fusion" else m.group(3) + "("
+        if m.group(2) in held | experts and re.search(
+                r"copy\(|copy_|transpose", op):
+            moved.append(line.strip()[:160])
+        if m.group(2) in experts and re.search(r"slice", op):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+    names = [re.search(r"%(\w+?)[.\d]* = ", ln).group(1)
+             for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert sorted(names) == ["kv_append", "paged_qblock"] + ["ssd_ragged"] * 3
+    scan = next(ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln and "%ssd_ragged" in ln)
+    assert "output_to_operand_aliasing" in scan
+    assert "f32[4,257,64,64,128]" in scan
+
+
 @pytest.mark.parametrize("t,nb", [(1024, 64), (32, 32)],
                          ids=["with_chunk", "verify_runs"])
 def test_self_drafting_step_is_one_program_with_its_pools_in_place(chip, t,

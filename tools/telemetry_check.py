@@ -339,6 +339,17 @@ EXPECTED_TRAIN_STEP_ARGS = ["flash_executed_share_bwd",
                             "flash_executed_share_fwd"]
 
 
+# arguments the ``v2.schedule`` span carries for a one-mixer-a-layer model
+# beside a mixer's (engine_v2.hybrid_step_counts), and those it adds to
+# ``v2.state_alloc`` (engine_v2.hybrid_alloc_counts): the benchmark's
+# readers read them by name
+EXPECTED_HYBRID_SCHEDULE_ARGS = ["expert_rows", "kv_pages_held"]
+EXPECTED_HYBRID_ALLOC_ARGS = ["attn_layers", "expert_layers",
+                              "kernel_calls_per_step", "kv_pool_bytes",
+                              "page_bytes", "slot_bytes", "ssm_layers",
+                              "state_pool_bytes"]
+
+
 def check_span_names() -> List[str]:
     """Tracing vocabulary: frozen lists match the modules, every name is
     in the docs span table."""
