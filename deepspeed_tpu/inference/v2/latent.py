@@ -186,6 +186,20 @@ def _at(tree, i):
     return jax.tree.map(lambda a: a[i], tree)
 
 
+def _head_product(x, w):
+    """``x @ w`` [T, heads x dim] as a value of its own, rows-major, for a
+    product whose result is cut into heads on the spot.  With the heads'
+    reshape folded into the product the TPU's compiler wants the weight
+    ``[out][in]``: it slices the layer's matrix out of the stack,
+    transposes the copy and only then multiplies (``wq_b`` of GLM-5: 67 MB
+    moved twice a layer before it is read once, a quarter of a decode
+    step: PERF.md, PR 49).  Behind the barrier the product streams the stack from
+    HBM as it lies, as ``model._ragged_layer``'s q, k and v do, and the
+    relayout falls on the step's rows.  Rounded to the rows' dtype here, as
+    the folded product was."""
+    return lax.optimization_barrier(jnp.matmul(x, w.astype(x.dtype)))
+
+
 def _project(h, p, w: LatentWidths, pos, cfg: TransformerConfig):
     """What both kinds of layer make of the normed input h [T, H]:
     ``(c_q, q [T, heads, stored] absorbed, row [T, stored])``, query and
@@ -196,7 +210,7 @@ def _project(h, p, w: LatentWidths, pos, cfg: TransformerConfig):
     kv_mul = (math.sqrt(cfg.hidden_size / w.kv_lora_rank)
               if m.lora_rescale else 1)
     c_q = _rms(h @ p["wq_a"].astype(dt), p["q_norm"], cfg) * q_mul
-    q = (c_q @ p["wq_b"].astype(dt)).reshape(t, w.num_heads, w.qk_head_dim)
+    q = _head_product(c_q, p["wq_b"]).reshape(t, w.num_heads, w.qk_head_dim)
     q_rope = _rope(q[..., w.qk_nope_head_dim:], pos, w.rope_theta,
                    m.rope_interleaved)
     q_abs = jnp.einsum("thn,hnr->thr", q[..., :w.qk_nope_head_dim],
@@ -295,8 +309,8 @@ def _index_inputs(h, c_q, p, token_pos, cfg: TransformerConfig):
     c_q: rotary on the first ``index_rope_dim`` dims of both."""
     m, dt, t = cfg.mla, h.dtype, h.shape[0]
     rot, theta = m.index_rope_dim, m.full.rope_theta
-    q_i = (c_q @ p["idx_wq"].astype(dt)).reshape(t, m.index_heads,
-                                                 m.index_head_dim)
+    q_i = _head_product(c_q, p["idx_wq"]).reshape(t, m.index_heads,
+                                                  m.index_head_dim)
     q_i = jnp.concatenate([_rope(q_i[..., :rot], token_pos, theta,
                                  m.rope_interleaved), q_i[..., rot:]], -1)
     k_i = (h @ p["idx_wk"].astype(dt)).astype(jnp.float32)

@@ -433,7 +433,64 @@ def _assert_latent_buffers_stay(text, buffers, experts, by_layer=False):
     assert not moved, moved
 
 
-def test_latent_step_keeps_its_pools_and_rings_in_place(chip):
+# the latent cells' models at five layers and the published widths: preset,
+# its overrides, pool rows, the self-drafting step?
+_LATENT = {
+    "glm-5-ep16-l5": ("glm-5-ep16", {"num_layers": 5, "first_k_dense": 1},
+                      1024 * 128, True),
+    "dots3-note-ep8-l5": ("dots3-note-prev-ep8", {"num_layers": 5},
+                          2048 * 128, False),
+}
+
+
+@pytest.fixture(scope="module")
+def latent_step(chip):
+    """``latent_step(model, rows, pages)`` -> (cfg, params, (cache_k,
+    cache_v, state), compiled): the step of a model of ``_LATENT`` as the
+    engine jits it (GLM-5: the self-drafting step), 32 sequences and the
+    padding row, pages of 128, the indexer's kernel named; compiled once
+    for every test that reads it."""
+    from deepspeed_tpu.inference.v2 import latent
+    from deepspeed_tpu.inference.v2 import model as v2_model
+    from deepspeed_tpu.inference.v2.ragged import PackedIndex
+    from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.models import transformer as tf_model
+
+    @functools.lru_cache(maxsize=None)
+    def step(model, t, nb):
+        preset, over, rows, drafting = _LATENT[model]
+        cfg = get_model_config(
+            preset, param_dtype=BF16, dtype=BF16,
+            v2_modules=(("indexer", "indexer_pallas"),), **over)
+        params = _abstract(chip, jax.eval_shape(
+            lambda k: tf_model.init_params(cfg, k), jax.random.PRNGKey(0)))
+        ck, cv, state = jax.eval_shape(
+            lambda: latent.new_cache(cfg, rows, 32, t))
+        ck, cv = _abstract(chip, (ck, cv))
+        index = PackedIndex(
+            chip((PackedIndex.size(t, 33, nb, drafting),), I32), t, 33, nb,
+            drafting)
+        with jax.default_matmul_precision("default"):
+            if drafting:
+                assert state is None
+                fn = functools.partial(v2_model.ragged_draft_step, cfg=cfg,
+                                       block_size=128)
+                compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+                    params, ck, cv, index).compile()
+            else:
+                state = _abstract(chip, state)
+                fn = functools.partial(v2_model.ragged_step_sampled, cfg=cfg,
+                                       block_size=128, greedy=True)
+                compiled = jax.jit(fn, donate_argnums=(1, 2),
+                                   donate_argnames=("state",)).lower(
+                    params, ck, cv, index, chip((2,), jnp.uint32),
+                    chip((), F32), state=state).compile()
+        return cfg, params, (ck, cv, state), compiled
+
+    return step
+
+
+def test_latent_step_keeps_its_pools_and_rings_in_place(latent_step):
     """The serving step of ``dots3-note-ep8-l5`` (five layers at the
     published widths, a full 1024-row step at an 8k context bucket): the
     pages of latent rows ``bf16[2,P,640]`` and of index keys
@@ -443,30 +500,11 @@ def test_latent_step_keeps_its_pools_and_rings_in_place(chip):
     compiler turns the pools rows-minor for the gather and copies them
     there and back in every layer: PERF.md, PR 34); no layer's stack of
     expert matrices is sliced out as a value of its own either."""
-    from deepspeed_tpu.inference.v2 import latent
-    from deepspeed_tpu.inference.v2 import model as v2_model
-    from deepspeed_tpu.inference.v2.ragged import PackedIndex
-    from deepspeed_tpu.models import get_model_config
-    from deepspeed_tpu.models import transformer as tf_model
-
-    cfg = get_model_config(
-        "dots3-note-prev-ep8", num_layers=5, param_dtype=BF16, dtype=BF16,
-        v2_modules=(("indexer", "indexer_pallas"),))
-    rows, t, nb, bs = 2048 * 128, 1024, 64, 128
-    params = _abstract(chip, jax.eval_shape(
-        lambda k: tf_model.init_params(cfg, k), jax.random.PRNGKey(0)))
-    ck, cv, state = _abstract(chip, jax.eval_shape(
-        lambda: latent.new_cache(cfg, rows, 32, t)))
+    rows = 2048 * 128
+    _, _, (ck, cv, state), compiled = latent_step("dots3-note-ep8-l5", 1024,
+                                                  64)
     assert ck.shape == (2, rows, 640) and cv.shape == (2, rows, 128)
     assert state["win"].shape == (3, 33, 1664, 1152)
-    index = PackedIndex(chip((PackedIndex.size(t, 33, nb),), I32), t, 33, nb)
-    fn = functools.partial(v2_model.ragged_step_sampled, cfg=cfg,
-                           block_size=bs, greedy=True)
-    with jax.default_matmul_precision("default"):
-        compiled = jax.jit(fn, donate_argnums=(1, 2),
-                           donate_argnames=("state",)).lower(
-            params, ck, cv, index, chip((2,), jnp.uint32), chip((), F32),
-            state=state).compile()
     text = compiled.as_text()
     assert len(_aliased_outputs(text)) == 3
     mem = compiled.memory_analysis()
@@ -555,8 +593,8 @@ def test_one_mixer_a_layer_step_keeps_every_buffer_in_place(chip, t):
 
 @pytest.mark.parametrize("t,nb", [(1024, 64), (32, 32)],
                          ids=["with_chunk", "verify_runs"])
-def test_self_drafting_step_is_one_program_with_its_pools_in_place(chip, t,
-                                                                   nb):
+def test_self_drafting_step_is_one_program_with_its_pools_in_place(
+        latent_step, t, nb):
     """The self-drafting step of ``glm-5-ep16-l5`` (five layers and the
     module at the published widths): trunk, the argmax at both rows of
     the verify runs, accept, module and next draft compile as ONE
@@ -565,29 +603,9 @@ def test_self_drafting_step_is_one_program_with_its_pools_in_place(chip, t,
     whole or by layer; the one result is ``s32[4, slots]``; the index
     kernel runs in all six layers; no stack of expert matrices is sliced
     out as a value of its own."""
-    from deepspeed_tpu.inference.v2 import latent
-    from deepspeed_tpu.inference.v2 import model as v2_model
-    from deepspeed_tpu.inference.v2.ragged import PackedIndex
-    from deepspeed_tpu.models import get_model_config
-    from deepspeed_tpu.models import transformer as tf_model
-
-    cfg = get_model_config(
-        "glm-5-ep16", num_layers=5, first_k_dense=1, param_dtype=BF16,
-        dtype=BF16, v2_modules=(("indexer", "indexer_pallas"),))
-    rows, bs = 1024 * 128, 128
-    params = _abstract(chip, jax.eval_shape(
-        lambda k: tf_model.init_params(cfg, k), jax.random.PRNGKey(0)))
-    ck, cv, state = jax.eval_shape(lambda: latent.new_cache(cfg, rows, 32, t))
-    assert state is None
-    ck, cv = _abstract(chip, (ck, cv))
+    rows = 1024 * 128
+    _, _, (ck, cv, _), compiled = latent_step("glm-5-ep16-l5", t, nb)
     assert ck.shape == (6, rows, 640) and cv.shape == (6, rows, 128)
-    index = PackedIndex(chip((PackedIndex.size(t, 33, nb, True),), I32),
-                        t, 33, nb, True)
-    fn = functools.partial(v2_model.ragged_draft_step, cfg=cfg,
-                           block_size=bs)
-    with jax.default_matmul_precision("default"):
-        compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-            params, ck, cv, index).compile()
     text = compiled.as_text()
     assert len(_aliased_outputs(text)) == 2
     assert "s32[4,33]" in text
@@ -595,6 +613,69 @@ def test_self_drafting_step_is_one_program_with_its_pools_in_place(chip, t,
     assert mem.temp_size_in_bytes < 2 * 2 ** 30, mem
     assert text.count("latent_index_scores") >= 3   # trunk segments, module
     _assert_latent_buffers_stay(text, (ck, cv), _GLM5_EXPERTS, by_layer=True)
+
+
+@pytest.mark.parametrize("model,t,nb", [
+    ("glm-5-ep16-l5", 32, 32), ("glm-5-ep16-l5", 1024, 64),
+    ("dots3-note-ep8-l5", 1024, 64), ("dots3-note-ep8-l5", 16, 64)],
+    ids=["glm5_verify_runs", "glm5_with_chunk", "dots3_full_step",
+         "dots3_decode_only"])
+def test_latent_step_streams_the_head_shaped_query_weights(latent_step, model,
+                                                           t, nb):
+    """``wq_b``'s and ``idx_wq``'s products (``latent._project``,
+    ``_index_inputs``) read their stacked weights from HBM inside the
+    fusion that multiplies, in every block of the step: the layer scans'
+    bodies (GLM-5's four expert layers, dots3's three window layers) and
+    the blocks of their own in the entry computation (GLM-5's dense layer
+    0, dots3's two full layers).  With the heads' reshape folded into the
+    product the compiler wants the weight ``[out][in]``: it slices the
+    layer's matrix out of the stack, transposes the copy and only then
+    multiplies: ``bf16[1,2048,16384]`` sliced and copied in the scan's
+    body, ``bf16[16384,2048]`` in the entry computation, 67 MB moved twice
+    before it is read once (PERF.md, PR 49: a fifth of ``reason_open``'s
+    device time).  No instruction that is a buffer of its own (not one
+    inside a fusion, which is the streaming read itself) has the shape of
+    ONE layer of either weight, in either index order."""
+    import collections
+    import re
+
+    cfg, params, _, compiled = latent_step(model, t, nb)
+    comps = _computations(compiled.as_text())
+    fused = {m.group(1) for _, lines in comps.values() for ln in lines
+             if (m := re.search(r" fusion\(.*calls=%([^\s,]+)", ln))}
+    # stack -> blocks that multiply out of it: the full layers' in two
+    # (GLM-5: the scan's body and layer 0; dots3: its two full layers),
+    # dots3's window layers' in their scan's body
+    layers = params["layers"]
+    blocks = {layers["full"]["wq_b"].shape: 2,
+              layers["full"]["idx_wq"].shape: 2}
+    if cfg.mla.has_window(cfg.num_layers):
+        blocks[layers["window"]["wq_b"].shape] = 1
+    one_layer = set()
+    for n, k, o in blocks:
+        assert n > 1
+        one_layer |= {f"bf16[1,{k},{o}]", f"bf16[1,{o},{k}]",
+                      f"bf16[{o},{k}]"}
+    moved, streamed = [], collections.Counter()
+    for name, (_, lines) in comps.items():
+        if name in fused:
+            continue
+        for ln in lines:
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (\S+?)[{ ]\S* ?([\w-]+)\(", ln)
+            # (a ``slice-done`` or ``copy-done`` is the compiler fetching a
+            # buffer ahead to its faster memory as it lies, with or
+            # without this: dots3's ``idx_wq``, 17 MB a layer)
+            if m and m.group(1) in one_layer and not m.group(2).endswith(
+                    "-done"):
+                moved.append(ln.strip()[:160])
+            m = re.search(r" fusion\(.*calls=%([^\s,]+)", ln)
+            if m and any(" convolution(" in x for x in comps[m.group(1)][1]):
+                streamed.update(re.findall(r": (bf16\[[\d,]+\])",
+                                           comps[m.group(1)][0]))
+    assert not moved, moved
+    want = collections.Counter(
+        {"bf16[%d,%d,%d]" % shape: n for shape, n in blocks.items()})
+    assert not want - streamed, (want, streamed)
 
 
 # (preset, its overrides, pool rows, the self-drafting step?, context pages)

@@ -307,12 +307,14 @@ def test_the_step_names_the_stages_the_latent_path_uses():
 # model the benchmark had, read on the parent commit (b5e58af) with this
 # engine configuration: a model whose layers are all of one kind gets the
 # program it got before.  (Read under this suite's conftest: eight virtual
-# devices; a change of the JAX version moves them all, on both commits.)
+# devices; a change of the JAX version moves them all, on both commits.
+# The two latent models' are PR 49's: `wq_b`'s and `idx_wq`'s products went
+# behind a barrier, `latent._head_product`, and nothing else moved.)
 PARENT_STEP = {"mistral-tiny": "c9237077b6f347ef",
                "falcon-h1-tiny": "0a917515148bfc20",
                "gptneo-tiny": "04cea80fd7a5dfc2",
-               "dots3-note-tiny": "f46f0a99c25f5022",
-               "glm-5-tiny": "ac1e0720f15fd89b"}
+               "dots3-note-tiny": "771bfd007280ee70",
+               "glm-5-tiny": "ae31c93635ea6b34"}
 PARENT_ENGINE = {"dtype": "float32",
                  "memory_config": {"num_blocks": 32, "block_size": 8},
                  "max_context": 64,
