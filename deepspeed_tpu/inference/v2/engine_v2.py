@@ -19,8 +19,9 @@ the same collectives AutoTP injection produces in the reference.
 from __future__ import annotations
 
 import time
+from contextlib import contextmanager
 from functools import partial
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +37,7 @@ from deepspeed_tpu.inference.v2.model import (attention_impl_name,
                                               ragged_step,
                                               ragged_step_sampled,
                                               ragged_verify, ssm_impl_name)
-from deepspeed_tpu.inference.v2.ragged import (DSStateManager,
+from deepspeed_tpu.inference.v2.ragged import (IN_FLIGHT, DSStateManager,
                                                KVCacheExhausted, PackedIndex,
                                                RaggedBatch,
                                                build_ragged_batch)
@@ -283,6 +284,22 @@ def hybrid_alloc_counts(cfg: TransformerConfig, engine_cfg, state, cache_k,
 _DRAFT = {"draft": True}
 
 
+class StepInFlight(NamedTuple):
+    """A sampled step that :meth:`InferenceEngineV2.launch` put on the
+    chip and :meth:`InferenceEngineV2.fetch` has not read back."""
+    out: Any                    # the sampled tokens by slot, on the device
+    key: tuple                  # the step program's key (``_ship``)
+    compiled: bool              # the key's first dispatch: it compiled
+    # of each sequence the step samples: its slot (the token's row of
+    # ``out``) and where the token goes in the sequence's ``tokens`` (an
+    # ``extend(uid, IN_FLIGHT)`` keeps the place)
+    places: Dict[int, tuple]
+
+    @property
+    def uids(self):
+        return self.places.keys()
+
+
 class RecurrentStateUnsupported(NotImplementedError):
     """A path that would need a snapshot of a sequence's slot state
     (prefix reuse, speculative verify and rewind, KV hand-off) was asked
@@ -405,6 +422,11 @@ class InferenceEngineV2:
         zeros = partial(jnp.zeros, device=replicated)
         # step()'s default key, on the engine's devices once (_next_key)
         self._step_key = self._put(jax.random.PRNGKey(seed ^ 0x57E9))
+        # what the last sampled step sampled, by slot, where it left it:
+        # the next one's rows of IN_FLIGHT tokens read it there (zeros
+        # before any: the SAME program whether or not a row reads it)
+        self._prev = zeros((self.cfg.max_tracked_sequences + 1,), jnp.int32)
+        self._flight: Optional[StepInFlight] = None    # launched, unfetched
 
         pages = self.cfg.num_blocks * self.cfg.block_size
         # [L, nkv, P, d]: kv-head-major so the paged-attention kernel's page
@@ -718,8 +740,8 @@ class InferenceEngineV2:
                                                     top_p is None)
             # host scalars: the jitted call ships them itself, and not at
             # all where the program does not read them (a greedy step)
-            kw = {"key": sample["key"], "greedy": greedy, "top_k": top_k,
-                  "top_p": top_p, "temperature": np.float32(
+            kw = {"prev": self._prev, "key": sample["key"], "greedy": greedy,
+                  "top_k": top_k, "top_p": top_p, "temperature": np.float32(
                       max(sample["temperature"], 1e-6))}
         if self.self_draft and sample is not _DRAFT:
             # a step without the module leaves a hole in its cache rows
@@ -734,6 +756,8 @@ class InferenceEngineV2:
             if sp is not None:
                 sp.end(**shape)
         out.copy_to_host_async()    # the fetch waits for a copy under way
+        if program is self._step_sampled:
+            self._prev = out
         return rb, out
 
     def _ship(self, rb: RaggedBatch, program, variant: tuple = (),
@@ -743,7 +767,8 @@ class InferenceEngineV2:
         step's packed index buffer (span ``v2.h2d``).  Returns the device
         index, the open ``v2.dispatch`` span (None unless spans are
         recorded) and that span's arguments (``programs``: what the caller
-        dispatched for this step, a key split's two programs included).
+        dispatched for this step, a key split's two programs included;
+        ``ahead``: 1 where the step before it has not been fetched).
         The caller makes the ONE program call itself, in its own frame,
         ends the span and asks for the result's copy back at once: a
         frame more between the serve loop and the jitted call costs every
@@ -758,14 +783,15 @@ class InferenceEngineV2:
         index = self._put(host)         # a pytree of one leaf
         if sp is not None:
             sp.end(arrays=1, bytes=host.buf.nbytes)
-        key = (program.__name__, host.rows, host.blocks) + variant
-        new_shape = key not in self._dispatched
+        key = rb.key = (program.__name__, host.rows, host.blocks) + variant
+        new_shape = rb.compiled = key not in self._dispatched
         if new_shape:
             self._dispatched.add(key)
         sp = (tr.span("v2.dispatch", self.trace_id, parent)
               if tr is not None else None)
         return index, sp, {"t_bucket": host.rows, "nb_bucket": host.blocks,
-                           "new_shape": new_shape, "programs": programs}
+                           "new_shape": new_shape, "programs": programs,
+                           "ahead": int(self._flight is not None)}
 
     def audit_step_args(self, phase: str = "decode"):
         """``(jitted ragged step, example args)`` for the static graph
@@ -808,6 +834,7 @@ class InferenceEngineV2:
         pending decode token) was processed this step; uids mid-prefill
         return nothing yet — call put([], []) again to continue.
         """
+        self._refuse_in_flight("put")
         rb, logits = self._ragged_step(batch_uids, batch_tokens)
         if rb is None:
             return {}
@@ -876,10 +903,88 @@ class InferenceEngineV2:
         if self.self_draft and temperature <= 0 and not return_logits:
             return {uid: burst[-1]
                     for uid, burst in self.step_bursts().items()}
+        with self.stepping():
+            if return_logits:
+                return self._step_logits()
+            flight = self.launch(temperature, key, top_k, top_p)
+            return {} if flight is None else self.fetch(flight)
+
+    def launch(self, temperature: float = 0.0, key: Optional[Any] = None,
+               top_k: int = 0, top_p: float = 1.0
+               ) -> Optional[StepInFlight]:
+        """The first half of a sampled :meth:`step`: schedule, build, ONE
+        transfer, ONE program, and no wait for the chip.  ``None`` when
+        nothing is scheduled; ``KVCacheExhausted`` as ``step`` raises it.
+
+        :meth:`fetch` is the other half.  A caller may launch the NEXT
+        step first, once, so that it waits in the device's queue when
+        this one ends: of the sequences this step samples (``.uids``),
+        those that go on are extended with ``ragged.IN_FLIGHT`` in place
+        of the token nobody has yet; the next step's row then reads the
+        token where this step's program left it, and ``fetch`` writes it
+        over the placeholder.  A sequence that turns out to have ended
+        (an ``eos_token_id`` the host could not see) has ridden one dead
+        row: flush it as any other, the device runs programs in order.
+        Everything else the engine does (``put``, ``step_bursts``,
+        ``verify_step``, ``preempt``, hand-off, ``rewind``) wants the
+        launched step fetched first, and says so."""
+        top_k, top_p = check_sampling_params(top_k, top_p,
+                                             self.model_config.vocab_size)
+        split = 0
+        if key is None:
+            self._step_key, key, split = _next_key(self._step_key,
+                                                   temperature)
+        rb, toks = self._ragged_step(
+            [], [], sample={"key": key, "temperature": temperature,
+                            "top_k": top_k, "top_p": top_p},
+            programs=1 + split)
+        if rb is None:
+            return None
+        mgr = self.state_manager
+        self._flight = StepInFlight(
+            toks, rb.key, rb.compiled,
+            {uid: (slot, len(mgr.get(uid).tokens))
+             for slot, uid in rb.uids_by_slot.items()})
+        return self._flight
+
+    def fetch(self, flight: StepInFlight) -> Dict[int, int]:
+        """The second half of a sampled :meth:`step`: ``{uid: token}`` of
+        a launched step, after the wait for the device and the copy back
+        (span ``v2.fetch``).  Where the caller kept a token's place with
+        ``IN_FLIGHT``, the token is written there."""
+        toks_np = self._fetch(flight.out)
+        if self._flight is flight:
+            self._flight = None
+        mgr = self.state_manager
+        result = {}
+        for uid, (slot, at) in flight.places.items():
+            tok = result[uid] = int(toks_np[slot])
+            if uid in mgr:          # not flushed meanwhile (a dead row's)
+                tokens = mgr.get(uid).tokens
+                if at < len(tokens) and tokens[at] == IN_FLIGHT:
+                    tokens[at] = tok
+        return result
+
+    def forget(self) -> None:
+        """Give up a launched step unfetched (the caller is flushing
+        every sequence: a server's shutdown or crash path)."""
+        self._flight = None
+
+    def _refuse_in_flight(self, what: str) -> None:
+        if self._flight is not None:
+            raise RuntimeError(
+                f"{what}: a launched step has not been fetched (its "
+                "sequences' tokens, positions and pages are a step ahead "
+                "of what the host knows): fetch() it first")
+
+    @contextmanager
+    def stepping(self):
+        """One ``v2.ragged_step`` span (and one ``engine.step`` injection
+        point) over what the caller does inside: a launch and a fetch,
+        which need not be the same step's."""
         sp = self._begin_step()
         try:
-            return self._step_impl(temperature, key, top_k, top_p,
-                                   return_logits)
+            yield
         finally:
             self._end_step(sp)
 
@@ -899,12 +1004,10 @@ class InferenceEngineV2:
         if not self.self_draft:
             raise ValueError("step_bursts: the engine's configuration "
                              "has no self_draft")
-        sp = self._begin_step()
-        try:
+        self._refuse_in_flight("step_bursts")
+        with self.stepping():
             rb, out = self._ragged_step([], [], sample=_DRAFT)
             return {} if rb is None else self._settle(rb, out)
-        finally:
-            self._end_step(sp)
 
     def _begin_step(self):
         """The ``engine.step`` injection point and the step's span
@@ -933,30 +1036,13 @@ class InferenceEngineV2:
             self._step_span = None
             sp.end()
 
-    def _step_impl(self, temperature: float, key: Optional[Any],
-                   top_k: int, top_p: float,
-                   return_logits: bool) -> Dict[int, Any]:
-        if return_logits:
-            rb, logits = self._ragged_step([], [])
-            if rb is None:
-                return {}
-            logits_np = self._fetch(logits)
-            return {uid: logits_np[slot]
-                    for slot, uid in rb.uids_by_slot.items()}
-        top_k, top_p = check_sampling_params(top_k, top_p,
-                                             self.model_config.vocab_size)
-        split = 0
-        if key is None:
-            self._step_key, key, split = _next_key(self._step_key,
-                                                   temperature)
-        rb, toks = self._ragged_step(
-            [], [], sample={"key": key, "temperature": temperature,
-                            "top_k": top_k, "top_p": top_p},
-            programs=1 + split)
+    def _step_logits(self) -> Dict[int, Any]:
+        self._refuse_in_flight("step(return_logits=True)")
+        rb, logits = self._ragged_step([], [])
         if rb is None:
             return {}
-        toks_np = self._fetch(toks)
-        return {uid: int(toks_np[slot])
+        logits_np = self._fetch(logits)
+        return {uid: logits_np[slot]
                 for slot, uid in rb.uids_by_slot.items()}
 
     def _settle(self, rb: RaggedBatch, out) -> Dict[int, List[int]]:
@@ -1015,6 +1101,7 @@ class InferenceEngineV2:
         returned list as a fresh prompt; re-prefill rebuilds the KV and
         greedy decoding continues bit-identically.  Slot and pages are
         freed immediately."""
+        self._refuse_in_flight("preempt")
         seq = self.state_manager.get(uid)
         tokens = list(seq.tokens)
         self.flush(uid)
@@ -1050,6 +1137,7 @@ class InferenceEngineV2:
         """
         import time as _time
 
+        self._refuse_in_flight("export_kv_chain")
         self._refuse_recurrent("exporting a sequence's KV pages for "
                                "hand-off")
         self._refuse_latent_handoff()
@@ -1083,6 +1171,7 @@ class InferenceEngineV2:
         to re-running prefill) and ``KVCacheExhausted`` when the pool
         cannot host the tail.  Engine-owning thread only.
         """
+        self._refuse_in_flight("import_kv_chain")
         self._refuse_recurrent("importing handed-off KV pages")
         self._refuse_latent_handoff()
         if tuple(payload["geom"]) != self.kv_geometry():
@@ -1152,6 +1241,7 @@ class InferenceEngineV2:
         from absolute positions, and attention masks by ``ctx_lens``).
         Raises ``KVCacheExhausted`` with every sequence rolled back.
         """
+        self._refuse_in_flight("verify_step")
         self._refuse_recurrent("verify_step (speculative decoding)")
         self._refuse_external_draft("verify_step")
         mgr = self.state_manager
@@ -1221,6 +1311,7 @@ class InferenceEngineV2:
         shrink — garbage KV beyond it is overwritten when those
         positions are legitimately re-run.  Allocated pages stay with
         the sequence (capacity, not content)."""
+        self._refuse_in_flight("rewind")
         self._refuse_recurrent("rewind")
         self._refuse_external_draft("rewind")
         seq = self.state_manager.get(uid)
@@ -1284,6 +1375,7 @@ class InferenceEngineV2:
         ``top_k``/``top_p`` restrict temperature sampling to the top-k
         logits / the top-p nucleus (ref FastGen logits processors);
         0 / 1.0 disable them."""
+        self._refuse_in_flight("generate")
         top_k, top_p = check_sampling_params(top_k, top_p,
                                              self.model_config.vocab_size)
         uids = list(range(len(prompts)))

@@ -1008,15 +1008,25 @@ def ragged_step(params, cache_k, cache_v, index, state=None, *,
                           window=index.window_arrays())
 
 
-def ragged_step_sampled(params, cache_k, cache_v, index, key, temperature,
-                        cfg: TransformerConfig, block_size: int,
+def ragged_step_sampled(params, cache_k, cache_v, index, prev, key,
+                        temperature, cfg: TransformerConfig, block_size: int,
                         greedy: bool, top_k: int = 0, top_p=None,
                         state=None):
     """:func:`ragged_forward_sampled` on a packed index buffer (its body,
-    not a call of it: a frame fewer under every traced operation)."""
+    not a call of it: a frame fewer under every traced operation).
+
+    ``prev`` [S+1] int32: the tokens the step before this one sampled, by
+    slot, still on the device.  A row whose token id is negative
+    (``ragged.IN_FLIGHT``: the host launched this step before that token
+    reached it) reads ``prev`` at its slot instead; any other row, and a
+    first step's ``prev`` of zeros, leaves the ids as the host wrote
+    them."""
+    token_ids, token_slot, *rest = index.arrays()
+    with jax.named_scope("embed"):
+        token_ids = jnp.where(token_ids < 0, prev[token_slot], token_ids)
     logits, *carried = ragged_forward(
-        params, cache_k, cache_v, *index.arrays(), state, cfg=cfg,
-        block_size=block_size, window=index.window_arrays())
+        params, cache_k, cache_v, token_ids, token_slot, *rest, state,
+        cfg=cfg, block_size=block_size, window=index.window_arrays())
     with jax.named_scope("head"):
         nxt = sample_tokens(logits, key, temperature, greedy, top_k, top_p)
     return (nxt, *carried)

@@ -113,6 +113,13 @@ class BlockedAllocator:
                 self._free.append(b)
 
 
+# the token id of a sequence's sampled token while the step that samples it
+# is still on the device (``InferenceEngineV2.launch``): the next step's row
+# reads the token where the device left it (``model.ragged_step_sampled``:
+# any negative id), and the fetch writes it over this
+IN_FLIGHT = -1
+
+
 @dataclass
 class SequenceDescriptor:
     """Host-side state of one in-flight sequence (ref ragged_manager.py:19)."""
@@ -249,9 +256,9 @@ class DSStateManager:
     recomputes from zeros.  ``open(num_cached > 0)`` would start a
     sequence past position 0: the engine refuses it for such a model.
 
-    It also keeps the host side of a step's index arrays: one
-    ``PackedIndex`` buffer a (token bucket, block bucket), made at the
-    bucket's first step and rewritten by every later one.
+    It also keeps the host side of a step's index arrays: two
+    ``PackedIndex`` buffers a (token bucket, block bucket), made at the
+    bucket's first steps and rewritten in turn by the later ones.
 
     **Two kinds of layer** (``window`` > 0: a model that mixes window and
     full attention by layer).  The full layers' rows live in the pool
@@ -282,7 +289,8 @@ class DSStateManager:
         self.allocator = BlockedAllocator(num_blocks)
         self._seqs: Dict[int, SequenceDescriptor] = {}
         self._free_slots = list(range(max_seqs - 1, -1, -1))
-        self._index: Dict[Tuple[int, int], PackedIndex] = {}
+        self._index: Dict[Tuple[int, int, int], PackedIndex] = {}
+        self._turn = 0              # which of a bucket's two buffers is next
         # a self-drafting engine's index buffers carry two more arrays
         self.drafting = False
         self.window = int(window)
@@ -391,17 +399,20 @@ class DSStateManager:
         self._free_slots.append(seq.slot)
 
     def step_index(self, rows: int, blocks: int) -> PackedIndex:
-        """The host index buffer of a (``rows``, ``blocks``) bucket,
+        """A host index buffer of a (``rows``, ``blocks``) bucket,
         cleared to a step's padding: token ids, positions and
         destinations 0 (the garbage page), slot ``max_seqs``, empty
-        tables.  The SAME buffer every time: the caller has read the
-        result of the step it last built here before it builds the next
-        (every engine path fetches what it dispatched), so nothing still
-        reads what is overwritten."""
-        index = self._index.get((rows, blocks))
+        tables.  Two buffers a bucket, handed out in turn whatever the
+        bucket: a step may be built while the one before it is still on
+        the device (its transfer may not have read the buffer yet), and
+        the caller has fetched the step before THAT one (the engine runs
+        one step ahead at most), so nothing still reads what is
+        overwritten."""
+        self._turn ^= 1
+        index = self._index.get((rows, blocks, self._turn))
         if index is None:
             slots = self.max_seqs + 1
-            index = self._index[rows, blocks] = PackedIndex(
+            index = self._index[rows, blocks, self._turn] = PackedIndex(
                 np.empty((PackedIndex.size(rows, slots, blocks, self.drafting,
                                            bool(self.window)),), np.int32),
                 rows, slots, blocks, self.drafting, bool(self.window))
@@ -426,6 +437,10 @@ class RaggedBatch:
     # a self-drafting step's verify runs: the sequences whose last row
     # is their draft
     verified: Tuple[SequenceDescriptor, ...] = ()
+    # once shipped (``InferenceEngineV2._ship``): the step program's key,
+    # and whether this was the key's first dispatch (the one that compiles)
+    key: Tuple = ()
+    compiled: bool = False
 
 
 def _bucket(n: int, floor: int, cap: int) -> int:

@@ -70,6 +70,14 @@ class SplitFuseScheduler:
     def has_work(self) -> bool:
         return bool(self._decode or self._prefill)
 
+    @property
+    def has_rows(self) -> bool:
+        """Whether a step scheduled now would hold a row: a prompt is
+        waiting, or a decoding sequence has a token (or the place of
+        one) that no step has run."""
+        return bool(self._prefill) or any(
+            self.mgr.get(uid).uncached > 0 for uid in self._decode)
+
     def next_schedule(self, drafts: bool = False
                       ) -> List[Tuple[SequenceDescriptor, int]]:
         """(sequence, n_tokens) items for one step, ≤ token_budget total.

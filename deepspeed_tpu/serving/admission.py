@@ -161,6 +161,9 @@ class AdmissionController:
         self._lock = threading.Condition()
         self._queue: Deque[GenerationRequest] = deque()
         self._closed = False
+        # requests ever put in the queue (offers and requeues): what a
+        # loop that waits with a request already queued waits on
+        self._arrivals = 0
         # set by the server when tracing is enabled: the blocking-offer
         # wait is a real request phase (serve.admission_block spans)
         self.tracer = None
@@ -195,6 +198,7 @@ class AdmissionController:
                 raise QueueFull(
                     f"queue full ({self.cfg.max_queue_size} waiting)")
             self._queue.append(req)
+            self._arrivals += 1
             self._lock.notify_all()
 
     def close(self) -> None:
@@ -208,6 +212,7 @@ class AdmissionController:
         """Preempted request: back of nobody's line."""
         with self._lock:
             self._queue.appendleft(req)
+            self._arrivals += 1
             self._lock.notify_all()
 
     def peek(self) -> Optional[GenerationRequest]:
@@ -248,12 +253,24 @@ class AdmissionController:
         with self._lock:
             return len(self._queue)
 
-    def wait_for_work(self, timeout: float) -> None:
+    @property
+    def arrivals(self) -> int:
+        """Requests ever put in the queue (``wait_for_work``'s ``since``)."""
+        return self._arrivals
+
+    def wait_for_work(self, timeout: float,
+                      since: Optional[int] = None) -> int:
         """Park the serve loop until a request arrives (or timeout — the
-        loop still needs to wake for deadline sweeps)."""
+        loop still needs to wake for deadline sweeps).  Returns the count
+        of requests ever queued; given back as ``since``, the wait is for
+        one MORE than that (a loop holding a launch back with the head of
+        the queue waiting on pages is woken by the next arrival, not kept
+        spinning by the head)."""
         with self._lock:
-            if not self._queue:
+            if (not self._queue if since is None
+                    else self._arrivals == since and not self._closed):
                 self._lock.wait(timeout)
+            return self._arrivals
 
     # -- policy ----------------------------------------------------------
     def kv_floor(self, engine, watermark: float) -> int:

@@ -68,7 +68,8 @@ class ServingMetrics:
                                 "prefix_misses", "prefill_tokens_saved",
                                 "handoffs_in", "handoffs_out",
                                 "handoff_bytes", "spec_rounds",
-                                "spec_proposed", "spec_accepted")
+                                "spec_proposed", "spec_accepted",
+                                "steps_ahead", "arrivals_after_launch")
                    + _OUTCOMES}
         # distributions (seconds)
         self._ttft = reg.histogram("serving_ttft_seconds",
@@ -106,6 +107,9 @@ class ServingMetrics:
     preemptions = property(lambda self: self._cv("preemptions"))
     tokens_out = property(lambda self: self._cv("tokens_out"))
     steps = property(lambda self: self._cv("steps"))
+    steps_ahead = property(lambda self: self._cv("steps_ahead"))
+    arrivals_after_launch = property(
+        lambda self: self._cv("arrivals_after_launch"))
     flight_dumps = property(lambda self: self._cv("flight_dumps"))
     prefix_hits = property(lambda self: self._cv("prefix_hits"))
     prefix_misses = property(lambda self: self._cv("prefix_misses"))
@@ -145,6 +149,18 @@ class ServingMetrics:
 
     def record_step(self) -> None:
         self._c["steps"].inc()
+
+    def record_step_ahead(self) -> None:
+        """A step whose program was called while the step before it was
+        still running (the loop's plain greedy path, one step ahead)."""
+        self._c["steps_ahead"].inc()
+
+    def record_arrival_after_launch(self) -> None:
+        """A request that arrived after the next step's launch and before
+        the running step's tokens: it waits one step more than in a loop
+        that launches nothing ahead (the timed launch keeps that stretch
+        to about the launch time)."""
+        self._c["arrivals_after_launch"].inc()
 
     def record_preemption(self) -> None:
         self._c["preemptions"].inc()
@@ -233,6 +249,8 @@ class ServingMetrics:
             "flight_dumps": self.flight_dumps,
             "tokens_out": tokens_out,
             "steps": self.steps,
+            "steps_ahead": self.steps_ahead,
+            "arrivals_after_launch": self.arrivals_after_launch,
             "tokens_per_sec": tokens_out / elapsed,
             "queue_depth": self.queue_depth,
             "active_requests": self.active_requests,

@@ -16,6 +16,16 @@ Loop anatomy (docs/SERVING.md has the diagram):
     submit() → bounded queue → admission (slots + KV watermarks)
              → SplitFuse scheduler → engine.step() → per-request streams
 
+One step ahead: while every running request is greedy (and the engine
+neither drafts for itself nor has an external draft), the loop calls step
+N+1's program before it waits for step N's tokens, so that the chip goes
+from one to the other without the host between them.  The tokens step N
+sampled reach step N+1 on the device (``engine.launch`` / ``engine.fetch``);
+the launch is held back to the running step's predicted end less the
+launch time, waiting on the admission queue, so that a request arriving
+meanwhile is in step N+1 (``_step_once``, ``_hold``).  Anything else with
+a step on the chip fetches it first (``_drain``).
+
 Robustness: cancellation and deadlines are swept every iteration; KV
 exhaustion preempts the lowest-priority/youngest running request
 (recompute-style requeue at the front of the queue) instead of crashing;
@@ -39,7 +49,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
-from deepspeed_tpu.inference.v2.ragged import KVCacheExhausted
+from deepspeed_tpu.inference.v2.ragged import IN_FLIGHT, KVCacheExhausted
 from deepspeed_tpu.serving.admission import (BROWNOUT_LEVELS,
                                              AdmissionConfig,
                                              AdmissionController,
@@ -89,6 +99,43 @@ def _host_sample(logits: np.ndarray, params: SamplingParams,
         masked[kept] = x[kept]
         x = masked
     return int(rng.choice(x.size, p=_softmax(x)))
+
+
+class _Flight:
+    """A plain greedy step on the chip whose tokens the loop has not
+    fetched, and what the loop knows of its time there."""
+
+    __slots__ = ("step", "extended", "idle", "called", "begins")
+
+    def __init__(self, step, idle: bool, called: float, begins: float):
+        self.step = step            # the engine's StepInFlight
+        self.called = called        # when its launch began (host clock)
+        # uids whose next token's place an IN_FLIGHT holds in the engine's
+        # sequence (they ride the step after this one; the fetch fills it)
+        self.extended: set = set()
+        # launched with nothing on the chip (it began at once) or behind
+        # the step before it (it begins when that one ends)
+        self.idle = idle
+        self.begins = begins        # when it begins on the chip (host clock)
+
+
+# a launch is begun this many launch times before the running step's
+# predicted end: the launch itself, and half as much again for a wake-up
+# that comes late and a step that ends early
+_LEAD = 1.5
+
+
+# a fetch that returns within this found its tokens on the host already:
+# the step had ended before the loop asked, and how long before is not read
+_HERE_S = 1e-4
+
+
+def _toward(old: Optional[float], new: float, up: float = 0.25,
+            down: float = 0.25) -> float:
+    """A running estimate moved part of the way to a new reading."""
+    if old is None:
+        return new
+    return old + (up if new > old else down) * (new - old)
 
 
 class ServerConfig:
@@ -234,6 +281,22 @@ class InferenceServer:
         self.loop_iters = 0
         self.step_ema_s = 0.0
         self._active: Dict[int, GenerationRequest] = {}
+        # -- one step ahead (the plain greedy path; see _step_once) --
+        self._flight: Optional[_Flight] = None
+        # the loop's own measurements, by the step program's key: what the
+        # program takes on the chip (fetch return to fetch return of a
+        # step launched behind another), what a step launched on an idle
+        # chip takes from its launch to its tokens, and what a launch
+        # takes on the host (schedule, build, transfer, call)
+        self._device_s: Dict[tuple, float] = {}
+        self._solo_s: Dict[tuple, float] = {}
+        self._launch_s: Dict[tuple, float] = {}
+        # from the chip's end of a step to its tokens on the host (the
+        # copy back and the wake-up): the second of those less the first
+        self._tail_s: Optional[float] = None
+        # [launch of the step ahead, the running step's fetch): requests
+        # submitted inside it missed that step (arrivals_after_launch)
+        self._missed: Optional[tuple] = None
         self._uid = itertools.count()
         self._uid_lock = threading.Lock()
         self._rngs: Dict[int, np.random.Generator] = {}
@@ -478,19 +541,20 @@ class InferenceServer:
                         RequestCancelled("server shutdown"))
                     return
                 now = time.monotonic()
+                if self._flight is not None and self._drain_due(now):
+                    self._drain()
                 # serve.admit_pass, serve.step and serve.deliver (or
                 # serve.idle_wait) tile one iteration of this loop
                 tr = self.tracer
                 sp = tr.span("serve.admit_pass", self._loop_trace_id)
                 n_before = self.metrics.admitted if tr.enabled else 0
-                self._sweep_queue(now)
-                self._sweep_active(now)
-                self._try_admit(now)
-                self._update_gauges()
+                self._admit_pass(now)
+                self._missed = None
                 if tr.enabled:
                     sp.set(admitted=self.metrics.admitted - n_before)
                 sp.end()
-                if self.engine.scheduler.has_work:
+                if self.engine.scheduler.has_work \
+                        or self._flight is not None:
                     self._step_once()
                 elif self._stop_requested and len(self.admission) == 0 \
                         and not self._active:
@@ -520,7 +584,10 @@ class InferenceServer:
         Crashes/hangs deliberately ride the loop's real failure paths —
         a ChaosError is indistinguishable from an organic death."""
         from deepspeed_tpu.resilience.chaos import ChaosError
-        for f in ch.fire("server.step"):
+        faults = ch.fire("server.step")
+        if faults:
+            self._drain()
+        for f in faults:
             kind = f.kind
             if kind == "replica_crash":
                 raise ChaosError(
@@ -570,6 +637,9 @@ class InferenceServer:
             pass  # forensics must never mask the original failure
 
     def _fail_everything(self, err: ServingError) -> None:
+        # a step still on the chip is given up: every sequence goes
+        self._flight = None
+        self.engine.forget()
         for req in self.admission.drain():
             self._finish(req, error=err)
         for uid in list(self._active):
@@ -583,6 +653,27 @@ class InferenceServer:
                 # remaining streams unterminated
                 pass
             self._finish(req, error=err)
+
+    def _admit_pass(self, now: float) -> None:
+        self._sweep_queue(now)
+        self._sweep_active(now)
+        self._try_admit(now)
+        self._update_gauges()
+
+    def _drain_due(self, now: float) -> bool:
+        """Whether the admit pass about to run would take a sequence away
+        or write pages with a step still on the chip: a cancel or a
+        deadline to sweep, a hand-off payload at the head of the queue."""
+        head = self.admission.peek()
+        return (head is not None and head.kv_payload is not None) or any(
+            self._swept(r, now) is not None for r in self._active.values())
+
+    def _drain(self) -> None:
+        """Fetch and deliver the step on the chip, if any, and launch
+        nothing: what follows finds engine, allocator and slots as a
+        loop that never ran ahead would have left them."""
+        if self._flight is not None:
+            self._step_once(launch=False)
 
     def _sweep_queue(self, now: float) -> None:
         """Cancelled/expired requests that never got admitted; under
@@ -608,15 +699,20 @@ class InferenceServer:
                         "shed from queue at brownout level "
                         f"{self.brownout_level!r}"))
 
+    @staticmethod
+    def _swept(req: GenerationRequest, now: float) -> Optional[ServingError]:
+        """What ends a running request at a sweep, if anything does."""
+        if req.stream.cancel_requested:
+            return RequestCancelled(f"request {req.uid} cancelled")
+        if req.expired(now):
+            return DeadlineExceeded(f"request {req.uid} deadline passed "
+                                    f"after {req.n_generated} tokens")
+        return None
+
     def _sweep_active(self, now: float) -> None:
         for uid in list(self._active):
             req = self._active[uid]
-            err = None
-            if req.stream.cancel_requested:
-                err = RequestCancelled(f"request {uid} cancelled")
-            elif req.expired(now):
-                err = DeadlineExceeded(f"request {uid} deadline passed "
-                                       f"after {req.n_generated} tokens")
+            err = self._swept(req, now)
             if err is not None:
                 del self._active[uid]
                 self._flush_seq(uid)
@@ -638,6 +734,8 @@ class InferenceServer:
             req = self.admission.peek()
             if req is None:
                 break
+            if req.kv_payload is not None and self._flight is not None:
+                break   # its import writes pages: the next pass drains first
             # Adopt the cached prefix FIRST: the acquired refs (>= 2 with
             # the cache's own) pin those pages against the eviction pass
             # below — and against this very request's need (adopted pages
@@ -717,6 +815,9 @@ class InferenceServer:
                 # queue wait — recording them would double-count the
                 # request and skew the distribution
                 self.metrics.record_admit(now - req.submitted_at)
+                missed = self._missed
+                if missed and missed[0] <= req.submitted_at < missed[1]:
+                    self.metrics.record_arrival_after_launch()
             self._active[req.uid] = req
 
     def _import_handoff(self, req: GenerationRequest, adopted: List[int],
@@ -791,17 +892,44 @@ class InferenceServer:
             return 0
         return self.prefix_cache.evict(n_blocks)
 
-    def _step_once(self) -> None:
-        """One engine step; KV exhaustion reclaims cache pages, then
-        preempts, and retries next tick."""
-        deficit = self.admission.low_watermark_deficit(self.engine)
-        if deficit > 0 and len(self._active) > 1:
+    def _step_once(self, launch: bool = True) -> None:
+        """One device step's tokens reach the host and their streams; KV
+        exhaustion reclaims cache pages, then preempts, and retries next
+        tick.
+
+        **The plain greedy path runs one step ahead.**  With step N on
+        the chip (``self._flight``) the loop holds until N's predicted end
+        less the launch time (``_hold``: arrivals are admitted meanwhile),
+        calls step N+1's program, and only then waits for N's tokens:
+        N+1 is in the device's queue when N ends.  The sequences N
+        samples ride N+1 on a placeholder (``_keep_places``); one that
+        ends by ``max_new_tokens`` is known to and is left out, one that
+        ends by an ``eos_token_id`` rides a dead row and is flushed here,
+        after N+1's launch.  A step whose program has no measured time
+        yet is fetched before anything follows it (``_go_time``).
+        Everything else (a
+        batch not all greedy, a draft of either kind, ``launch=False``: a
+        drain) runs or finishes with nothing launched behind it."""
+        eng = self.engine
+        if launch and len(self._active) > 1 \
+                and self.admission.low_watermark_deficit(eng) > 0:
+            if self._flight is not None:
+                self._drain()       # what it finishes frees pages too
+                return
             # floor hit: reclaim idle cache pages first, shed live work
             # only if that was not enough
+            deficit = self.admission.low_watermark_deficit(eng)
             if self._reclaim_cache(deficit) < deficit:
                 self._preempt_one()
         all_greedy = all(r.params.greedy for r in self._active.values())
-        spec_ready = self._spec_eligible()
+        spec_ready = launch and self._spec_eligible()
+        # the one kind of step that can follow a step still on the chip
+        plain = (all_greedy and not spec_ready and not self._self_draft
+                 and not any(r.handoff or r.pending_insert
+                             for r in self._active.values()))
+        if launch and self._flight is not None and not plain:
+            self._drain()
+            return
         tr = self.tracer
         step_span = tr.span("serve.step", self._loop_trace_id)
         if tr.enabled:
@@ -815,35 +943,55 @@ class InferenceServer:
         if warm and self._watchdog is not None:
             self._watchdog.pause()
         step_t0 = time.monotonic()
+        flight, ahead, exhausted = self._flight, None, False
         try:
             try:
-                if spec_ready:
+                if flight is None and spec_ready:
                     # draft proposes, target verifies in ONE ragged step;
                     # each value is the accepted token burst (>= 1), and
                     # the engine's sequences already carry them
                     emitted = self._spec.round(self._active)
-                elif all_greedy and self._self_draft:
+                elif flight is None and all_greedy and self._self_draft:
                     # the engine drafts for itself inside the ragged step
                     # (its multi-token-prediction module): a burst of one
                     # or two tokens a sequence, prompts' chunks in the
                     # same step; the last token is extended below as a
                     # plain step's
-                    before = (self.engine.drafts_verified,
-                              self.engine.drafts_accepted)
-                    emitted = self.engine.step_bursts()
+                    before = (eng.drafts_verified, eng.drafts_accepted)
+                    emitted = eng.step_bursts()
                     self.metrics.record_spec_round(
-                        self.engine.drafts_verified - before[0],
-                        self.engine.drafts_accepted - before[1])
-                elif all_greedy:
-                    emitted = {u: [t] for u, t in
-                               self.engine.step(temperature=0.0).items()}
-                else:
-                    logits = self.engine.step(return_logits=True)
+                        eng.drafts_verified - before[0],
+                        eng.drafts_accepted - before[1])
+                elif flight is None and not all_greedy:
+                    logits = eng.step(return_logits=True)
                     emitted = {u: [_host_sample(out,
                                                 self._active[u].params,
                                                 self._rngs[u])]
                                for u, out in logits.items()
                                if u in self._active}
+                else:
+                    held_s = 0.0
+                    with eng.stepping():
+                        if flight is None:
+                            # nothing on the chip: this step begins now
+                            flight = self._launch(idle=True)
+                            launch = launch and plain and flight is not None
+                            if launch and self._go_time(flight) is not None:
+                                self._keep_places(flight)
+                        # (no launch that would find nothing to run:
+                        # every sequence of the running step ends with it)
+                        go = (self._go_time(flight)
+                              if launch and eng.scheduler.has_rows else None)
+                        if go is not None:
+                            held_s = self._hold(go)
+                            try:
+                                ahead = self._launch(idle=False)
+                            except KVCacheExhausted:
+                                exhausted = True
+                        emitted = self._fetch(flight, ahead)
+                    self._flight = ahead
+                    if tr.enabled:
+                        step_span.set(held_us=held_s * 1e6)
                 # only a step that actually ran proves the compile is
                 # behind us — KVCacheExhausted rolls back with nothing
                 # run, so the retry still pays the first jit compile and
@@ -854,12 +1002,7 @@ class InferenceServer:
                     self._watchdog.resume()
         except KVCacheExhausted:
             step_span.end(kv_exhausted=True)
-            # a step's worth of pages from the cache buys a retry without
-            # touching live work; preempt only if the cache came up dry
-            want = max(1, self.engine.seq_blocks(
-                self.engine.scheduler.token_budget))
-            if self._reclaim_cache(want) == 0:
-                self._preempt_one()
+            self._make_room()
             return
         except BaseException:
             # close the span before the crash handler runs so the dying
@@ -883,21 +1026,167 @@ class InferenceServer:
                 if self.telemetry is not None:
                     self.telemetry.record_serving_step(
                         self.metrics.steps, self.metrics.snapshot())
-            n_tokens, n_finished = self._deliver(emitted, spec_ready)
+            n_tokens, n_finished = self._deliver(
+                emitted, spec_ready,
+                flight.extended if flight is not None else ())
+            if ahead is not None:
+                self._keep_places(ahead)
             if tr.enabled:
                 deliver_span.set(tokens=n_tokens, finished=n_finished)
+        if exhausted:
+            # the step ahead found no pages: nothing of it ran, and the
+            # running one is delivered now, as before a step that raised
+            self._make_room()
 
-    def _deliver(self, emitted: Dict[int, List[int]],
-                 spec_ready: bool) -> tuple:
+    def _make_room(self) -> None:
+        """After a step that found the KV pool exhausted (nothing ran):
+        a step's worth of pages from the cache buys a retry without
+        touching live work; preempt only if the cache came up dry."""
+        want = max(1, self.engine.seq_blocks(
+            self.engine.scheduler.token_budget))
+        if self._reclaim_cache(want) == 0:
+            self._preempt_one()
+
+    # -- one step ahead ---------------------------------------------------
+    def _launch(self, idle: bool) -> Optional[_Flight]:
+        """``engine.launch`` under the loop's clock; None when nothing is
+        scheduled.  ``idle``: nothing is on the chip, so the step begins
+        as soon as it is launched."""
+        t0 = time.monotonic()
+        step = self.engine.launch()
+        t1 = time.monotonic()
+        if step is None:
+            return None
+        if not step.compiled:
+            self._launch_s[step.key] = _toward(
+                self._launch_s.get(step.key), t1 - t0)
+        if not idle:
+            self.metrics.record_step_ahead()
+        return _Flight(step, idle, called=t0, begins=t1)
+
+    def _go_time(self, flight: _Flight) -> Optional[float]:
+        """When to launch the step after ``flight``: its predicted end on
+        the chip less ``_LEAD`` launch times.  None: not before its
+        tokens are here, because its program's time on the chip is not
+        known; the fetch then reads it, where the step was launched
+        behind another.  A step launched on an idle chip reads launch to
+        tokens only (``_solo_s``), so the next time its program begins
+        on an idle chip the step behind it is launched at once, and
+        reads the program's time."""
+        key = flight.step.key
+        device_s = self._device_s.get(key)
+        if device_s is not None:
+            return (flight.begins + device_s
+                    - _LEAD * self._launch_s.get(key, 0.0))
+        return 0.0 if flight.idle and key in self._solo_s else None
+
+    def _fetch(self, flight: Optional[_Flight],
+               ahead: Optional[_Flight]) -> Dict[int, List[int]]:
+        """``engine.fetch`` and what its return says of the program's time
+        on the chip.  From a step's beginning there to its tokens here is
+        the program's time and the way back to the host (``_tail_s``).
+        A step launched behind another began when that one ended, which
+        the host saw a way back later: fetch return to fetch return is
+        the program's time alone.  One launched on an idle chip began at
+        its launch: launch to tokens is its reading (``_solo_s``).  A
+        fetch that did not wait at all bounds the program's time from
+        above and no more: the estimate comes down by a launch time, so
+        that a loop the HOST bounds soon launches at once and holds
+        nothing."""
+        if flight is None:
+            return {}
+        called = time.monotonic()
+        tokens = self.engine.fetch(flight.step)
+        now = time.monotonic()
+        waited = now - called > _HERE_S
+        key = flight.step.key
+        device_s = self._device_s.get(key)
+        tail_s = self._tail_s or 0.0
+        if flight.idle:
+            if waited:
+                self._solo_s[key] = _toward(self._solo_s.get(key),
+                                            now - flight.begins)
+        else:
+            reading = max(0.0, now - tail_s - flight.begins)
+            if device_s is not None:
+                # a host stopped for seconds reads as a step of seconds:
+                # no one reading moves the estimate far
+                reading = min(reading, 2.0 * device_s + 1e-3)
+            if waited:
+                if device_s is None and key in self._solo_s:
+                    # the key's first two steps, one after the other:
+                    # the same work from an idle chip and behind a step
+                    self._read_tail(self._solo_s[key] - reading)
+                # the steps of one key differ (contexts, rows that are
+                # padding): the estimate follows the SHORT ones, fast
+                # down and slowly up.  Too short a one launches early,
+                # and an arrival in that stretch waits a step more; too
+                # long a one holds the launch past the step's end, and
+                # every stream waits for an idle chip
+                self._device_s[key] = _toward(device_s, reading, up=0.03,
+                                              down=0.5)
+            elif device_s is not None:
+                # the step had ended a launch time before at least
+                self._device_s[key] = max(0.0, min(device_s, reading)
+                                          - self._launch_s.get(key, 0.0))
+        if ahead is not None:
+            # it began when this step ended, or at its own launch if that
+            # came later still
+            ahead.begins = max(ahead.begins, now - tail_s)
+            self._missed = (ahead.called, now)
+        return {uid: [tok] for uid, tok in tokens.items()}
+
+    def _read_tail(self, reading_s: float) -> None:
+        """A reading of the way from the chip's end of a step to its
+        tokens on the host: a step's time from an idle chip less its
+        program's, read where a key's first two steps give both (no one
+        reading moves the estimate far: a host stopped in between reads
+        as a long way)."""
+        if self._tail_s is not None:
+            reading_s = min(reading_s, 2.0 * self._tail_s + 5e-4)
+        self._tail_s = max(0.0, _toward(self._tail_s, reading_s))
+
+    def _hold(self, go: float) -> float:
+        """Wait until ``go``, the moment to launch the next step, on the
+        admission queue: an arrival wakes the loop, is admitted (host
+        bookkeeping alone) and so rides the step about to be launched;
+        in a loop that launched nothing ahead it would have waited out
+        the running step as well.  Returns the seconds held."""
+        t0 = now = time.monotonic()
+        seen = self.admission.arrivals
+        while now < go and not self._abort:
+            arrivals = self.admission.wait_for_work(go - now, since=seen)
+            now = time.monotonic()
+            if arrivals != seen:
+                seen = arrivals
+                self._sweep_queue(now)
+                self._try_admit(now)
+        return now - t0
+
+    def _keep_places(self, flight: _Flight) -> None:
+        """Let the sequences ``flight`` samples ride the step after it:
+        an ``IN_FLIGHT`` holds the place of the token that step will
+        read on the device.  Not where the token in flight is the
+        request's last by ``max_new_tokens``."""
+        for uid in flight.step.uids:
+            req = self._active.get(uid)
+            if req is not None and req.remaining > 1:
+                self.engine.extend(uid, IN_FLIGHT)
+                flight.extended.add(uid)
+
+    def _deliver(self, emitted: Dict[int, List[int]], spec_ready: bool,
+                 extended=()) -> tuple:
         """Hand one step's tokens to their streams, finish what is done,
-        extend what is not; ``(tokens delivered, requests finished)``."""
+        extend what is not (``extended``: the uids whose place the engine
+        has kept and filled already, ``_keep_places``); ``(tokens
+        delivered, requests finished)``."""
         tr = self.tracer
         n_tokens = n_finished = 0
         now = time.monotonic()
         for uid, burst in emitted.items():
             req = self._active.get(uid)
-            if req is None:       # flushed between schedule and fetch
-                continue          # (cannot happen today; belt+braces)
+            if req is None:       # finished by the step before, whose
+                continue          # tokens came after this one's launch
             done = False
             for tok in burst:
                 tok = int(tok)
@@ -949,7 +1238,7 @@ class InferenceServer:
                     self._export_handoff(req)
                 self._flush_seq(uid)
                 self._finish(req)
-            elif not spec_ready:
+            elif not spec_ready and uid not in extended:
                 # speculative bursts were appended to the engine sequence
                 # by verify_step itself; a plain step's token must extend
                 # (and a self-drafting step's last)

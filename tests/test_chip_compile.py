@@ -170,7 +170,9 @@ def _compile_step(chip, cfg, pool, nb, t, state=None):
     index = PackedIndex(chip((PackedIndex.size(t, 65, nb),), I32), t, 65, nb)
     fn = functools.partial(v2_model.ragged_step_sampled, cfg=cfg,
                            block_size=_BS, greedy=True)
-    args = (params, pool, pool, index, chip((2,), jnp.uint32), chip((), F32))
+    # (the tokens the step before sampled, by slot: the fed-back operand)
+    args = (params, pool, pool, index, chip((65,), I32),
+            chip((2,), jnp.uint32), chip((), F32))
     kw = {} if state is None else {"state": state}
     with jax.default_matmul_precision("default"):
         return jax.jit(fn, donate_argnums=(1, 2),
@@ -483,8 +485,9 @@ def latent_step(chip):
                                        block_size=128, greedy=True)
                 compiled = jax.jit(fn, donate_argnums=(1, 2),
                                    donate_argnames=("state",)).lower(
-                    params, ck, cv, index, chip((2,), jnp.uint32),
-                    chip((), F32), state=state).compile()
+                    params, ck, cv, index, chip((33,), I32),
+                    chip((2,), jnp.uint32), chip((), F32),
+                    state=state).compile()
         return cfg, params, (ck, cv, state), compiled
 
     return step
@@ -556,8 +559,8 @@ def test_one_mixer_a_layer_step_keeps_every_buffer_in_place(chip, t):
     with jax.default_matmul_precision("default"):
         compiled = jax.jit(fn, donate_argnums=(1, 2),
                            donate_argnames=("state",)).lower(
-            params, pool, pool, index, chip((2,), jnp.uint32), chip((), F32),
-            state=state).compile()
+            params, pool, pool, index, chip((slots,), I32),
+            chip((2,), jnp.uint32), chip((), F32), state=state).compile()
     text = compiled.as_text()
     # both pools, the slots and the tails come back as they came in
     assert len(_aliased_outputs(text)) == 4, _aliased_outputs(text)
@@ -736,7 +739,8 @@ def test_latent_step_reads_by_the_one_path_its_shapes_choose(
                                    block_size=bs, greedy=True)
             compiled = jax.jit(fn, donate_argnums=(1, 2),
                                donate_argnames=("state",)).lower(
-                params, ck, cv, index, chip((2,), jnp.uint32), chip((), F32),
+                params, ck, cv, index, chip((33,), I32),
+                chip((2,), jnp.uint32), chip((), F32),
                 state=state).compile()
             held, experts = (ck, cv, state["win"]), _DOTS3_EXPERTS
     text = compiled.as_text()

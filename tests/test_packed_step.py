@@ -129,7 +129,7 @@ def test_packed_build_matches_the_unpacked_build(seed):
     rng = np.random.default_rng(seed)
     mgrs = [DSStateManager(max_seqs=8, num_blocks=160, block_size=4,
                            max_blocks_per_seq=16) for _ in range(2)]
-    seen, uid = set(), 0
+    seen, uid, last = set(), 0, None
     for _ in range(150):
         for _ in range(int(rng.integers(0, 3))):
             if mgrs[0].n_active < 8:
@@ -173,7 +173,12 @@ def test_packed_build_matches_the_unpacked_build(seed):
         assert rb.uids_by_slot == want_uids
         assert list(rb.uids_by_slot) == list(want_uids)     # the order too
         assert rb.n_tokens == sum(n for _, n in picks)
-        assert rb.index is mgrs[0]._index[rb.index.rows, rb.index.blocks]
+        # two buffers a bucket, in turn: the step before this one may
+        # still be on the device, its transfer not yet read
+        assert rb.index is mgrs[0]._index[rb.index.rows, rb.index.blocks,
+                                          mgrs[0]._turn]
+        assert last is None or not np.shares_memory(rb.index.buf, last)
+        last = rb.index.buf
         seen.add((rb.index.rows, rb.index.blocks))
         for u in live:
             seq = mgrs[0].get(u)
@@ -181,8 +186,9 @@ def test_packed_build_matches_the_unpacked_build(seed):
                 for m in mgrs:
                     m.flush(u)
     assert len(seen) >= 7, seen
-    # one buffer a bucket, however many steps
-    assert set(mgrs[0]._index) == seen
+    # two buffers a bucket at most, however many steps
+    assert {k[:2] for k in mgrs[0]._index} == seen
+    assert len(mgrs[0]._index) <= 2 * len(seen)
 
 
 def test_packed_index_is_a_pytree_of_one_leaf():
@@ -481,3 +487,96 @@ def test_audit_step_args_with_a_recurrent_state():
     donated = [i for i, a in enumerate(lowered.args_info[0])
                if any(leaf.donated for leaf in jax.tree.leaves(a))]
     assert donated == [1, 2, 4]
+
+
+# -- the sampled tokens fed back on the device (ISSUE 50) -------------------
+_FED = {"llama-tiny": {}, "falcon-h1-tiny": {},
+        "trinity-tiny": {"memory_config": {"num_blocks": 96, "block_size": 4,
+                                           "window_blocks": 48}}}
+
+
+@pytest.mark.parametrize("name", list(_FED))
+def test_the_fed_back_row_equals_the_row_fed_from_the_host(name):
+    """A step whose rows read the tokens of the step before where its
+    program left them (``launch`` twice, ``IN_FLIGHT`` between) against
+    the same step given them by the host (``step``, ``extend``): tokens,
+    both pools and whatever state the model carries, to the bit.  Prompts
+    mid-chunk beside decoding sequences, and a sequence that rides no
+    further step."""
+    from deepspeed_tpu.inference.v2.ragged import IN_FLIGHT
+
+    def fresh():
+        model, eng = _engine(name, **_FED[name])
+        rng = np.random.default_rng(5)
+        for uid, n in enumerate((3, 30, 41, 7)):
+            eng.admit(uid, rng.integers(1, model.vocab_size, n).tolist())
+        return eng
+
+    def held(eng):
+        return [np.asarray(a) for a in jax.tree.leaves(
+            (eng.cache_k, eng.cache_v, eng.state))]
+
+    host, dev = fresh(), fresh()
+    stop_at = {0: 4}                # uid 0 rides no step after its fourth
+    got_host, got_dev = [], []
+    done = set()
+    flight = dev.launch()
+    for step in range(12):
+        toks = host.step()
+        for uid, tok in toks.items():
+            if uid in done:
+                continue
+            if sum(uid in t for t in got_host) + 1 >= stop_at.get(uid, 99):
+                done.add(uid)
+            else:
+                host.extend(uid, tok)
+        got_host.append(toks)
+        # the same step on the other engine: its tokens stay on the device
+        for uid in flight.uids:
+            if uid not in done:
+                dev.extend(uid, IN_FLIGHT)
+        ahead = dev.launch()
+        got_dev.append(dev.fetch(flight))
+        flight = ahead
+        assert got_dev[-1] == toks, step
+        if flight is None:
+            break
+    assert flight is not None and len(got_host) == 12
+    dev.fetch(flight)
+    host.step()
+    for a, b in zip(held(host), held(dev)):
+        np.testing.assert_array_equal(a, b)
+    for uid in range(4):
+        assert host.state_manager.get(uid).tokens[:-1] == \
+            dev.state_manager.get(uid).tokens[:-1] or uid in done
+        assert IN_FLIGHT not in dev.state_manager.get(uid).tokens[:-1]
+    # one program a bucket on both, and the same ones
+    assert dev._dispatched == host._dispatched
+    assert dev._step_sampled._cache_size() == len(dev._dispatched)
+
+
+def test_the_sampled_step_lowers_with_the_fed_back_operand():
+    """The program the serve loop runs: the tokens of the step before are
+    ONE more parameter, ``[slots]`` int32, read before the embedding;
+    what is donated stays where it was (the pools, and a mixer's state by
+    name); the audited program (``ragged_step``: logits to the host)
+    takes no such operand."""
+    model, eng = _engine("falcon-h1-tiny")
+    slots = eng.state_manager.max_seqs + 1
+    assert eng._prev.shape == (slots,) and eng._prev.dtype == jnp.int32
+    assert eng._prev.sharding.mesh == eng.topology.mesh
+    fn, args = eng.audit_step_args("decode")
+    assert fn is eng._step and len(args) == 5
+    lowered = eng._step_sampled.lower(
+        *args[:4], prev=eng._prev, key=eng._step_key,
+        temperature=np.float32(1.0), greedy=True, top_k=0, top_p=None,
+        state=eng.state)
+    text = lowered.as_text()
+    assert "module @jit_ragged_step_sampled " in text
+    assert f"tensor<{slots}xi32>" in text
+    donated = [i for i, a in enumerate(lowered.args_info[0])
+               if any(leaf.donated for leaf in jax.tree.leaves(a))]
+    assert donated == [1, 2]
+    assert all(leaf.donated
+               for leaf in jax.tree.leaves(lowered.args_info[1]["state"]))
+    assert not lowered.args_info[1]["prev"].donated

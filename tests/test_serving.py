@@ -302,7 +302,7 @@ def test_loop_crash_fails_streams_and_sheds_new_load(monkeypatch):
     def boom(*a, **k):
         raise RuntimeError("injected engine failure")
 
-    monkeypatch.setattr(eng, "step", boom)
+    monkeypatch.setattr(eng, "launch", boom)   # the greedy loop's call
     s = srv.submit(p, SamplingParams(max_new_tokens=4))
     with pytest.raises(ServingError, match="serve loop died"):
         s.result(timeout=60)
@@ -330,7 +330,7 @@ def test_stop_drain_fails_fast_on_dead_loop(monkeypatch):
         # the state stop() must not wait out
         release.wait(30)
 
-    monkeypatch.setattr(eng, "step", boom)
+    monkeypatch.setattr(eng, "launch", boom)   # the greedy loop's call
     monkeypatch.setattr(eng, "flush", wedged_flush)
     srv.submit(p, SamplingParams(max_new_tokens=4))
     deadline = time.monotonic() + 10

@@ -77,7 +77,9 @@ def _lowered_serving_step(preset, kind):
                            block_size=bs, greedy=True)
     kw = {} if state is None else {"state": state}
     return jax.jit(fn).lower(
-        params, ck, cv, index, jax.ShapeDtypeStruct((2,), jnp.uint32),
+        params, ck, cv, index,
+        jax.ShapeDtypeStruct((slots,), jnp.int32),     # the step before's
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
         jax.ShapeDtypeStruct((), jnp.float32), **kw)
 
 

@@ -110,14 +110,20 @@ def test_new_spans_present_with_arguments_and_parents():
             parent = ragged[e["args"]["parent_id"]]      # KeyError = orphan
             assert parent["ts"] <= e["ts"]
             assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 1
-    # every ragged step that ran has one of each child, in order
+    # every ragged step span ends with its ONE fetch, after whole launches
+    # in order: the step's own (nothing ran ahead), the next step's (the
+    # loop runs one ahead), both (the first of a run ahead) or none (the
+    # last of one); a launch and a fetch a device step in all
+    launch = ["v2.schedule", "v2.h2d", "v2.dispatch"]
+    launches = 0
     for sid, parent in ragged.items():
-        kids = sorted((e for n in ("v2.schedule", "v2.h2d", "v2.dispatch",
-                                   "v2.fetch") for e in by_name[n]
-                       if e["args"]["parent_id"] == sid),
-                      key=lambda e: e["ts"])
-        assert [k["name"] for k in kids] == ["v2.schedule", "v2.h2d",
-                                             "v2.dispatch", "v2.fetch"]
+        kids = [k["name"] for k in sorted(
+            (e for n in launch + ["v2.fetch"] for e in by_name[n]
+             if e["args"]["parent_id"] == sid), key=lambda e: e["ts"])]
+        assert kids[-1] == "v2.fetch" and len(kids) % 3 == 1
+        assert kids[:-1] == launch * (len(kids) // 3) and len(kids) <= 7
+        launches += len(kids) // 3
+    assert launches == len(ragged) == len(by_name["serve.step"])
 
     sched = by_name["v2.schedule"]
     for e in sched:

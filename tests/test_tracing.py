@@ -444,11 +444,11 @@ def test_serve_loop_hang_fires_watchdog_with_forensics(tmp_path):
 
     model, eng = _tiny_engine()
     release = threading.Event()
-    orig_step = eng.step
+    orig_launch = eng.launch    # the first half of the greedy loop's step
 
     def hang(*a, **kw):
         release.wait(30)
-        return orig_step(*a, **kw)
+        return orig_launch(*a, **kw)
 
     flight_dir = str(tmp_path / "flight")
     srv = InferenceServer(eng, {
@@ -464,7 +464,7 @@ def test_serve_loop_hang_fires_watchdog_with_forensics(tmp_path):
         srv.submit(prompt, SamplingParams(max_new_tokens=1)).result(
             timeout=120)
         assert srv._watchdog.fire_count == 0
-        eng.step = hang
+        eng.launch = hang
         t0 = time.monotonic()
         stream = srv.submit(prompt, SamplingParams(max_new_tokens=2))
         while srv._watchdog.fire_count == 0 \
@@ -498,16 +498,16 @@ def test_first_step_kv_exhaustion_keeps_compile_skip(tmp_path):
                                        ServingError)
 
     model, eng = _tiny_engine()
-    orig_step = eng.step
+    orig_launch = eng.launch    # the first half of the greedy loop's step
     paused_at_call = []
 
     def exhaust_first(*a, **kw):
         paused_at_call.append(srv._watchdog._paused)
         if len(paused_at_call) == 1:
             raise KVCacheExhausted("synthetic: no pages")
-        return orig_step(*a, **kw)
+        return orig_launch(*a, **kw)
 
-    eng.step = exhaust_first
+    eng.launch = exhaust_first
     srv = InferenceServer(eng, {
         "flight": {"enabled": True, "deadline_s": 300.0,
                    "output_dir": str(tmp_path / "flight")}}).start()
@@ -522,7 +522,7 @@ def test_first_step_kv_exhaustion_keeps_compile_skip(tmp_path):
         srv.submit(prompt, SamplingParams(max_new_tokens=2)).result(
             timeout=120)
     finally:
-        eng.step = orig_step
+        eng.launch = orig_launch
         srv.stop()
     assert len(paused_at_call) >= 3
     assert paused_at_call[0]      # warm skip armed for the exhausted try
@@ -548,7 +548,7 @@ def test_serve_loop_crash_writes_flight_bundle(tmp_path, monkeypatch):
     def boom(*a, **kw):
         raise RuntimeError("injected engine failure")
 
-    monkeypatch.setattr(eng, "step", boom)
+    monkeypatch.setattr(eng, "launch", boom)
     s = srv.submit(prompt, SamplingParams(max_new_tokens=4))
     with pytest.raises(ServingError):
         s.result(timeout=60)
