@@ -203,7 +203,10 @@ def window_step_counts(items: Sequence[tuple], cfg: TransformerConfig,
     each sequence's context after the step counted once, cut to the
     window for a window layer; ``full_pages`` / ``window_pages``: pages
     live sequences hold after the step (``held``); ``pages_freed``: window
-    pages this step returned; ``expert_rows``: (row, held expert) products
+    pages this step returned; ``full_qk_pairs`` / ``window_qk_pairs``:
+    live (query, key) pairs of the step's new rows, causal, cut to the
+    window for a window layer (:func:`step_counts`' ``qk_pairs``);
+    ``expert_rows``: (row, held expert) products
     an expert layer expects under even routing, rows x experts per token x
     held / routed."""
     mx = cfg.mixed
@@ -212,8 +215,42 @@ def window_step_counts(items: Sequence[tuple], cfg: TransformerConfig,
             "window_kv_rows": sum(min(e, mx.sliding_window) for e in ends),
             "full_pages": held[0], "window_pages": held[1],
             "pages_freed": freed,
+            "full_qk_pairs": step_counts(items)["qk_pairs"],
+            "window_qk_pairs": step_counts(
+                items, mx.sliding_window)["qk_pairs"],
             "expert_rows": sum(n for _, n in items) * mx.num_experts_per_tok
             * mx.experts_held[1] / mx.n_routed_experts}
+
+
+def mixed_alloc_counts(cfg: TransformerConfig, engine_cfg, cache_k, cache_v,
+                       pools) -> Dict[str, int]:
+    """Further arguments of a mixed-attention model's ``v2.state_alloc``:
+    what the two pools keep a kind of layer.  ``full_page_bytes`` /
+    ``window_page_bytes``: ONE layer's page of the kind, K and V, as LAID
+    OUT (a key row in ``row_width`` lanes); both kinds' KV heads, the
+    key's and the value's published widths, the layers whose softmax has
+    a sink, and ``kernel_calls_per_step``: the Pallas calls ONE step
+    program makes, from the layer kinds and the kernels resolved (the
+    append and the read a layer, each kind's append by its own shapes)."""
+    bs = engine_cfg.block_size
+
+    def page_bytes(k, v):       # 0: no layer of the kind
+        return (int(k.nbytes) + int(v.nbytes)) // max(
+            1, k.shape[0] * k.shape[2] // bs)
+
+    calls = 0
+    if attention_impl_name(cfg, bs) == "paged_pallas":
+        for layers, k, v in ((cfg.attn_layers, cache_k, cache_v),
+                             (cfg.window_layers, pools["k"], pools["v"])):
+            appends = kv_append_fit(16, k.shape[1], k.shape[3], bs, k.dtype,
+                                    v.shape[3]) is not None
+            calls += layers * (1 + int(appends))
+    return {"full_page_bytes": page_bytes(cache_k, cache_v),
+            "window_page_bytes": page_bytes(pools["k"], pools["v"]),
+            "full_kv_heads": cfg.kv_heads,
+            "window_kv_heads": cfg.window_kv_heads,
+            "key_width": cfg.dim_per_head, "value_width": cfg.value_width,
+            "sink_layers": cfg.sink_layers, "kernel_calls_per_step": calls}
 
 
 def ssm_step_counts(items: Sequence[tuple], slot_bytes: int,
@@ -487,9 +524,12 @@ class InferenceEngineV2:
             self.state_kind = _WINDOW_PAGES
             self._state_alloc = {
                 "ts": t0 * 1e6, "dur": (time.monotonic() - t0) * 1e6,
-                "full_pool_bytes": 2 * int(self.cache_k.nbytes),
+                "full_pool_bytes": int(self.cache_k.nbytes)
+                + int(self.cache_v.nbytes),
                 "window_pool_bytes": self.state_bytes,
-                "window_layers": mc.window_layers}
+                "window_layers": mc.window_layers,
+                **mixed_alloc_counts(mc, self.cfg, self.cache_k,
+                                     self.cache_v, pools)}
             donate["donate_argnames"] = ("state",)
         if latent is not None and rings is not None:
             self.state = jax.block_until_ready(rings)

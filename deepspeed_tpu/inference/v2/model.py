@@ -42,7 +42,8 @@ from deepspeed_tpu.inference.v2.modules import (register_module, resolve,
 from deepspeed_tpu.models.transformer import (TransformerConfig, _mlp_block,
                                               _norm)
 from deepspeed_tpu.ops.pallas.kv_append import kv_append, step_pages
-from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
+from deepspeed_tpu.ops.pallas.paged_attention import (
+    paged_decode_attention, row_width, supports)
 from deepspeed_tpu.ops.pallas.ssd_ragged import run_layout, ssd_ragged
 from deepspeed_tpu.utils.platform import on_tpu
 
@@ -99,12 +100,15 @@ def _kv_append(pool, x, token_dest, layer=None):
 
 
 def _paged_attention_xla(q, k_pages, v_pages, gather_idx, token_pos,
-                         token_ctx_len, cfg: TransformerConfig):
+                         token_ctx_len, cfg: TransformerConfig, sink=None):
     """Gather-based fallback (non-TPU backends / oversize shapes).
 
-    q: [T, nh, d]; k_pages/v_pages: [nkv, P, d] (or int8 dict caches);
-    gather_idx: [T, C] flat page-row indices of each token's context.
-    GQA-native: queries are grouped by KV head instead of repeating KV.
+    q: [T, nh, d]; k_pages: [nkv, P, d] and v_pages: [nkv, P, dv] (or int8
+    dict caches); gather_idx: [T, C] flat page-row indices of each token's
+    context.  GQA-native: queries are grouped by KV head instead of
+    repeating KV.  ``sink`` [nh]: one more logit a head in every row's
+    softmax, which takes probability and adds no value.  Returns
+    [T, nh, dv].
     """
     t, nh, d = q.shape
     if _is_quant_cache(k_pages):
@@ -138,27 +142,33 @@ def _paged_attention_xla(q, k_pages, v_pages, gather_idx, token_pos,
                          < cfg.sliding_window)
     scores = jnp.where(valid[:, None, None, :], scores.astype(jnp.float32),
                        -1e30)
+    if sink is not None:
+        # the sink as one more column, its probability dropped
+        col = jnp.broadcast_to(sink.astype(jnp.float32).reshape(nkv, g, 1),
+                               (t, nkv, g, 1))
+        scores = jnp.concatenate([scores, col], axis=-1)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    if sink is not None:
+        probs = probs[..., :-1]
     out = jnp.einsum("tkgc,ktcd->tkgd", probs, v_ctx)
-    return out.reshape(t, nh, d)
+    return out.reshape(t, nh, v_ctx.shape[-1])
 
 
 def _pallas_attn_default(block_size=0, head_dim=0, on_tpu=False,
-                         has_tables=False, use_alibi=False, **_):
+                         has_tables=False, use_alibi=False, value_dim=None,
+                         **_):
     if not (has_tables and on_tpu) or use_alibi:
         # alibi rides the XLA gather path (the Pallas kernel has no
         # score-bias lane)
         return False
-    from deepspeed_tpu.ops.pallas.paged_attention import supports
-
-    return supports(block_size, head_dim)
+    return supports(block_size, head_dim, value_dim)
 
 
 @register_module("attention", "paged_pallas",
                  default_for=_pallas_attn_default)
 def _attn_impl_pallas(q, k_pages, v_pages, gather_idx, token_pos,
                       token_ctx_len, cfg, block_tables, token_slot,
-                      block_size, layer=None):
+                      block_size, layer=None, sink=None):
     """Pallas block-table kernel (ops/pallas/paged_attention.py: runs of
     a sequence's rows share one page walk with online softmax — no
     [T, C, ...] gather materialisation, no per-token page table).
@@ -174,7 +184,7 @@ def _attn_impl_pallas(q, k_pages, v_pages, gather_idx, token_pos,
     scale = (cfg.attn_scale if cfg.attn_scale is not None
              else 1.0 / math.sqrt(cfg.dim_per_head))
     kw = dict(window=cfg.sliding_window or None, token_slot=token_slot,
-              layer=layer)
+              layer=layer, sink=sink)
     if _is_quant_cache(k_pages):
         return paged_decode_attention(
             q, k_pages["q"], v_pages["q"], block_tables, token_pos,
@@ -188,9 +198,9 @@ def _attn_impl_pallas(q, k_pages, v_pages, gather_idx, token_pos,
 @register_module("attention", "paged_xla")
 def _attn_impl_xla(q, k_pages, v_pages, gather_idx, token_pos,
                    token_ctx_len, cfg, block_tables, token_slot,
-                   block_size):
+                   block_size, sink=None):
     return _paged_attention_xla(q, k_pages, v_pages, gather_idx, token_pos,
-                                token_ctx_len, cfg)
+                                token_ctx_len, cfg, sink)
 
 
 def attention_impl_name(cfg: TransformerConfig, block_size: int,
@@ -198,24 +208,31 @@ def attention_impl_name(cfg: TransformerConfig, block_size: int,
     """The attention implementation the module registry (modules.py — ref
     inference/v2/modules/heuristics.py) picks for this geometry: 'auto'
     takes the Pallas block-table kernel on TPU when ``supports()`` says
-    the chip's compiler takes the shape, the XLA gather path otherwise;
-    ``cfg.v2_modules`` pins a name explicitly."""
+    the chip's compiler takes the shape (K rows of the head's width, V
+    rows of ``cfg.value_width``, a sink or none), the XLA gather path
+    otherwise (ALiBi, a head narrower than a lane tile, a value width
+    that is no whole tile); ``cfg.v2_modules`` pins a name explicitly."""
     name = dict(cfg.v2_modules or ()).get("attention", "auto")
     return resolve_name("attention", name, block_size=block_size,
                         head_dim=cfg.dim_per_head, on_tpu=on_tpu(),
-                        has_tables=has_tables, use_alibi=cfg.use_alibi)
+                        has_tables=has_tables, use_alibi=cfg.use_alibi,
+                        value_dim=cfg.value_width)
 
 
 def _paged_attention(q, k_pages, v_pages, gather_idx, token_pos, token_ctx_len,
                      cfg: TransformerConfig, block_tables=None, token_slot=None,
-                     block_size: int = 0, layer=None):
+                     block_size: int = 0, layer=None, sink=None):
     """Attention of T query tokens against their sequences' KV pages
-    [nkv, P, d], through the implementation :func:`attention_impl_name`
-    resolves; with ``layer``, against that layer's pages of every
-    layer's pool [L, nkv, P, d] (``paged_pallas`` alone reads it so)."""
+    (K [nkv, P, d], V [nkv, P, dv]), through the implementation
+    :func:`attention_impl_name` resolves; with ``layer``, against that
+    layer's pages of every layer's pool [L, nkv, P, .] (``paged_pallas``
+    alone reads it so); with ``sink`` [nh], a learned logit a head in the
+    softmax's denominator."""
     impl = resolve("attention", attention_impl_name(
         cfg, block_size, has_tables=block_tables is not None))
     kw = {} if layer is None else {"layer": layer}
+    if sink is not None:
+        kw["sink"] = sink
     return impl(q, k_pages, v_pages, gather_idx, token_pos, token_ctx_len,
                 cfg, block_tables, token_slot, block_size, **kw)
 
@@ -383,7 +400,11 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
                   cfg: TransformerConfig, layer_is_moe=False, state=None,
                   ssm_meta=None):
     """Block ``layer`` over flat tokens [T, H]; appends its KV to the
-    pools [L, nkv, P, d] in place and attends via its pages of them.
+    pools [L, nkv, P, d] in place and attends via its pages of them
+    (``cfg`` says the layer's KV heads, window and rotary: of a
+    mixed-attention model, its KIND's; V rows are ``cfg.value_width``
+    wide, and K rows and queries are padded with zeros to the width the K
+    pool keeps, ``row_width``).
     Returns (x, cache_k, cache_v, state): ``state`` is the layer's
     recurrent slots where the block has an SSM mixer, else None."""
     (token_pos, token_dest, gather_idx, token_ctx_len, token_slot,
@@ -394,6 +415,7 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
     mx = cfg.mixed
     t = x.shape[0]
     nh, nkv, d = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
+    dv = cfg.value_width
     dt = x.dtype
 
     def proj(w, b_):
@@ -414,7 +436,7 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
         h_attn = h * mixer.attention_in_multiplier if mixer else h
         q = proj(lp["attn"]["wq"], lp["attn"].get("bq")).reshape(t, nh, d)
         k = proj(lp["attn"]["wk"], lp["attn"].get("bk")).reshape(t, nkv, d)
-        v = proj(lp["attn"]["wv"], lp["attn"].get("bv")).reshape(t, nkv, d)
+        v = proj(lp["attn"]["wv"], lp["attn"].get("bv")).reshape(t, nkv, dv)
         if mixer:
             # the multiplier as the model's dtype holds it (it scaled a dt k)
             k = k * jnp.asarray(mixer.key_multiplier, dt).astype(k.dtype)
@@ -427,7 +449,14 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
         if cfg.use_rope:
             q = _rope_tok(q, token_pos, cfg)
             k = _rope_tok(k, token_pos, cfg)
+        if mx is not None and mx.value_scale != 1.0:
+            v = v * mx.value_scale
         q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
+        pad = 0 if _is_quant_cache(cache_k) else cache_k.shape[-1] - d
+        if pad:
+            # the K pool keeps whole lane tiles a row: zeros add nothing
+            # to a score
+            q, k = (jnp.pad(a, ((0, 0), (0, 0), (0, pad))) for a in (q, k))
 
     # Write this step's KV to its pages (padding tokens target page 0 =
     # garbage, so no mask needed; ref: linear_blocked_kv_copy). A layer's
@@ -436,7 +465,8 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
     attend = functools.partial(
         _paged_attention, q, gather_idx=gather_idx, token_pos=token_pos,
         token_ctx_len=token_ctx_len, cfg=cfg, block_tables=block_tables,
-        token_slot=token_slot, block_size=block_size)
+        token_slot=token_slot, block_size=block_size,
+        sink=lp["attn"].get("sink"))
     if attention_impl_name(cfg, block_size,
                            block_tables is not None) == "paged_pallas":
         # the kernels read the layer's pages out of the whole pool; the
@@ -469,7 +499,7 @@ def _ragged_layer(x, lp, cache_k, cache_v, layer, meta,
                 lambda c, pages: c.at[layer].set(pages),
                 (cache_k, cache_v), (k_pages, v_pages))
     with jax.named_scope("attn.out"):
-        attn = attn.reshape(t, nh * d)
+        attn = attn.reshape(t, nh * dv)
         if mx is not None and mx.gate:
             attn = attn * jax.nn.sigmoid(gate).astype(dt)
         attn = attn @ lp["attn"]["wo"].astype(dt)
@@ -558,7 +588,8 @@ def _mixed_feed_forward(x, h2, lp, cfg: TransformerConfig):
     rows ``h2``, with its norm after and the residual add: dense
     (``lp["mlp"]``), or this program's routed experts and the shared one
     (``lp["held"]``: the expert layers' stack and this layer's index in
-    it, as ``moe_forward_held`` takes them)."""
+    it, as ``moe_forward_held`` takes them; a model without a shared
+    expert has no ``shared`` in the stack)."""
     from deepspeed_tpu.moe.sharded_moe import moe_forward_held
 
     mx = cfg.mixed
@@ -571,10 +602,11 @@ def _mixed_feed_forward(x, h2, lp, cfg: TransformerConfig):
     routed = moe_forward_held(
         h2, stack, i, top_k=mx.num_experts_per_tok,
         first=mx.experts_held[0], scale=mx.route_scale)
-    with jax.named_scope("moe.shared"):
-        y = routed + _mlp_block(h2, _at(stack["shared"], i), cfg)
+    if "shared" in stack:
+        with jax.named_scope("moe.shared"):
+            routed = routed + _mlp_block(h2, _at(stack["shared"], i), cfg)
     with jax.named_scope("moe.combine"):
-        return x + post(y)
+        return x + post(routed)
 
 
 def layer_segments(kinds) -> list:
@@ -598,16 +630,21 @@ def layer_segments(kinds) -> list:
 
 def new_window_pools(cfg: TransformerConfig, full_rows: int,
                      window_rows: int, zeros=jnp.zeros, dtype=None):
-    """``(cache_k, cache_v, state)`` of a mixed-attention model, zeroed:
-    the full layers' pools ``[full layers, nkv, full_rows, d]`` and, in
-    ``state``, the window layers' ``k`` and ``v`` ``[window layers, nkv,
-    window_rows, d]``."""
+    """``(cache_k, cache_v, state)`` of a mixed-attention model, zeroed,
+    each pool in its KIND's shape: the full layers' ``cache_k`` ``[full
+    layers, kv_heads, full_rows, key row]`` and ``cache_v`` ``[.., value
+    width]`` and, in ``state``, the window layers' ``k`` ``[window layers,
+    window_kv_heads, window_rows, key row]`` and ``v`` ``[.., value
+    width]``.  A key row is ``row_width(head_dim)`` lanes (192 dims in
+    256, the pad zeros), a value row ``cfg.value_width``."""
     dtype = dtype or cfg.dtype
     n_win = cfg.window_layers
-    full = (cfg.num_layers - n_win, cfg.kv_heads, full_rows, cfg.dim_per_head)
-    win = (n_win, cfg.kv_heads, window_rows, cfg.dim_per_head)
-    return (zeros(full, dtype=dtype), zeros(full, dtype=dtype),
-            {"k": zeros(win, dtype=dtype), "v": zeros(win, dtype=dtype)})
+    dk, dv = row_width(cfg.dim_per_head), cfg.value_width
+    full = (cfg.num_layers - n_win, cfg.kv_heads, full_rows)
+    win = (n_win, cfg.window_kv_heads, window_rows)
+    return (zeros(full + (dk,), dtype=dtype), zeros(full + (dv,), dtype=dtype),
+            {"k": zeros(win + (dk,), dtype=dtype),
+             "v": zeros(win + (dv,), dtype=dtype)})
 
 
 def _mixed_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
@@ -621,14 +658,22 @@ def _mixed_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
     feed-forwards first and held experts after.  The layer list is walked
     in :func:`layer_segments`: one ``lax.scan`` over the repeats where a
     stretch repeats, its body a stretch unrolled so that every layer's
-    window and rotary are static; all four pools ride the carry whole."""
+    window and rotary are static; all four pools ride the carry whole.
+    A kind of layer reads its own configuration
+    (``TransformerConfig.mixed_kind``: KV heads, rotary base, window) and,
+    where the kinds' attention weights differ, its own stack
+    (``layers/attn_full`` / ``attn_window``, indexed as the kind's pool
+    is)."""
     mx = cfg.mixed
     if state is None or window is None:
         raise ValueError(
             "this model's window layers keep their rows in a page pool of "
             "their own with tables of their own (state=new_window_pools("
-            "...)[2], a PackedIndex with window arrays); a caller that "
-            "keeps one pool (inference.kv_generate) cannot run it")
+            "...)[2]: K [window layers, window_kv_heads, rows, key row] "
+            "and V [.., value width], beside the full layers' [full "
+            "layers, kv_heads, rows, .]; a PackedIndex with window "
+            "arrays); a caller that keeps one pool (inference.kv_generate) "
+            "cannot run it")
     if _is_quant_cache(cache_k):
         raise ValueError("a mixed-attention model's pools are kept in the "
                          "compute dtype (no int8 cache)")
@@ -638,14 +683,13 @@ def _mixed_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
         x = params["embed"]["tokens"].astype(cfg.dtype)[token_ids]
         if mx.embed_multiplier != 1.0:
             x = x * mx.embed_multiplier
+    kind_cfg = {is_full: cfg.mixed_kind(is_full) for is_full in (True, False)}
     meta = {True: _step_meta(token_slot, token_pos, token_dest, block_tables,
-                             ctx_lens, block_size, cache_k, cfg),
+                             ctx_lens, block_size, cache_k, kind_cfg[True],
+                             cache_v),
             False: _step_meta(token_slot, token_pos, window_dest,
                               window_tables, ctx_lens, block_size,
-                              state["k"], cfg)}
-    kind_cfg = {True: cfg.replace(sliding_window=None, use_rope=mx.rope_full),
-                False: cfg.replace(sliding_window=mx.sliding_window,
-                                   use_rope=True)}
+                              state["k"], kind_cfg[False], state["v"])}
     kinds = mx.kinds(cfg.num_layers)
     fulls = [is_full for is_full, _ in kinds]
 
@@ -662,6 +706,10 @@ def _mixed_trunk(params, cache_k, cache_v, token_ids, token_slot, token_pos,
             lp = {name: _at(layers[name], layer)
                   for name in ("attn", "ln1", "ln2", "post_attn", "post_mlp")
                   if name in layers}
+            if mx.attn_by_kind:
+                # stacked a kind, as the pools are
+                lp["attn"] = _at(layers["attn_full" if is_full
+                                        else "attn_window"], in_pool)
             if has_experts:
                 lp["held"] = (layers["moe"], layer - mx.num_dense_layers)
             else:
@@ -850,7 +898,8 @@ def _embed_rows(params, token_ids, token_pos, cfg: TransformerConfig):
 
 
 def _step_meta(token_slot, token_pos, token_dest, block_tables, ctx_lens,
-               block_size: int, cache_k, cfg: TransformerConfig):
+               block_size: int, cache_k, cfg: TransformerConfig,
+               cache_v=None):
     """What every block needs of the step's layout, once: the context
     gather indices (ref: atom_builder) and, on the kernels' path, the
     rows' destinations by page for the append (None where the pools keep
@@ -860,7 +909,7 @@ def _step_meta(token_slot, token_pos, token_dest, block_tables, ctx_lens,
     if not _is_quant_cache(cache_k) and attention_impl_name(
             cfg, block_size, block_tables is not None) == "paged_pallas":
         with jax.named_scope("attn.append"):
-            dest_pages = step_pages(cache_k, token_dest, block_size)
+            dest_pages = step_pages(cache_k, token_dest, block_size, cache_v)
     with jax.named_scope("attn.read"):
         nb = block_tables.shape[1]
         c = jnp.arange(nb * block_size, dtype=jnp.int32)

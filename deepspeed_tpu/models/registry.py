@@ -340,6 +340,60 @@ register("trinity-tiny", TransformerConfig(
         embed_multiplier=8.0),
     **_trinity))
 
+# -- MiMo-V2-Flash (HF mimo_v2_flash: window-128 layers with a learned
+# sink a head in the softmax beside full layers, 8 and 4 KV heads, keys 192
+# wide of which 64 carry rotary (base 1e4 and 5e6) and values 128 times
+# 0.707; one dense layer, then 256 sigmoid-routed experts top 8, no shared
+# one); XiaomiMiMo/MiMo-V2-Flash config.json.  Trinity's q/k norms, gate,
+# sandwich norms and muP embedding are all off
+_mimo = dict(arch="mimo_v2_flash", norm="rmsnorm", activation="swiglu",
+             use_rope=True, rotary_pct=0.334, tie_embeddings=False,
+             use_bias=False)
+_W, _F = "sliding_attention", "full_attention"
+# hybrid_layer_pattern: full at 0, 5, 11, 17, ..., 47
+_mimo_types = (_F,) + (_W,) * 4 + ((_F,) + (_W,) * 5) * 7 + (_F,)
+
+
+def _mimo_mixed(experts_held, layer_types=_mimo_types):
+    return MixedAttentionConfig(
+        layer_types=layer_types, sliding_window=128,
+        n_routed_experts=256, experts_held=experts_held,
+        num_experts_per_tok=8, moe_intermediate_size=2048,
+        num_dense_layers=1, n_shared_experts=0, rope_full=True,
+        qk_norm=False, gate=False, sandwich_norm=False,
+        window_kv_heads=8, window_rope_theta=1e4, window_sink=True,
+        v_head_dim=128, value_scale=0.707)
+
+
+# whole: for ``describe`` and shape tests only (no chip holds a layer)
+register("mimo-v2-flash", TransformerConfig(
+    vocab_size=152576, hidden_size=4096, intermediate_size=16384,
+    num_layers=48, num_heads=64, num_kv_heads=4, head_dim=192,
+    max_seq_len=262144, rope_theta=5e6, layernorm_eps=1e-5,
+    mixed=_mimo_mixed((0, 256)), **_mimo))
+
+# one chip's share of a layer divided over sixteen: experts 0-15 of the
+# 256 (routing over all of them) and an eighth of the vocabulary;
+# attention and the dense feed-forward whole.  Depth as cut: layer 0
+# (full, dense) and one whole period, layers 6-11 (window x 5, full)
+register("mimo-v2-flash-ep16", TransformerConfig(
+    vocab_size=19072, hidden_size=4096, intermediate_size=16384,
+    num_layers=7, num_heads=64, num_kv_heads=4, head_dim=192,
+    max_seq_len=262144, rope_theta=5e6, layernorm_eps=1e-5,
+    mixed=_mimo_mixed((0, 16), (_F,) + (_W,) * 5 + (_F,)), **_mimo))
+
+# a dense full layer, two window layers and a full one; a window of 24
+# on pages of 8 or 16 rows, so that tiny prompts pass it
+register("mimo-tiny", TransformerConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=4,
+    num_heads=4, num_kv_heads=2, head_dim=24, max_seq_len=256,
+    rope_theta=5e6, layernorm_eps=1e-5,
+    mixed=dataclasses.replace(
+        _mimo_mixed((4, 4), (_F, _W, _W, _F)), sliding_window=24,
+        n_routed_experts=16, num_experts_per_tok=2,
+        moe_intermediate_size=32, window_kv_heads=4, v_head_dim=16),
+    **_mimo))
+
 # -- Nemotron-H (HF nemotron_h: ONE mixer a layer by a pattern, M a
 # Mamba-2 scan of 64 heads of 64 in 8 groups at state 128, * grouped-query
 # attention 32:2 at head 128 WITHOUT rotary, E 128 sigmoid-routed experts

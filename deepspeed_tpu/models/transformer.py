@@ -166,8 +166,9 @@ class LatentConfig:
 @dataclass(frozen=True)
 class MixedAttentionConfig:
     """Plain (grouped-query) attention of two kinds chosen per layer by
-    ``layer_types`` (HF ``afmoe``): ``"sliding_attention"`` layers see the
-    token and the ``sliding_window - 1`` before it and carry rotary,
+    ``layer_types`` (HF ``afmoe``, ``mimo_v2_flash``):
+    ``"sliding_attention"`` layers see the token and the
+    ``sliding_window - 1`` before it and carry rotary,
     ``"full_attention"`` layers are causal and carry rotary only with
     ``rope_full``.  ``qk_norm``: an RMSNorm over the dims of a head on
     queries and on keys, one gain vector each for all heads, before
@@ -176,12 +177,30 @@ class MixedAttentionConfig:
     projection; ``sandwich_norm``: an RMSNorm AFTER attention and AFTER
     the feed-forward, each before its residual add;
     ``embed_multiplier``: the embedding rows' muP scale.
+
+    **What a kind of layer may have of its own** (each None or False
+    leaves both kinds what the model's own fields say, which is HF
+    ``afmoe`` and the program it always got): ``window_kv_heads`` (the
+    window layers' KV heads; the full layers keep ``num_kv_heads``),
+    ``window_rope_theta`` (the window layers' rotary base; the full
+    layers keep ``rope_theta``), ``window_sink`` / ``full_sink`` (one
+    learned logit a query head, ``attn/sink`` ``[heads]``, that joins the
+    softmax's denominator and adds no value).  For both kinds alike:
+    ``v_head_dim`` (the width of a value row and of a head's output where
+    it is not the key's ``head_dim``; ``wo`` is then ``[heads x
+    v_head_dim, hidden]``), ``value_scale`` (values times it before the
+    product); the rotary share is the model's ``rotary_pct``.  Where the
+    kinds' attention weights differ in shape or in what they hold
+    (:attr:`attn_by_kind`) they are stacked a kind, ``layers/attn_full``
+    and ``layers/attn_window``, else over every layer, ``layers/attn``.
+
     ``num_dense_layers`` leading blocks have a dense feed-forward, the
     others sigmoid-routed experts of which this program holds
     ``experts_held`` (first, count): it routes over all
     ``n_routed_experts`` and computes its own experts' part (weights
     normalised over the chosen, times ``route_scale``) and the shared
-    expert whole.  ``TransformerConfig.mixed`` is ``None`` for any other
+    expert whole (none with ``n_shared_experts`` 0).
+    ``TransformerConfig.mixed`` is ``None`` for any other
     model.  Served by inference/v2 only: the window layers' rows live in
     a page pool of their own that frees pages behind the window."""
     layer_types: Tuple[str, ...]
@@ -198,6 +217,12 @@ class MixedAttentionConfig:
     gate: bool = True
     sandwich_norm: bool = True
     embed_multiplier: float = 1.0
+    window_kv_heads: Optional[int] = None
+    window_rope_theta: Optional[float] = None
+    window_sink: bool = False
+    full_sink: bool = False
+    v_head_dim: Optional[int] = None
+    value_scale: float = 1.0
 
     def kinds(self, num_layers: int) -> Tuple[Tuple[bool, bool], ...]:
         """(is a full layer, has experts) for each of the first
@@ -207,6 +232,15 @@ class MixedAttentionConfig:
 
     def window_layers(self, num_layers: int) -> int:
         return sum(1 for full, _ in self.kinds(num_layers) if not full)
+
+    @property
+    def attn_by_kind(self) -> bool:
+        """Whether the kinds' attention weights are stacked apart."""
+        return (self.window_kv_heads is not None
+                or self.window_sink != self.full_sink)
+
+    def sink(self, is_full: bool) -> bool:
+        return self.full_sink if is_full else self.window_sink
 
 
 @dataclass(frozen=True)
@@ -513,6 +547,38 @@ class TransformerConfig:
     def embed_multiplier(self) -> float:
         return self.mixed.embed_multiplier if self.mixed else 1.0
 
+    @property
+    def window_kv_heads(self) -> int:
+        """KV heads of a mixed-attention model's window layers."""
+        if not self.mixed:
+            return 0
+        return self.mixed.window_kv_heads or self.kv_heads
+
+    @property
+    def value_width(self) -> int:
+        """Dims of a value row and of a head's output."""
+        return (self.mixed and self.mixed.v_head_dim) or self.dim_per_head
+
+    @property
+    def sink_layers(self) -> int:
+        """Layers whose softmax carries a learned sink a head."""
+        if not self.mixed:
+            return 0
+        return sum(self.mixed.sink(full)
+                   for full, _ in self.mixed.kinds(self.num_layers))
+
+    def mixed_kind(self, is_full: bool) -> "TransformerConfig":
+        """A mixed-attention model's configuration as ONE kind of layer
+        reads it: the kind's window, rotary (and its base) and KV heads
+        in the model's own fields."""
+        mx = self.mixed
+        if is_full:
+            return self.replace(sliding_window=None, use_rope=mx.rope_full)
+        return self.replace(
+            sliding_window=mx.sliding_window, use_rope=True,
+            num_kv_heads=self.window_kv_heads,
+            rope_theta=mx.window_rope_theta or self.rope_theta)
+
     # a one-mixer-a-layer model's sizes by flat names ("" / 0 without one)
     @property
     def layer_kinds(self) -> str:
@@ -753,8 +819,10 @@ def refuse_ssm(cfg: TransformerConfig, what: str) -> None:
     if cfg.mixed is not None:
         raise NotImplementedError(
             f"{what}: this configuration mixes window-"
-            f"{cfg.mixed.sliding_window} and full attention by layer, with "
-            "q/k norms, an attention gate, sandwich norms and held "
+            f"{cfg.mixed.sliding_window} and full attention by layer "
+            "(each kind with what MixedAttentionConfig gives it: q/k "
+            "norms, a gate, sandwich norms, KV heads or a rotary base of "
+            "its own, a sink in the softmax, a value width) over held "
             "sigmoid-routed experts; models/transformer.py has none of "
             "them and would run a plain block under its name. Serve it "
             "through inference.v2.InferenceEngineV2")
@@ -985,7 +1053,12 @@ def init_mixed_params(cfg: TransformerConfig, key) -> Params:
     the four norms of a block are stacked over EVERY layer
     (``layers/attn``: ``wq``, ``wk``, ``wv``, ``wg`` (the gate), ``wo``,
     and the heads' ``q_norm`` / ``k_norm`` gains ``[L, head_dim]``;
-    ``layers/ln1``, ``post_attn``, ``ln2``, ``post_mlp``), the leading
+    ``layers/ln1``, ``post_attn``, ``ln2``, ``post_mlp``); where the kinds
+    of layer differ in their attention weights
+    (``MixedAttentionConfig.attn_by_kind``: KV heads of their own, a
+    ``sink`` ``[heads]`` in one kind alone) attention is stacked a kind,
+    in layer order, ``layers/attn_full`` and ``layers/attn_window``, as
+    the two page pools are.  The leading
     dense feed-forwards under ``layers/mlp`` and the expert layers under
     ``layers/moe`` (router over ALL experts, the selection ``bias``, the
     held experts' weights ``[n, held, ...]``, the ``shared`` expert), as a
@@ -1057,7 +1130,7 @@ def init_mixed_params(cfg: TransformerConfig, key) -> Params:
     if len(kinds) != nl:
         raise ValueError(f"layer_types names {len(kinds)} layers, the "
                          f"model has {nl}")
-    nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
+    nh, hd, vd = cfg.num_heads, cfg.dim_per_head, cfg.value_width
     c = max(MIXED_EMBED_COMMON, MIXED_COMMON_NORM / math.sqrt(h))
 
     def dense(k, shape, fan_in, centred=False):
@@ -1072,17 +1145,23 @@ def init_mixed_params(cfg: TransformerConfig, key) -> Params:
                 "wi": dense(k[1], lead + (h, width), h, True),
                 "wo": dense(k[2], lead + (width, h), width)}
 
-    def attn(k):
-        k = jax.random.split(k, 5)
+    def attn(key, is_full=True):
+        k = jax.random.split(key, 5)
+        nkv = cfg.kv_heads if is_full else cfg.window_kv_heads
         p = {"wq": dense(k[0], (h, nh * hd), h, True),
              "wk": dense(k[1], (h, nkv * hd), h, True),
-             "wv": dense(k[2], (h, nkv * hd), h, True),
-             "wo": dense(k[3], (nh * hd, h), nh * hd)}
+             "wv": dense(k[2], (h, nkv * vd), h, True),
+             "wo": dense(k[3], (nh * vd, h), nh * vd)}
         if m.gate:
-            p["wg"] = dense(k[4], (h, nh * hd), h, True)
+            p["wg"] = dense(k[4], (h, nh * vd), h, True)
         if m.qk_norm:
             p["q_norm"] = jnp.ones((hd,), pd)
             p["k_norm"] = jnp.ones((hd,), pd)
+        if m.sink(is_full):
+            # normal(0, 1): zeros would hide a sign error (a trained
+            # value is not public)
+            p["sink"] = jax.random.normal(jax.random.fold_in(key, 5),
+                                          (nh,)).astype(pd)
         return p
 
     def router(k, layer):
@@ -1109,7 +1188,15 @@ def init_mixed_params(cfg: TransformerConfig, key) -> Params:
     dense_at = [i for i, (_, has_experts) in enumerate(kinds)
                 if not has_experts]
     moe_at = [i for i, (_, has_experts) in enumerate(kinds) if has_experts]
-    layers = {"attn": stacked(attn, [sub[i][0] for i in range(nl)])}
+    if m.attn_by_kind:
+        layers = {name: stacked(partial(attn, is_full=full),
+                                [sub[i][0] for i in range(nl)
+                                 if kinds[i][0] == full])
+                  for name, full in (("attn_full", True),
+                                     ("attn_window", False))
+                  if any(k[0] == full for k in kinds)}
+    else:
+        layers = {"attn": stacked(attn, [sub[i][0] for i in range(nl)])}
     if dense_at:
         layers["mlp"] = stacked(
             lambda k: swiglu(k, (), cfg.intermediate_size),
@@ -1123,9 +1210,11 @@ def init_mixed_params(cfg: TransformerConfig, key) -> Params:
             lax.map(lambda k: swiglu(k, (m.experts_held[1],), f),
                     jnp.stack([sub[i][1] for i in moe_at])),
             router=jnp.stack([router(sub[i][2], i) for i in moe_at]),
-            bias=jnp.zeros((len(moe_at), m.n_routed_experts), pd),
-            shared=stacked(lambda k: swiglu(k, (), f * m.n_shared_experts),
-                           [sub[i][3] for i in moe_at]))
+            bias=jnp.zeros((len(moe_at), m.n_routed_experts), pd))
+        if m.n_shared_experts:
+            layers["moe"]["shared"] = stacked(
+                lambda k: swiglu(k, (), f * m.n_shared_experts),
+                [sub[i][3] for i in moe_at])
     for name in ("ln1", "ln2") + (("post_attn", "post_mlp")
                                   if m.sandwich_norm else ()):
         layers[name] = {"scale": jnp.ones((nl, h), pd)}

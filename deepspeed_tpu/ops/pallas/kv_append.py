@@ -1,7 +1,8 @@
 """Pallas append of a ragged step's new KV rows to their pages, both pools
 in one call.
 
-The step's rows reach the pools ``[L, nkv, P, d]`` by WHOLE PAGES: a
+The step's rows reach the pools ``[L, nkv, P, d]`` (K rows and V rows
+may each have a width of their own, whole 128-lane tiles) by WHOLE PAGES: a
 sequence's new rows are consecutive positions, so they fill consecutive
 rows of consecutive pages, and a page of 16 rows of bfloat16 is one packed
 tile.  The scatter this replaces made an update of ``d`` elements for every
@@ -69,20 +70,22 @@ def append_pages(items, block_size: int) -> int:
                for cached, n in items if n > 0)
 
 
-def fit(t: int, nkv: int, d: int, block_size: int, dtype):
+def fit(t: int, nkv: int, d: int, block_size: int, dtype,
+        dv: int | None = None):
     """``(rows, group)``: the rows of a ``t``-row step one program takes
     and the pages it holds at once, for pools of ``dtype`` with ``nkv``
-    heads of ``d`` and pages of ``block_size`` rows, inside the VMEM
-    budget: half of it for the pages (both pools), the rest for the rows
+    heads, K rows of ``d`` and V rows of ``dv`` (``None``: ``d``) and
+    pages of ``block_size`` rows, inside the VMEM budget: half of it for the pages (both pools), the rest for the rows
     (both pools: the block as it comes, twice, for the pipeline fetches
     the next behind the one at work, and once spread as float32 with a
     page of room at either end).  None where not a page fits beside
     sixteen rows: there is no kernel for the shape."""
     size = jnp.dtype(dtype).itemsize
-    a_page = 2 * nkv * block_size * d * size
-    a_row = 2 * nkv * d * (2 * size + 4)
+    both = d + (d if dv is None else dv)
+    a_page = nkv * block_size * both * size
+    a_row = nkv * both * (2 * size + 4)
     group = min(_GROUP, _VMEM_BUDGET // 2 // a_page)
-    room = _VMEM_BUDGET - group * a_page - 2 * block_size * 2 * nkv * d * 4
+    room = _VMEM_BUDGET - group * a_page - 2 * block_size * nkv * both * 4
     rows = ROW_BLOCK
     while rows > 16 and rows * a_row > room:
         rows //= 2
@@ -92,13 +95,14 @@ def fit(t: int, nkv: int, d: int, block_size: int, dtype):
     return rows, min(group, rows)       # an entry is a row at the least
 
 
-def step_pages(pool, token_dest, block_size: int):
+def step_pages(pool, token_dest, block_size: int, v_pool=None):
     """:func:`page_list` of a step's destinations, cut as :func:`kv_append`
-    takes it for pools like ``pool`` [L, nkv, P, d] (the same for every
-    layer of a step: made once); None where :func:`fit` has no kernel for
-    the shape."""
+    takes it for pools like ``pool`` [L, nkv, P, d] (and ``v_pool``
+    [L, nkv, P, dv] where V rows have a width of their own; the same for
+    every layer of a step: made once); None where :func:`fit` has no
+    kernel for the shape."""
     plan = fit(token_dest.shape[0], pool.shape[1], pool.shape[3], block_size,
-               pool.dtype)
+               pool.dtype, None if v_pool is None else v_pool.shape[3])
     return None if plan is None else page_list(token_dest, block_size,
                                                plan[0])
 
@@ -153,8 +157,9 @@ def _kernel(ends_ref, layer_ref, row0_ref, base_ref, lo_ref, hi_ref, k_ref,
     P, d]`` in HBM, read and written (the operands they alias, ``k_in`` /
     ``v_in``, are the same memory on the chip and not touched: a page cut
     between two programs is read as the first left it); ``k_rows`` /
-    ``v_rows`` ``[d / lanes, nkv, rows + 2 bs, lanes]`` float32; ``k_buf``
-    / ``v_buf`` ``[group, nkv, bs, d]``; ``sem_in`` / ``sem_out`` ``[2,
+    ``v_rows`` ``[row width / lanes, nkv, rows + 2 bs, lanes]`` float32;
+    ``k_buf`` / ``v_buf`` ``[group, nkv, bs, row width]`` (K rows and V
+    rows each of a width of their own); ``sem_in`` / ``sem_out`` ``[2,
     group]``, one a pool and buffer: a wait on a semaphore that several
     copies in flight signal proves that as many bytes came, not whose."""
     del k_in, v_in
@@ -163,18 +168,25 @@ def _kernel(ends_ref, layer_ref, row0_ref, base_ref, lo_ref, hi_ref, k_ref,
     e_lo = lax.select(g > 0, ends_ref[lax.max(g - 1, 0)], 0)
     e_hi = ends_ref[g]
     t = k_ref.shape[0]
-    tiles, nkv, _, lane = k_rows.shape
+    _, nkv, _, lane = k_rows.shape
+    # (the step's rows, their spread, the pages' buffer) a pool
+    pools = ((k_ref, k_rows, k_buf), (v_ref, v_rows, v_buf))
+    most_tiles = max(k_rows.shape[0], v_rows.shape[0])
 
     # the block's rows as 32-bit values, a head's lane tile apart, with a
     # page of room at either end that is never read unmasked
     def spread(h, _):
-        for c in range(tiles):
-            of_row = pl.dslice(pl.multiple_of((h * tiles + c) * lane, lane),
-                               lane)
-            k_rows[c, h, pl.ds(bs, t), :] = k_ref[:, of_row].astype(
-                jnp.float32)
-            v_rows[c, h, pl.ds(bs, t), :] = v_ref[:, of_row].astype(
-                jnp.float32)
+        for c in range(most_tiles):
+            of_row = {}     # by a row's tiles: one slice where K and V agree
+            for ref, rows, _ in pools:
+                tiles = rows.shape[0]
+                if c >= tiles:
+                    continue
+                if tiles not in of_row:
+                    of_row[tiles] = pl.dslice(
+                        pl.multiple_of((h * tiles + c) * lane, lane), lane)
+                rows[c, h, pl.ds(bs, t), :] = ref[:, of_row[tiles]].astype(
+                    jnp.float32)
         return 0
 
     lax.fori_loop(0, nkv, spread, 0)
@@ -193,14 +205,13 @@ def _kernel(ends_ref, layer_ref, row0_ref, base_ref, lo_ref, hi_ref, k_ref,
         new = jnp.broadcast_to((row >= lo_ref[e]) & (row < hi_ref[e]),
                                k_buf.shape[1:3] + (lane,))
         src = pl.dslice(base_ref[e], bs)
-        for c in range(tiles):
+        for c in range(most_tiles):
             lanes = slice(c * lane, (c + 1) * lane)
-            k_buf[j, :, :, lanes] = lax.select(
-                new, k_rows[c, :, src, :].astype(k_buf.dtype),
-                k_buf[j, :, :, lanes])
-            v_buf[j, :, :, lanes] = lax.select(
-                new, v_rows[c, :, src, :].astype(v_buf.dtype),
-                v_buf[j, :, :, lanes])
+            for _, rows, buf in pools:
+                if c < rows.shape[0]:
+                    buf[j, :, :, lanes] = lax.select(
+                        new, rows[c, :, src, :].astype(buf.dtype),
+                        buf[j, :, :, lanes])
 
     def one_group(c, _):
         e0 = e_lo + c * group
@@ -232,15 +243,15 @@ def _kernel(ends_ref, layer_ref, row0_ref, base_ref, lo_ref, hi_ref, k_ref,
 
 
 def kv_append(cache_k, cache_v, k, v, pages, layer, block_size: int):
-    """Put the step's rows ``k``, ``v`` [T, nkv, d] into ``layer``'s pages
-    of the pools ``cache_k``, ``cache_v`` [L, nkv, P, d], in place (donate
-    the pools), by whole pages of ``block_size`` rows: ``(cache_k',
+    """Put the step's rows ``k`` [T, nkv, d] and ``v`` [T, nkv, dv] into
+    ``layer``'s pages of the pools ``cache_k`` [L, nkv, P, d] and
+    ``cache_v`` [L, nkv, P, dv], in place (donate the pools), by whole pages of ``block_size`` rows: ``(cache_k',
     cache_v')``.  ``pages``: :func:`step_pages` of the rows' destinations
     for these pools.  Pages a step touches are distinct but for page 0: a
     page's new rows are ONE run of consecutive rows with consecutive
     destinations (as ``build_ragged_batch`` lays a sequence's rows)."""
     rows, group = fit(k.shape[0], k.shape[1], k.shape[2], block_size,
-                      cache_k.dtype)
+                      cache_k.dtype, v.shape[2])
     return _append(cache_k, cache_v, k, v, pages, layer, block_size, rows,
                    group, paged_attention.INTERPRET)
 
@@ -252,20 +263,21 @@ def kv_append(cache_k, cache_v, k, v, pages, layer, block_size: int):
 def _append(cache_k, cache_v, k, v, pages, layer, block_size, rows, group,
             interpret):
     t, nkv, d = k.shape
+    dv = v.shape[2]
     bs = block_size
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    block = pl.BlockSpec((rows, nkv * d), lambda g, *_: (g, 0))
-    spread = pltpu.VMEM((d // _LANES, nkv, rows + 2 * bs, _LANES),
-                        jnp.float32)
+    block = lambda w: pl.BlockSpec((rows, nkv * w), lambda g, *_: (g, 0))
+    spread = lambda w: pltpu.VMEM((w // _LANES, nkv, rows + 2 * bs, _LANES),
+                                  jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(pl.cdiv(t, rows),),
-        in_specs=[block, block, hbm, hbm],
+        in_specs=[block(d), block(dv), hbm, hbm],
         out_specs=[hbm, hbm],
         scratch_shapes=[
-            spread, spread,
+            spread(d), spread(dv),
             pltpu.VMEM((group, nkv, bs, d), cache_k.dtype),
-            pltpu.VMEM((group, nkv, bs, d), cache_v.dtype),
+            pltpu.VMEM((group, nkv, bs, dv), cache_v.dtype),
             pltpu.SemaphoreType.DMA((2, group)),
             pltpu.SemaphoreType.DMA((2, group)),
         ],
@@ -287,4 +299,4 @@ def _append(cache_k, cache_v, k, v, pages, layer, block_size, rows, group,
     # rounded to the pool's dtype once, as the scatter did
     )(ends, jnp.asarray(layer, jnp.int32).reshape(1), *tables,
       k.astype(cache_k.dtype).reshape(t, nkv * d),
-      v.astype(cache_v.dtype).reshape(t, nkv * d), cache_k, cache_v)
+      v.astype(cache_v.dtype).reshape(t, nkv * dv), cache_k, cache_v)

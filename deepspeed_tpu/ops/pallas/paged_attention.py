@@ -45,8 +45,10 @@ The int8-KV variant ``paged_decode_q8`` keeps the row-per-program grid
 (T, nkv) and a page a step (no cell or deployment measures it yet).
 
 Cache layout contract: k_pages/v_pages are one layer's pages ``[nkv, P, d]``
-where P = number of pages × block_size rows, or EVERY layer's pool
-``[L, nkv, P, d]`` with ``layer`` naming the one to read: the index is one
+where P = number of pages × block_size rows (V rows may be of a width of
+their own, ``[nkv, P, dv]``: scores contract over ``d``, the accumulator
+and the output are ``dv`` wide), or EVERY layer's pool
+``[L, nkv, P, .]`` with ``layer`` naming the one to read: the index is one
 more scalar-prefetched operand and a page's DMA is ``pool[layer, :, rows]``,
 so a layer loop carries the pool whole and no layer is ever sliced out of it
 into a value of its own (as ``ssd_ragged`` takes its state).  The kernels
@@ -78,13 +80,34 @@ _STEP_KEYS = 512
 _KV_BUFFER_BYTES = 4 * 1024 * 1024
 
 
-def supports(block_size: int, d: int) -> bool:
-    """Kernel applicability: page rows must be sublane-aligned and the
-    head dim lane-aligned — the page DMA slices ``[bs, d]`` out of the
-    HBM pool, and Mosaic refuses a slice whose minor dim is not a
-    multiple of the 128-lane tiling (head_dim 64 models ride
-    ``paged_xla``)."""
-    return block_size >= 8 and block_size % 8 == 0 and d % 128 == 0
+_LANES = 128
+
+
+def row_width(d: int) -> int:
+    """Lanes a pool keeps for a row of ``d`` dims: ``d`` up to one
+    128-lane tile, whole tiles past it (192 -> 256: the chip lays a
+    ``[.., rows, 192]`` array out in 256 lanes whatever its shape says,
+    and a page's DMA wants whole tiles; the pad lanes hold zeros, which
+    add nothing to a score)."""
+    return d if d <= _LANES else -(-d // _LANES) * _LANES
+
+
+def supports(block_size: int, d: int, dv: int | None = None) -> bool:
+    """Kernel applicability (``paged_qblock``, bf16 / f32 pools): page
+    rows must be sublane-aligned, and K rows and V rows lane-aligned AS
+    THE POOLS KEEP THEM — the page DMA slices ``[bs, row]`` out of the HBM
+    pool, and Mosaic refuses a slice whose minor dim is not a multiple of
+    the 128-lane tiling.  K rows of ``d`` dims and V rows of ``dv``
+    (``None``: ``d``) may differ: a key width past one tile that is no
+    multiple of 128 is TAKEN, kept in :func:`row_width` lanes (192 in
+    256, 1.2 x the published bytes of a 192 / 128 token); a value width
+    must be whole tiles as it is (it is the output's); a key narrower
+    than a tile (head_dim 64 models) is refused and rides ``paged_xla``.
+    A learned sink a head is taken.  The int8-KV kernel
+    (``paged_decode_q8``) takes ONE width and no sink."""
+    dv = d if dv is None else dv
+    return (block_size >= 8 and block_size % 8 == 0 and d >= _LANES
+            and dv % _LANES == 0)
 
 
 def shared_walk_rows(run_lengths, query_block: int = QUERY_BLOCK) -> int:
@@ -105,21 +128,33 @@ def shared_walk_rows(run_lengths, query_block: int = QUERY_BLOCK) -> int:
 
 
 def _kernel_qblock(tables_ref, slot_ref, pos_ref, clen_ref, layer_ref, q_ref,
-                   rowpos_ref, rowclen_ref, k_hbm, v_hbm, o_ref, m_scr, l_scr,
-                   acc_scr, k_buf, v_buf, sem_k, sem_v, *, bs, group, qb,
-                   pages_per_step, sm_scale, window=None):
+                   rowpos_ref, rowclen_ref, *refs, bs, group, qb,
+                   pages_per_step, sm_scale, window=None, sink=False):
     """Grid (T / qb,): one program, ``qb`` rows of the token array, every
-    KV head; ``q_ref`` / ``o_ref`` are ``[nkv, qb*group, d]``, row = token
-    * group + head of the group; ``k_hbm`` / ``v_hbm`` are every layer's
-    pool ``[L, nkv, P, d]`` and ``layer_ref[0]`` the layer to read.  The
-    rows are cut into runs of one sequence; each run is one
-    double-buffered walk of ``pages_per_step`` pages a step, a page's rows
-    of all KV heads in one strided DMA."""
+    KV head; ``q_ref`` is ``[nkv, qb*group, d]`` and ``o_ref`` ``[nkv,
+    qb*group, dv]``, row = token * group + head of the group; ``k_hbm``
+    ``[L, nkv, P, d]`` and ``v_hbm`` ``[L, nkv, P, dv]`` are every
+    layer's pool and ``layer_ref[0]`` the layer to read.  The rows are
+    cut into runs of one sequence; each run is one double-buffered walk
+    of ``pages_per_step`` pages a step, a page's rows of all KV heads in
+    one strided DMA a pool.  With ``sink`` one more operand
+    ``[nkv, qb*group, 1]`` comes before the pools: each row's head's
+    learned logit, with which its online softmax STARTS (running max the
+    logit, running sum ``exp(0)``, nothing accumulated): it takes
+    probability and adds no value."""
+    if sink:
+        sink_ref, *refs = refs
+    (k_hbm, v_hbm, o_ref, m_scr, l_scr, acc_scr, k_buf, v_buf, sem_k,
+     sem_v) = refs
     base = pl.program_id(0) * qb
     layer = layer_ref[0]
     nkv, rows = q_ref.shape[:2]
-    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    if sink:
+        m_scr[...] = sink_ref[...]
+        l_scr[...] = jnp.ones(l_scr.shape, jnp.float32)
+    else:
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
     acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
     row = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
     row_pos = rowpos_ref[...]                            # [rows, 1]
@@ -386,16 +421,19 @@ def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
                            token_ctx_len, block_size: int, sm_scale: float,
                            window: int | None = None,
                            k_scales=None, v_scales=None, token_slot=None,
-                           layer=None):
-    """q: [T, nh, d]; k_pages/v_pages: [nkv, P, d], or every layer's pool
-    [L, nkv, P, d] with ``layer`` (a traced scalar will do) naming the one
-    to read; token_pos/token_ctx_len: [T]; ``pages``: page ids, [S, NB]
-    block tables with ``token_slot`` [T] naming each token's table row, or
-    [T, NB] a table per token without it; ``window``: Mistral sliding
-    window (key visible iff qpos - kpos < window).  With
+                           layer=None, sink=None):
+    """q: [T, nh, d]; k_pages: [nkv, P, d] and v_pages: [nkv, P, dv], or
+    every layer's pool [L, nkv, P, .] with ``layer`` (a traced scalar will
+    do) naming the one to read; token_pos/token_ctx_len: [T]; ``pages``:
+    page ids, [S, NB] block tables with ``token_slot`` [T] naming each
+    token's table row, or [T, NB] a table per token without it;
+    ``window``: Mistral sliding window (key visible iff qpos - kpos <
+    window); ``sink`` [nh]: a learned logit a head that joins every row's
+    softmax denominator and adds no value.  With
     ``k_scales``/``v_scales`` [nkv, P] (or [L, nkv, P]) the page payloads
     are int8 rows scaled per (head, row) — ref KV-block layout
-    inference/v2/ragged/kv_cache.py:40.  Returns [T, nh, d]."""
+    inference/v2/ragged/kv_cache.py:40: one width, no sink.  Returns
+    [T, nh, dv]."""
     if layer is None:
         # one layer's pages are a pool of one layer (a free reshape)
         k_pages, v_pages = k_pages[None], v_pages[None]
@@ -404,11 +442,17 @@ def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
         layer = 0
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     t, nh, d = q.shape
-    nkv = k_pages.shape[1]
+    nkv, dv = k_pages.shape[1], v_pages.shape[3]
     group = nh // nkv
     bs = block_size
     i32 = lambda a: a.astype(jnp.int32)
     if k_scales is not None:
+        if sink is not None or dv != d:
+            raise NotImplementedError(
+                "paged_decode_q8 (the int8-KV kernel) takes K and V rows "
+                "of ONE width and no sink in the softmax; a model with "
+                "either keeps its pools in the compute dtype "
+                "(paged_qblock)")
         if token_slot is not None:
             pages = pages[token_slot]
         return _decode_q8(q, k_pages, v_pages, pages, token_pos,
@@ -429,30 +473,39 @@ def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
     tp = t + pad
     rows = qb * group
     step_keys = min(_STEP_KEYS, _KV_BUFFER_BYTES
-                    // (4 * nkv * d * k_pages.dtype.itemsize))
+                    // (2 * nkv * (d + dv) * k_pages.dtype.itemsize))
     pages_per_step = max(1, step_keys // bs)
 
     # per KV head a dense tile of its query groups, row = token * group +
     # head of the group: no kernel-side relayout, one XLA transpose each way
     q_spec = pl.BlockSpec((nkv, rows, d), lambda b, *refs: (0, b, 0))
+    o_spec = pl.BlockSpec((nkv, rows, dv), lambda b, *refs: (0, b, 0))
     # each tile row's own position and context length, as columns
     col_spec = pl.BlockSpec((rows, 1), lambda b, *refs: (b, 0))
     col = lambda a: jnp.repeat(a, group)[:, None]
+    sinks = ()
+    if sink is not None:
+        # every block's rows alike: row r of KV head h is query head
+        # h * group + r % group
+        sinks = (jnp.tile(sink.astype(jnp.float32).reshape(nkv, 1, group),
+                          (1, qb, 1)).reshape(nkv, rows, 1),)
+    sink_specs = [pl.BlockSpec((nkv, rows, 1), lambda b, *refs: (0, 0, 0))
+                  for _ in sinks]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(tp // qb,),
         # the page pools stay in HBM; the kernel DMAs live pages into
         # its double buffer itself
-        in_specs=[q_spec, col_spec, col_spec,
+        in_specs=[q_spec, col_spec, col_spec, *sink_specs,
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=q_spec,
+        out_specs=o_spec,
         scratch_shapes=[
             pltpu.VMEM((nkv, rows, 1), jnp.float32),  # running max
             pltpu.VMEM((nkv, rows, 1), jnp.float32),  # running sum
-            pltpu.VMEM((nkv, rows, d), jnp.float32),  # accumulator
+            pltpu.VMEM((nkv, rows, dv), jnp.float32),  # accumulator
             pltpu.VMEM((2, nkv, pages_per_step * bs, d), k_pages.dtype),
-            pltpu.VMEM((2, nkv, pages_per_step * bs, d), v_pages.dtype),
+            pltpu.VMEM((2, nkv, pages_per_step * bs, dv), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
@@ -460,13 +513,13 @@ def paged_decode_attention(q, k_pages, v_pages, pages, token_pos,
     out = pl.pallas_call(
         functools.partial(_kernel_qblock, bs=bs, group=group, qb=qb,
                           pages_per_step=pages_per_step, sm_scale=sm_scale,
-                          window=window),
+                          window=window, sink=bool(sinks)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nkv, tp * group, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((nkv, tp * group, dv), q.dtype),
         interpret=INTERPRET,
         name="paged_qblock",
     )(i32(pages), slot, pos, clen, layer,
       q4.swapaxes(0, 1).reshape(nkv, tp * group, d), col(pos), col(clen),
-      k_pages, v_pages)
-    out = out.reshape(nkv, tp, group, d).swapaxes(0, 1)
-    return out[:t].reshape(t, nh, d)
+      *sinks, k_pages, v_pages)
+    out = out.reshape(nkv, tp, group, dv).swapaxes(0, 1)
+    return out[:t].reshape(t, nh, dv)

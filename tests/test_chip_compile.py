@@ -394,6 +394,120 @@ def test_kv_append_other_shapes(chip, t, nkv, d, bs, dtype):
     assert "output_to_operand_aliasing={{0}: (8, {}), {1}: (9, {})}" in call
 
 
+# MiMo-V2-Flash's layers at the published widths: 64 query heads, keys 192
+# wide (kept in 256 lanes, ``paged_attention.row_width``) and values 128,
+# 4 KV heads in a full layer and 8 in a window-128 layer whose softmax
+# starts from a learned sink a head; pages of 128 rows, 161 a sequence
+_MIMO_KINDS = {"full": (2, 4, None, False), "window_sink": (5, 8, 128, True)}
+
+
+@pytest.mark.parametrize("t", [1024, 16], ids=["chunk", "decode_bucket"])
+@pytest.mark.parametrize("kind", list(_MIMO_KINDS))
+def test_paged_qblock_keys_192_values_128_heads_by_kind(chip, kind, t):
+    layers, nkv, window, sink = _MIMO_KINDS[kind]
+    assert paged_attention.supports(128, 192, 128)
+    dk = paged_attention.row_width(192)
+
+    def fn(q, k, v, tables, pos, clen, slot, layer, *sinks):
+        return paged_attention.paged_decode_attention(
+            q, k, v, tables, pos, clen, block_size=128,
+            sm_scale=192 ** -0.5, window=window, token_slot=slot,
+            layer=layer, sink=sinks[0] if sinks else None)
+
+    out = jax.eval_shape(
+        fn, chip((t, 64, dk), BF16), chip((layers, nkv, 512 * 128, dk), BF16),
+        chip((layers, nkv, 512 * 128, 128), BF16), chip((65, 161), I32),
+        chip((t,), I32), chip((t,), I32), chip((t,), I32), chip((), I32),
+        *([chip((64,), F32)] if sink else []))
+    assert out.shape == (t, 64, 128)
+    _compile(fn, chip((t, 64, dk), BF16),
+             chip((layers, nkv, 512 * 128, dk), BF16),
+             chip((layers, nkv, 512 * 128, 128), BF16), chip((65, 161), I32),
+             chip((t,), I32), chip((t,), I32), chip((t,), I32),
+             chip((), I32), *([chip((64,), F32)] if sink else []))
+
+
+@pytest.mark.parametrize("t", [1024, 16], ids=["chunk", "decode_bucket"])
+@pytest.mark.parametrize("kind", list(_MIMO_KINDS))
+def test_kv_append_rows_of_two_widths(chip, kind, t):
+    """K rows of 256 lanes (192 dims) and V rows of 128 go to their pages
+    in ONE call, both pools aliased."""
+    from deepspeed_tpu.ops.pallas import kv_append as ka
+
+    layers, nkv, _, _ = _MIMO_KINDS[kind]
+
+    def fn(ck, cv, k, v, dest, layer):
+        return ka.kv_append(ck, cv, k, v, ka.step_pages(ck, dest, 128, cv),
+                            layer, 128)
+
+    text = jax.jit(fn, donate_argnums=(0, 1)).lower(
+        chip((layers, nkv, 64 * 128, 256), BF16),
+        chip((layers, nkv, 64 * 128, 128), BF16), chip((t, nkv, 256), BF16),
+        chip((t, nkv, 128), BF16), chip((t,), I32), chip((), I32)
+    ).compile().as_text()
+    call = next(ln for ln in text.splitlines()
+                if "custom-call(" in ln and "kv_append" in ln)
+    assert "output_to_operand_aliasing={{0}: (8, {}), {1}: (9, {})}" in call
+
+
+@pytest.mark.parametrize("t", [1024, 16], ids=["full_step", "decode_bucket"])
+def test_kinds_of_two_shapes_step_keeps_all_four_pools_in_place(chip, t):
+    """The serving step of ``mimo-v2-flash-ep16-l7`` (seven layers at the
+    published widths, the configuration's pools and 161 pages a
+    sequence): the full layers' pools ``[2, 4, P, 256]`` / ``[2, 4, P,
+    128]`` and the window layers' ``[5, 8, P, 256]`` / ``[5, 8, P, 128]``
+    are donated and come back as the buffers they came in, no pool or
+    layer of a pool is copied or sliced whole, the five window layers are
+    ONE scan, and the step makes three call sites of each kernel (layer
+    0, the scan's body, layer 6)."""
+    import re
+
+    from deepspeed_tpu.inference.v2 import model as v2_model
+    from deepspeed_tpu.inference.v2.ragged import PackedIndex
+    from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.models import transformer as tf_model
+
+    cfg = get_model_config("mimo-v2-flash-ep16", param_dtype=BF16,
+                           v2_modules=(("attention", "paged_pallas"),))
+    assert v2_model.layer_segments(cfg.mixed.kinds(7)) \
+        == [(0, 1, 1), (1, 1, 5), (6, 1, 1)]
+    params = _abstract(chip, jax.eval_shape(
+        lambda k: tf_model.init_params(cfg, k), jax.random.PRNGKey(0)))
+    ck, cv, state = v2_model.new_window_pools(
+        cfg, 2560 * 128, 256 * 128, zeros=lambda s, dtype: chip(s, dtype),
+        dtype=BF16)
+    index = PackedIndex(
+        chip((PackedIndex.size(t, 65, 161, window=True),), I32), t, 65, 161,
+        window=True)
+    fn = functools.partial(v2_model.ragged_step_sampled, cfg=cfg,
+                           block_size=128, greedy=True)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn, donate_argnums=(1, 2),
+                           donate_argnames=("state",)).lower(
+            params, ck, cv, index, chip((65,), I32), chip((2,), jnp.uint32),
+            chip((), F32), state=state).compile()
+    text = compiled.as_text()
+    assert len(_aliased_outputs(text)) == 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 ** 28, mem
+    pools = (ck, cv, state["k"], state["v"])
+    assert mem.alias_size_in_bytes >= sum(2 * math.prod(a.shape)
+                                          for a in pools)
+    shapes = {"bf16[" + ",".join(map(str, a.shape[i:])) + "]"
+              for a in pools for i in (0, 1)}
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+?)[{ ]\S* ?([\w-]+)\(", line)
+        if m and m.group(2) in shapes and re.search(
+                r"copy|dynamic-slice|dynamic-update-slice",
+                m.group(1) if m.group(3) == "fusion" else m.group(3)):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+    for kernel in ("paged_qblock", "kv_append"):
+        assert sum(kernel in ln and "custom-call(" in ln
+                   for ln in text.splitlines()) == 3
+
+
 def test_latent_index_scores_at_dots3_widths(chip):
     """The indexer's score kernel: 64 heads of 128 over pages of 128
     rows, a full 1024-row step at a 32k context bucket (an output block

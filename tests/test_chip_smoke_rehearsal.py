@@ -61,6 +61,14 @@ def test_append_phase_tiny(tpu_branches):
     assert out["same"] and out["rows"] == 36
 
 
+def test_sink_phase_tiny(tpu_branches):
+    out = chip_smoke.sink_phase(heads=16, head_dim=192, value_dim=128,
+                                window=12, block_size=8, blocks=8, pages=24,
+                                chunk_rows=21)
+    assert out["window"] < 0.03 and out["full"] < 0.03
+    assert min(out["window sign"], out["window heads"]) > 10 * out["window"]
+
+
 def test_index_phase_tiny(tpu_branches):
     out = chip_smoke.index_phase(heads=4, dim=16, block_size=8, blocks=8,
                                  chunk_rows=21)

@@ -139,6 +139,9 @@ def test_the_schedule_spans_counts():
     assert counts == {"full_kv_rows": 56 + 5 + 91,
                       "window_kv_rows": 24 + 5 + 24,
                       "full_pages": 12, "window_pages": 7, "pages_freed": 3,
+                      # live pairs: causal, and cut to the window of 24
+                      "full_qk_pairs": sum(range(41, 57)) + 15 + 91,
+                      "window_qk_pairs": 16 * 24 + 15 + 24,
                       "expert_rows": 22 * 4 * 4 / 16}
 
 

@@ -327,10 +327,16 @@ EXPECTED_LATENT_SCHEDULE_ARGS = ["expert_rows", "index_pairs", "latent_rows",
 # and full attention by layer (engine_v2.window_step_counts), and those of
 # its pools' ``v2.state_alloc``: the benchmark's readers read them by name
 EXPECTED_WINDOW_SCHEDULE_ARGS = ["expert_rows", "full_kv_rows", "full_pages",
-                                 "pages_freed", "window_kv_rows",
-                                 "window_pages"]
+                                 "full_qk_pairs", "pages_freed",
+                                 "window_kv_rows", "window_pages",
+                                 "window_qk_pairs"]
+# (the last eight from engine_v2.mixed_alloc_counts: what the two pools
+# keep a kind of layer, and the Pallas calls ONE step program makes)
 EXPECTED_WINDOW_ALLOC_ARGS = ["full_pool_bytes", "window_layers",
-                              "window_pool_bytes"]
+                              "window_pool_bytes", "full_page_bytes",
+                              "window_page_bytes", "full_kv_heads",
+                              "window_kv_heads", "key_width", "value_width",
+                              "sink_layers", "kernel_calls_per_step"]
 
 
 # arguments a run's first ``train.step`` span carries where the model
