@@ -340,16 +340,20 @@ def append_phase(seed: int = 0, *, layers: int = 4, kv_heads: int = 8,
 def sink_phase(seed: int = 0, *, heads: int = 64, head_dim: int = 192,
                value_dim: int = 128, window: int = 128,
                block_size: int = 128, blocks: int = 6, pages: int = 64,
-               chunk_rows: int = 250) -> dict:
+               chunk_rows: int = 250, decode_rows: tuple = (13, 40)) -> dict:
     """``paged_qblock`` with K rows wider than V rows and a learned sink a
     head against the XLA gather path in float32, at mimo-v2-flash's two
     kinds of layer: 8 KV heads, a window and the sink; 4 KV heads, no
-    window and none.  One ragged step over pools of several layers: six
-    decoding rows (runs of ONE row), a chunk on cached tokens, padding.
-    The window kind is also held against the XLA path given the sink with
-    its SIGN flipped and with its heads in another order: each must read
-    an order above the kernel's own distance, so a wrong sign or a wrong
-    head-to-sink mapping on the chip cannot pass for rounding."""
+    window and none.  Three ragged steps over pools of several layers: six
+    decoding rows (runs of ONE row), a chunk on cached tokens, padding;
+    then ``decode_rows`` decoding rows alone in their token buckets, every
+    context past the window: the steps whose every walk is multiplied on
+    the narrow window of its program's tile (``narrow_rows``).
+    The window kind's first step is also held against the XLA path given
+    the sink with its SIGN flipped and with its heads in another order:
+    each must read an order above the kernel's own distance, so a wrong
+    sign or a wrong head-to-sink mapping on the chip cannot pass for
+    rounding."""
     import jax
     import jax.numpy as jnp
 
@@ -364,25 +368,11 @@ def sink_phase(seed: int = 0, *, heads: int = 64, head_dim: int = 192,
             f"{value_dim}")
     dk = row_width(head_dim)
     rng = np.random.default_rng(seed)
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 16))
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 32))
     bf16, f32 = jnp.bfloat16, jnp.float32
-    n_dec, cached = 6, 2 * bs + 5
-    real = n_dec + chunk_rows
-    t = -(-real // 128) * 128
-    tables = jnp.asarray(rng.integers(1, pages, size=(n_dec + 2, nb)),
-                         jnp.int32)
-    ctx = np.append(rng.integers(window + 1, nb * bs, size=n_dec),
-                    [cached + chunk_rows, 0])
+    cached = 2 * bs + 5
     require(cached + chunk_rows <= nb * bs, "the chunk passes its table")
-    slot = np.full((t,), n_dec + 1, np.int32)
-    slot[:real] = np.append(np.arange(n_dec), np.full(chunk_rows, n_dec))
-    pos = np.zeros((t,), np.int32)
-    pos[:real] = np.append(ctx[:n_dec] - 1,
-                           np.arange(cached, cached + chunk_rows))
-    slot, pos = jnp.asarray(slot), jnp.asarray(pos)
-    clen = jnp.asarray(ctx, jnp.int32)[slot]
     c_idx = jnp.arange(nb * bs)
-    gather_idx = tables[slot][:, c_idx // bs] * bs + (c_idx % bs)[None, :]
     scale = 1.0 / float(np.sqrt(head_dim))
     base = get_model_config(SERVE_MODEL).replace(attn_scale=scale)
 
@@ -390,51 +380,75 @@ def sink_phase(seed: int = 0, *, heads: int = 64, head_dim: int = 192,
         return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, dk - head_dim),))
 
     out = {}
-    for kind, nkv, win, has_sink in (("window", 8, window, True),
-                                     ("full", 4, None, False)):
-        layers, layer = 3, 1
-        q = padded(jax.random.normal(next(keys), (t, heads, head_dim), bf16))
-        kp = padded(jax.random.normal(
-            next(keys), (layers, nkv, pages * bs, head_dim), bf16))
-        vp = jax.random.normal(next(keys), (layers, nkv, pages * bs,
-                                            value_dim), bf16)
-        # logits of 2 +- 2 beside 128 scores of about N(0, 1): a sink
-        # holds from a hundredth to most of a row's probability, so its
-        # sign and its head show far above rounding
-        sink = 2.0 + 2.0 * jax.random.normal(next(keys), (heads,), f32) \
-            if has_sink else None
-        got = jax.jit(lambda q, kp, vp, sink: paged_decode_attention(
-            q, kp, vp, tables, pos, clen, bs, scale, window=win,
-            token_slot=slot, layer=jnp.int32(layer), sink=sink))(
-                q, kp, vp, sink)[:real].astype(f32)
-        require(got.shape == (real, heads, value_dim),
-                f"{kind}: the kernel's output is {got.shape}")
-        cfg = base.replace(sliding_window=win or 0)
+    # decode rows, rows of a chunk behind them, the step's token bucket,
+    # the name the step's distances are reported under
+    for n_dec, n_chunk, t, tag in (
+            (6, chunk_rows, -(-(6 + chunk_rows) // 128) * 128, ""),
+            *((n, 0, max(16, 1 << (n - 1).bit_length()), f" {n} rows")
+              for n in decode_rows)):
+        real = n_dec + n_chunk
+        tables = jnp.asarray(rng.integers(1, pages, size=(n_dec + 2, nb)),
+                             jnp.int32)
+        ctx = np.append(rng.integers(window + 1, nb * bs, size=n_dec),
+                        [cached + n_chunk, 0])
+        slot = np.full((t,), n_dec + 1, np.int32)
+        slot[:real] = np.append(np.arange(n_dec), np.full(n_chunk, n_dec))
+        pos = np.zeros((t,), np.int32)
+        pos[:real] = np.append(ctx[:n_dec] - 1,
+                               np.arange(cached, cached + n_chunk))
+        slot, pos = jnp.asarray(slot), jnp.asarray(pos)
+        clen = jnp.asarray(ctx, jnp.int32)[slot]
+        gather_idx = tables[slot][:, c_idx // bs] * bs \
+            + (c_idx % bs)[None, :]
+        for kind, nkv, win, has_sink in (("window", 8, window, True),
+                                         ("full", 4, None, False)):
+            layers, layer = 3, 1
+            q = padded(jax.random.normal(next(keys), (t, heads, head_dim),
+                                         bf16))
+            kp = padded(jax.random.normal(
+                next(keys), (layers, nkv, pages * bs, head_dim), bf16))
+            vp = jax.random.normal(next(keys), (layers, nkv, pages * bs,
+                                                value_dim), bf16)
+            # logits of 2 +- 2 beside 128 scores of about N(0, 1): a sink
+            # holds from a hundredth to most of a row's probability, so its
+            # sign and its head show far above rounding
+            sink = 2.0 + 2.0 * jax.random.normal(next(keys), (heads,), f32) \
+                if has_sink else None
+            got = jax.jit(lambda q, kp, vp, sink: paged_decode_attention(
+                q, kp, vp, tables, pos, clen, bs, scale, window=win,
+                token_slot=slot, layer=jnp.int32(layer), sink=sink))(
+                    q, kp, vp, sink)[:real].astype(f32)
+            require(got.shape == (real, heads, value_dim),
+                    f"{kind}: the kernel's output is {got.shape}")
+            cfg = base.replace(sliding_window=win or 0)
 
-        def xla(sink):
-            return v2_model._paged_attention_xla(
-                q.astype(f32), kp[layer].astype(f32), vp[layer].astype(f32),
-                gather_idx, pos, clen, cfg, sink)[:real]
+            def xla(sink):
+                return v2_model._paged_attention_xla(
+                    q.astype(f32), kp[layer].astype(f32),
+                    vp[layer].astype(f32), gather_idx, pos, clen, cfg,
+                    sink)[:real]
 
-        err = float(jnp.max(jnp.abs(got - xla(sink))))
-        out[kind] = err
-        log(f"[sink] {kind} layer, {heads} heads to {nkv}, keys {head_dim} "
-            f"in {dk} lanes, values {value_dim}, window {win}, "
-            f"{n_dec} decode rows and a chunk of {chunk_rows}: "
-            f"max |pallas - xla float32| = {err:.5f}")
-        require(np.isfinite(err) and err < 0.03,
-                f"{kind}: paged_qblock disagrees with the XLA path ({err})")
-        if has_sink:
-            for fault, wrong in (("sign", -sink),
-                                 ("heads", jnp.roll(sink, 1))):
-                far = float(jnp.max(jnp.abs(got - xla(wrong))))
-                out[f"{kind} {fault}"] = far
-                log(f"[sink] against the XLA path with the sink's {fault} "
-                    f"wrong: {far:.5f} ({far / max(err, 1e-9):.0f} x)")
-                require(far > 10 * err,
-                        f"a sink with its {fault} wrong reads {far}, the "
-                        f"kernel as built {err}: the check cannot tell them "
-                        f"apart")
+            err = float(jnp.max(jnp.abs(got - xla(sink))))
+            out[kind + tag] = err
+            log(f"[sink] {kind} layer, {heads} heads to {nkv}, keys "
+                f"{head_dim} in {dk} lanes, values {value_dim}, window "
+                f"{win}, {n_dec} decode rows and a chunk of {n_chunk} in "
+                f"{t}: max |pallas - xla float32| = {err:.5f}")
+            require(np.isfinite(err) and err < 0.03,
+                    f"{kind}{tag}: paged_qblock disagrees with the XLA "
+                    f"path ({err})")
+            if has_sink and not tag:
+                for fault, wrong in (("sign", -sink),
+                                     ("heads", jnp.roll(sink, 1))):
+                    far = float(jnp.max(jnp.abs(got - xla(wrong))))
+                    out[f"{kind} {fault}"] = far
+                    log(f"[sink] against the XLA path with the sink's "
+                        f"{fault} wrong: {far:.5f} "
+                        f"({far / max(err, 1e-9):.0f} x)")
+                    require(far > 10 * err,
+                            f"a sink with its {fault} wrong reads {far}, "
+                            f"the kernel as built {err}: the check cannot "
+                            f"tell them apart")
     return out
 
 

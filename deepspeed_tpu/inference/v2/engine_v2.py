@@ -170,7 +170,10 @@ def step_counts(items: Sequence[tuple], window: Optional[int] = None,
     seeing ``min(p + 1, window)`` keys.  ``blocked_rows``: the new tokens
     that share a page walk in the query-blocked paged kernel, which
     serves ``query_block`` rows of the step a program (0: the step does
-    not run that kernel)."""
+    not run that kernel); ``one_row_walks``: the rest, each a piece of one
+    row under the same cut (a decode row, or what a block boundary left
+    of a longer run), which that kernel multiplies on a narrow window of
+    its tile."""
     prefill = decode = kv_rows = pairs = 0
     for cached, n in items:
         if n == 1 and cached > 0:
@@ -191,7 +194,9 @@ def step_counts(items: Sequence[tuple], window: Optional[int] = None,
                if query_block else 0)
     return {"seqs": len(items), "tokens": prefill + decode,
             "prefill_tokens": prefill, "decode_tokens": decode,
-            "blocked_rows": blocked, "kv_rows": kv_rows, "qk_pairs": pairs}
+            "blocked_rows": blocked,
+            "one_row_walks": prefill + decode - blocked if query_block else 0,
+            "kv_rows": kv_rows, "qk_pairs": pairs}
 
 
 def window_step_counts(items: Sequence[tuple], cfg: TransformerConfig,

@@ -17,6 +17,16 @@ behind :func:`paged_decode_attention` for a bf16/fp32 cache:
   decode row is a run of one and walks its own pages, no others.  (The
   row-per-program grid (T, nkv) this replaces took 150 ms a call on a
   251-row chunk at context 3,840, this one 0.66 ms: PERF.md, PR 27.)
+* **The tile a walk multiplies is sized to its run**: a run of ONE row (a
+  decode row, alone in a decode bucket or beside a chunk, or the piece a
+  block boundary cuts off a longer run) is multiplied, soft-maxed and
+  accumulated on a window of ``narrow_rows`` tile rows (16, or 32 for a
+  group that does not divide 16) from a 16-row boundary, which holds its
+  ``group`` query rows; a run of two rows or more takes the whole tile.
+  The kernel decides a walk at a time, from the run's length at run time;
+  same pages, same DMAs, same arithmetic on the live rows.  (With 16
+  query heads to a KV head a one-row walk multiplied 256-512 rows for 16
+  live and ran at a fifth of its bytes' time: PERF.md, PR 54.)
 * **Masks are per row**: causal, context-length and sliding-window masks
   come from each row's own ``token_pos`` / ``token_ctx_len``, and rows
   outside the run being walked are masked out, so the kernel relies on no
@@ -60,6 +70,7 @@ token's causal position and its sequence's context length.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -127,6 +138,18 @@ def shared_walk_rows(run_lengths, query_block: int = QUERY_BLOCK) -> int:
     return shared
 
 
+def narrow_rows(group: int, rows: int) -> int:
+    """Tile rows a run of ONE row is multiplied on (0: the whole tile of
+    ``rows``): its ``group`` query rows start up to ``16 - gcd(16, group)``
+    rows past a 16-row boundary (16: the sublanes a packed bf16 tile
+    holds, so a window from there is a whole-tile slice in either dtype),
+    so they lie inside this many rows from it: 16 where ``group`` divides
+    16, 32 for groups of 5 and 6."""
+    reach = 16 - math.gcd(16, group) + group
+    nr = -(-reach // 16) * 16
+    return nr if rows % 16 == 0 and nr < rows else 0
+
+
 def _kernel_qblock(tables_ref, slot_ref, pos_ref, clen_ref, layer_ref, q_ref,
                    rowpos_ref, rowclen_ref, *refs, bs, group, qb,
                    pages_per_step, sm_scale, window=None, sink=False):
@@ -149,6 +172,7 @@ def _kernel_qblock(tables_ref, slot_ref, pos_ref, clen_ref, layer_ref, q_ref,
     base = pl.program_id(0) * qb
     layer = layer_ref[0]
     nkv, rows = q_ref.shape[:2]
+    narrow = narrow_rows(group, rows)
     if sink:
         m_scr[...] = sink_ref[...]
         l_scr[...] = jnp.ones(l_scr.shape, jnp.float32)
@@ -207,7 +231,51 @@ def _kernel_qblock(tables_ref, slot_ref, pos_ref, clen_ref, layer_ref, q_ref,
         def _():
             start_step(0, 0)
 
-        in_run = (row >= r0 * group) & (row < r1 * group)
+        one_row = r1 - r0 == 1
+
+        def step(n, buf, start=None):
+            """One compute step on the whole tile, or on its ``narrow``
+            rows from ``start``: rows outside the run, and rows with no
+            live key here, keep their state as it was (``alpha`` 1, ``p``
+            0)."""
+            if start is None:
+                sl, rws, rpos, rclen = slice(None), row, row_pos, row_clen
+            else:
+                sl = pl.ds(start, narrow)
+                rws = start + lax.broadcasted_iota(jnp.int32, (narrow, 1), 0)
+                rpos, rclen = rowpos_ref[sl], rowclen_ref[sl]
+            c = (lax.broadcasted_iota(
+                jnp.int32, (rws.shape[0], pages_per_step * bs), 1)
+                 + (j_lo + n * pages_per_step) * bs)
+            valid = ((rws >= r0 * group) & (rws < r1 * group)
+                     & (c <= rpos) & (c < rclen))
+            if window is not None:
+                valid &= rpos - c < window
+
+            def head(h, _):
+                k = k_buf[buf, h]                        # [step_keys, d]
+                v = v_buf[buf, h]
+                s = jax.lax.dot_general(
+                    q_ref[h, sl], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # [rows, step_keys]
+                s = jnp.where(valid, s * sm_scale, NEG_INF)
+                m_prev = m_scr[h, sl]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # a row with no live key in this step (another run's row,
+                # or one whose frontier lies before it) must add nothing:
+                # without the select its exp(NEG_INF - NEG_INF) would be 1
+                p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                l_scr[h, sl] = l_scr[h, sl] * alpha + jnp.sum(
+                    p, axis=1, keepdims=True)
+                acc_scr[h, sl] = acc_scr[h, sl] * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # [rows, d]
+                m_scr[h, sl] = m_new
+                return 0
+
+            lax.fori_loop(0, nkv, head, 0)
 
         def body(n, _):
             buf = lax.rem(n, 2)
@@ -217,37 +285,21 @@ def _kernel_qblock(tables_ref, slot_ref, pos_ref, clen_ref, layer_ref, q_ref,
                 start_step(n + 1, 1 - buf)
 
             wait_step(buf)
-            c = (lax.broadcasted_iota(jnp.int32, (rows, pages_per_step * bs),
-                                      1)
-                 + (j_lo + n * pages_per_step) * bs)
-            valid = in_run & (c <= row_pos) & (c < row_clen)
-            if window is not None:
-                valid &= row_pos - c < window
-
-            def head(h, _):
-                k = k_buf[buf, h]                        # [step_keys, d]
-                v = v_buf[buf, h]
-                s = jax.lax.dot_general(
-                    q_ref[h], k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # [rows, step_keys]
-                s = jnp.where(valid, s * sm_scale, NEG_INF)
-                m_prev = m_scr[h]
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=1, keepdims=True))
-                alpha = jnp.exp(m_prev - m_new)
-                # a row with no live key in this step (another run's row,
-                # or one whose frontier lies before it) must add nothing:
-                # without the select its exp(NEG_INF - NEG_INF) would be 1
-                p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-                l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1,
-                                                      keepdims=True)
-                acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # [rows, d]
-                m_scr[h] = m_new
+            if not narrow:
+                step(n, buf)
                 return 0
 
-            lax.fori_loop(0, nkv, head, 0)
+            @pl.when(one_row)
+            def _():
+                # the run's ``group`` query rows lie inside ``narrow`` tile
+                # rows from a 16-row boundary (a packed bf16 sublane tile)
+                step(n, buf, pl.multiple_of(
+                    jnp.minimum(r0 * group // 16 * 16, rows - narrow), 16))
+
+            @pl.when(jnp.logical_not(one_row))
+            def _():
+                step(n, buf)
+
             return 0
 
         lax.fori_loop(0, n_steps, body, 0)

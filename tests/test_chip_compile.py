@@ -401,10 +401,17 @@ def test_kv_append_other_shapes(chip, t, nkv, d, bs, dtype):
 _MIMO_KINDS = {"full": (2, 4, None, False), "window_sink": (5, 8, 128, True)}
 
 
-@pytest.mark.parametrize("t", [1024, 16], ids=["chunk", "decode_bucket"])
+@pytest.mark.parametrize("t", [1024, 16, 32, 64],
+                         ids=["chunk", "decode_bucket", "bucket_32",
+                              "bucket_64"])
 @pytest.mark.parametrize("kind", list(_MIMO_KINDS))
 def test_paged_qblock_keys_192_values_128_heads_by_kind(chip, kind, t):
+    """Every bucket's tile is wider than ``narrow_rows``, so each
+    compiles the narrow path of a one-row run too: a 16-row window of the
+    query tile and of the softmax state at a run-time 16-row boundary."""
     layers, nkv, window, sink = _MIMO_KINDS[kind]
+    assert paged_attention.narrow_rows(
+        64 // nkv, min(t, paged_attention.QUERY_BLOCK) * 64 // nkv) == 16
     assert paged_attention.supports(128, 192, 128)
     dk = paged_attention.row_width(192)
 
@@ -873,18 +880,38 @@ def test_latent_step_reads_by_the_one_path_its_shapes_choose(
         assert gathered
 
 
-def test_paged_qblock_group_of_five(chip):
+@pytest.mark.parametrize("t", [256, 32], ids=["full_step", "bucket_32"])
+def test_paged_qblock_group_of_five(chip, t):
     """Falcon-H1-34B's attention heads: 20 query heads on 4 key/value
     heads of 128, a group that is no power of two (160 query rows a KV
-    head and block), pages of 16, 64 pages a sequence."""
+    head and block, a one-row run's five inside a 32-row window of them),
+    pages of 16, 64 pages a sequence."""
+    assert paged_attention.narrow_rows(5, 160) == 32
+
     def fn(q, k, v, tables, pos, clen, slot):
         return paged_attention.paged_decode_attention(
             q, k, v, tables, pos, clen, block_size=_BS,
             sm_scale=128 ** -0.5, token_slot=slot)
 
     pool = chip((4, 4352 * _BS, 128), BF16)
-    _compile(fn, chip((256, 20, 128), BF16), pool, pool, chip((65, 64), I32),
-             chip((256,), I32), chip((256,), I32), chip((256,), I32))
+    _compile(fn, chip((t, 20, 128), BF16), pool, pool, chip((65, 64), I32),
+             chip((t,), I32), chip((t,), I32), chip((t,), I32))
+
+
+def test_paged_qblock_sixteen_heads_a_kv_head_bucket_128(chip):
+    """Nemotron-3-Nano's one attention layer: 32 query heads on 2
+    key/value heads of 128 (512 query rows a KV head and block, a one-row
+    run's sixteen a 16-row window), the 128-row bucket its 60 decoding
+    streams take, pages of 16, 256 a sequence, 256 sequences tracked."""
+    def fn(q, k, v, tables, pos, clen, slot, layer):
+        return paged_attention.paged_decode_attention(
+            q, k, v, tables, pos, clen, block_size=_BS,
+            sm_scale=128 ** -0.5, token_slot=slot, layer=layer)
+
+    pool = chip((1, 2, 4096 * _BS, 128), BF16)
+    _compile(fn, chip((128, 32, 128), BF16), pool, pool,
+             chip((257, 256), I32), chip((128,), I32), chip((128,), I32),
+             chip((128,), I32), chip((), I32))
 
 
 def test_paged_decode_int8_kv(chip):

@@ -64,8 +64,10 @@ def test_append_phase_tiny(tpu_branches):
 def test_sink_phase_tiny(tpu_branches):
     out = chip_smoke.sink_phase(heads=16, head_dim=192, value_dim=128,
                                 window=12, block_size=8, blocks=8, pages=24,
-                                chunk_rows=21)
+                                chunk_rows=21, decode_rows=(13,))
     assert out["window"] < 0.03 and out["full"] < 0.03
+    # and the step of one-row runs alone in their bucket
+    assert max(out["window 13 rows"], out["full 13 rows"]) < 0.03
     assert min(out["window sign"], out["window heads"]) > 10 * out["window"]
 
 

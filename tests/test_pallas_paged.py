@@ -365,7 +365,7 @@ def test_rows_out_of_order_keep_their_own_masks():
     assert err < 0.05, err
 
 
-@pytest.mark.parametrize("runs,qb,want", [
+_WALK_CASES = [
     ([1] * 20, 32, 0),                  # decode only
     ([256], 32, 256),                   # one chunk, eight blocks
     ([1] * 5 + [251], 32, 251),         # the seam block still shares a walk
@@ -373,9 +373,130 @@ def test_rows_out_of_order_keep_their_own_masks():
     ([1] * 31 + [2], 32, 0),            # a run cut in two rows of one
     ([3, 1], 32, 3),
     ([33], 32, 32),                     # its last row is alone in its block
-])
+]
+
+
+@pytest.mark.parametrize("runs,qb,want", _WALK_CASES)
 def test_shared_walk_rows(runs, qb, want):
     assert pm.shared_walk_rows(runs, qb) == want
+
+
+@pytest.mark.parametrize("runs,qb,shared", _WALK_CASES)
+def test_one_row_walks_are_the_rows_that_share_no_walk(runs, qb, shared):
+    """``one_row_walks`` of the ``v2.schedule`` span: the step's pieces of
+    one row under ``shared_walk_rows``' cut, which the kernel multiplies
+    on the narrow window; 0 where the step does not run the kernel."""
+    from deepspeed_tpu.inference.v2.engine_v2 import step_counts
+
+    items = [(7, n) for n in runs]
+    got = step_counts(items, query_block=qb)
+    assert got["blocked_rows"] == shared
+    assert got["one_row_walks"] == sum(runs) - shared
+    assert step_counts(items)["one_row_walks"] == 0
+
+
+# -- a run of ONE row is multiplied on a narrow window of its program's tile --
+# group: KV heads, key width, value width, window, sink
+_ONE_ROW_GROUPS = {
+    4: (2, 128, 128, None, False),
+    5: (2, 128, 128, None, False),
+    6: (2, 128, 128, None, False),
+    8: (2, 128, 128, 128, True),       # a window layer with a learned sink
+    16: (2, 256, 128, None, False),    # keys of 192 in 256 lanes, values 128
+}
+_PAD = -2       # a row the CALLER padded: the last table row, no context
+
+
+def _one_row_rows(scenario, rng):
+    """``(t, rows, ctx)``: ``rows`` the step's ``(sequence, position)`` by
+    row (``_PAD``: the caller's padding), ``ctx`` each sequence's context
+    length.  Runs are laid as ``(cached, n_new)`` items unless a scenario
+    says its rows one by one."""
+    if scenario == "out_of_order":
+        # three sequences' rows interleaved, no two neighbours of one
+        # sequence, positions in no order: thirteen runs of one row
+        ctx = [150, 90, 200]
+        seqs = [0, 1, 0, 2, 1, 0, 2, 1, 0, 1, 2, 0, 1]
+        return 16, [(s, int(rng.integers(0, ctx[s]))) for s in seqs] \
+            + [_PAD] * 3, ctx
+    t, runs = {
+        # decode rows alone in the smallest bucket, then padding
+        "alone_bucket_16": (16, [1] * 11),
+        # one-row runs before, between and after multi-row runs of ONE
+        # 32-row block, a padded row last
+        "beside_runs_in_a_block": (32, [1] * 3 + [17] + [1] * 2 + [4]
+                                   + [1] * 4),
+        # rows 31..64 are one run: the block boundaries cut a piece of one
+        # row off its head (row 31) and off its tail (row 64)
+        "cut_by_a_block_boundary": (96, [1] * 2 + [28] + [1] + [34]
+                                    + [1] * 3),
+        # T no multiple of the block: the kernel pads rows of slot -1; row
+        # 45 is the caller's padding, alone between a run and those
+        "padded_tail": (46, [1] * 30 + [5] + [1] * 10),
+    }[scenario]
+    rows, ctx = [], []
+    for s, n in enumerate(runs):
+        cached = int(rng.integers(1, 200 - n))
+        rows += [(s, cached + i) for i in range(n)]
+        ctx.append(cached + n)
+    return t, rows + [_PAD] * (t - len(rows)), ctx
+
+
+@pytest.mark.parametrize("scenario", [
+    "alone_bucket_16", "beside_runs_in_a_block", "cut_by_a_block_boundary",
+    "out_of_order", "padded_tail"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("group", list(_ONE_ROW_GROUPS))
+def test_one_row_runs_on_the_narrow_window(monkeypatch, group, dtype,
+                                           scenario):
+    """A run of one row multiplies, soft-maxes and accumulates on
+    ``narrow_rows`` rows of its program's tile, beside runs that keep the
+    whole tile: against the XLA gather path, in float32 to rounding (a
+    window's rows of OTHER runs are left exactly as they were), walks of
+    several compute steps."""
+    from deepspeed_tpu.inference.v2 import model as m2
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    nkv, dk, dv, window, has_sink = _ONE_ROW_GROUPS[group]
+    nh, bs, nb = nkv * group, 16, 13
+    monkeypatch.setattr(pm, "_STEP_KEYS", 64)   # a walk is up to 4 steps
+    rng = np.random.default_rng(group)
+    t, rows, ctx = _one_row_rows(scenario, rng)
+    assert pm.narrow_rows(group, min(t, pm.QUERY_BLOCK) * group) == (
+        16 if 16 % group == 0 else 32)
+    n_seq, n_real = len(ctx), sum(r != _PAD for r in rows)
+    slot = jnp.asarray([n_seq if r == _PAD else r[0] for r in rows],
+                       jnp.int32)
+    pos = jnp.asarray([0 if r == _PAD else r[1] for r in rows], jnp.int32)
+    clen = jnp.asarray(ctx + [0], jnp.int32)[slot]
+    # page 0 is garbage; every sequence owns its pages, in no order
+    n_pages = 1 + n_seq * nb
+    tables = np.zeros((n_seq + 1, nb), np.int32)
+    tables[:n_seq] = 1 + rng.permutation(n_seq * nb).reshape(n_seq, nb)
+    tables = jnp.asarray(tables)
+    dt = jnp.dtype(dtype)
+    ks = jax.random.split(jax.random.PRNGKey(group), 4)
+    q = jax.random.normal(ks[0], (t, nh, dk), dt)
+    kp = jax.random.normal(ks[1], (nkv, n_pages * bs, dk), dt)
+    vp = jax.random.normal(ks[2], (nkv, n_pages * bs, dv), dt)
+    sink = 1.0 + jax.random.normal(ks[3], (nh,), jnp.float32) \
+        if has_sink else None
+    scale = 1.0 / np.sqrt(dk)
+    out = _decode_fn(q, kp, vp, tables, pos, clen, block_size=bs,
+                     sm_scale=scale, window=window, token_slot=slot,
+                     sink=sink)
+    assert out.shape == (t, nh, dv) and out.dtype == dt
+    c_idx = jnp.arange(nb * bs)
+    gather_idx = tables[slot][:, c_idx // bs] * bs + (c_idx % bs)[None, :]
+    cfg = TransformerConfig(num_heads=nh, num_kv_heads=nkv,
+                            hidden_size=nh * dk, use_rope=True, arch="llama",
+                            attn_scale=scale, sliding_window=window or 0)
+    ref = m2._paged_attention_xla(q, kp, vp, gather_idx, pos, clen, cfg, sink)
+    outf, reff = out.astype(jnp.float32), ref.astype(jnp.float32)
+    err = float(jnp.max(jnp.abs(outf[:n_real] - reff[:n_real])))
+    assert err < (2e-5 if dtype == "float32" else 0.05), err
+    # a padded row belongs to no sequence and attends to nothing
+    assert float(jnp.max(jnp.abs(outf[n_real:]), initial=0.0)) == 0.0
 
 
 # -- the engine: a long prompt prefilled in chunks beside decoding rows ------

@@ -442,7 +442,8 @@ def test_models_without_a_mixer_keep_their_programs():
              if e["name"] == "v2.schedule"]
     assert all(set(a) == {"trace_id", "span_id", "parent_id", "seqs",
                           "tokens", "prefill_tokens", "decode_tokens",
-                          "blocked_rows", "kv_rows", "qk_pairs",
+                          "blocked_rows", "one_row_walks", "kv_rows",
+                          "qk_pairs",
                           "append_pages"}
                for a in sched)
     # sixteen rows from position 0 fill two pages of 8, four more a third,

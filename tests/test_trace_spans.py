@@ -61,16 +61,18 @@ def _serve(eng, model, config):
     # B: 9 cached + 1 new (position 9 sees 10 keys); A's three rows share
     # a walk where the step runs the query-blocked kernel
     (None, 32, {"seqs": 2, "tokens": 4, "prefill_tokens": 3,
-                "decode_tokens": 1, "blocked_rows": 3, "kv_rows": 8 + 10,
+                "decode_tokens": 1, "blocked_rows": 3, "one_row_walks": 1,
+                "kv_rows": 8 + 10,
                 "qk_pairs": (6 + 7 + 8) + 10}),
     # window 7: A's positions see 6, 7, 7; B's sees 7; each context 7 rows;
     # a block of two rows cuts A's run into two rows and one
     (7, 2, {"seqs": 2, "tokens": 4, "prefill_tokens": 3, "decode_tokens": 1,
-            "blocked_rows": 2, "kv_rows": 7 + 7,
+            "blocked_rows": 2, "one_row_walks": 2, "kv_rows": 7 + 7,
             "qk_pairs": (6 + 7 + 7) + 7}),
     # a window no context reaches changes nothing; no blocked kernel
     (64, 0, {"seqs": 2, "tokens": 4, "prefill_tokens": 3, "decode_tokens": 1,
-             "blocked_rows": 0, "kv_rows": 18, "qk_pairs": 31}),
+             "blocked_rows": 0, "one_row_walks": 0, "kv_rows": 18,
+             "qk_pairs": 31}),
 ])
 def test_step_counts_hand_worked(window, query_block, want):
     assert step_counts([(5, 3), (9, 1)], window, query_block) == want
