@@ -336,6 +336,8 @@ class StepInFlight(NamedTuple):
     # ``out``) and where the token goes in the sequence's ``tokens`` (an
     # ``extend(uid, IN_FLIGHT)`` keeps the place)
     places: Dict[int, tuple]
+    # a self-drafting step's: the uids whose run is a verify run
+    verified: frozenset = frozenset()
 
     @property
     def uids(self):
@@ -609,10 +611,23 @@ class InferenceEngineV2:
                     "refused draft row would have advanced; "
                     + (self.state_kind or "this model has no module"))
             self.state_manager.drafting = True
+            # what the last drafting step left by slot (its ``out``), as
+            # ``_prev`` is the sampled step's; the program returns it
+            # under the sharding these zeros have, so that the first
+            # step's operand and every later one's are one signature
+            self._prev_draft = zeros(
+                (4, self.cfg.max_tracked_sequences + 1), jnp.int32)
             self._draft = jax.jit(
                 _named("ragged_draft_step", ragged_draft_step, cfg=mc,
                        block_size=self.cfg.block_size),
-                donate_argnums=(1, 2))
+                donate_argnums=(1, 2),
+                out_shardings=(replicated, None, None))
+            # a greedy step's two halves and the place kept between them
+            # are the drafting step's: bound here, once, so that an
+            # engine that does not draft runs its own with no test for it
+            self.launch = self._launch_drafting
+            self.fetch = self._fetch_drafting
+            self.extend = self._extend_drafting
         self.attention_impl = (
             attention_impl_name(mc, self.cfg.block_size) if latent is None
             else "latent_" + latent.indexer_impl_name(mc))
@@ -770,14 +785,17 @@ class InferenceEngineV2:
                     items, self.model_config, rb.index.rows,
                     rb.index.blocks * self.cfg.block_size))
                 if sample is _DRAFT:
-                    counts.update(verify_runs=len(rb.verified),
-                                  draft_rows=len(rb.verified),
-                                  mtp_rows=rb.n_tokens)
+                    counts.update(
+                        verify_runs=len(rb.verified),
+                        draft_rows=len(rb.verified), mtp_rows=rb.n_tokens,
+                        ahead_runs=int(np.count_nonzero(
+                            rb.index.draft_arrays()[1] >= 2)))
             sp.end(**counts)
         if sample is None:
             program, variant, kw = self._step, (), {}
         elif sample is _DRAFT:
-            program, variant, kw = self._draft, (), {}
+            program, variant, kw = self._draft, (), {
+                "prev": self._prev_draft}
         else:
             greedy = sample["temperature"] <= 0
             top_k, top_p = sample.get("top_k", 0), sample.get("top_p")
@@ -1051,8 +1069,89 @@ class InferenceEngineV2:
                              "has no self_draft")
         self._refuse_in_flight("step_bursts")
         with self.stepping():
-            rb, out = self._ragged_step([], [], sample=_DRAFT)
-            return {} if rb is None else self._settle(rb, out)
+            flight = self.launch()
+            return {} if flight is None else self.fetch(flight)
+
+    def _launch_drafting(self, temperature: float = 0.0,
+                         key: Optional[Any] = None, top_k: int = 0,
+                         top_p: float = 1.0) -> Optional[StepInFlight]:
+        """:meth:`launch` of a self-drafting engine: a greedy step is the
+        drafting program (``ragged_draft_step``), and it too may be
+        launched before the step before it is fetched, once.  The
+        sequences that step samples ride this one where the caller kept
+        their place (``extend(uid, IN_FLIGHT)``): written for that
+        step's draft refused, moved a position on by the device where it
+        stood (``DSStateManager.keep_place``)."""
+        if temperature > 0:
+            self._refuse_in_flight("a sampled launch of a self-drafting "
+                                   "engine")
+            return InferenceEngineV2.launch(self, temperature, key, top_k,
+                                            top_p)
+        rb, out = self._ragged_step([], [], sample=_DRAFT)
+        if rb is None:
+            return None
+        self._prev_draft = out
+        mgr = self.state_manager
+        self._flight = StepInFlight(
+            out, rb.key, rb.compiled,
+            {uid: (slot, len(mgr.get(uid).tokens))
+             for slot, uid in rb.uids_by_slot.items()},
+            frozenset(seq.uid for seq in rb.verified))
+        return self._flight
+
+    def _fetch_drafting(self, flight: StepInFlight) -> Dict[int, Any]:
+        """:meth:`fetch` of a self-drafting engine: of a drafting step,
+        ``{uid: tokens delivered}`` (:meth:`step_bursts`) after its ONE
+        fetch (span ``v2.fetch``, with the drafts it verified and
+        accepted) and the host's bookkeeping: every sampled sequence
+        takes in its burst (over the place kept for it, if one was),
+        settles the position its verify run ran past the tokens known,
+        and keeps the module's next draft unless it rides the step
+        launched meanwhile, whose draft row read it on the device."""
+        if flight.key[0] != self._draft.__name__:
+            return InferenceEngineV2.fetch(self, flight)
+        sp = self._step_span
+        fetch = (self.tracer.span("v2.fetch", self.trace_id, sp)
+                 if sp is not None else None)
+        first, second, accepted, draft = np.asarray(flight.out).tolist()
+        ahead = self._flight
+        if ahead is flight:
+            ahead = self._flight = None
+        mgr = self.state_manager
+        room = mgr.max_blocks_per_seq * mgr.block_size
+        n_accepted = 0
+        result: Dict[int, List[int]] = {}
+        for uid, (slot, _) in flight.places.items():
+            ok = accepted[slot]             # never set but on a verify run
+            n_accepted += ok
+            burst = result[uid] = ([first[slot], second[slot]] if ok
+                                   else [first[slot]])
+            if uid not in mgr:      # flushed meanwhile (a dead row's)
+                continue
+            seq = mgr.get(uid)
+            seq.settle(burst, uid in flight.verified)
+            if ahead is None or uid not in ahead.places:
+                # a draft sits one position past the pending token's,
+                # the burst's last, which is the next to be run
+                seq.draft = (draft[slot] if seq.draftable
+                             and seq.num_cached + 1 < room else None)
+        self.drafts_verified += len(flight.verified)
+        self.drafts_accepted += n_accepted
+        if fetch is not None:
+            fetch.end(drafts=len(flight.verified), accepted=n_accepted)
+        return result
+
+    def _extend_drafting(self, uid: int, token: int) -> None:
+        """:meth:`extend` of a self-drafting engine: ``IN_FLIGHT`` for a
+        sequence the unfetched step samples keeps more than the token's
+        place (``DSStateManager.keep_place``)."""
+        flight = self._flight
+        if token == IN_FLIGHT and flight is not None \
+                and uid in flight.places:
+            self.state_manager.keep_place(self.state_manager.get(uid),
+                                          uid in flight.verified)
+        else:
+            self.state_manager.extend(uid, int(token))
 
     def _begin_step(self):
         """The ``engine.step`` injection point and the step's span
@@ -1089,37 +1188,6 @@ class InferenceEngineV2:
         logits_np = self._fetch(logits)
         return {uid: logits_np[slot]
                 for slot, uid in rb.uids_by_slot.items()}
-
-    def _settle(self, rb: RaggedBatch, out) -> Dict[int, List[int]]:
-        """A self-drafting step's ONE fetch (span ``v2.fetch``, with the
-        drafts it verified and accepted) and the host's bookkeeping:
-        every sampled sequence takes in a burst's first token, gives a
-        refused draft's position back and keeps the module's next
-        draft.  ``{uid: tokens delivered}``."""
-        sp = self._step_span
-        fetch = (self.tracer.span("v2.fetch", self.trace_id, sp)
-                 if sp is not None else None)
-        first, second, accepted, draft = np.asarray(out).tolist()
-        mgr = self.state_manager
-        room = mgr.max_blocks_per_seq * mgr.block_size
-        verified = {seq.uid for seq in rb.verified}
-        n_accepted = 0
-        result: Dict[int, List[int]] = {}
-        for slot, uid in rb.uids_by_slot.items():
-            seq = mgr.get(uid)
-            ok = bool(accepted[slot])       # never set but on a verify run
-            n_accepted += ok
-            result[uid] = seq.settle(first[slot], second[slot], ok,
-                                     uid in verified)
-            # a draft sits one position past the pending token's, which
-            # is the burst's last, still the caller's to append
-            seq.draft = (draft[slot] if seq.draftable
-                         and len(seq.tokens) + 1 < room else None)
-        self.drafts_verified += len(verified)
-        self.drafts_accepted += n_accepted
-        if fetch is not None:
-            fetch.end(drafts=len(verified), accepted=n_accepted)
-        return result
 
     def _fetch(self, out) -> np.ndarray:
         """The step's result on the host: the wait for the device and for

@@ -1151,7 +1151,7 @@ def ragged_forward_verify(params, cache_k, cache_v, token_ids, token_slot,
     return nxt, cache_k, cache_v
 
 
-def ragged_draft_step(params, cache_k, cache_v, index, *,
+def ragged_draft_step(params, cache_k, cache_v, index, prev, *,
                       cfg: TransformerConfig, block_size: int):
     """One SELF-DRAFTING greedy step of a latent model with a
     multi-token-prediction module (``cfg.mla.mtp_layers``), on a packed
@@ -1174,12 +1174,50 @@ def ragged_draft_step(params, cache_k, cache_v, index, *,
     slot: the first token delivered, the second (read only where a
     draft was accepted), whether one was, the next draft.  Any other
     run of rows (a prefill chunk, a sequence without a draft) delivers
-    the argmax at its last row, as ``ragged_step_sampled`` does."""
+    the argmax at its last row, as ``ragged_step_sampled`` does.
+
+    ``prev`` [4, S+1] int32: the ``out`` of the step before this one,
+    still on the device (zeros before any).  A run the host LAUNCHED
+    AHEAD of that step's fetch (``verify`` holds 2 beside its 1) was
+    written for the outcome in which the draft that step verified was
+    refused: its rows read their tokens from ``prev`` at their slot (the
+    pending token is that step's second where its draft stood, else its
+    first; the draft row is its next draft) and, where the draft stood,
+    lie one position on: position, context length and cache row
+    (recomputed from the step's own block table) move by ``prev``'s
+    ``accepted``.  Any other run, and a first step's zeros, leave the
+    arrays as the host wrote them: ONE program, ahead or not."""
     from deepspeed_tpu.inference.v2.latent import latent_trunk, mtp_rows
 
     (token_ids, token_slot, token_pos, token_dest, block_tables, ctx_lens,
      last) = index.arrays()
     token_next, verify = index.draft_arrays()
+    with jax.named_scope("embed"):
+        # (few operations, gathers that promise their bounds, lax's own
+        # division: what is traced here is traced in every step program,
+        # and set-up pays for it a program)
+        was_first, was_second, stood, was_draft = prev
+        ahead = verify >> 1                         # [S+1], 0 or 1
+        verify = verify & 1
+        stood = stood * ahead
+        rows = token_ids.shape[0]
+        by_slot = jnp.stack([
+            ahead, stood, lax.select(stood > 0, was_second, was_first),
+            was_draft, lax.select(verify > 0, last, last - rows)])
+        row_ahead, row_stood, pending, drafted, draft_row = by_slot.at[
+            :, token_slot].get(mode="promise_in_bounds")    # [T] each
+        row_ahead = row_ahead > 0
+        token_ids = lax.select(
+            row_ahead, lax.select(lax.iota(jnp.int32, rows) == draft_row,
+                                  drafted, pending), token_ids)
+        token_pos = token_pos + row_stood
+        ctx_lens = ctx_lens + stood
+        page = block_tables.at[
+            token_slot, lax.div(token_pos, block_size)].get(
+                mode="promise_in_bounds")
+        token_dest = lax.select(
+            row_ahead, page * block_size + lax.rem(token_pos, block_size),
+            token_dest)
     x, cache_k, cache_v, _ = latent_trunk(
         params, cache_k, cache_v, token_ids, token_slot, token_pos,
         token_dest, block_tables, ctx_lens, None, cfg, block_size)

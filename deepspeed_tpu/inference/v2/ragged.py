@@ -116,7 +116,9 @@ class BlockedAllocator:
 # the token id of a sequence's sampled token while the step that samples it
 # is still on the device (``InferenceEngineV2.launch``): the next step's row
 # reads the token where the device left it (``model.ragged_step_sampled``:
-# any negative id), and the fetch writes it over this
+# any negative id), and the fetch writes it over this.  A self-drafting
+# engine's ``SequenceDescriptor.draft`` holds it too, for the draft that
+# step makes
 IN_FLIGHT = -1
 
 
@@ -147,21 +149,24 @@ class SequenceDescriptor:
     def uncached(self) -> int:
         return len(self.tokens) - self.num_cached
 
-    def settle(self, first: int, second: int, accepted: bool,
-               verified: bool) -> List[int]:
-        """Take in a self-drafting step's tokens: ``first`` and, where
-        the draft it verified was ``accepted``, ``second``.  The last of
-        them is left to the caller's ``extend``, as after any step; the
-        one before it is appended here.  A refused draft's position is
-        given back (its cache rows are rewritten by the next step before
-        any row reads them): bookkeeping alone, no device copy.  Returns
-        the tokens delivered."""
-        if accepted:
-            self.tokens.append(first)
-            return [first, second]
+    def settle(self, burst: List[int], verified: bool) -> None:
+        """Take in the ``burst`` a self-drafting step delivers: one
+        token, or two where the draft it verified stood.  Where the
+        caller kept the place of the token in flight (``IN_FLIGHT``, the
+        last of ``tokens``) they are written over it; else the last of
+        them is left to the caller's ``extend``, as after any step, and
+        the one before it is appended here.  A ``verified`` run ran one
+        position past the tokens known: a refused draft's is given back
+        (its cache rows are rewritten by the next step before any row
+        reads them), unless ``DSStateManager.keep_place`` gave it back
+        already for a step launched ahead, which then ran a position on
+        where the draft stood: bookkeeping alone, no device copy."""
+        if self.tokens[-1] == IN_FLIGHT:
+            self.tokens[-1:] = burst
+        else:
+            self.tokens.extend(burst[:-1])
         if verified:
-            self.num_cached -= 1
-        return [first]
+            self.num_cached += len(burst) - 1 - (self.draft != IN_FLIGHT)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -208,7 +213,10 @@ class PackedIndex:
         ``token_next`` [T], the token that follows each row where the
         host knows it (-1: the step's own argmax at that row), and
         ``verify`` [max_seqs+1], 1 where the sequence's last row is a
-        draft to be verified against the argmax of the row before."""
+        draft to be verified against the argmax of the row before, and 2
+        beside it where the run was launched ahead of the fetch of the
+        step before (its tokens are that step's, on the device, and its
+        positions one on where that step's draft stood)."""
         at = self.size(self.rows, self.slots, self.blocks)
         return (self.buf[at:at + self.rows],
                 self.buf[at + self.rows:at + self.rows + self.slots])
@@ -354,6 +362,37 @@ class DSStateManager:
 
     def extend(self, uid: int, token: int) -> None:
         self._seqs[uid].tokens.append(token)
+
+    def keep_place(self, seq: SequenceDescriptor, verified: bool) -> None:
+        """A self-drafting engine's ``extend(uid, IN_FLIGHT)`` for a
+        sequence the unfetched step samples: the place of the token in
+        flight, and the sequence as the NEXT step is to find it with that
+        step's outcome unknown.  Its base is the outcome in which the
+        draft in flight is refused (``verified``: the step runs a verify
+        run for it): the position run for the draft is given back now,
+        the token in flight is the pending one, the next draft is in
+        flight too (the device moves the run one position on where the
+        draft stands: ``model.ragged_draft_step``).  The run needs its
+        pages and its block-table bucket whichever way that goes: where
+        the further position would take a page that is not to be had, a
+        wider table or more than the table holds, the sequence keeps its
+        place and sits the launch out (nothing of it is uncached; the
+        fetch settles it as after any step)."""
+        at = len(seq.tokens)
+        seq.tokens.append(IN_FLIGHT)
+        room = self.max_blocks_per_seq * self.block_size
+        if verified:
+            # pending token at ``at`` and draft behind it, or one on each
+            need = -(-(at + 3) // self.block_size)
+            buckets = self.min_blocks_bucket, self.max_blocks_per_seq
+            if at + 3 > room or _bucket(need, *buckets) != _bucket(
+                    -(-(at + 2) // self.block_size), *buckets) or (
+                        need > len(seq.blocks) + self.allocator.free_blocks):
+                seq.draft = None        # verified; the fetch brings the next
+                return
+            self.ensure_capacity(seq, at + 3)
+            seq.num_cached -= 1
+        seq.draft = (IN_FLIGHT if seq.draftable and at + 1 < room else None)
 
     def ensure_capacity(self, seq: SequenceDescriptor, upto_tokens: int) -> None:
         """Allocate pages so the first ``upto_tokens`` tokens fit."""
@@ -517,6 +556,8 @@ def build_ragged_batch(schedule: "List[tuple]", mgr: DSStateManager,
                 token_ids[cursor + n_new - 1] = seq.draft
                 verify[sl] = 1
                 verified.append(seq)
+            if known[0] == IN_FLIGHT:
+                verify[sl] |= 2         # launched ahead of that token
         # a sequence may hold pages past this step's context (a fused
         # decode's horizon, a rewound draft): the bucket cuts them off
         held = seq.blocks[:nb]

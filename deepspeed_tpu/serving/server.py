@@ -138,6 +138,11 @@ def _toward(old: Optional[float], new: float, up: float = 0.25,
     return old + (up if new > old else down) * (new - old)
 
 
+def _singles(step, tokens: Dict[int, int]) -> Dict[int, List[int]]:
+    """What a sampled step's fetch brought, as bursts of one token."""
+    return {uid: [tok] for uid, tok in tokens.items()}
+
+
 class ServerConfig:
     def __init__(self, d: Optional[dict] = None, **kw):
         d = {**(d or {}), **kw}
@@ -199,13 +204,17 @@ class InferenceServer:
         # an engine that drafts for itself (engine_config self_draft):
         # every all-greedy step is a self-drafting one, whoever is still
         # prefilling; no per-request opt-in, no gate (_spec_eligible is
-        # the external draft's)
+        # the external draft's).  The loop runs it as it runs any greedy
+        # step, launch and fetch; what its fetch brings is bursts already
         self._self_draft = bool(getattr(engine, "self_draft", False))
         if self._self_draft and spec_decoder is not None:
             raise ValueError(
                 "spec_decoder with a self-drafting engine (self_draft): "
                 "rows an external draft verifies or takes back would "
                 "leave the engine's own module's cache rows behind")
+        # what ``engine.fetch`` brought, as bursts (chosen once: the
+        # loop's step has no test for who drafts)
+        self._bursts = self._drafted if self._self_draft else _singles
         # a telemetry.Telemetry hub: serving histograms register in ITS
         # registry (one Prometheus exposition for both hot loops) and the
         # loop emits kind="serving" StepRecords to the same JSONL
@@ -907,9 +916,12 @@ class InferenceServer:
         ends by an ``eos_token_id`` rides a dead row and is flushed here,
         after N+1's launch.  A step whose program has no measured time
         yet is fetched before anything follows it (``_go_time``).
-        Everything else (a
-        batch not all greedy, a draft of either kind, ``launch=False``: a
-        drain) runs or finishes with nothing launched behind it."""
+        A self-drafting engine's greedy step is such a step: its
+        launch and its fetch are the engine's own (a verify run launched
+        ahead is settled on the device), its fetch brings bursts.
+        Everything else (a batch not all greedy, an external draft,
+        ``launch=False``: a drain) runs or finishes with nothing launched
+        behind it."""
         eng = self.engine
         if launch and len(self._active) > 1 \
                 and self.admission.low_watermark_deficit(eng) > 0:
@@ -924,7 +936,7 @@ class InferenceServer:
         all_greedy = all(r.params.greedy for r in self._active.values())
         spec_ready = launch and self._spec_eligible()
         # the one kind of step that can follow a step still on the chip
-        plain = (all_greedy and not spec_ready and not self._self_draft
+        plain = (all_greedy and not spec_ready
                  and not any(r.handoff or r.pending_insert
                              for r in self._active.values()))
         if launch and self._flight is not None and not plain:
@@ -951,17 +963,6 @@ class InferenceServer:
                     # each value is the accepted token burst (>= 1), and
                     # the engine's sequences already carry them
                     emitted = self._spec.round(self._active)
-                elif flight is None and all_greedy and self._self_draft:
-                    # the engine drafts for itself inside the ragged step
-                    # (its multi-token-prediction module): a burst of one
-                    # or two tokens a sequence, prompts' chunks in the
-                    # same step; the last token is extended below as a
-                    # plain step's
-                    before = (eng.drafts_verified, eng.drafts_accepted)
-                    emitted = eng.step_bursts()
-                    self.metrics.record_spec_round(
-                        eng.drafts_verified - before[0],
-                        eng.drafts_accepted - before[1])
                 elif flight is None and not all_greedy:
                     logits = eng.step(return_logits=True)
                     emitted = {u: [_host_sample(out,
@@ -1134,7 +1135,17 @@ class InferenceServer:
             # came later still
             ahead.begins = max(ahead.begins, now - tail_s)
             self._missed = (ahead.called, now)
-        return {uid: [tok] for uid, tok in tokens.items()}
+        return self._bursts(flight.step, tokens)
+
+    def _drafted(self, step, bursts: Dict[int, List[int]]
+                 ) -> Dict[int, List[int]]:
+        """What a self-drafting engine's fetch brought: bursts of one
+        token or two (prompts' chunks ran in the same step), the last of
+        each extended as a plain step's token is; the round's drafts and
+        how many stood (a burst of two) go to the metrics."""
+        self.metrics.record_spec_round(
+            len(step.verified), sum(len(b) == 2 for b in bursts.values()))
+        return bursts
 
     def _read_tail(self, reading_s: float) -> None:
         """A reading of the way from the chip's end of a step to its

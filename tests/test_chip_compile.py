@@ -599,7 +599,7 @@ def latent_step(chip):
                 fn = functools.partial(v2_model.ragged_draft_step, cfg=cfg,
                                        block_size=128)
                 compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-                    params, ck, cv, index).compile()
+                    params, ck, cv, index, chip((4, 33), I32)).compile()
             else:
                 state = _abstract(chip, state)
                 fn = functools.partial(v2_model.ragged_step_sampled, cfg=cfg,
@@ -724,15 +724,23 @@ def test_self_drafting_step_is_one_program_with_its_pools_in_place(
     the verify runs, accept, module and next draft compile as ONE
     program whose two pools (six cache layers: the trunk's five and the
     module's) are donated, come back in place and are never copied
-    whole or by layer; the one result is ``s32[4, slots]``; the index
+    whole or by layer; the one result is ``s32[4, slots]``, and the
+    result of the step before is an operand of the same shape beside the
+    index buffer (a run launched ahead reads its tokens and its
+    position's shift there: no second program for it); the index
     kernel runs in all six layers; no stack of expert matrices is sliced
     out as a value of its own."""
+    import re
+
     rows = 1024 * 128
     _, _, (ck, cv, _), compiled = latent_step("glm-5-ep16-l5", t, nb)
     assert ck.shape == (6, rows, 640) and cv.shape == (6, rows, 128)
     text = compiled.as_text()
     assert len(_aliased_outputs(text)) == 2
     assert "s32[4,33]" in text
+    entry = text[text.index("ENTRY "):]
+    operands = re.findall(r"= (\w+\[[\d,]*\])\S* parameter\(", entry)
+    assert operands.count("s32[4,33]") == 1, operands
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 * 2 ** 30, mem
     assert text.count("latent_index_scores") >= 3   # trunk segments, module
@@ -852,7 +860,7 @@ def test_latent_step_reads_by_the_one_path_its_shapes_choose(
             fn = functools.partial(v2_model.ragged_draft_step, cfg=cfg,
                                    block_size=bs)
             compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-                params, ck, cv, index).compile()
+                params, ck, cv, index, chip((4, 33), I32)).compile()
             held, experts = (ck, cv), _GLM5_EXPERTS
         else:
             state = _abstract(chip, state)

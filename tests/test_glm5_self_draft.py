@@ -279,6 +279,125 @@ def test_a_refused_drafts_position_is_rewritten_before_it_is_read(
     assert {u: t[:40] for u, t in out.items()} == plain_streams
 
 
+def ahead(eng, prompts, n_new, arrive=None, poison=False):
+    """Serve ``prompts`` through ``launch`` / ``fetch`` with every step
+    launched before the step before it is fetched, the place of each
+    token in flight kept with ``IN_FLIGHT``.  ``poison``: between two
+    launches every cache row at and past a sequence's next position is
+    overwritten with NaN, and the row of the draft the unfetched step
+    verifies too WHERE THAT STEP REFUSES IT (decided on the device: the
+    host does not know yet).  Returns ({uid: tokens}, steps, verify runs
+    launched ahead of the step that ran their prompt's last chunk)."""
+    from deepspeed_tpu.inference.v2.ragged import IN_FLIGHT
+
+    arrive = arrive or {}
+    waiting = dict(prompts)
+    out = {u: [] for u in prompts}
+    mgr, bs = eng.state_manager, eng.cfg.block_size
+    flight, steps, first_runs = None, 0, 0
+    while waiting or mgr.n_active:
+        for u in [u for u in waiting if arrive.get(u, 0) <= steps]:
+            eng.admit(u, waiting.pop(u))
+        nxt = eng.launch()
+        if flight is not None:
+            if nxt is not None:
+                first_runs += len(nxt.verified & flight.uids
+                                  - flight.verified)
+            for u, burst in eng.fetch(flight).items():
+                if u in mgr:
+                    out[u].extend(burst)
+                    if len(out[u]) >= n_new:
+                        eng.flush(u)
+        flight = nxt
+        steps += 1
+        assert steps < 40 * n_new
+        if flight is None:
+            continue
+        for u in flight.uids:
+            if u in mgr:
+                eng.extend(u, IN_FLIGHT)
+        if poison:
+            dead, maybe, slots = [], [], []
+            for u in prompts:
+                if u not in mgr:
+                    continue
+                seq = mgr.get(u)
+                # with the step in flight unknown the sequence stands as
+                # if its draft were refused: its position given back
+                nxt_pos = seq.num_cached + (seq.draft == IN_FLIGHT
+                                            and u in flight.verified)
+                for pos in range(nxt_pos, len(seq.blocks) * bs):
+                    dead.append(seq.blocks[pos // bs] * bs + pos % bs)
+                if u in flight.verified:
+                    pos = nxt_pos - 1       # the draft in flight
+                    maybe.append(seq.blocks[pos // bs] * bs + pos % bs)
+                    slots.append(seq.slot)
+            refused = (flight.out[2, jnp.asarray(slots, jnp.int32)]
+                       == 0)[None, :, None]
+            dead = jnp.asarray(dead, jnp.int32)
+            maybe = jnp.asarray(maybe, jnp.int32)
+
+            def nan(cache):
+                cache = cache.at[:, dead].set(jnp.nan)
+                return cache.at[:, maybe].set(
+                    jnp.where(refused, jnp.nan, cache[:, maybe]))
+
+            eng.cache_k, eng.cache_v = nan(eng.cache_k), nan(eng.cache_v)
+    if flight is not None:
+        eng.fetch(flight)       # dead rows of sequences flushed since
+    return {u: t[:n_new] for u, t in out.items()}, steps, first_runs
+
+
+# what the module's drafts come to: as initialised (some stand), with the
+# module's last norm zeroed (its logits are flat, its draft token 0: none
+# stands), with the trunk's zeroed too (every token is 0: all stand)
+_DRAFTS = {"mixed": (), "all_refused": ("mtp",),
+           "all_accepted": ("mtp", "trunk")}
+
+
+def test_steps_launched_ahead_deliver_plain_greedy_streams(plain_streams):
+    """At least 40 steps a case, each launched before the one before it
+    is fetched: verify runs written for a refused draft and moved on the
+    device where it stood, prompts that arrive meanwhile (a last chunk
+    followed ahead by its first verify run), sequences that sit a launch
+    out at a page's edge.  The streams are plain greedy decoding's and
+    ``step_bursts``' on the same engine, refused positions rewritten
+    before they are read; ONE engine and its programs for all three
+    kinds of draft (the weights are operands)."""
+    eng = engine(self_draft=True)
+    norms = {"mtp": eng.params["mtp"]["norm"],
+             "trunk": eng.params["final_norm"]}
+    arrive = {1: 9, 2: 18}
+    programs = None
+    for drafts, zeroed in _DRAFTS.items():
+        for name in zeroed:
+            norms[name]["scale"] = jnp.zeros_like(norms[name]["scale"])
+        n_new = 90 if "trunk" in zeroed else 40
+        want = ({u: [0] * n_new for u in PROMPTS} if "trunk" in zeroed
+                else plain_streams)
+        seen = eng.drafts_verified, eng.drafts_accepted
+        got, steps, first_runs = ahead(eng, PROMPTS, n_new, arrive,
+                                       poison="trunk" not in zeroed)
+        assert got == want, drafts
+        assert steps >= 40 and first_runs >= 2, drafts
+        verified = eng.drafts_verified - seen[0]
+        accepted = eng.drafts_accepted - seen[1]
+        if drafts == "mixed":
+            assert 0 < accepted < verified
+        elif drafts == "all_accepted":
+            assert accepted == verified > 80
+        else:
+            # (a flat module drafts token 0: a stream's own 0 stands)
+            assert accepted <= sum(t.count(0) for t in want.values()) + 1
+        assert eng.state_manager.n_active == 0 and eng._flight is None
+        assert eng.free_blocks == eng.cfg.num_blocks - 1
+        # the same programs with each step fetched before the next
+        programs = programs or set(eng._dispatched)
+        behind, _ = drafted(eng, PROMPTS, n_new, arrive=arrive)
+        assert behind == want and eng._dispatched == programs, drafts
+    assert eng._draft._cache_size() == len(programs)
+
+
 def test_the_server_delivers_bursts_and_cuts_them_at_max_new_tokens(
         plain_streams):
     from deepspeed_tpu.serving import InferenceServer, SamplingParams
@@ -293,10 +412,12 @@ def test_the_server_delivers_bursts_and_cuts_them_at_max_new_tokens(
                    for u in PROMPTS for n in (1, 2, 5, 8, 9)}
         got = {k: list(s) for k, s in streams.items()}
     finally:
-        srv.stop(drain=False, timeout=60)
+        # (the loop fetches the step it launched behind the last burst)
+        srv.stop(timeout=60)
     for (u, n), toks in got.items():
         assert toks == plain_streams[u][:n], (u, n)
     snap = srv.metrics.snapshot()
+    assert snap["steps_ahead"] > 0
     assert snap["spec_proposed"] == eng.drafts_verified > 0
     assert snap["spec_accepted"] == eng.drafts_accepted > 0
     assert eng.state_manager.n_active == 0
@@ -313,6 +434,11 @@ def test_the_server_delivers_bursts_and_cuts_them_at_max_new_tokens(
     assert sum(a["accepted"] for a in by["v2.fetch"]) == eng.drafts_accepted
     ran = [a for a in by["v2.schedule"] if a.get("tokens")]
     assert sum(a["verify_runs"] for a in ran) == eng.drafts_verified
+    # runs left to the device: on the steps launched ahead and no other
+    assert sum(a["ahead_runs"] for a in ran) > 0
+    assert all(a["ahead_runs"] <= a["seqs"] for a in ran)
+    assert sum(a["ahead"] for a in by["v2.dispatch"]) \
+        == snap["steps_ahead"] >= sum(a["ahead_runs"] > 0 for a in ran)
     assert all(a["mtp_rows"] == a["tokens"] and a["draft_rows"]
                == a["verify_runs"] for a in ran)
     # verify runs and prefill chunks in the same steps

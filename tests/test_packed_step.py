@@ -580,3 +580,90 @@ def test_the_sampled_step_lowers_with_the_fed_back_operand():
     assert all(leaf.donated
                for leaf in jax.tree.leaves(lowered.args_info[1]["state"]))
     assert not lowered.args_info[1]["prev"].donated
+
+
+# -- the drafting step's fed-back operand (ISSUE 55) -------------------------
+def _lowered_sampled_step(name):
+    model, eng = _engine(name)
+    _, args = eng.audit_step_args("decode")
+    return eng._step_sampled.lower(
+        *args[:4], prev=eng._prev, key=eng._step_key,
+        temperature=np.float32(1.0), greedy=True, top_k=0, top_p=None,
+        **eng._state_kw()).as_text()
+
+
+# sha256 of the lowered text at the commit before the drafting step took
+# its operand (f9594be), by the same call under this suite's settings
+_PARENT_TEXT = {
+    "llama-tiny":
+        "377a2432af433de2cac8f7b2cf217458dbd026a2875f6d558183111a1a1a83cf",
+    "falcon-h1-tiny":
+        "b70c624e77388f7ad688d437d637493fd66996c53ebb9291b5e62ab679747fc2",
+}
+
+
+@pytest.mark.parametrize("name", list(_PARENT_TEXT))
+def test_a_step_that_drafts_nothing_lowers_to_the_parents_text(name):
+    """What a self-drafting step reads of the step before it is the
+    drafting program's alone: the sampled step of an engine that does
+    not draft is, to the letter, the program it was."""
+    import hashlib
+
+    text = _lowered_sampled_step(name)
+    assert hashlib.sha256(text.encode()).hexdigest() == _PARENT_TEXT[name]
+
+
+def test_a_drafting_engine_compiles_one_program_a_bucket():
+    """Steps fetched before the next is launched (``step_bursts``) and
+    steps launched ahead (``launch``, ``IN_FLIGHT``, ``launch``,
+    ``fetch``) run the SAME programs, one a (token, block) bucket: the
+    ``out`` of the step before is one more operand, ``[4, slots]`` int32,
+    zeros before any step, read or not."""
+    from deepspeed_tpu.inference.v2.ragged import IN_FLIGHT
+
+    model = get_model_config("glm-5-tiny")
+    eng = InferenceEngineV2(model, dict(
+        ENGINE, self_draft=True, max_context=96,
+        memory_config={"num_blocks": 96, "block_size": 4}), seed=3)
+    slots = eng.state_manager.max_seqs + 1
+    assert eng._prev_draft.shape == (4, slots)
+    assert eng._prev_draft.sharding.mesh == eng.topology.mesh
+    prompt = np.random.default_rng(2).integers(1, 512, 21).tolist()
+
+    def serve(ahead, new=24):
+        eng.admit(7, prompt)
+        out, flight = [], None
+        while len(out) < new:
+            if not ahead:
+                bursts = eng.step_bursts()
+                if 7 in bursts:
+                    out += bursts[7]
+                    eng.extend(7, bursts[7][-1])
+                continue
+            nxt = eng.launch()
+            if flight is not None:
+                out += eng.fetch(flight).get(7, [])
+            flight = nxt
+            if flight is not None and 7 in flight.uids:
+                eng.extend(7, IN_FLIGHT)
+        if flight is not None:
+            eng.fetch(flight)
+        eng.flush(7)
+        return out[:new]
+
+    behind = serve(ahead=False)
+    warm = set(eng._dispatched)
+    assert eng._draft._cache_size() == len(warm) > 1
+    assert serve(ahead=True) == behind
+    assert eng._dispatched == warm
+    assert eng._draft._cache_size() == len(warm)
+    assert eng.drafts_accepted > 0 and eng._flight is None
+    sizes = (16, slots, 4, True)
+    index = PackedIndex(jnp.zeros((PackedIndex.size(*sizes),), jnp.int32),
+                        *sizes)
+    lowered = eng._draft.lower(eng.params, eng.cache_k, eng.cache_v, index,
+                               eng._prev_draft)
+    assert f"tensor<4x{slots}xi32>" in lowered.as_text()
+    donated = [i for i, a in enumerate(lowered.args_info[0])
+               if any(leaf.donated for leaf in jax.tree.leaves(a))]
+    assert donated == [1, 2]

@@ -72,7 +72,9 @@ def _lowered_serving_step(preset, kind):
     if draft:
         fn = functools.partial(v2_model.ragged_draft_step, cfg=cfg,
                                block_size=bs)
-        return jax.jit(fn).lower(params, ck, cv, index)
+        return jax.jit(fn).lower(
+            params, ck, cv, index,
+            jax.ShapeDtypeStruct((4, slots), jnp.int32))   # its ``out``
     fn = functools.partial(v2_model.ragged_step_sampled, cfg=cfg,
                            block_size=bs, greedy=True)
     kw = {} if state is None else {"state": state}
